@@ -15,8 +15,8 @@ armed across every child including the post-crash restart:
     admitted requests' p95 TTFT stays bounded.
 (c) **preflight invariants**: `roundtable lint` exits 0.
 
-`--smoke` shrinks (a) to one stream and (b) to a small burst for the
-run_hw_window3.sh CPU preflight step; the full run writes
+`--smoke` shrinks (a) to one stream and (b) to a small burst for a
+CPU preflight step; the full run writes
 GATEWAY_r16.json at the repo root.
 
 `--replicas 2` (ISSUE 17) switches to the router acceptance: a
@@ -35,7 +35,7 @@ legs; an open-loop loadgen sweep whose per-session records join to
 retained server-side traces with per-stage p95 attribution; and the
 SLO burn monitor staying quiet on a under-SLO baseline while firing
 exactly once on an induced breach. `--trace --smoke` shrinks it to
-one stream + one sweep point for the run_hw_window3.sh preflight.
+one stream + one sweep point for a CPU preflight.
 """
 
 from __future__ import annotations

@@ -22,10 +22,10 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 _cache = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), ".pytest_xla_cache")
-if os.path.isdir(_cache):
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR") and os.path.isdir(_cache):
     jax.config.update("jax_compilation_cache_dir", _cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 def main() -> int:

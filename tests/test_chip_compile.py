@@ -1,0 +1,341 @@
+"""The chip's compiler, asked in the sandbox (ISSUE 22).
+
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED, not attached (`topologies.get_topology_desc("tpu",
+"v5e:2x2")`): `jit(f).lower(shapes).compile()` raises whatever the v5e's
+compiler would raise — a lane slice it cannot prove aligned, a shape
+cast it has no layout for, a kernel over the fast-memory cap — so the
+main path's kernels are checked at real widths before any chip time is
+spent. That is a compile, never a run: nothing here says a result is
+right or fast (tests/test_pallas_tpu_lowering.py stops one step
+earlier, at Mosaic lowering; numeric parity lives in the interpret-mode
+suites).
+
+This is the ONLY file that describes a topology. Only one process may
+load the TPU library, and it keeps it until exit — so the description
+happens inside module-scoped fixtures that are neither autouse nor in
+conftest: every xdist worker collects the same tests, and only the
+worker that RUNS this file loads the library. Nothing here touches the
+topology at import, `skipif`, `parametrize` or conftest time, and the
+compiles run in the test's own process.
+
+The persistent compilation cache is turned off around the compiles
+(conftest turns it on): an entry written for a described chip cannot be
+read back without one, and the next run would warn on every case.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from theroundtaible_tpu.engine.pallas import attention as pattn
+from theroundtaible_tpu.engine.pallas import int4mm
+
+D, PAGE = 128, 128           # head_dim and page size of every case
+POOL_PAGES = 256             # chip_smoke.py's pool
+PAGES_PER_SEQ = 64           # max_seq_len 8192 / page 128
+ROWS = 8                     # decode batch rows / ragged sequences
+RAGGED_T = 256               # flat token buffer
+CHUNK = 256                  # prefill chunk
+
+# (H, K) per case family: Llama-3.2-3B on one chip; one model-axis
+# shard of Llama-3-8B (H=32, K=8) over four chips.
+HEADS = {"llama-3.2-3b": (24, 8), "llama-3-8b/4": (8, 2)}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler / library held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The engine's (data=1, model=4) mesh over the described devices."""
+    return Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(f, *shapes) -> str:
+    return jax.jit(f).lower(*shapes).compile().as_text()
+
+
+def _assert_kernel(hlo: str) -> None:
+    assert "tpu_custom_call" in hlo, "no Mosaic kernel in the program"
+
+
+def _pool_shapes(kh: int, kv: str, sharding):
+    """(pool, scale-or-None, kv_bits) shapes for a bf16 / int8 / int4
+    page pool with `kh` kv heads (kv_quant.py's storage contract)."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    if kv == "bf16":
+        return s((POOL_PAGES, PAGE, kh, D), jnp.bfloat16), None, 8
+    from theroundtaible_tpu.engine.kv_quant import KVQuantSpec
+    spec = KVQuantSpec(bits=8 if kv == "int8" else 4)
+    return (s((POOL_PAGES, PAGE, kh, spec.packed_dim(D)), jnp.int8),
+            s((POOL_PAGES, PAGE, kh, spec.num_groups(D)), jnp.float32),
+            spec.bits)
+
+
+def _attention_case(kernel: str, h: int, kh: int, kv: str, one_chip):
+    """(fn, shapes) for one single-device kernel at these widths; the
+    scale pools, where the pages are quantized, ride last."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    pool, scale, bits = _pool_shapes(kh, kv, one_chip)
+    scales = () if scale is None else (scale, scale)
+
+    def on_pool(call, *shapes):
+        def fn(*args):
+            n = len(shapes)
+            kw = dict(zip(("k_scale", "v_scale"), args[n:]))
+            return call(*args[:n], interpret=False, kv_bits=bits, **kw)
+        return fn, shapes + scales
+
+    if kernel == "paged_decode":
+        return on_pool(
+            pattn.paged_decode_attention,
+            s((ROWS, 1, h, D), jnp.bfloat16), pool, pool,
+            s((ROWS, PAGES_PER_SEQ), i32), s((ROWS,), i32))
+    if kernel == "ragged":
+        blocks = RAGGED_T // pattn.RAGGED_BLOCK_Q
+        return on_pool(
+            pattn.ragged_paged_attention,
+            s((RAGGED_T, h, D), jnp.bfloat16), pool, pool,
+            s((ROWS, PAGES_PER_SEQ), i32), s((blocks,), i32),
+            s((blocks,), i32), s((ROWS,), i32), s((ROWS,), i32))
+    if kernel == "paged_prefill":
+        return on_pool(
+            pattn.paged_prefill_attention,
+            s((1, CHUNK, h, D), jnp.bfloat16), pool, pool,
+            s((1, PAGES_PER_SEQ), i32), s((1,), i32), s((1,), i32))
+    assert kernel == "flash_prefill" and kv == "bf16"
+    cache = s((1, 2048, kh, D), jnp.bfloat16)
+    return (functools.partial(pattn.flash_prefill_attention,
+                              interpret=False),
+            (s((1, CHUNK, h, D), jnp.bfloat16), cache, cache,
+             s((1,), i32), s((1,), i32)))
+
+
+ATTENTION_CASES = [
+    ("paged_decode", "bf16"), ("paged_decode", "int8"),
+    ("ragged", "bf16"), ("ragged", "int8"),
+    ("paged_prefill", "bf16"), ("flash_prefill", "bf16"),
+]
+
+
+@pytest.mark.parametrize("widths", list(HEADS))
+@pytest.mark.parametrize("kernel,kv", ATTENTION_CASES)
+def test_attention_kernel_compiles_for_v5e(one_chip, kernel, kv, widths):
+    h, kh = HEADS[widths]
+    fn, shapes = _attention_case(kernel, h, kh, kv, one_chip)
+    _assert_kernel(_compile(fn, *shapes))
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "ragged"])
+def test_spmd_kernel_compiles_for_four_chips(four_chips, kernel):
+    """paged_decode_spmd / ragged_paged_spmd at Llama-3-8B widths on a
+    4-device mesh of described chips, arguments placed as the engine
+    places them: kv heads of the pool and q heads on "model", metadata
+    replicated."""
+    h, kh = 32, 8
+    mesh = four_chips
+
+    def s(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    pool = s((POOL_PAGES, PAGE, kh, D), jnp.bfloat16,
+             P(None, None, "model", None))
+    i32 = jnp.int32
+    if kernel == "paged_decode":
+        fn = functools.partial(pattn.paged_decode_spmd, mesh,
+                               interpret=False)
+        shapes = (s((ROWS, 1, h, D), jnp.bfloat16,
+                    P(None, None, "model", None)), pool, pool,
+                  s((ROWS, PAGES_PER_SEQ), i32), s((ROWS,), i32))
+    else:
+        blocks = RAGGED_T // pattn.RAGGED_BLOCK_Q
+        fn = functools.partial(pattn.ragged_paged_spmd, mesh,
+                               interpret=False)
+        shapes = (s((RAGGED_T, h, D), jnp.bfloat16,
+                    P(None, "model", None)), pool, pool,
+                  s((ROWS, PAGES_PER_SEQ), i32), s((blocks,), i32),
+                  s((blocks,), i32), s((ROWS,), i32), s((ROWS,), i32))
+    _assert_kernel(_compile(fn, *shapes))
+
+
+def test_whole_decode_step_of_the_3b_engine_compiles(one_chip,
+                                                     monkeypatch):
+    """One whole pool-direct decode step (paged_forward.forward_paged,
+    the program the engine's decode dispatch wraps) of Llama-3.2-3B at
+    published widths and depth, from jax.eval_shape shapes: 28 unrolled
+    layers, each with its scatter and its paged-decode kernel, plus the
+    128k-vocab head — and it fits one 16 GB chip beside its pool. The
+    kernels ask jax.default_backend() whether to interpret, and that
+    still says "cpu" here, so the test steers it; the program gets no
+    new option."""
+    from theroundtaible_tpu.engine.models.common import init_params
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+    from theroundtaible_tpu.engine.paged_forward import forward_paged
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_model_config("llama-3.2-3b-instruct"),
+                              attn_impl="flash")
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = placed(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    pool = s((POOL_PAGES, PAGE, cfg.num_kv_heads, D), jnp.bfloat16)
+    pools = [(pool, pool)] * cfg.num_layers
+    i32 = jnp.int32
+
+    def step(params, tokens, positions, pools, table, valid, last):
+        return forward_paged(params, cfg, tokens, positions, pools,
+                             table, valid, last_pos=last)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, s((ROWS, 1), i32), s((ROWS, 1), i32), pools,
+        s((ROWS, PAGES_PER_SEQ), i32), s((ROWS,), i32),
+        s((ROWS,), i32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= cfg.num_layers
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < 16e9, f"decode step needs {resident / 1e9:.1f} GB"
+
+
+# --- the int4 kernels the compiler refuses --------------------------------
+#
+# Shapes below come from a real Int4Leaf (quant.quantize_params on a
+# Llama-3.2-3B-wide layer) and a real int4 pool spec (KVQuantSpec). Each refusal is
+# what the plan-time gates now quote (int4mm.MOSAIC_REFUSAL,
+# attention.kv_quant_decline_reason for bits=4), so describe() carries
+# the reason from construction and no runtime rung is taken. The
+# xfail(strict=True) cases tell the repair PR when it has worked: a
+# kernel that compiles turns its case into a failure, and the gate for
+# it then goes.
+
+
+def _int4_case(spec: str):
+    """(a_shape, Int4Leaf of shapes) for one matmul of a Llama-3.2-3B-
+    wide layer, laid out by the real quantizer (quant.quantize_params
+    under eval_shape)."""
+    from theroundtaible_tpu.engine.models.common import (Int4Leaf,
+                                                         init_params)
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+    from theroundtaible_tpu.engine.quant import quantize_params
+
+    cfg = dataclasses.replace(get_model_config("llama-3.2-3b-instruct"),
+                              num_layers=1)
+    tree = jax.eval_shape(
+        lambda k: quantize_params(init_params(cfg, k, jnp.bfloat16), cfg,
+                                  bits=4), jax.random.PRNGKey(0))
+    leaf = {"bte,ef->btf": tree["layers"][0]["gate_proj"],
+            "bte,ve->btv": tree["embedding"]}[spec]
+    assert isinstance(leaf, Int4Leaf)
+    return (ROWS, 1, cfg.embed_dim), leaf
+
+
+INT4_MM_CASES = [
+    pytest.param(
+        "bte,ef->btf", "out",
+        marks=pytest.mark.xfail(strict=True, reason=(
+            "_mm_pack_out: " + int4mm.MOSAIC_REFUSAL["out"]))),
+    pytest.param(
+        "bte,ve->btv", "contract",
+        marks=pytest.mark.xfail(strict=True, reason=(
+            "_mm_pack_contract: " + int4mm.MOSAIC_REFUSAL["contract"]))),
+]
+
+
+@pytest.mark.parametrize("spec,mode", INT4_MM_CASES)
+def test_int4_matmul_kernel_compiles_for_v5e(one_chip, monkeypatch, spec,
+                                             mode):
+    a_shape, leaf = _int4_case(spec)
+    cls, _ = int4mm._classify(spec, leaf)
+    assert cls is not None and cls[0] == mode
+    # The dispatch as it would run on the chip were the gate lifted.
+    monkeypatch.setattr(int4mm, "_interpret", lambda: False)
+    monkeypatch.setattr(int4mm, "MOSAIC_REFUSAL", {})
+
+    def fn(a, leaf):
+        y, reason = int4mm.einsum_int4_or_reason(spec, a, leaf)
+        assert y is not None, reason
+        return y
+
+    placed = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        leaf)
+    _assert_kernel(_compile(
+        fn, jax.ShapeDtypeStruct(a_shape, jnp.bfloat16, sharding=one_chip),
+        placed))
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "ragged"])
+@pytest.mark.xfail(strict=True, reason=(
+    "int4 page dequant (_dequant_kv): " + pattn.MOSAIC_INT4_KV_REFUSAL))
+def test_int4_kv_kernel_compiles_for_v5e(one_chip, kernel):
+    h, kh = HEADS["llama-3.2-3b"]
+    fn, shapes = _attention_case(kernel, h, kh, "int4", one_chip)
+    _assert_kernel(_compile(fn, *shapes))
+
+
+def test_int4_gates_decline_at_plan_time_with_the_compilers_reason(
+        monkeypatch):
+    """On the chip (`_interpret()` false) the three refused kernels
+    decline when they are PLANNED, quoting the compiler — never by a
+    runtime degradation rung. In interpret mode (this suite's CPU
+    parity tests) the plans stand."""
+    assert int4mm._plan_pack_out(8, 3072, 4096, 32)[0] is not None
+    assert int4mm._plan_pack_contract(8, 1536, 128_256, 32)[0] is not None
+    assert pattn.kv_quant_decline_reason(PAGE, D, 8, 3, bits=4) is None
+    assert pattn.kv_quant_decline_reason(PAGE, D, 8, 3, bits=8) is None
+
+    monkeypatch.setattr(int4mm, "_interpret", lambda: False)
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    plan, reason = int4mm._plan_pack_out(8, 3072, 4096, 32)
+    assert plan is None and reason.startswith("mosaic:")
+    assert int4mm.MOSAIC_REFUSAL["out"] in reason
+    plan, reason = int4mm._plan_pack_contract(8, 1536, 128_256, 32)
+    assert plan is None and reason.startswith("mosaic:")
+    assert int4mm.MOSAIC_REFUSAL["contract"] in reason
+    reason = pattn.kv_quant_decline_reason(PAGE, D, 8, 3, bits=4)
+    assert reason.startswith("mosaic:")
+    assert pattn.MOSAIC_INT4_KV_REFUSAL in reason
+    # int8 pages compile (ATTENTION_CASES above) and stay on the kernels.
+    assert pattn.kv_quant_decline_reason(PAGE, D, 8, 3, bits=8) is None

@@ -209,6 +209,76 @@ class TestWarmupCommand:
         reset_engines()
 
 
+class TestGatewayBuildScheduler:
+    """`roundtable gateway`'s scheduler seam (ISSUE 22 satellite): a
+    seat whose engine cannot be built must say why — an out-of-memory
+    on the chip used to surface as a bare "no scheduler available"."""
+
+    def _config(self, project_root, engine_cfg):
+        from theroundtaible_tpu.core.config import load_config
+
+        write_config(project_root, knights=[
+            {"name": "A", "adapter": "tpu-llm", "capabilities": [],
+             "priority": 1}])
+        path = project_root / ".roundtable" / "config.json"
+        cfg = json.loads(path.read_text())
+        cfg["adapter_config"] = {"tpu-llm": engine_cfg}
+        path.write_text(json.dumps(cfg))
+        return load_config(project_root)
+
+    def test_unbuildable_seat_reports_its_reason(self, project_root):
+        from theroundtaible_tpu.commands.gateway_cmd import \
+            _build_scheduler
+        from theroundtaible_tpu.core.errors import ConfigError
+        from theroundtaible_tpu.engine import reset_engines
+
+        reset_engines()
+        config = self._config(project_root, {"model": "no-such-model"})
+        with pytest.raises(ConfigError) as err:
+            _build_scheduler(config, None)
+        assert "no scheduler available" in str(err.value)
+        assert "no-such-model" in str(err.value)
+        reset_engines()
+
+    def test_scheduler_failure_is_chained(self, project_root,
+                                          monkeypatch):
+        from theroundtaible_tpu.commands.gateway_cmd import \
+            _build_scheduler
+        from theroundtaible_tpu.core.errors import ConfigError
+        from theroundtaible_tpu.engine import reset_engines
+        from theroundtaible_tpu.engine import scheduler as sched_mod
+
+        def boom(engine, **opts):
+            raise MemoryError("RESOURCE_EXHAUSTED: out of device memory")
+
+        reset_engines()
+        monkeypatch.setattr(sched_mod, "acquire_scheduler", boom)
+        config = self._config(project_root, {
+            "model": "tiny-gemma", "max_seq_len": 256, "num_slots": 2})
+        with pytest.raises(ConfigError) as err:
+            _build_scheduler(config, None)
+        assert isinstance(err.value.__cause__, MemoryError)
+        assert "RESOURCE_EXHAUSTED" in str(err.value)
+        reset_engines()
+
+    def test_buildable_seat_is_unchanged(self, project_root):
+        from theroundtaible_tpu.commands.gateway_cmd import \
+            _build_scheduler
+        from theroundtaible_tpu.engine import reset_engines
+
+        reset_engines()
+        config = self._config(project_root, {
+            "model": "tiny-gemma", "max_seq_len": 256, "num_slots": 2,
+            "kv_layout": "paged"})
+        sched = _build_scheduler(config, None)
+        try:
+            assert sched.engine.cfg.name == "tiny-gemma"
+            assert sched.journal is None
+        finally:
+            sched.close()
+            reset_engines()
+
+
 class TestAtomicWrites:
     def test_atomic_write_replaces_and_cleans_up(self, tmp_path):
         from theroundtaible_tpu.utils.session import atomic_write_text

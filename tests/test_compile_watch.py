@@ -39,11 +39,27 @@ def _installed(tmp_path, monkeypatch):
     compile_watch.reset_steady_state()
 
 
+def test_failed_registration_is_an_error(monkeypatch):
+    """No silent "off": the installed JAX has jax.monitoring, so a
+    registration that fails raises (ISSUE 22)."""
+    import jax.monitoring as monitoring
+
+    def refuse(_listener):
+        raise RuntimeError("no listeners here")
+
+    monkeypatch.setattr(compile_watch, "_installed_mode", None)
+    monkeypatch.setattr(
+        monitoring, "register_event_duration_secs_listener", refuse)
+    with pytest.raises(RuntimeError, match="no listeners"):
+        compile_watch.install()
+    assert compile_watch._installed_mode is None
+
+
 @pytest.mark.perf_obs
 class TestObservatory:
     def test_install_idempotent_and_mode(self):
         mode = compile_watch.install()
-        assert mode in ("monitoring", "lower-seam")
+        assert mode == "monitoring"
         # Second install must not double-register listeners: two
         # installs then one compile must count each event once.
         assert compile_watch.install() == mode
@@ -55,6 +71,27 @@ class TestObservatory:
         force_compile()
         # Same op pattern: a double-registered listener would see ~2x.
         assert compile_watch.compiles_seen() - c1 <= delta + 1
+
+    def test_cache_hit_counts_once(self):
+        """jax 0.9.0 times the whole compile-or-get-cached call as
+        backend_compile_duration, so a persistent-cache hit fires the
+        retrieval event AND, enclosing it, the compile event: one
+        compile, recorded as a hit (measured on the v5e, PR 22: 30 hits
+        had counted as 60 compiles)."""
+        c0 = compile_watch.compiles_seen()
+        with compile_watch.label("unit[hit]"):
+            compile_watch._on_duration(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.1)
+            compile_watch._on_duration(
+                "/jax/core/compile/backend_compile_duration", 0.2)
+        assert compile_watch.compiles_seen() - c0 == 1
+        mine = [e for e in compile_watch.history()
+                if e["label"] == "unit[hit]"]
+        assert [e["cache_hit"] for e in mine[-1:]] == [True]
+        # The mark is spent: a fresh compile right after still counts.
+        compile_watch._on_duration(
+            "/jax/core/compile/backend_compile_duration", 0.3)
+        assert compile_watch.compiles_seen() - c0 == 2
 
     def test_label_attribution_and_registry(self):
         c0 = telemetry.REGISTRY.counter_total(
@@ -195,6 +232,48 @@ class TestCompilationCacheDecision:
             lambda: (_ for _ in ()).throw(AssertionError("re-probed")))
         assert engine_pkg.enable_compilation_cache() is None
 
+    @pytest.mark.parametrize("env_dir", [True, False],
+                             ids=["env-set", "env-unset"])
+    def test_directory_placed_from_outside(self, monkeypatch, tmp_path,
+                                           env_dir):
+        """On an accelerator backend: JAX_COMPILATION_CACHE_DIR set →
+        the function sets NO directory (JAX already reads the variable);
+        unset → the fixed <checkout>/.xla_cache."""
+        from theroundtaible_tpu import engine as engine_pkg
+
+        monkeypatch.setattr(engine_pkg, "_compile_cache_decision", None)
+        monkeypatch.setattr(engine_pkg, "_CHECKOUT", str(tmp_path))
+        # The process's real (CPU) decision keeps its gauge.
+        monkeypatch.setattr(engine_pkg, "_record_cache_decision",
+                            lambda: None)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        updates = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.__setitem__(k, v))
+        outside = str(tmp_path / "outside")
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        got = engine_pkg.enable_compilation_cache()
+        if env_dir:
+            assert got == outside
+            assert "jax_compilation_cache_dir" not in updates
+        else:
+            assert got == str(tmp_path / ".xla_cache")
+            assert updates["jax_compilation_cache_dir"] == got
+            assert os.path.isdir(got)
+        assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+        assert updates["jax_persistent_cache_min_entry_size_bytes"] == 0
+        assert engine_pkg.get_compile_cache_decision() == {
+            "enabled": True, "backend": "tpu", "dir": got}
+
+    def test_fixed_path_is_the_checkout(self):
+        from theroundtaible_tpu import engine as engine_pkg
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert engine_pkg._CHECKOUT == repo
+
     def test_decision_lands_in_describe(self):
         from theroundtaible_tpu.engine.engine import InferenceEngine
         from theroundtaible_tpu.engine.models.registry import \
@@ -205,6 +284,5 @@ class TestCompilationCacheDecision:
                               num_slots=2)
         info = eng.describe()
         assert info["compile_cache"]["backend"] == "cpu"
-        assert info["compile_observatory"]["mode"] in ("monitoring",
-                                                       "lower-seam")
+        assert info["compile_observatory"]["mode"] == "monitoring"
         assert info["perf"]["param_bytes"] > 0

@@ -49,7 +49,8 @@ class TestModelCore:
         """forward(last_pos=p) must equal slicing full logits at p —
         the prefill paths pass last_pos so the lm head only ever sees
         one row per batch element (a batched full-sequence [B,T,V] f32
-        logits temp OOM'd the discuss bench on hardware, BENCH_r05);
+        logits temp OOM'd the discuss bench on hardware — measured once
+        before PR 1; not re-measured);
         this pins the gather-before-head refactor to the old semantics,
         including ragged per-row positions."""
         cfg = get_model_config("tiny-llama")
@@ -586,6 +587,47 @@ class TestSharding:
         out_single = single.generate("sharded hello", slot_name="tp",
                                      max_new_tokens=6)
         assert out == out_single  # TP must not change results (greedy)
+
+
+    def test_born_sharded_init_matches_eager_init(self):
+        """Random init under jit with out_shardings (ISSUE 22): every
+        leaf lands with its NamedSharding, re-placing the tree is a
+        no-op, and greedy tokens equal the old eager init + shard_params
+        build for the same seed."""
+        from jax.sharding import NamedSharding
+
+        from theroundtaible_tpu.engine.sharding import param_shardings
+
+        def build():
+            return InferenceEngine(
+                get_model_config("tiny-llama"), num_slots=2, seed=3,
+                mesh_shape={"model": 2},
+                sampling=SamplingParams(temperature=0.0,
+                                        max_new_tokens=8))
+
+        engine = build()
+        want = param_shardings(engine.cfg, engine.mesh, engine.params)
+        for leaf, sharding in zip(jax.tree_util.tree_leaves(engine.params),
+                                  jax.tree_util.tree_leaves(want)):
+            assert isinstance(leaf.sharding, NamedSharding)
+            assert leaf.sharding.is_equivalent_to(sharding, leaf.ndim)
+        q = engine.params["layers"][0]["q_proj"]
+        assert not q.sharding.is_fully_replicated
+        assert {s.data.shape for s in q.addressable_shards} == {
+            (q.shape[0], q.shape[1] // 2, q.shape[2])}
+        again = shard_params(engine.params, engine.cfg, engine.mesh)
+        for a, b in zip(jax.tree_util.tree_leaves(again),
+                        jax.tree_util.tree_leaves(engine.params)):
+            assert a is b
+        out = engine.generate("born sharded", slot_name="s",
+                              max_new_tokens=8)
+
+        eager = build()
+        eager.params = shard_params(
+            init_params(eager.cfg, jax.random.PRNGKey(3), eager.dtype),
+            eager.cfg, eager.mesh)
+        assert eager.generate("born sharded", slot_name="s",
+                              max_new_tokens=8) == out
 
 
 class TestTokenizer:

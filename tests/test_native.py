@@ -138,3 +138,39 @@ class TestSafetensorsReader:
         p = tmp_path / "trunc.safetensors"
         p.write_bytes(b"\x04")  # shorter than the 8-byte header length
         assert native_can_read(p) is False
+
+
+@needs_native
+@pytest.mark.parametrize("source_newer,origin", [(True, "built"),
+                                                 (False, "loaded")])
+def test_stale_library_is_rebuilt_from_source(tmp_path, monkeypatch,
+                                              source_newer, origin):
+    """The .so is git-ignored, so a working tree may carry a build of an
+    earlier rt_native.cc: a source newer than the library rebuilds it
+    (ISSUE 22), a fresh library just loads — and native_origin() says
+    which happened."""
+    import os
+    import shutil
+
+    from theroundtaible_tpu.native import loader
+
+    so = tmp_path / "librt_native.so"
+    shutil.copy(loader._SO_PATH, so)
+    so_mtime = so.stat().st_mtime
+    src = tmp_path / "rt_native.cc"
+    shutil.copy(loader._SRC_PATH, src)
+    delta = 60 if source_newer else -60
+    os.utime(src, (so_mtime + delta, so_mtime + delta))
+    monkeypatch.setattr(loader, "_SO_PATH", so)
+    monkeypatch.setattr(loader, "_SRC_PATH", src)
+    monkeypatch.setattr(loader, "_lib", None)
+    monkeypatch.setattr(loader, "_lib_tried", False)
+    monkeypatch.setattr(loader, "_lib_origin", None)
+
+    assert loader._stale() is source_newer
+    # The latency-sensitive path never builds: stale → nothing, unlatched.
+    assert (loader._get_lib(build=False) is None) is source_newer
+    assert loader.native_available()
+    assert loader.native_origin() == origin
+    assert (so.stat().st_mtime > so_mtime) is source_newer
+    assert loader.lcp([1, 2, 3, 4], [1, 2, 9]) == 2

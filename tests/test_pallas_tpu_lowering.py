@@ -126,6 +126,10 @@ def test_int4_spmd_lowers_on_data_model_mesh(spec, tp, ashape, wshape,
     from theroundtaible_tpu.engine.quant import _quantize_leaf_int4
 
     monkeypatch.setattr(int4mm, "_interpret", lambda: False)
+    # Lowering is one step short of the compile the v5e's compiler
+    # refuses (int4mm.MOSAIC_REFUSAL, tests/test_chip_compile.py): lift
+    # the plan-time gate so this keeps guarding what the repair needs.
+    monkeypatch.setattr(int4mm, "MOSAIC_REFUSAL", {})
     mesh = _mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.standard_normal(wshape).astype(np.float32) * 0.02,

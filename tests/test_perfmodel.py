@@ -254,7 +254,7 @@ class TestLiveGauges:
         snap = perfmodel.attribution_snapshot()
         assert any(k.startswith("roundtable_kv_")
                    for k in snap["series"])
-        assert snap["compiles"]["mode"] in ("monitoring", "lower-seam")
+        assert snap["compiles"]["mode"] == "monitoring"
 
 
 @pytest.mark.perf_obs(allow_quiet=True)

@@ -8,21 +8,12 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
-from theroundtaible_tpu.engine import compat as _compat
 from theroundtaible_tpu.engine.engine import InferenceEngine
 from theroundtaible_tpu.engine.models.registry import get_model_config
 from theroundtaible_tpu.engine.pp_serving import PPEngine
 from theroundtaible_tpu.engine.sampling import SamplingParams
 
 
-
-# TP inside stages (a pipe+model mesh) lowers partial-manual stage bodies,
-# which the legacy jax.experimental.shard_map cannot express (axis_index
-# becomes a PartitionId the old SPMD partitioner refuses) — the engine
-# refuses the config at build time there (pp_serving.py).
-requires_native_shard_map = pytest.mark.skipif(
-    not _compat.HAS_NATIVE_SHARD_MAP,
-    reason="TP-in-stage needs the modern jax.shard_map API")
 
 # Cross-engine comparisons run in f32: PP's program structure (stacked
 # scan, psum gathers) legitimately reorders bf16 summations, and random
@@ -210,7 +201,6 @@ class TestPPConfigValidation:
         eng = PPEngine.from_config(self._cfg(attn="flash"))
         assert eng.cfg.attn_impl == "flash"
 
-    @requires_native_shard_map
     def test_flash_attn_honored_with_tp_in_stage(self):
         """Divisible heads (tiny-llama H4/K2 over model 2): explicit
         flash runs via the nested-shard_map spmd wrappers."""
@@ -218,7 +208,6 @@ class TestPPConfigValidation:
             self._cfg(mesh={"pipe": 2, "model": 2}, attn="flash"))
         assert eng.cfg.attn_impl == "flash"
 
-    @requires_native_shard_map
     def test_flash_attn_raises_on_nonpartitionable_heads(self):
         """tiny-llama K=2 kv heads cannot split 4 ways (and K!=1, so no
         MQA replication either) — explicit flash must refuse, exactly as
@@ -227,7 +216,6 @@ class TestPPConfigValidation:
             PPEngine.from_config(
                 self._cfg(mesh={"pipe": 2, "model": 4}, attn="flash"))
 
-    @requires_native_shard_map
     def test_auto_attn_resolves_dense_on_cpu(self):
         # auto mirrors the main engine: kernels only on TPU backends
         eng = PPEngine.from_config(
@@ -235,7 +223,6 @@ class TestPPConfigValidation:
         assert eng.cfg.attn_impl == "dense"
 
 
-@requires_native_shard_map
 class TestPPTensorParallel:
     """mesh={"pipe": N, "model": M} — TP inside each pipeline stage
     (SURVEY §2.3's (pipeline, tensor, data) split; VERDICT r3 missing
@@ -377,7 +364,6 @@ class TestPPFlashAndPoolDirect:
                 == self._ref().generate_batch(self.PROMPTS,
                                               max_new_tokens=12))
 
-    @requires_native_shard_map
     def test_tp_in_stage_paged_is_pool_direct_and_matches(self):
         """Partitionable heads: pool-direct survives TP-in-stage via the
         paged spmd wrappers (nested shard_map over "model")."""
@@ -391,7 +377,6 @@ class TestPPFlashAndPoolDirect:
                 == self._ref().generate_batch(self.PROMPTS,
                                               max_new_tokens=12))
 
-    @requires_native_shard_map
     def test_tp_in_stage_flash_matches_reference(self):
         """Explicit flash under pipe 2 x model 2: attention runs through
         the spmd wrappers as a nested shard_map inside the manual-pipe
@@ -407,7 +392,6 @@ class TestPPFlashAndPoolDirect:
                                               max_new_tokens=12))
         assert pp.last_stats.decode_tokens > 0
 
-    @requires_native_shard_map
     def test_tp_in_stage_full_matrix_matches_reference(self):
         """flash + int8 + paged pool-direct + pipe 2 x model 2 — the
         complete composition in one engine."""

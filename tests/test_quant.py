@@ -310,12 +310,6 @@ class TestInt4:
         stage, placed via quantized_specs' metadata-mirroring spec tree,
         TP inside stages): token parity with the main engine's int4 on
         the same seed, contiguous AND paged."""
-        from theroundtaible_tpu.engine import compat
-        if not compat.HAS_NATIVE_SHARD_MAP:
-            # TP-in-stage needs the modern jax.shard_map API — the PP
-            # engine refuses the config at build (see test_pp_serving's
-            # requires_native_shard_map).
-            pytest.skip("TP-in-stage needs the modern jax.shard_map API")
         from theroundtaible_tpu.engine.pp_serving import PPEngine
         cfg = get_model_config("tiny-llama", max_seq_len=128)
         sp = SamplingParams(temperature=0.0, max_new_tokens=8)

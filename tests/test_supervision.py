@@ -697,9 +697,9 @@ os.environ.setdefault("ROUNDTABLE_DISABLE_TPU_DETECT", "1")
 import jax
 jax.config.update("jax_platforms", "cpu")
 cache = {os.path.join(repo, ".pytest_xla_cache")!r}
-if os.path.isdir(cache):
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR") and os.path.isdir(cache):
     jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 from theroundtaible_tpu.engine.engine import InferenceEngine
 from theroundtaible_tpu.engine.scheduler import SessionScheduler
 from theroundtaible_tpu.engine.session_journal import SessionJournal

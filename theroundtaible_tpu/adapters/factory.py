@@ -111,8 +111,13 @@ def initialize_adapters(
                              f"seated via {api_id}")
                 continue
         if on_event:
+            # The reason rides along where the adapter knows it (a
+            # tpu-llm seat whose engine failed to build: bad model
+            # name, out of device memory).
+            why = getattr(adapter, "unavailable_reason", lambda: None)()
             on_event("unavailable",
-                     f"{knight.name} ({adapter_id}) is unavailable")
+                     f"{knight.name} ({adapter_id}) is unavailable"
+                     + (f": {why}" if why else ""))
     return adapters
 
 

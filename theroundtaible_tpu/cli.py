@@ -236,7 +236,7 @@ def build_parser():
     li = sub.add_parser(
         "lint",
         help="Static serving-invariant analyzer: AST rules + "
-             "device-free jaxpr audit (CI / tunnel preflight)")
+             "device-free jaxpr audit (CI / pre-chip check)")
     li.add_argument("--rules", default=None, metavar="ID,ID",
                     help="Comma-separated rule ids to run "
                          "(default: all)")

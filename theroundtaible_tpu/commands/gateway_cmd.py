@@ -21,8 +21,17 @@ def _build_scheduler(config, journal_dir: Optional[str]):
     from ..adapters.factory import initialize_adapters
     from ..engine.scheduler import acquire_scheduler
 
-    adapters = initialize_adapters(config)
+    # A seat whose engine cannot be built is dropped at seating, with
+    # its reason in the notice — keep the notices for the error below.
+    unseated: list[str] = []
+
+    def note(kind: str, message: str) -> None:
+        if kind == "unavailable":
+            unseated.append(message)
+
+    adapters = initialize_adapters(config, note)
     sched = None
+    last_error: Optional[Exception] = None
     for adapter in adapters.values():
         if not hasattr(adapter, "attach_scheduler"):
             continue
@@ -30,12 +39,19 @@ def _build_scheduler(config, journal_dir: Optional[str]):
             engine = adapter._get_engine()
             sched, _created = acquire_scheduler(engine)
             break
-        except Exception:  # noqa: BLE001 — try the next seat
+        except Exception as e:  # noqa: BLE001 — try the next seat
+            last_error = e
             continue
     if sched is None:
+        # The reasons ride along: an out-of-memory on the chip must not
+        # read as "no scheduler available".
+        if last_error is not None:
+            unseated.append(f"{type(last_error).__name__}: {last_error}")
         raise ConfigError(
             "gateway needs at least one tpu-llm knight whose engine "
-            "can be built — no scheduler available to serve")
+            "can be built — no scheduler available to serve"
+            + (f" ({'; '.join(unseated)})" if unseated else "")
+        ) from last_error
     if journal_dir is not None and sched.journal is None:
         from ..engine.session_journal import SessionJournal
         sched.attach_journal(SessionJournal(journal_dir))

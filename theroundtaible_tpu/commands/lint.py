@@ -6,8 +6,8 @@ registered serving program on two toy CPU engines (contiguous, and
 paged + ragged + spec-tree + LoRA — together they register every
 program family: prefill, decode, ragged, spec-verify, propose,
 LoRA-setter). Exit code 1 on any unallowlisted finding — the CI /
-tunnel-preflight contract: a statically detectable violation must
-never cost a hardware window.
+pre-chip contract: a statically detectable violation must never cost
+chip time.
 """
 
 from __future__ import annotations

@@ -102,7 +102,7 @@ _AUTH_MARKERS = (
 _API_MARKERS = ("429", "500", "502", "503", "529", "overloaded",
                 "rate limit", "econnrefused", "fetch failed", "bad gateway")
 # TPU-engine-specific kinds (no reference counterpart; SURVEY.md §5.3 calls for
-# HBM OOM classification mapped onto the taxonomy).
+# HBM OOM classification mapped onto these kinds).
 _OOM_MARKERS = ("resource_exhausted", "out of memory", "hbm", "oom",
                 "allocation failure")
 # Watchdog hang detection (engine/deadlines.py): a wait that exceeded

@@ -25,6 +25,9 @@ _lock = threading.Lock()
 # every call — and recorded once into the telemetry registry and
 # engine.describe() so an operator can see which it was after the fact.
 _compile_cache_decision: dict[str, Any] | None = None
+# The directory that holds the package: the fixed home of `.xla_cache`.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def enable_compilation_cache():
@@ -32,14 +35,15 @@ def enable_compilation_cache():
 
     Every engine process otherwise pays a full XLA compile per
     (batch, bucket) program — minutes of cold-start on a real chip
-    (SURVEY.md §7.3 hard part 5). The cache dir is stable across runs so
-    `discuss` cold-start after the first ever run is dominated by
-    deserialization, not compilation. Override with ROUNDTABLE_XLA_CACHE.
+    (SURVEY.md §7.3 hard part 5). The directory is placed from outside:
+    where JAX_COMPILATION_CACHE_DIR is set JAX already uses it and this
+    function sets NO directory; where it is not, the cache lives at the
+    fixed path `<checkout>/.xla_cache` (the path is part of the cache
+    key, so a directory that moves never hits).
 
     CPU backends are a no-op: tiny-shape CPU compiles are seconds, and
     XLA:CPU AOT cache entries embed host machine features — reloading one
     compiled under different flags/machines warns "could lead to SIGILL".
-    The dir is namespaced by backend so mixed-platform runs can't collide.
 
     Returns the cache dir when enabled, None for the no-op — and either
     way decides exactly ONCE per process (get_compile_cache_decision()
@@ -55,14 +59,11 @@ def enable_compilation_cache():
             "reason": "cpu no-op (AOT entries embed host features)"}
         _record_cache_decision()
         return None
-    cache_dir = os.path.join(
-        os.environ.get(
-            "ROUNDTABLE_XLA_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache",
-                         "theroundtaible_tpu", "xla-cache")),
-        backend)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(_CHECKOUT, ".xla_cache")
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # Cache even fast compiles: serving has many small bucket programs and
     # the default 1s threshold would skip exactly the ones that add up.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -1,110 +1,37 @@
-"""JAX version compatibility — the shard_map API seam.
+"""The shard_map API seam.
 
-The engine is written against the modern manual-axes API (`jax.shard_map`
-with `axis_names=`/`check_vma=`, `jax.lax.pcast`, abstract-mesh contexts).
-Older runtimes (jax 0.4.x) ship the same machinery as
-`jax.experimental.shard_map` with the inverse `auto=` parameter, no vma
-tracking and no abstract meshes. This module is the ONE place that
-difference lives: every engine module imports `shard_map` (and friends)
-from here instead of from jax, so a version bump in either direction is a
-compat-module change, not a nine-module sweep.
-
-Translation rules for the experimental fallback:
-- `axis_names={manual...}` → `auto = mesh.axis_names - manual` (the old
-  parameter names the axes NOT manualized);
-- `check_vma` → `check_rep`, defaulting to False (the old rep checker
-  predates pcast-style varying annotations and false-positives on them);
-- `pcast(..., to="varying")` → identity (no vma tracking to convince);
-- partial-manual regions (TP inside PP stages) are REFUSED at build on
-  old jax (pp_serving raises with the fix), so `mesh_manual_axes` only
-  needs the axis_types read on modern meshes and "manualize everything"
-  on old ones.
+The engine is written against the manual-axes API of the installed JAX
+(0.9.0: `jax.shard_map` with `axis_names=`/`check_vma=`, `jax.lax.pcast`,
+meshes that carry `axis_types`, an int8 → int4 bitcast that expands
+minor-most). Every engine module imports these names from here instead
+of from jax, so a version bump is a change to this module, not a
+nine-module sweep. It holds no branch for a JAX that is not installed.
 """
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 
-_native_shard_map = getattr(jax, "shard_map", None)
-HAS_NATIVE_SHARD_MAP = _native_shard_map is not None
-
-if _native_shard_map is not None:
-    shard_map = _native_shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _experimental
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma: Optional[bool] = None):
-        kwargs = {"check_rep": bool(check_vma) if check_vma is not None
-                  else False}
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kwargs["auto"] = auto
-        return _experimental(f, mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
+shard_map = jax.shard_map
 
 
 def pcast(x, axis_names, to: str = "varying"):
-    """`jax.lax.pcast` where it exists; identity elsewhere (pre-vma
-    runtimes don't track varying-ness, so there is nothing to cast)."""
-    fn = getattr(jax.lax, "pcast", None)
-    if fn is None:
-        return x
-    return fn(x, axis_names, to=to)
-
-
-def _int4_bitcast_expands() -> bool:
-    """Feature-detect `lax.bitcast_convert_type(int8 → int4)`: modern
-    jax appends a minor dim of 2 (one nibble pair per byte); jax 0.4.x
-    abstract-evals it at the SAME rank and then fails MLIR verification
-    at lowering ("rank of smaller element type should be 1 more"). The
-    probe is abstract-only (eval_shape) — no compile, no device."""
-    try:
-        import jax.numpy as jnp
-        out = jax.eval_shape(
-            lambda x: jax.lax.bitcast_convert_type(x, jnp.int4),
-            jax.ShapeDtypeStruct((2,), jnp.int8))
-        return out.shape == (2, 2)
-    except Exception:  # noqa: BLE001 — any probe failure ⇒ fallback
-        return False
-
-
-HAS_INT4_BITCAST = _int4_bitcast_expands()
+    return jax.lax.pcast(x, axis_names, to=to)
 
 
 def unpack_int4_pairs(q4):
-    """int8[..., n] → signed nibble pairs int4/int8[..., n, 2], low
-    nibble first (the engine/quant.py pack order).
-
-    Modern jax: the one-op bitcast whose nibble pair expands minor-most
-    — the layout Mosaic fuses into the consuming matmul operand on TPU
-    (models/common.dequant_int4's performance contract). Old jax
-    (0.4.x, broken int4 bitcast — see _int4_bitcast_expands): arithmetic
-    shift extraction + a minor-axis stack. The stack is an interleave
-    XLA:TPU would NOT fuse (the exact layout BENCH_r05 measured slower
-    than bf16), but the fallback only ever runs on runtimes where the
-    bitcast cannot lower AT ALL — correctness-gated, and numerically
-    identical: `(q << 4) >> 4` sign-extends the low nibble, `q >> 4`
-    the high one (arithmetic shifts on int8)."""
+    """int8[..., n] → signed nibble pairs int4[..., n, 2], low nibble
+    first (the engine/quant.py pack order): the one-op bitcast whose
+    nibble pair expands minor-most — the layout XLA fuses into the
+    consuming matmul operand (models/common.dequant_int4's performance
+    contract)."""
     import jax.numpy as jnp
-    if HAS_INT4_BITCAST:
-        return jax.lax.bitcast_convert_type(q4, jnp.int4)
-    low = jnp.right_shift(jnp.left_shift(q4, 4), 4)
-    high = jnp.right_shift(q4, 4)
-    return jnp.stack([low, high], axis=-1)
+    return jax.lax.bitcast_convert_type(q4, jnp.int4)
 
 
 def mesh_manual_axes(mesh) -> set:
     """The axes a wrapper's shard_map must manualize: the mesh's AUTO
-    axes. Modern meshes carry axis_types; old ones report every axis —
-    correct there, because partial-manual regions (the only case where
-    an axis would already be Manual) are refused at build on old jax."""
-    types = getattr(mesh, "axis_types", None)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if types is not None and axis_type is not None:
-        return {a for a, t in zip(mesh.axis_names, types)
-                if t == axis_type.Auto}
-    return set(mesh.axis_names)
+    axes (inside the PP engine's manual-"pipe" stage bodies, "model" is
+    the only one left)."""
+    return {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto}

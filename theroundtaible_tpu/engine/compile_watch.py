@@ -7,9 +7,8 @@ inside a bucket compiles nothing mid-serve) had zero runtime detection:
 a recompile regression would show up only as mysterious tail latency.
 This module hooks JAX compilation via `jax.monitoring` events (the
 supported seam — fires for both fresh backend compiles and persistent-
-cache retrievals, which ALSO stall the serving loop), falling back to
-wrapping the lower/compile seam on jax builds without monitoring
-listeners, and records every compile into the PR-5 telemetry spine:
+cache retrievals, which ALSO stall the serving loop) and records every
+compile into the PR-5 telemetry spine:
 
 - registry counters `roundtable_compiles_total{label=...}` /
   `roundtable_compile_seconds_total` /
@@ -49,10 +48,13 @@ from ..utils import telemetry
 STRICT_ENV = "ROUNDTABLE_RECOMPILE_STRICT"
 _HISTORY_CAP = 256
 
-# Monitoring event names observed (jax 0.4.x): a fresh compile fires
-# backend_compile_duration; a persistent-cache hit skips it and fires
-# cache_retrieval_time_sec instead — BOTH are mid-serve compilation
-# work from the serving loop's point of view, so both count.
+# Monitoring event names (jax 0.9.0): backend_compile_duration times the
+# whole compile-or-get-cached call, so it fires for a fresh compile AND
+# for a persistent-cache hit — the hit fires cache_retrieval_time_sec
+# first, from inside it, on the same thread. BOTH kinds are mid-serve
+# compilation work from the serving loop's point of view, so both
+# count, once each: a retrieval marks its thread, and the enclosing
+# backend_compile_duration that follows is then not counted again.
 _COMPILE_EVENT = "backend_compile_duration"
 _RETRIEVAL_EVENT = "cache_retrieval_time_sec"
 _CACHE_HIT_EVENT = "cache_hits"
@@ -121,64 +123,32 @@ def current_label() -> tuple[str, dict]:
 
 
 def install() -> str:
-    """Register the compile hooks (idempotent; returns the mode:
-    "monitoring" | "lower-seam" | "off"). Called from both engines'
-    constructors so any serving process observes its compiles."""
+    """Register the compile hooks (idempotent; returns the mode,
+    "monitoring"). Called from both engines' constructors so any
+    serving process observes its compiles. A registration that fails is
+    an error: an observatory silently off would let every
+    no-recompile guarantee go unwatched."""
     global _installed_mode
     with _state_lock:
         if _installed_mode is not None:
             return _installed_mode
-        mode = "off"
-        try:
-            import jax.monitoring as monitoring
-            monitoring.register_event_duration_secs_listener(
-                _on_duration)
-            mode = "monitoring"
-        except Exception:  # noqa: BLE001 — fall back to the lower seam
-            mode = _install_lower_seam()
-        if mode == "monitoring":
-            # Separate try: losing the plain-event listener only costs
-            # the cache-hit/miss counters — falling through to the
-            # lower seam HERE would double-count every compile (the
-            # duration listener above is already registered).
-            try:
-                monitoring.register_event_listener(_on_event)
-            except Exception:  # noqa: BLE001
-                pass
-        _installed_mode = mode
-    telemetry.set_gauge("roundtable_compile_observatory",
-                        0.0 if mode == "off" else 1.0)
-    return mode
-
-
-def _install_lower_seam() -> str:
-    """Fallback for jax builds without monitoring listeners: time the
-    internal lower→compile seam. Best-effort — a jax refactor leaves
-    the observatory off, never broken."""
-    try:
-        from jax._src.interpreters import pxla
-        orig = pxla.MeshComputation.compile
-        if getattr(orig, "_rt_compile_watch", False):
-            return "lower-seam"
-
-        def wrapped(self, *a, **k):
-            t0 = time.monotonic()
-            out = orig(self, *a, **k)
-            _record_compile(time.monotonic() - t0, cache_hit=False)
-            return out
-
-        wrapped._rt_compile_watch = True
-        pxla.MeshComputation.compile = wrapped
-        return "lower-seam"
-    except Exception:  # noqa: BLE001 — observatory off, nothing broken
-        return "off"
+        import jax.monitoring as monitoring
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
+        _installed_mode = "monitoring"
+    telemetry.set_gauge("roundtable_compile_observatory", 1.0)
+    return _installed_mode
 
 
 def _on_duration(event: str, duration: float, **_kw) -> None:
-    if event.endswith(_COMPILE_EVENT):
-        _record_compile(duration, cache_hit=False)
-    elif event.endswith(_RETRIEVAL_EVENT):
+    if event.endswith(_RETRIEVAL_EVENT):
+        _tls.retrieved = True
         _record_compile(duration, cache_hit=True)
+    elif event.endswith(_COMPILE_EVENT):
+        if getattr(_tls, "retrieved", False):
+            _tls.retrieved = False   # the hit just counted, enclosed
+            return
+        _record_compile(duration, cache_hit=False)
 
 
 def _on_event(event: str, **_kw) -> None:

@@ -206,8 +206,9 @@ class Int4Leaf:
     4-bit GGUF). An earlier revision packed along the einsum-contracted
     axis and unpacked with a stack+reshape interleave; on real TPU that
     shuffle broke operand fusion and decode measured SLOWER than bf16
-    (BENCH_r05: 22.9 tok/s vs bf16's 130) — the last-axis/bitcast layout
-    exists to keep the unpack inside the matmul fusion.
+    (22.9 tok/s vs bf16's 130 — measured once before PR 1; not
+    re-measured) — the last-axis/bitcast layout exists to keep the
+    unpack inside the matmul fusion.
 
     `axis` is always q4.ndim-1 at pack time and is kept as metadata so
     spec mirroring (quantized_specs) and PP stage-stacking round-trip
@@ -284,8 +285,9 @@ def _einsum_base(spec: str, a: jax.Array, b, tp=None) -> jax.Array:
     if isinstance(b, Int4Leaf):
         # Fused VMEM-dequant kernels — the only layout that actually
         # streams packed int4 bytes on real TPU (pallas/int4mm.py; XLA
-        # materializes this dequant, BENCH_r05). Gate: the kernel is
-        # emitted ONLY where the enclosing program explicitly announced
+        # materializes this dequant — measured once before PR 1; not
+        # re-measured). Gate: the kernel is emitted ONLY where the
+        # enclosing program explicitly announced
         # its mesh (spmd_mesh — every engine jit does). A 1-device mesh
         # (or a fully-manual region announcing LOCAL_MESH) dispatches
         # the raw kernel; a multi-device mesh goes through
@@ -561,8 +563,9 @@ def forward(
     gathered at last_pos BEFORE the lm-head matmul, so prefill never
     materializes full-sequence logits. On a 256k-vocab model a batched
     [B,T,V] f32 logits temp is gigabytes (B=3, T=2048 ≈ 6.3 GB — it
-    OOM'd the 3-knight discuss bench on a v5e chip, BENCH_r05) and XLA
-    cannot push the caller's post-hoc dynamic slice back through the
+    OOM'd the 3-knight discuss bench on a v5e chip; measured once
+    before PR 1; not re-measured) and XLA cannot push the caller's
+    post-hoc dynamic slice back through the
     einsum; callers that only need the last valid row must pass
     last_pos instead of slicing the result."""
     # Activations follow the param dtype: bf16 params (serving) keep the

@@ -49,9 +49,11 @@ def _dequant_kv(x, s, kv_bits: int, dtype):
     """In-kernel dequant of one KV block (ISSUE 11): payload [bkv, Dp]
     int8 + per-cell scales [bkv, G] f32 -> values [bkv, D] in `dtype`.
     int4 payloads unpack through kv_quant.unpack_int4 (the ONE copy of
-    the nibble-order contract — shift arithmetic only, which Mosaic
-    lowers; probed chipless); the grouped scale multiply is a
-    minor-axis reshape, also Mosaic-legal. This is the kernel-side
+    the nibble-order contract — shift arithmetic, which Mosaic lowers
+    but whose interleaving reshape the v5e compiler then refuses:
+    MOSAIC_INT4_KV_REFUSAL below, so int4 pools decline at plan time on
+    the chip); the grouped scale multiply is a minor-axis reshape,
+    which the int8 path compiles. This is the kernel-side
     twin of kv_quant.dequantize_cells — same unpack, same scale
     math, so the kernel and XLA fallback cannot drift."""
     if kv_bits == 4:
@@ -1058,6 +1060,20 @@ def ragged_supported(page_size: int, d: int, kh: int = 1,
     return ragged_decline_reason(page_size, d, kh, group) is None
 
 
+# What the v5e's compiler (Mosaic, JAX 0.9.0) answers the int4 page
+# dequant (_dequant_kv → kv_quant.unpack_int4) inside the paged decode,
+# paged prefill and ragged kernels — reproduced without a chip by the
+# xfail(strict) cases of tests/test_chip_compile.py. While it stands,
+# kv_quant_decline_reason declines int4 pools wherever the kernel would
+# be compiled for the chip, so the engine records the reason at
+# construction and serves the XLA dequant paths by plan, not by a
+# runtime degradation rung. int8 pages compile and keep the kernels.
+MOSAIC_INT4_KV_REFUSAL = (
+    "infer-vector-layout: unsupported shape cast (tpu.reshape of the "
+    "packed page block, vector<ps x D/2 x i8> -> ps x D/2 x 1, in "
+    "unpack_int4)")
+
+
 def kv_quant_decline_reason(page_size: int, d: int, kh: int, group: int,
                             bits: int = 8,
                             quant_group: int = 32) -> Optional[str]:
@@ -1085,6 +1101,8 @@ def kv_quant_decline_reason(page_size: int, d: int, kh: int, group: int,
             # effective_group clamps to >= 2; a grouping that doesn't
             # tile D evenly means no well-formed scale layout exists.
             return f"int4_group:d={d},g={quant_group}"
+        if not _interpret():
+            return f"mosaic:{MOSAIC_INT4_KV_REFUSAL}"
     return None
 
 

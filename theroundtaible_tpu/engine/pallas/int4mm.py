@@ -4,9 +4,10 @@ the matmul, so HBM streams the PACKED bytes.
 Why a kernel at all: the XLA path (models/common.py `_einsum` →
 `dequant_int4`) expresses dequant as bitcast → convert → grouped-scale
 multiply → reshape and hopes XLA fuses that chain into the dot's operand
-read. On real TPU it does not: BENCH_r05 hardware runs measured int4
-decode at 22.9 tok/s (interleave layout) then 31.6 tok/s (bitcast
-layout) against bf16's 130 and int8's 205 — the dequantized bf16 weight
+read. On real TPU it does not: int4 decode ran at 22.9 tok/s
+(interleave layout) then 31.6 tok/s (bitcast layout) against bf16's 130
+and int8's 205 (measured once before PR 1; not re-measured) — the
+dequantized bf16 weight
 was materialized (and copied) in HBM every token, so int4 streamed MORE
 bytes than bf16. int8 escapes because its dequant is a plain
 convert (fusable operand) plus an OUTPUT-side scale; int4's grouped
@@ -63,7 +64,7 @@ its own output slice, no collective; row-parallel for o/down — each
 shard contracts its input slice and one psum over the "model" axis
 combines, exactly the all-reduce the XLA path's sharded einsum inserts)
 and runs the single-device kernel per shard inside `shard_map` (via
-engine/compat.py's version shim). The plan is checked against the
+engine/compat.py's seam). The plan is checked against the
 PER-SHARD shapes before entering shard_map, so the body's dispatch
 never declines mid-trace; a weight axis the mesh does not divide is
 served replicated (matching sharding._fallback_replicated's placement,
@@ -255,6 +256,32 @@ def _classify(spec: str, leaf):
     return None, "spec:mixed-kept-contracted"   # MoE expert layouts
 
 
+# What the v5e's compiler (Mosaic, JAX 0.9.0) answers each kernel at real
+# Int4Leaf shapes — reproduced without a chip by the xfail(strict) cases
+# of tests/test_chip_compile.py, which tell the repair PR when an entry
+# may go. While an entry stands, the plan declines with it wherever the
+# kernel would be compiled for the chip, so describe()["int4_paths"]
+# carries the reason from the first trace and no dispatch ever reaches
+# the compiler's refusal (a runtime degradation rung).
+MOSAIC_REFUSAL = {
+    "out": "cannot statically prove that index in dimension 1 is a "
+           "multiple of 128 (vector.load of the scale block's lane "
+           "slice, s_ref[:, pl.ds(j * bg, bg)])",
+    "contract": "infer-vector-layout: unsupported shape cast "
+                "(tpu.reshape of the scale block to [bn, G, 1] in "
+                "jnp.repeat)",
+}
+
+
+def _mosaic_refusal(mode: str) -> Optional[str]:
+    """`mosaic:<the compiler's message>` when this kernel would be
+    compiled for the chip and the compiler is known to refuse it; None
+    in interpret mode (the CPU parity suites) or once it compiles."""
+    if _interpret() or mode not in MOSAIC_REFUSAL:
+        return None
+    return f"mosaic:{MOSAIC_REFUSAL[mode]}"
+
+
 def _plan_rows(m_rows: int) -> Optional[int]:
     """Padded block_m for m_rows, or None above 64: the kernels are
     DECODE kernels (weight-streaming-bound GEMVs, where fused dequant
@@ -271,6 +298,9 @@ def _plan_pack_out(m_rows: int, c_dim: int, p_dim: int, gp: int):
     kernel at these (possibly per-shard) dims. Block search walks the
     candidates until the working set fits `_VMEM_BUDGET`, so a plan is
     emitted only for shapes Mosaic can actually allocate."""
+    refused = _mosaic_refusal("out")
+    if refused:
+        return None, refused
     bm = _plan_rows(m_rows)
     if bm is None:
         return None, "rows:prefill-m"
@@ -293,6 +323,9 @@ def _plan_pack_contract(m_rows: int, cp: int, n_dim: int, gp: int):
     kernel. The whole (packed) contraction rides one block, so the
     budget check shrinks bn until the x/q/s working set fits — replacing
     the old magic `cp > 4096` gate with an actual per-shape estimate."""
+    refused = _mosaic_refusal("contract")
+    if refused:
+        return None, refused
     bm = _plan_rows(m_rows)
     if bm is None:
         return None, "rows:prefill-m"

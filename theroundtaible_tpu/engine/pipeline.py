@@ -32,7 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .compat import pcast, shard_map
 
 from .models.common import (
-    ModelConfig, Params, make_attention_mask, rms_norm, transformer_block)
+    ModelConfig, Params, init_params, make_attention_mask, rms_norm,
+    transformer_block)
 
 PIPE_AXIS = "pipe"
 
@@ -73,23 +74,39 @@ def stack_stage_params(params: Params, cfg: ModelConfig, n_stages: int,
     quant.quantized_specs. Any dim that doesn't divide its mesh axis
     falls back to replication (sharding._fallback_replicated).
     """
-    if cfg.num_layers % n_stages != 0:
-        raise ValueError(
-            f"{cfg.num_layers} layers do not split into {n_stages} stages")
-    per = cfg.num_layers // n_stages
-
     from .quant import quantized, quantized_specs
-    from .sharding import _fallback_replicated, param_specs
+    from .sharding import param_specs
     specs = param_specs(cfg)
     if any(quantized(l) for l in
            jax.tree_util.tree_leaves(params, is_leaf=quantized)):
         specs = quantized_specs(specs, params)
-    has_model = len(mesh.axis_names) > 1
+    shared, stacked = _stack_stages(params, cfg, n_stages)
+    return jax.device_put(
+        (shared, stacked), _stage_shardings(shared, stacked, specs, mesh))
 
+
+def _stack_stages(params: Params, cfg: ModelConfig,
+                  n_stages: int) -> tuple[Params, Params]:
+    """(shared, stacked) with no placement: the non-layer leaves, and
+    each layer tensor stacked to [n_stages, layers_per_stage, ...]."""
+    if cfg.num_layers % n_stages != 0:
+        raise ValueError(
+            f"{cfg.num_layers} layers do not split into {n_stages} stages")
+    per = cfg.num_layers // n_stages
     stacked = jax.tree_util.tree_map(
         lambda *leaves: jnp.stack(leaves).reshape(
             (n_stages, per) + leaves[0].shape),
         *params["layers"])
+    shared = {k: v for k, v in params.items() if k != "layers"}
+    return shared, stacked
+
+
+def _stage_shardings(shared: Params, stacked: Params, specs: Params,
+                     mesh: Mesh) -> tuple[Params, Params]:
+    """NamedSharding trees for _stack_stages' output (arrays or
+    ShapeDtypeStructs) under the param_specs tree `specs`."""
+    from .sharding import _fallback_replicated
+    has_model = len(mesh.axis_names) > 1
 
     def stage_place(x, spec):
         tp = tuple(spec) if has_model else ()
@@ -97,21 +114,31 @@ def stack_stage_params(params: Params, cfg: ModelConfig, n_stages: int,
         return NamedSharding(mesh,
                              _fallback_replicated(full, x.shape, mesh))
 
-    staged = jax.device_put(
-        stacked,
-        jax.tree_util.tree_map(stage_place, stacked, specs["layers"][0]))
-
     def shared_place(x, spec):
         full = spec if has_model else P()
         return NamedSharding(mesh,
                              _fallback_replicated(full, x.shape, mesh))
 
-    shared = {k: v for k, v in params.items() if k != "layers"}
     shared_specs = {k: specs.get(k, jax.tree_util.tree_map(
         lambda _: P(), v)) for k, v in shared.items()}
-    shared = jax.device_put(
-        shared, jax.tree_util.tree_map(shared_place, shared, shared_specs))
-    return shared, staged
+    return (jax.tree_util.tree_map(shared_place, shared, shared_specs),
+            jax.tree_util.tree_map(stage_place, stacked,
+                                   specs["layers"][0]))
+
+
+def init_stage_params(cfg: ModelConfig, key: jax.Array, dtype,
+                      n_stages: int, mesh: Mesh) -> tuple[Params, Params]:
+    """Random init born staged: init + stacking under one jit with the
+    (shared, staged) out_shardings, so each pipe device generates only
+    its own stage's layers (the twin of sharding.init_sharded_params)."""
+    from .sharding import param_specs
+
+    def build(k):
+        return _stack_stages(init_params(cfg, k, dtype), cfg, n_stages)
+
+    shardings = _stage_shardings(*jax.eval_shape(build, key),
+                                 param_specs(cfg), mesh)
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 def make_pp_prefill(cfg: ModelConfig, mesh: Mesh, n_micro: int):

@@ -61,7 +61,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..utils import telemetry
-from . import compat, deadlines, faults, trace_hooks
+from . import deadlines, faults, trace_hooks
 from .compat import pcast, shard_map
 from .engine import GenStats
 from .kvcache import SlotBook
@@ -72,7 +72,8 @@ from .models.common import (ModelConfig, _einsum, _softcap, embed_tokens,
                             gather_rows, init_params, make_attention_mask,
                             param_count, project_qkv, rms_norm,
                             spmd_mesh, transformer_block)
-from .pipeline import PIPE_AXIS, build_pipe_mesh, stack_stage_params
+from .pipeline import (PIPE_AXIS, build_pipe_mesh, init_stage_params,
+                       stack_stage_params)
 from .sampling import (SamplingParams, sample_token_batch, sampling_arrays)
 from .tokenizer import load_tokenizer
 
@@ -120,17 +121,6 @@ class PPEngine:
         # (pallas/attention._manual_axes) — heads must divide the model
         # axis exactly as on the main engine (explicit flash on a
         # non-divisible layout raises; auto falls back to dense).
-        if n_model > 1 and not compat.HAS_NATIVE_SHARD_MAP:
-            # Partial-manual stage bodies (manual "pipe", auto "model")
-            # lower axis_index to a PartitionId the legacy SPMD
-            # partitioner refuses — TP-in-stage needs the modern
-            # shard_map API. Refuse at build with the fix, instead of
-            # an opaque XLA error mid-prefill.
-            raise ValueError(
-                "mesh={'pipe': N, 'model': M} (TP inside stages) needs "
-                "jax.shard_map, which this jax version lacks — upgrade "
-                "jax or use mesh={'pipe': N} / the main engine's "
-                "(data, model) mesh")
         from .pallas.attention import spmd_partitionable
         heads_divide = spmd_partitionable(
             model_cfg.num_heads, model_cfg.num_kv_heads, n_model)
@@ -169,30 +159,46 @@ class PPEngine:
         # (SURVEY §2.3's (pipeline, tensor, data) requirement).
         self.mesh = build_pipe_mesh(n_stages, device_list, n_model)
 
-        if checkpoint:
-            from .checkpoint import load_hf_checkpoint
-            params = load_hf_checkpoint(checkpoint, model_cfg, dtype)
-        else:
-            params = init_params(model_cfg, jax.random.PRNGKey(seed), dtype)
-        self.num_params = param_count(params)
         self.quant = quant
-        if quant in ("int8", "int4"):
-            # PP is the engine for checkpoints too big for one chip —
-            # exactly where shrinking streamed weight bytes matters most.
-            # Quantize BEFORE stacking: the {"q","s"} dict / Int4Leaf
-            # leaves stack and shard like any other layer leaf, and the
-            # stage programs reach them only through _einsum/embed_tokens
-            # (which dequantize fusably, see engine/quant.py).
-            # model_shards: int4 grouping aligns to the in-stage TP shard
-            # boundary so the shard-aware kernel dispatch partitions
-            # scales with whole groups per shard.
-            from .quant import quantize_params
-            params = quantize_params(params, model_cfg, act_dtype=dtype,
-                                     free_source=True,
-                                     bits=8 if quant == "int8" else 4,
-                                     model_shards=n_model)
-        self.shared, self.staged = stack_stage_params(
-            params, model_cfg, n_stages, self.mesh)
+        if not checkpoint and quant == "none":
+            # Born staged: no device ever holds more than its stage.
+            self.shared, self.staged = init_stage_params(
+                model_cfg, jax.random.PRNGKey(seed), dtype, n_stages,
+                self.mesh)
+            self.num_params = (param_count(self.shared)
+                               + param_count(self.staged))
+        else:
+            if checkpoint:
+                from .checkpoint import load_hf_checkpoint
+                params = load_hf_checkpoint(checkpoint, model_cfg, dtype)
+            else:
+                # Quantization wants whole per-layer leaves BEFORE
+                # stacking, so this tree still lands on the default
+                # device (ROADMAP D2) — under jit, so its values are
+                # the born-sharded paths' bit for bit.
+                params = jax.jit(partial(init_params, model_cfg,
+                                         dtype=dtype))(
+                    jax.random.PRNGKey(seed))
+            self.num_params = param_count(params)
+            if quant in ("int8", "int4"):
+                # PP is the engine for checkpoints too big for one chip
+                # — exactly where shrinking streamed weight bytes
+                # matters most. Quantize BEFORE stacking: the {"q","s"}
+                # dict / Int4Leaf leaves stack and shard like any other
+                # layer leaf, and the stage programs reach them only
+                # through _einsum/embed_tokens (which dequantize
+                # fusably, see engine/quant.py). model_shards: int4
+                # grouping aligns to the in-stage TP shard boundary so
+                # the shard-aware kernel dispatch partitions scales
+                # with whole groups per shard.
+                from .quant import quantize_params
+                params = quantize_params(params, model_cfg,
+                                         act_dtype=dtype,
+                                         free_source=True,
+                                         bits=8 if quant == "int8" else 4,
+                                         model_shards=n_model)
+            self.shared, self.staged = stack_stage_params(
+                params, model_cfg, n_stages, self.mesh)
 
         per = model_cfg.num_layers // n_stages
         # Caches [st, per, slots|pages, S|ps, K, D]: stage axis over
@@ -358,10 +364,8 @@ class PPEngine:
             if not mesh_in_stage:
                 return spmd_mesh(LOCAL_MESH,
                                  int4_sink=self._int4_dispatches)
-            # Native shard_map is guaranteed here — the constructor
-            # refuses TP-in-stage on old jax — so the trace-context
-            # AbstractMesh is real (it carries the Manual "pipe" axis
-            # the nested spmd wrappers subtract via axis_types).
+            # The trace-context AbstractMesh carries the Manual "pipe"
+            # axis the nested spmd wrappers subtract via axis_types.
             return spmd_mesh(jax.sharding.get_abstract_mesh(),
                              int4_sink=self._int4_dispatches)
 

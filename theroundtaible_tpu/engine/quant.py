@@ -91,8 +91,9 @@ _EXPERT_SCALE_AXES = {
 # layout XLA/Mosaic fuses into the matmul operand on TPU. Packing the
 # contracted axis (the llama.cpp convention, used in an earlier
 # revision) forced an interleaving stack+reshape that broke operand
-# fusion on real TPU and decoded slower than bf16 (BENCH_r05). Scales
-# remain per-group × per-every-other-coordinate, so grouping along a
+# fusion on real TPU and decoded slower than bf16 (measured once before
+# PR 1; not re-measured). Scales remain per-group ×
+# per-every-other-coordinate, so grouping along a
 # kept axis changes only which direction group error correlates.
 
 

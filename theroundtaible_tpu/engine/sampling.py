@@ -119,7 +119,8 @@ def sample_token_batch(logits: jax.Array, key: jax.Array,
     need — the k-th logit and the top-p cutoff — are found in a
     `lax.top_k(_K_CAND)` candidate pool instead of two full-vocab
     descending SORTS (at a 256k vocab those sorts dominated sampled
-    decode: BENCH_r05 config 2 decoded at ~140 tok/s vs greedy's 205).
+    decode: ~140 tok/s sampled vs greedy's 205 — measured once before
+    PR 1; not re-measured).
     The candidate prefix IS the full sort's prefix, and the softmax is
     recomputed with the same ops (exp of max-shifted values over the
     kept-set sum — max and sum are plain reductions, no sort). The kth

@@ -401,9 +401,10 @@ def decode_segments(
     PIPELINED: the next segment is queued from the previous segment's
     DEVICE outputs (budget decremented and done carried with device
     arithmetic) BEFORE the host reads steps/out/done — so the device
-    never idles for the host round-trips between segments (material on
-    a high-RTT tunnel). When the just-read segment turns out to have
-    finished the generation, the speculative segment's while_loop
+    never idles for the host round-trips between segments (material
+    wherever the host is slow to turn around). When the just-read
+    segment turns out to have finished the generation, the speculative
+    segment's while_loop
     condition is false on entry and it costs microseconds; its results
     are discarded.
     """

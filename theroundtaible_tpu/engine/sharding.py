@@ -15,13 +15,14 @@ virtual 8-CPU mesh (tests / dryrun_multichip).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .models.common import ModelConfig, Params
+from .models.common import ModelConfig, Params, init_params
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
@@ -241,19 +242,42 @@ def _fallback_replicated(spec: P, shape: tuple[int, ...], mesh: Mesh) -> P:
     return P(*fixed)
 
 
+def param_shardings(cfg: ModelConfig, mesh: Mesh, shapes: Params) -> Params:
+    """NamedSharding tree for a param tree of these shapes (arrays or
+    ShapeDtypeStructs): param_specs, with any dimension that doesn't
+    divide its mesh axis falling back to replication (e.g. 1 kv head on
+    an 8-way model axis)."""
+    # tree_map flattens the spec tree up to `shapes`' treedef, so each
+    # PartitionSpec (a tuple subclass) arrives whole at its matching leaf.
+    return jax.tree_util.tree_map(
+        lambda x, spec: NamedSharding(
+            mesh, _fallback_replicated(spec, x.shape, mesh)),
+        shapes, param_specs(cfg))
+
+
 def shard_params(params: Params, cfg: ModelConfig, mesh: Mesh) -> Params:
-    """device_put the param tree with its spec tree; any dimension that
-    doesn't divide the mesh axis falls back to replication (e.g. 1 kv head
-    on an 8-way model axis)."""
-    specs = param_specs(cfg)
+    """device_put the param tree with its sharding tree. A leaf that
+    already sits where it belongs (init_sharded_params' output) is
+    returned as is — no copy, no transfer."""
+    def place(x, sharding):
+        have = getattr(x, "sharding", None)
+        if have is not None and have.is_equivalent_to(sharding, x.ndim):
+            return x
+        return jax.device_put(x, sharding)
 
-    def place(x, spec):
-        spec = _fallback_replicated(spec, x.shape, mesh)
-        return jax.device_put(x, NamedSharding(mesh, spec))
+    return jax.tree_util.tree_map(
+        place, params, param_shardings(cfg, mesh, params))
 
-    # tree_map flattens `specs` up to params' treedef, so each PartitionSpec
-    # (a tuple subclass) arrives whole at its matching array leaf.
-    return jax.tree_util.tree_map(place, params, specs)
+
+def init_sharded_params(cfg: ModelConfig, key: jax.Array, dtype,
+                        mesh: Mesh) -> Params:
+    """Random init born sharded: init_params under jit with the tree's
+    out_shardings, so every device generates its own shard (threefry is
+    partitionable) and none ever holds a whole leaf — a model that only
+    fits split across the mesh can be built on it."""
+    init = functools.partial(init_params, cfg, dtype=dtype)
+    shardings = param_shardings(cfg, mesh, jax.eval_shape(init, key))
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def logical_sharding(mesh: Mesh, spec: P) -> NamedSharding:

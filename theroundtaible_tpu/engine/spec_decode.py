@@ -113,8 +113,8 @@ NGRAM_MAX = 3
 # dispatches, a row whose windowed acceptance rate (accepted drafts /
 # drafted) sits below the floor stops drafting — drafting must never
 # cost a slow row more dispatches than plain decode buys back.
-# ROUNDTABLE_SPEC_ACCEPT_FLOOR raises/lowers the floor: on a high-RTT
-# tunnel, where a verify dispatch's host round-trip is dearer than the
+# ROUNDTABLE_SPEC_ACCEPT_FLOOR raises/lowers the floor: on a host
+# where a verify dispatch's host round-trip is dearer than the
 # pipelined while-loop's hidden one, a modest-acceptance row can be
 # net-slower than plain decode without ever dropping below the default
 # — the operator lever until the on-chip A/B settles the break-even.

@@ -476,7 +476,7 @@ class Gateway:
         """The scheduler half: pick the serving replica (router) or the
         one scheduler (N=1), submit with the streaming seam bridged
         onto the asyncio loop, classify every refusal into the shed
-        taxonomy, and publish the inflight gauge."""
+        kinds, and publish the inflight gauge."""
         loop = self._loop
         assert loop is not None, "gateway not started"
 

@@ -29,6 +29,7 @@ _SRC_PATH = _PKG_DIR.parent.parent / "native" / "rt_native.cc"
 
 _lib = None
 _lib_tried = False
+_lib_origin: Optional[str] = None   # "built" | "loaded" once resolved
 _lock = threading.Lock()
 
 # Must match DType in rt_native.cc.
@@ -65,19 +66,31 @@ def _build() -> bool:
         return False
 
 
+def _stale() -> bool:
+    """The .so is missing, or older than its source: the library is
+    git-ignored, so a working tree can carry a build of an earlier
+    rt_native.cc — what runs must come from the tracked source."""
+    try:
+        return (not _SO_PATH.exists()
+                or (_SRC_PATH.exists() and _SRC_PATH.stat().st_mtime
+                    > _SO_PATH.stat().st_mtime))
+    except OSError:
+        return True
+
+
 def _get_lib(build: bool = True):
-    global _lib, _lib_tried
+    global _lib, _lib_tried, _lib_origin
     with _lock:
         if _lib_tried:
             return _lib
-        if not build:
-            # latency-sensitive caller: load only if the .so already
+        stale = _stale()
+        if not build and stale:
+            # latency-sensitive caller: load only if a fresh .so already
             # exists; never shell out to g++ and never latch a negative
             # result (a later load path may still build it)
-            if not _SO_PATH.exists():
-                return None
+            return None
         _lib_tried = True
-        if not _SO_PATH.exists() and not _build():
+        if stale and not _build():
             return None
         try:
             lib = ctypes.CDLL(str(_SO_PATH))
@@ -90,6 +103,7 @@ def _get_lib(build: bool = True):
                 ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_int32), ctypes.c_int64]
             _lib = lib
+            _lib_origin = "built" if stale else "loaded"
         except OSError:
             _lib = None
         return _lib
@@ -97,6 +111,14 @@ def _get_lib(build: bool = True):
 
 def native_available() -> bool:
     return _get_lib() is not None
+
+
+def native_origin() -> Optional[str]:
+    """How this process got the library: "built" (compiled from
+    native/rt_native.cc just now — the .so was missing or older than
+    the source), "loaded" (a fresh .so was already there), or None
+    (unavailable, or not resolved yet — native_available() resolves)."""
+    return _lib_origin
 
 
 def iter_safetensors(path: str | Path, n_threads: int = 0):
