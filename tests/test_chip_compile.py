@@ -156,7 +156,12 @@ ATTENTION_CASES = [
 def test_attention_kernel_compiles_for_v5e(one_chip, kernel, kv, widths):
     h, kh = HEADS[widths]
     fn, shapes = _attention_case(kernel, h, kh, kv, one_chip)
-    _assert_kernel(_compile(fn, *shapes))
+    hlo = _compile(fn, *shapes)
+    _assert_kernel(hlo)
+    # The kernel's `name=` is the instruction's name, which is what the
+    # profiler's operations line shows (ISSUE 25).
+    name = {"ragged": "ragged_paged"}.get(kernel, kernel) + "_attention"
+    assert f"%{name}" in hlo
 
 
 @pytest.mark.parametrize("kernel", ["paged_decode", "ragged"])

@@ -813,3 +813,128 @@ class TestRecompileSentinel:
             "roundtable_flight_dumps_total",
             trigger="steady_state_compile") == d0 + 1
         sched.close()
+
+
+# ---------------------------------------------------------------------------
+# the loop clock (ISSUE 25): what the scheduler's thread was doing
+# ---------------------------------------------------------------------------
+
+
+class TestLoopClock:
+    @pytest.mark.scheduler
+    def test_phases_sum_to_the_loops_wall_and_every_phase_is_met(
+            self, shared_engine):
+        """Over a scheduled three-session run the ten phases telescope
+        to the loop thread's wall (within 1 %), each is met at least
+        once, and the registry series moves with describe()."""
+        from theroundtaible_tpu.engine.scheduler import LOOP_PHASES
+        from theroundtaible_tpu.utils import telemetry
+
+        name = shared_engine.cfg.name
+        published = {p: telemetry.REGISTRY.counter_total(
+            "roundtable_sched_loop_seconds_total", engine=name, phase=p)
+            for p in LOOP_PHASES}
+        sched = SessionScheduler(shared_engine, admit_hold_s=0.3)
+        try:
+            a, t_a = sched.describe()["loop_seconds"], time.monotonic()
+            results, errors = run_concurrent(sched)
+            assert not errors and len(results) == 3
+            time.sleep(0.3)            # the loop goes back to waiting
+            b, t_b = sched.describe()["loop_seconds"], time.monotonic()
+        finally:
+            sched.close()
+        assert tuple(b) == LOOP_PHASES
+        gained = {p: b[p] - a[p] for p in LOOP_PHASES}
+        assert sum(gained.values()) == pytest.approx(t_b - t_a, rel=0.01)
+        assert all(b[p] > 0.0 for p in LOOP_PHASES), b
+        # the device's work shows as the host's blocked phases
+        assert gained["sync"] + gained["dispatch"] > gained["flush"]
+        for p in ("admit", "build", "accept", "retire"):
+            moved = telemetry.REGISTRY.counter_total(
+                "roundtable_sched_loop_seconds_total", engine=name,
+                phase=p) - published[p]
+            assert 0.0 < moved <= b[p] + 1e-6, (p, moved, b[p])
+
+    @pytest.mark.scheduler(allow_serial=True)
+    def test_unarmed_a_tick_creates_no_span_and_the_totals_still_move(
+            self, shared_engine, monkeypatch):
+        from theroundtaible_tpu.utils import telemetry
+
+        made = []
+
+        class CountingSpan(telemetry.Span):
+            def __init__(self, *args, **kw):
+                made.append(args[0])
+                super().__init__(*args, **kw)
+
+        monkeypatch.setattr(telemetry, "Span", CountingSpan)
+        assert not telemetry.ACTIVE
+        emitted = telemetry.spans_emitted()
+        sched = SessionScheduler(shared_engine)
+        try:
+            before = sched.describe()["loop_seconds"]
+            sched.submit("quiet", PROMPTS["s0"], max_new_tokens=70)
+            after = sched.describe()["loop_seconds"]
+            assert sched._clock._open is None
+            assert sched._clock.tick >= 1
+        finally:
+            sched.close()
+        assert made == [] and telemetry.spans_emitted() == emitted
+        assert sum(after.values()) > sum(before.values())
+        assert after["sync"] + after["dispatch"] > 0.0
+
+    @pytest.mark.scheduler(allow_serial=True)
+    @pytest.mark.telemetry
+    def test_admit_and_segment_spans_carry_their_counts(
+            self, shared_engine):
+        """The `admit` span of a request: caused by, and in the trace
+        of, the request's own span; it and the `segment` spans carry
+        the counts of the work done at that boundary, and the loop's
+        stretches lie around them on the same clock."""
+        from theroundtaible_tpu.utils import telemetry
+
+        telemetry.disarm()
+        telemetry.arm()                # this test's own span buffer
+        sched = SessionScheduler(shared_engine)
+        t_a = time.monotonic()
+        try:
+            with telemetry.span("request", stream="st") as request:
+                texts, stats = sched.submit(
+                    "traced", PROMPTS["s1"], max_new_tokens=70)
+        finally:
+            sched.close()
+        spans = telemetry.spans_between(t_a, time.monotonic())
+        (admit,) = [r for r in spans if r["rung"] == "admit"]
+        assert admit["trace_id"] == request.trace_id
+        assert admit["parent_id"] == request.span_id
+        at = admit["attrs"]
+        assert at["session"] == "traced" and at["rows"] == 2
+        assert at["deferred"] is False
+        assert at["prefill_tokens"] == stats.prefill_tokens > 0
+        assert at["reused_tokens"] == stats.reused_tokens
+        assert at["prefix_reused_tokens"] == stats.prefix_reused_tokens
+        assert at["queue_wait_s"] >= 0.0
+        assert 0.0 < at["sync_s"] <= admit["dur_s"]
+        # admission's dispatches parent under it, in the same trace
+        kids = [r for r in spans if r["parent_id"] == admit["span_id"]]
+        assert kids and {r["rung"] for r in kids} == {"dispatch"}
+        segments = [r for r in spans if r["rung"] == "segment"]
+        assert segments
+        for seg in segments:
+            sa = seg["attrs"]
+            assert sa["kind"] == "plain" and sa["rows"] == 2
+            assert sa["label"] == "decode[b=2]"
+            assert sa["decode_tokens"] == sa["steps"] * sa["rows"]
+            assert (sa["prefill_tokens"], sa["drafted"],
+                    sa["accepted"]) == (0, 0, 0)
+            assert sa["tick"] >= 1
+        assert sum(s["attrs"]["steps"] for s in segments) >= 69
+        # every phase's stretch of that run is on the same clock
+        loops = [r for r in spans if r["rung"].startswith("loop.")]
+        assert {"loop.admit", "loop.admit_sync", "loop.build",
+                "loop.sync", "loop.accept", "loop.retire"} <= {
+                    r["rung"] for r in loops}
+        sync = [r for r in loops if r["rung"] == "loop.sync"]
+        assert any(s["t0"] <= seg["t0"] + seg["dur_s"]
+                   and seg["t0"] <= s["t0"] + s["dur_s"]
+                   for s in sync for seg in segments)

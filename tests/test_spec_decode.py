@@ -438,6 +438,53 @@ class TestScheduledSpec:
 
     @pytest.mark.scheduler
     @pytest.mark.spec_decode
+    @pytest.mark.telemetry
+    def test_segment_spans_say_which_kind_ran_and_what_it_committed(
+            self, spec_engine):
+        """ISSUE 25: armed, every scheduler segment is a span of kind
+        plain | ragged | spec carrying the counts its fold produced —
+        summed over the run they are the scheduler's own totals, and a
+        deferred join's `admit` span says so."""
+        telemetry.disarm()
+        telemetry.arm()                # this test's own span buffer
+        sched = SessionScheduler(spec_engine)
+        t_a = time.monotonic()
+        try:
+            _out, err = _join_mid_decode(sched, ["s0", "s1", "s2"])
+            assert not err, err
+            d = sched.describe()
+        finally:
+            sched.close()
+        spans = telemetry.spans_between(t_a, time.monotonic())
+        segs = [r["attrs"] for r in spans if r["rung"] == "segment"]
+        kinds = {a["kind"] for a in segs}
+        assert {"ragged", "spec"} <= kinds <= {"plain", "ragged", "spec"}
+        count = {k: sum(1 for a in segs if a["kind"] == k)
+                 for k in ("plain", "ragged", "spec")}
+        assert count == {"plain": d["segments"],
+                         "ragged": d["ragged_segments"],
+                         "spec": d["spec_segments"]}
+        assert sum(a["decode_tokens"] for a in segs) == \
+            d["segment_decode_tokens"]
+        assert sum(a["prefill_tokens"] for a in segs) == \
+            d["segment_prefill_tokens"] > 0
+        info = spec_engine.spec_describe()
+        spec = [a for a in segs if a["kind"] == "spec"]
+        assert sum(a["accepted"] for a in spec) > 0
+        assert all(a["accepted"] <= a["drafted"] for a in spec)
+        assert sum(a["drafted"] for a in spec) <= info["drafted_tokens"]
+        for a in segs:
+            assert a["label"].startswith(
+                "decode[b=" if a["kind"] == "plain" else "ragged[t=")
+            assert 0 < a["pages_in_use"] <= spec_engine.kv.usable_pages()
+            assert a["steps"] >= 1 and a["rows"] >= 1
+        admits = [r["attrs"] for r in spans if r["rung"] == "admit"]
+        assert len(admits) == 3
+        assert sum(1 for a in admits if a["deferred"]) == \
+            d["ragged_joins"] >= 1
+
+    @pytest.mark.scheduler
+    @pytest.mark.spec_decode
     @pytest.mark.prefix_cache
     def test_prefix_cache_attach_of_drafted_transcript(self,
                                                        spec_engine,

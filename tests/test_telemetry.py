@@ -290,6 +290,298 @@ class TestSpans:
         assert payload["spans"]  # spans shipped too, separately
 
 
+# --- one clock: the armed span buffer (ISSUE 25) ---
+
+
+@pytest.fixture
+def fresh_buffer():
+    """A span buffer of this test's own: a disarm/arm cycle opens one."""
+    telemetry.disarm()
+    telemetry.arm()
+    yield
+    telemetry.arm()
+
+
+@pytest.mark.telemetry
+class TestSpanBuffer:
+    def test_a_finished_span_record_carries_t0_on_the_monotonic_clock(
+            self, fresh_buffer):
+        before = time.monotonic()
+        with telemetry.span("turn"):
+            time.sleep(0.01)
+        after = time.monotonic()
+        (rec,) = telemetry.spans_between(before, after)
+        assert before <= rec["t0"] <= rec["t0"] + rec["dur_s"] <= after
+        assert rec["dur_s"] >= 0.01
+        # the wall-clock start it always had is still there
+        assert abs(rec["start"] - time.time()) < 60
+
+    def test_spans_between_returns_exactly_the_spans_started_in_the_stretch(
+            self, fresh_buffer):
+        with telemetry.span("turn", n=0):
+            pass
+        t_a = time.monotonic()
+        straddler = telemetry.start_span("turn", n=1)
+        with telemetry.span("dispatch", n=2):
+            pass
+        t_b = time.monotonic()
+        with telemetry.span("dispatch", n=3):
+            pass
+        straddler.end()            # started inside, ended after t_b
+        still_open = telemetry.start_span("turn", n=4)
+        got = telemetry.spans_between(t_a, t_b)
+        assert sorted(r["attrs"]["n"] for r in got) == [1, 2]
+        assert telemetry.spans_between(t_b, t_b) == []
+        assert telemetry.spans_dropped() == 0
+        still_open.end()
+
+    def test_the_buffer_is_bounded_and_counts_what_it_drops(
+            self, monkeypatch):
+        monkeypatch.setattr(telemetry, "SPAN_BUFFER_CAPACITY", 8)
+        telemetry.disarm()
+        telemetry.arm()            # opens a buffer of 8
+        t_a = time.monotonic()
+        for i in range(11):
+            with telemetry.span("dispatch", i=i):
+                pass
+        got = telemetry.spans_between(t_a, time.monotonic())
+        assert [r["attrs"]["i"] for r in got] == list(range(3, 11))
+        assert telemetry.spans_dropped() == 3
+
+    def test_disarm_leaves_the_buffer_readable_until_the_next_arm(
+            self, fresh_buffer):
+        t_a = time.monotonic()
+        with telemetry.span("turn"):
+            pass
+        telemetry.disarm()
+        try:
+            assert len(telemetry.spans_between(
+                t_a, time.monotonic())) == 1
+            with telemetry.span("turn"):    # disarmed: no record
+                pass
+            assert len(telemetry.spans_between(
+                t_a, time.monotonic())) == 1
+        finally:
+            telemetry.arm()        # a fresh arming: a fresh buffer
+        assert telemetry.spans_between(t_a, time.monotonic()) == []
+        with telemetry.span("turn"):
+            pass                   # the marker guard wants a span
+
+    def test_arming_twice_keeps_what_the_first_arming_gathered(
+            self, fresh_buffer):
+        t_a = time.monotonic()
+        with telemetry.span("turn"):
+            pass
+        telemetry.arm()
+        assert len(telemetry.spans_between(t_a, time.monotonic())) == 1
+
+    def test_emit_span_backdates_its_start(self, fresh_buffer):
+        t_a = time.monotonic()
+        with telemetry.span("dispatch") as d:
+            telemetry.emit_span("compile", 0.25, label="decode[b=4]",
+                                cache_hit=True)
+        recs = {r["rung"]: r for r in telemetry.spans_between(
+            t_a - 1.0, time.monotonic())}
+        c = recs["compile"]
+        assert c["dur_s"] == 0.25 and c["parent_id"] == d.span_id
+        assert c["t0"] == pytest.approx(t_a - 0.25, abs=0.05)
+        assert c["attrs"] == {"label": "decode[b=4]", "cache_hit": True}
+
+    def test_leave_fixes_the_stretch_and_end_emits_later(
+            self, fresh_buffer):
+        t_a = time.monotonic()
+        seg = telemetry.start_span("segment")
+        with seg:
+            pass                   # a with-block still ends it at once
+        seg = telemetry.start_span("segment")
+        seg.__enter__()
+        assert telemetry.current_context()["span_id"] == seg.span_id
+        seg.leave()
+        assert telemetry.current_context() is None
+        assert len(telemetry.spans_between(t_a, time.monotonic())) == 1
+        time.sleep(0.02)
+        seg.set_attr("accepted", 3)
+        seg.end()
+        late = telemetry.spans_between(t_a, time.monotonic())[-1]
+        assert late["dur_s"] < 0.02 and late["attrs"] == {"accepted": 3}
+        telemetry.NULL_SPAN.leave()    # the disarmed twin: a no-op
+
+
+@pytest.mark.telemetry
+class TestLexicalMirror:
+    """Only a span entered with `with` mirrors into the profiler: a
+    held one would lie open over every idle gap (ISSUE 25)."""
+
+    @pytest.fixture
+    def mirrored(self, monkeypatch):
+        names = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                names.append(("open", self.name))
+                return self
+
+            def __exit__(self, *exc):
+                names.append(("close", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        telemetry.set_profiling(True)
+        yield names
+        telemetry.set_profiling(False)
+
+    def test_a_held_span_opens_no_trace_annotation(self, mirrored):
+        turn = telemetry.start_span("turn", session="s")
+        turn.end()
+        assert mirrored == []
+        spans = telemetry.recorder().span_events()
+        assert spans[-1]["rung"] == "turn"      # its record is kept
+
+    def test_a_lexical_span_mirrors_for_exactly_its_block(self, mirrored):
+        with telemetry.span("segment"):
+            assert mirrored == [("open", "rt:segment")]
+        assert mirrored == [("open", "rt:segment"),
+                            ("close", "rt:segment")]
+
+    def test_no_annotation_without_a_running_profile(self, mirrored):
+        telemetry.set_profiling(False)
+        with telemetry.span("segment"):
+            pass
+        assert mirrored == []
+
+    def test_the_request_clocks_held_span_is_not_mirrored(self, mirrored):
+        from theroundtaible_tpu.utils import tracing
+        trace = tracing.RequestTrace(stream="st", session="s")
+        trace.stage("admission")
+        record = trace.finish("ok")
+        assert mirrored == [] and record["span_id"]
+
+
+# --- the loop clock (ISSUE 25) ---
+
+
+PHASES = ("wait", "admit", "admit_sync", "build", "dispatch", "sync")
+WITHIN = {"admit": {"sync": "admit_sync", "dispatch": "admit"}}
+
+
+class TestLoopClock:
+    def test_phases_telescope_to_the_wall(self):
+        t0 = time.monotonic()
+        clock = telemetry.LoopClock(PHASES, "wait")
+        for phase in ("admit", "build", "dispatch", "sync", "build",
+                      "wait"):
+            time.sleep(0.002)
+            clock.mark(phase)
+        snap = clock.snapshot()
+        wall = time.monotonic() - t0
+        assert sum(snap.values()) == pytest.approx(wall, rel=0.01)
+        assert snap["build"] >= 0.004 and snap["admit_sync"] == 0.0
+        assert clock.phase == "wait"
+
+    def test_a_seam_switches_and_marks_back(self):
+        clock = telemetry.LoopClock(PHASES, "build", within=WITHIN)
+        back = clock.switch("sync")
+        assert (back, clock.phase) == ("build", "sync")
+        clock.mark(back)
+        assert clock.phase == "build"
+        # inside admission a blocking read is admit_sync, and issuing a
+        # program stays admission's own host work
+        clock.mark("admit")
+        back = clock.switch("sync")
+        assert (back, clock.phase) == ("admit", "admit_sync")
+        clock.mark(back)
+        back = clock.switch("dispatch")
+        assert (back, clock.phase) == ("admit", "admit")
+        clock.mark(back)
+        assert clock.phase == "admit"
+
+    def test_unarmed_a_mark_creates_no_span_and_the_totals_move(self):
+        telemetry.disarm()
+        before = telemetry.spans_emitted()
+        clock = telemetry.LoopClock(PHASES, "wait")
+        time.sleep(0.002)
+        clock.mark("build")
+        clock.mark("wait")
+        assert telemetry.spans_emitted() == before
+        assert clock._open is None
+        assert clock.seconds["wait"] >= 0.002
+
+    @pytest.mark.telemetry
+    def test_armed_the_stretches_lie_end_to_end_outside_the_flight_ring(
+            self, fresh_buffer):
+        ring_before = len(telemetry.recorder().span_events())
+        t_a = time.monotonic()
+        clock = telemetry.LoopClock(PHASES, "wait", engine="e")
+        clock.tick = 7
+        for phase in ("admit", "build", "sync", "build", "wait"):
+            time.sleep(0.001)
+            clock.mark(phase)
+        recs = [r for r in telemetry.spans_between(t_a, time.monotonic())
+                if r["rung"].startswith("loop.")]
+        # armed from the first mark on: admit, build, sync, build are
+        # over, the last wait is still open
+        assert [r["rung"] for r in recs] == [
+            "loop.admit", "loop.build", "loop.sync", "loop.build"]
+        for a, b in zip(recs, recs[1:]):
+            assert a["t0"] + a["dur_s"] == pytest.approx(b["t0"],
+                                                         abs=2e-6)
+        assert {r["trace_id"] for r in recs} == {recs[0]["trace_id"]}
+        assert all(r["attrs"] == {"engine": "e", "tick": 7}
+                   for r in recs)
+        assert len(telemetry.recorder().span_events()) == ring_before
+        # disarmed mid-phase: the open stretch still ends, no new one
+        telemetry.disarm()
+        clock.mark("build")
+        telemetry.arm()
+        assert clock._open is None
+        last = telemetry.spans_between(t_a, time.monotonic())
+        assert last == []          # (a fresh arming, a fresh buffer)
+
+    @pytest.mark.telemetry
+    def test_while_profiling_each_stretch_is_an_rt_loop_annotation(
+            self, monkeypatch, fresh_buffer):
+        names = []
+        monkeypatch.setattr(telemetry, "_open_annotation",
+                            lambda name: names.append(name) or name)
+        monkeypatch.setattr(telemetry, "_close_annotation",
+                            lambda ann: names.append("/" + ann))
+        telemetry.set_profiling(True)
+        try:
+            clock = telemetry.LoopClock(PHASES, "wait")
+            clock.mark("build")
+            clock.mark("sync")
+        finally:
+            telemetry.set_profiling(False)
+        clock.mark("wait")
+        assert names == ["loop.build", "/loop.build", "loop.sync",
+                         "/loop.sync"]
+
+    def test_the_serving_seams_switch_the_threads_clock_and_back(self):
+        from theroundtaible_tpu.engine.serving_loop import (host_sync,
+                                                            run_dispatch)
+        clock = telemetry.LoopClock(PHASES, "build", within=WITHIN)
+        seen = []
+        assert telemetry.loop_clock() is None
+        telemetry.bind_loop_clock(clock)
+        try:
+            assert run_dispatch(lambda: seen.append(clock.phase) or 1,
+                                None) == 1
+            assert host_sync(lambda: seen.append(clock.phase) or 2) == 2
+            with pytest.raises(ValueError):
+                host_sync(lambda: (_ for _ in ()).throw(ValueError()))
+            assert clock.phase == "build"
+            clock.mark("admit")
+            host_sync(lambda: seen.append(clock.phase))
+            assert clock.phase == "admit"
+        finally:
+            telemetry.bind_loop_clock(None)
+        assert seen == ["dispatch", "sync", "admit_sync"]
+        # a thread with no clock (generate_batch callers) pays a lookup
+        assert host_sync(lambda: 3) == 3
+
+
 # --- watchdog / breaker auto-dump seams ---
 
 

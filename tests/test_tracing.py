@@ -466,6 +466,38 @@ class TestPropagation:
             "roundtable_gateway_ttft_seconds")
         assert any(v["trace_id"] == tid for v in ex.values())
 
+    def test_the_admit_span_joins_the_requests_trace(self, gw):
+        """ISSUE 25: the scheduler's `admit` span — admission's host
+        work, on the loop thread — is caused by the gateway's `request`
+        span and shares the client's trace id; the request's held spans
+        keep their records and ids."""
+        tid = "feedc0dedeadbee2"
+        t_a = time.monotonic()
+        meta, _toks, terminal = read_stream(
+            gw.port, "/v1/discussions",
+            {"session": "tr-admit", "max_new_tokens": 6,
+             "turns": [{"knight": "lancelot", "prompt": PROMPT}]},
+            headers={"Traceparent": tracing.format_traceparent(
+                tid, "1234567890ab")})
+        assert terminal["type"] == "retired" and meta["trace"] == tid
+        _wait_record(tid)
+        deadline = time.monotonic() + 5.0
+        while True:
+            mine = [r for r in telemetry.spans_between(
+                t_a, time.monotonic()) if r["trace_id"] == tid]
+            rungs = {r["rung"] for r in mine}
+            if {"request", "turn", "admit"} <= rungs \
+                    or time.monotonic() > deadline:
+                break
+            time.sleep(0.05)
+        assert {"request", "turn", "admit", "dispatch"} <= rungs
+        by_rung = {r["rung"]: r for r in mine}
+        assert by_rung["admit"]["parent_id"] == \
+            by_rung["request"]["span_id"]
+        assert by_rung["admit"]["attrs"]["session"] == "tr-admit"
+        assert by_rung["turn"]["parent_id"] == \
+            by_rung["request"]["span_id"]
+
     def test_minted_root_when_no_header(self, gw):
         meta, toks, terminal = read_stream(
             gw.port, "/v1/discussions",

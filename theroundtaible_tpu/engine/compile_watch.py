@@ -13,7 +13,8 @@ compile into the PR-5 telemetry spine:
 - registry counters `roundtable_compiles_total{label=...}` /
   `roundtable_compile_seconds_total` /
   `roundtable_compile_cache_{hits,misses}_total`, a flight-recorder
-  `compile` event per observation, and a bounded in-process history
+  `compile` event per observation, a `compile` span (label, cache_hit,
+  duration) while telemetry is armed, and a bounded in-process history
   ring (`history()` — what `status --perf` renders);
 - **program labels** via `label(...)`: engine dispatch seams wrap
   their device calls in a thread-local attribution window
@@ -196,6 +197,12 @@ def _record_compile(duration: float, cache_hit: bool) -> None:
     telemetry.inc("roundtable_compiles_total", label=lbl)
     telemetry.inc("roundtable_compile_seconds_total", duration)
     telemetry.recorder().record("compile", **entry)
+    if telemetry.ACTIVE:
+        # On the span timeline too (ISSUE 25): the hook fires on the
+        # compiling thread as the compile ends, so the span lies inside
+        # the dispatch — and the scheduler tick — it stalled.
+        telemetry.emit_span("compile", duration, label=lbl,
+                            cache_hit=cache_hit)
     if not entry["steady_state"]:
         return
     telemetry.inc("roundtable_steady_state_compiles_total", label=lbl)

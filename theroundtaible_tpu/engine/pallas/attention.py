@@ -283,6 +283,7 @@ def flash_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="flash_prefill_attention",
     )(offsets.astype(jnp.int32), kv_valid.astype(jnp.int32), qt, kt, vt)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
@@ -458,6 +459,7 @@ def paged_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(table.astype(jnp.int32), offsets.astype(jnp.int32),
       kv_valid.astype(jnp.int32), *operands)
     return out.reshape(b, kh * group, t, d).transpose(0, 2, 1, 3)
@@ -977,6 +979,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(table.astype(jnp.int32), kv_valid.astype(jnp.int32), *operands)
     return out.reshape(b, 1, h, d)
 
@@ -1275,6 +1278,7 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(tables.astype(jnp.int32), seq_of_block.astype(jnp.int32),
       block_qstart.astype(jnp.int32), query_offsets.astype(jnp.int32),
       kv_valid.astype(jnp.int32), *operands)
@@ -1420,5 +1424,6 @@ def ragged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name="ragged_decode_attention",
     )(kv_valid.astype(jnp.int32), qt, kt, vt)
     return out.reshape(b, 1, h, d)

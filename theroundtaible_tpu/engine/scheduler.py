@@ -88,6 +88,16 @@ from .serving_loop import (DECODE_SEGMENT, RAGGED_BLOCK_Q, RaggedSeq,
 _OCCUPANCY_LOG_CAP = 256
 _EVENT_LOG_CAP = 64
 
+# The loop clock's phases (ISSUE 25; PERF.md section 3 has the table):
+# every instant of the scheduler's thread belongs to exactly one.
+# `dispatch`, `sync` and `admit_sync` are marked by the two seams in
+# serving_loop (run_dispatch, host_sync), the rest here; inside
+# admission a blocking read is `admit_sync` and issuing a program is
+# admission's own host work.
+LOOP_PHASES = ("wait", "health", "admit", "admit_sync", "build",
+               "dispatch", "sync", "accept", "flush", "retire")
+_LOOP_WITHIN = {"admit": {"sync": "admit_sync", "dispatch": "admit"}}
+
 # Test-visibility counter (tests/conftest.py `scheduler` marker guard):
 # the maximum number of live rows any scheduler dispatched in one decode
 # segment since the last reset. A guard that sees < 2 here knows the
@@ -382,6 +392,14 @@ class SessionScheduler:
         # LOCKSTEP (_bump), so describe() and the registry can never
         # disagree — the single-source-of-truth migration.
         self._tname = getattr(engine.cfg, "name", "engine")
+        # The loop clock (ISSUE 25): which phase the loop thread is in,
+        # lifetime seconds per phase (describe()["loop_seconds"], the
+        # roundtable_sched_loop_seconds_total series), and — armed —
+        # one `loop.<phase>` span per stretch. Marked on the loop
+        # thread only; `_loop_published` is what the series has seen.
+        self._clock = telemetry.LoopClock(
+            LOOP_PHASES, "wait", within=_LOOP_WITHIN, engine=self._tname)
+        self._loop_published = dict.fromkeys(LOOP_PHASES, 0.0)
         # Replica identity (ISSUE 17): set by the session router when
         # this scheduler serves as one replica of a data-parallel
         # fleet. N replicas of one model share `_tname` (same config),
@@ -700,6 +718,7 @@ class SessionScheduler:
             },
             "journal_turns": self.journal_turns,
             "journal_errors": self.journal_errors,
+            "loop_seconds": self._clock.snapshot(),
             "events": events,
         }
 
@@ -889,6 +908,8 @@ class SessionScheduler:
     # ------------------------------------------------------------------
 
     def _loop(self) -> None:
+        clock = self._clock
+        telemetry.bind_loop_clock(clock)
         while True:
             with self._cv:
                 # A paused scheduler with only queued work sleeps: the
@@ -897,6 +918,7 @@ class SessionScheduler:
                 while (not self._active and not self._stop
                        and not self._idle_spill_due()
                        and (not self._queue or self._paused)):
+                    clock.mark("wait")
                     self._cv.wait(timeout=0.25)
                     if self._queue and self._paused:
                         # Paused with queued work: tick at the wait
@@ -920,6 +942,9 @@ class SessionScheduler:
         self._release_engine()
 
     def _tick(self) -> None:
+        clock = self._clock
+        clock.tick += 1
+        clock.mark("health")
         if deadlines.DRAINING:
             self.reject_queued()
         if self._stop:
@@ -928,7 +953,9 @@ class SessionScheduler:
         self._sweep_queue()
         self._prune_last_active()
         self._spill_idle_by_age()
+        clock.mark("admit")
         self._admit_queued()
+        clock.mark("build")
         live = [r for r in self._active
                 if not r.done and not r.pending]
         filling = [r for r in self._active
@@ -949,9 +976,26 @@ class SessionScheduler:
             # _may_speculate composition rules by construction.
             if not self._run_spec_segment(live):
                 self._run_segment(live)
+        clock.mark("flush")
         self._flush_streams()
+        clock.mark("retire")
         self._retire_finished()
+        clock.mark("health")
         self._check_request_health()
+        self._publish_loop_seconds()
+
+    def _publish_loop_seconds(self) -> None:
+        """Move roundtable_sched_loop_seconds_total{phase=} by what the
+        loop clock gained since the last tick's end (the _bump rule:
+        describe()["loop_seconds"] and the series are one store)."""
+        seen = self._loop_published
+        labels = self._series_labels()
+        for phase, total in self._clock.seconds.items():
+            gained = total - seen[phase]
+            if gained > 0.0:
+                seen[phase] = total
+                telemetry.inc("roundtable_sched_loop_seconds_total",
+                              gained, phase=phase, **labels)
 
     def _acquire_engine(self) -> None:
         if not self._lock_held:
@@ -1020,7 +1064,9 @@ class SessionScheduler:
                     remaining = (req.enqueued + self.admit_hold_s
                                  - time.monotonic())
                     if remaining > 0:
+                        back = self._clock.switch("wait")
                         self._cv.wait(timeout=remaining)
+                        self._clock.mark(back)
                         continue
                 if not self._fits_now(req):
                     # Backpressure: keep it QUEUED — retirement frees
@@ -1031,7 +1077,18 @@ class SessionScheduler:
                 self._queue.popleft()
             self._acquire_engine()
             try:
-                self._start_request(req)
+                if telemetry.ACTIVE:
+                    # The request's `admit` span (ISSUE 25): lexical on
+                    # the loop thread, caused by — and in the trace of
+                    # — the request's own span; admission's dispatches
+                    # parent under it.
+                    with telemetry.span(
+                            "admit", parent=req.tele_ctx,
+                            session=req.session,
+                            engine=self._tname) as admit:
+                        self._start_request(req, admit)
+                else:
+                    self._start_request(req)
             except Exception as e:  # noqa: BLE001 — per-request contain
                 if self._requeue_on_exhaustion(req, e):
                     return
@@ -1247,7 +1304,7 @@ class SessionScheduler:
             self._spillable_sessions(exclude={req.session}),
             reason="pressure", want_pages=need)
 
-    def _start_request(self, req: _Request) -> None:
+    def _start_request(self, req: _Request, admit=None) -> None:
         """Admission: the engine's own pre-decode phase
         (InferenceEngine._prepare_batch — reuse-plan → intra-session
         prefix share → chunked prefill → first-token sample; ONE
@@ -1256,6 +1313,7 @@ class SessionScheduler:
         against eviction. Loop-thread only (single-writer counter
         bumps need no cv — RT-LOCK-BUMP contract)."""
         engine = self.engine
+        sync_before = self._clock.seconds["admit_sync"]
         # Admission STARTS the request's clock (queue time is bounded
         # separately in _admit_queued): the scheduler-side deadline and
         # the waiter's anti-wedge bound both key off this moment.
@@ -1401,6 +1459,20 @@ class SessionScheduler:
         self._bump("admitted")
         if deferred:
             self._bump("ragged_joins")
+        if admit is not None:
+            # Counts at the boundary where the work happened: the
+            # prompt tokens this admission has to prefill (in its
+            # prologue, or — `deferred` — as chunks of ragged segments)
+            # and those it found cached, and how long it stood blocked
+            # in host_sync.
+            admit.attrs.update(
+                rows=len(rows), deferred=deferred,
+                prefill_tokens=stats.prefill_tokens,
+                reused_tokens=stats.reused_tokens,
+                prefix_reused_tokens=stats.prefix_reused_tokens,
+                queue_wait_s=round(req.admitted_at - req.enqueued, 6),
+                sync_s=round(self._clock.seconds["admit_sync"]
+                             - sync_before, 6))
         if telemetry.ACTIVE:
             # The request's "turn" span: lives across segments (ended at
             # retire/fail), parented to the SUBMITTER's trace so spans
@@ -1426,6 +1498,8 @@ class SessionScheduler:
         (material wherever the host is slow). The mini-loop exits
         whenever the batch must recompose (join pending, a request fully done,
         budgets/deadline/drain) and _tick takes over."""
+        clock = self._clock
+        clock.mark("build")
         ctx = self._build_batch(live)
         # The clock starts BEFORE the first dispatch (ISSUE 9 perfmodel
         # satellite): on synchronous backends the jit call itself runs
@@ -1440,6 +1514,7 @@ class SessionScheduler:
             self._handle_segment_failure(live, e)
             return
         while True:
+            clock.mark("build")
             spec_ctx = spec_handles = spec_err = None
             if self._may_speculate(ctx):
                 spec_ctx = self._advance(ctx, handles)
@@ -1450,18 +1525,24 @@ class SessionScheduler:
                     # first so host state is consistent, THEN ladder
                     # the speculative dispatch's failure.
                     spec_err = e
+            clock.mark("accept")
             alive = [r for r in ctx["rows"] if not r.done]
             counts = self._account_segment(alive)
+            # Scheduler-side "segment" span (sink-less: it spans
+            # SEVERAL sessions' traces, so it lands in the flight
+            # recorder ring rather than any one session's JSONL). Its
+            # stretch is the blocking read; the fold's counts ride it.
+            seg = self._open_segment("plain", len(alive),
+                                     int(ctx["last_d"].shape[0]))
             try:
-                # Scheduler-side "segment" span (sink-less: it spans
-                # SEVERAL sessions' traces, so it lands in the flight
-                # recorder ring rather than any one session's JSONL).
-                with telemetry.span("segment", engine=self._tname,
-                                    rows=len(alive), scheduled=True):
-                    steps = self._read_segment(ctx, handles)
+                arrays = self._sync_segment(ctx, handles)
             except Exception as e:  # noqa: BLE001 — preempt-isolate
+                seg.end(f"error:{type(e).__name__}")
                 self._handle_segment_failure(alive, e)
                 return
+            seg.leave()
+            steps = self._fold_segment(ctx, arrays)
+            self._end_segment(seg, steps, steps * len(alive))
             now = time.monotonic()
             self._attribute_wall(counts, now - t_prev)
             # Per-phase token split (ISSUE 8): a while-loop segment is
@@ -1487,6 +1568,41 @@ class SessionScheduler:
             if spec_handles is None:
                 return
             ctx, handles = spec_ctx, spec_handles
+
+    def _open_segment(self, kind: str, rows: int, size: int):
+        """Open (and enter) a scheduler `segment` span of `kind`
+        plain | ragged | spec; `size` is its program's padded batch
+        (plain) or flat-buffer shape, which with the kind gives the
+        compile watch's label of that program. Unarmed: the null span —
+        positional arguments only, so nothing is built for it."""
+        if not telemetry.ACTIVE:
+            return telemetry.NULL_SPAN
+        if kind != "plain":
+            label = f"ragged[t={size}]"
+        elif self.engine.kv_layout == "paged":
+            label = f"decode[b={size},paged]"
+        else:
+            label = f"decode[b={size}]"
+        seg = telemetry.start_span(
+            "segment", engine=self._tname, rows=rows, scheduled=True,
+            kind=kind, label=label, tick=self._clock.tick)
+        seg.__enter__()
+        return seg          # every runner ends it (_end_segment)
+
+    def _end_segment(self, seg, steps: int, decode_tokens: int,
+                     prefill_tokens: int = 0, drafted: int = 0,
+                     accepted: int = 0) -> None:
+        """Emit a segment span with the counts its fold produced, and
+        the pool's pages in use at its end (the pool's peak over any
+        stretch is the maximum over that stretch's segment spans)."""
+        if seg is telemetry.NULL_SPAN:
+            return
+        seg.attrs.update(steps=steps, decode_tokens=decode_tokens,
+                         prefill_tokens=prefill_tokens, drafted=drafted,
+                         accepted=accepted)
+        if self.engine.kv_layout == "paged":
+            seg.attrs["pages_in_use"] = self.engine.kv.pages_in_use()
+        seg.end()
 
     # --- the ragged mixed segment (ISSUE 8) ---
 
@@ -1564,6 +1680,7 @@ class SessionScheduler:
         every boundary."""
         engine = self.engine
         budget_slots = engine.ragged_tokens
+        self._clock.mark("build")
         # A leader that finished its span in the previous dispatch
         # unblocks its laggards BEFORE packing, so their chunks join
         # this very segment.
@@ -1634,18 +1751,19 @@ class SessionScheduler:
             page_size=engine.kv.page_size)
 
         t0 = time.monotonic()
+        seg = self._open_segment("ragged", len(seqs), shape)
         try:
-            with telemetry.span("segment", engine=self._tname,
-                                rows=len(seqs), scheduled=True,
-                                ragged=True):
-                handles = run_dispatch(
-                    lambda: engine._ragged_dispatch(batch),
-                    engine.retry, deadline, budget=seg_budget)
-                nxt = host_sync(lambda: np.asarray(handles), seg_budget,
-                                "decode")
+            handles = run_dispatch(
+                lambda: engine._ragged_dispatch(batch),
+                engine.retry, deadline, budget=seg_budget)
+            nxt = host_sync(lambda: np.asarray(handles), seg_budget,
+                            "decode")
         except Exception as e:  # noqa: BLE001 — preempt-isolate ladder
+            seg.end(f"error:{type(e).__name__}")
             self._handle_ragged_failure(live, filling, e)
             return
+        seg.leave()
+        self._clock.mark("accept")
         wall = time.monotonic() - t0
 
         eos = engine.tokenizer.eos_id
@@ -1698,6 +1816,7 @@ class SessionScheduler:
         telemetry.inc("roundtable_sched_ragged_segments_total",
                       engine=self._tname)
         self._note_segment_tokens(n_prefill, n_decode)
+        self._end_segment(seg, 1, n_decode, n_prefill)
         occ = len(seqs)
         self.max_occupancy = max(self.max_occupancy, occ)
         with self._cv:
@@ -1921,6 +2040,7 @@ class SessionScheduler:
         returned, the preempt-isolate ladder re-dispatches from intact
         host state)."""
         engine = self.engine
+        self._clock.mark("build")
         reqs = self._reqs_of(live)
         remaining = min((req.turn_budget.remaining() for req in reqs),
                         default=float("inf"))
@@ -2036,24 +2156,25 @@ class SessionScheduler:
             copy_slots=engine.spec_copy_slots)
 
         t0 = time.monotonic()
+        seg = self._open_segment("spec", len(seqs), shape)
         try:
-            with telemetry.span("segment", engine=self._tname,
-                                rows=len(seqs), scheduled=True,
-                                spec=True):
-                handles = run_dispatch(
-                    lambda: engine._ragged_dispatch(batch),
-                    engine.retry, deadline, budget=seg_budget)
-                nxt = host_sync(lambda: np.asarray(handles), seg_budget,
-                                "decode")
+            handles = run_dispatch(
+                lambda: engine._ragged_dispatch(batch),
+                engine.retry, deadline, budget=seg_budget)
+            nxt = host_sync(lambda: np.asarray(handles), seg_budget,
+                            "decode")
         except Exception as e:  # noqa: BLE001 — preempt-isolate ladder
             # Indistinguishable from a decode failure: host state is
             # untouched (the drafts are discarded with the dispatch and
             # the loaned pages return to the free list), so the ragged
             # failure path's donation-death check + per-session
             # re-dispatch applies verbatim.
+            seg.end(f"error:{type(e).__name__}")
             return_all_loans()
             self._handle_ragged_failure(live, [], e)
             return True
+        seg.leave()
+        self._clock.mark("accept")
         wall = time.monotonic() - t0
 
         eos = engine.tokenizer.eos_id
@@ -2176,6 +2297,7 @@ class SessionScheduler:
         telemetry.inc("roundtable_sched_spec_segments_total",
                       engine=self._tname)
         self._note_segment_tokens(0, n_emit)
+        self._end_segment(seg, 1, n_emit, 0, drafted_tot, accepted_tot)
         occ = len(seqs)
         self.max_occupancy = max(self.max_occupancy, occ)
         with self._cv:
@@ -2481,20 +2603,28 @@ class SessionScheduler:
         return nxt
 
     def _read_segment(self, ctx: dict, handles) -> int:
-        """Host-read one segment's results (through the watchdog seam —
-        this is where a wedged program freezes the host) and fold them
-        into the rows' host state. Returns the steps the segment
-        actually took (the roofline sample's token count)."""
+        """Host-read one segment's results and fold them into the rows'
+        host state. Returns the steps the segment actually took (the
+        roofline sample's token count)."""
+        return self._fold_segment(ctx, self._sync_segment(ctx, handles))
+
+    def _sync_segment(self, ctx: dict, handles) -> tuple:
+        """The blocking half of a segment's read, through the watchdog
+        seam — this is where a wedged program freezes the host, and
+        where the loop clock reads `sync`."""
         out, steps, l2, v2, d2 = handles
-        plan = ctx["plan"]
 
         def read():
             n = int(steps)  # forces completion of the segment
             return (n, np.asarray(out)[:, :n], np.asarray(l2),
                     np.asarray(v2), np.asarray(d2))
 
-        n, out_np, last_np, valid_np, done_np = host_sync(
-            read, ctx["seg_budget"], "decode")
+        return host_sync(read, ctx["seg_budget"], "decode")
+
+    def _fold_segment(self, ctx: dict, arrays: tuple) -> int:
+        """The host half: fold what _sync_segment read into the rows."""
+        n, out_np, last_np, valid_np, done_np = arrays
+        plan = ctx["plan"]
         if plan is not None:
             out_np = out_np[plan.pos]
             last_np = last_np[plan.pos]

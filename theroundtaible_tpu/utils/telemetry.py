@@ -10,7 +10,7 @@ first-class subsystem feeding live dashboards and postmortems alike
 xprof-aligned annotations (arxiv 2605.25645). This module is that
 spine; the existing surfaces publish through it and become views.
 
-Three pieces:
+Four pieces:
 
 - **Span tracer** — explicit spans mirroring the PR-2 Budget tree
   (`discussion → round → turn → prefill|decode → segment → dispatch`)
@@ -19,13 +19,18 @@ Three pieces:
   scheduler thread) hand a `current_context()` dict across and attach
   it with `attached(ctx)`. Finished spans append to the per-session
   JSONL sink riding the span tree (root spans carry it; children
-  inherit) and into the flight recorder; while a jax profiler trace is
-  armed (`maybe_profile` → `set_profiling`), each span also opens a
-  `jax.profiler.TraceAnnotation` so xprof timelines and JSONL spans
-  line up on the same names. Disarmed, `span()` returns a no-op
-  singleton behind the same module-flag pattern as `deadlines.ACTIVE`
-  / `faults.ARMED` — hot call sites additionally pre-guard with
-  `if telemetry.ACTIVE:`.
+  inherit), into the flight recorder, and into the armed span buffer
+  (`spans_between`: records carry `t0` on time.monotonic(), the clock
+  a benchmark reads its window on); while a jax profiler trace is
+  armed (`maybe_profile` → `set_profiling`), each LEXICAL span also
+  opens a `jax.profiler.TraceAnnotation` so xprof timelines and JSONL
+  spans line up on the same names (held spans do not: see `Span`).
+  Disarmed, `span()` returns a no-op singleton behind the same
+  module-flag pattern as `deadlines.ACTIVE` / `faults.ARMED` — hot
+  call sites additionally pre-guard with `if telemetry.ACTIVE:`.
+- **Loop clock** — `LoopClock`: which phase a loop thread (the session
+  scheduler's) is in at every instant; lifetime seconds per phase
+  always, one `loop.<phase>` span per stretch while armed.
 - **Metrics registry** — process-wide counters/gauges/histograms
   (decode tok/s, queue wait, batch occupancy, pages held, breaker
   state, hang/fault/fallback counts) with `snapshot()` for embedding
@@ -77,13 +82,31 @@ TRACE_RUNGS = ("profile", "request", "resume", "discussion", "round",
 
 _INF = float("inf")
 
+# While armed, every finished span also lands in one in-memory buffer,
+# on the clock a benchmark reads its window on (`t0` is
+# time.monotonic()): `spans_between(t_a, t_b)` is how a per-layer reader
+# takes a traced slice's spans without a snapshot of any lifetime
+# total. Bounded — the oldest record goes first and is counted.
+SPAN_BUFFER_CAPACITY = 65536
+_span_buffer: deque = deque(maxlen=SPAN_BUFFER_CAPACITY)
+_span_buffer_dropped = 0
+
 
 def arm() -> None:
-    global ACTIVE
+    """Arm the span tracer. Going from disarmed to armed opens a FRESH
+    span buffer (the last armed stretch's records stay readable only
+    until then); arming twice keeps what the first arming gathered."""
+    global ACTIVE, _span_buffer, _span_buffer_dropped
+    if not ACTIVE:
+        with _spans_lock:
+            _span_buffer = deque(maxlen=SPAN_BUFFER_CAPACITY)
+            _span_buffer_dropped = 0
     ACTIVE = True
 
 
 def disarm() -> None:
+    """Stop tracing. The span buffer stays readable until the next
+    arm() — a benchmark disarms first and reads afterwards."""
     global ACTIVE
     ACTIVE = False
 
@@ -487,6 +510,32 @@ def reset_spans_emitted() -> None:
         _spans_emitted = 0
 
 
+def _keep_span(record: dict) -> None:
+    """A finished span's record into the armed buffer, and the count of
+    spans emitted — one critical section for both."""
+    global _spans_emitted, _span_buffer_dropped
+    with _spans_lock:
+        _spans_emitted += 1
+        if len(_span_buffer) == _span_buffer.maxlen:
+            _span_buffer_dropped += 1
+        _span_buffer.append(record)
+
+
+def spans_between(t_a: float, t_b: float) -> list[dict]:
+    """The buffered records of spans that STARTED in [t_a, t_b), both
+    read on time.monotonic() (a record's `t0`). A span is buffered when
+    it ends, so one still open is not there yet."""
+    with _spans_lock:
+        records = list(_span_buffer)
+    return [r for r in records if t_a <= r["t0"] < t_b]
+
+
+def spans_dropped() -> int:
+    """Records the bounded buffer has pushed out since it was opened:
+    a reader that finds this above zero does not have every span."""
+    return _span_buffer_dropped
+
+
 def _stack() -> list:
     stack = getattr(_tls, "stack", None)
     if stack is None:
@@ -519,14 +568,56 @@ def session_sink(session_path) -> SpanSink:
                                  "spans.jsonl"))
 
 
+def _open_annotation(name: str):
+    """Mirror a span into the device profile as `rt:<name>`: xprof rows
+    named like the JSONL rungs. Lazy import; any failure silently drops
+    the mirror (profiling is best-effort by standing contract)."""
+    try:
+        import jax
+        annotation = jax.profiler.TraceAnnotation(f"rt:{name}")
+        annotation.__enter__()
+        return annotation
+    except Exception:  # noqa: BLE001 — mirror is best-effort
+        return None
+
+
+def _close_annotation(annotation) -> None:
+    try:
+        annotation.__exit__(None, None, None)
+    except Exception:  # noqa: BLE001
+        pass
+
+
+def _span_record(trace_id: str, span_id: str, parent_id: str, rung: str,
+                 wall0: float, t0: float, dur_s: float, status: str,
+                 attrs: dict) -> dict:
+    """A finished span as it is written everywhere: `start` on the
+    wall clock, `t0` on time.monotonic()."""
+    record = {
+        "trace_id": trace_id, "span_id": span_id, "parent_id": parent_id,
+        "rung": rung, "start": round(wall0, 6), "t0": t0,
+        "dur_s": round(dur_s, 6), "status": status,
+    }
+    if attrs:
+        record["attrs"] = attrs
+    return record
+
+
 class Span:
     """One span of the trace tree. Context manager for the common
     same-thread case; `start_span()`/`.end()` for holders that outlive
-    a lexical scope (the scheduler's per-request turn spans)."""
+    a lexical scope (the scheduler's per-request turn spans).
+
+    Only a LEXICAL span (entered with `with`) mirrors into the device
+    profile: a profiler annotation is meant to nest on its thread, and
+    a span held across scheduler ticks (`request`, `resume`, `turn`)
+    would lie open over every instant in which any row is live and win
+    every idle gap no inner span covers. Held spans keep their records,
+    ring entries and ids."""
 
     __slots__ = ("rung", "trace_id", "span_id", "parent_id", "attrs",
                  "sink", "t0", "_wall0", "status", "_annotation",
-                 "_on_stack")
+                 "_on_stack", "_dur")
 
     def __init__(self, rung: str, trace_id: str, parent_id: str,
                  sink: Optional[SpanSink], attrs: dict[str, Any]):
@@ -541,17 +632,7 @@ class Span:
         self.status = "ok"
         self._annotation = None
         self._on_stack = False
-        if _PROFILING:
-            # Mirror into the device profile: xprof rows named like the
-            # JSONL rungs. Lazy import; any failure silently drops the
-            # mirror (profiling is best-effort by standing contract).
-            try:
-                import jax
-                self._annotation = jax.profiler.TraceAnnotation(
-                    f"rt:{rung}")
-                self._annotation.__enter__()
-            except Exception:  # noqa: BLE001 — mirror is best-effort
-                self._annotation = None
+        self._dur: Optional[float] = None
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -561,6 +642,8 @@ class Span:
     def __enter__(self) -> "Span":
         _stack().append(self)
         self._on_stack = True
+        if _PROFILING:
+            self._annotation = _open_annotation(self.rung)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -569,9 +652,15 @@ class Span:
         self.end()
         return False
 
-    def end(self, status: Optional[str] = None) -> None:
-        if status is not None:
-            self.status = status
+    def leave(self) -> None:
+        """End the span's STRETCH — off the stack, the profiler mirror
+        closed, the duration fixed — without emitting it yet: a later
+        end() writes the record, so counts that are only known after
+        the timed stretch (what a segment's accept walk committed) ride
+        the span they belong to without stretching it over that work."""
+        if self._dur is not None:
+            return
+        self._dur = time.monotonic() - self.t0
         if self._on_stack:
             stack = _stack()
             if stack and stack[-1] is self:
@@ -580,22 +669,16 @@ class Span:
                 stack.remove(self)
             self._on_stack = False
         if self._annotation is not None:
-            try:
-                self._annotation.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass
+            _close_annotation(self._annotation)
             self._annotation = None
-        record = {
-            "trace_id": self.trace_id,
-            "span_id": self.span_id,
-            "parent_id": self.parent_id,
-            "rung": self.rung,
-            "start": round(self._wall0, 6),
-            "dur_s": round(time.monotonic() - self.t0, 6),
-            "status": self.status,
-        }
-        if self.attrs:
-            record["attrs"] = self.attrs
+
+    def end(self, status: Optional[str] = None) -> None:
+        if status is not None:
+            self.status = status
+        self.leave()
+        record = _span_record(self.trace_id, self.span_id, self.parent_id,
+                              self.rung, self._wall0, self.t0, self._dur,
+                              self.status, self.attrs)
         if self.sink is not None:
             self.sink.write(record)
         ring = {"rung": self.rung, "trace_id": self.trace_id,
@@ -606,9 +689,7 @@ class Span:
                     v, (str, int, float, bool)):
                 ring.setdefault(k, v)
         recorder().record("span", **ring)
-        global _spans_emitted
-        with _spans_lock:
-            _spans_emitted += 1
+        _keep_span(record)
 
 
 class _NullSpan:
@@ -629,11 +710,14 @@ class _NullSpan:
     def set_attr(self, key, value):
         pass
 
+    def leave(self):
+        pass
+
     def end(self, status=None):
         pass
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class _AttachedContext:
@@ -698,7 +782,7 @@ def span(rung: str, sink: Optional[SpanSink] = None,
     else this thread's innermost span; roots mint a fresh trace id.
     `sink` overrides the inherited JSONL sink (roots set it)."""
     if not ACTIVE:
-        return _NULL_SPAN
+        return NULL_SPAN
     return start_span(rung, sink=sink, parent=parent, **attrs)
 
 
@@ -719,6 +803,129 @@ def start_span(rung: str, sink: Optional[SpanSink] = None,
         inherited = top.sink if top else None
     return Span(rung, trace_id, parent_id,
                 sink if sink is not None else inherited, attrs)
+
+
+def emit_span(rung: str, dur_s: float, **attrs) -> None:
+    """Record a span that has just ENDED and took `dur_s` — for work
+    that is only known once it is over (a compile, reported by JAX's
+    monitoring hook with its duration). Parented to this thread's
+    innermost span; it goes to that span's sink and to the armed
+    buffer, not to the flight ring (its caller records the event
+    there) and not to the profiler, which has its own rows for such
+    work. Call sites pre-guard with `if telemetry.ACTIVE:`."""
+    stack = _stack()
+    top = stack[-1] if stack else None
+    record = _span_record(
+        top.trace_id if top else uuid.uuid4().hex[:16],
+        uuid.uuid4().hex[:12], top.span_id if top else "", rung,
+        time.time() - dur_s, time.monotonic() - dur_s, dur_s, "ok", attrs)
+    if top is not None and top.sink is not None:
+        top.sink.write(record)
+    _keep_span(record)
+
+
+# ---------------------------------------------------------------------------
+# loop clock
+# ---------------------------------------------------------------------------
+
+
+class LoopClock:
+    """Which phase one loop thread is in, at every instant.
+
+    The idiom of `tracing.RequestTrace.stage()`: a mark attributes the
+    time since the previous mark, so the phases telescope to the
+    thread's wall by construction — no instant lies under two phases
+    or under none. The owner (the session scheduler) marks its own
+    phases with `mark()`; the two seams every dispatch and every
+    blocking read pass through (`serving_loop.run_dispatch`,
+    `host_sync`) find the clock of their thread with `loop_clock()`,
+    `switch()` to their phase and `mark()` back, so they need to know
+    nothing of the loop that called them. `within` renames a seam's
+    phase by the phase it is entered from (a blocking read inside
+    admission is `admit_sync`, not `sync`).
+
+    Always on: `seconds`, per-phase lifetime totals (a clock read and
+    a float add per mark). Armed (`ACTIVE`): each stretch of a phase is
+    also a span record `loop.<phase>` carrying the tick's index — in
+    the armed buffer only: ten a tick would push the flight ring's
+    request and segment spans out — and, while a profile is taken, an
+    `rt:loop.<phase>` annotation on the loop's thread. The stretches
+    lie end to end, each starting on the clock read that ended the one
+    before, so clipped to any stretch of time they sum to it."""
+
+    __slots__ = ("seconds", "phase", "tick", "_within", "_last",
+                 "_attrs", "_trace_id", "_open")
+
+    def __init__(self, phases: tuple[str, ...], start: str,
+                 within: Optional[dict[str, dict[str, str]]] = None,
+                 **attrs) -> None:
+        self.seconds: dict[str, float] = dict.fromkeys(phases, 0.0)
+        self.phase = start
+        self.tick = 0
+        self._within = within or {}
+        self._last = time.monotonic()
+        self._attrs = attrs
+        self._trace_id = uuid.uuid4().hex[:16]
+        # The open stretch's span: (phase, tick, t0, wall0, mirror).
+        self._open: Optional[tuple] = None
+
+    def mark(self, phase: str) -> None:
+        """Everything since the last mark was the phase that was open;
+        from here on it is `phase`."""
+        now = time.monotonic()
+        self.seconds[self.phase] += now - self._last
+        self._last = now
+        changed = phase != self.phase
+        self.phase = phase
+        if self._open is None:
+            if ACTIVE:              # armed since the last mark, or new
+                self._respan(now)
+        elif changed or not ACTIVE:
+            self._respan(now)
+
+    def switch(self, phase: str) -> str:
+        """A seam's mark: enter `phase` (as `within` renames it for the
+        phase it is entered from); → the phase to mark() back to."""
+        prev = self.phase
+        renames = self._within.get(prev)
+        self.mark(renames.get(phase, phase) if renames else phase)
+        return prev
+
+    def snapshot(self) -> dict[str, float]:
+        """Per-phase lifetime seconds, the open phase's counted up to
+        now — read from other threads (describe()); a read that races
+        a mark is off by that one lap at most."""
+        out = dict(self.seconds)
+        out[self.phase] += max(time.monotonic() - self._last, 0.0)
+        return {k: round(v, 6) for k, v in out.items()}
+
+    def _respan(self, now: float) -> None:
+        """Close the open stretch's span at `now` and, while armed,
+        open the new phase's at the same instant."""
+        if self._open is not None:
+            phase, tick, t0, wall0, mirror = self._open
+            self._open = None
+            if mirror is not None:
+                _close_annotation(mirror)
+            _keep_span(_span_record(
+                self._trace_id, uuid.uuid4().hex[:12], "",
+                "loop." + phase, wall0, t0, now - t0, "ok",
+                dict(self._attrs, tick=tick)))
+        if ACTIVE:
+            self._open = (
+                self.phase, self.tick, now, time.time(),
+                _open_annotation("loop." + self.phase)
+                if _PROFILING else None)
+
+
+def bind_loop_clock(clock: Optional[LoopClock]) -> None:
+    """Make `clock` the calling thread's loop clock (None unbinds)."""
+    _tls.clock = clock
+
+
+def loop_clock() -> Optional[LoopClock]:
+    """The calling thread's loop clock, if it runs a clocked loop."""
+    return getattr(_tls, "clock", None)
 
 
 # ---------------------------------------------------------------------------
@@ -807,6 +1014,11 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
                          "THIS scheduler's share)",
         "journal_errors": "roundtable_journal_errors_total "
                           "(same per-scheduler split)",
+        # ISSUE 25: the loop clock — which phase the scheduler's thread
+        # was in, lifetime seconds per phase; the series moves at the
+        # end of every tick by what the clock gained since the last.
+        "loop_seconds": "roundtable_sched_loop_seconds_total"
+                        "{phase=...}",
         "events": "flight recorder ring (sched_* kinds)",
     },
     # engine.describe()["spec_decode"] (ISSUE 9 + 13): the speculation
