@@ -397,6 +397,159 @@ class TestEngineGenerate:
         assert _bucket(9999) == 2048
 
 
+# greedy / sampled x B = 1 / 3: the cases of every first-token test.
+FIRST_TOKEN_CASES = [pytest.param(temp, b, id=f"{mode}-b{b}")
+                     for mode, temp in (("greedy", 0.0), ("sampled", 0.7))
+                     for b in (1, 3)]
+
+
+def _first_token_compiles(b):
+    """Lifetime compiles under the first-token program's label (the
+    registry's counter: compile_watch.history() is a capped ring)."""
+    from theroundtaible_tpu.utils import telemetry
+    return telemetry.REGISTRY.counter_total(
+        "roundtable_compiles_total", label=f"prefill[b={b},first_token]")
+
+
+def _prologue_engine(temp, seed=0):
+    return InferenceEngine(
+        get_model_config("tiny-gemma", max_seq_len=256), num_slots=4,
+        kv_layout="paged", seed=seed,
+        sampling=SamplingParams(temperature=temp, max_new_tokens=8))
+
+
+class TestFirstTokenProgram:
+    """The prologue samples its first token inside jit (ISSUE 26): one
+    compiled program per ([B, V], greedy) and one blocking read, where
+    the eager sampler was fifty dispatches and a recompile of its
+    lax.cond on every call."""
+
+    @pytest.fixture(scope="class")
+    def engines(self):
+        return {temp: _prologue_engine(temp) for temp in (0.0, 0.7)}
+
+    def _prologue(self, engine, b, rep):
+        import time
+
+        from theroundtaible_tpu.engine import deadlines
+        turns = [(f"ft{rep}_{i}", f"knight {i} speaks in round {rep}")
+                 for i in range(b)]
+        budget = deadlines.Budget.root(120.0, rung="turn")
+        try:
+            return engine._prepare_batch(
+                turns, 64, time.monotonic() + 120.0,
+                budget.child("prefill"))
+        finally:
+            for name, _ in turns:
+                engine.kv.release(name)
+
+    @pytest.mark.parametrize("temp,b", FIRST_TOKEN_CASES)
+    def test_second_prologue_of_a_shape_compiles_nothing(self, engines,
+                                                         temp, b):
+        from theroundtaible_tpu.engine import compile_watch
+        engine = engines[temp]
+        before = _first_token_compiles(b)
+        # Twice: the prefill step meets its donated pool's layout.
+        for rep in range(2):
+            self._prologue(engine, b, rep)
+        assert _first_token_compiles(b) - before == 1
+        seen = compile_watch.compiles_seen()
+        prep = self._prologue(engine, b, 2)
+        assert compile_watch.compiles_seen() == seen
+        assert prep["first_np"].shape == (b,)
+        assert prep["first_np"].dtype == np.int32
+
+    def test_warmup_compiles_the_program_once_a_batch_size(
+            self, monkeypatch):
+        """warmup() walks the prologue for every batch size it warms;
+        after it a prologue of a warmed shape compiles nothing, which
+        the STRICT sentinel would turn into an error."""
+        from theroundtaible_tpu.engine import compile_watch
+        engine = _prologue_engine(0.7)
+        before = {b: _first_token_compiles(b) for b in (1, 2)}
+        engine.warmup(max_prompt_tokens=64, batch_sizes=(1, 2))
+        for b in (1, 2):
+            assert _first_token_compiles(b) - before[b] == 1
+        monkeypatch.setenv(compile_watch.STRICT_ENV, "1")
+        try:
+            seen = compile_watch.compiles_seen()
+            engine.generate_batch([("wa", "a warmed shape"),
+                                   ("wb", "another knight")],
+                                  max_new_tokens=2)
+            assert compile_watch.compiles_seen() == seen
+        finally:
+            compile_watch.reopen_warmup(engine.cfg.name)
+
+    @pytest.mark.parametrize("temp,b", FIRST_TOKEN_CASES)
+    def test_program_matches_the_eager_sampler(self, engines, temp, b):
+        from theroundtaible_tpu.engine.sampling import (sample_token_batch,
+                                                        sampling_arrays)
+        rng = np.random.default_rng(100 * b + int(10 * temp))
+        logits = jnp.asarray(rng.normal(size=(b, 640)) * 3, jnp.bfloat16)
+        arrays = sampling_arrays([SamplingParams(temperature=temp)] * b)
+        for seed in (3, 5):
+            key = jax.random.PRNGKey(seed)
+            got = engines[temp]._first_token(logits, key, *arrays,
+                                             greedy=temp <= 0.0)
+            f32 = logits.astype(jnp.float32)
+            want = (jnp.argmax(f32, axis=-1) if temp <= 0.0
+                    else sample_token_batch(f32, key, *arrays))
+            assert got.dtype == jnp.int32
+            assert got.tolist() == want.tolist()
+
+    def test_program_matches_the_eager_sampler_on_a_mixed_batch(
+            self, engines):
+        """One greedy row, one top_k row and one top_p < 1 row."""
+        from theroundtaible_tpu.engine.sampling import (sample_token_batch,
+                                                        sampling_arrays)
+        rng = np.random.default_rng(41)
+        logits = jnp.asarray(rng.normal(size=(3, 640)) * 3, jnp.float32)
+        arrays = sampling_arrays([
+            SamplingParams(temperature=0.0),
+            SamplingParams(temperature=0.9, top_k=5),
+            SamplingParams(temperature=1.1, top_p=0.7)])
+        for seed in range(8):
+            key = jax.random.PRNGKey(seed)
+            got = engines[0.7]._first_token(logits, key, *arrays,
+                                            greedy=False)
+            want = sample_token_batch(logits, key, *arrays)
+            assert got.tolist() == want.tolist(), seed
+        assert got.tolist()[0] == int(jnp.argmax(logits[0]))
+
+    @pytest.mark.parametrize("temp,b", FIRST_TOKEN_CASES)
+    def test_scheduler_admission_and_generate_batch_agree(self, temp, b):
+        """An admission into an empty batch and generate_batch run the
+        one prologue: same prompts, same seed, same first tokens — each
+        from one call of the program. Sampled rows run hot, so that a
+        key out of step would show."""
+        from theroundtaible_tpu.engine.scheduler import SessionScheduler
+
+        def recording(engine):
+            program, calls = engine._first_token, []
+
+            def spy(*args, **kw):
+                calls.append(program(*args, **kw))
+                return calls[-1]
+            engine._first_token = spy
+            return engine, calls
+
+        temp = temp and 8.0
+        turns = [(f"k{i}", f"knight {i} opens the round with a claim")
+                 for i in range(b)]
+        engine, direct_calls = recording(_prologue_engine(temp, seed=7))
+        direct, _ = engine.generate_batch_with_stats(
+            turns, max_new_tokens=1, session="s")
+        engine, sched_calls = recording(_prologue_engine(temp, seed=7))
+        sched = SessionScheduler(engine)
+        try:
+            scheduled, _ = sched.submit("s", turns, max_new_tokens=1)
+        finally:
+            sched.close()
+        assert len(direct_calls) == len(sched_calls) == 1
+        assert sched_calls[0].tolist() == direct_calls[0].tolist()
+        assert scheduled == direct
+
+
 class TestSharedPrefix:
     """Cross-knight shared-prefix reuse (SURVEY §7.3 hard part 2,
     VERDICT r1 #3): K/V spans copied between slots instead of

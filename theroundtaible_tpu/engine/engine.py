@@ -401,6 +401,23 @@ class InferenceEngine:
 
         self._prefill_step = prefill_step
 
+        @partial(jax.jit, static_argnames=("greedy",))
+        def first_token(last_logits, key, temps, top_ks, top_ps, greedy):
+            # The prologue's first token from ONE program per ([B, V],
+            # greedy) — the same cast, argmax and sampler the decode
+            # loop's body runs. Outside jit the sampler is some fifty
+            # one-operation dispatches and its lax.cond recompiles on
+            # every call (sampling.sample_token_batch).
+            row_logits = last_logits.astype(jnp.float32)
+            if greedy:
+                nxt = jnp.argmax(row_logits, axis=-1)
+            else:
+                nxt = sample_token_batch(row_logits, key, temps, top_ks,
+                                         top_ps)
+            return host_read(nxt.astype(jnp.int32))
+
+        self._first_token = first_token
+
         def decode_while(step_fn, caches, first_token, start_valid, key,
                          budget, temps, top_ks, top_ps, row_budgets,
                          done0, max_new, greedy, lora=None):
@@ -2350,16 +2367,6 @@ class InferenceEngine:
             p_offsets = plan.scatter_list(offsets, 0)
             if p_lora is not None:
                 p_lora = plan.scatter_list(p_lora, 0)
-        last_logits = self._prefill(slot_ids, suffixes, p_offsets,
-                                    deadline=deadline, tables=tables_np,
-                                    budget=pre_budget, lora_ids=p_lora)
-        # A scalar fetch, not block_until_ready: a PJRT transport may
-        # return from block_until_ready before the computation
-        # finishes, which would blame prefill time on decode — and a
-        # blocking read, so it goes through the deadline seam (a wedged
-        # prefill program freezes the host exactly here).
-        host_sync(lambda: float(last_logits[0, 0]), pre_budget, "prefill")
-
         per_row = sampling_per_turn or [self.sampling] * len(turns)
         if len(per_row) != len(turns):
             raise ValueError(
@@ -2373,17 +2380,29 @@ class InferenceEngine:
             temps = plan.scatter_rows(temps, 1.0)
             top_ks = plan.scatter_rows(top_ks, 0)
             top_ps = plan.scatter_rows(top_ps, 1.0)
-        if greedy:
-            first = jnp.argmax(last_logits.astype(jnp.float32),
-                               axis=-1).astype(jnp.int32)
-        else:
-            first = sample_token_batch(last_logits.astype(jnp.float32),
-                                       self._next_key(), temps, top_ks,
-                                       top_ps).astype(jnp.int32)
+        # The sampler's inputs are ready BEFORE the prefill is issued
+        # (splitting the key is itself a device program), so nothing
+        # eager stands between the prefill step and the first token. A
+        # greedy batch draws no key, as before.
+        key = self._key if greedy else self._next_key()
+        last_logits = self._prefill(slot_ids, suffixes, p_offsets,
+                                    deadline=deadline, tables=tables_np,
+                                    budget=pre_budget, lora_ids=p_lora)
+        from . import compile_watch
+        with compile_watch.label(
+                f"prefill[b={last_logits.shape[0]},first_token]",
+                engine=self.cfg.name):
+            first = self._first_token(last_logits, key, temps, top_ks,
+                                      top_ps, greedy=greedy)
         if plan is not None and len(plan.pad_positions):
             # Pad rows open at eos so they are done from the first step.
             first = first.at[jnp.asarray(plan.pad_positions)].set(
                 jnp.int32(self.tokenizer.eos_id))
+        # ONE blocking read for the whole prologue: it waits on the
+        # prefill's logits, so it also pins prefill's time before decode
+        # (a PJRT transport may return from block_until_ready before the
+        # computation finishes) — and it goes through the deadline seam
+        # (a wedged prefill program freezes the host exactly here).
         first_np = host_sync(lambda: np.asarray(first), pre_budget,
                              "prefill")
         if plan is not None:

@@ -132,7 +132,10 @@ def sample_token_batch(logits: jax.Array, key: jax.Array,
     stays full-vocab under the SAME key either way. Rows the pool
     cannot prove correct (top_k > _K_CAND, or candidate mass short of
     top_p) trigger the exact full-sort tail via lax.cond — compiled
-    once, executed only when needed."""
+    once, executed only when needed. Called outside `jit` that
+    lax.cond's branches are new objects on every call, so XLA compiles
+    the conditional again every time: this function is only ever called
+    from inside a compiled program."""
     v = logits.shape[-1]
     k_cand = min(_K_CAND, v)
     greedy = jnp.argmax(logits, axis=-1)
