@@ -244,6 +244,82 @@ def test_whole_decode_step_of_the_3b_engine_compiles(one_chip,
     assert resident < 16e9, f"decode step needs {resident / 1e9:.1f} GB"
 
 
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_hybrid_step_of_the_nemotron_cut_compiles(one_chip, monkeypatch,
+                                                   program):
+    """One decode step and one ragged join of the benchmark's
+    Nemotron-3-Nano cut (the pattern's first three kinds, `ME*`, at
+    published widths, 64 of 128 experts held), as the hybrid step
+    programs wrap them: what the chip's compiler refuses of the scans,
+    the expert loop or the kernels' operands fails here, not there."""
+    from theroundtaible_tpu.engine.models import hybrid
+    from theroundtaible_tpu.engine.models.common import init_params
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+    from theroundtaible_tpu.engine.paged_forward import (
+        forward_paged_hybrid, forward_ragged_hybrid)
+    from theroundtaible_tpu.engine.serving_loop import (RAGGED_BLOCK_Q,
+                                                        RaggedSeq,
+                                                        build_ragged_batch)
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        get_model_config("nemotron-3-nano-30b-a3b"), num_layers=3,
+        layer_kinds=hybrid.kinds_of_pattern("ME*"), vocab_size=65_536,
+        experts_held=64, attn_impl="flash")
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    params = placed(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    pool = s((POOL_PAGES, PAGE, cfg.num_kv_heads, D), jnp.bfloat16)
+    pools = [(pool, pool)]
+    if program == "decode":
+        state = placed(jax.eval_shape(lambda: hybrid.zero_state(cfg, ROWS)))
+
+        def step(params, pools, state, tokens, positions, table, valid,
+                 active):
+            return forward_paged_hybrid(
+                params, cfg, tokens, positions, pools, table, valid,
+                state, active=active)
+
+        hlo = _compile(step, params, pools, state, s((ROWS, 1), i32),
+                       s((ROWS, 1), i32), s((ROWS, PAGES_PER_SEQ), i32),
+                       s((ROWS,), i32), s((ROWS,), jnp.bool_))
+    else:
+        state = placed(jax.eval_shape(
+            lambda: hybrid.zero_state(cfg, ROWS + 1)))
+        table = np.zeros((PAGES_PER_SEQ,), np.int32)
+        b = build_ragged_batch(
+            [RaggedSeq([5] * 150, 100, table), RaggedSeq([7], 300, table)],
+            t_budget=RAGGED_T, s_max=ROWS + 1,
+            pages_per_seq=PAGES_PER_SEQ, scratch_page=0, pad_id=0,
+            page_size=PAGE)
+        assert RAGGED_T % RAGGED_BLOCK_Q == 0
+        names = ("tokens", "positions", "tables", "seq_of_block",
+                 "block_qstart", "query_offsets", "kv_valid",
+                 "token_pages", "token_offs", "token_seq", "last_rows")
+
+        def step(params, pools, state, seq_slot, cap_n, *arrays):
+            kw = dict(zip(names, arrays))
+            return forward_ragged_hybrid(
+                params, cfg, kw["tokens"], kw["positions"], pools,
+                kw["tables"], kw["seq_of_block"], kw["block_qstart"],
+                kw["query_offsets"], kw["kv_valid"], kw["token_pages"],
+                kw["token_offs"], kw["token_seq"], kw["last_rows"], state,
+                seq_slot, cap_n)
+
+        hlo = _compile(step, params, pools, state, s((ROWS + 1,), i32),
+                       s((ROWS + 1,), i32),
+                       *[s(np.asarray(b[n]).shape, i32) for n in names])
+    _assert_kernel(hlo)
+
+
 # --- the int4 kernels the compiler refuses --------------------------------
 #
 # Shapes below come from a real Int4Leaf (quant.quantize_params on a

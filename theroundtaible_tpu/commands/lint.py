@@ -28,7 +28,7 @@ def _source_root() -> str:
 
 
 def _audit_findings() -> tuple[list, list[str]]:
-    """Build the two toy CPU engines and run the jaxpr audit; returns
+    """Build the three toy CPU engines and run the jaxpr audit; returns
     (findings, audited program names). Forces the CPU platform BEFORE
     first jax import — the audit is device-free by construction and
     must never touch (or wait on) a TPU."""
@@ -47,6 +47,11 @@ def _audit_findings() -> tuple[list, list[str]]:
                         spec_decode={"drafter": "ngram",
                                      "tree": {"branch": 2, "depth": 2}},
                         lora={"rank": 4, "max_adapters": 4}),
+        # Recurrent state beside the pools (models/hybrid.py): the
+        # hybrid step programs and their donated state trees.
+        InferenceEngine(get_model_config("tiny-nemotron-h"), num_slots=4,
+                        kv_layout="paged", page_size=16,
+                        mesh_shape={"data": 1, "model": 1}),
     ]
     findings, names = [], []
     for eng in engines:

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import threading
 import time
+import dataclasses
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Optional
@@ -29,7 +30,7 @@ import numpy as np
 from . import deadlines, faults
 from .kvcache import KVCache
 from .models.common import ModelConfig, forward, param_count, spmd_mesh
-from .models.registry import get_model_config
+from .models.registry import resolve_model_config
 from .sampling import SamplingParams, sample_token_batch, sampling_arrays
 from .serving_loop import (DECODE_SEGMENT, MAX_PREFILL_CHUNK,
                            PREFILL_BUCKETS, ReplicaGroupPlan,
@@ -116,7 +117,8 @@ class InferenceEngine:
                  spec_decode: Optional[bool] = None,
                  spec_max_draft: Optional[int] = None,
                  lora: Optional[dict] = None,
-                 kv_quant: Any = None):
+                 kv_quant: Any = None,
+                 state_snapshot_bytes: Optional[int] = None):
         # Multi-host: join the process group BEFORE any backend/device
         # call when ROUNDTABLE_COORDINATOR is set (engine/distributed.py);
         # jax.devices() below then spans every host's chips.
@@ -140,6 +142,42 @@ class InferenceEngine:
         self.mesh = build_mesh(mesh_shape, device_list, dcn_axis=dcn_axis)
         model_cfg = self._resolve_attn(model_cfg, attn, self.mesh)
         self.cfg = model_cfg
+        # A model with layer_kinds (models/hybrid.py) keeps state that
+        # is not pages. What cannot carry that state yet declines HERE,
+        # each with a reason describe() reports — never as a failure at
+        # trace time. What the model cannot be served without fails now.
+        self.declines: dict[str, str] = {}
+        if model_cfg.layer_kinds is not None:
+            why = "recurrent-state"
+            if kv_layout != "paged":
+                raise ValueError(
+                    f"{model_cfg.name} has layer_kinds: it serves through "
+                    "kv_layout 'paged' only (its KV pools hold the "
+                    "attention layers alone, its recurrent state lives "
+                    "beside them)")
+            if self.mesh.devices.size > 1:
+                raise ValueError(
+                    f"{model_cfg.name} has layer_kinds: a mesh over "
+                    f"{self.mesh.devices.size} devices is not supported "
+                    "yet (the state tree and the expert loop are "
+                    "single-device); give it one device")
+            if quant != "none":
+                self.declines["quant"] = f"{why}:quant-leaves"
+                quant = "none"
+            if kv_quant and kv_quant != "none":
+                self.declines["kv_quant"] = why
+                kv_quant = None
+            if seq_parallel and seq_parallel > 1:
+                self.declines["seq_parallel"] = why
+                seq_parallel = 0
+            if lora:
+                self.declines["lora"] = why
+                lora = None
+            if kv_offload:
+                self.declines["kv_offload"] = why
+            kv_offload = False
+            self.declines["spec_decode"] = why
+            spec_decode = False
         self.max_seq_len = model_cfg.max_seq_len
         self.sampling = sampling or SamplingParams()
         self.tokenizer = load_tokenizer(checkpoint or None)
@@ -227,6 +265,8 @@ class InferenceEngine:
         else:
             self.kv_quant_spec, self.kv_quant_reason = \
                 _kvq_resolve(kv_quant)
+            if "kv_quant" in self.declines:
+                self.kv_quant_reason = self.declines["kv_quant"]
 
         if kv_layout == "paged":
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -420,7 +460,8 @@ class InferenceEngine:
 
         def decode_while(step_fn, caches, first_token, start_valid, key,
                          budget, temps, top_ks, top_ps, row_budgets,
-                         done0, max_new, greedy, lora=None):
+                         done0, max_new, greedy, lora=None,
+                         pass_active=False):
             """The decode while_loop, ONCE for all three cache layouts
             (contiguous, paged gather-view, paged pool-direct) —
             `step_fn(last, valid, caches) -> (logits [B,1,V], caches)` is
@@ -454,7 +495,15 @@ class InferenceEngine:
 
             def body(state):
                 step, last, valid, done, out, caches, key = state
-                logits, caches = step_fn(last, valid, caches)
+                if pass_active:
+                    # A recurrent state must consume exactly the tokens
+                    # whose successors are real: not a finished row's
+                    # filler, not the step past a row's budget.
+                    logits, caches = step_fn(
+                        last, valid, caches,
+                        ~done & (step < row_budgets))
+                else:
+                    logits, caches = step_fn(last, valid, caches)
                 key, sub = jax.random.split(key)
                 row_logits = logits[:, 0].astype(jnp.float32)
                 if greedy:
@@ -929,6 +978,17 @@ class InferenceEngine:
 
             self._ragged_step = ragged_step
 
+        # Recurrent state beside the pools (models/hybrid.py,
+        # engine/hybrid_state.py): a model with layer_kinds serves
+        # through three programs of its own that carry a second donated
+        # tree — every slot's Mamba-2 state and the expert counters —
+        # and, where a snapshot is taken, the snapshot store.
+        self.hybrid = None
+        if model_cfg.layer_kinds is not None:
+            self._build_hybrid_programs(
+                model_cfg, mesh, host_read, decode_while, num_slots,
+                page_size, state_snapshot_bytes)
+
         # Speculative decoding (ISSUE 9): self-drafting verify folded
         # into the scheduler's ragged segment loop. The verify dispatch
         # IS a ragged dispatch (a draft run is a short multi-token row
@@ -982,6 +1042,10 @@ class InferenceEngine:
             else None
         if kv_layout != "paged":
             self.spec_reason = "kv_layout:contiguous"
+        elif "spec_decode" in self.declines:
+            # A rejected draft cannot be un-consumed from a recurrent
+            # state: chain, tree and draft rows all decline.
+            self.spec_reason = self.declines["spec_decode"]
         elif not spec_enabled(spec_decode):
             self.spec_reason = "disabled:config/env"
         elif not self.ragged_enabled:
@@ -1019,7 +1083,9 @@ class InferenceEngine:
         # tagged _einsum sites short-circuit on the inert scope).
         from .lora import (DEFAULT_MAX_ADAPTERS, DEFAULT_RANK,
                            DEFAULT_SCALE, LoraStore, lora_enabled)
-        if not lora:
+        if "lora" in self.declines:
+            self.lora_reason = self.declines["lora"]
+        elif not lora:
             self.lora_reason = "disabled:config"
         elif not lora_enabled(lora):
             self.lora_reason = "disabled:env"
@@ -1063,6 +1129,122 @@ class InferenceEngine:
             except Exception as e:  # noqa: BLE001 — degrade, record
                 self.spec_drafter_reason = (
                     f"{self.spec_options.drafter}:{str(e)[:120]}")
+
+    def _build_hybrid_programs(self, cfg, mesh, host_read, decode_while,
+                               num_slots, page_size,
+                               state_snapshot_bytes) -> None:
+        """The step programs of a model with layer_kinds, and its state
+        store. Same four seams as every model (prefill_step,
+        decode_loop, ragged_step; first_token is shared as is), with
+        the slot states donated beside the pools."""
+        from .hybrid_state import HybridStateStore
+        from .paged_forward import (forward_paged_hybrid,
+                                    forward_ragged_hybrid)
+        if not self.paged_direct:
+            raise ValueError(
+                f"{cfg.name} has layer_kinds: it serves pool-direct only "
+                "(attn must not be 'dense', and the page and head "
+                "shapes must suit the paged kernels)")
+        if state_snapshot_bytes is None:
+            # Default: room for four snapshots a slot.
+            from .models.hybrid import state_bytes_per_sequence
+            state_snapshot_bytes = (4 * num_slots
+                                    * state_bytes_per_sequence(cfg))
+        self.hybrid = HybridStateStore(
+            cfg, num_slots, page_size, state_snapshot_bytes,
+            engine=cfg.name)
+        def rows_of(state, rows):
+            return {"ssm": [a[rows] for a in state["ssm"]],
+                    "conv": [a[rows] for a in state["conv"]]}
+
+        def put_rows(state, rows, new):
+            return {
+                "ssm": [a.at[rows].set(n)
+                        for a, n in zip(state["ssm"], new["ssm"])],
+                "conv": [a.at[rows].set(n)
+                         for a, n in zip(state["conv"], new["conv"])]}
+
+        def put_snaps(snaps, idx, cap):
+            return {part: [s.at[idx].set(c)
+                           for s, c in zip(snaps[part], cap[part])]
+                    for part in ("ssm", "conv")}
+
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def prefill_step_hybrid(params, pools, state, snaps, tables,
+                                tokens, offsets, lengths, rows, cap_len,
+                                snap_idx):
+            # rows [B]: each batch row's state row (pads: scratch).
+            # cap_len / snap_idx [B]: the snapshot this chunk yields
+            # (0 / the scratch snapshot: none).
+            with spmd_mesh(mesh):
+                t = tokens.shape[1]
+                positions = offsets[:, None] + jnp.arange(t)[None, :]
+                logits, new_pools, new, cap, counts = forward_paged_hybrid(
+                    params, cfg, tokens, positions, pools, tables,
+                    offsets + lengths, rows_of(state, rows),
+                    lengths=lengths, cap_len=cap_len,
+                    last_pos=jnp.maximum(lengths - 1, 0))
+                return (host_read(logits[:, 0]), new_pools,
+                        put_rows(state, rows, new),
+                        put_snaps(snaps, snap_idx, cap), host_read(counts))
+
+        self._prefill_step_hybrid = prefill_step_hybrid
+
+        @partial(jax.jit, donate_argnums=(1, 2),
+                 static_argnames=("max_new", "greedy"))
+        def decode_loop_hybrid(params, pools, state, tables, rows,
+                               first_token, start_valid, key, budget,
+                               temps, top_ks, top_ps, row_budgets, done0,
+                               max_new, greedy):
+            # The rows' states are gathered once, carried through the
+            # loop in batch order, and scattered back once.
+            def step_fn(last, valid, caches, active):
+                pools_c, st, counts = caches
+                logits, pools_c, st, _cap, c = forward_paged_hybrid(
+                    params, cfg, last[:, None], valid[:, None], pools_c,
+                    tables, valid + 1, st, active=active)
+                return logits, (pools_c, st, counts + c)
+
+            out, step, last, valid, done, caches = decode_while(
+                step_fn, (pools, rows_of(state, rows),
+                          jnp.zeros((3,), jnp.int32)),
+                first_token, start_valid, key, budget, temps, top_ks,
+                top_ps, row_budgets, done0, max_new, greedy,
+                pass_active=True)
+            new_pools, st, counts = caches
+            return (out, step, last, valid, done, new_pools,
+                    put_rows(state, rows, st), host_read(counts))
+
+        self._decode_loop_hybrid = decode_loop_hybrid
+
+        @partial(jax.jit, donate_argnums=(1, 2, 3),
+                 static_argnames=("greedy", "attn_path"))
+        def ragged_step_hybrid(params, pools, state, snaps, tables, tokens,
+                               positions, token_pages, token_offs,
+                               token_seq, seq_of_block, block_qstart,
+                               query_offsets, kv_valid, last_rows, key,
+                               temps, top_ks, top_ps, seq_slot, cap_n,
+                               snap_idx, greedy=True, attn_path="kernel"):
+            with spmd_mesh(mesh):
+                logits, new_pools, new, cap, counts = \
+                    forward_ragged_hybrid(
+                        params, cfg, tokens, positions, pools, tables,
+                        seq_of_block, block_qstart, query_offsets,
+                        kv_valid, token_pages, token_offs, token_seq,
+                        last_rows, state, seq_slot, cap_n,
+                        attn_path=attn_path)
+                lf = logits.astype(jnp.float32)
+                if greedy:
+                    nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+                else:
+                    nxt = sample_token_batch(
+                        lf, key, temps, top_ks, top_ps).astype(jnp.int32)
+            return (host_read(nxt), new_pools, new,
+                    put_snaps(snaps, snap_idx, cap), host_read(counts))
+
+        self._ragged_step_hybrid = ragged_step_hybrid
+        if self.prefix_cache is not None:
+            self.prefix_cache.state_store = self.hybrid
 
     def _install_drafter(self, kind: str, adapter: Optional[str] = None,
                          checkpoint: Optional[str] = None) -> None:
@@ -1198,11 +1380,10 @@ class InferenceEngine:
 
     @classmethod
     def from_config(cls, config: dict[str, Any]) -> "InferenceEngine":
-        model_name = config.get("model", "tiny-gemma")
-        overrides = {}
+        model_cfg = resolve_model_config(config)
         if config.get("max_seq_len"):
-            overrides["max_seq_len"] = int(config["max_seq_len"])
-        model_cfg = get_model_config(model_name, **overrides)
+            model_cfg = dataclasses.replace(
+                model_cfg, max_seq_len=int(config["max_seq_len"]))
         dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
                  "float16": jnp.float16}[config.get("dtype", "bfloat16")]
         sampling_cfg = config.get("sampling", {})
@@ -1246,6 +1427,9 @@ class InferenceEngine:
                             else None),
             lora=config.get("lora"),
             kv_quant=config.get("kv_quant"),
+            state_snapshot_bytes=(int(config["state_snapshot_bytes"])
+                                  if config.get("state_snapshot_bytes")
+                                  is not None else None),
         )
         # Set by fleet.check_fleet_fits when it flips an unpinned config
         # to int8: surfaced via describe() so the degrade is visible
@@ -1257,6 +1441,14 @@ class InferenceEngine:
         # built outside the get_engine cache (tests, benches) are
         # supervisable too.
         engine._engine_config = dict(config)
+        if engine.hybrid is not None and engine.ragged_enabled:
+            # A model with recurrent state compiles its ragged grid at
+            # build: a join re-scans from where its state stands, so
+            # which flat-buffer shape it takes depends on the batch's
+            # composition, and traffic alone may first meet one of the
+            # shapes long after start-up (a 21 s compile in the window:
+            # my chip run, PR 27).
+            engine._warm_ragged()
         if "dispatch_retries" in config:
             from .faults import RetryPolicy
             engine.retry = RetryPolicy(
@@ -1471,6 +1663,10 @@ class InferenceEngine:
                         copy_slots=copy_slots)
                     if pw:
                         batch["propose_width"] = pw
+                    # (Both warm runs start at their slot's first or
+                    # second sequence; a model with recurrent state
+                    # finds the runs' states by these names.)
+                    batch["seq_names"] = list(names)
                     for _ in range(2):
                         nxt = self._ragged_dispatch(batch)
                         jax.tree_util.tree_map(np.asarray, nxt)
@@ -1486,6 +1682,8 @@ class InferenceEngine:
         its page range)."""
         for i in range(self.kv.num_slots):
             self.kv.release(f"__warmup_{i}")
+            if self.hybrid is not None:
+                self.hybrid.forget(f"__warmup_{i}")
 
     def _warm_prompt_cap(self, b: int) -> int:
         """Longest prompt a b-row warm batch can pin without exhausting
@@ -1570,6 +1768,14 @@ class InferenceEngine:
         recovery also holds for failures that surface AFTER donation
         consumed the cache). True iff fresh buffers were allocated."""
         revived = self.kv.revive_if_dead()
+        if self.hybrid is not None:
+            # The slot states and the snapshot store are donated through
+            # the same dispatches; either tree dead means neither the
+            # pools' pages nor the states can be trusted together.
+            if self.hybrid.revive_if_dead() or revived:
+                self.hybrid.forget_all()
+                self.hybrid.drop_all_snapshots()
+                revived = True
         if revived and self.kv_offload is not None:
             # Spilled records reference pages of the DEAD pools (kept
             # shared pages) — they cannot be restored into the fresh
@@ -1586,7 +1792,9 @@ class InferenceEngine:
         the request in flight re-dispatches through the gather view and
         every later call skips the kernels entirely. Returns False when
         already degraded / never pool-direct (caller re-raises)."""
-        if not self.paged_direct:
+        if not self.paged_direct or self.hybrid is not None:
+            # (A model with recurrent state has no gather-view programs
+            # to degrade to: the failure surfaces to the retry ladder.)
             return False
         import warnings
         warnings.warn(
@@ -1626,6 +1834,118 @@ class InferenceEngine:
         self.ragged_fallback_reason = f"degraded:{reason[:120]}"
         return True
 
+    # --- the step seams of a model with recurrent state -------------------
+
+    def _live_slots(self) -> set:
+        return set(self.kv.slot_names())
+
+    def _hybrid_prefill(self, tables, chunk, offs, takes, rows):
+        """One prefill chunk: the rows' states advance with it, and the
+        last page boundary each row crosses leaves a snapshot."""
+        hy = self.hybrid
+        b = chunk.shape[0]
+        row_name = hy.names_by_row()
+        cap_len = np.zeros((b,), np.int32)
+        snap_idx = np.full((b,), hy.scratch_snap, np.int32)
+        rows_np = np.full((b,), hy.scratch_row, np.int32)
+        keys = []
+        for i in range(b):
+            row = rows[i] if i < len(rows) else None
+            if row is None or row < 0 or not takes[i]:
+                continue
+            rows_np[i] = row
+            cap_len[i], snap_idx[i], key = hy.capture_slot(
+                row_name[row], int(offs[i]), int(takes[i]))
+            keys.append(key)
+        try:
+            last, pools, state, snaps, counts = self._prefill_step_hybrid(
+                self.params, self.kv.combined_pools(), hy.state, hy.snaps,
+                tables, jnp.asarray(chunk), jnp.asarray(offs, jnp.int32),
+                jnp.asarray(takes, jnp.int32), jnp.asarray(rows_np),
+                jnp.asarray(cap_len), jnp.asarray(snap_idx))
+        except Exception:
+            for key in keys:
+                hy.drop(key)
+            raise
+        with deadlines.commit_guard():
+            hy.commit_state(state)
+            hy.commit_snaps(snaps)
+        hy.note_counts(counts, pipelined=False)
+        return last, pools
+
+    def _hybrid_decode(self, tables, names, last, valid, key, budget,
+                       temps, top_ks, top_ps, row_budgets, done0, max_new,
+                       greedy):
+        hy = self.hybrid
+        if names is None:
+            raise ValueError(
+                f"{self.cfg.name} keeps recurrent state: a decode "
+                "dispatch needs the rows' slot names")
+        rows = hy.rows_for(list(names), int(tables.shape[0]),
+                           self._live_slots())
+        out, steps, l2, v2, d2, pools, state, counts = \
+            self._decode_loop_hybrid(
+            self.params, self.kv.combined_pools(), hy.state, tables,
+            jnp.asarray(rows), last, valid, key, budget, temps, top_ks,
+            top_ps, row_budgets, done0, max_new=max_new, greedy=greedy)
+        with deadlines.commit_guard():
+            hy.commit_state(state)
+        hy.note_counts(counts, pipelined=True)
+        return out, steps, l2, v2, d2, pools
+
+    def _hybrid_ragged(self, batch: dict, path: str):
+        """One ragged dispatch: every sequence's run advances its slot's
+        state; a joining run that crosses a page boundary leaves a
+        snapshot at the last one."""
+        hy = self.hybrid
+        names = batch.get("seq_names")
+        if names is None:
+            raise ValueError(
+                f"{self.cfg.name} keeps recurrent state: a ragged batch "
+                "needs `seq_names` (the slot of every sequence)")
+        if int(batch.get("score_width", 0) or 0) \
+                or batch.get("copy_src") is not None:
+            raise ValueError("speculative verify is declined for a model "
+                             "with recurrent state")
+        s_max = batch["tables"].shape[0]
+        seq_slot = hy.rows_for(list(names), s_max, self._live_slots())
+        cap_n = np.zeros((s_max,), np.int32)
+        snap_idx = np.full((s_max,), hy.scratch_snap, np.int32)
+        starts = np.asarray(batch["query_offsets"])
+        ends = np.asarray(batch["kv_valid"])
+        keys = []
+        for i, name in enumerate(names):
+            cap_n[i], snap_idx[i], key = hy.capture_slot(
+                name, int(starts[i]), int(ends[i] - starts[i]))
+            keys.append(key)
+        try:
+            nxt, pools, state, snaps, counts = self._ragged_step_hybrid(
+                self.params, self.kv.combined_pools(), hy.state, hy.snaps,
+                jnp.asarray(batch["tables"]), jnp.asarray(batch["tokens"]),
+                jnp.asarray(batch["positions"]),
+                jnp.asarray(batch["token_pages"]),
+                jnp.asarray(batch["token_offs"]),
+                jnp.asarray(batch["token_seq"]),
+                jnp.asarray(batch["seq_of_block"]),
+                jnp.asarray(batch["block_qstart"]),
+                jnp.asarray(batch["query_offsets"]),
+                jnp.asarray(batch["kv_valid"]),
+                jnp.asarray(batch["last_rows"]), self._next_key(),
+                jnp.asarray(batch["temps"]), jnp.asarray(batch["top_ks"]),
+                jnp.asarray(batch["top_ps"]), jnp.asarray(seq_slot),
+                jnp.asarray(cap_n), jnp.asarray(snap_idx),
+                greedy=batch["greedy"],
+                attn_path="kernel" if path == "pallas_ragged" else "xla")
+        except Exception:
+            for key in keys:
+                hy.drop(key)
+            raise
+        with deadlines.commit_guard():
+            hy.commit_state(state)
+            hy.commit_snaps(snaps)
+        hy.note_counts(counts, pipelined=False)
+        return nxt, pools
+
     def _ragged_dispatch(self, batch: dict):
         """One mixed prefill/decode dispatch over a flat token buffer
         (serving_loop.build_ragged_batch output) — the scheduler's
@@ -1651,6 +1971,8 @@ class InferenceEngine:
         def run(path):
             if path == "pallas_ragged" and faults.ARMED:
                 faults.maybe_inject("mosaic_compile")
+            if self.hybrid is not None:
+                return self._hybrid_ragged(batch, path)
             return self._ragged_step(
                 params, self.kv.combined_pools(),
                 jnp.asarray(batch["tables"]),
@@ -1967,9 +2289,18 @@ class InferenceEngine:
                 lora_ids if lora_ids is not None
                 else [0] * len(token_lists))
 
+        ends = [o + len(t) for o, t in zip(offsets, token_lists)]
+
         def paged_prefill(chunk, offs, lengths):
             if self.paged_direct and faults.ARMED:
                 faults.maybe_inject("mosaic_compile")
+            if self.hybrid is not None:
+                # The tokens each row really feeds (an exhausted row's
+                # filler pad must not reach its recurrent state).
+                takes = [max(min(e - o, chunk.shape[1]), 0)
+                         for e, o in zip(ends, offs)]
+                return self._hybrid_prefill(tables, chunk, offs, takes,
+                                            slot_ids)
             return self._prefill_step_paged(
                 self.params, self.kv.combined_pools(), tables,
                 jnp.asarray(chunk), jnp.asarray(offs, jnp.int32),
@@ -2129,7 +2460,41 @@ class InferenceEngine:
             min_shared=MIN_SHARED_PREFIX, add_share=add_share,
             flush_shares=flush_shares, prefill_span=prefill_span,
             extra_pinned=extra_pinned, defer_span=defer_span,
-            donor_ok=donor_ok)
+            donor_ok=donor_ok,
+            decline_leader=(self._decline_leader_share
+                            if self.hybrid is not None else None))
+
+    def _decline_leader_share(self, n_laggards: int) -> None:
+        self.hybrid.share_declined += n_laggards
+        from ..utils import telemetry
+        telemetry.inc("roundtable_state_share_declined_total", n_laggards,
+                      engine=self.cfg.name, reason="recurrent-state")
+
+    def _plan_states(self, names, all_tokens, offsets) -> dict:
+        """The joint reuse plan of one admission (hybrid_state.plan): each
+        row starts where BOTH its pages and a state stand. Lowers
+        `offsets` in place to that position, drops the slot's pages
+        beyond it (the attention layers re-write theirs from there),
+        and copies the states in. -> what the admit span reports."""
+        hy = self.hybrid
+        plans = []
+        out = {"continue": 0, "snapshot": 0, "zero": 0,
+               "kv_matched_tokens": 0, "state_reused_tokens": 0,
+               "prompt_tokens": sum(len(t) for t in all_tokens)}
+        for i, name in enumerate(names):
+            start, source, snap = hy.plan(name, all_tokens[i], offsets[i])
+            out[source] += 1
+            out["kv_matched_tokens"] += offsets[i]
+            out["state_reused_tokens"] += start
+            if start < offsets[i]:
+                slot = self.kv.acquire(name)
+                slot.tokens = slot.tokens[:start]
+                self.kv._trim_pages(slot, start)
+                offsets[i] = start
+            plans.append((name, source, snap))
+        hy.attach(plans, self._live_slots())
+        out["rows"] = [hy.row_of(n) for n in names]
+        return out
 
     def _prepare_batch(self, turns, max_new_padded, deadline, pre_budget,
                        sampling_per_turn=None,
@@ -2266,7 +2631,8 @@ class InferenceEngine:
                         sub_off, pinned)
                     for j, i in enumerate(base_idx):
                         offsets[i] = sub_off[j]
-        if defer_prefill:
+        defer_by_state = defer_prefill and self.hybrid is not None
+        if defer_prefill and not defer_by_state:
             # Deferral pays off only for COLD prefills: after own-slot
             # reuse and the prefix-cache attach, a warm join's leftover
             # is often a few dozen tokens — one tiny bucket dispatch,
@@ -2300,6 +2666,16 @@ class InferenceEngine:
                 budget=pre_budget, extra_pinned=tuple(extra_pinned),
                 defer_span=defer_span, row_adapters=ad,
                 row_lora_slots=lora_slots)
+        state_plan = None
+        if self.hybrid is not None:
+            state_plan = self._plan_states(names, all_tokens, offsets)
+            slot_ids = state_plan.pop("rows")
+            if defer_by_state:
+                # What a join has to scan is decided by where its STATE
+                # stands, not its pages: the cold-or-warm question
+                # above, asked after the joint plan.
+                est = sum(len(t) - o for t, o in zip(all_tokens, offsets))
+                defer_prefill = est >= self.ragged_defer_min
         plan = None
         tables_np = None
         if self.kv_layout == "paged":
@@ -2358,6 +2734,7 @@ class InferenceEngine:
                 "prefix_reused_tokens": prefix_reused,
                 "share_plan": share_plan,
                 "lora_slots": lora_slots, "adapters": ad,
+                "state_plan": state_plan,
             }
         p_offsets = offsets
         p_lora = lora_slots
@@ -2416,20 +2793,27 @@ class InferenceEngine:
             "reused_tokens": reused_tokens,
             "prefix_reused_tokens": prefix_reused,
             "lora_slots": lora_slots, "adapters": ad,
+            "state_plan": state_plan,
         }
 
     def _decode_dispatch_paged(self, tables, last, valid, key, budget,
                                temps, top_ks, top_ps, row_budgets, done0,
                                *, greedy, max_new=DECODE_SEGMENT,
-                               lora=None):
+                               lora=None, names=None):
         """One paged decode-segment dispatch through the kernel-
         degradation rung (mosaic chaos point; pool-direct → gather-view
         on kernel failure, re-dispatching this segment), committing the
         donated pools under commit_guard. Shared by generate_batch's
-        segment loop and the session scheduler."""
+        segment loop and the session scheduler. `names`: the rows' slot
+        names (pad rows left out) — a model with recurrent state finds
+        each row's state by it."""
         def run():
             if self.paged_direct and faults.ARMED:
                 faults.maybe_inject("mosaic_compile")
+            if self.hybrid is not None:
+                return self._hybrid_decode(
+                    tables, names, last, valid, key, budget, temps,
+                    top_ks, top_ps, row_budgets, done0, max_new, greedy)
             return self._decode_loop_paged(
                 self.params, self.kv.combined_pools(), tables, last,
                 valid, key, budget, temps, top_ks, top_ps, row_budgets,
@@ -2650,7 +3034,7 @@ class InferenceEngine:
                 return self._decode_dispatch_paged(
                     tables, cur_last, cur_valid, self._next_key(),
                     budget, temps, top_ks, top_ps, row_budgets, done0,
-                    greedy=greedy, lora=dec_lora)
+                    greedy=greedy, lora=dec_lora, names=prep["names"])
             return self._decode_dispatch_slots(
                 slot_idx, cur_last, cur_valid, self._next_key(),
                 budget, temps, top_ks, top_ps, row_budgets, done0,
@@ -2677,6 +3061,21 @@ class InferenceEngine:
 
             def commit(name, toks, _kv=self.kv, _idx=idx_of):
                 _kv.commit(name, toks, index=_idx.get(name, True))
+
+        if self.hybrid is not None:
+            # A row that ended on a sampled eos has consumed one token
+            # more than it commits: its state cannot be continued.
+            eos_id = self.tokenizer.eos_id
+            ended = {name: eos_id in ([int(first_np[i])]
+                                      + [int(x) for x in out_np[i]]
+                                      )[:max_new]
+                     for i, (name, _p) in enumerate(turns)}
+            kv_commit = commit
+            self.hybrid.fold_counts()    # every dispatch has been read
+
+            def commit(name, toks):  # noqa: F811
+                kv_commit(name, toks)
+                self.hybrid.on_commit(name, toks, exact=not ended[name])
 
         results = finalize_outputs(
             turns, first_np, out_np, all_tokens, max_new,
@@ -2745,6 +3144,21 @@ class InferenceEngine:
             # ISSUE 11: quantized-KV-page provenance (spec, per-seam
             # dispatch paths, kernel-decline reason, bytes saved).
             info["kv_quant"] = self.kv_quant_describe()
+        if self.hybrid is not None:
+            # Recurrent state beside the pools, and the chip's share of
+            # the experts (models/hybrid.py, engine/hybrid_state.py).
+            info["hybrid_state"] = self.hybrid.describe()
+            if self.prefix_cache is not None:
+                info["hybrid_state"]["deduped_pages"] = \
+                    self.prefix_cache.deduped_pages
+            info["moe"] = {"held": self.cfg.experts_held,
+                           "published": self.cfg.routed_experts,
+                           "offset": self.cfg.expert_offset,
+                           "top_k": self.cfg.moe_top_k,
+                           "expert_layers": len(self.cfg.expert_layers),
+                           **self.hybrid.moe_totals()}
+        # What this model declined at build time, each with its reason.
+        info["declines"] = dict(self.declines)
         # ISSUE 10: multi-LoRA persona provenance — the resolved
         # state, adapter store residency, per-leaf routing paths.
         info["lora"] = self.lora_describe()
@@ -2799,8 +3213,9 @@ def _analysis_engine_programs(engine) -> list:
     `_prefill`/`_decode_dispatch_*`; drift between the twins fails the
     audit's trace step loudly rather than silently auditing nothing.
     """
-    if not isinstance(engine, InferenceEngine):
-        return []
+    if not isinstance(engine, InferenceEngine) \
+            or engine.hybrid is not None:
+        return []       # (a hybrid engine: _analysis_hybrid_programs)
     from .serving_loop import pow2_bucket
     paged = engine.kv_layout == "paged"
     params = _audit_sds(engine.params)
@@ -2865,3 +3280,97 @@ def _analysis_engine_programs(engine) -> list:
         variants=[decode_variant(occ)
                   for occ in (1, 2, 3, 4) if occ <= num_slots])
     return [prefill, decode]
+
+
+@analysis_register("engine_hybrid")
+def _analysis_hybrid_programs(engine) -> list:
+    """The three step programs of a model with recurrent state
+    (`_build_hybrid_programs`), on the same variant grids as their
+    plain twins: prefill per (batch, bucket), decode occupancies through
+    `pow2_bucket`, the ragged flat buffer per warmed shape with one and
+    two sequences. Slot rows, snapshot indices and capture points are
+    VALUES: every composition of a label must trace to one jaxpr.
+    Argument construction mirrors `_hybrid_prefill` / `_hybrid_decode` /
+    `_hybrid_ragged`."""
+    if not isinstance(engine, InferenceEngine) or engine.hybrid is None:
+        return []
+    from .paged_forward import analysis_warm_seqs
+    from .serving_loop import build_ragged_batch, pow2_bucket
+    hy, kv = engine.hybrid, engine.kv
+    params = _audit_sds(engine.params)
+    pools = _audit_sds(kv.combined_pools())
+    state, snaps = _audit_sds(hy.state), _audit_sds(hy.snaps)
+    key = jax.random.PRNGKey(0)
+    pps = kv.pages_per_seq
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def floats(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def prefill_variant(b: int, bucket: int) -> Variant:
+        def thunk():
+            return jax.make_jaxpr(engine._prefill_step_hybrid)(
+                params, pools, state, snaps, ints(b, pps),
+                ints(b, bucket), ints(b), ints(b), ints(b), ints(b),
+                ints(b))
+        return Variant(label=f"b{b}x{bucket}", thunk=thunk,
+                       situation=f"batch {b}, bucket {bucket}")
+
+    def decode_variant(occ: int) -> Variant:
+        b = pow2_bucket(occ)
+
+        def thunk():
+            fn = engine._decode_loop_hybrid
+            return jax.make_jaxpr(
+                lambda p, pl, st, *a: fn(p, pl, st, *a,
+                                         max_new=DECODE_SEGMENT,
+                                         greedy=True))(
+                params, pools, state, ints(b, pps), ints(b), ints(b),
+                ints(b), key, jnp.int32(DECODE_SEGMENT), floats(b),
+                ints(b), floats(b), ints(b),
+                jax.ShapeDtypeStruct((b,), jnp.bool_))
+        return Variant(label=f"b{b}", thunk=thunk,
+                       situation=f"occupancy {occ}")
+
+    def ragged_variant(shape: int, n_seqs: int) -> Variant:
+        def thunk():
+            b = build_ragged_batch(
+                analysis_warm_seqs(engine, n_seqs), t_budget=shape,
+                s_max=kv.num_slots + 1, pages_per_seq=pps,
+                scratch_page=kv.scratch_page(0),
+                pad_id=engine.tokenizer.pad_id, page_size=kv.page_size)
+            s_max = b["tables"].shape[0]
+            arrays = [jnp.asarray(b[k]) for k in (
+                "tables", "tokens", "positions", "token_pages",
+                "token_offs", "token_seq", "seq_of_block",
+                "block_qstart", "query_offsets", "kv_valid", "last_rows")]
+            fn = engine._ragged_step_hybrid
+            return jax.make_jaxpr(
+                lambda p, pl, st, sn, *a: fn(
+                    p, pl, st, sn, *a, greedy=True,
+                    attn_path=("kernel" if engine.ragged_path
+                               == "pallas_ragged" else "xla")))(
+                params, pools, state, snaps, *arrays, key,
+                jnp.asarray(b["temps"]), jnp.asarray(b["top_ks"]),
+                jnp.asarray(b["top_ps"]), ints(s_max), ints(s_max),
+                ints(s_max))
+        return Variant(label=f"t{shape}", thunk=thunk,
+                       situation=f"{n_seqs} seq(s) in shape {shape}")
+
+    num_slots = kv.num_slots
+    specs = [
+        ProgramSpec(name="prefill[hybrid]", phase="prefill",
+                    variants=[prefill_variant(b, PREFILL_BUCKETS[0])
+                              for b in (1, 2) if b <= num_slots]),
+        ProgramSpec(name="decode[hybrid]", phase="decode",
+                    variants=[decode_variant(occ)
+                              for occ in (1, 2, 3, 4) if occ <= num_slots]),
+    ]
+    if engine.ragged_enabled:
+        specs.append(ProgramSpec(
+            name="ragged[hybrid]", phase="ragged",
+            variants=[ragged_variant(shape, n)
+                      for shape in engine.ragged_shapes for n in (1, 2)]))
+    return specs

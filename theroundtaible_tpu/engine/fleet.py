@@ -21,7 +21,7 @@ import warnings
 from typing import Any, Optional
 
 from .models.common import ModelConfig
-from .models.registry import get_model_config
+from .models.registry import resolve_model_config
 
 _DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
@@ -30,6 +30,21 @@ def estimate_param_count(cfg: ModelConfig) -> int:
     """Closed-form parameter count (no arrays built)."""
     e, h, k, d, f = (cfg.embed_dim, cfg.num_heads, cfg.num_kv_heads,
                      cfg.head_dim, cfg.mlp_dim)
+    if cfg.layer_kinds is not None:
+        # One mixer a layer (models/hybrid.py), counted by kind; the
+        # experts are the ones HELD here, the router the published width.
+        d_in, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
+        per_kind = {
+            "mamba2": (e * (d_in + conv + cfg.mamba_heads) + d_in * e
+                       + (cfg.conv_kernel + 1) * conv
+                       + 3 * cfg.mamba_heads + d_in),
+            "experts": (cfg.experts_held * 2 * e * cfg.expert_dim
+                        + 2 * e * cfg.shared_expert_dim
+                        + (e + 1) * cfg.routed_experts),
+            "attention": 2 * e * h * d + 2 * e * k * d,
+        }
+        return (sum(per_kind[kind] + e for kind in cfg.layer_kinds)
+                + 2 * cfg.vocab_size * e + e)
     mlp = 3 * e * f
     if cfg.num_experts:
         mlp = cfg.num_experts * 3 * e * f + e * cfg.num_experts  # + router
@@ -51,7 +66,7 @@ def estimate_engine_hbm_bytes(engine_cfg: dict[str, Any],
     at plan time with a clear message instead of minutes later as an
     opaque XLA allocation error. Margins err high (weights dominate)."""
     if model_cfg is None:
-        model_cfg = get_model_config(engine_cfg.get("model", "tiny-gemma"))
+        model_cfg = resolve_model_config(engine_cfg)
     max_seq = int(engine_cfg.get("max_seq_len") or model_cfg.max_seq_len)
     n_params = estimate_param_count(model_cfg)
     dtype_b = _DTYPE_BYTES.get(engine_cfg.get("dtype", "bfloat16"), 2)
@@ -64,7 +79,7 @@ def estimate_engine_hbm_bytes(engine_cfg: dict[str, Any],
                               else 0.58 if quant == "int4"
                               else dtype_b))
     num_slots = int(engine_cfg.get("num_slots", 4))
-    kv_bytes = (num_slots * max_seq * model_cfg.num_layers * 2
+    kv_bytes = (num_slots * max_seq * len(model_cfg.attention_layers) * 2
                 * model_cfg.num_kv_heads * model_cfg.head_dim * dtype_b)
     if engine_cfg.get("kv_layout") == "paged":
         # Default pool halves the contiguous budget. Total across the
@@ -92,6 +107,17 @@ def estimate_engine_hbm_bytes(engine_cfg: dict[str, Any],
             kv_bytes = int(int(num_pages) * page_size
                            * cell_bytes_per_token(model_cfg, spec,
                                                   dtype_b))
+    state_bytes = 0
+    if model_cfg.layer_kinds is not None:
+        # Recurrent state beside the pools (engine/hybrid_state.py): a
+        # row a slot plus scratch, and the snapshot store's budget (the
+        # engine's default: four snapshots a slot).
+        from .models.hybrid import state_bytes_per_sequence
+        per = state_bytes_per_sequence(model_cfg)
+        snap = engine_cfg.get("state_snapshot_bytes")
+        state_bytes = ((num_slots + 1) * per
+                       + (int(snap) if snap is not None
+                          else 4 * num_slots * per))
     lora_bytes = 0
     lora_cfg = engine_cfg.get("lora")
     if lora_cfg:
@@ -107,7 +133,7 @@ def estimate_engine_hbm_bytes(engine_cfg: dict[str, Any],
     # Activations + XLA workspace: prefill chunks are ≤2048 tokens, so
     # this is small next to 7B-class weights; floor it for tiny models.
     margin = max(256 << 20, w_bytes // 16)
-    return w_bytes + kv_bytes + lora_bytes + margin
+    return w_bytes + kv_bytes + state_bytes + lora_bytes + margin
 
 
 # HBM per chip by device_kind, for backends whose memory_stats is None
@@ -425,6 +451,12 @@ def drain(timeout_s: float = 30.0, flush_kv: bool = True) -> dict[str, Any]:
                     # must not abandon the remaining engines mid-drain.
                     try:
                         entry["flushed_slots"] = eng.kv.flush()
+                        hy = getattr(eng, "hybrid", None)
+                        if hy is not None:
+                            # The slots' recurrent states and the
+                            # snapshots go with their pages.
+                            hy.forget_all()
+                            hy.drop_all_snapshots()
                         # Spilled sessions' kept-resident pages are the
                         # only thing left between a flushed paged pool
                         # and zero pages in use — evacuate them to host
@@ -498,9 +530,9 @@ def plan_fleet(engine_configs: list[dict[str, Any]],
 
     weights = []
     for ident, cfgs in identities.items():
-        model_name = cfgs[0].get("model", "tiny-gemma")
         try:
-            weights.append(estimate_param_count(get_model_config(model_name)))
+            weights.append(estimate_param_count(
+                resolve_model_config(cfgs[0])))
         except ValueError:
             weights.append(1)
     groups = partition_devices(weights, n_devices)

@@ -1,0 +1,385 @@
+"""Recurrent state beside paged KV: the second kind of cache.
+
+A model with Mamba-2 layers (`ModelConfig.recurrent`) keeps, for every
+sequence, a state that is not pages: per Mamba-2 layer the SSM state
+`[H, P, N]` float32 and the last K-1 inputs of the causal conv. Pages
+can be truncated to any prefix and shared by reference; a state is valid
+at exactly ONE position — the number of tokens it has consumed — and can
+only be copied whole. This module owns both halves of that:
+
+- **Slot states** — one row a knight slot (`state["ssm"][l][row]`,
+  `state["conv"][l][row]`; the last row is scratch, where pad rows of a
+  batch land). The tree is donated through the engine's step programs
+  beside the page pools. What the expert layers counted in a dispatch
+  (experts hit, assignments to held experts, expert-layer steps) comes
+  back as an output of its own, never donated: `note_counts` queues it
+  and folds it into host ints on the dispatching thread once its
+  program has been read. Nothing off that thread touches the device.
+- **The snapshot store** — `snapshot_bytes` of device memory holding
+  states at PAGE BOUNDARIES, keyed by the content of the prefix they
+  consumed (a hash chain over whole pages, so any slot — own, sibling or
+  another session's — whose prompt starts with those pages can start
+  from it). Snapshots are taken where the chunked scan yields them for
+  one more small product: the last page boundary a prefill chunk or a
+  ragged join crosses. They are bound to the radix node of their page
+  when the slot commits (prefix_cache.insert) and dropped with that
+  node; unbound ones age out LRU under the byte budget.
+
+`plan()` is the reuse plan for one admission row: the longest prefix
+for which pages AND a state exist — (i) the slot's own state when the
+prompt extends exactly what it consumed, (ii) else the deepest snapshot
+at a page boundary inside the matched pages, (iii) else zero. The
+caller lowers the row's KV offset to that position (attention layers
+re-write their few pages from there) and the state is copied in before
+the first dispatch.
+
+Single-writer like the pool: every caller holds the engine's serve lock.
+"""
+
+from __future__ import annotations
+
+import hashlib
+from collections import OrderedDict, deque
+from functools import partial
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..utils import telemetry
+from .models import hybrid
+from .models.common import ModelConfig
+
+CONTINUE, SNAPSHOT, ZERO = "continue", "snapshot", "zero"
+MOE_COUNTS = ("experts_hit", "local_assignments", "expert_layer_steps")
+
+
+def _chain(prev: bytes, block: list[int]) -> bytes:
+    return hashlib.blake2b(prev + np.asarray(block, np.int32).tobytes(),
+                           digest_size=16).digest()
+
+
+def page_keys(tokens: list[int], page_size: int, upto: int) -> list[bytes]:
+    """keys[j] identifies tokens[:(j+1)*page_size], for every whole page
+    at or below `upto` tokens."""
+    out, key = [], b""
+    for j in range(min(upto, len(tokens)) // page_size):
+        key = _chain(key, tokens[j * page_size:(j + 1) * page_size])
+        out.append(key)
+    return out
+
+
+class HybridStateStore:
+    def __init__(self, cfg: ModelConfig, num_slots: int, page_size: int,
+                 snapshot_bytes: int, engine: str = "engine"):
+        self.cfg = cfg
+        self.engine = engine
+        self.page_size = page_size
+        self.num_slots = num_slots
+        self.scratch_row = num_slots
+        self.bytes_per_state = hybrid.state_bytes_per_sequence(cfg)
+        self.budget_bytes = int(snapshot_bytes)
+        self.capacity = self.budget_bytes // self.bytes_per_state
+        self.scratch_snap = self.capacity
+        self._alloc()
+        self._row_of: dict[str, int] = {}
+        self._consumed: dict[str, Optional[list[int]]] = {}
+        self._keys: dict[str, list[bytes]] = {}
+        self._snap: "OrderedDict[bytes, int]" = OrderedDict()
+        self._free_snaps = list(range(self.capacity - 1, -1, -1))
+        self._counts_pending: "deque[jax.Array]" = deque()
+        self._moe_total = [0, 0, 0]
+        self._moe_seen = [0, 0, 0]
+        self.hits = self.misses = self.evictions = 0
+        self.snapshots_taken = 0
+        self.continued_tokens = self.reused_tokens = 0
+        self.rescanned_tokens = 0
+        self.share_declined = 0
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def restore(state, snaps, dst_rows, src_snaps, zero):
+            # state[dst] = zero ? 0 : snaps[src]; unused lanes copy the
+            # scratch snapshot onto the scratch row.
+            out = dict(state)
+            for part in ("ssm", "conv"):
+                out[part] = [
+                    a.at[dst_rows].set(jnp.where(
+                        zero.reshape((-1,) + (1,) * (a.ndim - 1)),
+                        0.0, s[src_snaps]))
+                    for a, s in zip(state[part], snaps[part])]
+            return out
+
+        self._restore = restore
+
+    def _alloc(self) -> None:
+        self.state: dict[str, Any] = hybrid.zero_state(
+            self.cfg, self.num_slots + 1)
+        self.snaps = hybrid.zero_state(self.cfg, self.capacity + 1)
+
+    # --- device trees ---------------------------------------------------
+
+    def commit_state(self, state: dict) -> None:
+        self.state = state
+
+    def commit_snaps(self, snaps: dict) -> None:
+        self.snaps = snaps
+
+    def revive_if_dead(self) -> bool:
+        """A failed donated dispatch may have consumed either tree:
+        reallocate both and forget every host record (every next
+        admission then starts from zero, which is always right)."""
+        leaves = (jax.tree_util.tree_leaves(self.state)
+                  + jax.tree_util.tree_leaves(self.snaps))
+        if not any(x.is_deleted() for x in leaves):
+            return False
+        self._alloc()
+        self._consumed.clear()
+        self._snap.clear()
+        self._free_snaps = list(range(self.capacity - 1, -1, -1))
+        self._counts_pending.clear()
+        return True
+
+    def hbm_bytes(self) -> int:
+        return sum(x.size * x.dtype.itemsize for x in
+                   jax.tree_util.tree_leaves(self.state)
+                   + jax.tree_util.tree_leaves(self.snaps))
+
+    # --- rows -----------------------------------------------------------
+
+    def row_of(self, name: str, live: Optional[set] = None) -> int:
+        """The state row of slot `name`; a new name takes a free row,
+        reclaiming the rows of names the pool no longer holds."""
+        row = self._row_of.get(name)
+        if row is not None:
+            return row
+        used = set(self._row_of.values())
+        if len(used) >= self.num_slots:
+            for old in [n for n in self._row_of
+                        if live is not None and n not in live]:
+                self.forget(old)
+            used = set(self._row_of.values())
+        if len(used) >= self.num_slots:
+            raise RuntimeError(
+                f"hybrid state: all {self.num_slots} state rows belong "
+                "to live slots — raise num_slots")
+        row = next(i for i in range(self.num_slots) if i not in used)
+        self._row_of[name] = row
+        self._consumed[name] = None
+        return row
+
+    def rows_for(self, names: list[str], width: int,
+                 live: Optional[set] = None) -> np.ndarray:
+        rows = np.full((width,), self.scratch_row, np.int32)
+        for i, n in enumerate(names):
+            rows[i] = self.row_of(n, live)
+        return rows
+
+    def names_by_row(self) -> dict[int, str]:
+        return {row: name for name, row in self._row_of.items()}
+
+    def forget(self, name: str) -> None:
+        for table in (self._row_of, self._consumed, self._keys):
+            table.pop(name, None)
+
+    def forget_all(self) -> None:
+        for name in list(self._row_of):
+            self.forget(name)
+
+    # --- the reuse plan -------------------------------------------------
+
+    def plan(self, name: str, tokens: list[int],
+             kv_matched: int) -> tuple[int, str, Optional[int]]:
+        """-> (start, source, snapshot index): where slot `name`'s state
+        can stand for prompt `tokens`, given pages for tokens[:kv_matched].
+        Also records the prompt's page keys, so captures of this
+        admission can be keyed. Leaves the slot marked in flight (no continuation until
+        its next commit)."""
+        cap = min(kv_matched, len(tokens) - 1)
+        keys = page_keys(tokens, self.page_size, len(tokens))
+        self._keys[name] = keys
+        best, source, snap = 0, ZERO, None
+        for j in range(cap // self.page_size - 1, -1, -1):
+            idx = self._snap.get(keys[j])
+            if idx is not None:
+                self._snap.move_to_end(keys[j])
+                best, source, snap = (j + 1) * self.page_size, SNAPSHOT, idx
+                break
+        own = self._consumed.get(name)
+        if (own is not None and best < len(own) <= cap
+                and tokens[:len(own)] == own):
+            best, source, snap = len(own), CONTINUE, None
+        self._consumed[name] = None
+        if source == ZERO:
+            self.misses += 1
+        else:
+            self.hits += 1
+        if source == CONTINUE:
+            self.continued_tokens += best
+        else:
+            self.reused_tokens += best
+        self.rescanned_tokens += max(kv_matched - best, 0)
+        telemetry.inc("roundtable_state_admissions_total",
+                      engine=self.engine, source=source)
+        telemetry.inc("roundtable_state_prompt_tokens_total",
+                      len(tokens), engine=self.engine)
+        telemetry.inc("roundtable_state_rescanned_tokens_total",
+                      max(kv_matched - best, 0), engine=self.engine)
+        return best, source, snap
+
+    def attach(self, plans: list[tuple[str, str, Optional[int]]],
+               live: Optional[set] = None) -> None:
+        """Bring each planned row's state to its start: copy the
+        snapshot in, or zero it; a continuing row is left alone. One
+        program of one shape, whatever the mix."""
+        todo = [(n, src, idx) for n, src, idx in plans if src != CONTINUE]
+        for n, _s, _i in plans:
+            self.row_of(n, live)
+        if not todo:
+            return
+        for k in range(0, len(todo), self.num_slots):
+            part = todo[k:k + self.num_slots]
+            dst = np.full((self.num_slots,), self.scratch_row, np.int32)
+            src = np.full((self.num_slots,), self.scratch_snap, np.int32)
+            zero = np.zeros((self.num_slots,), bool)
+            for i, (n, source, idx) in enumerate(part):
+                dst[i] = self._row_of[n]
+                if source == SNAPSHOT:
+                    src[i] = idx
+                else:
+                    zero[i] = True
+            self.state = self._restore(
+                self.state, self.snaps, jnp.asarray(dst),
+                jnp.asarray(src), jnp.asarray(zero))
+
+    def on_commit(self, name: str, tokens: list[int], exact: bool) -> None:
+        """The slot committed `tokens`. `exact`: its state has consumed
+        exactly those (a row that ended on a sampled eos consumed one
+        more: no continuation from it)."""
+        if name in self._row_of:
+            self._consumed[name] = list(tokens) if exact else None
+
+    # --- snapshots ------------------------------------------------------
+
+    def capture_slot(self, name: str, start: int, n_tokens: int
+                     ) -> tuple[int, int, Optional[bytes]]:
+        """For a dispatch that feeds slot `name` tokens [start, start +
+        n): -> (cap_len, snapshot index, key) — after how many of them
+        the state stands at the last page boundary the run crosses, and
+        where the program stores it; (0, scratch, None) when there is
+        none, it is already held, or the prompt is unknown. A dispatch
+        that fails drops the keys it reserved (`drop`)."""
+        ps = self.page_size
+        b = (start + n_tokens) // ps * ps
+        keys = self._keys.get(name)
+        if (self.capacity == 0 or b <= start or keys is None
+                or b // ps > len(keys) or name.startswith("__warmup_")):
+            return 0, self.scratch_snap, None
+        key = keys[b // ps - 1]
+        if key in self._snap:
+            self._snap.move_to_end(key)
+            return 0, self.scratch_snap, None
+        if not self._free_snaps:
+            self._evict_lru()
+        idx = self._free_snaps.pop()
+        self._snap[key] = idx
+        self.snapshots_taken += 1
+        telemetry.inc("roundtable_state_snapshots_total",
+                      engine=self.engine)
+        self._publish()
+        return b - start, idx, key
+
+    def _evict_lru(self) -> None:
+        key, idx = self._snap.popitem(last=False)
+        self._free_snaps.append(idx)
+        self.evictions += 1
+        telemetry.inc("roundtable_state_snapshot_evictions_total",
+                      engine=self.engine)
+
+    def drop(self, key: Optional[bytes]) -> None:
+        """The radix node this snapshot was bound to is gone."""
+        idx = self._snap.pop(key, None) if key is not None else None
+        if idx is not None:
+            self._free_snaps.append(idx)
+            self.evictions += 1
+            telemetry.inc("roundtable_state_snapshot_evictions_total",
+                          engine=self.engine)
+            self._publish()
+
+    def drop_all_snapshots(self) -> None:
+        for key in list(self._snap):
+            self.drop(key)
+
+    def bind_nodes(self, name: str, nodes: list) -> None:
+        """prefix_cache.insert hands over the radix path of a committed
+        slot: the snapshot of each page goes onto that page's node, and
+        is evicted with it."""
+        keys = self._keys.get(name) or []
+        for node, key in zip(nodes, keys):
+            if key in self._snap and getattr(node, "snap", None) is None:
+                node.snap = key
+
+    def holds(self, tokens: list[int], upto: int) -> bool:
+        keys = page_keys(tokens, self.page_size, upto)
+        return bool(keys) and keys[-1] in self._snap
+
+    def snapshot_bytes(self) -> int:
+        return len(self._snap) * self.bytes_per_state
+
+    def _publish(self) -> None:
+        telemetry.set_gauge("roundtable_state_snapshots", len(self._snap),
+                            engine=self.engine)
+        telemetry.set_gauge("roundtable_state_snapshot_bytes",
+                            self.snapshot_bytes(), engine=self.engine)
+
+    # --- counters -------------------------------------------------------
+
+    def note_counts(self, counts: jax.Array, pipelined: bool) -> None:
+        """Queue a dispatch's expert counts (int32 [3], an output of the
+        program just issued). What was queued before is folded first —
+        all of it, or all but the newest when this dispatch is a decode
+        segment issued before the last one is read (the pipeline is one
+        deep), so the fold never waits on a program in flight."""
+        self.fold_counts(keep=1 if pipelined else 0)
+        self._counts_pending.append(counts)
+
+    def fold_counts(self, keep: int = 0) -> None:
+        """Add queued counts to the host totals, oldest first, leaving
+        the newest `keep`. Dispatching thread only (the serve lock)."""
+        while len(self._counts_pending) > keep:
+            try:
+                got = np.asarray(self._counts_pending.popleft())
+            except Exception:  # noqa: BLE001 — its program failed: the
+                continue       # segment's own read has said so already
+            for i, name in enumerate(MOE_COUNTS):
+                self._moe_total[i] += int(got[i])
+                telemetry.inc(f"roundtable_moe_{name}_total", int(got[i]),
+                              engine=self.engine)
+
+    def moe_totals(self) -> dict[str, int]:
+        """Lifetime totals as folded so far. Host ints: any thread."""
+        return dict(zip(MOE_COUNTS, self._moe_total))
+
+    def moe_delta(self) -> dict[str, int]:
+        """What was folded since the last call."""
+        now = list(self._moe_total)
+        delta = {name: new - old for name, new, old in
+                 zip(MOE_COUNTS, now, self._moe_seen)}
+        self._moe_seen = now
+        return delta
+
+    def describe(self) -> dict[str, Any]:
+        return {
+            "slots": len(self._row_of), "slot_rows": self.num_slots,
+            "bytes_per_state": self.bytes_per_state,
+            "snapshots": len(self._snap),
+            "snapshot_capacity": self.capacity,
+            "bytes": self.snapshot_bytes(), "budget": self.budget_bytes,
+            "hits": self.hits, "misses": self.misses,
+            "evictions": self.evictions,
+            "snapshots_taken": self.snapshots_taken,
+            "continued_tokens": self.continued_tokens,
+            "reused_tokens": self.reused_tokens,
+            "rescanned_tokens": self.rescanned_tokens,
+            "share_declined": self.share_declined,
+        }

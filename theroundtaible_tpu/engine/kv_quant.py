@@ -109,7 +109,9 @@ def cell_bytes_per_token(cfg: Any, spec: Optional[KVQuantSpec],
     streamed-KV term all share."""
     per_cell = (spec.cell_bytes(cfg.head_dim) if spec is not None
                 else bf16_cell_bytes(cfg.head_dim, dtype_bytes))
-    return cfg.num_layers * 2 * cfg.num_kv_heads * per_cell
+    layers = getattr(cfg, "attention_layers", None)
+    n_layers = cfg.num_layers if layers is None else len(layers)
+    return n_layers * 2 * cfg.num_kv_heads * per_cell
 
 
 def page_ratio(spec: KVQuantSpec, head_dim: int,

@@ -230,7 +230,8 @@ def share_prefixes(kv, names, all_tokens, offsets, *, min_shared: int,
                    add_share, flush_shares, prefill_span,
                    extra_pinned: tuple[str, ...] = (),
                    defer_span=None,
-                   donor_ok=None) -> tuple[list[int], int]:
+                   donor_ok=None,
+                   decline_leader=None) -> tuple[list[int], int]:
     """Two-pass cross-knight shared-prefix reuse — THE algorithm, used by
     both serving engines so the donor cap, batch-common-prefix fold,
     l_shared clamp, laggard threshold and extra_prefill accounting cannot
@@ -275,6 +276,14 @@ def share_prefixes(kv, names, all_tokens, offsets, *, min_shared: int,
     uniform-adapter batches (engine._prepare_batch suppresses mixed
     ones).
 
+    `decline_leader(n_laggards)`: when given, a leader whose cache
+    does not cover the common span yet prefills NOTHING for the others
+    and the callback counts the decline — a model with recurrent state
+    cannot hand laggards the leader's state at the span's end through
+    this pass (every row scans the span itself, from the deepest
+    snapshot it finds). A leader that already covers the span still
+    aliases its pages: that is KV alone.
+
     Returns (updated offsets, leader-prefilled token count)."""
     b = len(names)
     pinned = tuple(names) + tuple(extra_pinned)
@@ -305,6 +314,9 @@ def share_prefixes(kv, names, all_tokens, offsets, *, min_shared: int,
     if not laggards:
         return offsets, extra_prefill
     if offsets[m] < l_shared:
+        if decline_leader is not None:
+            decline_leader(len(laggards))
+            return offsets, extra_prefill
         if defer_span is not None:
             defer_span(m, offsets[m], l_shared,
                        [(i, offsets[i]) for i in laggards])

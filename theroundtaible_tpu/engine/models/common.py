@@ -151,10 +151,65 @@ class ModelConfig:
     # attention; "flash" = Pallas blockwise kernels (engine/pallas/) that
     # stream KV through VMEM and skip blocks beyond each row's valid length
     attn_impl: str = "dense"
+    # Hybrid decoders (models/hybrid.py): one kind a layer, each layer ONE
+    # mixer behind one norm and a residual — "mamba2" | "experts" |
+    # "attention". None = the attention-plus-MLP block above, untouched.
+    layer_kinds: Optional[tuple[str, ...]] = None
+    rope: bool = True                 # False: no position embedding at all
+    # Mamba-2 mixer
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    conv_kernel: int = 4
+    mamba_chunk: int = 128
+    # Routed + shared experts, as ONE chip's share of an expert-parallel
+    # group: the router scores all `routed_experts`; this chip computes
+    # ids [expert_offset, expert_offset + experts_held).
+    routed_experts: int = 0
+    experts_held: int = 0
+    expert_offset: int = 0
+    moe_top_k: int = 0
+    expert_dim: int = 0
+    shared_expert_dim: int = 0
+    routed_scaling: float = 1.0
+    router_rule: str = "sigmoid_bias_topk"
 
     @property
     def kv_repeat(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    def _layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i, k in enumerate(self.layer_kinds or ())
+                     if k == kind)
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps state that is not pages."""
+        return bool(self._layers_of("mamba2"))
+
+    @property
+    def mamba_layers(self) -> tuple[int, ...]:
+        return self._layers_of("mamba2")
+
+    @property
+    def expert_layers(self) -> tuple[int, ...]:
+        return self._layers_of("experts")
+
+    @property
+    def attention_layers(self) -> tuple[int, ...]:
+        """The layers that own KV pages (every layer of a plain model)."""
+        if self.layer_kinds is None:
+            return tuple(range(self.num_layers))
+        return self._layers_of("attention")
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        return self.mamba_d_inner + 2 * self.ssm_groups * self.ssm_state
 
 
 # --- primitives ---
@@ -367,8 +422,11 @@ def project_qkv(
         k = k + layer["k_bias"].astype(jnp.float32)
         v = v + layer["v_bias"].astype(jnp.float32)
 
-    q = rope(q.astype(x.dtype), positions, cfg.rope_theta)
-    k = rope(k.astype(x.dtype), positions, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q.astype(x.dtype), positions, cfg.rope_theta)
+        k = rope(k.astype(x.dtype), positions, cfg.rope_theta)
+    else:
+        q, k = q.astype(x.dtype), k.astype(x.dtype)
     v = v.astype(x.dtype)
 
     scale = (cfg.query_pre_attn_scalar
@@ -568,6 +626,14 @@ def forward(
     post-hoc dynamic slice back through the
     einsum; callers that only need the last valid row must pass
     last_pos instead of slicing the result."""
+    if cfg.layer_kinds is not None:
+        if kv_caches is not None:
+            raise ValueError(
+                f"{cfg.name}: a model with layer_kinds serves through "
+                "the paged layout (engine/paged_forward.py); forward() "
+                "runs it only whole, from position 0, with no cache")
+        return _forward_hybrid_whole(params, cfg, tokens, positions,
+                                     kv_valid_len, last_pos)
     # Activations follow the param dtype: bf16 params (serving) keep the
     # whole network bf16; f32 params (HF logit-parity tests) stay f32.
     x = embed_tokens(params["embedding"], tokens)
@@ -597,6 +663,38 @@ def forward(
     return logits, new_caches
 
 
+def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
+                          last_pos):
+    """A hybrid decoder over whole sequences from position 0, no cache:
+    the serving path's own layer functions (chunked scan, masked expert
+    loop, dense causal attention), for tests and one-shot scoring."""
+    from . import hybrid
+    b, t = tokens.shape
+    x = embed_tokens(params["embedding"], tokens)
+    mask = make_attention_mask(positions, t, kv_valid_len,
+                               cfg.sliding_window)
+    zero = hybrid.zero_state(cfg, b)
+    caches = []
+    for kind, layer in zip(cfg.layer_kinds, params["layers"]):
+        h = hybrid.layer_norm_in(x, layer, cfg)
+        if kind == hybrid.MAMBA2:
+            out, _, _ = hybrid.mamba2_prefill(
+                h, layer, cfg, zero["ssm"][0], zero["conv"][0],
+                kv_valid_len)
+        elif kind == hybrid.EXPERTS:
+            out, _ = hybrid.experts_mlp(h, layer, cfg)
+        else:
+            out, kv = attention(h, layer, cfg, positions, None, None,
+                                mask, kv_valid_len)
+            caches.append(kv)
+        x = x + out
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
+    if last_pos is not None:
+        x = gather_rows(x, last_pos)
+    logits = _einsum("bte,ve->btv", x, params["lm_head"], tp="col")
+    return logits, caches
+
+
 def gather_rows(x: jax.Array, pos: jax.Array) -> jax.Array:
     """Gather one T-row per batch element: [B,T,E], [B] → [B,1,E]."""
     idx = jnp.broadcast_to(pos[:, None, None],
@@ -611,6 +709,24 @@ def init_params(cfg: ModelConfig, key: jax.Array,
                 dtype=jnp.bfloat16) -> Params:
     """Random init with sane scales — used for tests and weight-free bench."""
     k_embed, k_layers = jax.random.split(key)
+    if cfg.layer_kinds is not None:
+        from . import hybrid
+        keys = jax.random.split(k_layers, cfg.num_layers)
+        k_head = jax.random.fold_in(k_embed, 1)
+        scale = cfg.embed_dim ** -0.5
+        # The embedding at unit rms: the residual stream the mixers add
+        # their share to (hybrid.RESIDUAL_SHARE).
+        return {
+            "embedding": jax.random.normal(
+                k_embed, (cfg.vocab_size, cfg.embed_dim),
+                jnp.float32).astype(dtype),
+            "layers": [hybrid.init_layer(cfg, kind, lk, dtype)
+                       for kind, lk in zip(cfg.layer_kinds, keys)],
+            "final_norm": jnp.ones((cfg.embed_dim,), dtype),
+            "lm_head": (jax.random.normal(
+                k_head, (cfg.vocab_size, cfg.embed_dim), jnp.float32)
+                * scale).astype(dtype),
+        }
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, dtype=jnp.float32)

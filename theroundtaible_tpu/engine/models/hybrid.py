@@ -1,0 +1,550 @@
+"""The layers of a hybrid decoder (`ModelConfig.layer_kinds`): a Mamba-2
+mixer, a layer of routed and shared experts, and the block wiring in
+which every layer is ONE mixer behind one RMSNorm and a residual
+(`nemotron_h`). Attention layers reuse `common.project_qkv` / the paged
+kernels; what is here is what those models add.
+
+Mamba-2 (state-space duality form). Per head, with state S in
+R^{P x N} kept in float32:
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t
+    y_t = S_t C_t + D x_t
+
+Three programs compute it, all from `_ssd_chunk` (one chunk's products
+on the MXU, one state hand-over a chunk):
+
+- `mamba2_prefill`: [B, T] rows, chunk `cfg.mamba_chunk` (128), each
+  row from its own state; also the state after `cap_len` tokens (a
+  snapshot at a page boundary costs one more small product).
+- `mamba2_ragged`: the scheduler's flat token buffer. Its blocks of
+  RAGGED_BLOCK_Q rows belong to one sequence each, so the chunk is the
+  block: the scan reads each block's sequence state from the slot
+  array, advances it, and writes it back — a run restarts from its
+  slot's state at every sequence boundary by construction.
+- `mamba2_step`: one token a row, the recurrence itself.
+
+A token with dt = 0 is the identity on the state (exp(0) = 1, no
+input), which is how pad tokens and finished rows are masked.
+
+Experts (`experts_mlp`): sigmoid scores over ALL published experts,
+top-k of score + bias, weights renormalised and scaled; the chip
+computes the part of the result its own experts give
+(`expert_offset <= id < offset + experts_held`) and the shared expert.
+The routed part loops over the held experts with the token weights as
+a mask, and skips an expert no token chose (`lax.cond`): static
+shapes, no token ever dropped, and a decode step reads only the
+experts it hit. A gathered (sorted / grouped) product would do a
+twentieth of the prefill arithmetic at 64 held experts; it is left to
+a PR that can measure it (PERF.md, PR 27).
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from .common import ModelConfig, Params, _einsum, rms_norm
+
+MAMBA2, EXPERTS, ATTENTION = "mamba2", "experts", "attention"
+LAYER_KINDS = (MAMBA2, EXPERTS, ATTENTION)
+PATTERN_LETTERS = {"M": MAMBA2, "E": EXPERTS, "*": ATTENTION}
+
+
+def kinds_of_pattern(pattern: str) -> tuple[str, ...]:
+    """`hybrid_override_pattern` -> layer kinds. A letter this module
+    has no layer for ('-', a plain MLP layer) fails here."""
+    try:
+        return tuple(PATTERN_LETTERS[c] for c in pattern)
+    except KeyError as e:
+        raise ValueError(
+            f"hybrid_override_pattern has a layer kind {e.args[0]!r} "
+            f"this engine does not run (known: "
+            f"{''.join(PATTERN_LETTERS)})") from None
+
+
+# --- Mamba-2 ---------------------------------------------------------------
+
+
+def _split_in_proj(zxbcdt: jax.Array, cfg: ModelConfig):
+    d_in, conv = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    return (zxbcdt[..., :d_in], zxbcdt[..., d_in:d_in + conv],
+            zxbcdt[..., d_in + conv:])
+
+
+def _split_xbc(xbc: jax.Array, cfg: ModelConfig):
+    """[..., conv_dim] -> x [..., H, P], B [..., G, N], C [..., G, N]."""
+    d_in = cfg.mamba_d_inner
+    gn = cfg.ssm_groups * cfg.ssm_state
+    lead = xbc.shape[:-1]
+    x = xbc[..., :d_in].reshape(*lead, cfg.mamba_heads, cfg.mamba_head_dim)
+    b = xbc[..., d_in:d_in + gn].reshape(*lead, cfg.ssm_groups,
+                                         cfg.ssm_state)
+    c = xbc[..., d_in + gn:].reshape(*lead, cfg.ssm_groups, cfg.ssm_state)
+    return x, b, c
+
+
+def _conv_taps(rows: list, layer: Params) -> jax.Array:
+    """silu(sum_k w[k] * rows[k] + b): rows[k] is the input K-1-k tokens
+    back (rows[-1] the token itself), float32."""
+    w = layer["conv_w"].astype(jnp.float32)              # [K, C]
+    acc = layer["conv_b"].astype(jnp.float32)
+    for k, r in enumerate(rows):
+        acc = acc + w[k] * r
+    return jax.nn.silu(acc)
+
+
+def _dt_of(dt_raw: jax.Array, layer: Params) -> jax.Array:
+    return jax.nn.softplus(dt_raw.astype(jnp.float32)
+                           + layer["dt_bias"].astype(jnp.float32))
+
+
+def _gated_out(y: jax.Array, z: jax.Array, layer: Params,
+               cfg: ModelConfig, dtype) -> jax.Array:
+    """y * silu(z), RMS-normalised over each group of d_in / groups
+    channels, times the norm's weight, then the out-projection.
+    y [..., H, P] float32, z [..., d_in]."""
+    lead = y.shape[:-2]
+    g = cfg.ssm_groups
+    y = y.reshape(*lead, cfg.mamba_d_inner) \
+        * jax.nn.silu(z.astype(jnp.float32))
+    yg = y.reshape(*lead, g, cfg.mamba_d_inner // g)
+    yg = yg * jax.lax.rsqrt(
+        jnp.mean(jnp.square(yg), axis=-1, keepdims=True) + cfg.norm_eps)
+    y = yg.reshape(*lead, cfg.mamba_d_inner) \
+        * layer["gate_norm"].astype(jnp.float32)
+    return _einsum("...f,fe->...e", y.astype(dtype),
+                   layer["out_proj"]).astype(dtype)
+
+
+def _ssd_chunk(x, dt, a_neg, bm, cm, d_skip, s_in, cap_idx=None):
+    """One chunk of the scan for B rows.
+
+    x [B,Q,H,P], dt [B,Q,H] (0 = identity token), a_neg [H] (< 0),
+    bm/cm [B,Q,G,N], d_skip [H], s_in [B,H,P,N]; all float32.
+    -> y [B,Q,H,P], s_out [B,H,P,N], and with `cap_idx` [B] (index in
+    the chunk of the last token consumed) the state after that token.
+    """
+    b_, q, h, p = x.shape
+    g = bm.shape[2]
+    rep = h // g
+    la = dt * a_neg                                       # [B,Q,H] <= 0
+    cs = jnp.cumsum(la, axis=1)                           # inclusive
+    xdt = x * dt[..., None]                               # [B,Q,H,P]
+    # Heads as (group, head-in-group): B and C are per group.
+    xdt_g = xdt.reshape(b_, q, g, rep, p)
+    cs_g = cs.reshape(b_, q, g, rep)
+    s_g = s_in.reshape(b_, g, rep, p, -1)
+    cb = jnp.einsum("bign,bjgn->bgij", cm, bm,
+                    preferred_element_type=jnp.float32)   # [B,G,Q,Q]
+    seg = cs_g[:, :, None] - cs_g[:, None, :]             # [B,Qi,Qj,G,R]
+    causal = jnp.tril(jnp.ones((q, q), bool))[None, :, :, None, None]
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    m = cb.transpose(0, 2, 3, 1)[..., None] * decay       # [B,Qi,Qj,G,R]
+    y = jnp.einsum("bijgr,bjgrp->bigrp", m, xdt_g,
+                   preferred_element_type=jnp.float32)
+    y = y + jnp.exp(cs_g)[..., None] * jnp.einsum(
+        "bign,bgrpn->bigrp", cm, s_g,
+        preferred_element_type=jnp.float32)
+    y = y.reshape(b_, q, h, p) + d_skip[None, None, :, None] * x
+
+    def state_after(cs_at, keep):
+        # cs_at [B,G,R]: cumulative log-decay at the token the state is
+        # taken after; keep [B,Q] bool: tokens at or before it.
+        w = jnp.where(keep[:, :, None, None],
+                      jnp.exp(cs_at[:, None] - cs_g), 0.0)  # [B,Q,G,R]
+        new = jnp.einsum("bjgr,bjgrp,bjgn->bgrpn", w, xdt_g, bm,
+                         preferred_element_type=jnp.float32)
+        return (jnp.exp(cs_at)[..., None, None] * s_g + new) \
+            .reshape(s_in.shape)
+
+    s_out = state_after(cs_g[:, -1], jnp.ones((b_, q), bool))
+    if cap_idx is None:
+        return y, s_out
+    idx = jnp.clip(cap_idx, 0, q - 1)
+    cs_at = jnp.take_along_axis(
+        cs_g, idx[:, None, None, None], axis=1)[:, 0]
+    s_cap = state_after(cs_at, jnp.arange(q)[None, :] <= idx[:, None])
+    return y, s_out, s_cap
+
+
+def _tail_rows(ext: jax.Array, lengths: jax.Array, k1: int) -> jax.Array:
+    """Rows [len, len + K-1) of ext = [old tail (K-1 rows); the run's
+    rows]: the last K-1 inputs of a run of `lengths` tokens.
+    ext [B, K-1+T, C], lengths [B] -> [B, K-1, C]."""
+    idx = jnp.clip(lengths[:, None] + jnp.arange(k1)[None, :], 0,
+                   ext.shape[1] - 1)
+    return jnp.take_along_axis(ext, idx[:, :, None], axis=1)
+
+
+def mamba2_prefill(h: jax.Array, layer: Params, cfg: ModelConfig,
+                   ssm0: jax.Array, conv0: jax.Array,
+                   lengths: jax.Array,
+                   cap_len: Optional[jax.Array] = None):
+    """A Mamba-2 mixer over [B, T] rows, each from its own state.
+
+    h [B,T,E] (normed input), ssm0 [B,H,P,N] f32, conv0 [B,K-1,C] f32,
+    lengths [B] valid tokens a row. -> (out [B,T,E], ssm [B,H,P,N],
+    conv [B,K-1,C]) after `lengths` tokens, and with `cap_len` [B]
+    (1..lengths; anything else: garbage the caller drops) also
+    (ssm_cap, conv_cap) after `cap_len` tokens."""
+    b_, t, _e = h.shape
+    k1 = cfg.conv_kernel - 1
+    z, xbc, dt_raw = _split_in_proj(
+        _einsum("bte,ef->btf", h, layer["in_proj"]), cfg)
+    ext = jnp.concatenate([conv0, xbc.astype(jnp.float32)], axis=1)
+    xbc = _conv_taps([ext[:, k:k + t] for k in range(cfg.conv_kernel)],
+                     layer)
+    x, bm, cm = _split_xbc(xbc, cfg)
+    valid = jnp.arange(t)[None, :] < lengths[:, None]
+    dt = jnp.where(valid[..., None], _dt_of(dt_raw, layer), 0.0)
+    a_neg = -jnp.exp(layer["A_log"].astype(jnp.float32))
+    d_skip = layer["D"].astype(jnp.float32)
+
+    q = min(cfg.mamba_chunk, t)
+    n_c = -(-t // q)
+    pad = n_c * q - t
+
+    def chunks(a):
+        if pad:
+            a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape(b_, n_c, q, *a.shape[2:]), 1, 0)
+
+    want_cap = cap_len is not None
+    last = (cap_len if want_cap else lengths) - 1         # token index
+    cap_chunk, cap_idx = last // q, last % q
+
+    def body(carry, xs):
+        s, s_cap = carry
+        ci, xc, dtc, bc, cc = xs
+        if want_cap:
+            y, s_new, s_at = _ssd_chunk(xc, dtc, a_neg, bc, cc, d_skip,
+                                        s, cap_idx)
+            hit = (ci == cap_chunk)[:, None, None, None]
+            s_cap = jnp.where(hit, s_at, s_cap)
+        else:
+            y, s_new = _ssd_chunk(xc, dtc, a_neg, bc, cc, d_skip, s)
+        return (s_new, s_cap), y
+
+    s_cap0 = ssm0 if want_cap else jnp.zeros((), jnp.float32)
+    (ssm, ssm_cap), ys = jax.lax.scan(
+        body, (ssm0, s_cap0),
+        (jnp.arange(n_c), chunks(x), chunks(dt), chunks(bm), chunks(cm)))
+    y = jnp.moveaxis(ys, 0, 1).reshape(b_, n_c * q, *ys.shape[3:])[:, :t]
+    out = _gated_out(y, z, layer, cfg, h.dtype)
+    conv = _tail_rows(ext, lengths, k1)
+    if not want_cap:
+        return out, ssm, conv
+    return out, ssm, conv, ssm_cap, _tail_rows(ext, cap_len, k1)
+
+
+def mamba2_step(h: jax.Array, layer: Params, cfg: ModelConfig,
+                ssm: jax.Array, conv: jax.Array, active: jax.Array):
+    """One decode token a row: the recurrence. h [B,1,E]; rows with
+    `active` False keep their state (a finished row still rides the
+    batch). -> (out [B,1,E], ssm, conv)."""
+    z, xbc, dt_raw = _split_in_proj(
+        _einsum("bte,ef->btf", h, layer["in_proj"]), cfg)
+    cur = xbc[:, 0].astype(jnp.float32)                   # [B,C]
+    k1 = cfg.conv_kernel - 1
+    xc = _conv_taps([conv[:, k] for k in range(k1)] + [cur], layer)
+    x, bm, cm = _split_xbc(xc, cfg)                       # [B,H,P] [B,G,N]
+    dt = _dt_of(dt_raw[:, 0], layer)                      # [B,H]
+    a_neg = -jnp.exp(layer["A_log"].astype(jnp.float32))
+    b_, hh, p = x.shape
+    g = bm.shape[1]
+    rep = hh // g
+    xg = x.reshape(b_, g, rep, p)
+    dtg = dt.reshape(b_, g, rep)
+    sg = ssm.reshape(b_, g, rep, p, -1)
+    new = (jnp.exp(dtg * a_neg.reshape(g, rep))[..., None, None] * sg
+           + (dtg[..., None] * xg)[..., None] * bm[:, :, None, None, :])
+    y = jnp.einsum("bgrpn,bgn->bgrp", new, cm,
+                   preferred_element_type=jnp.float32)
+    y = y.reshape(b_, hh, p) \
+        + layer["D"].astype(jnp.float32)[None, :, None] * x
+    out = _gated_out(y[:, None], z, layer, cfg, h.dtype)
+    keep = active[:, None, None, None]
+    ssm = jnp.where(keep, new.reshape(ssm.shape), ssm)
+    conv_new = jnp.concatenate([conv[:, 1:], cur[:, None]], axis=1)
+    conv = jnp.where(active[:, None, None], conv_new, conv)
+    return out, ssm, conv
+
+
+def mamba2_ragged(h: jax.Array, layer: Params, cfg: ModelConfig,
+                  ssm_all: jax.Array, conv_all: jax.Array, rg: dict):
+    """A Mamba-2 mixer over the flat token buffer.
+
+    h [1,T,E]; ssm_all [R,H,P,N] / conv_all [R,K-1,C]: EVERY slot's
+    state (row R-1 is scratch: pads land there). `rg` (built once a
+    dispatch by `ragged_meta`): token_seq [T], run_idx [T] (index of
+    the token in its run), token_valid [T], seq_slot [S], seq_start
+    [S], seq_len [S], block_slot / seq_of_block / block_qstart [T/Q],
+    cap_n [S] (snapshot after this many tokens of the run; 0: none).
+    -> (out [1,T,E], ssm_all, conv_all, cap_ssm [S,H,P,N], cap_conv
+    [S,K-1,C]): every sequence's slot advanced by its run, and the
+    state of each at its snapshot point (rows with cap_n 0: garbage)."""
+    t = h.shape[1]
+    k1 = cfg.conv_kernel - 1
+    q = rg["block"]
+    z, xbc, dt_raw = _split_in_proj(
+        _einsum("bte,ef->btf", h, layer["in_proj"]), cfg)
+    raw = xbc[0].astype(jnp.float32)                      # [T,C]
+    tok_slot = rg["seq_slot"][rg["token_seq"]]            # [T]
+    run_idx = rg["run_idx"]
+    rows = []
+    for back in range(k1, 0, -1):
+        # The input `back` tokens ago: in the buffer while the run
+        # reaches that far, else in the slot's tail.
+        in_run = run_idx >= back
+        prev = raw[jnp.clip(jnp.arange(t) - back, 0, t - 1)]
+        tail = conv_all[tok_slot, jnp.clip(k1 + run_idx - back, 0,
+                                           k1 - 1)]
+        rows.append(jnp.where(in_run[:, None], prev, tail))
+    xc = _conv_taps(rows + [raw], layer)
+    x, bm, cm = _split_xbc(xc, cfg)                       # [T,H,P] ...
+    dt = jnp.where(rg["token_valid"][:, None],
+                   _dt_of(dt_raw[0], layer), 0.0)
+    a_neg = -jnp.exp(layer["A_log"].astype(jnp.float32))
+    d_skip = layer["D"].astype(jnp.float32)
+    nb = t // q
+
+    def blocks(a):
+        return a.reshape(nb, 1, q, *a.shape[1:])
+
+    # The block holding the last token before a sequence's snapshot
+    # point, and that token's index in it (-1: no snapshot here).
+    cap_n = rg["cap_n"]                                   # [S]
+    seq_b = rg["seq_of_block"]
+    last = cap_n[seq_b] - 1
+    cap_idx = jnp.where((cap_n[seq_b] > 0)
+                        & (rg["block_qstart"] == last // q * q),
+                        last % q, -1)                     # [T/Q]
+
+    def body(carry, xs):
+        state, caps = carry
+        slot, seq, cidx, xc_, dtc, bc, cc = xs
+        s_in = jax.lax.dynamic_index_in_dim(state, slot, 0, keepdims=True)
+        y, s_out, s_cap = _ssd_chunk(xc_, dtc, a_neg, bc, cc, d_skip, s_in,
+                                     jnp.maximum(cidx, 0)[None])
+        state = jax.lax.dynamic_update_index_in_dim(state, s_out[0],
+                                                    slot, 0)
+        caps = jax.lax.cond(
+            cidx >= 0,
+            lambda c: jax.lax.dynamic_update_index_in_dim(c, s_cap[0],
+                                                          seq, 0),
+            lambda c: c, caps)
+        return (state, caps), y[0]
+
+    caps0 = jnp.zeros((cap_n.shape[0],) + ssm_all.shape[1:], jnp.float32)
+    (ssm_all, cap_ssm), ys = jax.lax.scan(
+        body, (ssm_all, caps0),
+        (rg["block_slot"], seq_b, cap_idx, blocks(x), blocks(dt),
+         blocks(bm), blocks(cm)))
+    y = ys.reshape(t, *ys.shape[2:])
+    out = _gated_out(y[None], z, layer, cfg, h.dtype)
+
+    def tails(n):
+        # The last K-1 inputs of [old tail; the run's first n rows].
+        j = jnp.arange(k1)[None, :]
+        src = n[:, None] - k1 + j                         # index in run
+        from_run = raw[jnp.clip(rg["seq_start"][:, None] + src, 0, t - 1)]
+        old = conv_all[rg["seq_slot"][:, None],
+                       jnp.clip(n[:, None] + j, 0, k1 - 1)]
+        return jnp.where((src >= 0)[..., None], from_run, old)
+
+    cap_conv = tails(cap_n)
+    conv_all = conv_all.at[rg["seq_slot"]].set(tails(rg["seq_len"]))
+    return out, ssm_all, conv_all, cap_ssm, cap_conv
+
+
+def ragged_meta(positions, token_seq, query_offsets, kv_valid, last_rows,
+                seq_of_block, block_qstart, seq_slot, cap_n,
+                block: int) -> dict:
+    """What the Mamba-2 layers of one ragged dispatch share, from the
+    flat buffer's own arrays (serving_loop.build_ragged_batch) and the
+    state slot of every sequence (pads: the scratch row)."""
+    seq_len = kv_valid - query_offsets                    # tokens a run
+    run_idx = positions - query_offsets[token_seq]
+    return {
+        "block": block, "token_seq": token_seq, "run_idx": run_idx,
+        "token_valid": run_idx < seq_len[token_seq],
+        "seq_slot": seq_slot, "seq_len": seq_len,
+        "seq_start": last_rows - (seq_len - 1),
+        "block_slot": seq_slot[seq_of_block],
+        "seq_of_block": seq_of_block, "block_qstart": block_qstart,
+        "cap_n": cap_n,
+    }
+
+
+# --- experts ---------------------------------------------------------------
+
+
+def _relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+def route(h: jax.Array, layer: Params, cfg: ModelConfig):
+    """The router's rule (DeepSeek-V3's, as `nemotron_h` uses it with
+    one group): s = sigmoid(h W_r) in float32 over ALL published
+    experts; choose top-k of s + bias; weights s[chosen] / (sum +
+    1e-20) * scale. h [T,E] -> (ids [T,k] int32, weights [T,k] f32)."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "te,ex->tx", h.astype(jnp.float32),
+        layer["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(
+        s + layer["router_bias"].astype(jnp.float32), cfg.moe_top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) \
+        * cfg.routed_scaling
+    return ids.astype(jnp.int32), w
+
+
+def experts_mlp(h: jax.Array, layer: Params, cfg: ModelConfig,
+                token_mask: Optional[jax.Array] = None):
+    """Routed experts held here + the shared expert. h [..., T, E] ->
+    (out, counts int32[2]): counts = (held experts some counted token
+    chose, assignments of counted tokens to held experts) — what a step
+    must read of the experts, for the `moe.*` metrics. `token_mask`
+    [..., T] says which tokens count (pads and finished rows do not);
+    every token is still computed."""
+    lead = h.shape[:-1]
+    x = h.reshape(-1, h.shape[-1])                        # [T,E]
+    t = x.shape[0]
+    ids, w = route(x, layer, cfg)
+    held, off = cfg.experts_held, cfg.expert_offset
+    local = ids - off
+    here = (local >= 0) & (local < held)
+    # Per held expert, each token's weight (0: not chosen).
+    dense = jnp.sum(
+        jnp.where(here[..., None],
+                  jax.nn.one_hot(local, held, dtype=jnp.float32)
+                  * w[..., None], 0.0), axis=1)           # [T,held]
+    counted = (jnp.ones((t,), bool) if token_mask is None
+               else token_mask.reshape(-1))
+    chosen = (dense > 0) & counted[:, None]
+    counts = jnp.stack([
+        jnp.sum(jnp.any(chosen, axis=0)),
+        jnp.sum(here & counted[:, None])]).astype(jnp.int32)
+
+    def one(acc, xs):
+        up, down, wt = xs
+
+        def run(acc):
+            a = _relu2(_einsum("te,ef->tf", x, up)).astype(x.dtype)
+            y = _einsum("tf,fe->te", a, down)
+            return acc + y * wt[:, None]
+
+        return jax.lax.cond(jnp.any(wt > 0), run, lambda a: a, acc), None
+
+    routed, _ = jax.lax.scan(
+        one, jnp.zeros((t, x.shape[-1]), jnp.float32),
+        (layer["experts"]["up"], layer["experts"]["down"], dense.T))
+    shared = _einsum(
+        "tf,fe->te",
+        _relu2(_einsum("te,ef->tf", x, layer["shared"]["up"]))
+        .astype(x.dtype), layer["shared"]["down"])
+    out = (routed + shared).astype(h.dtype)
+    return out.reshape(*lead, -1), counts
+
+
+# --- the block -------------------------------------------------------------
+
+
+def layer_norm_in(x: jax.Array, layer: Params, cfg: ModelConfig):
+    return rms_norm(x, layer["norm"], cfg.norm_eps, False)
+
+
+# What a mixer's out-projection adds to a residual stream of unit rms
+# (the embedding's, models/common.py: init_params), in random weights.
+# With every mixer at unit scale the embedding is lost after one layer
+# and one changed expert moves the logits by 0.17 sigma — and a top-k
+# router changes experts on rounding noise: in bfloat16 a tenth of the
+# served tokens then lay over 0.25 sigma from a float32 reference's
+# maximum. A trained stack's layers each add a fraction. Measured at the
+# benchmark's widths (PERF.md, PR 27): at 0.1 (the depth-scaled residual
+# init, 1/sqrt(2 x 52)) the worst of 4608 positions lay 0.22 sigma off,
+# at 0.07 the worst of 2304 lay 0.07 off.
+RESIDUAL_SHARE = 0.07
+
+
+def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
+               dtype) -> Params:
+    """Random weights of one layer, by kind: in-projections at the
+    scale that keeps activations of order one, out-projections at
+    RESIDUAL_SHARE of it."""
+    e = cfg.embed_dim
+    ks = jax.random.split(key, 8)
+
+    def dense(key, shape, fan_in, share=1.0):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * (share * fan_in ** -0.5)).astype(dtype)
+
+    layer: Params = {"norm": jnp.ones((e,), dtype)}
+    if kind == MAMBA2:
+        hh, d_in, conv = (cfg.mamba_heads, cfg.mamba_d_inner,
+                          cfg.mamba_conv_dim)
+        # dt from the published range [time_step_min, time_step_max],
+        # log-uniform; A in [1, 16]: the reference implementation's init.
+        dt = jnp.exp(jax.random.uniform(ks[2], (hh,), jnp.float32)
+                     * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+        layer.update({
+            "in_proj": dense(ks[0], (e, 2 * d_in + 2 * cfg.ssm_groups
+                                     * cfg.ssm_state + hh), e),
+            "conv_w": dense(ks[1], (cfg.conv_kernel, conv),
+                            cfg.conv_kernel),
+            "conv_b": jnp.zeros((conv,), dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(jnp.float32),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[3], (hh,), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((hh,), jnp.float32),
+            "gate_norm": jnp.ones((d_in,), dtype),
+            "out_proj": dense(ks[4], (d_in, e), d_in, RESIDUAL_SHARE),
+        })
+    elif kind == EXPERTS:
+        f, fs, held = cfg.expert_dim, cfg.shared_expert_dim, \
+            cfg.experts_held
+        layer.update({
+            "router": dense(ks[0], (e, cfg.routed_experts), e)
+            .astype(jnp.float32),
+            "router_bias": jax.random.normal(
+                ks[1], (cfg.routed_experts,), jnp.float32) * 0.02,
+            "experts": {"up": dense(ks[2], (held, e, f), e),
+                        "down": dense(ks[3], (held, f, e), f,
+                                      RESIDUAL_SHARE)},
+            "shared": {"up": dense(ks[4], (e, fs), e),
+                       "down": dense(ks[5], (fs, e), fs,
+                                     RESIDUAL_SHARE)},
+        })
+    elif kind == ATTENTION:
+        h_, k_, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        layer.update({
+            "q_proj": dense(ks[0], (e, h_, d), e),
+            "k_proj": dense(ks[1], (e, k_, d), e),
+            "v_proj": dense(ks[2], (e, k_, d), e),
+            "o_proj": dense(ks[3], (h_, d, e), h_ * d, RESIDUAL_SHARE),
+        })
+    else:
+        raise ValueError(f"unknown layer kind {kind!r}")
+    return layer
+
+
+def zero_state(cfg: ModelConfig, rows: int) -> dict:
+    """The recurrent state of `rows` sequences, one entry a Mamba-2
+    layer: {"ssm": [[rows,H,P,N] f32...], "conv": [[rows,K-1,C] f32...]}."""
+    n = len(cfg.mamba_layers)
+    return {
+        "ssm": [jnp.zeros((rows, cfg.mamba_heads, cfg.mamba_head_dim,
+                           cfg.ssm_state), jnp.float32) for _ in range(n)],
+        "conv": [jnp.zeros((rows, cfg.conv_kernel - 1,
+                            cfg.mamba_conv_dim), jnp.float32)
+                 for _ in range(n)],
+    }
+
+
+def state_bytes_per_sequence(cfg: ModelConfig) -> int:
+    per = (cfg.mamba_heads * cfg.mamba_head_dim * cfg.ssm_state
+           + (cfg.conv_kernel - 1) * cfg.mamba_conv_dim) * 4
+    return per * len(cfg.mamba_layers)

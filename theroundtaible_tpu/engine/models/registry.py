@@ -1,13 +1,26 @@
-"""Model registry — the reference-targeted open-weight families
-(BASELINE.md configs: Gemma-2B/7B, Llama-3-8B/3.2, Mistral-7B) plus
-Mixtral (MoE), Qwen2.5 (attention bias) and tiny test presets.
-Architecture behavior lives in ModelConfig flags (common.py); a family
-here is a named hyperparameter set.
+"""Model registry — named hyperparameter sets, and the resolver that
+builds one from a published `config.json`.
+
+Presets: the reference-targeted open-weight families (BASELINE.md:
+Gemma-2B/7B, Llama-3-8B/3.2, Mistral-7B), Mixtral (compute-dense MoE),
+Qwen2.5 (attention bias), Nemotron-3-Nano (hybrid: Mamba-2, routed and
+shared experts, attention — models/hybrid.py) and tiny test presets.
+Architecture behavior lives in ModelConfig fields (common.py).
+
+`resolve_model_config(adapter_config)` is the one way an engine gets its
+ModelConfig: from the `architecture` block of the adapter's config (the
+published config.json's own keys, plus the chip's share of an
+expert-parallel group) under the name in `model`, else by name from the
+presets. A model the presets lack is served by giving its published
+keys; a key or `model_type` this engine cannot run fails at once.
 """
 
 from __future__ import annotations
 
+from typing import Any
+
 from .common import ModelConfig
+from .hybrid import kinds_of_pattern
 
 _REGISTRY: dict[str, ModelConfig] = {}
 
@@ -104,6 +117,156 @@ TINY_MIXTRAL = register(ModelConfig(
     num_heads=4, num_kv_heads=2, head_dim=16, mlp_dim=128,
     max_seq_len=512, tie_embeddings=False,
     num_experts=4, num_experts_per_tok=2))
+
+
+# --- Nemotron-3-Nano / nemotron_h (one mixer a layer: Mamba-2 | experts |
+# attention without position embedding; sigmoid router, squared ReLU) ---
+
+NEMOTRON3_NANO = register(ModelConfig(
+    name="nemotron-3-nano-30b-a3b", vocab_size=131_072, num_layers=52,
+    embed_dim=2688, num_heads=32, num_kv_heads=2, head_dim=128,
+    mlp_dim=1856, max_seq_len=8192, norm_eps=1e-5, tie_embeddings=False,
+    rope=False,
+    layer_kinds=kinds_of_pattern(
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"),
+    mamba_heads=64, mamba_head_dim=64, ssm_state=128, ssm_groups=8,
+    conv_kernel=4, mamba_chunk=128,
+    routed_experts=128, experts_held=128, expert_offset=0, moe_top_k=6,
+    expert_dim=1856, shared_expert_dim=3712, routed_scaling=2.5))
+
+TINY_NEMOTRON_H = register(ModelConfig(
+    name="tiny-nemotron-h", vocab_size=512, num_layers=5, embed_dim=64,
+    num_heads=4, num_kv_heads=2, head_dim=16, mlp_dim=32,
+    max_seq_len=512, norm_eps=1e-5, tie_embeddings=False, rope=False,
+    layer_kinds=kinds_of_pattern("ME*ME"),
+    mamba_heads=4, mamba_head_dim=16, ssm_state=16, ssm_groups=2,
+    conv_kernel=4, mamba_chunk=128,
+    routed_experts=8, experts_held=8, expert_offset=0, moe_top_k=2,
+    expert_dim=32, shared_expert_dim=64, routed_scaling=2.5))
+
+
+# --- from a published config.json -------------------------------------------
+
+# Keys of a nemotron_h config.json that say nothing this engine acts on
+# (initialisation ranges, HF runtime switches).
+_NEMOTRON_INERT = {
+    "num_logits_to_keep", "rescale_prenorm_residual", "residual_in_fp32",
+    "time_step_floor", "time_step_max", "time_step_min",
+    "use_mamba_kernels", "expand", "intermediate_size",
+    "max_position_embeddings", "rope_theta", "partial_rotary_factor",
+    "layer_norm_epsilon", "model_type"}
+# ... and the values the layer equations of models/hybrid.py assume.
+_NEMOTRON_FIXED = {
+    "attention_bias": False, "mamba_proj_bias": False, "mlp_bias": False,
+    "use_bias": False, "use_conv_bias": True, "mamba_hidden_act": "silu",
+    "mlp_hidden_act": "relu2", "n_group": 1, "topk_group": 1,
+    "n_shared_experts": 1, "norm_topk_prob": True, "sliding_window": None,
+    "tie_word_embeddings": False}
+_DENSE_TYPES = ("llama", "mistral", "qwen2")
+
+
+def _nemotron_h(name: str, arch: dict[str, Any],
+                max_seq_len: int) -> ModelConfig:
+    arch = dict(arch)
+    for key, want in _NEMOTRON_FIXED.items():
+        got = arch.pop(key, want)
+        if got != want:
+            raise ValueError(
+                f"architecture of {name!r}: {key}={got!r}, and this "
+                f"engine's nemotron_h layers are written for {want!r}")
+    for key in _NEMOTRON_INERT:
+        arch.pop(key, None)
+    try:
+        kinds = kinds_of_pattern(arch.pop("hybrid_override_pattern"))
+        n_layers = int(arch.pop("num_hidden_layers"))
+        held = int(arch.pop("n_routed_experts"))
+        ep_size = int(arch.pop("ep_size", 1))
+        ep_rank = int(arch.pop("ep_rank", 0))
+        cfg = ModelConfig(
+            name=name, vocab_size=int(arch.pop("vocab_size")),
+            num_layers=n_layers, embed_dim=int(arch.pop("hidden_size")),
+            num_heads=int(arch.pop("num_attention_heads")),
+            num_kv_heads=int(arch.pop("num_key_value_heads")),
+            head_dim=int(arch.pop("head_dim")),
+            mlp_dim=int(arch["moe_intermediate_size"]),
+            max_seq_len=max_seq_len, norm_eps=float(arch.pop("norm_eps")),
+            tie_embeddings=False,
+            # The nemotron_h modelling code applies no position
+            # embedding; `rope: true` is the other reading, one key.
+            rope=bool(arch.pop("rope", False)), layer_kinds=kinds,
+            mamba_heads=int(arch.pop("mamba_num_heads")),
+            mamba_head_dim=int(arch.pop("mamba_head_dim")),
+            ssm_state=int(arch.pop("ssm_state_size")),
+            ssm_groups=int(arch.pop("n_groups")),
+            conv_kernel=int(arch.pop("conv_kernel")),
+            mamba_chunk=int(arch.pop("chunk_size")),
+            routed_experts=held * ep_size, experts_held=held,
+            expert_offset=held * ep_rank,
+            moe_top_k=int(arch.pop("num_experts_per_tok")),
+            expert_dim=int(arch.pop("moe_intermediate_size")),
+            shared_expert_dim=int(
+                arch.pop("moe_shared_expert_intermediate_size")),
+            routed_scaling=float(arch.pop("routed_scaling_factor")))
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    if arch:
+        raise ValueError(f"architecture of {name!r}: unknown keys "
+                         f"{sorted(arch)} for model_type 'nemotron_h'")
+    if len(kinds) != n_layers:
+        raise ValueError(
+            f"architecture of {name!r}: hybrid_override_pattern has "
+            f"{len(kinds)} layers, num_hidden_layers says {n_layers}")
+    if not 0 <= ep_rank < ep_size:
+        raise ValueError(f"architecture of {name!r}: ep_rank {ep_rank} "
+                         f"outside 0..{ep_size - 1}")
+    return cfg
+
+
+def _dense_gqa(name: str, arch: dict[str, Any],
+               max_seq_len: int) -> ModelConfig:
+    heads = int(arch["num_attention_heads"])
+    return ModelConfig(
+        name=name, vocab_size=int(arch["vocab_size"]),
+        num_layers=int(arch["num_hidden_layers"]),
+        embed_dim=int(arch["hidden_size"]), num_heads=heads,
+        num_kv_heads=int(arch.get("num_key_value_heads", heads)),
+        head_dim=int(arch.get("head_dim")
+                     or int(arch["hidden_size"]) // heads),
+        mlp_dim=int(arch["intermediate_size"]), max_seq_len=max_seq_len,
+        rope_theta=float(arch.get("rope_theta", 10_000.0)),
+        norm_eps=float(arch.get("rms_norm_eps", 1e-6)),
+        sliding_window=arch.get("sliding_window"),
+        attn_bias=bool(arch.get("attention_bias",
+                                arch["model_type"] == "qwen2")),
+        tie_embeddings=bool(arch.get("tie_word_embeddings", False)))
+
+
+def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
+    """The ModelConfig an adapter config asks for: built from its
+    `architecture` block (a published config.json's keys) under the
+    name in `model` when there is one, else the preset of that name."""
+    name = config.get("model", "tiny-gemma")
+    arch = config.get("architecture")
+    if arch is None:
+        return get_model_config(name)
+    if not isinstance(arch, dict):
+        raise ValueError("architecture: expected the keys of a published "
+                         f"config.json, got {type(arch).__name__}")
+    kind = arch.get("model_type")
+    max_seq_len = int(config.get("max_seq_len") or min(
+        int(arch.get("max_position_embeddings", 8192)), 8192))
+    if kind == "nemotron_h":
+        return _nemotron_h(name, arch, max_seq_len)
+    if kind in _DENSE_TYPES:
+        try:
+            return _dense_gqa(name, arch, max_seq_len)
+        except KeyError as e:
+            raise ValueError(f"architecture of {name!r} lacks the key "
+                             f"{e.args[0]!r}") from None
+    raise ValueError(
+        f"architecture of {name!r}: model_type {kind!r} is not one this "
+        f"engine runs (nemotron_h, {', '.join(_DENSE_TYPES)})")
 
 
 def get_model_config(name: str, **overrides) -> ModelConfig:

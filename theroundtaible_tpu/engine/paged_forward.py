@@ -384,6 +384,175 @@ def forward_ragged(
     return logits[0], new_pools + new_scales
 
 
+# --- hybrid decoders: one mixer a layer, state beside the pools -------------
+
+
+def _hybrid_head(params, cfg, x):
+    logits = _einsum("bte,ve->btv", x, params["lm_head"], tp="col")
+    return _softcap(logits, cfg.final_logit_softcap)
+
+
+def forward_paged_hybrid(
+    params: Params, cfg: ModelConfig,
+    tokens: jax.Array,            # [B, T] (T==1 with `active`: decode)
+    positions: jax.Array,         # [B, T]
+    pools: list,                  # (k_pool, v_pool) per ATTENTION layer
+    table: jax.Array,             # [B, pages_per_seq]
+    kv_valid_len: jax.Array,      # [B] valid entries AFTER this call
+    state: dict,                  # {"ssm": [[B,H,P,N]..], "conv": [..]}
+    *,
+    lengths: Optional[jax.Array] = None,   # [B] prefill: valid tokens
+    cap_len: Optional[jax.Array] = None,   # [B] prefill: snapshot after
+    active: Optional[jax.Array] = None,    # [B] decode: rows that advance
+    last_pos: Optional[jax.Array] = None,
+):
+    """forward_paged for a model with `layer_kinds` (models/hybrid.py):
+    every layer is one mixer behind one norm and a residual. Attention
+    layers scatter into their own pools and attend through the same
+    page-table kernels; Mamba-2 layers advance the rows' recurrent
+    `state` (batch-row order: the program gathers and scatters the
+    slot rows); expert layers count what they touched.
+
+    -> (logits, new_pools, new_state, captured, counts): `captured` is
+    the state after `cap_len` tokens (prefill with `cap_len`), else
+    None; counts int32[3] = (experts hit, assignments to held experts,
+    expert-layer steps) over the counted tokens."""
+    from .models import hybrid
+    page_size = pools[0][0].shape[1]
+    b, t = tokens.shape
+    pages = table[jnp.arange(b)[:, None], positions // page_size]
+    offs = positions % page_size
+    decode = active is not None
+    x = embed_tokens(params["embedding"], tokens)
+    if decode:
+        counted = active[:, None]
+    else:
+        counted = jnp.arange(t)[None, :] < lengths[:, None]
+    ssm, conv = list(state["ssm"]), list(state["conv"])
+    cap = {"ssm": [], "conv": []} if cap_len is not None else None
+    counts = jnp.zeros((3,), jnp.int32)
+    new_pools = []
+    ai = mi = 0
+    for kind, layer in zip(cfg.layer_kinds, params["layers"]):
+        h = hybrid.layer_norm_in(x, layer, cfg)
+        if kind == hybrid.MAMBA2:
+            if decode:
+                out, ssm[mi], conv[mi] = hybrid.mamba2_step(
+                    h, layer, cfg, ssm[mi], conv[mi], active)
+            elif cap is None:
+                out, ssm[mi], conv[mi] = hybrid.mamba2_prefill(
+                    h, layer, cfg, ssm[mi], conv[mi], lengths)
+            else:
+                out, ssm[mi], conv[mi], s_cap, c_cap = \
+                    hybrid.mamba2_prefill(h, layer, cfg, ssm[mi],
+                                          conv[mi], lengths, cap_len)
+                cap["ssm"].append(s_cap)
+                cap["conv"].append(c_cap)
+            mi += 1
+        elif kind == hybrid.EXPERTS:
+            out, c = hybrid.experts_mlp(h, layer, cfg, counted)
+            counts = counts + jnp.concatenate(
+                [c, jnp.any(counted).astype(jnp.int32)[None]])
+        else:
+            k_pool, v_pool = pools[ai]
+            q, k, v = (a.astype(k_pool.dtype) for a in
+                       project_qkv(h, layer, cfg, positions))
+            k_pool = k_pool.at[pages, offs].set(k)
+            v_pool = v_pool.at[pages, offs].set(v)
+            if t == 1:
+                out = pattn.paged_decode_attention(
+                    q, k_pool, v_pool, table, kv_valid_len,
+                    sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap)
+            else:
+                out = pattn.paged_prefill_attention(
+                    q, k_pool, v_pool, table, positions[:, 0],
+                    kv_valid_len, sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap)
+            if out is None:
+                raise ValueError(
+                    "paged pool-direct kernels declined this shape "
+                    f"(T={t}, ps={page_size}); the engine gates hybrid "
+                    "models on paged_direct at build time")
+            out = _einsum("bthd,hde->bte", out, layer["o_proj"],
+                          tp="row").astype(h.dtype)
+            new_pools.append((k_pool, v_pool))
+            ai += 1
+        x = x + out
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
+    if last_pos is not None:
+        x = gather_rows(x, last_pos)
+    return (_hybrid_head(params, cfg, x), new_pools,
+            {"ssm": ssm, "conv": conv}, cap, counts)
+
+
+def forward_ragged_hybrid(
+    params: Params, cfg: ModelConfig, tokens, positions, pools, tables,
+    seq_of_block, block_qstart, query_offsets, kv_valid, token_pages,
+    token_offs, token_seq, last_rows,
+    state: dict,                  # EVERY slot: {"ssm": [[R,...]..], ..}
+    seq_slot: jax.Array,          # [S] state row of each sequence
+    cap_n: jax.Array,             # [S] snapshot after this many tokens (0: none)
+    attn_path: str = "kernel",
+):
+    """forward_ragged for a model with `layer_kinds`: the flat buffer's
+    Mamba-2 layers run the block-chunked scan straight on the slot
+    array (each block reads its sequence's state and writes it back),
+    attention layers the ragged page-table kernel. -> (logits [S, V],
+    new_pools, new_state, captured {"ssm": [[S,...]..], "conv": ..},
+    counts)."""
+    from .models import hybrid
+    from .serving_loop import RAGGED_BLOCK_Q
+    s_max = tables.shape[0]
+    x = embed_tokens(params["embedding"], tokens[None])  # [1, T, E]
+    pos2 = positions[None]
+    rg = hybrid.ragged_meta(positions, token_seq, query_offsets, kv_valid,
+                            last_rows, seq_of_block, block_qstart,
+                            seq_slot, cap_n, RAGGED_BLOCK_Q)
+    counted = (rg["token_valid"] & (token_seq != s_max - 1))[None]
+    ssm, conv = list(state["ssm"]), list(state["conv"])
+    cap = {"ssm": [], "conv": []}
+    counts = jnp.zeros((3,), jnp.int32)
+    new_pools = []
+    ai = mi = 0
+    for kind, layer in zip(cfg.layer_kinds, params["layers"]):
+        h = hybrid.layer_norm_in(x, layer, cfg)
+        if kind == hybrid.MAMBA2:
+            out, ssm[mi], conv[mi], s_cap, c_cap = hybrid.mamba2_ragged(
+                h, layer, cfg, ssm[mi], conv[mi], rg)
+            cap["ssm"].append(s_cap)
+            cap["conv"].append(c_cap)
+            mi += 1
+        elif kind == hybrid.EXPERTS:
+            out, c = hybrid.experts_mlp(h, layer, cfg, counted)
+            counts = counts + jnp.concatenate(
+                [c, jnp.ones((1,), jnp.int32)])
+        else:
+            k_pool, v_pool = pools[ai]
+            q, k, v = (a.astype(k_pool.dtype) for a in
+                       project_qkv(h, layer, cfg, pos2))    # [1,T,H,D]
+            k_pool = k_pool.at[token_pages, token_offs].set(k[0])
+            v_pool = v_pool.at[token_pages, token_offs].set(v[0])
+            if attn_path == "kernel":
+                out = pattn.ragged_paged_attention(
+                    q[0], k_pool, v_pool, tables, seq_of_block,
+                    block_qstart, query_offsets, kv_valid,
+                    sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap)
+            else:
+                out = _ragged_xla_attention(
+                    q[0], k_pool, v_pool, tables, token_seq, positions,
+                    kv_valid, cfg)
+            out = _einsum("bthd,hde->bte", out[None], layer["o_proj"],
+                          tp="row").astype(h.dtype)
+            new_pools.append((k_pool, v_pool))
+            ai += 1
+        x = x + out
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
+    logits = _hybrid_head(params, cfg, x[0, last_rows][None])
+    return (logits[0], new_pools, {"ssm": ssm, "conv": conv}, cap, counts)
+
+
 # ---------------------------------------------------------------------------
 # static-analysis program registration (ISSUE 15)
 # ---------------------------------------------------------------------------
@@ -469,8 +638,9 @@ def _analysis_ragged_programs(engine) -> list:
     label: composition is values, so both must produce the one jaxpr
     that shape warmed — a leak of composition into a static argument
     fails RT-JAXPR-VARIANTS."""
-    if not getattr(engine, "ragged_enabled", False):
-        return []
+    if not getattr(engine, "ragged_enabled", False) \
+            or getattr(engine, "hybrid", None) is not None:
+        return []       # (a hybrid engine: engine._analysis_hybrid_programs)
     from .serving_loop import build_ragged_batch
     kv = engine.kv
 

@@ -171,7 +171,7 @@ class PagedKVCache:
                 else (lambda: jax.device_put(jnp.zeros(shape, dtype),
                                              sharding))
             self._make_pools = lambda n_pages: [
-                (make(), make()) for _ in range(cfg.num_layers)]
+                (make(), make()) for _ in cfg.attention_layers]
         else:
             qshape = (self.num_pages, page_size, cfg.num_kv_heads,
                       kv_quant.packed_dim(cfg.head_dim))

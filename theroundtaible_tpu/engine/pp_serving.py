@@ -755,12 +755,18 @@ class PPEngine:
 
     @classmethod
     def from_config(cls, config: dict[str, Any]) -> "PPEngine":
-        from .models.registry import get_model_config
-        model_name = config.get("model", "tiny-gemma")
-        overrides = {}
+        import dataclasses
+        from .models.registry import resolve_model_config
+        model_cfg = resolve_model_config(config)
+        if model_cfg.layer_kinds is not None:
+            raise ValueError(
+                f"{model_cfg.name}: the pipeline engine declines a model "
+                "with layer_kinds (reason: recurrent-state — its stage "
+                "programs carry pages only); serve it with the main "
+                "engine on one device")
         if config.get("max_seq_len"):
-            overrides["max_seq_len"] = int(config["max_seq_len"])
-        model_cfg = get_model_config(model_name, **overrides)
+            model_cfg = dataclasses.replace(
+                model_cfg, max_seq_len=int(config["max_seq_len"]))
         dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
                  "float16": jnp.float16}[config.get("dtype", "bfloat16")]
         sampling_cfg = config.get("sampling", {})

@@ -92,7 +92,7 @@ def cache_enabled(flag: Optional[bool]) -> bool:
 
 
 class _Node:
-    __slots__ = ("children", "parent", "block", "page", "tick")
+    __slots__ = ("children", "parent", "block", "page", "tick", "snap")
 
     def __init__(self, parent=None, block=None, page=None):
         self.children: dict[tuple, "_Node"] = {}
@@ -100,6 +100,9 @@ class _Node:
         self.block = block
         self.page = page
         self.tick = 0
+        # Key of the recurrent-state snapshot taken at this page's end
+        # (engine/hybrid_state.py), dropped with the node.
+        self.snap = None
 
 
 class PrefixCache:
@@ -120,6 +123,10 @@ class PrefixCache:
         self.max_pages = max_pages or kv.usable_pages()
         self.root = _Node()
         self._pages = 0
+        # A model with recurrent state attaches its HybridStateStore
+        # here: snapshots are bound to the nodes of their pages at
+        # insert and evicted with them (never outliving their pages).
+        self.state_store = None
         # page id -> node (1:1 — a live node's page is ref-held, so an
         # id can back only one node at a time). The offload tier asks
         # `holds_page` to tell a cache-only share (spill the bytes,
@@ -133,6 +140,7 @@ class PrefixCache:
         # surfaced via describe() and mirrored into the registry.
         self.hits = 0
         self.misses = 0
+        self.deduped_pages = 0
         self.evictions = 0
         self.inserted_pages = 0
         self.reused_tokens = 0
@@ -188,6 +196,7 @@ class PrefixCache:
         node = self.root
         added = 0
         tick = self._tick()
+        path = []
         for j in range(n_pages):
             block = tuple(state.tokens[j * ps:(j + 1) * ps])
             child = node.children.get(block)
@@ -199,8 +208,25 @@ class PrefixCache:
                 self._pages += 1
                 self._by_page[page] = child
                 added += 1
+            elif (self.state_store is not None
+                  and child.page != state.pages[j]):
+                # A model with recurrent state RE-WRITES the pages it
+                # re-scans (its state stood before its pages' frontier),
+                # so a committing slot often holds its own copy of a
+                # block the index already has. The copy is content-equal
+                # by construction: the slot takes the index's page and
+                # frees its own, or every knight of a discussion would
+                # keep a whole transcript of duplicates (my chip run,
+                # PR 27: the 640-page pool full, admissions shed).
+                self.kv.ref(child.page)
+                self.kv.unref(state.pages[j])
+                state.pages[j] = child.page
+                self.deduped_pages += 1
             child.tick = tick
             node = child
+            path.append(child)
+        if self.state_store is not None:
+            self.state_store.bind_nodes(state.name, path)
         if added:
             self.inserted_pages += added
             telemetry.inc("roundtable_prefix_cache_inserted_pages_total",
@@ -316,6 +342,7 @@ class PrefixCache:
             while victim is not None and freed < want:
                 parent = victim.parent
                 del parent.children[victim.block]
+                self._drop_snapshot(victim)
                 self.kv.unref(victim.page)
                 self._pages -= 1
                 self._by_page.pop(victim.page, None)
@@ -351,6 +378,7 @@ class PrefixCache:
         while stack:
             n = stack.pop()
             stack.extend(n.children.values())
+            self._drop_snapshot(n)
             self.kv.unref(n.page)
             self._by_page.pop(n.page, None)
             self._pages -= 1
@@ -361,6 +389,11 @@ class PrefixCache:
         self._publish_sizes()
         return True
 
+    def _drop_snapshot(self, node: _Node) -> None:
+        if node.snap is not None and self.state_store is not None:
+            self.state_store.drop(node.snap)
+        node.snap = None
+
     def drop_all(self) -> int:
         """Unref every indexed page and clear the tree (flush/drain)."""
         dropped = self._pages
@@ -368,6 +401,7 @@ class PrefixCache:
         while stack:
             node = stack.pop()
             stack.extend(node.children.values())
+            self._drop_snapshot(node)
             self.kv.unref(node.page)
         self.root = _Node()
         self._pages = 0
@@ -382,6 +416,8 @@ class PrefixCache:
         if unref:
             self.drop_all()
             return
+        if self.state_store is not None:
+            self.state_store.drop_all_snapshots()
         self.root = _Node()
         self._pages = 0
         self._by_page.clear()

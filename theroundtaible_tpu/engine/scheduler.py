@@ -379,6 +379,8 @@ class SessionScheduler:
         # while-loop) — bumped in lockstep with their registry series
         # like every other counter here.
         self.ragged_segments = 0
+        self._snaps_seen = 0        # hybrid: snapshots at the last span's end
+        self._hy_counting = False   # ... and whether that span was armed
         self.ragged_joins = 0
         self.segment_prefill_tokens = 0
         self.segment_decode_tokens = 0
@@ -1473,6 +1475,20 @@ class SessionScheduler:
                 queue_wait_s=round(req.admitted_at - req.enqueued, 6),
                 sync_s=round(self._clock.seconds["admit_sync"]
                              - sync_before, 6))
+            sp = prep.get("state_plan")
+            if sp is not None:
+                # Where each row's recurrent state came from, and how
+                # many tokens had pages but no state (re-scanned).
+                admit.attrs.update(
+                    state_from=",".join(
+                        f"{k}:{sp[k]}" for k in
+                        ("continue", "snapshot", "zero") if sp[k]),
+                    state_continue=sp["continue"],
+                    state_snapshot=sp["snapshot"],
+                    state_zero=sp["zero"],
+                    prompt_tokens=sp["prompt_tokens"],
+                    kv_matched_tokens=sp["kv_matched_tokens"],
+                    state_reused_tokens=sp["state_reused_tokens"])
         if telemetry.ACTIVE:
             # The request's "turn" span: lives across segments (ended at
             # retire/fail), parented to the SUBMITTER's trace so spans
@@ -1542,7 +1558,8 @@ class SessionScheduler:
                 return
             seg.leave()
             steps = self._fold_segment(ctx, arrays)
-            self._end_segment(seg, steps, steps * len(alive))
+            self._end_segment(seg, steps, steps * len(alive),
+                              in_flight=int(spec_handles is not None))
             now = time.monotonic()
             self._attribute_wall(counts, now - t_prev)
             # Per-phase token split (ISSUE 8): a while-loop segment is
@@ -1591,17 +1608,36 @@ class SessionScheduler:
 
     def _end_segment(self, seg, steps: int, decode_tokens: int,
                      prefill_tokens: int = 0, drafted: int = 0,
-                     accepted: int = 0) -> None:
+                     accepted: int = 0, in_flight: int = 0) -> None:
         """Emit a segment span with the counts its fold produced, and
         the pool's pages in use at its end (the pool's peak over any
-        stretch is the maximum over that stretch's segment spans)."""
+        stretch is the maximum over that stretch's segment spans).
+        `in_flight`: segments issued after this one and not yet read."""
+        hy = getattr(self.engine, "hybrid", None)
+        if hy is not None:
+            # This segment has been read, so every program up to it has
+            # ended: its expert counts fold into host ints without a
+            # wait (the one issued after it, if any, stays queued).
+            hy.fold_counts(keep=in_flight)
         if seg is telemetry.NULL_SPAN:
+            self._hy_counting = False
             return
         seg.attrs.update(steps=steps, decode_tokens=decode_tokens,
                          prefill_tokens=prefill_tokens, drafted=drafted,
                          accepted=accepted)
         if self.engine.kv_layout == "paged":
             seg.attrs["pages_in_use"] = self.engine.kv.pages_in_use()
+        if hy is not None:
+            # What the expert layers touched since the last segment
+            # span ended (a prologue's prefill in between counts with
+            # the segment after it) and the snapshot store now. The
+            # first span after arming only sets the base.
+            delta, taken = hy.moe_delta(), hy.snapshots_taken
+            if self._hy_counting:
+                seg.attrs.update(
+                    delta, snapshots_taken=taken - self._snaps_seen)
+            self._hy_counting, self._snaps_seen = True, taken
+            seg.attrs["snapshot_bytes"] = hy.snapshot_bytes()
         seg.end()
 
     # --- the ragged mixed segment (ISSUE 8) ---
@@ -1749,6 +1785,8 @@ class SessionScheduler:
             scratch_page=engine.kv.scratch_page(0),
             pad_id=engine.tokenizer.pad_id,
             page_size=engine.kv.page_size)
+        # (A model with recurrent state finds each run's state by it.)
+        batch["seq_names"] = [r.name for _k, r, _t in rows_in]
 
         t0 = time.monotonic()
         seg = self._open_segment("ragged", len(seqs), shape)
@@ -2557,7 +2595,7 @@ class SessionScheduler:
             "top_ks": top_ks, "top_ps": top_ps, "greedy": greedy,
             "seg_budget": seg_budget, "deadline": deadline,
             "budgets_max": int(budgets.max()) if len(budgets) else 0,
-            "lora": lora,
+            "lora": lora, "names": names,
         }
 
     def _dispatch(self, ctx: dict):
@@ -2576,7 +2614,8 @@ class SessionScheduler:
                     engine._next_key(), jnp.int32(DECODE_SEGMENT),
                     ctx["temps"], ctx["top_ks"], ctx["top_ps"],
                     ctx["budgets_d"], ctx["done_d"],
-                    greedy=ctx["greedy"], lora=ctx["lora"])
+                    greedy=ctx["greedy"], lora=ctx["lora"],
+                    names=ctx["names"])
             return engine._decode_dispatch_slots(
                 ctx["slot_idx"], ctx["last_d"], ctx["valid_d"],
                 engine._next_key(), jnp.int32(DECODE_SEGMENT),
@@ -2856,6 +2895,12 @@ class SessionScheduler:
                 fed = ids[:-1] if ids else []
                 engine.kv.commit(r.name, r.tokens + fed,
                                  index=not r.adapter_slot)
+                hy = getattr(engine, "hybrid", None)
+                if hy is not None:
+                    # A row that ended on a sampled eos consumed one
+                    # token more than it commits: no continuation.
+                    hy.on_commit(r.name, r.tokens + fed,
+                                 exact=eos not in r.produced[:max_new])
                 texts.append(engine.tokenizer.decode(ids))
             # (roundtable_lora_apply_tokens_total was bumped per
             # DISPATCH as the tokens were served — retire must not

@@ -109,7 +109,15 @@ def param_specs(cfg: ModelConfig) -> Params:
 
     TP sharding: q/o on query heads, k/v on kv heads, MLP on hidden.
     Embedding sharded on vocab (big tables, cheap all-gather of one row).
+    A model with `layer_kinds` serves on one device (the engine
+    declines a wider mesh for it): every leaf is replicated.
     """
+    if cfg.layer_kinds is not None:
+        import jax
+        from .models.common import init_params
+        shapes = jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0)))
+        return jax.tree_util.tree_map(lambda _: P(), shapes)
     layer = {
         "q_proj": P(None, MODEL_AXIS, None),    # [E, H, D] heads sharded
         "k_proj": P(None, MODEL_AXIS, None),    # [E, K, D]

@@ -1068,6 +1068,48 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
                        "(memory-ledger publish)",
         "share_suppressed": "derived (engine counter; lora_describe)",
     },
+    # engine.describe()["hybrid_state"] (ISSUE 27): recurrent state
+    # beside the pools — slot states and the snapshot store
+    # (engine/hybrid_state.HybridStateStore is the one writer of both
+    # the describe() totals and the series). No per-session series:
+    # nothing to remove at retire.
+    "engine_hybrid_state": {
+        "slots": "derived (state rows in use; describe-only)",
+        "slot_rows": "static config (num_slots)",
+        "bytes_per_state": "static (model sizes)",
+        "snapshots": "roundtable_state_snapshots gauge",
+        "snapshot_capacity": "static (state_snapshot_bytes / state)",
+        "bytes": "roundtable_state_snapshot_bytes gauge",
+        "budget": "static config (state_snapshot_bytes)",
+        "hits": "roundtable_state_admissions_total"
+                "{source=continue|snapshot}",
+        "misses": "roundtable_state_admissions_total{source=zero}",
+        "evictions": "roundtable_state_snapshot_evictions_total",
+        "snapshots_taken": "roundtable_state_snapshots_total",
+        "continued_tokens": "derived (plan totals; describe-only)",
+        "reused_tokens": "derived (plan totals; describe-only)",
+        "rescanned_tokens": "roundtable_state_rescanned_tokens_total "
+                            "(over roundtable_state_prompt_tokens_total)",
+        "share_declined": "roundtable_state_share_declined_total"
+                          "{reason=recurrent-state}",
+        "deduped_pages": "derived (prefix_cache.insert: re-written "
+                         "pages given back for the index's; "
+                         "describe-only)",
+    },
+    # engine.describe()["moe"] (ISSUE 27): the chip's share of the
+    # routed experts and what the steps touched (each step program
+    # returns its counts; HybridStateStore.fold_counts adds them to
+    # host ints and these series on the dispatching thread).
+    "engine_moe": {
+        "held": "static (experts held here)",
+        "published": "static (experts the router scores)",
+        "offset": "static (first held expert's published id)",
+        "top_k": "static (experts a token)",
+        "expert_layers": "static (layer pattern)",
+        "experts_hit": "roundtable_moe_experts_hit_total",
+        "local_assignments": "roundtable_moe_local_assignments_total",
+        "expert_layer_steps": "roundtable_moe_expert_layer_steps_total",
+    },
     # Gateway.describe() (ISSUE 16): the HTTP front door's admission /
     # shed / stream provenance — counters move in lockstep with the
     # registry series (AdmissionController._count is the one writer).
