@@ -42,13 +42,17 @@ from theroundtaible_tpu.engine.pallas import int4mm
 D, PAGE = 128, 128           # head_dim and page size of every case
 POOL_PAGES = 256             # chip_smoke.py's pool
 PAGES_PER_SEQ = 64           # max_seq_len 8192 / page 128
-ROWS = 8                     # decode batch rows / ragged sequences
+ROWS = 8                     # ragged sequences / whole-step batch rows
+DECODE_ROWS = 16             # the benchmark's decode batch (16 slots)
 RAGGED_T = 256               # flat token buffer
 CHUNK = 256                  # prefill chunk
 
 # (H, K) per case family: Llama-3.2-3B on one chip; one model-axis
-# shard of Llama-3-8B (H=32, K=8) over four chips.
-HEADS = {"llama-3.2-3b": (24, 8), "llama-3-8b/4": (8, 2)}
+# shard of Llama-3-8B (H=32, K=8) over four chips; and the benchmark's
+# own widths — Mistral-7B and the attention layers of Nemotron-3-Nano —
+# so the compiler is asked about the shapes the cells run (ISSUE 28).
+HEADS = {"llama-3.2-3b": (24, 8), "llama-3-8b/4": (8, 2),
+         "mistral-7b": (32, 8), "nemotron-3-nano": (32, 2)}
 
 
 @pytest.fixture(scope="module")
@@ -122,8 +126,8 @@ def _attention_case(kernel: str, h: int, kh: int, kv: str, one_chip):
     if kernel == "paged_decode":
         return on_pool(
             pattn.paged_decode_attention,
-            s((ROWS, 1, h, D), jnp.bfloat16), pool, pool,
-            s((ROWS, PAGES_PER_SEQ), i32), s((ROWS,), i32))
+            s((DECODE_ROWS, 1, h, D), jnp.bfloat16), pool, pool,
+            s((DECODE_ROWS, PAGES_PER_SEQ), i32), s((DECODE_ROWS,), i32))
     if kernel == "ragged":
         blocks = RAGGED_T // pattn.RAGGED_BLOCK_Q
         return on_pool(
@@ -162,6 +166,63 @@ def test_attention_kernel_compiles_for_v5e(one_chip, kernel, kv, widths):
     # profiler's operations line shows (ISSUE 25).
     name = {"ragged": "ragged_paged"}.get(kernel, kernel) + "_attention"
     assert f"%{name}" in hlo
+
+
+def test_paged_decode_gate_declines_what_the_compiler_refuses(
+        one_chip, monkeypatch):
+    """`paged_decode_supported` against the compiler itself. A bf16
+    page of Mistral-7B's pool is stored in VMEM at the array's own
+    bytes (the walk lands it flattened, dense), so sixteen pages a trip
+    are 16 MiB of copy buffers by either count — and with the product's
+    own temporaries the kernel asks the compiler for more than its
+    16 MiB scope. The gate's estimate, not the arrays' bytes, is what
+    keeps a plan under it: it declines sixteen and plans two."""
+    h, kh = HEADS["mistral-7b"]
+    page = pattn._walk_page_bytes(PAGE, kh, D, 2, 0)
+    assert page == 2 * PAGE * kh * D * 2            # stored == logical
+    assert pattn._walk_vmem_est(16, PAGE, D, kh, h // kh, D, 2, 0) \
+        > pattn._VMEM_BUDGET
+    assert pattn._walk_pages(PAGE, D, kh, h // kh) == 2
+    with monkeypatch.context() as m:
+        # plan sixteen pages a trip past the estimate
+        m.setattr(pattn, "_walk_vmem_est", lambda n, *a, **k: 0)
+        m.setattr(pattn, "_WALK_TRIP_BYTES", 16 * page)
+        fn, shapes = _attention_case("paged_decode", h, kh, "bf16",
+                                     one_chip)
+        with pytest.raises(Exception, match="exceeded scoped vmem"):
+            _compile(fn, *shapes)
+    fn, shapes = _attention_case("paged_decode", h, kh, "bf16", one_chip)
+    _assert_kernel(_compile(fn, *shapes))
+
+
+@pytest.mark.parametrize("h,kh,kv", [
+    (32, 8, "bf16"), (32, 2, "bf16"), (32, 8, "int8"),     # token-major
+    (8, 1, "bf16"), (24, 3, "bf16"), (8, 2, "int8")])      # head-major
+def test_paged_decode_takes_the_pools_as_xla_stores_them(one_chip, h, kh,
+                                                         kv):
+    """The decode walk's operands are views of the pools as XLA lays
+    them out — token-major where a token's heads fill whole tiles,
+    head-major where not, the scale pools token-minor — so no operand
+    of the call is a copy: a re-laid-out pool (the whole pool, on every
+    call) is what a row-major operand of the other shapes costs, and a
+    flattened view of one reads its padding."""
+    import re
+    fn, shapes = _attention_case("paged_decode", h, kh, kv, one_chip)
+    hlo = _compile(fn, *shapes)
+    _assert_kernel(hlo)
+    pool = rf"\[{POOL_PAGES},[0-9,]+\]"
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if re.search(rf"= \w+{pool}\S* copy(-start)?\(", line)]
+    assert not copies, copies
+    call = next(line for line in hlo.splitlines()
+                if "tpu_custom_call" in line)
+    rows = PAGE * kh
+    want = (f"[{POOL_PAGES},{PAGE},{kh},{D}]"
+            if pattn._token_major(kh, 2 if kv == "bf16" else 1)
+            else f"[{POOL_PAGES},{rows},{D}]")
+    assert want in call
+    if kv == "int8":
+        assert f"f32[{POOL_PAGES},{kh},{PAGE}]" in call
 
 
 @pytest.mark.parametrize("kernel", ["paged_decode", "ragged"])

@@ -205,6 +205,144 @@ def test_paged_decode_never_reads_beyond_frontier():
     assert np.isfinite(np.asarray(out)).all()
 
 
+def gather_softmax_ref(q, k_pool, v_pool, table, valid, window=None,
+                       softcap=None):
+    """Plain gather-and-softmax over each row's own pages, in numpy:
+    nothing of the kernels' block structure, online softmax or masking
+    is shared with it."""
+    q, k_pool, v_pool = (np.asarray(x, np.float64)
+                         for x in (q, k_pool, v_pool))
+    B, _, H, D = q.shape
+    ps, K = k_pool.shape[1], k_pool.shape[2]
+    out = np.zeros((B, 1, H, D))
+    for b in range(B):
+        n = int(valid[b])
+        if n == 0:
+            continue                       # an idle row yields zeros
+        pages = np.asarray(table[b, :-(-n // ps)])
+        k = k_pool[pages].reshape(-1, K, D)[:n]
+        v = v_pool[pages].reshape(-1, K, D)[:n]
+        first = max(0, n - window) if window else 0
+        for h in range(H):
+            s = k[first:, h // (H // K)] @ q[b, 0, h]
+            if softcap:
+                s = softcap * np.tanh(s / softcap)
+            p = np.exp(s - s.max())
+            out[b, 0, h] = (p / p.sum()) @ v[first:, h // (H // K)]
+    return out
+
+
+WALK_MODES = {"plain": {}, "window": {"sliding_window": 40},
+              "softcap": {"softcap": 30.0}, "int8": {}}
+
+
+@pytest.mark.parametrize("mode", list(WALK_MODES))
+@pytest.mark.parametrize("heads", [(32, 8), (32, 2), (8, 1), (6, 3)])
+def test_paged_decode_walk_matches_gather_and_softmax(heads, mode,
+                                                      monkeypatch):
+    """The decode walk against a plain reference at the benchmark's
+    head layouts (Mistral-7B's 32/8, Nemotron's 32/2), MQA and three kv
+    heads — pools the walk takes token-major and, where the heads of a
+    token do not fill whole tiles (one or three heads, two of int8),
+    head-major. Four
+    pages a trip in a table twelve wide; rows of length 0 (idle), 1,
+    exactly one page, one past a page, seven pages (not a multiple of
+    four) and the table's full width. The pool is shuffled, and behind
+    every table entry past a row's frontier lies a poisoned page: the
+    walk must never read one into the result."""
+    from theroundtaible_tpu.engine import kv_quant as kvq
+    from theroundtaible_tpu.engine.pallas import attention as pattn
+    (H, K), D, ps, W, N = heads, 32, 16, 12, 4
+    lens = np.asarray([0, 1, ps, ps + 1, 6 * ps + 5, W * ps])
+    B = len(lens)
+    rng = np.random.default_rng(28)
+    q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
+    k_pool, v_pool = (rng.normal(size=(1 + B * W, ps, K, D))
+                      .astype(np.float32) for _ in range(2))
+    table = (rng.permutation(B * W) + 1).reshape(B, W).astype(np.int32)
+    dead = np.concatenate([table[b, -(-int(n) // ps):]
+                           for b, n in enumerate(lens)])
+    quant = {}
+    if mode == "int8":
+        spec = kvq.KVQuantSpec(bits=8)
+        (k_pool, ks), (v_pool, vs) = (
+            tuple(np.array(a) for a in kvq.quantize_cells(
+                jnp.asarray(x), spec)) for x in (k_pool, v_pool))
+        ref_pools = [np.asarray(kvq.dequantize_cells(
+            jnp.asarray(x), jnp.asarray(sc), spec, jnp.float32))
+            for x, sc in ((k_pool, ks), (v_pool, vs))]
+        ks[dead] = vs[dead] = np.nan       # int8 cells hold no NaN
+        quant = {"k_scale": jnp.asarray(ks), "v_scale": jnp.asarray(vs)}
+        shape = dict(itemsize=1, scale_groups=ks.shape[-1])
+    else:
+        ref_pools = [k_pool.copy(), v_pool.copy()]
+        k_pool[dead] = v_pool[dead] = np.nan
+        shape = dict(itemsize=4, scale_groups=0)
+    monkeypatch.setattr(
+        pattn, "_WALK_TRIP_BYTES",
+        N * pattn._walk_page_bytes(ps, K, D, **shape))
+    assert pattn._walk_pages(ps, D, K, H // K, D, **shape) == N
+    out = pattn.paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(table), jnp.asarray(lens, jnp.int32), interpret=True,
+        **WALK_MODES[mode], **quant)
+    ref = gather_softmax_ref(
+        q, *ref_pools, table, lens,
+        WALK_MODES[mode].get("sliding_window"),
+        WALK_MODES[mode].get("softcap"))
+    assert out.shape == q.shape
+    assert not np.asarray(out[0]).any()
+    np.testing.assert_allclose(np.asarray(out), ref, atol=5e-5, rtol=5e-5)
+
+
+def test_paged_decode_gate_reckons_what_vmem_stores(monkeypatch):
+    """The walk's plan counts what VMEM holds beside the page buffers:
+    the q and out blocks of a row block (so the plan holds for every
+    batch size: a larger batch is more grid steps, not a larger block),
+    the scores, and for a quantized pool the dequantized rows, the
+    scale rows as stored (K*G rows of ps lanes) and the one-hot that
+    turns them into columns. An int8 pool of 512-token pages fits by
+    its buffers alone and not with what the product needs, so it
+    declines to the gather view instead of failing Mosaic."""
+    from theroundtaible_tpu.engine.pallas import attention as pattn
+    ps, d, kh, group = 512, 128, 8, 1
+    int8 = dict(itemsize=1, scale_groups=1)
+    page = pattn._walk_page_bytes(ps, kh, d, **int8)
+    assert page == 2 * (ps * kh * d + 8 * ps * 4)  # payload + scale rows
+    assert 2 * page <= pattn._VMEM_BUDGET           # two slots alone fit
+    assert pattn._walk_vmem_est(1, ps, d, kh, group, d, **int8) \
+        > pattn._VMEM_BUDGET
+    assert not pattn.paged_decode_supported(ps, d, kh, group, **int8)
+    assert pattn.paged_decode_decline_reason(
+        ps, d, kh, group, **int8).startswith("vmem:")
+    # q and out: sixteen rows of 32 heads, two blocks, two buffers each
+    est = pattn._walk_vmem_est(2, 128, 128, 8, 4, 128, 2, 0)
+    assert est - 2 * 2 * pattn._walk_page_bytes(128, 8, 128, 2, 0) \
+        >= 2 * 2 * pattn._WALK_ROW_BLOCK * 32 * 128 * 2
+    # the same pages in bf16 fit, one a trip; the benchmark's pools take
+    # two (Mistral-7B) and eight (Nemotron's two kv heads) a trip
+    assert pattn._walk_pages(ps, d, kh, group) == 1
+    assert pattn._walk_pages(128, 128, 8, 4) == 2
+    assert pattn._walk_pages(128, 128, 2, 16) == 8
+    assert pattn._walk_pages(128, 128, 8, 4, **int8) == 2
+    # on the chip: every head count is served (token-major where XLA
+    # stores the pool so, head-major where not); a head width or a
+    # scale page that does not fill lane rows is not
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    for k, g in ((8, 4), (1, 8), (2, 16), (16, 2), (3, 4), (6, 4)):
+        assert pattn.paged_decode_supported(128, 128, k, g)
+    assert [k for k in range(1, 17) if pattn._token_major(k, 2)] \
+        == [2, 4, 8, 16]
+    assert not pattn._token_major(2, 1) and pattn._token_major(4, 1)
+    assert pattn.paged_decode_supported(128, 128, 8, 4, **int8)
+    assert pattn.paged_decode_supported(128, 128, 2, 4, **int8)
+    assert pattn.paged_decode_decline_reason(128, 64, 8, 4) \
+        == "head_dim:64"
+    assert pattn.paged_decode_decline_reason(64, 128, 8, 4, **int8) \
+        == "scale_page:64"
+    assert pattn.paged_decode_supported(64, 128, 8, 4)
+
+
 def test_supported_shapes():
     assert supported(64, 512, 16)          # interpret mode: any D
     assert supported(1, 2048, 128)

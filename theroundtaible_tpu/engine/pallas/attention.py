@@ -13,14 +13,20 @@ src/adapters/local-llm.ts):
 - ragged_decode_attention: single-position decode attention over the padded
   cache. Rows with valid=600 in an S=8192 cache read 600 tokens of KV, not
   8192: the kv-block index map clamps to the row's frontier, and Pallas
-  elides the DMA when consecutive grid steps map to the same block.
+  elides the DMA when consecutive grid steps map to the same block (the
+  grid step itself is still paid; the paged decode walk pays none).
 - paged_decode_attention: the same ragged decode DIRECTLY against the page
-  POOL [P, page_size, K, D] (engine/paging.py): the kv-block index map
-  reads the scalar-prefetched page TABLE, so decode never materializes the
-  position-aligned [B, S, K, D] gather view — during decode the paged
-  layout keeps its whole resident-memory advantage (the gather view
-  temporarily recreated the full contiguous budget) and reads only the
-  pages below each row's frontier.
+  POOL [P, page_size, K, D] (engine/paging.py), so decode never
+  materializes the position-aligned [B, S, K, D] gather view — during
+  decode the paged layout keeps its whole resident-memory advantage (the
+  gather view temporarily recreated the full contiguous budget). It is a
+  WALK, not a grid over the table's width: a grid step loops over a
+  block of batch rows, and each row loops over the pages it holds
+  (table[b, lo..hi] from the scalar-prefetched page table), a few pages
+  a trip, copied HBM -> VMEM by explicit double-buffered DMAs while the
+  trip before is multiplied. A page is copied flattened to [ps*K, D] and
+  multiplied as it lies, all q heads at once with foreign kv heads
+  masked — no per-head strided read (see "the walk" below).
 
 Both kernels handle GQA natively (kv head = q head // group) so the
 [B, S, K, D] cache is never repeated to [B, S, H, D] in HBM, and support
@@ -52,18 +58,19 @@ def _dequant_kv(x, s, kv_bits: int, dtype):
     the nibble-order contract — shift arithmetic, which Mosaic lowers
     but whose interleaving reshape the v5e compiler then refuses:
     MOSAIC_INT4_KV_REFUSAL below, so int4 pools decline at plan time on
-    the chip); the grouped scale multiply is a minor-axis reshape,
-    which the int8 path compiles. This is the kernel-side
+    the chip); the grouped scale multiply repeats each group's scale
+    along the lanes (the [bkv, G, D/G] reshape compiles only for a
+    block that came from a strided read). This is the kernel-side
     twin of kv_quant.dequantize_cells — same unpack, same scale
     math, so the kernel and XLA fallback cannot drift."""
     if kv_bits == 4:
         from ..kv_quant import unpack_int4
         x = unpack_int4(x)
-    bkv, d = x.shape
-    n_groups = s.shape[-1]
-    xg = x.astype(jnp.float32).reshape(bkv, n_groups, d // n_groups)
-    return (xg * s[..., None].astype(jnp.float32)) \
-        .reshape(bkv, d).astype(dtype)
+    d = x.shape[-1]
+    per_group = d // s.shape[-1]
+    return (x.astype(jnp.float32)
+            * jnp.repeat(s.astype(jnp.float32), per_group, axis=-1)) \
+        .astype(dtype)
 
 
 def _interpret() -> bool:
@@ -298,8 +305,11 @@ def _paged_prefill_kernel(table_ref, offs_ref, valid_ref, q_ref, k_ref,
     # Identical math to _prefill_kernel (shared _prefill_accumulate); the
     # paged differences: the kv block for grid step sb is pool page
     # table[b, sb], and ALL kv heads ride one (1, ps, K, D) block with a
-    # static in-kernel head loop — per-head pool blocks are
-    # Mosaic-illegal for K > 1 (see _paged_decode_kernel). Quantized
+    # static in-kernel head loop — a per-head block (1, ps, 1, D) is
+    # Mosaic-ILLEGAL for K > 1 (second-minor block dim 1 is neither
+    # 8-aligned nor the full K axis; unseen on hardware until GQA
+    # because gemma's MQA pool has K == 1), and total DMA bytes are the
+    # same either way (each page read once with every head). Quantized
     # pools (ISSUE 11) ride two extra per-page scale blocks whose index
     # map is the kv block's, dequantized inside _prefill_accumulate.
     if quantized:
@@ -636,7 +646,8 @@ def _decode_accumulate(q, k, v, kv_start, valid, state, *,
                        group: int, block_kv: int,
                        sliding_window: Optional[int],
                        softcap: Optional[float],
-                       k_scale=None, v_scale=None, kv_bits: int = 8):
+                       k_scale=None, v_scale=None, kv_bits: int = 8,
+                       kv_pos=None):
     """One online-softmax accumulation of a single-position query group
     [G, D] against one kv block [bkv, D] whose first entry holds absolute
     position kv_start. Shared by the contiguous (_decode_kernel) and
@@ -644,7 +655,11 @@ def _decode_accumulate(q, k, v, kv_start, valid, state, *,
     how the kv block is addressed, so the math lives here once. Pure
     value-in/value-out over `state` = (m, l, acc) — see
     _prefill_accumulate for why. `k_scale`/`v_scale`: quantized-page
-    blocks dequantize in-kernel first (ISSUE 11 — ditto)."""
+    blocks dequantize in-kernel first (ISSUE 11 — ditto). `kv_pos`
+    [G, bkv], where given, is each (q row, kv row) pair's position in
+    place of kv_start + column: the paged walk's rows interleave the kv
+    heads, and a row of a q row's foreign head carries a position no
+    `valid` reaches."""
     if k_scale is not None:
         k = _dequant_kv(k, k_scale, kv_bits, q.dtype)
         v = _dequant_kv(v, v_scale, kv_bits, q.dtype)
@@ -653,8 +668,9 @@ def _decode_accumulate(q, k, v, kv_start, valid, state, *,
         preferred_element_type=jnp.float32)                # [G, bkv]
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    kv_pos = kv_start + jax.lax.broadcasted_iota(
-        jnp.int32, (group, block_kv), 1)
+    if kv_pos is None:
+        kv_pos = kv_start + jax.lax.broadcasted_iota(
+            jnp.int32, (group, block_kv), 1)
     mask = kv_pos < valid
     if sliding_window is not None:
         mask &= kv_pos > (valid - 1) - sliding_window
@@ -707,16 +723,30 @@ def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
-# Conservative VMEM working-set budget for the paged kernels. All kv
-# heads ride one block since the per-head pool block is Mosaic-illegal
-# (see _paged_decode_kernel), so q/out/kv blocks and scratch all scale
-# with kh — large-GQA shapes must shrink block_q or decline to the
-# gather-view fallback INSTEAD of failing Mosaic compilation on chip.
+# VMEM working-set budget for the paged kernels. The compiler's scoped
+# limit on a v5e is 16 MiB, and a kernel asks it for more than the
+# buffers it declares — Mosaic's own temporaries — so the estimates
+# below are held to 12 MiB. All kv heads ride one block since the
+# per-head pool block is Mosaic-illegal, so q/out/kv blocks and scratch
+# all scale with kh — large-GQA shapes must shrink block_q or decline to
+# the gather-view fallback INSTEAD of failing Mosaic compilation on chip.
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _paged_vmem_est(page_size: int, d: int, kh: int, group: int,
                     block_q: int) -> int:
+    """What the paged prefill and ragged kernels DECLARE, in bytes. What
+    VMEM really holds (PR 28, from compiling for a v5e): a bf16 page
+    block (1, ps, K, D) is stored at the array's own bytes — Mosaic
+    tiles it (K, 128) like the pool in HBM; a copy-only kernel takes
+    twelve [128, 8, 128] pages a slot pair and not sixteen — so the kv
+    term is right as it stands. What this sum leaves out is the
+    compiler's scratch for the per-head strided reads
+    `k_ref[0, :, khi, :]`, about the kv term again: a kernel with 8 MiB
+    of page buffers read that way asked for 16.4 MiB (the "block stored
+    at twice its size" of ISSUE 28's sketch was this). The 4 MiB
+    between the budget and the scope has covered it at every width
+    tests/test_chip_compile.py compiles."""
     scratch = kh * group * block_q * (2 * _LANES + d) * 4   # f32 m/l/acc
     q_out = 2 * kh * group * block_q * d * 2                # bf16 blocks
     kv = 2 * 2 * page_size * kh * d * 2                     # 2×(k+v) bufs
@@ -732,79 +762,313 @@ def _paged_prefill_block_q(t: int, page_size: int, d: int, kh: int,
     return None
 
 
-def paged_decode_supported(page_size: int, d: int, kh: int = 1,
-                           group: int = 1) -> bool:
-    """Can paged_decode_attention serve this pool shape? The page is the
-    kv block, so page_size must be a legal block; TPU wants lane-aligned
-    D (any shape goes in interpret mode). Pass the LOCAL kv-head count
-    and GQA group so the kh-scaled VMEM working set is budgeted — an
-    oversized layout must route to the gather view, not fail Mosaic."""
+# --- paged decode: the walk ---
+#
+# One grid step serves a block of _WALK_ROW_BLOCK batch rows (the whole
+# batch, at the serving sizes). Each row walks ITS OWN pages,
+# table[b, lo..hi], `n` pages a trip: the pools stay in HBM (pl.ANY) and
+# a trip's pages are copied by explicit DMAs into one of two VMEM slots,
+# the next trip's copies — or the next row's first — started before the
+# present trip is waited for. A page arrives FLATTENED: the HBM ref
+# [P, ps, K, D] is viewed as [P, ps*K, D] (no bytes move: XLA tiles the
+# pool (K, 128), so a page's rows already lie token-major with the heads
+# interleaved), which lands dense in VMEM and is multiplied as it lies —
+# all q heads against all (token, head) rows, the foreign heads masked
+# out of the softmax. No per-head strided read is left, which is what
+# the grid kernel spent its time on: on a v5e at Mistral-7B's heads this
+# walk runs at the speed of its copies.
+#
+# That holds where the K heads of a token fill whole tiles (_token_major:
+# 2, 4 or a multiple of 8 heads, of four bytes together at least). Any
+# other pool XLA stores HEAD-major — physically [P, K, ps, D], a page is
+# K dense [ps, D] blocks — and would re-lay out, whole, on every call
+# for a row-major [P, ps, K, D] operand, padding the head axis; the
+# flattened view of THAT reads the padding (wrong numbers on the chip,
+# PERF.md PR 28). Such a pool is handed over as XLA has it, [P, K*ps, D]
+# — again a bitcast — and only the map from a row to its (head, token)
+# differs, built once (_page_rows).
+#
+# A quantized pool's scales walk with the pages in the form XLA stores
+# them: it keeps f32[P, ps, K, G] token-minor (`{1,3,2,0:T(1,128)}`, a
+# page is K*G dense rows of ps scales), so the operand is that view,
+# [P, K*G, ps] — a bitcast, where the row-major [P, ps, K, G] every
+# BlockSpec kernel asks for makes XLA re-lay the WHOLE pool out, G padded
+# to a lane row, on every call (PERF.md, PR 28). In VMEM a trip's scale
+# rows are turned into the column a flattened page needs — one scale a
+# (head, token) row — by one small one-hot product a page
+# (_scale_columns): the MXU is the only unit that moves lanes to rows.
+
+# A trip should move at least this much of keys and values: below it
+# the trip's fixed work (scalar reads of the table, DMA issue, the
+# softmax bookkeeping) shows against the copy.
+_WALK_TRIP_BYTES = 1 << 20
+_WALK_ROW_BLOCK = 16      # batch rows a grid step holds q and out for
+_FOREIGN = 1 << 30        # "position" of a row of another kv head
+
+
+def _token_major(kh: int, itemsize: int) -> bool:
+    """Does XLA keep a pool [P, ps, K, D] row-major, the heads of a
+    token filling whole tiles (see "the walk")?"""
+    return kh * itemsize >= 4 and (kh in (2, 4) or kh % 8 == 0)
+
+
+def _page_rows(shape, axis: int, *, page_size: int, kh: int,
+               token_major: bool):
+    """(head, token) of each row of a flattened page, as int32 arrays of
+    `shape` with the page's rows along `axis`."""
+    c = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    if token_major:
+        return c % kh, c // kh
+    return c // page_size, c % page_size
+
+
+def _walk_page_bytes(page_size: int, kh: int, dk: int, itemsize: int,
+                     scale_groups: int) -> int:
+    """Bytes of one page's keys AND values as VMEM holds them: rows of
+    `dk` cells, lane-padded; the f32 scales of a page are K*G rows (a
+    whole sublane tile at least) of ps lanes."""
+    rows = page_size * kh
+    lanes = -(-dk // _LANES) * _LANES
+    page = 2 * rows * lanes * itemsize
+    if scale_groups:
+        page += 2 * (-(-kh * scale_groups // 8) * 8) * (
+            -(-page_size // _LANES) * _LANES) * 4
+    return page
+
+
+def _walk_vmem_est(n: int, page_size: int, d: int, kh: int, group: int,
+                   dk: int, itemsize: int, scale_groups: int) -> int:
+    cols = n * page_size * kh
+    hq = -(-kh * group // 8) * 8
+    bufs = 2 * n * _walk_page_bytes(page_size, kh, dk, itemsize,
+                                    scale_groups)      # two slots
+    # q and out blocks of a row block, double-buffered by the pipeline
+    q_out = 2 * 2 * _WALK_ROW_BLOCK * hq * d * 2
+    scores = 4 * hq * cols * 4       # row index, s, p, the mask's temps
+    deq = 0
+    if scale_groups:
+        deq = 2 * cols * d * (4 + 2)                   # f32, then bf16
+        # the one-hot [ps*K, ps]; the picked scales [cols, K*G] and the
+        # columns [cols, G] of both pools, f32 in whole lane rows
+        deq += page_size * kh * page_size * 4 + 2 * 2 * cols * _LANES * 4
+    return bufs + q_out + scores + deq
+
+
+def _walk_pages(page_size: int, d: int, kh: int, group: int,
+                dk: Optional[int] = None, itemsize: int = 2,
+                scale_groups: int = 0) -> Optional[int]:
+    """Pages a trip of the decode walk moves — from what the operands
+    show: the page's stored bytes and the VMEM budget — or None when not
+    even one page a trip fits."""
+    dk = d if dk is None else dk
+    page = _walk_page_bytes(page_size, kh, dk, itemsize, scale_groups)
+    n = 1
+    while n * page < _WALK_TRIP_BYTES:
+        n *= 2
+    est = functools.partial(_walk_vmem_est, page_size=page_size, d=d,
+                            kh=kh, group=group, dk=dk, itemsize=itemsize,
+                            scale_groups=scale_groups)
+    while n > 1 and est(n) > _VMEM_BUDGET:
+        n //= 2
+    return n if est(n) <= _VMEM_BUDGET else None
+
+
+def paged_decode_decline_reason(page_size: int, d: int, kh: int = 1,
+                                group: int = 1, *, itemsize: int = 2,
+                                scale_groups: int = 0,
+                                dk: Optional[int] = None) -> Optional[str]:
+    """Why paged_decode_attention cannot serve this pool shape, or None
+    when it can. Pass the LOCAL kv-head count and GQA group; `itemsize`
+    is a page cell's (2: bf16, 1: int8/int4 payloads, whose f32 scale
+    pools carry `scale_groups` groups a cell). Page size must be a
+    legal block for the prefill kernels that share the pool; one page a
+    trip must fit the VMEM budget; and on the chip (any shape goes in
+    interpret mode) D must be lane-aligned, and a quantized pool's
+    pages must fill whole lane rows of scales (a scale page is K*G rows
+    of ps lanes). Every head count is served: see _token_major."""
     if page_size not in (512, 256, 128, 64, 32, 16, 8):
-        return False
-    if _paged_vmem_est(page_size, d, kh, group, 1) > _VMEM_BUDGET:
-        return False
-    return _interpret() or d % 128 == 0
+        return f"page_size:{page_size}"
+    if _walk_pages(page_size, d, kh, group, dk, itemsize,
+                   scale_groups) is None:
+        return f"vmem:ps={page_size},d={d},kh={kh},g={group}"
+    if _interpret():
+        return None
+    if d % 128 != 0:
+        return f"head_dim:{d}"
+    if scale_groups and page_size % _LANES:
+        return f"scale_page:{page_size}"
+    return None
 
 
-def _paged_decode_kernel(table_ref, valid_ref, q_ref, k_ref, v_ref,
-                         *rest, page_size: int,
-                         num_page_blocks: int, kh: int, group: int,
+def paged_decode_supported(page_size: int, d: int, kh: int = 1,
+                           group: int = 1, **kw) -> bool:
+    """Can paged_decode_attention serve this pool shape? An unsupported
+    layout must route to the gather view, not fail Mosaic."""
+    return paged_decode_decline_reason(page_size, d, kh, group,
+                                       **kw) is None
+
+
+def _scale_columns(scales, onehot, first_pos, valid, *, page_size: int,
+                   kh: int, token_major: bool):
+    """A trip's scale rows [n, K*G, ps] (a page's scales as the pool
+    stores them: one row a head and group, a lane a token) -> the column
+    block [n*ps*K, G] its flattened pages take, a row of a page being
+    one (head, token) by _page_rows. `onehot` [ps*K, ps] picks each
+    row's token in one exact product; the row's own head is then the
+    one lane group kept. Tokens from `valid` on read as scale 0 whatever
+    the pool (or a slot no copy reached) holds there: they are masked
+    out of the softmax, and a zero keeps them out of the weighted sum."""
+    n, kg, _ = scales.shape
+    groups = kg // kh
+    pr = page_size * kh
+    lane = jax.lax.broadcasted_iota(jnp.int32, (pr, kg), 1)
+    head, _ = _page_rows((pr, kg), 0, page_size=page_size, kh=kh,
+                         token_major=token_major)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (kg, page_size), 1)
+    pages = []
+    for j in range(n):
+        live = first_pos + j * page_size + tok < valid
+        picked = jax.lax.dot_general(
+            onehot, jnp.where(live, scales[j], 0.0),
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)            # [pr, K*G]
+        pages.append(jnp.concatenate(
+            [jnp.sum(jnp.where(lane == head * groups + g, picked, 0.0),
+                     axis=-1, keepdims=True) for g in range(groups)],
+            axis=-1))
+    return jnp.concatenate(pages, axis=0)
+
+
+def _paged_decode_kernel(table_ref, valid_ref, q_ref, *rest,
+                         page_size: int, n: int, kh: int,
+                         group: int, rows: int, token_major: bool,
                          sliding_window: Optional[int],
                          softcap: Optional[float],
                          kv_bits: int = 8, quantized: bool = False):
-    # Identical online-softmax math to _decode_kernel; the paged
-    # differences: the kv block for grid step sb is pool page
-    # table[b, sb] (not cache row sb), and ALL kv heads ride one block —
-    # the pool keeps its [P, ps, K, D] layout, and a per-head block
-    # (1, ps, 1, D) is Mosaic-ILLEGAL for K > 1 (second-minor block dim
-    # 1 is neither 8-aligned nor the full K axis; unseen on hardware
-    # until GQA because gemma's MQA pool has K == 1). So the grid drops
-    # its kv-head dimension, each page is DMA'd once per row with every
-    # head (same total bytes as per-head page reads), and a STATIC
-    # unrolled loop walks the heads against per-head scratch slices.
-    # valid INCLUDES the current step's entry, which the caller has
-    # already written into the pool (q position = valid - 1).
-    # Quantized pools (ISSUE 11): two extra per-page scale blocks ride
-    # the kv index map, dequantized inside _decode_accumulate.
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    sb = pl.program_id(1)
+    # See "the walk" above. valid INCLUDES the current step's entry,
+    # which the caller has already written into the pool (q position =
+    # valid - 1). Quantized pools (ISSUE 11): the two scale pools walk
+    # with the pages and the rows dequantize inside _decode_accumulate.
+    # `rest`: the pools in HBM (k, v and, if quantized, their scales),
+    # the output, one two-slot VMEM buffer a pool, the position scratch,
+    # the scales' one-hot (quantized), the DMA semaphores.
+    n_pools = 4 if quantized else 2
+    hbms, o_ref = rest[:n_pools], rest[n_pools]
+    bufs, sem = rest[n_pools + 1:2 * n_pools + 1], rest[-1]
+    pos_scr = rest[2 * n_pools + 1]
+    kbuf, vbuf = bufs[:2]
+    block, hq, d = q_ref.shape
+    pr = page_size * kh                 # rows of one flattened page
+    cols = n * pr
+    first_row = pl.program_id(0) * block
+    layout = dict(page_size=page_size, kh=kh, token_major=token_major)
+    # (pool in HBM, its two-slot buffer, where page j of a trip lands)
+    lanes = [(hbm.reshape(hbm.shape[0], pr, hbm.shape[-1]), buf,
+              lambda j: pl.ds(j * pr, pr)) for hbm, buf in zip(hbms[:2],
+                                                               bufs[:2])]
+    lanes += [(hbm, buf, lambda j: j) for hbm, buf in zip(hbms[2:],
+                                                         bufs[2:])]
 
-    @pl.when(sb == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        # Column c of a trip is one (head, token) of page c // pr; q
+        # row r belongs to kv head r // group. Foreign heads get a
+        # position no `valid` reaches, so ONE compare masks both.
+        col = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (hq, cols), 0)
+        head, tok = _page_rows((hq, cols), 1, **layout)
+        head, tok = head % kh, tok % page_size      # col runs over n pages
+        pos_scr[...] = jnp.where(head == row // group,
+                                 col // pr * page_size + tok, _FOREIGN)
+        # A trip's last pages may lie past the row's frontier and are
+        # then not copied: what the slot holds there is masked out of
+        # the scores, but the weighted sum multiplies it by zero —
+        # which a NaN left in fresh VMEM would survive.
+        vbuf[...] = jnp.zeros_like(vbuf)
+        if quantized:
+            onehot = rest[-2]
+            _, tok = _page_rows(onehot.shape, 0, **layout)
+            t = jax.lax.broadcasted_iota(jnp.int32, onehot.shape, 1)
+            onehot[...] = (tok == t).astype(onehot.dtype)
 
-    valid = valid_ref[b]
-    hi = (valid - 1) // page_size
-    if sliding_window is None:
-        lo = jnp.int32(0)
-    else:
-        lo = jnp.maximum(0, (valid - sliding_window) // page_size)
+    def span(b):
+        valid = valid_ref[b]
+        hi = jnp.maximum(valid - 1, 0) // page_size
+        if sliding_window is None:
+            lo = jnp.int32(0)
+        else:
+            lo = jnp.maximum(0, (valid - sliding_window) // page_size)
+        pages = jnp.where(valid > 0, hi - lo + 1, 0)
+        return valid, lo, hi, (pages + n - 1) // n
 
-    @pl.when((sb >= lo) & (sb <= hi))
-    def _compute():
-        for khi in range(kh):
-            m_scr[khi], l_scr[khi], acc_scr[khi] = _decode_accumulate(
-                q_ref[0, khi], k_ref[0, :, khi, :], v_ref[0, :, khi, :],
-                sb * page_size, valid,
-                (m_scr[khi], l_scr[khi], acc_scr[khi]), group=group,
-                block_kv=page_size, sliding_window=sliding_window,
-                softcap=softcap,
-                k_scale=(ks_ref[0, :, khi, :] if quantized else None),
-                v_scale=(vs_ref[0, :, khi, :] if quantized else None),
-                kv_bits=kv_bits)
+    def copies(b, lo, hi, t, slot, go):
+        # Start (go=True) or await trip t of row b into `slot`; a wait
+        # only needs the copy's shape, not its source page.
+        for j in range(n):
+            at = lo + t * n + j
 
-    @pl.when(sb == num_page_blocks - 1)
-    def _finish():
-        for khi in range(kh):
-            l = jnp.maximum(l_scr[khi, :, :1], 1e-30)
-            o_ref[0, khi] = (acc_scr[khi] / l).astype(o_ref.dtype)
+            @pl.when(at <= hi)
+            def _():
+                page = table_ref[b, at] if go else 0
+                for i, (hbm, buf, where) in enumerate(lanes):
+                    c = pltpu.make_async_copy(
+                        hbm.at[page], buf.at[slot, where(j)],
+                        sem.at[i, slot])
+                    c.start() if go else c.wait()
+
+    last = jnp.minimum(first_row + block, rows) - 1
+
+    def one_row(i, carry):
+        g0, started = carry     # trips so far; was my first trip started
+        b = first_row + i
+        valid, lo, hi, trips = span(b)
+        nb = jnp.minimum(b + 1, last)
+        _, nlo, nhi, ntrips = span(nb)
+        hand_on = (b < last) & (ntrips > 0) & (trips > 0)
+
+        @pl.when((trips > 0) & (started == 0))
+        def _():
+            copies(b, lo, hi, 0, g0 % 2, True)
+
+        q = q_ref[i]                                    # [hq, d]
+
+        def trip(t, state):
+            slot = (g0 + t) % 2
+
+            @pl.when(t + 1 < trips)
+            def _():
+                copies(b, lo, hi, t + 1, 1 - slot, True)
+
+            @pl.when((t + 1 == trips) & hand_on)
+            def _():
+                copies(nb, nlo, nhi, 0, 1 - slot, True)
+
+            copies(b, lo, hi, t, slot, False)
+            first_pos = (lo + t * n) * page_size
+            scales = {}
+            if quantized:
+                scales = {
+                    name: _scale_columns(
+                        buf[slot], rest[-2][...], first_pos, valid,
+                        **layout)
+                    for name, buf in zip(("k_scale", "v_scale"), bufs[2:])}
+            return _decode_accumulate(
+                q, kbuf[slot], vbuf[slot], 0, valid, state, group=hq,
+                block_kv=cols, sliding_window=sliding_window,
+                softcap=softcap, kv_bits=kv_bits,
+                kv_pos=first_pos + pos_scr[...], **scales)
+
+        _, l, acc = jax.lax.fori_loop(
+            0, trips, trip,
+            (jnp.full((hq, _LANES), NEG_INF, jnp.float32),
+             jnp.zeros((hq, _LANES), jnp.float32),
+             jnp.zeros((hq, d), jnp.float32)))
+        o_ref[i] = (acc / jnp.maximum(l[:, :1], 1e-30)).astype(o_ref.dtype)
+        return g0 + trips, hand_on.astype(jnp.int32)
+
+    jax.lax.fori_loop(0, last - first_row + 1, one_row,
+                      (jnp.int32(0), jnp.int32(0)))
 
 
 def paged_decode_spmd(
@@ -909,78 +1173,70 @@ def paged_decode_attention(
     """Single-position decode attention straight off the page pool.
 
     The caller must have written this step's K/V into each row's frontier
-    page already (a [B]-row scatter — engine/paged_forward.py). The kv
-    block index map reads the page table, so only pages holding each
-    row's valid prefix are ever DMA'd, and the [B, S, K, D] gather view
-    the engine's fallback path materializes is never built. The pool
-    keeps its prefill-friendly [P, ps, K, D] layout; a page block
-    carries ALL kv heads (1, ps, K, D) and a static in-kernel loop walks
-    them — per-head (1, ps, 1, D) blocks are Mosaic-illegal for K > 1,
-    and total DMA bytes are identical either way (each page read once
-    per row). Returns [B, 1, H, D]. `k_scale`/`v_scale` (ISSUE 11):
-    quantized pools dequantize in-kernel — the scale blocks ride the
-    same page index map.
+    page already (a [B]-row scatter — engine/paged_forward.py). Each row
+    walks the pages of its own valid prefix (from the window's first
+    page, for a sliding window) and nothing else of the table's width,
+    several pages a trip, copied while the trip before is multiplied
+    (see "the walk" above); the [B, S, K, D] gather view the engine's
+    fallback path materializes is never built, and the pool keeps its
+    prefill-friendly [P, ps, K, D] layout and stays an operand of the
+    call as it is. A row with nothing valid costs no trip and yields
+    zeros. Returns [B, 1, H, D]. `k_scale`/`v_scale` (ISSUE 11):
+    quantized pools dequantize in-kernel — the scale pools walk with
+    the pages.
     """
     b, t, h, d = q.shape
     assert t == 1, "decode kernel serves exactly one position"
     page_size, kh = k_pool.shape[1], k_pool.shape[2]
     group = h // kh
-    pages_per_seq = table.shape[1]
     quantized = k_scale is not None
-    if not paged_decode_supported(page_size, d, kh, group):
-        raise ValueError(f"unsupported pool shape ps={page_size} D={d}")
+    shape = dict(dk=k_pool.shape[-1], itemsize=k_pool.dtype.itemsize,
+                 scale_groups=k_scale.shape[-1] if quantized else 0)
+    reason = paged_decode_decline_reason(page_size, d, kh, group, **shape)
+    if reason is not None:
+        raise ValueError(f"unsupported pool shape: {reason}")
     interpret = _interpret() if interpret is None else interpret
+    n = min(_walk_pages(page_size, d, kh, group, **shape), table.shape[1])
+    trip_rows = n * page_size * kh
+    block = min(b, _WALK_ROW_BLOCK)
 
-    qt = q[:, 0].reshape(b, kh, group, d)
-
-    def kv_index(bi, sb, table_ref, valid_ref):
-        hi_blk = (valid_ref[bi] - 1) // page_size
-        if sliding_window is None:
-            lo_blk = jnp.int32(0)
-        else:
-            lo_blk = jnp.maximum(
-                0, (valid_ref[bi] - sliding_window) // page_size)
-        sb = jnp.clip(sb, lo_blk, jnp.maximum(hi_blk, 0))
-        return (table_ref[bi, sb], 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, kh, group, d),
-                     lambda bi, sb, t_, v_: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, page_size, kh, k_pool.shape[-1]), kv_index),
-        pl.BlockSpec((1, page_size, kh, v_pool.shape[-1]), kv_index),
-    ]
-    operands = [qt, k_pool, v_pool]
+    token_major = _token_major(kh, k_pool.dtype.itemsize)
+    if not token_major:
+        # the pool as XLA stores it, head-major: see "the walk" above
+        k_pool, v_pool = (p.swapaxes(1, 2).reshape(
+            p.shape[0], kh * page_size, p.shape[-1])
+            for p in (k_pool, v_pool))
+    pools = [k_pool, v_pool]
+    bufs = [pltpu.VMEM((2, trip_rows, p.shape[-1]), p.dtype) for p in pools]
+    consts = [pltpu.VMEM((h, trip_rows), jnp.int32)]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, page_size, kh, k_scale.shape[-1]), kv_index),
-            pl.BlockSpec((1, page_size, kh, v_scale.shape[-1]), kv_index),
-        ]
-        operands += [k_scale, v_scale]
+        # the scale pools as XLA stores them: see "the walk" above
+        pools += [jnp.transpose(s, (0, 2, 3, 1)).reshape(
+            s.shape[0], -1, page_size) for s in (k_scale, v_scale)]
+        bufs += [pltpu.VMEM((2, n) + p.shape[1:], p.dtype)
+                 for p in pools[2:]]
+        consts += [pltpu.VMEM((page_size * kh, page_size), jnp.float32)]
+    rows_blk = pl.BlockSpec((block, h, d), lambda i, t_, v_: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, pages_per_seq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, kh, group, d),
-            lambda bi, sb, t_, v_: (bi, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kh, group, _LANES), jnp.float32),
-            pltpu.VMEM((kh, group, _LANES), jnp.float32),
-            pltpu.VMEM((kh, group, d), jnp.float32),
-        ],
+        grid=(pl.cdiv(b, block),),
+        in_specs=[rows_blk] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=rows_blk,
+        scratch_shapes=bufs + consts + [
+            pltpu.SemaphoreType.DMA((len(bufs), 2))],
     )
     kernel = functools.partial(
-        _paged_decode_kernel, page_size=page_size,
-        num_page_blocks=pages_per_seq, kh=kh, group=group,
-        sliding_window=sliding_window, softcap=softcap,
-        kv_bits=kv_bits, quantized=quantized)
+        _paged_decode_kernel, page_size=page_size, n=n, kh=kh,
+        group=group, rows=b, token_major=token_major,
+        sliding_window=sliding_window, softcap=softcap, kv_bits=kv_bits,
+        quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
-    )(table.astype(jnp.int32), kv_valid.astype(jnp.int32), *operands)
+    )(table.astype(jnp.int32), kv_valid.astype(jnp.int32), q[:, 0], *pools)
     return out.reshape(b, 1, h, d)
 
 
@@ -1070,7 +1326,7 @@ def ragged_supported(page_size: int, d: int, kh: int = 1,
 # kv_quant_decline_reason declines int4 pools wherever the kernel would
 # be compiled for the chip, so the engine records the reason at
 # construction and serves the XLA dequant paths by plan, not by a
-# runtime degradation rung. int8 pages compile and keep the kernels.
+# runtime degradation rung.
 MOSAIC_INT4_KV_REFUSAL = (
     "infer-vector-layout: unsupported shape cast (tpu.reshape of the "
     "packed page block, vector<ps x D/2 x i8> -> ps x D/2 x 1, in "
@@ -1084,22 +1340,30 @@ def kv_quant_decline_reason(page_size: int, d: int, kh: int, group: int,
     shape, or None when they can — the machine-readable
     `fallback_reason` the engine records (the int4mm plan_reason
     pattern, ISSUE 11). The bf16 kernel gates (page_size block
-    legality, VMEM, lane-aligned D) apply unchanged — quantized blocks
-    are strictly smaller, so the bf16 VMEM estimate stays a safe upper
-    bound; int4 additionally needs an even head_dim whose packed width
-    and scale grouping are well-formed. A declined shape serves through
-    the XLA dequant fallback (gather view / ragged dense path) — the
-    pages stay quantized either way, only the dequant site moves."""
+    legality, VMEM, lane-aligned D) apply unchanged, and the decode
+    walk's with the pool's real cells (payload rows and lane-padded
+    scale rows); int4 additionally needs an even head_dim whose packed
+    width and scale grouping are well-formed. A declined shape serves
+    through the XLA dequant fallback (gather view / ragged dense path)
+    — the pages stay quantized either way, only the dequant site
+    moves."""
     if bits not in (8, 4):
         return f"kv_bits:{bits}"
     base = ragged_decline_reason(page_size, d, kh, group)
     if base is not None:
         return base
+    from ..kv_quant import KVQuantSpec
+    spec = KVQuantSpec(bits=bits, group=quant_group)
+    if bits == 8 or (d % 2 == 0 and d % spec.effective_group(d) == 0):
+        base = paged_decode_decline_reason(
+            page_size, d, kh, group, itemsize=1, dk=spec.packed_dim(d),
+            scale_groups=spec.num_groups(d))
+        if base is not None:
+            return base
     if bits == 4:
         if d % 2:
             return f"int4_head_dim:{d}"
-        from ..kv_quant import KVQuantSpec
-        g = KVQuantSpec(bits=4, group=quant_group).effective_group(d)
+        g = spec.effective_group(d)
         if d % g or g % 2:
             # effective_group clamps to >= 2; a grouping that doesn't
             # tile D evenly means no well-formed scale layout exists.
@@ -1124,7 +1388,7 @@ def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
                    kv_bits: int = 8, quantized: bool = False):
     # Grid (q_blocks, pages_per_seq). Identical online-softmax math to
     # _paged_prefill_kernel (shared _prefill_accumulate, all kv heads on
-    # one pool block with a static head loop — see _paged_decode_kernel
+    # one pool block with a static head loop — see _paged_prefill_kernel
     # for why per-head pool blocks are Mosaic-illegal); the ragged
     # difference is WHICH sequence a q block serves: blkseq_ref maps the
     # flat-buffer block to its sequence, whose page table / causal
