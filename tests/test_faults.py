@@ -595,30 +595,6 @@ class TestKvRevive:
         assert kv.slot_names() == []            # nothing cached survives
         kv.acquire("Sage")                      # slots usable again
 
-    def test_pp_paged_revive_drops_dead_gather_view(self):
-        """A dispatch that dies inside the PP engine's gather→scatter
-        window leaves self.kc as a DELETED gather view (the finally's
-        scatter raises before resetting it). revive_kv_if_dead must
-        branch on the layout — not `kc is None` — drop the view, and
-        leave pool revival to the allocator, instead of crashing on the
-        contiguous branch's _make_contig."""
-        import jax.numpy as jnp
-        cfg = {"model": "tiny-gemma", "max_seq_len": 256, "num_slots": 2,
-               "mesh": {"pipe": 2}, "kv_layout": "paged", "page_size": 32,
-               "seed": 107,
-               "sampling": {"temperature": 0.0, "max_new_tokens": 4}}
-        engine = get_engine(cfg)
-        dead = jnp.zeros((2,))
-        dead.delete()
-        engine.kc = engine.vc = dead
-        assert engine.revive_kv_if_dead() is False   # pools still alive
-        assert engine.kc is None and engine.vc is None
-        for k, v in engine.kv.pools:                 # now kill the pools
-            k.delete()
-            v.delete()
-        assert engine.revive_kv_if_dead() is True
-        assert not engine.kv.pools[0][0].is_deleted()
-
     def test_paged_revive_resets_pages(self):
         from theroundtaible_tpu.engine.paging import PagedKVCache
         kv = PagedKVCache(self._model_cfg(), 2, max_seq_len=64,
@@ -709,17 +685,6 @@ class TestEngineChaos:
         assert adapter.breaker().failures == 1
         # next call (fault exhausted) serves and closes the breaker
         assert isinstance(adapter.execute("a healthy question"), str)
-        assert adapter.breaker().failures == 0
-
-    def test_pp_engine_dispatch_retried_in_place(self):
-        """The PP engine shares the serving loop's retry seam."""
-        cfg = {"model": "tiny-gemma", "max_seq_len": 256, "num_slots": 2,
-               "mesh": {"pipe": 2}, "seed": 105,
-               "sampling": {"temperature": 0.0, "max_new_tokens": 8}}
-        adapter = TpuLlmAdapter("Sage", cfg, timeout_ms=600_000)
-        spec = faults.arm("dispatch", count=1)
-        assert isinstance(adapter.execute("a pipelined question"), str)
-        assert spec.fired == 1
         assert adapter.breaker().failures == 0
 
     def test_donation_death_revives_and_serves_serially(self):

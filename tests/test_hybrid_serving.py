@@ -445,13 +445,6 @@ def test_what_the_model_cannot_serve_without_fails_at_build(config,
         make_engine(num_slots=2, **config)
 
 
-def test_the_pipeline_engine_declines():
-    from theroundtaible_tpu.engine.pp_serving import PPEngine
-    with pytest.raises(ValueError, match="recurrent-state"):
-        PPEngine.from_config({"model": "tiny-nemotron-h",
-                              "mesh": {"pipe": 2}})
-
-
 def test_fleet_estimates_the_state_beside_the_pools():
     from theroundtaible_tpu.engine.fleet import estimate_engine_hbm_bytes
     base = {"model": "tiny-nemotron-h", "kv_layout": "paged",

@@ -340,38 +340,6 @@ def test_engine_sharded_serving_token_parity(monkeypatch):
     assert stats.int4_paths["pallas_w4a16"]
 
 
-@pytest.mark.quant_kernels(allow=("rows:prefill-m",))
-def test_pp_pipe_only_int4_kernel_path(monkeypatch):
-    """PP stage bodies on a pipe-only mesh announce LOCAL_MESH (fully
-    manual → arrays local and full-size), so int4 serves on the raw
-    kernels inside the stages AND on the in-stage decode lm head —
-    token parity vs the XLA path, provenance asserted."""
-    import dataclasses
-
-    from theroundtaible_tpu.engine.pp_serving import PPEngine
-    from theroundtaible_tpu.engine.sampling import SamplingParams
-
-    if len(jax.devices()) < 2:
-        pytest.skip("needs 2 virtual devices")
-    cfg = dataclasses.replace(SHARDED, max_seq_len=256)
-    outs, eng = {}, None
-    for flag in ("1", "0"):
-        monkeypatch.setenv("ROUNDTABLE_INT4_MM", flag)
-        e = PPEngine(cfg, n_stages=2, n_model=1, n_micro=2, num_slots=2,
-                     quant="int4", devices=[0, 1],
-                     sampling=SamplingParams(temperature=0.0,
-                                             max_new_tokens=6))
-        outs[flag] = e.generate("pipeline the packed nibbles",
-                                slot_name="pp", max_new_tokens=6)
-        if flag == "1":
-            eng = e
-    assert outs["1"] == outs["0"]
-    rep = eng.int4_path_report()
-    kernel_specs = {x["spec"] for x in rep["pallas_w4a16"]}
-    assert "bte,ve->btv" in kernel_specs, rep   # in-stage decode head
-    assert "bte,ef->btf" in kernel_specs, rep   # stage-scan MLP
-
-
 @pytest.mark.quant_kernels
 def test_model_forward_token_parity(monkeypatch):
     """Full int4 forward with the kernel on vs off: same greedy tokens,

@@ -551,13 +551,6 @@ class TestDeclineAndLowering:
         assert eng.kv_quant_spec is None
         assert eng.kv_quant_reason == "kv_layout:contiguous"
 
-    def test_pool_factory_declines(self):
-        cfg = get_model_config("tiny-gemma", max_seq_len=128)
-        with pytest.raises(ValueError, match="pool_factory"):
-            PagedKVCache(cfg, 2, 128, jnp.bfloat16, page_size=16,
-                         pool_factory=lambda n: [],
-                         kv_quant=kvq.KVQuantSpec(bits=8))
-
     def _quant_pool(self, bits=8):
         spec = kvq.KVQuantSpec(bits=bits, group=32)
         pool_pages = 16

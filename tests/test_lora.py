@@ -266,19 +266,6 @@ def test_stack_bytes_for_matches_store():
 
 
 @pytest.mark.lora(allow_single=True)
-def test_adapter_kwarg_gated_on_engine_support():
-    """Persona configs on engines WITHOUT a lora store (PP engine,
-    kill-switched InferenceEngine) must serve base gracefully — the
-    adapter never passes a kwarg the engine may not accept."""
-    from types import SimpleNamespace
-
-    from theroundtaible_tpu.adapters.tpu_llm import _engine_serves_lora
-    assert not _engine_serves_lora(SimpleNamespace())       # PP shape
-    assert not _engine_serves_lora(SimpleNamespace(lora=None))
-    assert _engine_serves_lora(SimpleNamespace(lora=object()))
-
-
-@pytest.mark.lora(allow_single=True)
 def test_store_rejects_bad_config():
     with pytest.raises(ValueError, match="max_adapters"):
         LoraStore(_cfg(), max_adapters=0)

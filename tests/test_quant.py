@@ -217,8 +217,8 @@ class TestQuantServing:
 
 class TestInt4:
     """Grouped w4a16 (engine/quant.py bits=4 → Int4Leaf): packing
-    roundtrip, forward accuracy, serving across meshes/layouts, byte
-    shrink, and the PP-engine gate."""
+    roundtrip, forward accuracy, serving across meshes/layouts, and byte
+    shrink."""
 
     def test_leaf_structure_and_roundtrip(self):
         from theroundtaible_tpu.engine.models.common import (Int4Leaf,
@@ -304,29 +304,3 @@ class TestInt4:
         # add ~2/group); logical param_count stays the full count
         assert tree_bytes(q4.params) < 0.33 * tree_bytes(fp.params)
         assert q4.num_params >= fp.num_params
-
-    def test_pp_tp_int4_matches_main_engine(self):
-        """int4 under the pipeline engine (Int4Leaf leaves stacked per
-        stage, placed via quantized_specs' metadata-mirroring spec tree,
-        TP inside stages): token parity with the main engine's int4 on
-        the same seed, contiguous AND paged."""
-        from theroundtaible_tpu.engine.pp_serving import PPEngine
-        cfg = get_model_config("tiny-llama", max_seq_len=128)
-        sp = SamplingParams(temperature=0.0, max_new_tokens=8)
-        ref = InferenceEngine(cfg, num_slots=2, quant="int4",
-                              dtype=jnp.float32, seed=7, sampling=sp)
-        for extra in ({}, {"kv_layout": "paged", "page_size": 32,
-                           "num_pages": 9}):
-            pp = PPEngine(cfg, n_stages=2, n_model=2, n_micro=2,
-                          num_slots=2, quant="int4", dtype=jnp.float32,
-                          seed=7, sampling=sp, devices=list(range(4)),
-                          **extra)
-            p = "the pipeline serves packed nibbles now"
-            ext = p + " and a follow-up turn reuses the slot prefix"
-            for eng in (pp, ref):
-                eng.kv.release("k")
-            assert (pp.generate(p, slot_name="k", max_new_tokens=8)
-                    == ref.generate(p, slot_name="k", max_new_tokens=8))
-            assert (pp.generate(ext, slot_name="k", max_new_tokens=8)
-                    == ref.generate(ext, slot_name="k", max_new_tokens=8))
-            assert pp.last_stats.reused_tokens > 0

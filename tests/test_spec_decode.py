@@ -369,8 +369,11 @@ class TestScheduledSpec:
         # spec flag never appears in the recent ring.
         assert all("spec" not in e
                    for e in nospec_engine.ragged_describe()["recent"])
-        assert before.keys() == \
-            nospec_engine._ragged_dispatches.keys()
+        # ... and whatever path is new in the provenance sink after
+        # this run is a plain ragged one (`before` is empty when this
+        # test is the first to serve on the module's engine).
+        new = nospec_engine._ragged_dispatches.keys() - before.keys()
+        assert new <= {"pallas_ragged", "xla_ragged"}, new
 
     @pytest.mark.scheduler
     @pytest.mark.spec_decode

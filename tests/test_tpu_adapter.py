@@ -12,7 +12,8 @@ from theroundtaible_tpu.core.types import (
     RoundtableConfig,
     RulesConfig,
 )
-from theroundtaible_tpu.engine import reset_engines
+from theroundtaible_tpu.engine import (ENGINE_CONFIG_KEYS, _cache_key,
+                                      get_engine, reset_engines)
 
 TPU_CFG = {
     "model": "tiny-gemma",
@@ -140,3 +141,90 @@ class TestTpuAdapter:
         cfg.adapter_config["tpu-llm"] = {"model": "no-such-model"}
         adapter = create_adapter("tpu-llm", cfg)
         assert not adapter.is_available()
+
+
+# One realistic second value for every key that shapes the engine built
+# from an adapter config. A key added to ENGINE_CONFIG_KEYS without a
+# value here fails its case with a KeyError.
+OTHER_VALUE = {
+    "model": "tiny-llama",
+    "architecture": {"model_type": "llama", "hidden_size": 64},
+    "checkpoint": "/models/tiny", "max_seq_len": 256, "dtype": "float32",
+    "mesh": {"model": 2}, "seq_parallel": 2, "long_scheme": "ulysses",
+    "long_threshold": 1024, "devices": [0, 1], "attn": "dense",
+    "num_slots": 2, "sampling": {"temperature": 0.7}, "seed": 1,
+    "kv_layout": "paged", "page_size": 64, "num_pages": 33,
+    "quant": "int8", "dcn_axis": "data", "prefix_cache": False,
+    "prefix_cache_pages": 16, "kv_offload": False, "ragged_attn": False,
+    "spec_decode": False, "spec_max_draft": 2,
+    "lora": {"rank": 4, "max_adapters": 2}, "kv_quant": "int8",
+    "state_snapshot_bytes": 0,
+}
+
+
+class TestEngineCacheKey:
+    """get_engine shares one resident engine between configs with the
+    same key: every key the build reads must be part of it."""
+
+    @pytest.mark.parametrize("key", ENGINE_CONFIG_KEYS)
+    def test_a_key_the_build_reads_separates_engines(self, key):
+        other = dict(TPU_CFG)
+        other[key] = OTHER_VALUE[key]
+        assert other[key] != TPU_CFG.get(key)
+        assert _cache_key(other) != _cache_key(TPU_CFG)
+
+    @pytest.mark.parametrize("key,value", [
+        ("breaker_threshold", 5), ("dispatch_retries", 0),
+        ("knight_sampling", {"Sage": {"top_k": 4}})])
+    def test_a_setting_around_the_engine_does_not(self, key, value):
+        assert _cache_key(dict(TPU_CFG, **{key: value})) == \
+            _cache_key(TPU_CFG)
+
+    def test_the_build_sees_no_key_outside_the_tuple(self, monkeypatch):
+        """from_config reads its config through ENGINE_CONFIG_KEYS: a
+        key left out of the tuple cannot shape an engine behind the
+        cache key's back."""
+        from theroundtaible_tpu.engine import engine
+        seen = {}
+
+        def resolve(cfg):
+            seen.update(cfg)
+            raise LookupError("stop before anything is built")
+
+        monkeypatch.setattr(engine, "resolve_model_config", resolve)
+        with pytest.raises(LookupError):
+            engine.InferenceEngine.from_config(
+                dict(TPU_CFG, breaker_threshold=5, not_a_key=1))
+        assert set(seen) == set(TPU_CFG) <= set(ENGINE_CONFIG_KEYS)
+
+
+class TestMeshValidation:
+    """A mesh is data x model. An axis it does not have — the retired
+    pipeline axis among them — fails at build and says what to use."""
+
+    MESHES = [{"pipe": 2}, {"pipe": 2, "model": 2}]
+
+    @staticmethod
+    def _names_the_axis_and_the_remedy(message):
+        assert "'pipe'" in message and '{"model": N}' in message, message
+
+    @pytest.mark.parametrize("mesh", MESHES, ids=str)
+    def test_build_mesh_rejects_it(self, mesh):
+        from theroundtaible_tpu.engine.sharding import build_mesh
+        with pytest.raises(ValueError) as e:
+            build_mesh(mesh)
+        self._names_the_axis_and_the_remedy(str(e.value))
+
+    @pytest.mark.parametrize("mesh", MESHES, ids=str)
+    def test_get_engine_rejects_it(self, mesh):
+        with pytest.raises(ValueError) as e:
+            get_engine(dict(TPU_CFG, mesh=mesh))
+        self._names_the_axis_and_the_remedy(str(e.value))
+
+    @pytest.mark.parametrize("mesh", MESHES, ids=str)
+    def test_the_adapter_reports_it_unavailable(self, mesh):
+        cfg = make_config()
+        cfg.adapter_config["tpu-llm"] = dict(TPU_CFG, mesh=mesh)
+        adapter = create_adapter("tpu-llm", cfg)
+        assert not adapter.is_available()
+        self._names_the_axis_and_the_remedy(adapter.unavailable_reason())

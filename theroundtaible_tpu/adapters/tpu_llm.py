@@ -42,14 +42,6 @@ MIN_AVAILABLE_TOKENS = 2000
 BATCH_BUDGET_FRACTION = 0.6
 
 
-def _engine_serves_lora(engine) -> bool:
-    """True when this engine resolved an adapter store (ISSUE 10).
-    Gates the adapters_per_turn kwarg: the PP engine's
-    generate_batch_with_stats has no such parameter, and a lora-off
-    engine serves base regardless — both must decline gracefully."""
-    return getattr(engine, "lora", None) is not None
-
-
 class TpuLlmAdapter(BaseAdapter):
     """BaseAdapter over an EngineHandle (theroundtaible_tpu.engine)."""
 
@@ -366,13 +358,12 @@ class TpuLlmAdapter(BaseAdapter):
             "timeout_s": max(batch_budget.remaining(), 0.0),
             "budget": batch_budget}
         ads = self._adapters_for(turns)
-        if ads is not None and _engine_serves_lora(engine):
+        if ads is not None:
             # Persona adapters ride the round into the engine /
             # scheduler (ISSUE 10); co-batched knights with DIFFERENT
-            # personas decode in one mixed-adapter segment. Engines
-            # without a lora store — the PP engine, a kill-switched or
-            # config-less InferenceEngine — serve the base model
-            # instead of choking on an unknown kwarg (the
+            # personas decode in one mixed-adapter segment. An engine
+            # without a lora store — kill-switched or config-less —
+            # drops the kwarg itself and serves the base model (the
             # ROUNDTABLE_LORA=0 byte-identity contract).
             kwargs["adapters_per_turn"] = ads
         if per_turn is not None:
@@ -472,7 +463,7 @@ class TpuLlmAdapter(BaseAdapter):
                 "timeout_s": max(knight_budget.remaining(), 0.0),
                 "budget": knight_budget}
             ad = self._adapter_for(t.knight_name)
-            if ad is not None and _engine_serves_lora(engine):
+            if ad is not None:
                 kwargs["adapters_per_turn"] = [ad]
             if per_turn is not None:
                 kwargs["sampling_per_turn"] = [per_turn[i]]

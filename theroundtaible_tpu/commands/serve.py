@@ -82,15 +82,9 @@ def _attach_schedulers(adapters: dict, session_id: str,
             # same-named knights would collide on the recovered engine.
             adapter.session = session_id
             continue
-        # PPEngine has no segment seam to schedule at — sessions on a
-        # pipe mesh still get namespace isolation via adapter.session.
         from ..engine.scheduler import acquire_scheduler
-        try:
-            sched, created = acquire_scheduler(
-                engine, admit_hold_s=admit_hold_s)
-        except TypeError:
-            adapter.session = session_id
-            continue
+        sched, created = acquire_scheduler(
+            engine, admit_hold_s=admit_hold_s)
         if journal is not None and sched.journal is not journal:
             # Durable turn journal (ISSUE 12): one journal per serve
             # root, shared by every scheduler — committed turns fsync

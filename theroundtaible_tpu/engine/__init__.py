@@ -2,12 +2,11 @@
 
 `get_engine(config)` is the single construction seam used by
 adapters/tpu_llm.py: it joins the multi-host process group (distributed),
-routes pipe meshes to the pipeline engine (pp_serving) and everything
-else to InferenceEngine (engine), and caches engines by every
-serving-relevant config key so knights with identical configs share one
-resident model while differing ones never silently collide (SURVEY.md
-§7.1; per-call settings like knight_sampling are deliberately NOT in the
-key).
+builds an InferenceEngine (engine), and caches engines by every config
+key the build reads (ENGINE_CONFIG_KEYS) so knights with identical
+configs share one resident model while differing ones never silently
+collide (SURVEY.md §7.1; per-call settings like knight_sampling are
+deliberately NOT in the key).
 """
 
 from __future__ import annotations
@@ -91,24 +90,28 @@ def get_compile_cache_decision() -> dict[str, Any] | None:
     return _compile_cache_decision
 
 
+# Every adapter-config key that shapes the engine built from it.
+# InferenceEngine.from_config (and registry.resolve_model_config under
+# it) sees a config through this tuple only, and _cache_key is made of
+# the same one: a key the build can read is a key that separates
+# engines. breaker_threshold and dispatch_retries are settings of the
+# fault ladder around a built engine and are left out on purpose.
+ENGINE_CONFIG_KEYS = (
+    "model", "architecture", "checkpoint", "max_seq_len", "dtype", "mesh",
+    "seq_parallel", "long_scheme", "long_threshold", "devices", "attn",
+    "num_slots", "sampling", "seed", "kv_layout", "page_size",
+    "num_pages", "quant", "dcn_axis", "prefix_cache",
+    "prefix_cache_pages", "kv_offload", "ragged_attn", "spec_decode",
+    "spec_max_draft", "lora", "kv_quant", "state_snapshot_bytes")
+
+
 def _cache_key(config: dict[str, Any]) -> str:
-    relevant = {k: config.get(k) for k in
-                ("model", "checkpoint", "max_seq_len", "dtype", "mesh",
-                 "seq_parallel", "long_scheme", "long_threshold",
-                 "devices", "attn", "num_slots", "sampling", "seed",
-                 "kv_layout", "page_size", "num_pages", "n_micro",
-                 "quant", "dcn_axis", "prefix_cache",
-                 "prefix_cache_pages", "kv_offload", "ragged_attn",
-                 "spec_decode", "spec_max_draft", "lora", "kv_quant")}
-    return json.dumps(relevant, sort_keys=True)
+    return json.dumps({k: config.get(k) for k in ENGINE_CONFIG_KEYS},
+                      sort_keys=True)
 
 
 def get_engine(config: dict[str, Any]):
-    """Build (or reuse) an engine for this adapter config.
-
-    A mesh with a "pipe" axis selects the pipeline-parallel serving
-    engine (stage-local weights + KV, engine/pp_serving.py); everything
-    else gets the main InferenceEngine."""
+    """Build (or reuse) an engine for this adapter config."""
     # Join the multi-host process group BEFORE any backend/device call —
     # this seam runs ahead of plan_fleet's jax.devices() and every engine
     # constructor (engine/distributed.py; jax.distributed.initialize must
@@ -118,12 +121,8 @@ def get_engine(config: dict[str, Any]):
     key = _cache_key(config)
     with _lock:
         if key not in _engines:
-            if (config.get("mesh") or {}).get("pipe"):
-                from .pp_serving import PPEngine
-                eng = PPEngine.from_config(config)
-            else:
-                from .engine import InferenceEngine
-                eng = InferenceEngine.from_config(config)
+            from .engine import InferenceEngine
+            eng = InferenceEngine.from_config(config)
             # Supervision identity + rebuild recipe (ISSUE 12): the
             # EngineSupervisor rebuilds a dead engine from exactly this
             # config and keys its restart budget by this cache key.

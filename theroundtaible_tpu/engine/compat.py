@@ -1,8 +1,7 @@
 """The shard_map API seam.
 
-The engine is written against the manual-axes API of the installed JAX
-(0.9.0: `jax.shard_map` with `axis_names=`/`check_vma=`, `jax.lax.pcast`,
-meshes that carry `axis_types`, an int8 → int4 bitcast that expands
+The engine is written against the API of the installed JAX (0.9.0:
+`jax.shard_map` with `check_vma=`, an int8 → int4 bitcast that expands
 minor-most). Every engine module imports these names from here instead
 of from jax, so a version bump is a change to this module, not a
 nine-module sweep. It holds no branch for a JAX that is not installed.
@@ -15,10 +14,6 @@ import jax
 shard_map = jax.shard_map
 
 
-def pcast(x, axis_names, to: str = "varying"):
-    return jax.lax.pcast(x, axis_names, to=to)
-
-
 def unpack_int4_pairs(q4):
     """int8[..., n] → signed nibble pairs int4[..., n, 2], low nibble
     first (the engine/quant.py pack order): the one-op bitcast whose
@@ -27,11 +22,3 @@ def unpack_int4_pairs(q4):
     contract)."""
     import jax.numpy as jnp
     return jax.lax.bitcast_convert_type(q4, jnp.int4)
-
-
-def mesh_manual_axes(mesh) -> set:
-    """The axes a wrapper's shard_map must manualize: the mesh's AUTO
-    axes (inside the PP engine's manual-"pipe" stage bodies, "model" is
-    the only one left)."""
-    return {a for a, t in zip(mesh.axis_names, mesh.axis_types)
-            if t == jax.sharding.AxisType.Auto}

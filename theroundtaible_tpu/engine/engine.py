@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import deadlines, faults
+from . import ENGINE_CONFIG_KEYS, deadlines, faults
 from .kvcache import KVCache
 from .models.common import ModelConfig, forward, param_count, spmd_mesh
 from .models.registry import resolve_model_config
@@ -50,8 +50,7 @@ def summarize_int4_paths(dispatches: dict) -> dict:
     """Fold the trace-time int4 dispatch log (models/common._record_int4
     entries) into the path-provenance report describe()/stats expose:
     {"pallas_w4a16": [entry...], "xla_dequant": [entry...]} with each
-    entry carrying spec/shapes (and `fallback_reason` on the XLA side).
-    Shared with the PP engine."""
+    entry carrying spec/shapes (and `fallback_reason` on the XLA side)."""
     kernel, fallback = [], []
     for e in dispatches.values():
         (kernel if e["path"] == "pallas_w4a16" else fallback).append(e)
@@ -1380,13 +1379,18 @@ class InferenceEngine:
 
     @classmethod
     def from_config(cls, config: dict[str, Any]) -> "InferenceEngine":
-        model_cfg = resolve_model_config(config)
-        if config.get("max_seq_len"):
+        # The build sees the config through ENGINE_CONFIG_KEYS only —
+        # the tuple the engine cache key is made of — so a key read
+        # below and missing there never arrives, instead of arriving
+        # and letting two different engines share one cache entry.
+        cfg = {k: config[k] for k in ENGINE_CONFIG_KEYS if k in config}
+        model_cfg = resolve_model_config(cfg)
+        if cfg.get("max_seq_len"):
             model_cfg = dataclasses.replace(
-                model_cfg, max_seq_len=int(config["max_seq_len"]))
+                model_cfg, max_seq_len=int(cfg["max_seq_len"]))
         dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
-                 "float16": jnp.float16}[config.get("dtype", "bfloat16")]
-        sampling_cfg = config.get("sampling", {})
+                 "float16": jnp.float16}[cfg.get("dtype", "bfloat16")]
+        sampling_cfg = cfg.get("sampling", {})
         sampling = SamplingParams(
             temperature=float(sampling_cfg.get("temperature", 0.7)),
             top_k=int(sampling_cfg.get("top_k", 0)),
@@ -1395,40 +1399,40 @@ class InferenceEngine:
         )
         engine = cls(
             model_cfg,
-            checkpoint=config.get("checkpoint", "") or "",
-            mesh_shape=config.get("mesh"),
-            num_slots=int(config.get("num_slots", 8)),
+            checkpoint=cfg.get("checkpoint", "") or "",
+            mesh_shape=cfg.get("mesh"),
+            num_slots=int(cfg.get("num_slots", 8)),
             dtype=dtype,
             sampling=sampling,
-            seed=int(config.get("seed", 0)),
-            seq_parallel=int(config.get("seq_parallel", 0)),
-            long_threshold=int(config.get("long_threshold", 2048)),
-            long_scheme=config.get("long_scheme", "ring"),
-            attn=config.get("attn", "auto"),
-            devices=config.get("devices"),
-            kv_layout=config.get("kv_layout", "contiguous"),
-            page_size=int(config.get("page_size", 128)),
-            num_pages=(int(config["num_pages"])
-                       if config.get("num_pages") else None),
-            quant=config.get("quant", "none"),
-            dcn_axis=config.get("dcn_axis"),
-            prefix_cache=config.get("prefix_cache"),
-            prefix_cache_pages=(int(config["prefix_cache_pages"])
-                                if config.get("prefix_cache_pages")
+            seed=int(cfg.get("seed", 0)),
+            seq_parallel=int(cfg.get("seq_parallel", 0)),
+            long_threshold=int(cfg.get("long_threshold", 2048)),
+            long_scheme=cfg.get("long_scheme", "ring"),
+            attn=cfg.get("attn", "auto"),
+            devices=cfg.get("devices"),
+            kv_layout=cfg.get("kv_layout", "contiguous"),
+            page_size=int(cfg.get("page_size", 128)),
+            num_pages=(int(cfg["num_pages"])
+                       if cfg.get("num_pages") else None),
+            quant=cfg.get("quant", "none"),
+            dcn_axis=cfg.get("dcn_axis"),
+            prefix_cache=cfg.get("prefix_cache"),
+            prefix_cache_pages=(int(cfg["prefix_cache_pages"])
+                                if cfg.get("prefix_cache_pages")
                                 else None),
-            kv_offload=config.get("kv_offload"),
-            ragged_attn=config.get("ragged_attn"),
-            spec_decode=config.get("spec_decode"),
+            kv_offload=cfg.get("kv_offload"),
+            ragged_attn=cfg.get("ragged_attn"),
+            spec_decode=cfg.get("spec_decode"),
             # `is not None`, not truthiness: spec_max_draft: 0 must
             # surface the constructor's ValueError, not silently run
             # with the default.
-            spec_max_draft=(int(config["spec_max_draft"])
-                            if config.get("spec_max_draft") is not None
+            spec_max_draft=(int(cfg["spec_max_draft"])
+                            if cfg.get("spec_max_draft") is not None
                             else None),
-            lora=config.get("lora"),
-            kv_quant=config.get("kv_quant"),
-            state_snapshot_bytes=(int(config["state_snapshot_bytes"])
-                                  if config.get("state_snapshot_bytes")
+            lora=cfg.get("lora"),
+            kv_quant=cfg.get("kv_quant"),
+            state_snapshot_bytes=(int(cfg["state_snapshot_bytes"])
+                                  if cfg.get("state_snapshot_bytes")
                                   is not None else None),
         )
         # Set by fleet.check_fleet_fits when it flips an unpinned config
@@ -2395,8 +2399,8 @@ class InferenceEngine:
         FLOPs for the shared span are paid once instead of N times; HBM
         still holds per-slot copies (true page-level dedup is the paged-KV
         allocator's job). The pass structure itself lives in
-        kvcache.share_prefixes (shared with the PP engine); this method
-        provides the device mechanics: paged caches ALIAS the donor's
+        kvcache.share_prefixes; this method provides the device
+        mechanics: paged caches ALIAS the donor's
         whole pages (refcount, zero copy; partial boundary pages are
         device-copied), contiguous caches queue K/V span copies, and the
         leader span prefills via _prefill so a fresh long shared span

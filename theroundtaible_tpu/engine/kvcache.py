@@ -55,9 +55,7 @@ class SlotState:
 
 class SlotBook:
     """Host-side slot bookkeeping alone — LRU allocation, LCP reuse
-    planning, donor search. KVCache adds the contiguous device arrays;
-    the pipeline engine (pp_serving.py) uses SlotBook directly with its
-    stage-stacked caches."""
+    planning, donor search. KVCache adds the contiguous device arrays."""
 
     def __init__(self, num_slots: int):
         self.num_slots = num_slots
@@ -163,7 +161,7 @@ class SlotBook:
             "slot_occupancy": round(in_use / max(self.num_slots, 1), 3),
             "cached_tokens": sum(len(s.tokens)
                                  for s in self._slots.values()),
-            "hbm_bytes": None,  # SlotBook owns no buffers (PP stages do)
+            "hbm_bytes": None,  # SlotBook owns no buffers
         }
 
     # --- prefix reuse ---
@@ -249,7 +247,7 @@ def share_prefixes(kv, names, all_tokens, offsets, *, min_shared: int,
       flush_shares() — dispatch queued shares (called after each pass so
         leader-sourced copies never read a pending span);
       prefill_span(row_i, lo, hi) — prefill that row's token span
-        (ring-eligible on the main engine, chunked on PP).
+        (ring-eligible when long).
 
     `extra_pinned`: slot names OUTSIDE this batch that must survive any
     eviction the passes trigger — the session scheduler pins every

@@ -77,25 +77,6 @@ def _current_int4_sink():
     return stack[-1][1] if stack else None
 
 
-class _ManualLocalMesh:
-    """Mesh sentinel for FULLY-MANUAL regions (the PP engine's stage
-    bodies on pipe-only meshes): every array there is device-local and
-    full-size, so single-device kernel dispatch is correct even though
-    the enclosing program spans many devices. `size` mirrors Mesh so
-    every existing `mesh.size` branch takes its single-device arm.
-    Distinct from an UNSET context — "no announcement" still must never
-    be mistaken for "single device" (a trace under GSPMD with no
-    context keeps the XLA path)."""
-
-    size = 1
-
-    def __repr__(self):
-        return "ManualLocalMesh()"
-
-
-LOCAL_MESH = _ManualLocalMesh()
-
-
 # Path-provenance labels for int4 einsum dispatches (ISSUE 3): the next
 # hardware window's numbers must be attributable to the kernel, not a
 # silent fallback, so every Int4Leaf dispatch records which path it
@@ -265,12 +246,9 @@ class Int4Leaf:
     re-measured) — the last-axis/bitcast layout exists to keep the
     unpack inside the matmul fusion.
 
-    `axis` is always q4.ndim-1 at pack time and is kept as metadata so
-    spec mirroring (quantized_specs) and PP stage-stacking round-trip
-    the treedef; packing minor-most makes it invariant under the PP
-    engine's leading stage-stack. axis/group are static pytree metadata
-    (register_dataclass), so tree_map / sharding / param-byte accounting
-    see only q4/s4 arrays.
+    `axis` is always q4.ndim-1 at pack time. axis/group are static
+    pytree metadata (register_dataclass), so tree_map / sharding /
+    param-byte accounting see only q4/s4 arrays.
     """
 
     q4: jax.Array
@@ -344,8 +322,7 @@ def _einsum_base(spec: str, a: jax.Array, b, tp=None) -> jax.Array:
         # re-measured). Gate: the kernel is emitted ONLY where the
         # enclosing program explicitly announced
         # its mesh (spmd_mesh — every engine jit does). A 1-device mesh
-        # (or a fully-manual region announcing LOCAL_MESH) dispatches
-        # the raw kernel; a multi-device mesh goes through
+        # dispatches the raw kernel; a multi-device mesh goes through
         # einsum_int4_spmd, which re-partitions the matmul and runs the
         # kernel per shard inside shard_map — a bare pallas_call under
         # GSPMD would be an opaque, unpartitionable custom call. Traces

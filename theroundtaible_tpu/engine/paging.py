@@ -111,7 +111,6 @@ class PagedKVCache:
                  sharding=None, page_size: int = 128,
                  num_pages: Optional[int] = None,
                  copy_pages_fn: Optional[Callable] = None,
-                 pool_factory: Optional[Callable] = None,
                  data_size: int = 1, kv_quant=None):
         self.cfg = cfg
         self.num_slots = num_slots
@@ -131,10 +130,6 @@ class PagedKVCache:
         # carries them with the page for free.
         self.kv_quant = kv_quant
         self._kv_dtype_bytes = jnp.dtype(dtype).itemsize
-        if kv_quant is not None and pool_factory is not None:
-            raise ValueError(
-                "kv_quant is not supported with a custom pool_factory "
-                "(the PP engine's stage-stacked pools decline upstream)")
         # Default pool: HALF the contiguous budget — the honest claim of
         # paging is serving the same slots in less HBM — plus one scratch
         # page per data replica (data_size == 1: page 0, as before).
@@ -158,13 +153,7 @@ class PagedKVCache:
                 f"num_pages {self.num_pages} over {self.data_size} "
                 f"replica(s) cannot hold even one full sequence per "
                 f"replica ({self.pages_per_seq} pages + scratch)")
-        if pool_factory is not None:
-            # Custom pool layout (the PP engine stacks every stage's
-            # layer range into ONE stage-sharded pool pair whose page
-            # axis this allocator still manages; copy_pages_fn must
-            # address pages in that layout).
-            self._make_pools = pool_factory
-        elif kv_quant is None:
+        if kv_quant is None:
             shape = (self.num_pages, page_size, cfg.num_kv_heads,
                      cfg.head_dim)
             make = (lambda: jnp.zeros(shape, dtype)) if sharding is None \

@@ -368,19 +368,18 @@ def paged_prefill_supported(t: int, page_size: int, d: int,
 
 def paged_pool_direct_supported(chunk: int, page_size: int, d: int,
                                 kh_local: int, group: int) -> bool:
-    """The ONE build-time gate for pool-direct paged serving, shared by
-    both engines (engine.py / pp_serving.py — the two copies drifted
-    once, gating only on decode support): pool-direct runs prefill
-    chunks AND decode steps off the pool, so BOTH kernels must accept
-    the shape. A layout only the decode kernel fits would otherwise
-    raise mid-request in the prefill wrapper instead of serving the
-    gather view (ISSUE 1: degrade, don't crash). `chunk` is the largest
-    serving bucket — the block_q search shrinks from there, so smaller
-    buckets only relax the estimate. Pass the LOCAL kv-head count.
+    """The ONE build-time gate for pool-direct paged serving:
+    pool-direct runs prefill chunks AND decode steps off the pool, so
+    BOTH kernels must accept the shape. A layout only the decode kernel
+    fits would otherwise raise mid-request in the prefill wrapper
+    instead of serving the gather view (ISSUE 1: degrade, don't crash).
+    `chunk` is the largest serving bucket — the block_q search shrinks
+    from there, so smaller buckets only relax the estimate. Pass the
+    LOCAL kv-head count.
 
     paged_prefill_supported's last clause IS the decode gate, so one
     delegation covers both kernels without duplicating the conjunction
-    here (the duplicate is how the engines drifted last time)."""
+    here."""
     return paged_prefill_supported(chunk, page_size, d, kh_local, group)
 
 
@@ -538,24 +537,11 @@ def paged_prefill_spmd(
         args += [k_scale, v_scale]
     fn = shard_map(body, mesh=mesh,
                    in_specs=in_specs,
-                   out_specs=q_spec, axis_names=_manual_axes(mesh),
-                   check_vma=False)
+                   out_specs=q_spec, check_vma=False)
     return fn(*args)
 
 
 # --- decode kernel ---
-
-
-def _manual_axes(mesh):
-    """The axes this wrapper's shard_map must manualize: the mesh's AUTO
-    axes. On the engines' concrete meshes every axis is Auto, so this is
-    the same set shard_map would manualize with no axis_names at all.
-    Inside a partial-manual region — the PP engine's manual-"pipe" stage
-    bodies calling these wrappers with the context AbstractMesh — the
-    already-Manual "pipe" axis must be excluded, leaving a NESTED
-    shard_map over "model" only."""
-    from ..compat import mesh_manual_axes
-    return mesh_manual_axes(mesh)
 
 
 def _spmd_axes(mesh, h: int, kh: int, b: int):
@@ -636,8 +622,7 @@ def flash_attention_spmd(
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(q_spec, kv_spec, kv_spec, row_spec, row_spec),
-                   out_specs=out_spec, axis_names=_manual_axes(mesh),
-                   check_vma=False)
+                   out_specs=out_spec, check_vma=False)
     return fn(q, k, v, offsets.astype(jnp.int32),
               kv_valid.astype(jnp.int32))
 
@@ -1151,8 +1136,7 @@ def paged_decode_spmd(
         args += [k_scale, v_scale]
     fn = shard_map(body, mesh=mesh,
                    in_specs=in_specs,
-                   out_specs=q_spec, axis_names=_manual_axes(mesh),
-                   check_vma=False)
+                   out_specs=q_spec, check_vma=False)
     return fn(*args)
 
 
@@ -1617,8 +1601,7 @@ def ragged_paged_spmd(
         args += [k_scale, v_scale]
     fn = shard_map(body, mesh=mesh,
                    in_specs=in_specs,
-                   out_specs=q_spec, axis_names=_manual_axes(mesh),
-                   check_vma=False)
+                   out_specs=q_spec, check_vma=False)
     return fn(*args)
 
 

@@ -460,7 +460,7 @@ def _dispatch_pack_contract(a, leaf, gp: int):
 
 def einsum_int4_spmd(mesh, spec: str, a: jax.Array, leaf, tp=None):
     """The fused kernels under a multi-device mesh: per-shard
-    single-device dispatch inside shard_map (compat shim), partitioned
+    single-device dispatch inside shard_map, partitioned
     the way sharding.param_specs already shards the weight.
 
     `tp` is the call site's TP convention hint ("col" / "row" — see
@@ -477,14 +477,10 @@ def einsum_int4_spmd(mesh, spec: str, a: jax.Array, leaf, tp=None):
       matching sharding._fallback_replicated, which replicated exactly
       those weights at placement time;
     - row-parallel shards contract locally and psum over "model",
-      exactly the all-reduce the XLA path's sharded einsum inserts;
-    - the manual axis set comes from compat.mesh_manual_axes, so the
-      same call nests correctly inside the PP engine's manual-"pipe"
-      stage bodies (model stays the only axis this wrapper manualizes
-      there)."""
+      exactly the all-reduce the XLA path's sharded einsum inserts."""
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import mesh_manual_axes, shard_map
+    from ..compat import shard_map
     from ..sharding import MODEL_AXIS, int4_shard_axis, model_axis_size
 
     cls, reason = _classify(spec, leaf)
@@ -493,9 +489,6 @@ def einsum_int4_spmd(mesh, spec: str, a: jax.Array, leaf, tp=None):
     mode, n_cont, gp = cls
     q4, s4 = leaf.q4, leaf.s4
     m_shards = model_axis_size(mesh)
-    manual = mesh_manual_axes(mesh)
-    if m_shards > 1 and MODEL_AXIS not in manual:
-        return None, "mesh:model-axis-not-auto"
 
     w_ax, needs_psum = int4_shard_axis(tp, q4.ndim, n_cont, mode)
     if m_shards <= 1:
@@ -560,5 +553,5 @@ def einsum_int4_spmd(mesh, spec: str, a: jax.Array, leaf, tp=None):
         return y
 
     fn = shard_map(body, mesh=mesh, in_specs=(a_spec, w_spec, s_spec),
-                   out_specs=out_spec, axis_names=manual, check_vma=False)
+                   out_specs=out_spec, check_vma=False)
     return fn(a, q4, s4), None

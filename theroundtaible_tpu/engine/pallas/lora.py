@@ -193,15 +193,12 @@ def lora_bgmv_spmd(mesh, x2: jax.Array, a_t: jax.Array, b_s: jax.Array,
     (delta, None) or (None, fallback_reason)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import mesh_manual_axes, shard_map
+    from ..compat import shard_map
     from ..sharding import MODEL_AXIS, lora_shard_axis, model_axis_size
 
     m, c_dim = x2.shape
     s, r, o_dim = b_s.shape
     m_shards = model_axis_size(mesh)
-    manual = mesh_manual_axes(mesh)
-    if m_shards > 1 and MODEL_AXIS not in manual:
-        return None, "mesh:model-axis-not-auto"
 
     which = lora_shard_axis(tp)
     if m_shards <= 1:
@@ -232,6 +229,5 @@ def lora_bgmv_spmd(mesh, x2: jax.Array, a_t: jax.Array, b_s: jax.Array,
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P(None), x_spec, a_spec, b_spec),
-                   out_specs=out_spec, axis_names=manual,
-                   check_vma=False)
+                   out_specs=out_spec, check_vma=False)
     return fn(ids.astype(jnp.int32), x2, a_t, b_s), None

@@ -19,11 +19,11 @@ Design (ISSUE 7 tentpole):
   pool reference per node (`PagedKVCache.ref`); slots that later release
   or truncate merely UNREF — the page's bytes survive in the pool for as
   long as anyone (index, slot, offload tier) still references them.
-- **attach() is the read path.** `InferenceEngine._prepare_batch` (and
-  the PP engine's prepare) call it per row after the slot's own
-  reuse_plan: the longest complete-block match extends the row's reuse
-  frontier by ALIASING the matched pages (refcount++, zero copy; pages
-  on another data replica, and the partial boundary page, device-copy —
+- **attach() is the read path.** `InferenceEngine._prepare_batch`
+  calls it per row after the slot's own reuse_plan: the longest
+  complete-block match extends the row's reuse frontier by ALIASING
+  the matched pages (refcount++, zero copy; pages on another data
+  replica, and the partial boundary page, device-copy —
   `PagedKVCache.adopt_span`). The attached span is READ-ONLY by
   construction: `ensure_capacity` copy-on-writes any shared page in the
   row's write range before the first divergent write, so two sessions
@@ -296,11 +296,9 @@ class PrefixCache:
     def attach_rows(self, names: list[str],
                     all_tokens: list[list[int]], offsets: list[int],
                     pinned: tuple[str, ...] = ()) -> int:
-        """The per-batch consult both serving engines run after their
-        own-slot reuse_plan pass — ONE definition (main engine
-        _prepare_batch + PP prepare) so the warmup-exclusion rule and
-        the reused accounting can never drift between them. Mutates
-        `offsets` in place; returns the tokens the index served."""
+        """The per-batch consult `_prepare_batch` runs after its
+        own-slot reuse_plan pass. Mutates `offsets` in place; returns
+        the tokens the index served."""
         gained = 0
         for i, name in enumerate(names):
             if name.startswith("__warmup_"):

@@ -35,11 +35,10 @@ inherits the weight's, s keeps the kept axes') and no separate spec tree
 is needed. Absmax over a sharded contracted axis costs one all-reduce at
 load time.
 
-Scope: every serving path — the main InferenceEngine (dense + flash
-attention, contiguous + paged KV, MoE), the pipeline engine (quantized
-leaves stack per stage), and the ring/Ulysses sequence-parallel prefill
-— all of which reach weights exclusively through the quant-aware
-_einsum/embed_tokens accessors.
+Scope: every serving path — InferenceEngine (dense + flash attention,
+contiguous + paged KV, MoE) and the ring/Ulysses sequence-parallel
+prefill — all of which reach weights exclusively through the
+quant-aware _einsum/embed_tokens accessors.
 """
 
 from __future__ import annotations
@@ -71,9 +70,7 @@ _SCALE_AXES: dict[str, tuple[int, ...]] = {
     # near-tied choice on random weights). Quantizing the decision-maker
     # itself invites those flips for E×X params of savings — bytes-
     # irrelevant — so it stays fp, which is standard MoE deployment
-    # practice. Keep quantized_specs' key-for-key mirror in mind:
-    # absence here makes BOTH the weights and the spec tree pass it
-    # through.
+    # practice.
     "embedding": (0,),     # [V, E] → s[V] (row scale: lookup AND lm head)
     "lm_head": (0,),
 }
@@ -258,16 +255,6 @@ def _quantize_layer(layer: dict[str, Any], act_dtype,
     return new
 
 
-def _spec_for_scale(spec, scale_axes: tuple[int, ...]):
-    """PartitionSpec for a scale leaf: `s` keeps exactly `scale_axes` of
-    the weight, so its spec keeps those axes' entries (a spec shorter
-    than the weight's rank means trailing dims are unsharded)."""
-    from jax.sharding import PartitionSpec as P
-    entries = tuple(spec) if spec is not None else ()
-    return P(*(entries[a] if a < len(entries) else None
-               for a in scale_axes))
-
-
 def quantize_lora_stack(stack: jax.Array, act_dtype) -> dict[str, Any]:
     """Symmetric int8 quantization of a STACKED LoRA tensor [S, r, X]
     (ISSUE 10 quantize-aware adapter store): per-(slot, rank-row)
@@ -295,62 +282,3 @@ def quantize_lora_slot(leaf: dict[str, Any], slot, value32,
     q = jnp.clip(jnp.round(value32 / s[..., None]), -127, 127)
     return {"q": set_slot(leaf["q"], slot, q),
             "s": set_slot(leaf["s"], slot, s)}
-
-
-def quantized_specs(specs: Params,
-                    params: Optional[Params] = None) -> Params:
-    """Transform a param PartitionSpec tree (sharding.param_specs) into
-    the spec tree matching quantize_params' OUTPUT structure: each
-    quantized weight spec becomes {"q": spec, "s": kept-axes spec} — or
-    an Int4Leaf of specs mirroring the actual leaf's static axis/group
-    metadata (pytree treedefs include that metadata, so explicit
-    placement via tree_map needs it to MATCH; pass the quantized
-    `params` tree whenever it may contain int4 leaves). Needed because
-    the PP engine stacks leaves itself and cannot rely on jit sharding
-    propagation.
-
-    Mirrors quantize_params/_quantize_layer key-for-key; keep the two in
-    sync when a new weight becomes quantizable."""
-    out: Params = {}
-    for key, value in specs.items():
-        pv = params.get(key) if params is not None else None
-        if key in ("embedding", "lm_head"):
-            out[key] = _qspec_leaf(value, _SCALE_AXES[key], pv)
-        elif key == "layers":
-            out[key] = [
-                _quantized_layer_specs(
-                    layer, pv[i] if pv is not None else None)
-                for i, layer in enumerate(value)]
-        else:
-            out[key] = value
-    return out
-
-
-def _qspec_leaf(spec, scale_axes: tuple[int, ...], param_leaf):
-    from .models.common import Int4Leaf
-    if isinstance(param_leaf, Int4Leaf):
-        # q4 shares the weight's spec (last axis halved — placement's
-        # _fallback_replicated checks divisibility against the actual
-        # shape); s4 has the same rank with the last axis → n_groups,
-        # so the same entries apply.
-        return Int4Leaf(q4=spec, s4=spec, axis=param_leaf.axis,
-                        group=param_leaf.group)
-    return {"q": spec, "s": _spec_for_scale(spec, scale_axes)}
-
-
-def _quantized_layer_specs(layer: dict[str, Any],
-                           param_layer: Optional[dict[str, Any]] = None
-                           ) -> dict[str, Any]:
-    new: dict[str, Any] = {}
-    for key, value in layer.items():
-        pv = param_layer.get(key) if param_layer is not None else None
-        if key == "experts":
-            new[key] = {
-                k: _qspec_leaf(v, _EXPERT_SCALE_AXES[k],
-                               pv.get(k) if pv is not None else None)
-                for k, v in value.items()}
-        elif key in _SCALE_AXES and "norm" not in key:
-            new[key] = _qspec_leaf(value, _SCALE_AXES[key], pv)
-        else:
-            new[key] = value
-    return new

@@ -56,10 +56,6 @@ Design, shaped by JAX's static-shape constraints (ISSUE 4 tentpole):
   refuse / preempt, queue depth, per-segment batch occupancy) is
   recorded into GenStats.sched and engine.describe()["scheduler"] the
   same way the int4 paths are.
-
-The scheduler serves InferenceEngine only: PPEngine's stage-pipelined
-programs have no single decode-segment seam to recompose at (its rounds
-still batch and its slot names still namespace — see pp_serving).
 """
 
 from __future__ import annotations
@@ -303,16 +299,6 @@ class SessionScheduler:
                  max_rows: Optional[int] = None,
                  idle_spill_s: Optional[float] = None,
                  journal=None):
-        # The continuous-batching loop recomposes rows at the decode
-        # SEGMENT seam — it needs the single-program engine's compiled
-        # closures. PPEngine has no such seam (stage-pipelined decode).
-        for attr in ("_prefill", "_decode_loop", "_share_prefixes"):
-            if not hasattr(engine, attr):
-                raise TypeError(
-                    "SessionScheduler requires the single-program "
-                    "InferenceEngine (missing %r); pipe-mesh engines "
-                    "serve round-level batches — use session-namespaced "
-                    "generate_batch calls instead" % attr)
         self.engine = engine
         self.admit_hold_s = admit_hold_s
         self.max_rows = min(max_rows or engine.kv.num_slots,

@@ -1,12 +1,10 @@
-"""Host-side serving loop pieces shared by the engines.
+"""The host-side serving loop of InferenceEngine (engine.py) and the
+scheduler.
 
-InferenceEngine (engine.py) and PPEngine (pp_serving.py) dispatch very
-different device programs, but the HOST logic around them — chunked
-bucketed prefill with the cache-end bucket-shrink guard, the decode
-segment loop with deadline checks, and the eos-trim/commit epilogue — is
-identical and subtle enough that two copies WILL drift (round-2 review
-finding). Each engine passes its dispatch closure; everything else lives
-here once.
+The device programs are the engine's; the HOST logic around them —
+chunked bucketed prefill with the cache-end bucket-shrink guard, the
+decode segment loop with deadline checks, and the eos-trim/commit
+epilogue — lives here once. The caller passes its dispatch closure.
 """
 
 from __future__ import annotations
@@ -121,10 +119,10 @@ def run_dispatch(dispatch: Callable, retry, deadline: float = float("inf"),
     def attempt_traced():
         # "dispatch" is the span tree's leaf rung (ISSUE 5), mirroring
         # the budget rung the watchdog times this wait against. The
-        # compile-attribution window (ISSUE 6) is a FALLBACK: engines
-        # that already opened a precise (batch, bucket) label keep it;
-        # callers that didn't (PP stage dispatches) still get a
-        # rung-level label instead of "unlabeled".
+        # compile-attribution window (ISSUE 6) is a FALLBACK: a caller
+        # that already opened a precise (batch, bucket) label keeps it;
+        # one that didn't still gets a rung-level label instead of
+        # "unlabeled".
         from . import compile_watch
         with compile_watch.label(f"dispatch[{rung}]", fallback=True):
             if telemetry.ACTIVE:

@@ -47,6 +47,12 @@ def build_mesh(mesh_shape: Optional[dict[str, int]] = None,
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
     shape = dict(mesh_shape or {})
+    unknown = sorted(set(shape) - {DATA_AXIS, MODEL_AXIS})
+    if unknown:
+        raise ValueError(
+            f"mesh axis {', '.join(map(repr, unknown))} is not one this "
+            f"engine has (a mesh is {DATA_AXIS!r} x {MODEL_AXIS!r}): shard "
+            f'a model over N chips with mesh {{"{MODEL_AXIS}": N}}')
     data = shape.get(DATA_AXIS, 1)
     model = shape.get(MODEL_AXIS, -1)
     if model == -1:

@@ -297,14 +297,9 @@ class EnginePerf:
             note_published(2)
 
     @classmethod
-    def from_engine(cls, engine, params: Any = None,
-                    kv_itemsize: Optional[int] = None) -> "EnginePerf":
+    def from_engine(cls, engine) -> "EnginePerf":
         """Build from a live engine: streamed bytes from its ACTUAL
-        (quantized) tree, chip from its mesh's device 0. ONE
-        definition for both engine families — `params` overrides for
-        engines whose tree isn't at `.params` (PPEngine's stage-stacked
-        shared/staged pair), `kv_itemsize` for caches that don't hang
-        pools/layers off `.kv`."""
+        (quantized) tree, chip from its mesh's device 0."""
         kind = ""
         try:
             kind = getattr(engine.mesh.devices.flatten()[0],
@@ -315,26 +310,24 @@ class EnginePerf:
         source = ("env" if os.environ.get(CHIP_ENV)
                   else "detected" if chip else "none")
         quant_spec = getattr(engine, "kv_quant_spec", None)
-        if kv_itemsize is None:
-            kv_itemsize = 2
-            kv = getattr(engine, "kv", None)
-            pools = getattr(kv, "pools", None)
-            layers = getattr(kv, "layers", None)
-            if pools and quant_spec is None:
-                kv_itemsize = pools[0][0].dtype.itemsize
-            elif pools:
-                # Quantized pools store int8 payload — itemsize 1 would
-                # miss the scales; the spec's closed cell form below
-                # charges both, against the engine's LOGICAL kv dtype
-                # (the allocator records it — quantize-off round-trips
-                # to exactly that width).
-                kv_itemsize = getattr(kv, "_kv_dtype_bytes", 2)
-            elif layers:
-                kv_itemsize = layers[0][0].dtype.itemsize
+        kv_itemsize = 2
+        kv = getattr(engine, "kv", None)
+        pools = getattr(kv, "pools", None)
+        layers = getattr(kv, "layers", None)
+        if pools and quant_spec is None:
+            kv_itemsize = pools[0][0].dtype.itemsize
+        elif pools:
+            # Quantized pools store int8 payload — itemsize 1 would
+            # miss the scales; the spec's closed cell form below
+            # charges both, against the engine's LOGICAL kv dtype
+            # (the allocator records it — quantize-off round-trips
+            # to exactly that width).
+            kv_itemsize = getattr(kv, "_kv_dtype_bytes", 2)
+        elif layers:
+            kv_itemsize = layers[0][0].dtype.itemsize
         return cls(
             engine.cfg.name,
-            param_bytes=streamed_param_bytes(
-                params if params is not None else engine.params),
+            param_bytes=streamed_param_bytes(engine.params),
             num_params=engine.num_params,
             n_devices=int(engine.mesh.devices.size),
             chip=chip, chip_source=source,
