@@ -94,9 +94,18 @@ PROMPTS = {
 }
 
 
-def _join_mid_decode(sched, sessions, max_new=70, **submit_kw):
+def _join_mid_decode(sched, sessions, max_new=70, first_max_new=None,
+                     **submit_kw):
     """Later sessions submit only once the first has LIVE rows — a
-    deterministic mid-decode join (the test_ragged_attn pattern)."""
+    deterministic mid-decode join (the test_ragged_attn pattern).
+
+    `first_max_new`: the first session's own budget. With speculation
+    off a lone row's plain segments are pipelined — 64 steps, and the
+    next issued before the first is read — so a 70-token row may have
+    its whole answer in flight before a joiner's 5 ms poll has seen it
+    live, and whether the two then ever share a segment is the
+    machine's speed. A test whose claim needs them to share one gives
+    the first row several segments to outlive the join."""
     results, errors = {}, {}
 
     def run(sid, wait_active):
@@ -105,9 +114,11 @@ def _join_mid_decode(sched, sessions, max_new=70, **submit_kw):
                 deadline = time.monotonic() + 60
                 while not sched._active and time.monotonic() < deadline:
                     time.sleep(0.005)
-            results[sid] = sched.submit(sid, PROMPTS[sid],
-                                        max_new_tokens=max_new,
-                                        **submit_kw)
+            results[sid] = sched.submit(
+                sid, PROMPTS[sid],
+                max_new_tokens=(max_new if wait_active
+                                else first_max_new or max_new),
+                **submit_kw)
         except Exception as e:  # noqa: BLE001 — asserted by callers
             errors[sid] = e
 
@@ -357,7 +368,10 @@ class TestScheduledSpec:
         before = dict(nospec_engine._ragged_dispatches)
         sched = SessionScheduler(nospec_engine)
         try:
-            results, err = _join_mid_decode(sched, ["s0", "s2"])
+            # Two single-row sessions: the scheduler marker's guard
+            # needs them in one segment, so s0 outlives s2's join.
+            results, err = _join_mid_decode(
+                sched, ["s0", "s2"], first_max_new=70 + 4 * 64)
             assert not err, err
             assert sched.describe()["spec_segments"] == 0
         finally:

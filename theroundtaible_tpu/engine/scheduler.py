@@ -368,6 +368,11 @@ class SessionScheduler:
         self._snaps_seen = 0        # hybrid: snapshots at the last span's end
         self._hy_counting = False   # ... and whether that span was armed
         self.ragged_joins = 0
+        # N-gram prompt indices by where they were built (ISSUE 30):
+        # under a segment in flight, the device busy, or at the row's
+        # first draft, the device waiting for it.
+        self.indexed_in_flight = 0
+        self.indexed_at_draft = 0
         self.segment_prefill_tokens = 0
         self.segment_decode_tokens = 0
         # Speculative verify dispatches issued (ISSUE 9) — bumped in
@@ -679,6 +684,8 @@ class SessionScheduler:
             "segments": self.segments,
             "ragged_segments": self.ragged_segments,
             "ragged_joins": self.ragged_joins,
+            "indexed_in_flight": self.indexed_in_flight,
+            "indexed_at_draft": self.indexed_at_draft,
             "spec_segments": self.spec_segments,
             "segment_prefill_tokens": self.segment_prefill_tokens,
             "segment_decode_tokens": self.segment_decode_tokens,
@@ -1095,6 +1102,36 @@ class SessionScheduler:
                     return
                 self._after_engine_failure(e)
 
+    def _owes_index(self) -> list[_Row]:
+        """Rows admitted without their n-gram drafter's prompt index."""
+        return [r for r in self._active
+                if r.spec is not None and r.spec.drafter is None
+                and r.spec.kind == "ngram"]
+
+    def _index_in_flight(self, handle) -> None:
+        """Between a segment's dispatch and its blocking read: index
+        the prompts of rows that still owe it, oldest first, a row at a
+        time for as long as the segment in flight runs (`handle`, a
+        device array it yields: `is_ready()`). Once it has ended every
+        further millisecond here is one the device stands idle, so the
+        rest waits for the next segment (or for the row's first draft,
+        which builds what is still missing). Loop phase `admit` — the
+        work is admission's, deferred — entered from and left to the
+        runner's own. Loop-thread only (single-writer counter bumps
+        need no cv)."""
+        owing = self._owes_index()
+        ended = getattr(handle, "is_ready", None)
+        if not owing or ended is None:
+            return
+        from .spec_decode import NGramDrafter
+        back = self._clock.switch("admit")
+        for r in owing:
+            if ended():
+                break
+            r.spec.drafter = NGramDrafter(r.tokens)   # copies them
+            self._bump("indexed_in_flight")
+        self._clock.mark(back)
+
     def _release_request_slots(self, req: _Request) -> None:
         """Undo a partial admission: release every slot this request's
         turns may have acquired (scheduler thread only — KV host state
@@ -1411,18 +1448,18 @@ class SessionScheduler:
             # OWN prompt — which carries the whole transcript and any
             # prefix-cache-attached context — extended incrementally as
             # output tokens commit (RowSpec.drafter.sync before every
-            # draft). Host dict work only, O(prompt) once per admission.
+            # draft). Indexing a prompt is host dict work, O(prompt) a
+            # row and most of an admission's host time at transcript
+            # lengths; nothing needs it before the row's first draft,
+            # so the row is admitted WITHOUT its index (ISSUE 30): it
+            # is built under a segment in flight (_index_in_flight) or,
+            # if none came first, at that draft (_spec_drafts). Device
+            # drafters (model/lora) keep their state in the shadow
+            # draft slots and have no index at all.
             from .spec_decode import RowSpec
-            # Device drafters (model/lora) keep their state in the
-            # shadow draft slots — skip the per-row O(prompt) n-gram
-            # index entirely (prompts carry whole transcripts); a
-            # later hot-swap to ngram rebuilds it lazily in
-            # _spec_drafts.
             kind = getattr(engine, "spec_drafter", None) or "ngram"
             for r in rows:
-                r.spec = RowSpec(
-                    list(r.tokens) if kind == "ngram" else None,
-                    kind=kind)
+                r.spec = RowSpec(kind=kind)
         if deferred:
             # Deferred leader-span plans (the last prologue dispatch,
             # gone): laggard rows BLOCK until the leader's chunks write
@@ -1516,6 +1553,9 @@ class SessionScheduler:
             self._handle_segment_failure(live, e)
             return
         while True:
+            # A segment is in flight and unread: host work that needs
+            # no idle device runs under it (ISSUE 30).
+            self._index_in_flight(handles[1])
             clock.mark("build")
             spec_ctx = spec_handles = spec_err = None
             if self._may_speculate(ctx):
@@ -1707,8 +1747,11 @@ class SessionScheduler:
         # unblocks its laggards BEFORE packing, so their chunks join
         # this very segment.
         self._apply_share_plans()
+        # (A request that alias failed has left `_row_req`, its
+        # leader — filling still, or live by now — with it.)
+        live = [r for r in live if id(r) in self._row_req]
         filling = [r for r in filling if not r.done and r.pending
-                   and not r.blocked]
+                   and not r.blocked and id(r) in self._row_req]
         if not filling:
             if live:
                 self._run_segment(live)
@@ -1780,6 +1823,7 @@ class SessionScheduler:
             handles = run_dispatch(
                 lambda: engine._ragged_dispatch(batch),
                 engine.retry, deadline, budget=seg_budget)
+            self._index_in_flight(handles)
             nxt = host_sync(lambda: np.asarray(handles), seg_budget,
                             "decode")
         except Exception as e:  # noqa: BLE001 — preempt-isolate ladder
@@ -1928,7 +1972,8 @@ class SessionScheduler:
         or None when NO row drafts — the tick then serves the plain
         pipelined segments, which is exactly the 1-token-decode
         fallback the adaptive throttle promises (a non-accepting batch
-        must never pay more dispatches than plain decode)."""
+        must never pay more dispatches than plain decode). Loop-thread
+        only (single-writer counter bumps need no cv)."""
         engine = self.engine
         if (not getattr(engine, "spec_decode", False)
                 or not engine.ragged_enabled):
@@ -1995,13 +2040,12 @@ class SessionScheduler:
             cap = cap_of(r)
             if cap >= 1:
                 if r.spec.drafter is None:
-                    # Hot-swapped from a device drafter to ngram
-                    # mid-flight: build this row's index lazily (the
-                    # admission-time build is skipped under device
-                    # drafters — whole-transcript prompts make it real
-                    # host CPU/memory).
+                    # No segment in flight got to this row's index
+                    # (_index_in_flight), or the engine was hot-swapped
+                    # from a device drafter to ngram: build it now.
                     from .spec_decode import NGramDrafter
                     r.spec.drafter = NGramDrafter(list(r.tokens))
+                    self._bump("indexed_at_draft")
                     r.spec.kind = "ngram"
                 r.spec.drafter.sync_parts(r.tokens, r.produced)
                 if branch > 1:
@@ -2185,6 +2229,7 @@ class SessionScheduler:
             handles = run_dispatch(
                 lambda: engine._ragged_dispatch(batch),
                 engine.retry, deadline, budget=seg_budget)
+            self._index_in_flight(handles)
             nxt = host_sync(lambda: np.asarray(handles), seg_budget,
                             "decode")
         except Exception as e:  # noqa: BLE001 — preempt-isolate ladder

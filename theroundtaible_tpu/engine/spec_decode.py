@@ -367,9 +367,12 @@ class RowSpec:
                  kind: str = "ngram"):
         # Device-batched drafters (model/lora) keep their state in the
         # draft slots the DeviceDrafter coordinator owns; only the
-        # ngram drafter lives here per row.
+        # ngram drafter lives here per row — and only once its prompt
+        # is given: the scheduler admits with none and indexes the
+        # prompt where that costs the device nothing.
         self.drafter = (NGramDrafter(prompt_tokens)
-                        if kind == "ngram" else None)
+                        if kind == "ngram" and prompt_tokens is not None
+                        else None)
         self.kind = kind
         self.drafted = 0
         self.accepted = 0

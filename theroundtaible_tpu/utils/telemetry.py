@@ -987,6 +987,8 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "segments": "roundtable_sched_segments_total",
         "ragged_segments": "roundtable_sched_ragged_segments_total",
         "ragged_joins": "roundtable_sched_ragged_joins_total",
+        "indexed_in_flight": "roundtable_sched_indexed_in_flight_total",
+        "indexed_at_draft": "roundtable_sched_indexed_at_draft_total",
         "spec_segments": "roundtable_sched_spec_segments_total",
         "segment_prefill_tokens":
             "roundtable_segment_prefill_tokens_total",
