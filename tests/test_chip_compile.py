@@ -381,6 +381,155 @@ def test_hybrid_step_of_the_nemotron_cut_compiles(one_chip, monkeypatch,
     _assert_kernel(hlo)
 
 
+# --- latent pages (ISSUE 31) ------------------------------------------------
+
+LATENT_W, LATENT_V, LATENT_HEADS = 640, 512, 64     # A.X-K1: 576 -> 640
+
+
+def _latent_case(kernel: str, one_chip):
+    """(fn, shapes) for one latent kernel at the published shape: page
+    128, the 576-value entry in five lane rows, one kv head, group 64,
+    values the first 512 columns of the keys — ONE pool operand."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    pool = s((640, PAGE, LATENT_W), jnp.bfloat16)   # the cell's pool
+
+    def on_pool(call, q, *meta):
+        def fn(q, pool, *meta):
+            return call(q, pool, None, *meta, v_dim=LATENT_V,
+                        interpret=False)
+        return fn, (q, pool) + meta
+
+    if kernel == "paged_decode":
+        return on_pool(
+            pattn.paged_decode_attention,
+            s((DECODE_ROWS, 1, LATENT_HEADS, LATENT_W), jnp.bfloat16),
+            s((DECODE_ROWS, PAGES_PER_SEQ), i32), s((DECODE_ROWS,), i32))
+    if kernel == "ragged":
+        blocks = 1024 // pattn.RAGGED_BLOCK_Q       # the leaders' shape
+        return on_pool(
+            pattn.ragged_paged_attention,
+            s((1024, LATENT_HEADS, LATENT_W), jnp.bfloat16),
+            s((DECODE_ROWS + 1, PAGES_PER_SEQ), i32), s((blocks,), i32),
+            s((blocks,), i32), s((DECODE_ROWS + 1,), i32),
+            s((DECODE_ROWS + 1,), i32))
+    assert kernel == "paged_prefill"
+    return on_pool(
+        pattn.paged_prefill_attention,
+        s((1, 1024, LATENT_HEADS, LATENT_W), jnp.bfloat16),
+        s((1, PAGES_PER_SEQ), i32), s((1,), i32), s((1,), i32))
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "ragged",
+                                    "paged_prefill"])
+def test_latent_kernel_compiles_for_v5e(one_chip, monkeypatch, kernel):
+    """The gates say yes for the padded shape, no for the bare 576
+    (`head_dim:576`), and the compiler agrees with the yes; the latent
+    pool is the kernel's only pool operand."""
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    assert pattn.paged_decode_decline_reason(
+        PAGE, LATENT_W, 1, LATENT_HEADS, latent=True, dk=LATENT_W) is None
+    assert pattn.paged_decode_decline_reason(
+        PAGE, 576, 1, LATENT_HEADS, latent=True, dk=576) == "head_dim:576"
+    assert pattn.ragged_decline_reason(PAGE, LATENT_W, 1,
+                                       LATENT_HEADS) is None
+    assert pattn.ragged_decline_reason(PAGE, 576, 1, LATENT_HEADS) \
+        == "head_dim:576"
+    assert pattn.paged_pool_direct_supported(1024, PAGE, LATENT_W, 1,
+                                             LATENT_HEADS)
+    fn, shapes = _latent_case(kernel, one_chip)
+    hlo = _compile(fn, *shapes)
+    _assert_kernel(hlo)
+    call = next(line for line in hlo.splitlines()
+                if "tpu_custom_call" in line)
+    assert call.count("bf16[640,128,640]") == 1
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
+def test_hybrid_step_of_the_axk1_cut_compiles(one_chip, monkeypatch,
+                                              program):
+    """One decode step, one ragged join and one prologue chunk of the
+    benchmark's A.X-K1 cut (the dense block and one expert block at
+    published widths, 12 of 192 experts held, an eighth of the
+    vocabulary), as the hybrid step programs wrap them with an EMPTY
+    state tree: what the chip's compiler refuses of the absorbed
+    projections, the gated expert loop or the latent kernels' operands
+    fails here, not there."""
+    from theroundtaible_tpu.engine.models import hybrid
+    from theroundtaible_tpu.engine.models.common import init_params
+    from theroundtaible_tpu.engine.models.registry import (axk1_kinds,
+                                                           get_model_config)
+    from theroundtaible_tpu.engine.paged_forward import (
+        forward_paged_hybrid, forward_ragged_hybrid)
+    from theroundtaible_tpu.engine.serving_loop import (RaggedSeq,
+                                                        build_ragged_batch)
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        get_model_config("a.x-k1"), num_layers=4,
+        layer_kinds=axk1_kinds(2, 1), vocab_size=20_480, experts_held=12,
+        attn_impl="flash")
+    assert cfg.page_width == LATENT_W and not cfg.recurrent
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    params = placed(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    pools = [(s((640, PAGE, LATENT_W), jnp.bfloat16),)] * 2
+    state = hybrid.zero_state(cfg, ROWS)
+    assert state == {"ssm": [], "conv": []}
+    if program == "decode":
+        def step(params, pools, tokens, positions, table, valid, active):
+            return forward_paged_hybrid(
+                params, cfg, tokens, positions, pools, table, valid,
+                state, active=active)
+
+        hlo = _compile(step, params, pools, s((DECODE_ROWS, 1), i32),
+                       s((DECODE_ROWS, 1), i32),
+                       s((DECODE_ROWS, PAGES_PER_SEQ), i32),
+                       s((DECODE_ROWS,), i32),
+                       s((DECODE_ROWS,), jnp.bool_))
+    elif program == "prefill":
+        def step(params, pools, tokens, positions, table, valid, lengths):
+            return forward_paged_hybrid(
+                params, cfg, tokens, positions, pools, table, valid,
+                state, lengths=lengths, last_pos=lengths - 1)
+
+        hlo = _compile(step, params, pools, s((1, 1024), i32),
+                       s((1, 1024), i32), s((1, PAGES_PER_SEQ), i32),
+                       s((1,), i32), s((1,), i32))
+    else:
+        table = np.zeros((PAGES_PER_SEQ,), np.int32)
+        b = build_ragged_batch(
+            [RaggedSeq([5] * 150, 100, table), RaggedSeq([7], 300, table)],
+            t_budget=RAGGED_T, s_max=ROWS + 1,
+            pages_per_seq=PAGES_PER_SEQ, scratch_page=0, pad_id=0,
+            page_size=PAGE)
+        names = ("tokens", "positions", "tables", "seq_of_block",
+                 "block_qstart", "query_offsets", "kv_valid",
+                 "token_pages", "token_offs", "token_seq", "last_rows")
+
+        def step(params, pools, seq_slot, cap_n, *arrays):
+            kw = dict(zip(names, arrays))
+            return forward_ragged_hybrid(
+                params, cfg, kw["tokens"], kw["positions"], pools,
+                kw["tables"], kw["seq_of_block"], kw["block_qstart"],
+                kw["query_offsets"], kw["kv_valid"], kw["token_pages"],
+                kw["token_offs"], kw["token_seq"], kw["last_rows"], state,
+                seq_slot, cap_n)
+
+        hlo = _compile(step, params, pools, s((ROWS + 1,), i32),
+                       s((ROWS + 1,), i32),
+                       *[s(np.asarray(b[n]).shape, i32) for n in names])
+    assert hlo.count("tpu_custom_call") >= 2        # a kernel a block
+
+
 # --- the int4 kernels the compiler refuses --------------------------------
 #
 # Shapes below come from a real Int4Leaf (quant.quantize_params on a

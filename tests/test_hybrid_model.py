@@ -89,7 +89,7 @@ def test_whole_forward_matches_the_reference(tiny, length):
     assert np.abs(want[1] - got[40]).max() < F32_BOUND
 
 
-@pytest.mark.parametrize("kind", hybrid.LAYER_KINDS)
+@pytest.mark.parametrize("kind", list(hybrid.PATTERN_LETTERS.values()))
 def test_each_layer_kind_matches_the_reference(tiny, kind):
     cfg, params = tiny
     li = cfg.layer_kinds.index(kind)

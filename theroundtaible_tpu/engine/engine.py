@@ -141,13 +141,22 @@ class InferenceEngine:
         self.mesh = build_mesh(mesh_shape, device_list, dcn_axis=dcn_axis)
         model_cfg = self._resolve_attn(model_cfg, attn, self.mesh)
         self.cfg = model_cfg
-        # A model with layer_kinds (models/hybrid.py) keeps state that
-        # is not pages. What cannot carry that state yet declines HERE,
-        # each with a reason describe() reports — never as a failure at
-        # trace time. What the model cannot be served without fails now.
+        # A model with layer_kinds (models/hybrid.py) serves through the
+        # step programs that carry a second tree beside the pools and
+        # return what the expert layers counted. What cannot carry
+        # recurrent state, latent pages or the held experts' leaves yet
+        # declines HERE, each with a reason describe() reports — never
+        # as a failure at trace time. What the model cannot be served
+        # without fails now.
         self.declines: dict[str, str] = {}
+        self._latent_positions = 0
         if model_cfg.layer_kinds is not None:
-            why = "recurrent-state"
+            # Without recurrent state everything that addresses pages by
+            # id stays on (the leader pass, the prefix cache, offload);
+            # what declines is what reads inside a page or a weight leaf.
+            why = ("recurrent-state" if model_cfg.recurrent
+                   else "latent-pages" if model_cfg.latent
+                   else "layer-kinds")
             if kv_layout != "paged":
                 raise ValueError(
                     f"{model_cfg.name} has layer_kinds: it serves through "
@@ -164,18 +173,26 @@ class InferenceEngine:
                 self.declines["quant"] = f"{why}:quant-leaves"
                 quant = "none"
             if kv_quant and kv_quant != "none":
-                self.declines["kv_quant"] = why
+                self.declines["kv_quant"] = (
+                    f"{why}:cells-are-per-head" if model_cfg.latent
+                    else why)
                 kv_quant = None
             if seq_parallel and seq_parallel > 1:
                 self.declines["seq_parallel"] = why
                 seq_parallel = 0
             if lora:
-                self.declines["lora"] = why
+                self.declines["lora"] = (
+                    why if model_cfg.recurrent else f"{why}:no-lora-targets")
                 lora = None
-            if kv_offload:
-                self.declines["kv_offload"] = why
-            kv_offload = False
-            self.declines["spec_decode"] = why
+            if model_cfg.recurrent:
+                if kv_offload:
+                    self.declines["kv_offload"] = why
+                kv_offload = False
+            # A rejected draft cannot be un-consumed from a recurrent
+            # state; without one, the programs of a model with
+            # layer_kinds still have no verify shape (score_width).
+            self.declines["spec_decode"] = (
+                why if model_cfg.recurrent else f"{why}:no-verify-program")
             spec_decode = False
         self.max_seq_len = model_cfg.max_seq_len
         self.sampling = sampling or SamplingParams()
@@ -293,11 +310,8 @@ class InferenceEngine:
                 # Callers pad the id lists to a fixed width so this
                 # compiles exactly one shape (pad rows copy the scratch
                 # page onto itself — identical bytes, any scatter order).
-                out = []
-                for k, v in pools:
-                    out.append((k.at[dst_ids].set(k[src_ids]),
-                                v.at[dst_ids].set(v[src_ids])))
-                return out
+                return [tuple(p.at[dst_ids].set(p[src_ids]) for p in layer)
+                        for layer in pools]
 
             from .paging import make_padded_copier
             copy_pages_padded = make_padded_copier(copy_pages)
@@ -599,14 +613,14 @@ class InferenceEngine:
             # replica (ReplicaGroupPlan) so each shard_map block reads
             # only its local pages; the kernels rebase tables to the
             # local range via axis_index. No gather view on any mesh.
-            kh_l = model_cfg.num_kv_heads
+            kh_l = model_cfg.page_heads
             if self.mesh.devices.size > 1 and kh_l % max(n_model, 1) == 0:
                 kh_l //= max(n_model, 1)   # kernel sees the local shard
-            group = model_cfg.num_heads // model_cfg.num_kv_heads
+            group = model_cfg.num_heads // model_cfg.page_heads
             self.paged_direct = (
                 attn != "dense"
                 and paged_pool_direct_supported(
-                    MAX_PREFILL_CHUNK, page_size, model_cfg.head_dim,
+                    MAX_PREFILL_CHUNK, page_size, model_cfg.page_width,
                     kh_l, group)
                 and (self.mesh.devices.size == 1
                      or spmd_partitionable(model_cfg.num_heads,
@@ -878,10 +892,10 @@ class InferenceEngine:
             from .pallas import attention as _pattn
             from .serving_loop import ragged_token_budget
             n_model = dict(self.mesh.shape).get("model", 1)
-            kh_l = model_cfg.num_kv_heads
+            kh_l = model_cfg.page_heads
             if self.mesh.devices.size > 1 and kh_l % max(n_model, 1) == 0:
                 kh_l //= max(n_model, 1)
-            group = model_cfg.num_heads // model_cfg.num_kv_heads
+            group = model_cfg.num_heads // model_cfg.page_heads
             if not env_flag(ragged_attn, "ROUNDTABLE_RAGGED_ATTN"):
                 self.ragged_reason = "disabled:config/env"
             elif dict(self.mesh.shape).get("data", 1) > 1:
@@ -904,7 +918,7 @@ class InferenceEngine:
                     decline = "heads:model-axis"
                 else:
                     decline = _pattn.ragged_decline_reason(
-                        page_size, model_cfg.head_dim, kh_l, group)
+                        page_size, model_cfg.page_width, kh_l, group)
                 if (decline is None
                         and self.kv_quant_fallback_reason is not None):
                     # Quantized pool the kernel cannot dequantize
@@ -1242,7 +1256,7 @@ class InferenceEngine:
                     put_snaps(snaps, snap_idx, cap), host_read(counts))
 
         self._ragged_step_hybrid = ragged_step_hybrid
-        if self.prefix_cache is not None:
+        if self.prefix_cache is not None and cfg.recurrent:
             self.prefix_cache.state_store = self.hybrid
 
     def _install_drafter(self, kind: str, adapter: Optional[str] = None,
@@ -1347,8 +1361,9 @@ class InferenceEngine:
                       mesh) -> ModelConfig:
         """Pick the attention implementation (SURVEY.md §7.3 hard part 1).
 
-        "auto" enables the Pallas kernels on TPU with lane-aligned
-        head_dim. On a multi-device mesh they run under shard_map with kv
+        "auto" enables the Pallas kernels on TPU with a lane-aligned
+        page width (head_dim; a latent entry in whole lane rows). On a
+        multi-device mesh they run under shard_map with kv
         heads partitioned on the "model" axis (pallas/attention.py
         flash_attention_spmd), which requires both head counts to divide
         the model-axis size — otherwise auto stays dense (matching
@@ -1370,7 +1385,7 @@ class InferenceEngine:
         if attn in ("flash", "dense"):
             return dataclasses.replace(model_cfg, attn_impl=attn)
         if (jax.default_backend() == "tpu"
-                and model_cfg.head_dim % 128 == 0
+                and model_cfg.page_width % 128 == 0
                 and (mesh.devices.size == 1 or heads_divide)):
             return dataclasses.replace(model_cfg, attn_impl="flash")
         return dataclasses.replace(model_cfg, attn_impl="dense")
@@ -2466,7 +2481,48 @@ class InferenceEngine:
             extra_pinned=extra_pinned, defer_span=defer_span,
             donor_ok=donor_ok,
             decline_leader=(self._decline_leader_share
-                            if self.hybrid is not None else None))
+                            if self.cfg.recurrent else None))
+
+    def note_latent_positions(self, n: int) -> None:
+        """Positions of latent pages the rows of a segment read (the
+        scheduler's fold; `describe()["mla"]`, the segment span)."""
+        self._latent_positions += n
+        from ..utils import telemetry
+        telemetry.inc("roundtable_mla_latent_positions_total", n,
+                      engine=self.cfg.name)
+
+    def mla_describe(self) -> dict[str, Any]:
+        """Latent pages and the kernels that read them (models/mla.py,
+        engine/paging.py): the second page shape's provenance."""
+        from .pallas import attention as pattn
+        cfg, pool = self.cfg, self.kv.pools[0][0]
+        itemsize = pool.dtype.itemsize
+        entry = cfg.kv_lora_rank + cfg.qk_rope_dim
+        group = cfg.num_heads // cfg.page_heads
+        return {
+            "pool_shape": list(pool.shape),
+            "pools_per_layer": len(self.kv.pools[0]),
+            "layers": len(self.kv.pools),
+            "entry_width": entry, "page_width": cfg.page_width,
+            "bytes_per_position_published": entry * itemsize,
+            "bytes_per_position_stored": cfg.page_width * itemsize,
+            # Every path that reads pages computes the absorbed form,
+            # the prologue's first chunk too (one form, one kernel
+            # family); the expanded form is the whole-sequence forward.
+            "form": "absorbed", "prologue_form": "absorbed",
+            "paged_decode": ("mla_paged_decode" if self.paged_direct
+                             else "gather-view"),
+            "paged_prefill": ("mla_paged_prefill" if self.paged_direct
+                              else "gather-view"),
+            "ragged": ("mla_ragged"
+                       if self.ragged_path == "pallas_ragged"
+                       else self.ragged_path),
+            "decode_decline": pattn.paged_decode_decline_reason(
+                self.kv.page_size, cfg.page_width, 1, group,
+                itemsize=itemsize, latent=True),
+            "ragged_decline": self.ragged_fallback_reason,
+            "latent_positions": self._latent_positions,
+        }
 
     def _decline_leader_share(self, n_laggards: int) -> None:
         self.hybrid.share_declined += n_laggards
@@ -2635,7 +2691,7 @@ class InferenceEngine:
                         sub_off, pinned)
                     for j, i in enumerate(base_idx):
                         offsets[i] = sub_off[j]
-        defer_by_state = defer_prefill and self.hybrid is not None
+        defer_by_state = defer_prefill and self.cfg.recurrent
         if defer_prefill and not defer_by_state:
             # Deferral pays off only for COLD prefills: after own-slot
             # reuse and the prefix-cache attach, a warm join's leftover
@@ -3161,6 +3217,8 @@ class InferenceEngine:
                            "top_k": self.cfg.moe_top_k,
                            "expert_layers": len(self.cfg.expert_layers),
                            **self.hybrid.moe_totals()}
+        if self.cfg.latent and self.kv_layout == "paged":
+            info["mla"] = self.mla_describe()
         # What this model declined at build time, each with its reason.
         info["declines"] = dict(self.declines)
         # ISSUE 10: multi-LoRA persona provenance — the resolved

@@ -38,11 +38,20 @@ def estimate_param_count(cfg: ModelConfig) -> int:
             "mamba2": (e * (d_in + conv + cfg.mamba_heads) + d_in * e
                        + (cfg.conv_kernel + 1) * conv
                        + 3 * cfg.mamba_heads + d_in),
-            "experts": (cfg.experts_held * 2 * e * cfg.expert_dim
-                        + 2 * e * cfg.shared_expert_dim
-                        + (e + 1) * cfg.routed_experts),
+            "experts": ((3 if cfg.expert_gated else 2) * e * (
+                cfg.experts_held * cfg.expert_dim + cfg.shared_expert_dim)
+                + (e + (cfg.router_rule == "sigmoid_bias_topk"))
+                * cfg.routed_experts),
             "attention": 2 * e * h * d + 2 * e * k * d,
+            "mlp": 3 * e * f,
         }
+        if cfg.latent:
+            r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
+            per_kind["attention"] = (
+                e * r_q + r_q + r_q * h * d
+                + e * (r_kv + cfg.qk_rope_dim) + r_kv
+                + r_kv * h * (cfg.qk_nope_dim + cfg.v_head_dim)
+                + h * cfg.v_head_dim * e)
         return (sum(per_kind[kind] + e for kind in cfg.layer_kinds)
                 + 2 * cfg.vocab_size * e + e)
     mlp = 3 * e * f
@@ -79,8 +88,8 @@ def estimate_engine_hbm_bytes(engine_cfg: dict[str, Any],
                               else 0.58 if quant == "int4"
                               else dtype_b))
     num_slots = int(engine_cfg.get("num_slots", 4))
-    kv_bytes = (num_slots * max_seq * len(model_cfg.attention_layers) * 2
-                * model_cfg.num_kv_heads * model_cfg.head_dim * dtype_b)
+    kv_bytes = (num_slots * max_seq * len(model_cfg.attention_layers)
+                * model_cfg.page_cells * dtype_b)
     if engine_cfg.get("kv_layout") == "paged":
         # Default pool halves the contiguous budget. Total across the
         # submesh: the page axis shards over "data" and kv heads over

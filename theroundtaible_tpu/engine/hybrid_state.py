@@ -33,6 +33,12 @@ caller lowers the row's KV offset to that position (attention layers
 re-write their few pages from there) and the state is copied in before
 the first dispatch.
 
+A model with `layer_kinds` and NO recurrent layer (`axk1`: attention,
+MLP and expert layers) runs the same step programs with an EMPTY state
+tree: its store holds no bytes and no snapshots, every position of its
+pages is a place to continue from (`plan` hands the pages' own frontier
+back), and what is left of this module for it is the expert counters.
+
 Single-writer like the pool: every caller holds the engine's serve lock.
 """
 
@@ -79,8 +85,9 @@ class HybridStateStore:
         self.num_slots = num_slots
         self.scratch_row = num_slots
         self.bytes_per_state = hybrid.state_bytes_per_sequence(cfg)
-        self.budget_bytes = int(snapshot_bytes)
-        self.capacity = self.budget_bytes // self.bytes_per_state
+        self.budget_bytes = int(snapshot_bytes) if cfg.recurrent else 0
+        self.capacity = (self.budget_bytes // self.bytes_per_state
+                         if cfg.recurrent else 0)
         self.scratch_snap = self.capacity
         self._alloc()
         self._row_of: dict[str, int] = {}
@@ -194,7 +201,10 @@ class HybridStateStore:
         can stand for prompt `tokens`, given pages for tokens[:kv_matched].
         Also records the prompt's page keys, so captures of this
         admission can be keyed. Leaves the slot marked in flight (no continuation until
-        its next commit)."""
+        its next commit). Without recurrent state the pages' frontier
+        IS the place to continue from."""
+        if not self.cfg.recurrent:
+            return kv_matched, CONTINUE, None
         cap = min(kv_matched, len(tokens) - 1)
         keys = page_keys(tokens, self.page_size, len(tokens))
         self._keys[name] = keys

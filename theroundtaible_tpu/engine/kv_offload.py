@@ -97,13 +97,13 @@ class SpilledSession:
                        for kind, _p in srec.entries)
 
     def host_bytes(self) -> int:
-        return sum(k.nbytes + v.nbytes for k, v in self.host)
+        return sum(a.nbytes for layer in self.host for a in layer)
 
     def append_rows(self, fetched, replicas: list[int]) -> None:
         if self.host:
             self.host = [
-                (np.concatenate([k0, k1]), np.concatenate([v0, v1]))
-                for (k0, v0), (k1, v1) in zip(self.host, fetched)]
+                tuple(np.concatenate([a0, a1]) for a0, a1 in zip(old, new))
+                for old, new in zip(self.host, fetched)]
         else:
             self.host = fetched
         self.replicas.extend(replicas)
@@ -128,23 +128,17 @@ class HostOffloadTier:
         def fetch_pages(pools, ids):
             # Replicated outputs so the host read works on any mesh
             # (the engines' host_read contract).
-            out = []
-            for k, v in pools:
-                out.append(
-                    (jax.lax.with_sharding_constraint(k[ids], rep),
-                     jax.lax.with_sharding_constraint(v[ids], rep)))
-            return out
+            return [tuple(jax.lax.with_sharding_constraint(p[ids], rep)
+                          for p in layer) for layer in pools]
 
         @partial(jax.jit, donate_argnums=(0,))
         def write_pages(pools, ids, data):
             # Pad rows target the scratch page with zero bytes — never
             # read, and duplicate scratch indices only ever race other
             # pads (real ids are distinct fresh allocations).
-            out = []
-            for (k, v), (dk, dv) in zip(pools, data):
-                out.append((k.at[ids].set(dk.astype(k.dtype)),
-                            v.at[ids].set(dv.astype(v.dtype))))
-            return out
+            return [tuple(p.at[ids].set(d.astype(p.dtype))
+                          for p, d in zip(layer, new))
+                    for layer, new in zip(pools, data)]
 
         self._fetch_pages = fetch_pages
         self._write_pages = write_pages
@@ -188,14 +182,14 @@ class HostOffloadTier:
     # --- device chunk helpers (fixed WIDTH shapes) ---
 
     def _fetch(self, page_ids: list[int],
-               replica: int) -> list[tuple[np.ndarray, np.ndarray]]:
+               replica: int) -> list[tuple[np.ndarray, ...]]:
         kv = self.engine.kv
         scratch = kv.scratch_page(replica)
         # Combined pools (ISSUE 11): quantized pools spill their scale
         # arrays as extra "layers" in the same host record — int8
         # payload + scales is the whole state, so restore is exactly
         # lossless and spill bandwidth drops with the payload width.
-        per_layer: list[list[tuple[np.ndarray, np.ndarray]]] = [
+        per_layer: list[list[tuple[np.ndarray, ...]]] = [
             [] for _ in kv.combined_pools()]
         from . import compile_watch
         for start in range(0, len(page_ids), WIDTH):
@@ -206,14 +200,13 @@ class HostOffloadTier:
                                      engine=self._name):
                 out = self._fetch_pages(kv.combined_pools(),
                                         jnp.asarray(ids, jnp.int32))
-            for li, (k, v) in enumerate(out):
-                per_layer[li].append((np.asarray(k)[:n],
-                                      np.asarray(v)[:n]))
-        return [(np.concatenate([c[0] for c in chunks])
-                 if chunks else np.zeros(0),
-                 np.concatenate([c[1] for c in chunks])
-                 if chunks else np.zeros(0))
-                for chunks in per_layer]
+            for li, layer in enumerate(out):
+                per_layer[li].append(tuple(np.asarray(a)[:n]
+                                           for a in layer))
+        arity = [len(layer) for layer in kv.combined_pools()]
+        return [tuple(np.concatenate([c[j] for c in chunks])
+                      if chunks else np.zeros(0) for j in range(width))
+                for chunks, width in zip(per_layer, arity)]
 
     def _write(self, page_ids: list[int],
                host: list[tuple[np.ndarray, np.ndarray]],
@@ -228,14 +221,15 @@ class HostOffloadTier:
             n = len(ids)
             ids = ids + [scratch] * (WIDTH - n)
             data = []
-            for k_all, v_all in host:
-                k = k_all[sel]
-                v = v_all[sel]
-                if n < WIDTH:
-                    pad = (WIDTH - n,) + k.shape[1:]
-                    k = np.concatenate([k, np.zeros(pad, k.dtype)])
-                    v = np.concatenate([v, np.zeros(pad, v.dtype)])
-                data.append((jnp.asarray(k), jnp.asarray(v)))
+            for layer in host:
+                rows_l = []
+                for a_all in layer:
+                    a = a_all[sel]
+                    if n < WIDTH:
+                        pad = (WIDTH - n,) + a.shape[1:]
+                        a = np.concatenate([a, np.zeros(pad, a.dtype)])
+                    rows_l.append(jnp.asarray(a))
+                data.append(tuple(rows_l))
             with compile_watch.label("kv_restore[write]",
                                      engine=self._name):
                 pools = self._write_pages(

@@ -111,6 +111,10 @@ def cell_bytes_per_token(cfg: Any, spec: Optional[KVQuantSpec],
                 else bf16_cell_bytes(cfg.head_dim, dtype_bytes))
     layers = getattr(cfg, "attention_layers", None)
     n_layers = cfg.num_layers if layers is None else len(layers)
+    if spec is None and getattr(cfg, "latent", False):
+        # Latent pages (models/mla.py): one entry a position, no
+        # per-head cell to quantize (the engine declines kv_quant).
+        return n_layers * cfg.page_cells * dtype_bytes
     return n_layers * 2 * cfg.num_kv_heads * per_cell
 
 

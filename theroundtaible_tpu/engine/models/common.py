@@ -134,7 +134,9 @@ class ModelConfig:
     attn_impl: str = "dense"
     # Hybrid decoders (models/hybrid.py): one kind a layer, each layer ONE
     # mixer behind one norm and a residual — "mamba2" | "experts" |
-    # "attention". None = the attention-plus-MLP block above, untouched.
+    # "attention" | "mlp". None = the attention-plus-MLP block above,
+    # untouched. A pre-norm block whose two halves have a norm each IS two
+    # such layers (attention, then mlp or experts): `axk1`.
     layer_kinds: Optional[tuple[str, ...]] = None
     rope: bool = True                 # False: no position embedding at all
     # Mamba-2 mixer
@@ -154,7 +156,20 @@ class ModelConfig:
     expert_dim: int = 0
     shared_expert_dim: int = 0
     routed_scaling: float = 1.0
-    router_rule: str = "sigmoid_bias_topk"
+    router_rule: str = "sigmoid_bias_topk"   # | "sigmoid_topk": no bias
+    expert_act: str = "relu2"                # | "silu"
+    expert_gated: bool = False               # (act(x W_gate) * x W_up) W_down
+    # Multi-head latent attention (models/mla.py), on where kv_lora_rank
+    # > 0: a page holds, a position, the normed c_kv (kv_lora_rank) and
+    # the roped shared key part (qk_rope_dim) — one "head", no values.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale, mscale_all_dim), or None for plain rope.
+    rope_yarn: Optional[tuple[float, ...]] = None
 
     @property
     def kv_repeat(self) -> int:
@@ -163,6 +178,34 @@ class ModelConfig:
     def _layers_of(self, kind: str) -> tuple[int, ...]:
         return tuple(i for i, k in enumerate(self.layer_kinds or ())
                      if k == kind)
+
+    @property
+    def latent(self) -> bool:
+        """Attention layers keep latent pages (models/mla.py)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def page_heads(self) -> int:
+        """The kv heads a page holds, as the paged kernels see them."""
+        return 1 if self.latent else self.num_kv_heads
+
+    @property
+    def page_width(self) -> int:
+        """The cells of one head of one position of a page: head_dim, or
+        a latent entry (kv_lora_rank + qk_rope_dim) padded to whole lane
+        rows — 576 is 4.5 of them, and the kernels copy and multiply
+        pages as they lie."""
+        if not self.latent:
+            return self.head_dim
+        return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
+
+    @property
+    def page_cells(self) -> int:
+        """Cells one position costs one attention layer's pools: keys
+        and values of every kv head, or one latent entry."""
+        if self.latent:
+            return self.page_width
+        return 2 * self.num_kv_heads * self.head_dim
 
     @property
     def recurrent(self) -> bool:
@@ -207,12 +250,19 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float,
     return (x * w).astype(dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding. x: [B, T, H, D], positions: [B, T]."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq: Optional[jax.Array] = None) -> jax.Array:
+    """Rotary position embedding. x: [B, T, H, D], positions: [B, T].
+    `inv_freq` [D/2], where given, replaces theta's own frequencies
+    (YaRN's blend: models/mla.py)."""
     head_dim = x.shape[-1]
-    fraction = jnp.arange(0, head_dim // 2, dtype=jnp.float32) / (head_dim // 2)
-    timescale = theta ** fraction                       # [D/2]
-    angles = positions[..., None].astype(jnp.float32) / timescale  # [B,T,D/2]
+    pos = positions[..., None].astype(jnp.float32)
+    if inv_freq is None:
+        fraction = jnp.arange(0, head_dim // 2,
+                              dtype=jnp.float32) / (head_dim // 2)
+        angles = pos / theta ** fraction                # [B,T,D/2]
+    else:
+        angles = pos * inv_freq
     angles = angles[:, :, None, :]                      # [B, T, 1, D/2]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -660,6 +710,13 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
                 kv_valid_len)
         elif kind == hybrid.EXPERTS:
             out, _ = hybrid.experts_mlp(h, layer, cfg)
+        elif kind == hybrid.MLP:
+            out = mlp(h, layer, cfg)
+        elif cfg.latent:
+            from . import mla
+            out, kv = mla.expanded_attention(h, layer, cfg, positions,
+                                             mask)
+            caches.append(kv)
         else:
             out, kv = attention(h, layer, cfg, positions, None, None,
                                 mask, kv_valid_len)

@@ -1,8 +1,11 @@
 """The layers of a hybrid decoder (`ModelConfig.layer_kinds`): a Mamba-2
 mixer, a layer of routed and shared experts, and the block wiring in
 which every layer is ONE mixer behind one RMSNorm and a residual
-(`nemotron_h`). Attention layers reuse `common.project_qkv` / the paged
-kernels; what is here is what those models add.
+(`nemotron_h`). Attention layers reuse `common.project_qkv` (or, with
+latent pages, `models/mla.py`) and the paged kernels; a plain MLP layer
+reuses `common.mlp`. A pre-norm block of attention and MLP, each behind
+its own norm, is two such layers (`axk1`: attention + mlp, then
+attention + experts): one wiring for both families.
 
 Mamba-2 (state-space duality form). Per head, with state S in
 R^{P x N} kept in float32:
@@ -27,7 +30,11 @@ A token with dt = 0 is the identity on the state (exp(0) = 1, no
 input), which is how pad tokens and finished rows are masked.
 
 Experts (`experts_mlp`): sigmoid scores over ALL published experts,
-top-k of score + bias, weights renormalised and scaled; the chip
+top-k of score (+ bias, where the router's rule has one), weights
+renormalised and scaled; each expert `act(x W_up) W_down` or, gated,
+`(act(x W_gate) * x W_up) W_down` (`cfg.expert_act`,
+`cfg.expert_gated`: relu squared ungated for `nemotron_h`, gated SiLU
+for `axk1`); the chip
 computes the part of the result its own experts give
 (`expert_offset <= id < offset + experts_held`) and the shared expert.
 The routed part loops over the held experts with the token weights as
@@ -47,8 +54,7 @@ import jax.numpy as jnp
 
 from .common import ModelConfig, Params, _einsum, rms_norm
 
-MAMBA2, EXPERTS, ATTENTION = "mamba2", "experts", "attention"
-LAYER_KINDS = (MAMBA2, EXPERTS, ATTENTION)
+MAMBA2, EXPERTS, ATTENTION, MLP = "mamba2", "experts", "attention", "mlp"
 PATTERN_LETTERS = {"M": MAMBA2, "E": EXPERTS, "*": ATTENTION}
 
 
@@ -385,17 +391,24 @@ def _relu2(x: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(x))
 
 
+EXPERT_ACTS = {"relu2": _relu2, "silu": jax.nn.silu}
+
+
 def route(h: jax.Array, layer: Params, cfg: ModelConfig):
-    """The router's rule (DeepSeek-V3's, as `nemotron_h` uses it with
-    one group): s = sigmoid(h W_r) in float32 over ALL published
-    experts; choose top-k of s + bias; weights s[chosen] / (sum +
-    1e-20) * scale. h [T,E] -> (ids [T,k] int32, weights [T,k] f32)."""
+    """The router's rule (DeepSeek-V3's with one group): s = sigmoid(h
+    W_r) in float32 over ALL published experts; choose top-k of s +
+    bias ("sigmoid_bias_topk": `nemotron_h`) or of s alone
+    ("sigmoid_topk": `axk1`, which declares no bias); weights
+    s[chosen] / (sum + 1e-20) * scale. h [T,E] -> (ids [T,k] int32,
+    weights [T,k] f32)."""
     s = jax.nn.sigmoid(jnp.einsum(
         "te,ex->tx", h.astype(jnp.float32),
         layer["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, ids = jax.lax.top_k(
-        s + layer["router_bias"].astype(jnp.float32), cfg.moe_top_k)
+    pick = s
+    if cfg.router_rule == "sigmoid_bias_topk":
+        pick = s + layer["router_bias"].astype(jnp.float32)
+    _, ids = jax.lax.top_k(pick, cfg.moe_top_k)
     w = jnp.take_along_axis(s, ids, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) \
         * cfg.routed_scaling
@@ -429,24 +442,25 @@ def experts_mlp(h: jax.Array, layer: Params, cfg: ModelConfig,
         jnp.sum(jnp.any(chosen, axis=0)),
         jnp.sum(here & counted[:, None])]).astype(jnp.int32)
 
+    act = EXPERT_ACTS[cfg.expert_act]
+
+    def expert(w):
+        a = act(_einsum("te,ef->tf", x, w["gate" if cfg.expert_gated
+                                           else "up"]))
+        if cfg.expert_gated:
+            a = a * _einsum("te,ef->tf", x, w["up"])
+        return _einsum("tf,fe->te", a.astype(x.dtype), w["down"])
+
     def one(acc, xs):
-        up, down, wt = xs
-
-        def run(acc):
-            a = _relu2(_einsum("te,ef->tf", x, up)).astype(x.dtype)
-            y = _einsum("tf,fe->te", a, down)
-            return acc + y * wt[:, None]
-
-        return jax.lax.cond(jnp.any(wt > 0), run, lambda a: a, acc), None
+        w, wt = xs
+        return jax.lax.cond(jnp.any(wt > 0),
+                            lambda a: a + expert(w) * wt[:, None],
+                            lambda a: a, acc), None
 
     routed, _ = jax.lax.scan(
         one, jnp.zeros((t, x.shape[-1]), jnp.float32),
-        (layer["experts"]["up"], layer["experts"]["down"], dense.T))
-    shared = _einsum(
-        "tf,fe->te",
-        _relu2(_einsum("te,ef->tf", x, layer["shared"]["up"]))
-        .astype(x.dtype), layer["shared"]["down"])
-    out = (routed + shared).astype(h.dtype)
+        (layer["experts"], dense.T))
+    out = (routed + expert(layer["shared"])).astype(h.dtype)
     return out.reshape(*lead, -1), counts
 
 
@@ -509,14 +523,37 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
         layer.update({
             "router": dense(ks[0], (e, cfg.routed_experts), e)
             .astype(jnp.float32),
-            "router_bias": jax.random.normal(
-                ks[1], (cfg.routed_experts,), jnp.float32) * 0.02,
             "experts": {"up": dense(ks[2], (held, e, f), e),
                         "down": dense(ks[3], (held, f, e), f,
                                       RESIDUAL_SHARE)},
             "shared": {"up": dense(ks[4], (e, fs), e),
                        "down": dense(ks[5], (fs, e), fs,
                                      RESIDUAL_SHARE)},
+        })
+        if cfg.router_rule == "sigmoid_bias_topk":
+            layer["router_bias"] = jax.random.normal(
+                ks[1], (cfg.routed_experts,), jnp.float32) * 0.02
+        if cfg.expert_gated:
+            layer["experts"]["gate"] = dense(ks[6], (held, e, f), e)
+            layer["shared"]["gate"] = dense(ks[7], (e, fs), e)
+    elif kind == MLP:
+        f = cfg.mlp_dim
+        layer.update({
+            "gate_proj": dense(ks[0], (e, f), e),
+            "up_proj": dense(ks[1], (e, f), e),
+            "down_proj": dense(ks[2], (f, e), f, RESIDUAL_SHARE),
+        })
+    elif kind == ATTENTION and cfg.latent:
+        h_, r_q, r_kv = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rot, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        layer.update({
+            "q_a": dense(ks[0], (e, r_q), e),
+            "q_norm": jnp.ones((r_q,), dtype),
+            "q_b": dense(ks[1], (r_q, h_, nope + rot), r_q),
+            "kv_a": dense(ks[2], (e, r_kv + rot), e),
+            "kv_norm": jnp.ones((r_kv,), dtype),
+            "kv_b": dense(ks[3], (r_kv, h_, nope + dv), r_kv),
+            "o_proj": dense(ks[4], (h_, dv, e), h_ * dv, RESIDUAL_SHARE),
         })
     elif kind == ATTENTION:
         h_, k_, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim

@@ -4,7 +4,9 @@ builds one from a published `config.json`.
 Presets: the reference-targeted open-weight families (BASELINE.md:
 Gemma-2B/7B, Llama-3-8B/3.2, Mistral-7B), Mixtral (compute-dense MoE),
 Qwen2.5 (attention bias), Nemotron-3-Nano (hybrid: Mamba-2, routed and
-shared experts, attention — models/hybrid.py) and tiny test presets.
+shared experts, attention — models/hybrid.py), A.X-K1 (latent attention
+— models/mla.py — beside a dense MLP or gated routed and shared
+experts) and tiny test presets.
 Architecture behavior lives in ModelConfig fields (common.py).
 
 `resolve_model_config(adapter_config)` is the one way an engine gets its
@@ -20,7 +22,7 @@ from __future__ import annotations
 from typing import Any
 
 from .common import ModelConfig
-from .hybrid import kinds_of_pattern
+from .hybrid import ATTENTION, EXPERTS, MLP, kinds_of_pattern
 
 _REGISTRY: dict[str, ModelConfig] = {}
 
@@ -145,6 +147,41 @@ TINY_NEMOTRON_H = register(ModelConfig(
     expert_dim=32, shared_expert_dim=64, routed_scaling=2.5))
 
 
+# --- A.X-K1 / axk1 (DeepSeek-V3's block: latent attention, then a dense
+# MLP in the first layers and gated routed + shared experts after; every
+# published layer is TWO layers here, a mixer behind a norm each) ---
+
+
+def axk1_kinds(n_blocks: int, dense_blocks: int) -> tuple[str, ...]:
+    return tuple(k for b in range(n_blocks)
+                 for k in (ATTENTION, MLP if b < dense_blocks else EXPERTS))
+
+
+AXK1_YARN = (32.0, 4096.0, 32.0, 1.0, 1.0, 1.0)
+
+AXK1 = register(ModelConfig(
+    name="a.x-k1", vocab_size=163_840, num_layers=122, embed_dim=7168,
+    num_heads=64, num_kv_heads=64, head_dim=192, mlp_dim=18_432,
+    max_seq_len=8192, norm_eps=1e-6, tie_embeddings=False,
+    layer_kinds=axk1_kinds(61, 1),
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+    v_head_dim=128, rope_yarn=AXK1_YARN,
+    routed_experts=192, experts_held=192, expert_offset=0, moe_top_k=8,
+    expert_dim=2048, shared_expert_dim=2048, routed_scaling=2.5,
+    router_rule="sigmoid_topk", expert_act="silu", expert_gated=True))
+
+TINY_AXK1 = register(ModelConfig(
+    name="tiny-axk1", vocab_size=512, num_layers=6, embed_dim=64,
+    num_heads=4, num_kv_heads=4, head_dim=24, mlp_dim=128,
+    max_seq_len=512, norm_eps=1e-6, tie_embeddings=False,
+    layer_kinds=axk1_kinds(3, 1),
+    q_lora_rank=32, kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+    v_head_dim=16, rope_yarn=(32.0, 64.0, 32.0, 1.0, 1.0, 1.0),
+    routed_experts=8, experts_held=8, expert_offset=0, moe_top_k=2,
+    expert_dim=32, shared_expert_dim=32, routed_scaling=2.5,
+    router_rule="sigmoid_topk", expert_act="silu", expert_gated=True))
+
+
 # --- from a published config.json -------------------------------------------
 
 # Keys of a nemotron_h config.json that say nothing this engine acts on
@@ -165,17 +202,37 @@ _NEMOTRON_FIXED = {
 _DENSE_TYPES = ("llama", "mistral", "qwen2")
 
 
-def _nemotron_h(name: str, arch: dict[str, Any],
-                max_seq_len: int) -> ModelConfig:
+def _acted_on(name: str, arch: dict[str, Any], kind: str,
+              fixed: dict[str, Any], inert: set) -> dict[str, Any]:
+    """A copy of `arch` without the keys that say nothing this engine
+    acts on; a key whose value the layer equations do not assume fails."""
     arch = dict(arch)
-    for key, want in _NEMOTRON_FIXED.items():
+    for key, want in fixed.items():
         got = arch.pop(key, want)
         if got != want:
             raise ValueError(
                 f"architecture of {name!r}: {key}={got!r}, and this "
-                f"engine's nemotron_h layers are written for {want!r}")
-    for key in _NEMOTRON_INERT:
+                f"engine's {kind} layers are written for {want!r}")
+    for key in inert:
         arch.pop(key, None)
+    return arch
+
+
+def _all_read(name: str, arch: dict[str, Any], kind: str, ep_rank: int,
+              ep_size: int) -> None:
+    """What is left of `arch` when every key has been read is unknown."""
+    if arch:
+        raise ValueError(f"architecture of {name!r}: unknown keys "
+                         f"{sorted(arch)} for model_type {kind!r}")
+    if not 0 <= ep_rank < ep_size:
+        raise ValueError(f"architecture of {name!r}: ep_rank {ep_rank} "
+                         f"outside 0..{ep_size - 1}")
+
+
+def _nemotron_h(name: str, arch: dict[str, Any],
+                max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "nemotron_h", _NEMOTRON_FIXED,
+                     _NEMOTRON_INERT)
     try:
         kinds = kinds_of_pattern(arch.pop("hybrid_override_pattern"))
         n_layers = int(arch.pop("num_hidden_layers"))
@@ -210,16 +267,80 @@ def _nemotron_h(name: str, arch: dict[str, Any],
     except KeyError as e:
         raise ValueError(f"architecture of {name!r} lacks the key "
                          f"{e.args[0]!r}") from None
-    if arch:
-        raise ValueError(f"architecture of {name!r}: unknown keys "
-                         f"{sorted(arch)} for model_type 'nemotron_h'")
+    _all_read(name, arch, "nemotron_h", ep_rank, ep_size)
     if len(kinds) != n_layers:
         raise ValueError(
             f"architecture of {name!r}: hybrid_override_pattern has "
             f"{len(kinds)} layers, num_hidden_layers says {n_layers}")
-    if not 0 <= ep_rank < ep_size:
-        raise ValueError(f"architecture of {name!r}: ep_rank {ep_rank} "
-                         f"outside 0..{ep_size - 1}")
+    return cfg
+
+
+# Keys of an axk1 config.json that say nothing this engine acts on, and
+# the values its layer equations assume. `topk_method: "none"` is read
+# literally: plain top-k over every score, so n_group and topk_group are
+# recorded and unused.
+_AXK1_INERT = {"model_type", "max_position_embeddings", "n_group",
+               "topk_group", "seq_aux", "num_key_value_heads"}
+_AXK1_FIXED = {
+    "attention_bias": False, "hidden_act": "silu", "moe_layer_freq": 1,
+    "n_shared_experts": 1, "norm_topk_prob": True,
+    "scoring_func": "sigmoid", "topk_method": "none",
+    "tie_word_embeddings": False}
+
+
+def _axk1(name: str, arch: dict[str, Any],
+          max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "axk1", _AXK1_FIXED, _AXK1_INERT)
+    try:
+        yarn = dict(arch.pop("rope_scaling"))
+        if yarn.pop("type") != "yarn":
+            raise ValueError(f"architecture of {name!r}: rope_scaling "
+                             "must be of type 'yarn'")
+        rope_yarn = tuple(float(yarn.pop(k)) for k in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow", "mscale", "mscale_all_dim"))
+        if yarn:
+            raise ValueError(f"architecture of {name!r}: unknown "
+                             f"rope_scaling keys {sorted(yarn)}")
+        n_blocks = int(arch.pop("num_hidden_layers"))
+        dense_blocks = int(arch.pop("first_k_dense_replace"))
+        held = int(arch.pop("n_routed_experts"))
+        ep_size = int(arch.pop("ep_size", 1))
+        ep_rank = int(arch.pop("ep_rank", 0))
+        nope = int(arch.pop("qk_nope_head_dim"))
+        rot = int(arch.pop("qk_rope_head_dim"))
+        heads = int(arch.pop("num_attention_heads"))
+        width = int(arch.pop("moe_intermediate_size"))
+        cfg = ModelConfig(
+            name=name, vocab_size=int(arch.pop("vocab_size")),
+            num_layers=2 * n_blocks,
+            embed_dim=int(arch.pop("hidden_size")), num_heads=heads,
+            num_kv_heads=heads, head_dim=nope + rot,
+            mlp_dim=int(arch.pop("intermediate_size")),
+            max_seq_len=max_seq_len,
+            rope_theta=float(arch.pop("rope_theta")),
+            norm_eps=float(arch.pop("rms_norm_eps")),
+            tie_embeddings=False,
+            layer_kinds=axk1_kinds(n_blocks, dense_blocks),
+            q_lora_rank=int(arch.pop("q_lora_rank")),
+            kv_lora_rank=int(arch.pop("kv_lora_rank")),
+            qk_nope_dim=nope, qk_rope_dim=rot,
+            v_head_dim=int(arch.pop("v_head_dim")), rope_yarn=rope_yarn,
+            routed_experts=held * ep_size, experts_held=held,
+            expert_offset=held * ep_rank,
+            moe_top_k=int(arch.pop("num_experts_per_tok")),
+            expert_dim=width, shared_expert_dim=width,
+            routed_scaling=float(arch.pop("routed_scaling_factor")),
+            router_rule="sigmoid_topk", expert_act="silu",
+            expert_gated=True)
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    _all_read(name, arch, "axk1", ep_rank, ep_size)
+    if not 0 <= dense_blocks <= n_blocks:
+        raise ValueError(
+            f"architecture of {name!r}: first_k_dense_replace "
+            f"{dense_blocks} outside 0..{n_blocks}")
     return cfg
 
 
@@ -258,6 +379,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
         int(arch.get("max_position_embeddings", 8192)), 8192))
     if kind == "nemotron_h":
         return _nemotron_h(name, arch, max_seq_len)
+    if kind == "axk1":
+        return _axk1(name, arch, max_seq_len)
     if kind in _DENSE_TYPES:
         try:
             return _dense_gqa(name, arch, max_seq_len)
@@ -266,7 +389,7 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
                              f"{e.args[0]!r}") from None
     raise ValueError(
         f"architecture of {name!r}: model_type {kind!r} is not one this "
-        f"engine runs (nemotron_h, {', '.join(_DENSE_TYPES)})")
+        f"engine runs (nemotron_h, axk1, {', '.join(_DENSE_TYPES)})")
 
 
 def get_model_config(name: str, **overrides) -> ModelConfig:

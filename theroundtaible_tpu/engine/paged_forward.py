@@ -40,9 +40,10 @@ import jax
 import jax.numpy as jnp
 
 from . import kv_quant as kvq
+from .models import mla
 from .models.common import (MASK_VALUE, ModelConfig, Params, _einsum,
                             _softcap, current_spmd_mesh, embed_tokens,
-                            gather_rows, project_qkv, rms_norm,
+                            gather_rows, mlp, project_qkv, rms_norm,
                             transformer_block)
 from .pallas import attention as pattn
 
@@ -208,10 +209,15 @@ def _ragged_xla_attention(q, k_pool, v_pool, tables, token_seq,
     the gather (kv_quant.dequantize_cells — identical math to the
     in-kernel dequant, so kernel and fallback agree)."""
     t, h, d = q.shape
-    page_size, kh = k_pool.shape[1], k_pool.shape[2]
+    page_size, kh = k_pool.shape[1], pattn._pool_heads(k_pool)
     s, pp = tables.shape
     length = pp * page_size
-    if k_sc is not None:
+    if v_pool is None:
+        # A latent pool [P, ps, W] (models/mla.py): one head, whose
+        # values are the first kv_lora_rank columns of its keys.
+        kg = k_pool[tables].reshape(s, length, 1, d)
+        vg = kg[..., :cfg.kv_lora_rank]
+    elif k_sc is not None:
         # Gather FIRST, then dequantize the gathered slices — the
         # dequant cost scales with the view, not the whole pool.
         kg = kvq.dequantize_cells(k_pool[tables], k_sc[tables],
@@ -225,9 +231,9 @@ def _ragged_xla_attention(q, k_pool, v_pool, tables, token_seq,
         vg = v_pool[tables].reshape(s, length, kh, d)
     kt = kg[token_seq]                                # [T, L, K, D]
     vt = vg[token_seq]
-    if cfg.kv_repeat > 1:
-        kt = jnp.repeat(kt, cfg.kv_repeat, axis=2)    # [T, L, H, D]
-        vt = jnp.repeat(vt, cfg.kv_repeat, axis=2)
+    if h // kh > 1:
+        kt = jnp.repeat(kt, h // kh, axis=2)          # [T, L, H, D]
+        vt = jnp.repeat(vt, h // kh, axis=2)
     logits = jnp.einsum("thd,tlhd->thl", q, kt,
                         preferred_element_type=jnp.float32)
     logits = _softcap(logits, cfg.attn_logit_softcap)
@@ -392,6 +398,29 @@ def _hybrid_head(params, cfg, x):
     return _softcap(logits, cfg.final_logit_softcap)
 
 
+def _attention_io(h, layer, cfg: ModelConfig, positions, dtype):
+    """What an attention layer of a model with `layer_kinds` asks of its
+    pages and writes to them: (q [B,T,H,D], entries — one array a pool
+    of the layer, each [B,T,...] — and the kernels' extra keywords).
+    Grouped-query: keys and values, two pools. Latent (models/mla.py,
+    absorbed form): ONE entry a position and no value pool."""
+    if cfg.latent:
+        q, entry = mla.latents(h, layer, cfg, positions)
+        return (q.astype(dtype), (entry.astype(dtype),),
+                {"v_dim": cfg.kv_lora_rank})
+    q, k, v = (a.astype(dtype) for a in
+               project_qkv(h, layer, cfg, positions))
+    return q, (k, v), {}
+
+
+def _attention_out(out, layer, cfg: ModelConfig, dtype):
+    """The kernels' result [B,T,H,*] -> the layer's output [B,T,E]."""
+    if cfg.latent:
+        out = mla.values_of(out, layer, cfg)
+    return _einsum("bthd,hde->bte", out, layer["o_proj"],
+                   tp="row").astype(dtype)
+
+
 def forward_paged_hybrid(
     params: Params, cfg: ModelConfig,
     tokens: jax.Array,            # [B, T] (T==1 with `active`: decode)
@@ -453,30 +482,31 @@ def forward_paged_hybrid(
             out, c = hybrid.experts_mlp(h, layer, cfg, counted)
             counts = counts + jnp.concatenate(
                 [c, jnp.any(counted).astype(jnp.int32)[None]])
+        elif kind == hybrid.MLP:
+            out = mlp(h, layer, cfg)
         else:
-            k_pool, v_pool = pools[ai]
-            q, k, v = (a.astype(k_pool.dtype) for a in
-                       project_qkv(h, layer, cfg, positions))
-            k_pool = k_pool.at[pages, offs].set(k)
-            v_pool = v_pool.at[pages, offs].set(v)
+            q, entries, kw = _attention_io(h, layer, cfg, positions,
+                                           pools[ai][0].dtype)
+            layer_pools = tuple(p.at[pages, offs].set(e)
+                                for p, e in zip(pools[ai], entries))
+            k_pool, v_pool = (layer_pools + (None,))[:2]
             if t == 1:
                 out = pattn.paged_decode_attention(
                     q, k_pool, v_pool, table, kv_valid_len,
                     sliding_window=cfg.sliding_window,
-                    softcap=cfg.attn_logit_softcap)
+                    softcap=cfg.attn_logit_softcap, **kw)
             else:
                 out = pattn.paged_prefill_attention(
                     q, k_pool, v_pool, table, positions[:, 0],
                     kv_valid_len, sliding_window=cfg.sliding_window,
-                    softcap=cfg.attn_logit_softcap)
+                    softcap=cfg.attn_logit_softcap, **kw)
             if out is None:
                 raise ValueError(
                     "paged pool-direct kernels declined this shape "
                     f"(T={t}, ps={page_size}); the engine gates hybrid "
                     "models on paged_direct at build time")
-            out = _einsum("bthd,hde->bte", out, layer["o_proj"],
-                          tp="row").astype(h.dtype)
-            new_pools.append((k_pool, v_pool))
+            out = _attention_out(out, layer, cfg, h.dtype)
+            new_pools.append(layer_pools)
             ai += 1
         x = x + out
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
@@ -527,25 +557,27 @@ def forward_ragged_hybrid(
             out, c = hybrid.experts_mlp(h, layer, cfg, counted)
             counts = counts + jnp.concatenate(
                 [c, jnp.ones((1,), jnp.int32)])
+        elif kind == hybrid.MLP:
+            out = mlp(h, layer, cfg)
         else:
-            k_pool, v_pool = pools[ai]
-            q, k, v = (a.astype(k_pool.dtype) for a in
-                       project_qkv(h, layer, cfg, pos2))    # [1,T,H,D]
-            k_pool = k_pool.at[token_pages, token_offs].set(k[0])
-            v_pool = v_pool.at[token_pages, token_offs].set(v[0])
+            q, entries, kw = _attention_io(h, layer, cfg, pos2,
+                                           pools[ai][0].dtype)
+            layer_pools = tuple(
+                p.at[token_pages, token_offs].set(e[0])
+                for p, e in zip(pools[ai], entries))        # e [1,T,...]
+            k_pool, v_pool = (layer_pools + (None,))[:2]
             if attn_path == "kernel":
                 out = pattn.ragged_paged_attention(
                     q[0], k_pool, v_pool, tables, seq_of_block,
                     block_qstart, query_offsets, kv_valid,
                     sliding_window=cfg.sliding_window,
-                    softcap=cfg.attn_logit_softcap)
+                    softcap=cfg.attn_logit_softcap, **kw)
             else:
                 out = _ragged_xla_attention(
                     q[0], k_pool, v_pool, tables, token_seq, positions,
                     kv_valid, cfg)
-            out = _einsum("bthd,hde->bte", out[None], layer["o_proj"],
-                          tp="row").astype(h.dtype)
-            new_pools.append((k_pool, v_pool))
+            out = _attention_out(out[None], layer, cfg, h.dtype)
+            new_pools.append(layer_pools)
             ai += 1
         x = x + out
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)

@@ -104,6 +104,17 @@ class PagedKVCache:
     `copy_pages_fn(pools, src_ids, dst_ids)` is the engine-provided jit'd
     program that copies whole pages (used for copy-on-write); it is the
     only device operation the allocator itself triggers.
+
+    Two page shapes, both owned here. `pools[l]` is the tuple of one
+    attention layer's pools, every one indexed by page id on its first
+    axis: a (keys, values) pair of `[P, ps, K, D]`, or — a model with
+    latent attention (`cfg.latent`, models/mla.py) — ONE pool
+    `[P, ps, W]` whose entry a position is the normed c_kv and the roped
+    shared key part, padded to `cfg.page_width` (whole lane rows: the
+    kernels copy and multiply pages as they lie); there is no value
+    pool. Everything that addresses pages by id (slots, aliasing,
+    copy-on-write, the radix index, the offload tier) treats a layer's
+    pools as that tuple and never looks inside a page.
     """
 
     def __init__(self, cfg: ModelConfig, num_slots: int,
@@ -154,13 +165,16 @@ class PagedKVCache:
                 f"replica(s) cannot hold even one full sequence per "
                 f"replica ({self.pages_per_seq} pages + scratch)")
         if kv_quant is None:
-            shape = (self.num_pages, page_size, cfg.num_kv_heads,
-                     cfg.head_dim)
+            latent = cfg.latent
+            shape = ((self.num_pages, page_size, cfg.page_width) if latent
+                     else (self.num_pages, page_size, cfg.num_kv_heads,
+                           cfg.head_dim))
             make = (lambda: jnp.zeros(shape, dtype)) if sharding is None \
                 else (lambda: jax.device_put(jnp.zeros(shape, dtype),
                                              sharding))
             self._make_pools = lambda n_pages: [
-                (make(), make()) for _ in cfg.attention_layers]
+                tuple(make() for _ in range(1 if latent else 2))
+                for _ in cfg.attention_layers]
         else:
             qshape = (self.num_pages, page_size, cfg.num_kv_heads,
                       kv_quant.packed_dim(cfg.head_dim))
@@ -232,8 +246,8 @@ class PagedKVCache:
     def hbm_bytes(self) -> int:
         """Resident pool bytes across all layers — payload plus, on
         quantized pools, the per-cell scale arrays (ISSUE 11)."""
-        k, _ = self.pools[0]
-        total = 2 * k.size * k.dtype.itemsize * len(self.pools)
+        total = sum(p.size * p.dtype.itemsize
+                    for layer in self.pools for p in layer)
         if self.scales is not None:
             s, _ = self.scales[0]
             total += 2 * s.size * s.dtype.itemsize * len(self.scales)
@@ -369,8 +383,7 @@ class PagedKVCache:
         them (KVCache.revive_if_dead's paged counterpart). Every slot,
         page mapping and refcount is dropped — the bytes are gone — so
         later prefills start from scratch. Returns True iff revived."""
-        k, _ = self.pools[0]
-        if not k.is_deleted():
+        if not self.pools[0][0].is_deleted():
             return False
         self.pools = self._make_pools(self.num_pages)
         if self.scales is not None:

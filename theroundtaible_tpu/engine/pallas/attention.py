@@ -77,6 +77,24 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _pool_heads(pool) -> int:
+    """kv heads of a page pool [P, ps, K, D]. A LATENT pool is [P, ps, W]:
+    one head, whose values are the first `v_dim` columns of its keys
+    (absorbed multi-head latent attention — the paged kernels then take
+    `v_pool=None` and copy each page once)."""
+    return pool.shape[2] if pool.ndim == 4 else 1
+
+
+def _page_block(k_ref, v_ref, khi: int, v_dim: Optional[int]):
+    """(keys, values) of kv head `khi` from one page's block refs: a
+    latent page [1, ps, W] (`v_dim`; no v ref) is keys and, in its first
+    columns, values."""
+    if v_dim is None:
+        return k_ref[0, :, khi, :], v_ref[0, :, khi, :]
+    k = k_ref[0]
+    return k, k[:, :v_dim]
+
+
 def _pick_block(n: int, candidates: tuple[int, ...]) -> Optional[int]:
     for c in candidates:
         if n % c == 0:
@@ -296,12 +314,13 @@ def flash_prefill_attention(
 
 
 def _paged_prefill_kernel(table_ref, offs_ref, valid_ref, q_ref, k_ref,
-                          v_ref, *rest,
+                          *rest,
                           block_q: int, page_size: int,
                           num_page_blocks: int, kh: int, group: int,
                           sliding_window: Optional[int],
                           softcap: Optional[float],
-                          kv_bits: int = 8, quantized: bool = False):
+                          kv_bits: int = 8, quantized: bool = False,
+                          v_dim: Optional[int] = None):
     # Identical math to _prefill_kernel (shared _prefill_accumulate); the
     # paged differences: the kv block for grid step sb is pool page
     # table[b, sb], and ALL kv heads ride one (1, ps, K, D) block with a
@@ -312,6 +331,10 @@ def _paged_prefill_kernel(table_ref, offs_ref, valid_ref, q_ref, k_ref,
     # same either way (each page read once with every head). Quantized
     # pools (ISSUE 11) ride two extra per-page scale blocks whose index
     # map is the kv block's, dequantized inside _prefill_accumulate.
+    # A latent pool (`v_dim`, see _pool_heads) has no v operand.
+    v_ref = None
+    if v_dim is None:
+        v_ref, *rest = rest
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -336,9 +359,10 @@ def _paged_prefill_kernel(table_ref, offs_ref, valid_ref, q_ref, k_ref,
     @pl.when((sb >= lo) & (sb <= hi))
     def _compute():
         for khi in range(kh):
+            k, v = _page_block(k_ref, v_ref, khi, v_dim)
             m_scr[khi], l_scr[khi], acc_scr[khi] = _prefill_accumulate(
                 q_ref[0, khi].reshape(group * block_q, -1),
-                k_ref[0, :, khi, :], v_ref[0, :, khi, :], q_start,
+                k, v, q_start,
                 sb * page_size, valid,
                 (m_scr[khi], l_scr[khi], acc_scr[khi]), group=group,
                 block_q=block_q, block_kv=page_size,
@@ -386,7 +410,7 @@ def paged_pool_direct_supported(chunk: int, page_size: int, d: int,
 def paged_prefill_attention(
     q: jax.Array,                 # [B, T, H, D] (pre-scaled, rope'd)
     k_pool: jax.Array,            # [P, page_size, K, D] page pool
-    v_pool: jax.Array,            # [P, page_size, K, D]
+    v_pool: Optional[jax.Array],  # [P, page_size, K, D]; None: latent
     table: jax.Array,             # [B, pages_per_seq] int32 page table
     offsets: jax.Array,           # [B] absolute position of q row start
     kv_valid: jax.Array,          # [B] valid cache entries per row
@@ -397,6 +421,7 @@ def paged_prefill_attention(
     k_scale: Optional[jax.Array] = None,   # [P, ps, K, G] (ISSUE 11)
     v_scale: Optional[jax.Array] = None,
     kv_bits: int = 8,
+    v_dim: Optional[int] = None,  # latent pool: values = keys[..., :v_dim]
 ) -> jax.Array:
     """Blockwise causal prefill attention straight off the page pool.
 
@@ -410,9 +435,15 @@ def paged_prefill_attention(
     `k_scale`/`v_scale` (ISSUE 11): the pool holds quantized pages —
     int8 payload (int4: D/2 packed nibbles when kv_bits=4) with
     per-cell scales; the scale blocks ride the SAME page index map as
-    the kv blocks and dequant happens in-kernel."""
+    the kv blocks and dequant happens in-kernel.
+
+    `v_pool=None` with `v_dim` (a latent pool [P, ps, W], _pool_heads):
+    one kv head whose values are the first `v_dim` columns of its keys;
+    each page is copied once and the result is [B, T, H, v_dim]."""
     b, t, h, d = q.shape
-    page_size, kh = k_pool.shape[1], k_pool.shape[2]
+    page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
+    latent = v_pool is None
+    dv = v_dim if latent else d
     group = h // kh
     pages_per_seq = table.shape[1]
     quantized = k_scale is not None
@@ -429,16 +460,19 @@ def paged_prefill_attention(
         lo_blk, hi_blk = _prefill_blk_bounds(
             q_start, valid_ref[bi], block_q, page_size, sliding_window)
         sb = jnp.clip(sb, lo_blk, jnp.maximum(hi_blk, 0))
-        return (table_ref[bi, sb], 0, 0, 0)
+        return (table_ref[bi, sb],) + (0,) * (k_pool.ndim - 1)
 
     in_specs = [
         pl.BlockSpec((1, kh, group, block_q, d),
                      lambda bi, tb, sb, t_, o_, v_:
                      (bi, 0, 0, tb, 0)),
-        pl.BlockSpec((1, page_size, kh, k_pool.shape[-1]), kv_index),
-        pl.BlockSpec((1, page_size, kh, v_pool.shape[-1]), kv_index),
+        pl.BlockSpec((1,) + k_pool.shape[1:], kv_index),
     ]
-    operands = [qt, k_pool, v_pool]
+    operands = [qt, k_pool]
+    if not latent:
+        in_specs.append(
+            pl.BlockSpec((1, page_size, kh, v_pool.shape[-1]), kv_index))
+        operands.append(v_pool)
     if quantized:
         in_specs += [
             pl.BlockSpec((1, page_size, kh, k_scale.shape[-1]), kv_index),
@@ -450,28 +484,29 @@ def paged_prefill_attention(
         grid=(b, t // block_q, pages_per_seq),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, kh, group, block_q, d),
+            (1, kh, group, block_q, dv),
             lambda bi, tb, sb, t_, o_, v_: (bi, 0, 0, tb, 0)),
         scratch_shapes=[
             pltpu.VMEM((kh, group * block_q, _LANES), jnp.float32),
             pltpu.VMEM((kh, group * block_q, _LANES), jnp.float32),
-            pltpu.VMEM((kh, group * block_q, d), jnp.float32),
+            pltpu.VMEM((kh, group * block_q, dv), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_prefill_kernel, block_q=block_q, page_size=page_size,
         num_page_blocks=pages_per_seq, kh=kh, group=group,
         sliding_window=sliding_window, softcap=softcap,
-        kv_bits=kv_bits, quantized=quantized)
+        kv_bits=kv_bits, quantized=quantized,
+        v_dim=v_dim if latent else None)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape[:-1] + (dv,), q.dtype),
         interpret=interpret,
-        name="paged_prefill_attention",
+        name="mla_paged_prefill" if latent else "paged_prefill_attention",
     )(table.astype(jnp.int32), offsets.astype(jnp.int32),
       kv_valid.astype(jnp.int32), *operands)
-    return out.reshape(b, kh * group, t, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, kh * group, t, dv).transpose(0, 2, 1, 3)
 
 
 def paged_prefill_spmd(
@@ -808,13 +843,14 @@ def _page_rows(shape, axis: int, *, page_size: int, kh: int,
 
 
 def _walk_page_bytes(page_size: int, kh: int, dk: int, itemsize: int,
-                     scale_groups: int) -> int:
+                     scale_groups: int, latent: bool = False) -> int:
     """Bytes of one page's keys AND values as VMEM holds them: rows of
-    `dk` cells, lane-padded; the f32 scales of a page are K*G rows (a
-    whole sublane tile at least) of ps lanes."""
+    `dk` cells, lane-padded (a latent page is both at once); the f32
+    scales of a page are K*G rows (a whole sublane tile at least) of ps
+    lanes."""
     rows = page_size * kh
     lanes = -(-dk // _LANES) * _LANES
-    page = 2 * rows * lanes * itemsize
+    page = (1 if latent else 2) * rows * lanes * itemsize
     if scale_groups:
         page += 2 * (-(-kh * scale_groups // 8) * 8) * (
             -(-page_size // _LANES) * _LANES) * 4
@@ -822,11 +858,12 @@ def _walk_page_bytes(page_size: int, kh: int, dk: int, itemsize: int,
 
 
 def _walk_vmem_est(n: int, page_size: int, d: int, kh: int, group: int,
-                   dk: int, itemsize: int, scale_groups: int) -> int:
+                   dk: int, itemsize: int, scale_groups: int,
+                   latent: bool = False) -> int:
     cols = n * page_size * kh
     hq = -(-kh * group // 8) * 8
     bufs = 2 * n * _walk_page_bytes(page_size, kh, dk, itemsize,
-                                    scale_groups)      # two slots
+                                    scale_groups, latent)  # two slots
     # q and out blocks of a row block, double-buffered by the pipeline
     q_out = 2 * 2 * _WALK_ROW_BLOCK * hq * d * 2
     scores = 4 * hq * cols * 4       # row index, s, p, the mask's temps
@@ -841,18 +878,20 @@ def _walk_vmem_est(n: int, page_size: int, d: int, kh: int, group: int,
 
 def _walk_pages(page_size: int, d: int, kh: int, group: int,
                 dk: Optional[int] = None, itemsize: int = 2,
-                scale_groups: int = 0) -> Optional[int]:
+                scale_groups: int = 0, latent: bool = False
+                ) -> Optional[int]:
     """Pages a trip of the decode walk moves — from what the operands
     show: the page's stored bytes and the VMEM budget — or None when not
     even one page a trip fits."""
     dk = d if dk is None else dk
-    page = _walk_page_bytes(page_size, kh, dk, itemsize, scale_groups)
+    page = _walk_page_bytes(page_size, kh, dk, itemsize, scale_groups,
+                            latent)
     n = 1
     while n * page < _WALK_TRIP_BYTES:
         n *= 2
     est = functools.partial(_walk_vmem_est, page_size=page_size, d=d,
                             kh=kh, group=group, dk=dk, itemsize=itemsize,
-                            scale_groups=scale_groups)
+                            scale_groups=scale_groups, latent=latent)
     while n > 1 and est(n) > _VMEM_BUDGET:
         n //= 2
     return n if est(n) <= _VMEM_BUDGET else None
@@ -861,7 +900,8 @@ def _walk_pages(page_size: int, d: int, kh: int, group: int,
 def paged_decode_decline_reason(page_size: int, d: int, kh: int = 1,
                                 group: int = 1, *, itemsize: int = 2,
                                 scale_groups: int = 0,
-                                dk: Optional[int] = None) -> Optional[str]:
+                                dk: Optional[int] = None,
+                                latent: bool = False) -> Optional[str]:
     """Why paged_decode_attention cannot serve this pool shape, or None
     when it can. Pass the LOCAL kv-head count and GQA group; `itemsize`
     is a page cell's (2: bf16, 1: int8/int4 payloads, whose f32 scale
@@ -874,7 +914,7 @@ def paged_decode_decline_reason(page_size: int, d: int, kh: int = 1,
     if page_size not in (512, 256, 128, 64, 32, 16, 8):
         return f"page_size:{page_size}"
     if _walk_pages(page_size, d, kh, group, dk, itemsize,
-                   scale_groups) is None:
+                   scale_groups, latent) is None:
         return f"vmem:ps={page_size},d={d},kh={kh},g={group}"
     if _interpret():
         return None
@@ -930,30 +970,35 @@ def _paged_decode_kernel(table_ref, valid_ref, q_ref, *rest,
                          group: int, rows: int, token_major: bool,
                          sliding_window: Optional[int],
                          softcap: Optional[float],
-                         kv_bits: int = 8, quantized: bool = False):
+                         kv_bits: int = 8, quantized: bool = False,
+                         v_dim: Optional[int] = None):
     # See "the walk" above. valid INCLUDES the current step's entry,
     # which the caller has already written into the pool (q position =
     # valid - 1). Quantized pools (ISSUE 11): the two scale pools walk
     # with the pages and the rows dequantize inside _decode_accumulate.
     # `rest`: the pools in HBM (k, v and, if quantized, their scales),
     # the output, one two-slot VMEM buffer a pool, the position scratch,
-    # the scales' one-hot (quantized), the DMA semaphores.
-    n_pools = 4 if quantized else 2
+    # the scales' one-hot (quantized), the DMA semaphores. A latent
+    # pool (`v_dim`, see _pool_heads) is the ONE pool: a page is copied
+    # once and its first `v_dim` columns are the values.
+    n_kv = 2 if v_dim is None else 1
+    n_pools = n_kv + (2 if quantized else 0)
     hbms, o_ref = rest[:n_pools], rest[n_pools]
     bufs, sem = rest[n_pools + 1:2 * n_pools + 1], rest[-1]
     pos_scr = rest[2 * n_pools + 1]
-    kbuf, vbuf = bufs[:2]
-    block, hq, d = q_ref.shape
+    kbuf, vbuf = bufs[0], bufs[n_kv - 1]
+    block, hq, _ = q_ref.shape
+    d = o_ref.shape[-1]
     pr = page_size * kh                 # rows of one flattened page
     cols = n * pr
     first_row = pl.program_id(0) * block
     layout = dict(page_size=page_size, kh=kh, token_major=token_major)
     # (pool in HBM, its two-slot buffer, where page j of a trip lands)
     lanes = [(hbm.reshape(hbm.shape[0], pr, hbm.shape[-1]), buf,
-              lambda j: pl.ds(j * pr, pr)) for hbm, buf in zip(hbms[:2],
-                                                               bufs[:2])]
-    lanes += [(hbm, buf, lambda j: j) for hbm, buf in zip(hbms[2:],
-                                                         bufs[2:])]
+              lambda j: pl.ds(j * pr, pr)) for hbm, buf in zip(hbms[:n_kv],
+                                                               bufs[:n_kv])]
+    lanes += [(hbm, buf, lambda j: j) for hbm, buf in zip(hbms[n_kv:],
+                                                         bufs[n_kv:])]
 
     @pl.when(pl.program_id(0) == 0)
     def _():
@@ -1038,8 +1083,10 @@ def _paged_decode_kernel(table_ref, valid_ref, q_ref, *rest,
                         buf[slot], rest[-2][...], first_pos, valid,
                         **layout)
                     for name, buf in zip(("k_scale", "v_scale"), bufs[2:])}
+            keys = kbuf[slot]
+            vals = vbuf[slot] if v_dim is None else keys[:, :v_dim]
             return _decode_accumulate(
-                q, kbuf[slot], vbuf[slot], 0, valid, state, group=hq,
+                q, keys, vals, 0, valid, state, group=hq,
                 block_kv=cols, sliding_window=sliding_window,
                 softcap=softcap, kv_bits=kv_bits,
                 kv_pos=first_pos + pos_scr[...], **scales)
@@ -1143,7 +1190,7 @@ def paged_decode_spmd(
 def paged_decode_attention(
     q: jax.Array,                 # [B, 1, H, D] this step's query
     k_pool: jax.Array,            # [P, page_size, K, D] page pool
-    v_pool: jax.Array,            # [P, page_size, K, D]
+    v_pool: Optional[jax.Array],  # [P, page_size, K, D]; None: latent
     table: jax.Array,             # [B, pages_per_seq] int32 page table
     kv_valid: jax.Array,          # [B] valid entries INCLUDING this step
     *,
@@ -1153,6 +1200,7 @@ def paged_decode_attention(
     k_scale: Optional[jax.Array] = None,   # [P, ps, K, G] (ISSUE 11)
     v_scale: Optional[jax.Array] = None,
     kv_bits: int = 8,
+    v_dim: Optional[int] = None,  # latent pool: values = keys[..., :v_dim]
 ) -> jax.Array:
     """Single-position decode attention straight off the page pool.
 
@@ -1167,15 +1215,20 @@ def paged_decode_attention(
     call as it is. A row with nothing valid costs no trip and yields
     zeros. Returns [B, 1, H, D]. `k_scale`/`v_scale` (ISSUE 11):
     quantized pools dequantize in-kernel — the scale pools walk with
-    the pages.
+    the pages. `v_pool=None` with `v_dim`: a latent pool [P, ps, W]
+    (_pool_heads) — one pool walks, each page is copied once, and the
+    result is [B, 1, H, v_dim].
     """
     b, t, h, d = q.shape
     assert t == 1, "decode kernel serves exactly one position"
-    page_size, kh = k_pool.shape[1], k_pool.shape[2]
+    page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
+    latent = v_pool is None
+    dv = v_dim if latent else d
     group = h // kh
     quantized = k_scale is not None
     shape = dict(dk=k_pool.shape[-1], itemsize=k_pool.dtype.itemsize,
-                 scale_groups=k_scale.shape[-1] if quantized else 0)
+                 scale_groups=k_scale.shape[-1] if quantized else 0,
+                 latent=latent)
     reason = paged_decode_decline_reason(page_size, d, kh, group, **shape)
     if reason is not None:
         raise ValueError(f"unsupported pool shape: {reason}")
@@ -1185,12 +1238,12 @@ def paged_decode_attention(
     block = min(b, _WALK_ROW_BLOCK)
 
     token_major = _token_major(kh, k_pool.dtype.itemsize)
-    if not token_major:
+    pools = [k_pool] if latent else [k_pool, v_pool]
+    if not token_major and not latent:
         # the pool as XLA stores it, head-major: see "the walk" above
-        k_pool, v_pool = (p.swapaxes(1, 2).reshape(
-            p.shape[0], kh * page_size, p.shape[-1])
-            for p in (k_pool, v_pool))
-    pools = [k_pool, v_pool]
+        pools = [p.swapaxes(1, 2).reshape(
+            p.shape[0], kh * page_size, p.shape[-1]) for p in pools]
+    n_kv = len(pools)
     bufs = [pltpu.VMEM((2, trip_rows, p.shape[-1]), p.dtype) for p in pools]
     consts = [pltpu.VMEM((h, trip_rows), jnp.int32)]
     if quantized:
@@ -1198,14 +1251,18 @@ def paged_decode_attention(
         pools += [jnp.transpose(s, (0, 2, 3, 1)).reshape(
             s.shape[0], -1, page_size) for s in (k_scale, v_scale)]
         bufs += [pltpu.VMEM((2, n) + p.shape[1:], p.dtype)
-                 for p in pools[2:]]
+                 for p in pools[n_kv:]]
         consts += [pltpu.VMEM((page_size * kh, page_size), jnp.float32)]
-    rows_blk = pl.BlockSpec((block, h, d), lambda i, t_, v_: (i, 0, 0))
+
+    def rows_blk(width):
+        return pl.BlockSpec((block, h, width), lambda i, t_, v_: (i, 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(pl.cdiv(b, block),),
-        in_specs=[rows_blk] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=rows_blk,
+        in_specs=[rows_blk(d)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=rows_blk(dv),
         scratch_shapes=bufs + consts + [
             pltpu.SemaphoreType.DMA((len(bufs), 2))],
     )
@@ -1213,15 +1270,15 @@ def paged_decode_attention(
         _paged_decode_kernel, page_size=page_size, n=n, kh=kh,
         group=group, rows=b, token_major=token_major,
         sliding_window=sliding_window, softcap=softcap, kv_bits=kv_bits,
-        quantized=quantized)
+        quantized=quantized, v_dim=v_dim if latent else None)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         interpret=interpret,
-        name="paged_decode_attention",
+        name="mla_paged_decode" if latent else "paged_decode_attention",
     )(table.astype(jnp.int32), kv_valid.astype(jnp.int32), q[:, 0], *pools)
-    return out.reshape(b, 1, h, d)
+    return out.reshape(b, 1, h, dv)
 
 
 # --- ragged paged attention (ISSUE 8) ---
@@ -1365,11 +1422,12 @@ def kv_quant_kernel_supported(page_size: int, d: int, kh: int,
 
 
 def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
-                   q_ref, k_ref, v_ref, *rest,
+                   q_ref, k_ref, *rest,
                    page_size: int, num_page_blocks: int, kh: int,
                    group: int, sliding_window: Optional[int],
                    softcap: Optional[float],
-                   kv_bits: int = 8, quantized: bool = False):
+                   kv_bits: int = 8, quantized: bool = False,
+                   v_dim: Optional[int] = None):
     # Grid (q_blocks, pages_per_seq). Identical online-softmax math to
     # _paged_prefill_kernel (shared _prefill_accumulate, all kv heads on
     # one pool block with a static head loop — see _paged_prefill_kernel
@@ -1382,7 +1440,11 @@ def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
     # MASK_VALUE is a large finite negative, so even an all-masked row
     # exponentiates to finite junk) and the host drops their outputs.
     # Quantized pools (ISSUE 11): per-page scale blocks ride the kv
-    # index map, dequantized inside _prefill_accumulate.
+    # index map, dequantized inside _prefill_accumulate. A latent pool
+    # (`v_dim`, see _pool_heads) has no v operand.
+    v_ref = None
+    if v_dim is None:
+        v_ref, *rest = rest
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -1406,9 +1468,10 @@ def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
     @pl.when((sb >= lo) & (sb <= hi))
     def _compute():
         for khi in range(kh):
+            k, v = _page_block(k_ref, v_ref, khi, v_dim)
             m_scr[khi], l_scr[khi], acc_scr[khi] = _prefill_accumulate(
                 q_ref[khi].reshape(group * RAGGED_BLOCK_Q, -1),
-                k_ref[0, :, khi, :], v_ref[0, :, khi, :], q_start,
+                k, v, q_start,
                 sb * page_size, valid,
                 (m_scr[khi], l_scr[khi], acc_scr[khi]), group=group,
                 block_q=RAGGED_BLOCK_Q, block_kv=page_size,
@@ -1429,7 +1492,7 @@ def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
 def ragged_paged_attention(
     q: jax.Array,                 # [T, H, D] flat token buffer
     k_pool: jax.Array,            # [P, page_size, K, D] page pool
-    v_pool: jax.Array,            # [P, page_size, K, D]
+    v_pool: Optional[jax.Array],  # [P, page_size, K, D]; None: latent
     tables: jax.Array,            # [S, pages_per_seq] int32 page tables
     seq_of_block: jax.Array,      # [T/8] sequence id of each q block
     block_qstart: jax.Array,      # [T/8] block start row WITHIN its seq
@@ -1442,6 +1505,7 @@ def ragged_paged_attention(
     k_scale: Optional[jax.Array] = None,   # [P, ps, K, G] (ISSUE 11)
     v_scale: Optional[jax.Array] = None,
     kv_bits: int = 8,
+    v_dim: Optional[int] = None,  # latent pool: values = keys[..., :v_dim]
 ) -> jax.Array:
     """Mixed prefill/decode attention over a flat token buffer, straight
     off the page pool.
@@ -1455,10 +1519,13 @@ def ragged_paged_attention(
     compiled shape serves every prefill/decode composition of the same
     T — the no-recompile property the scheduler's ragged segments rely
     on. Returns [T, H, D] in q's dtype; pad-row outputs are garbage and
-    must be dropped by the caller.
+    must be dropped by the caller. `v_pool=None` with `v_dim`: a latent
+    pool (paged_prefill_attention), the result [T, H, v_dim].
     """
     t, h, d = q.shape
-    page_size, kh = k_pool.shape[1], k_pool.shape[2]
+    page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
+    latent = v_pool is None
+    dv = v_dim if latent else d
     group = h // kh
     pages_per_seq = tables.shape[1]
     quantized = k_scale is not None
@@ -1487,16 +1554,19 @@ def ragged_paged_attention(
             q_start, valid_ref[seq], RAGGED_BLOCK_Q, page_size,
             sliding_window)
         sb = jnp.clip(sb, lo_blk, jnp.maximum(hi_blk, 0))
-        return (table_ref[seq, sb], 0, 0, 0)
+        return (table_ref[seq, sb],) + (0,) * (k_pool.ndim - 1)
 
     in_specs = [
         pl.BlockSpec((kh, group, RAGGED_BLOCK_Q, d),
                      lambda qb, sb, t_, b_, s_, o_, v_:
                      (0, 0, qb, 0)),
-        pl.BlockSpec((1, page_size, kh, k_pool.shape[-1]), kv_index),
-        pl.BlockSpec((1, page_size, kh, v_pool.shape[-1]), kv_index),
+        pl.BlockSpec((1,) + k_pool.shape[1:], kv_index),
     ]
-    operands = [qt, k_pool, v_pool]
+    operands = [qt, k_pool]
+    if not latent:
+        in_specs.append(
+            pl.BlockSpec((1, page_size, kh, v_pool.shape[-1]), kv_index))
+        operands.append(v_pool)
     if quantized:
         in_specs += [
             pl.BlockSpec((1, page_size, kh, k_scale.shape[-1]), kv_index),
@@ -1508,29 +1578,30 @@ def ragged_paged_attention(
         grid=(num_blocks, pages_per_seq),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (kh, group, RAGGED_BLOCK_Q, d),
+            (kh, group, RAGGED_BLOCK_Q, dv),
             lambda qb, sb, t_, b_, s_, o_, v_: (0, 0, qb, 0)),
         scratch_shapes=[
             pltpu.VMEM((kh, group * RAGGED_BLOCK_Q, _LANES), jnp.float32),
             pltpu.VMEM((kh, group * RAGGED_BLOCK_Q, _LANES), jnp.float32),
-            pltpu.VMEM((kh, group * RAGGED_BLOCK_Q, d), jnp.float32),
+            pltpu.VMEM((kh, group * RAGGED_BLOCK_Q, dv), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _ragged_kernel, page_size=page_size,
         num_page_blocks=pages_per_seq, kh=kh, group=group,
         sliding_window=sliding_window, softcap=softcap,
-        kv_bits=kv_bits, quantized=quantized)
+        kv_bits=kv_bits, quantized=quantized,
+        v_dim=v_dim if latent else None)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape[:-1] + (dv,), q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention",
+        name="mla_ragged" if latent else "ragged_paged_attention",
     )(tables.astype(jnp.int32), seq_of_block.astype(jnp.int32),
       block_qstart.astype(jnp.int32), query_offsets.astype(jnp.int32),
       kv_valid.astype(jnp.int32), *operands)
-    return out.transpose(2, 0, 1, 3).reshape(t, h, d)
+    return out.transpose(2, 0, 1, 3).reshape(t, h, dv)
 
 
 def ragged_paged_spmd(

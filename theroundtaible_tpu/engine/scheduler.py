@@ -1585,7 +1585,8 @@ class SessionScheduler:
             seg.leave()
             steps = self._fold_segment(ctx, arrays)
             self._end_segment(seg, steps, steps * len(alive),
-                              in_flight=int(spec_handles is not None))
+                              in_flight=int(spec_handles is not None),
+                              read_to=tuple(r.valid for r in alive))
             now = time.monotonic()
             self._attribute_wall(counts, now - t_prev)
             # Per-phase token split (ISSUE 8): a while-loop segment is
@@ -1634,11 +1635,20 @@ class SessionScheduler:
 
     def _end_segment(self, seg, steps: int, decode_tokens: int,
                      prefill_tokens: int = 0, drafted: int = 0,
-                     accepted: int = 0, in_flight: int = 0) -> None:
+                     accepted: int = 0, in_flight: int = 0,
+                     read_to: tuple = ()) -> None:
         """Emit a segment span with the counts its fold produced, and
         the pool's pages in use at its end (the pool's peak over any
         stretch is the maximum over that stretch's segment spans).
-        `in_flight`: segments issued after this one and not yet read."""
+        `in_flight`: segments issued after this one and not yet read.
+        `read_to`: how many positions each row's attention read at the
+        segment's last step (a model with latent pages counts them:
+        `latent_positions`, every step's reads of every row)."""
+        latent = None
+        if getattr(self.engine.cfg, "latent", False):
+            latent = sum(steps * v - steps * (steps - 1) // 2
+                         for v in read_to)
+            self.engine.note_latent_positions(latent)
         hy = getattr(self.engine, "hybrid", None)
         if hy is not None:
             # This segment has been read, so every program up to it has
@@ -1651,6 +1661,8 @@ class SessionScheduler:
         seg.attrs.update(steps=steps, decode_tokens=decode_tokens,
                          prefill_tokens=prefill_tokens, drafted=drafted,
                          accepted=accepted)
+        if latent is not None:
+            seg.attrs["latent_positions"] = latent
         if self.engine.kv_layout == "paged":
             seg.attrs["pages_in_use"] = self.engine.kv.pages_in_use()
         if hy is not None:
@@ -1884,7 +1896,10 @@ class SessionScheduler:
         telemetry.inc("roundtable_sched_ragged_segments_total",
                       engine=self._tname)
         self._note_segment_tokens(n_prefill, n_decode)
-        self._end_segment(seg, 1, n_decode, n_prefill)
+        self._end_segment(seg, 1, n_decode, n_prefill,
+                          read_to=tuple(r.pos if kind != "decode"
+                                        else r.valid
+                                        for kind, r, _take in rows_in))
         occ = len(seqs)
         self.max_occupancy = max(self.max_occupancy, occ)
         with self._cv:

@@ -227,8 +227,7 @@ def kv_bytes_per_token(cfg: Any, dtype_bytes: int = 2,
     if quant_spec is not None:
         from ..engine.kv_quant import cell_bytes_per_token
         return int(cell_bytes_per_token(cfg, quant_spec, dtype_bytes))
-    return int(len(cfg.attention_layers) * 2 * cfg.num_kv_heads
-               * cfg.head_dim * dtype_bytes)
+    return int(len(cfg.attention_layers) * cfg.page_cells * dtype_bytes)
 
 
 # --- gauge-publication counter (tests/conftest.py `perf_obs` guard) ---

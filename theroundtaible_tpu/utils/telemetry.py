@@ -1112,6 +1112,28 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "local_assignments": "roundtable_moe_local_assignments_total",
         "expert_layer_steps": "roundtable_moe_expert_layer_steps_total",
     },
+    # engine.describe()["mla"] (ISSUE 31): latent pages — the second
+    # page shape (engine/paging.py) — and the kernels that read them.
+    # Static but for the positions read, which the scheduler's segment
+    # fold counts (engine.note_latent_positions is the one writer of the
+    # total and the series; the segment span carries its own share).
+    "engine_mla": {
+        "pool_shape": "static (one pool a layer: [P, ps, page_width])",
+        "pools_per_layer": "static (1: no value pool)",
+        "layers": "static (attention layers)",
+        "entry_width": "static (kv_lora_rank + qk_rope_head_dim)",
+        "page_width": "static (entry_width in whole lane rows)",
+        "bytes_per_position_published": "static (entry_width cells)",
+        "bytes_per_position_stored": "static (page_width cells)",
+        "form": "static (absorbed wherever pages are read)",
+        "prologue_form": "static (absorbed)",
+        "paged_decode": "static (the kernel's name, or gather-view)",
+        "paged_prefill": "static (the kernel's name, or gather-view)",
+        "ragged": "static (the kernel's name, or the fallback path)",
+        "decode_decline": "static (paged_decode_decline_reason)",
+        "ragged_decline": "static (engine.ragged_fallback_reason)",
+        "latent_positions": "roundtable_mla_latent_positions_total",
+    },
     # Gateway.describe() (ISSUE 16): the HTTP front door's admission /
     # shed / stream provenance — counters move in lockstep with the
     # registry series (AdmissionController._count is the one writer).
