@@ -445,6 +445,114 @@ def test_latent_kernel_compiles_for_v5e(one_chip, monkeypatch, kernel):
     assert call.count("bf16[640,128,640]") == 1
 
 
+# --- the ragged walk at the three cells' widths (ISSUE 32) -------------------
+
+CELL_POOL = 640              # the cells' pool, in pages
+WALK_WIDTHS = {"mistral-7b": (32, 8), "nemotron-3-nano": (32, 2),
+               "a.x-k1": (LATENT_HEADS, 1)}
+WALK_CASES = [(w, t, kv) for w in WALK_WIDTHS for t in (1024, 256)
+              for kv in ("bf16", "int8")
+              if not (w == "a.x-k1" and kv == "int8")]  # declines: S1b
+
+
+def _walk_case(widths: str, t: int, kv: str, one_chip):
+    """(fn, shapes) of the ragged kernel at one cell's widths and one
+    of its two flat-buffer shapes, seventeen page tables (16 slots and
+    the inert sequence)."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    h, kh = WALK_WIDTHS[widths]
+    blocks, seqs = t // pattn.RAGGED_BLOCK_Q, DECODE_ROWS + 1
+    meta = (s((seqs, PAGES_PER_SEQ), i32), s((blocks,), i32),
+            s((blocks,), i32), s((seqs,), i32), s((seqs,), i32))
+    if widths == "a.x-k1":
+        def fn(q, pool, *meta):
+            return pattn.ragged_paged_attention(
+                q, pool, None, *meta, v_dim=LATENT_V, interpret=False)
+        return fn, (s((t, h, LATENT_W), jnp.bfloat16),
+                    s((CELL_POOL, PAGE, LATENT_W), jnp.bfloat16)) + meta
+    pool, scale, bits = (
+        a if a is None or isinstance(a, int) else
+        s((CELL_POOL,) + a.shape[1:], a.dtype)
+        for a in _pool_shapes(kh, kv, one_chip))
+    scales = () if scale is None else (scale, scale)
+
+    def fn(q, k, v, *rest):
+        kw = dict(zip(("k_scale", "v_scale"), rest[5:]))
+        return pattn.ragged_paged_attention(
+            q, k, v, *rest[:5], interpret=False, kv_bits=bits, **kw)
+    return fn, (s((t, h, D), jnp.bfloat16), pool, pool) + meta + scales
+
+
+@pytest.mark.parametrize("widths,t,kv", WALK_CASES)
+def test_ragged_kernel_compiles_at_the_cells_widths(one_chip, widths, t,
+                                                    kv):
+    """Both flat-buffer shapes of all three cells, bf16 and int8 pages.
+    A bf16 pool is an operand of the call as the cell holds it — the
+    benchmark's readers find the attention kernels by that operand —
+    and is not copied on the way in."""
+    import re
+    fn, shapes = _walk_case(widths, t, kv, one_chip)
+    hlo = _compile(fn, *shapes)
+    _assert_kernel(hlo)
+    call = next(line for line in hlo.splitlines()
+                if "tpu_custom_call" in line)
+    if kv == "int8":
+        return
+    h, kh = WALK_WIDTHS[widths]
+    pool = (f"bf16[{CELL_POOL},{PAGE},{LATENT_W}]" if widths == "a.x-k1"
+            else f"bf16[{CELL_POOL},{PAGE},{kh},{D}]")
+    assert call.count(pool) == (1 if widths == "a.x-k1" else 2)
+    assert f"%{'mla_ragged' if widths == 'a.x-k1' else 'ragged_paged_attention'}" \
+        in hlo
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if re.search(rf"= \w+\[{CELL_POOL},[0-9,]+\]\S* copy(-start)?\(",
+                           line)]
+    assert not copies, copies
+
+
+def test_ragged_gate_plans_what_the_compiler_takes(one_chip, monkeypatch):
+    """`_ragged_block_q` against the compiler itself. The estimate is
+    the walk's own: the block's float32 state, its q and out blocks
+    twice, three times a trip's four pages; the call asks the compiler
+    for `_RAGGED_VMEM_LIMIT` and the estimate is held to two thirds of
+    it. At 32 heads x 128 it plans the largest block, 128 rows; a
+    latent block of 128 rows (64 heads x 640: q alone is 10 MiB, twice,
+    the state 24) is over, the estimate plans 64 — and a plan of 128
+    past the estimate is what the compiler refuses."""
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    gqa = dict(dk=D, dv=D)
+    lat = dict(dk=LATENT_W, dv=LATENT_V, latent=True)
+    assert pattn._ragged_block_q(1024, PAGE, D, 8, 4, **gqa) == 128
+    assert pattn._ragged_block_q(1024, PAGE, D, 2, 16, **gqa) == 128
+    assert pattn._ragged_block_q(1024, PAGE, LATENT_W, 1, LATENT_HEADS,
+                                 **lat) == 64
+    assert pattn._ragged_trip_pages(PAGE) == 4
+    est = pattn._ragged_vmem_est(128, PAGE, D, 8, 4, **gqa)
+    rows = 32 * 128
+    assert est == (rows * (2 * 128 + D) * 4 + 2 * rows * 2 * D * 2
+                   + 3 * 4 * 2 * PAGE * 8 * D * 2)
+    assert est <= pattn._RAGGED_VMEM_BUDGET < pattn._RAGGED_VMEM_LIMIT
+    assert pattn._ragged_vmem_est(
+        128, PAGE, LATENT_W, 1, LATENT_HEADS, **lat) \
+        > pattn._RAGGED_VMEM_BUDGET
+    assert pattn.ragged_decline_reason(
+        PAGE, LATENT_W, 1, LATENT_HEADS, **lat) is None
+    # what does not fit the packing's own 8 rows declines, by the same
+    # estimate: 4096 q heads of 128
+    assert pattn.ragged_decline_reason(PAGE, D, 8, 512).startswith(
+        "vmem:")
+    with monkeypatch.context() as m:
+        m.setattr(pattn, "_ragged_vmem_est", lambda *a, **k: 0)
+        pattn._ragged_walk.clear_cache()    # (a jit: it keeps its plans)
+        fn, shapes = _walk_case("a.x-k1", 1024, "bf16", one_chip)
+        with pytest.raises(Exception, match="exceeded scoped vmem"):
+            _compile(fn, *shapes)
+    pattn._ragged_walk.clear_cache()
+    fn, shapes = _walk_case("a.x-k1", 1024, "bf16", one_chip)
+    _assert_kernel(_compile(fn, *shapes))
+
+
 @pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
 def test_hybrid_step_of_the_axk1_cut_compiles(one_chip, monkeypatch,
                                               program):

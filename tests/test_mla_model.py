@@ -280,6 +280,50 @@ def test_the_ragged_kernel_reads_a_latent_pool(latent_pool):
     assert np.abs(np.asarray(got[24:25]) - np.asarray(step)).max() < 1e-5
 
 
+@pytest.mark.parametrize("t,runs", [
+    # a join longer than the walk's query block beside a decode row
+    # and a verify tile; the join ends one past a page boundary
+    (256, [(129, 16), (1, 90), (4, 60)]),
+    # a follower and a short run share a tile of products; contexts
+    # end on a page boundary and one before it
+    (128, [(44, 20), (9, 6), (7, 9)]),
+])
+def test_the_ragged_walk_reads_a_latent_pool(t, runs):
+    """Runs of every kind in one flat buffer over a latent pool, every
+    real row against the dense absorbed form (ISSUE 32: the kernel's
+    query block is not the packing's 8)."""
+    from theroundtaible_tpu.engine.serving_loop import (
+        RaggedSeq, build_ragged_batch)
+    rng = np.random.RandomState(11)
+    seqs, page = [], 1
+    for n, pos in runs:
+        table = np.zeros(12, np.int32)
+        need = -(-(pos + n) // PS)
+        table[:need] = np.arange(page, page + need)
+        page += need
+        seqs.append(RaggedSeq([1] * n, pos, table))
+    b = build_ragged_batch(seqs, t_budget=t, s_max=4,
+                           pages_per_seq=12, scratch_page=0, pad_id=0,
+                           page_size=PS)
+    pool = np.zeros((page, PS, W), np.float32)
+    pool[1:, :, :40] = rng.randn(page - 1, PS, 40)
+    pool = jnp.asarray(pool)
+    q = queries(4, t, HEADS)
+    got = pattn.ragged_paged_attention(
+        q, pool, None, *(jnp.asarray(b[k]) for k in (
+            "tables", "seq_of_block", "block_qstart", "query_offsets",
+            "kv_valid")), v_dim=DV, interpret=True)
+    assert got.shape == (t, HEADS, DV)
+    row = 0
+    for i, (n, pos) in enumerate(runs):
+        want = dense_absorbed(
+            q[row:row + n], entries_of(pool, b["tables"][i], pos + n), DV,
+            pos + jnp.arange(n))
+        assert np.abs(np.asarray(got[row:row + n])
+                      - np.asarray(want)).max() < 1e-5, (n, pos)
+        row += -(-n // 8) * 8
+
+
 def test_the_latent_kernels_carry_their_names(latent_pool):
     """The trace finds them by name, and a latent call has one pool
     among its operands."""

@@ -240,6 +240,215 @@ class TestRaggedKernel:
         np.testing.assert_allclose(out[chunk_t], ref_dec,
                                    atol=1e-5, rtol=1e-5)
 
+    # -- the walk's freedom (ISSUE 32): the kernel's query block is not
+    # the packing's 8, a run's pages are walked once a block, a tile of
+    # products can overlap a neighbour's rows ------------------------
+
+    WALK_PS = 16
+    # (tokens, first position): runs of 1, 7, 8, 9, 44, 129, 240 and
+    # 1000 rows; contexts ending on a page boundary (7 + 9 = 16,
+    # 44 + 20 = 64), one before it (8 + 23 = 31, 240 + 15 = 255) and
+    # one after (9 + 8 = 17, 129 + 0 = 129); decode rows deep in their
+    # context; two verify tiles (four rows scored)
+    WALK_RUNS = [(1, 0), (7, 9), (8, 23), (9, 8), (44, 20), (129, 0),
+                 (240, 15), (1, 300), (1000, 40), (1, 47), (4, 77),
+                 (4, 63)]
+    WALK_CASES = {
+        # kh, group, d, and what else the call is given
+        "8-kv-heads": dict(kh=8, group=2, d=16),
+        "2-kv-heads": dict(kh=2, group=4, d=32),
+        "head-major-3": dict(kh=3, group=2, d=32),
+        "head-major-1": dict(kh=1, group=4, d=32),
+        "latent-512": dict(kh=1, group=2, d=640, v_dim=512),
+        "window": dict(kh=2, group=2, d=32, sliding_window=24),
+        "softcap": dict(kh=2, group=2, d=32, softcap=30.0),
+        "int8-pool": dict(kh=2, group=2, d=32, int8=True),
+        # bfloat16 pages are copied as the 32-bit words their pairs of
+        # heads are stored in, and parted by shifts
+        "bf16-8-kv-heads": dict(kh=8, group=2, d=32, bf16=True),
+        "bf16-2-kv-heads": dict(kh=2, group=4, d=32, bf16=True),
+    }
+
+    def _walk_batch(self, runs, t_budget, s_max):
+        ps = self.WALK_PS
+        pps = 80
+        seqs, page = [], 1
+        for n, pos in runs:
+            need = -(-(pos + n) // ps)
+            table = np.zeros(pps, np.int32)
+            table[:need] = np.arange(page, page + need)
+            page += need
+            seqs.append(RaggedSeq(list(range(1, n + 1)), pos, table,
+                                  n_scores=4 if n == 4 else 1))
+        batch = build_ragged_batch(
+            seqs, t_budget=t_budget, s_max=s_max,
+            pages_per_seq=pps, scratch_page=0, pad_id=0, page_size=ps,
+            score_width=4)
+        return batch, page
+
+    def _walk_call(self, batch, q, pools, case, scales=(None, None)):
+        meta = [jnp.asarray(batch[k]) for k in (
+            "tables", "seq_of_block", "block_qstart", "query_offsets",
+            "kv_valid")]
+        kw = {k: case[k] for k in ("sliding_window", "softcap", "v_dim")
+              if k in case}
+        return np.asarray(pattn.ragged_paged_attention(
+            q, pools[0], pools[1], *meta, k_scale=scales[0],
+            v_scale=scales[1], **kw))
+
+    @staticmethod
+    def _dense(q, keys, vals, pos, case):
+        """Dense float32 attention of q rows [n, H, D] at positions
+        `pos` over a sequence's keys / values [L, K, *]."""
+        kh = keys.shape[1]
+        group = q.shape[1] // kh
+        s = np.einsum("nkgd,lkd->nkgl",
+                      q.reshape(len(q), kh, group, -1), keys)
+        if case.get("softcap"):
+            s = case["softcap"] * np.tanh(s / case["softcap"])
+        lpos = np.arange(keys.shape[0])
+        mask = lpos[None] <= pos[:, None]
+        if case.get("sliding_window"):
+            mask &= lpos[None] > pos[:, None] - case["sliding_window"]
+        s = np.where(mask[:, None, None], s, -np.inf)
+        w = np.exp(s - s.max(-1, keepdims=True))
+        w /= w.sum(-1, keepdims=True)
+        out = np.einsum("nkgl,lkd->nkgd", w, vals)
+        return out.reshape(len(q), kh * group, -1)
+
+    @pytest.mark.ragged_attn
+    @pytest.mark.parametrize("name", list(WALK_CASES))
+    def test_walk_matches_dense_reference(self, name):
+        """Runs of every length in ONE buffer, beside decode rows,
+        verify tiles and inert pad tiles, against a dense float32
+        softmax over each sequence's own pages, every real row."""
+        case = self.WALK_CASES[name]
+        kh, group, d = case["kh"], case["group"], case["d"]
+        latent = "v_dim" in case
+        rng = np.random.default_rng(32)
+        batch, pages = self._walk_batch(self.WALK_RUNS, 1664, 13)
+        assert batch["n_tokens"] == sum(n for n, _ in self.WALK_RUNS)
+        shape = (pages, self.WALK_PS) + (() if latent else (kh,)) + (d,)
+        kpool = rng.standard_normal(shape).astype(np.float32)
+        vpool = None if latent else \
+            rng.standard_normal(shape).astype(np.float32)
+        q = jnp.asarray(
+            rng.standard_normal((1664, kh * group, d)) * d ** -0.5,
+            jnp.float32)
+        pools, scales = (kpool, vpool), (None, None)
+        if case.get("bf16"):
+            # the reference reads the SAME rounded values
+            q = q.astype(jnp.bfloat16)
+            pools = tuple(jnp.asarray(p, jnp.bfloat16) for p in pools)
+            kpool, vpool = (np.asarray(p.astype(jnp.float32))
+                            for p in pools)
+        if case.get("int8"):
+            from theroundtaible_tpu.engine import kv_quant
+            spec = kv_quant.KVQuantSpec(bits=8)
+            (kq, ks), (vq, vs) = (kv_quant.quantize_cells(
+                jnp.asarray(p), spec) for p in pools)
+            pools, scales = (kq, vq), (ks, vs)
+            # the reference reads the SAME dequantized values
+            kpool, vpool = (np.asarray(kv_quant.dequantize_cells(
+                a, b, spec, jnp.float32)) for a, b in ((kq, ks), (vq, vs)))
+        out = self._walk_call(
+            batch, q, [None if p is None else jnp.asarray(p)
+                       for p in pools], case, scales)
+        assert out.shape == (1664, kh * group, case.get("v_dim", d))
+        assert np.isfinite(np.asarray(out, np.float32)).all()   # pads too
+        row = 0
+        for i, (n, pos) in enumerate(self.WALK_RUNS):
+            table, length = batch["tables"][i], pos + n
+            keys = kpool[table].reshape((-1,) + kpool.shape[2:])[:length]
+            if latent:
+                keys = keys[:, None]
+                vals = keys[..., :case["v_dim"]]
+            else:
+                vals = vpool[table].reshape(
+                    (-1,) + vpool.shape[2:])[:length]
+            want = self._dense(
+                np.asarray(q.astype(jnp.float32))[row:row + n], keys,
+                vals, pos + np.arange(n), case)
+            tol = 2e-2 if case.get("bf16") else 3e-5
+            np.testing.assert_allclose(
+                np.asarray(out[row:row + n], np.float32), want,
+                atol=tol, rtol=tol, err_msg=f"run {i}: {n} at {pos}")
+            row += -(-n // RAGGED_BLOCK_Q) * RAGGED_BLOCK_Q
+        # the inert pad tiles behind the last run: the walk skips all
+        # but the first, and the block's state was zero (the grid
+        # kernel of quantized pools computes every one)
+        assert case.get("int8") or not out[row + RAGGED_BLOCK_Q:].any()
+
+    @pytest.mark.ragged_attn
+    @pytest.mark.parametrize("absent", ["the-run-before",
+                                        "the-run-after"])
+    def test_a_neighbours_rows_are_its_own(self, absent):
+        """A tile of products overlaps the next sequence's rows (a
+        44-row run ends 48 rows into a 64-row tile; its neighbour
+        starts there): each neighbour's output is bit for bit what it
+        is with the other run absent, its tiles inert."""
+        rng = np.random.default_rng(7)
+        case = dict(kh=2, group=2, d=32)
+        runs = [(44, 20), (60, 5)]
+        batch, pages = self._walk_batch(runs, 128, 3)
+        assert pattn._ragged_tile_rows(2, 128) == 64
+        pools = [jnp.asarray(rng.standard_normal(
+            (pages, self.WALK_PS, 2, 32)), jnp.float32) for _ in "kv"]
+        q = jnp.asarray(rng.standard_normal((128, 4, 32)) * 0.2,
+                        jnp.float32)
+        both = self._walk_call(batch, q, pools, case)
+        lone = dict(batch)
+        tiles = slice(0, 6) if absent == "the-run-before" else \
+            slice(6, 14)
+        lone["seq_of_block"] = batch["seq_of_block"].copy()
+        lone["block_qstart"] = batch["block_qstart"].copy()
+        lone["seq_of_block"][tiles] = 2             # the inert sequence
+        lone["block_qstart"][tiles] = 0
+        alone = self._walk_call(lone, q, pools, case)
+        kept = slice(48, 108) if absent == "the-run-before" else \
+            slice(0, 44)
+        assert np.array_equal(both[kept], alone[kept])
+        assert np.abs(both[kept]).max() > 0
+
+    def test_segments_and_page_visits_by_hand(self):
+        """The kernel's segment map and the host's page-visit counts,
+        for a 240-row run over 24 pages beside three decode rows:
+        page_visits = 2 blocks x 24 pages + 3 x 24 + the first inert
+        tile's one; by eights, the run's first 14 blocks end in page
+        22 and its last 16 in page 23: 14 x 23 + 16 x 24 + 3 x 24 + 1."""
+        ps, pps = 128, 32
+        table = np.arange(pps, dtype=np.int32)
+        seqs = [RaggedSeq([1] * 240, 24 * ps - 240, table)] + [
+            RaggedSeq([1], 24 * ps - 1, table) for _ in range(3)]
+        batch = build_ragged_batch(seqs, t_budget=512, s_max=5,
+                                   pages_per_seq=pps, scratch_page=0,
+                                   pad_id=0, page_size=ps)
+        seg = np.asarray(pattn._ragged_segments(
+            jnp.asarray(batch["seq_of_block"]),
+            jnp.asarray(batch["block_qstart"]), 16))
+        want = np.zeros(64, np.int32)
+        want[[0, 16]] = 16, 14         # the run, cut at the 128-row block
+        want[[30, 31, 32]] = 1         # decode rows
+        want[33] = 1                   # the first inert tile; 34.. skipped
+        assert np.array_equal(seg, want)
+        assert pattn.ragged_page_visits(
+            batch, page_size=ps, block_q=128) == (
+                2 * 24 + 3 * 24 + 1, 14 * 23 + 16 * 24 + 3 * 24 + 1)
+        # under a window of two pages, against the pages each block's
+        # rows can see, row by row
+        window, want = 2 * ps, 0
+        for n, pos in [(240, 24 * ps - 240)] + [(1, 24 * ps - 1)] * 3 \
+                + [(1, 0)]:
+            for blk in range(-(-n // 8)):
+                rows = pos + 8 * blk + np.arange(8)
+                seen = {p for r in rows
+                        for p in range(max(0, r - window + 1) // ps,
+                                       min(r, pos + n - 1) // ps + 1)}
+                want += len(seen)
+        assert pattn.ragged_page_visits(
+            batch, page_size=ps, block_q=8,
+            sliding_window=window)[1] == want
+
     def test_decline_reasons_are_machine_readable(self):
         assert pattn.ragged_decline_reason(16, 32) is None
         assert pattn.ragged_decline_reason(48, 32).startswith(
@@ -327,12 +536,26 @@ class TestScheduledRagged:
         ragged prefill chunks interleaved with the live decode segment
         — and every session's tokens are byte-identical to direct
         generate_batch (greedy)."""
+        from theroundtaible_tpu.utils import telemetry
         direct = self._direct(prologue_engine)
         sched = SessionScheduler(ragged_engine)
         try:
+            telemetry.arm()
+            t_a = time.monotonic()
             results, errors = _join_mid_decode(sched,
                                                ["s0", "s1", "s2"])
+            spans = telemetry.spans_between(t_a, time.monotonic())
+            telemetry.disarm()
             assert not errors, errors
+            # every ragged dispatch's `segment` span says what its
+            # attention read, in page visits (ISSUE 32)
+            segs = [s["attrs"] for s in spans if s["rung"] == "segment"
+                    and s["attrs"]["kind"] == "ragged"]
+            assert segs and all(
+                1 <= a["page_visits"] <= a["page_visits_by_eights"]
+                for a in segs)
+            assert ragged_engine.describe()["ragged"]["page_visits"] \
+                >= sum(a["page_visits"] for a in segs)
             for sid in PROMPTS:
                 texts, stats = results[sid]
                 assert texts == direct[sid], f"{sid} diverged"
@@ -460,6 +683,48 @@ class TestRaggedResolution:
         assert info["ragged"]["enabled"] is True
         assert info["ragged"]["path"] == "pallas_ragged"
         assert info["ragged"]["tokens_budget"] >= 256
+
+    def test_page_visits_count_what_the_kernel_reads(self, ragged_engine):
+        """describe()["ragged"] and the roundtable_ragged_* series move
+        by what one dispatch's attention read, for a hand-built batch:
+        a run of 120 rows at position 200 beside three decode rows at
+        300, pages of 128. The walk's block is 128 rows: the run is one
+        segment and reads 3 pages, each decode row 3, the first inert
+        tile 1; of the run's fifteen 8-row blocks the first seven end
+        in the second page."""
+        from theroundtaible_tpu.utils import telemetry
+        eng = ragged_engine
+        ps = eng.kv.page_size
+        assert ps == 128 and eng.cfg.sliding_window is None
+        table = np.arange(eng.kv.pages_per_seq, dtype=np.int32)
+        seqs = [RaggedSeq([1] * 120, 200, table)] + [
+            RaggedSeq([1], 300, table) for _ in range(3)]
+        batch = build_ragged_batch(
+            seqs, t_budget=256, s_max=eng.kv.num_slots + 1,
+            pages_per_seq=eng.kv.pages_per_seq, scratch_page=0,
+            pad_id=0, page_size=ps)
+        before = dict(eng.describe()["ragged"])
+        series = [telemetry.REGISTRY.counter_total(
+            f"roundtable_ragged_{k}_total")
+            for k in ("page_visits", "page_visits_by_eights")]
+        eng._note_page_visits(batch, kernel=True)
+        walk = 3 + 3 * 3 + 1
+        eights = 7 * 2 + 8 * 3 + 3 * 3 + 1
+        assert (batch["page_visits"], batch["page_visits_by_eights"]) \
+            == (walk, eights)
+        after = eng.describe()["ragged"]
+        assert after["page_visits"] - before["page_visits"] == walk
+        assert after["page_visits_by_eights"] \
+            - before["page_visits_by_eights"] == eights
+        assert [telemetry.REGISTRY.counter_total(
+            f"roundtable_ragged_{k}_total") - was for k, was in zip(
+                ("page_visits", "page_visits_by_eights"), series)] \
+            == [walk, eights]
+        # a dispatch the XLA path served read no pages through a kernel
+        # block: both counts are the packing's
+        eng._note_page_visits(batch, kernel=False)
+        assert batch["page_visits"] == eights
+        assert set(after) == set(telemetry.SURFACE_BINDINGS["engine_ragged"])
 
     def test_contiguous_engine_has_no_ragged_seam(self):
         eng = InferenceEngine(get_model_config("tiny-gemma", **MODEL_KW),

@@ -887,6 +887,11 @@ class InferenceEngine:
         self.ragged_defer_min = 0
         self._ragged_dispatches: dict[str, int] = {}
         self._ragged_recent = _deque(maxlen=32)
+        # (page_visits, page_visits_by_eights) over every dispatch, and
+        # the pool as the ragged kernel sees it (ragged_decline_reason's
+        # arguments)
+        self._ragged_visits = [0, 0]
+        self._ragged_pool_shape: tuple = ()
         if kv_layout == "paged":
             from .prefix_cache import env_flag
             from .pallas import attention as _pattn
@@ -917,8 +922,18 @@ class InferenceEngine:
                           n_model)):
                     decline = "heads:model-axis"
                 else:
+                    self._ragged_pool_shape = (
+                        (page_size, model_cfg.page_width, kh_l, group),
+                        dict(dv=(model_cfg.kv_lora_rank if model_cfg.latent
+                                 else model_cfg.head_dim),
+                             itemsize=(1 if self.kv_quant_spec is not None
+                                       else jnp.dtype(dtype).itemsize),
+                             q_itemsize=jnp.dtype(dtype).itemsize,
+                             latent=model_cfg.latent,
+                             quantized=self.kv_quant_spec is not None))
                     decline = _pattn.ragged_decline_reason(
-                        page_size, model_cfg.page_width, kh_l, group)
+                        *self._ragged_pool_shape[0],
+                        **self._ragged_pool_shape[1])
                 if (decline is None
                         and self.kv_quant_fallback_reason is not None):
                     # Quantized pool the kernel cannot dequantize
@@ -2054,7 +2069,31 @@ class InferenceEngine:
                                         or "unknown")
         self._ragged_recent.append(entry)
         pattn.note_ragged_dispatch(kernel=path == "pallas_ragged")
+        self._note_page_visits(batch, kernel=path == "pallas_ragged")
         return nxt
+
+    def _note_page_visits(self, batch: dict, kernel: bool) -> None:
+        """Count what this dispatch's attention read, in page visits
+        (pallas.attention.ragged_page_visits): as the kernel that
+        served it blocks the runs, and at the packing's 8-row blocks.
+        One writer for the lifetime totals, their series and the two
+        fields the scheduler puts on the dispatch's `segment` span."""
+        from ..utils import telemetry
+        from .pallas import attention as pattn
+        block = pattn.RAGGED_BLOCK_Q
+        if kernel and self._ragged_pool_shape:
+            args, kw = self._ragged_pool_shape
+            block = pattn.ragged_query_block(len(batch["tokens"]), *args,
+                                             **kw)
+        visits = pattn.ragged_page_visits(
+            batch, page_size=self.kv.page_size, block_q=block,
+            sliding_window=self.cfg.sliding_window)
+        for i, name in enumerate(("page_visits",
+                                  "page_visits_by_eights")):
+            batch[name] = visits[i]
+            self._ragged_visits[i] += visits[i]
+            telemetry.inc(f"roundtable_ragged_{name}_total", visits[i],
+                          engine=self.cfg.name)
 
     def ragged_describe(self) -> dict[str, Any]:
         """Ragged-path provenance (ISSUE 8): the resolved path, why the
@@ -2070,6 +2109,8 @@ class InferenceEngine:
             "shapes": list(self.ragged_shapes),
             "defer_min_tokens": self.ragged_defer_min,
             "dispatches": dict(self._ragged_dispatches),
+            "page_visits": self._ragged_visits[0],
+            "page_visits_by_eights": self._ragged_visits[1],
             "recent": list(self._ragged_recent)[-8:],
         }
 

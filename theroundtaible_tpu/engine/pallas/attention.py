@@ -129,11 +129,35 @@ def supported(t: int, s: int, d: int) -> bool:
 # --- prefill kernel ---
 
 
+def _prefill_mask(q_start, kv_start, valid, *, group: int, block_q: int,
+                  block_kv: int, sliding_window: Optional[int],
+                  rows=None):
+    """Which (q row, kv column) pairs of one accumulation attend: the
+    causal frontier, the valid length, the window and, where given, the
+    live `rows` (see _prefill_accumulate) -> bool [G*bq, bkv]. The same
+    for every kv head, so a kernel that loops over heads builds it
+    once."""
+    # positions only depend on the q row WITHIN the block, identical
+    # across the group; build [bq, bkv] then tile over the group rows
+    r = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
+    q_pos = q_start + r
+    kv_pos = kv_start + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_kv), 1)
+    mask = (kv_pos <= q_pos) & (kv_pos < valid)
+    if sliding_window is not None:
+        mask &= kv_pos > q_pos - sliding_window
+    if rows is not None:
+        mask &= (r >= rows[0]) & (r < rows[1])
+    return jnp.broadcast_to(mask[None], (group, block_q, block_kv)) \
+        .reshape(group * block_q, block_kv)
+
+
 def _prefill_accumulate(q, k, v, q_start, kv_start, valid, state, *,
                         group: int, block_q: int, block_kv: int,
                         sliding_window: Optional[int],
                         softcap: Optional[float],
-                        k_scale=None, v_scale=None, kv_bits: int = 8):
+                        k_scale=None, v_scale=None, kv_bits: int = 8,
+                        rows=None, mask=None):
     """One online-softmax accumulation of a q block [G*bq, D] against one
     kv block [bkv, D] whose first entry holds absolute position kv_start.
     Shared by the contiguous (_prefill_kernel) and paged
@@ -148,7 +172,14 @@ def _prefill_accumulate(q, k, v, q_start, kv_start, valid, state, *,
     quantized page — dequantize in-kernel before the dots, so the bytes
     streamed from HBM are the int8/int4 payload + scales and the math
     past this line is IDENTICAL to the bf16 path (the numeric core of
-    the quantized-parity discipline)."""
+    the quantized-parity discipline).
+
+    `rows` = (lo, hi), where given: only q rows lo <= r < hi of the
+    block are LIVE. The state of every other row comes back bit for bit
+    as it went in (the ragged walk's tile can overlap a neighbouring
+    sequence's rows, whose state is then their own). `mask`: the ready
+    _prefill_mask (q_start, kv_start, valid, the window and the live
+    rows are then unused)."""
     if k_scale is not None:
         k = _dequant_kv(k, k_scale, kv_bits, q.dtype)
         v = _dequant_kv(v, v_scale, kv_bits, q.dtype)
@@ -159,23 +190,21 @@ def _prefill_accumulate(q, k, v, q_start, kv_start, valid, state, *,
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
 
-    # positions only depend on the q row WITHIN the block, identical
-    # across the group; build [bq, bkv] then tile over the group rows
-    q_pos = q_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_kv), 0)
-    kv_pos = kv_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_kv), 1)
-    mask = (kv_pos <= q_pos) & (kv_pos < valid)
-    if sliding_window is not None:
-        mask &= kv_pos > q_pos - sliding_window
-    mask = jnp.broadcast_to(mask[None], (group, block_q, block_kv)) \
-        .reshape(group * block_q, block_kv)
+    some_dead = rows is not None or mask is not None
+    if mask is None:
+        mask = _prefill_mask(q_start, kv_start, valid, group=group,
+                             block_q=block_q, block_kv=block_kv,
+                             sliding_window=sliding_window, rows=rows)
     s = jnp.where(mask, s, NEG_INF)
 
     m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new[:, :1])
+    if some_dead:
+        # a row with nothing live yet has m == NEG_INF and would weigh
+        # every masked column 1: keep the dead rows' sums untouched
+        p = jnp.where(mask, p, 0.0)
     l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -1281,25 +1310,67 @@ def paged_decode_attention(
     return out.reshape(b, 1, h, dv)
 
 
-# --- ragged paged attention (ISSUE 8) ---
+# --- ragged paged attention (ISSUE 8): the ragged walk ---
 #
 # Mixed prefill chunks and decode tokens in ONE dispatch (arxiv
 # 2604.15464 "Ragged Paged Attention"): the query is a FLAT token buffer
 # [T, H, D] carved into per-sequence row runs, each sequence attending
-# its own page-table pages. The flat axis is blocked at RAGGED_BLOCK_Q=8
-# — the MXU sublane minimum, so a decode token (a 1-row sequence)
-# occupies exactly one hardware tile — and the host builder
-# (serving_loop.build_ragged_batch) aligns every sequence's run to that
-# granularity. Scalar-prefetched per-BLOCK metadata maps each q block to
-# its sequence, so the kv index map walks that sequence's pages only:
-# one compiled program serves every prefill/decode mix of a fixed token
-# budget, which is what retires the scheduler's pow2 row buckets on this
-# path.
+# its own page-table pages. The host builder
+# (serving_loop.build_ragged_batch) aligns every run to RAGGED_BLOCK_Q=8
+# rows — the MXU sublane minimum, so a decode token (a 1-row sequence)
+# occupies exactly one hardware tile — and scalar-prefetched per-TILE
+# metadata maps each 8 rows to their sequence: one compiled program
+# serves every prefill/decode mix of a fixed token budget, which is what
+# retires the scheduler's pow2 row buckets on this path.
+#
+# The kernel's query block is NOT the packing's 8. A grid step holds a
+# block of up to 128 flat rows (_ragged_block_q: what the VMEM estimate
+# allows for the pool's shape; 64 in latent mode), q and out pipelined
+# by their BlockSpecs, the float32 softmax state of every row in
+# scratch. Inside, the step goes over the SEGMENTS of its block — the
+# stretch of one run that lies in it (_ragged_segments) — and walks each
+# segment's own pages, table[seq, lo..hi], up to the causal frontier of
+# the segment's last row and no further, `n` pages a trip
+# (_ragged_trip_pages: 512 kv columns a product, so that the per-row
+# work of a product — rescaling m, l and acc, their loads and stores —
+# is shared by four pages): the pools stay in HBM (pl.ANY) and a trip's
+# pages are copied by explicit DMAs into one of two VMEM slots, the next
+# trip's copies — or the first trip of the block's next segment — started
+# before the present trip is waited for. So a run's pages are copied once
+# a block the run lies in — twice for a 240-token leader — and not once
+# every 8 rows; nothing is paid for the width of the page table; and an
+# inert pad tile costs one skipped iteration.
+#
+# A trip's kv heads are parted ONCE, into dense [n*ps, D] blocks, and
+# then multiplied TILE by tile (_ragged_tile_rows): the block's rows in
+# tiles of 16-64, only those the segment touches and, of them, only
+# those whose positions reach the trip — a decode row or a verify tile
+# pays for one tile of products, a follower of 44 tokens for two, and a
+# long chunk skips the (tile, trip) pairs above the diagonal. Within a
+# tile the kv heads' products stand side by side under one mask: they
+# are independent, and one's latency hides behind the others' work
+# (with the heads in an inner LOOP of their own the same products took
+# twice as long on a v5e). A tile can overlap a neighbouring run's
+# rows: they are dead to this segment (`rows` of _prefill_mask), their
+# state passes through bit for bit, and every row of the output block
+# is written once, by the step that owns it.
+#
+# The pools are operands as XLA stores them. Token-major [P, ps, K, D]
+# (_token_major): a bfloat16 page is copied as the 32-bit words it is
+# stored in — a token's K heads are K/2 rows of words, a word a pair of
+# heads — and a pair is parted by one strided load and two shifts, a
+# quarter of what the same slice costs as a strided read of bfloat16
+# rows (which other dtypes take). Head-major [P, K*ps, D], the view the
+# decode walk takes: a kv head is a dense block of its page and lands
+# dense, one copy a head. A latent pool [P, ps, W] is keys and, in its
+# first columns, values. QUANTIZED pools keep the grid kernel the walk
+# replaced (_ragged_grid_kernel) until their scale pools walk too
+# (ROADMAP S1b).
 #
 # RAGGED_BLOCK_Q has ONE owner (serving_loop): the host builder aligns
-# runs and sizes seq_of_block/block_qstart with it, and the kernel grid
+# runs and sizes seq_of_block/block_qstart with it, and the segment map
 # + VMEM estimate here must agree — two definitions would let a lone
-# tuning change silently mis-map blocks to sequences.
+# tuning change silently mis-map tiles to sequences.
 from ..serving_loop import RAGGED_BLOCK_Q  # noqa: E402
 
 # Test-visibility counters (tests/conftest.py `ragged_attn` marker
@@ -1340,15 +1411,30 @@ def ragged_fallback_dispatches() -> int:
 
 
 def ragged_decline_reason(page_size: int, d: int, kh: int = 1,
-                          group: int = 1) -> Optional[str]:
+                          group: int = 1, *, dk: Optional[int] = None,
+                          dv: Optional[int] = None, itemsize: int = 2,
+                          q_itemsize: int = 2, latent: bool = False,
+                          quantized: bool = False) -> Optional[str]:
     """Why the ragged kernel cannot serve this pool shape, or None when
     it can — the machine-readable `fallback_reason` the engine records
     per dispatch (the int4mm plan_reason pattern). Pass the LOCAL
-    kv-head count under SPMD."""
+    kv-head count under SPMD; `dk` / `dv` are the pool's key and the
+    result's value widths where they are not `d` (a latent pool) and
+    `itemsize` a page cell's. The VMEM estimate is the walk's own
+    (_ragged_vmem_est) at the packing's 8-row block, the smallest query
+    block it can take — or, for a `quantized` pool, the grid kernel's
+    (_paged_vmem_est)."""
     if page_size not in (512, 256, 128, 64, 32, 16, 8):
         return f"page_size:{page_size}"
-    if _paged_vmem_est(page_size, d, kh, group,
-                       RAGGED_BLOCK_Q) > _VMEM_BUDGET:
+    if quantized:
+        fits = _paged_vmem_est(page_size, d, kh, group,
+                               RAGGED_BLOCK_Q) <= _VMEM_BUDGET
+    else:
+        fits = _ragged_block_q(
+            RAGGED_BLOCK_Q, page_size, d, kh, group, dk=dk or d,
+            dv=dv or d, itemsize=itemsize, q_itemsize=q_itemsize,
+            latent=latent) is not None
+    if not fits:
         return f"vmem:ps={page_size},d={d},kh={kh},g={group}"
     if not _interpret() and d % 128 != 0:
         return f"head_dim:{d}"
@@ -1356,8 +1442,8 @@ def ragged_decline_reason(page_size: int, d: int, kh: int = 1,
 
 
 def ragged_supported(page_size: int, d: int, kh: int = 1,
-                     group: int = 1) -> bool:
-    return ragged_decline_reason(page_size, d, kh, group) is None
+                     group: int = 1, **shape) -> bool:
+    return ragged_decline_reason(page_size, d, kh, group, **shape) is None
 
 
 # What the v5e's compiler (Mosaic, JAX 0.9.0) answers the int4 page
@@ -1390,7 +1476,8 @@ def kv_quant_decline_reason(page_size: int, d: int, kh: int, group: int,
     moves."""
     if bits not in (8, 4):
         return f"kv_bits:{bits}"
-    base = ragged_decline_reason(page_size, d, kh, group)
+    base = ragged_decline_reason(page_size, d, kh, group,
+                                 quantized=True)
     if base is not None:
         return base
     from ..kv_quant import KVQuantSpec
@@ -1421,35 +1508,19 @@ def kv_quant_kernel_supported(page_size: int, d: int, kh: int,
                                    quant_group) is None
 
 
-def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
-                   q_ref, k_ref, *rest,
-                   page_size: int, num_page_blocks: int, kh: int,
-                   group: int, sliding_window: Optional[int],
-                   softcap: Optional[float],
-                   kv_bits: int = 8, quantized: bool = False,
-                   v_dim: Optional[int] = None):
-    # Grid (q_blocks, pages_per_seq). Identical online-softmax math to
-    # _paged_prefill_kernel (shared _prefill_accumulate, all kv heads on
-    # one pool block with a static head loop — see _paged_prefill_kernel
-    # for why per-head pool blocks are Mosaic-illegal); the ragged
-    # difference is WHICH sequence a q block serves: blkseq_ref maps the
-    # flat-buffer block to its sequence, whose page table / causal
-    # frontier then drive the kv index map exactly like the batched
-    # kernels' row index. Rows past a sequence's real length are pad
-    # rows: they attend the sequence's valid prefix (finite garbage —
-    # MASK_VALUE is a large finite negative, so even an all-masked row
-    # exponentiates to finite junk) and the host drops their outputs.
-    # Quantized pools (ISSUE 11): per-page scale blocks ride the kv
-    # index map, dequantized inside _prefill_accumulate. A latent pool
-    # (`v_dim`, see _pool_heads) has no v operand.
-    v_ref = None
-    if v_dim is None:
-        v_ref, *rest = rest
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-        ks_ref = vs_ref = None
+def _ragged_grid_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref,
+                        valid_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                        o_ref, m_scr, l_scr, acc_scr, *,
+                        page_size: int, num_page_blocks: int, kh: int,
+                        group: int, sliding_window: Optional[int],
+                        softcap: Optional[float], kv_bits: int):
+    # QUANTIZED pools only: their scale pools do not walk yet (ROADMAP
+    # S1b), so they keep the grid every pool had before the walk — a
+    # step for every RAGGED_BLOCK_Q query rows and every entry of the
+    # page table, the steps past the block's frontier skipped but paid,
+    # every page copied again by every 8-row block, per-page scale
+    # blocks riding the kv index map and dequantized inside
+    # _prefill_accumulate.
     qb = pl.program_id(0)
     sb = pl.program_id(1)
 
@@ -1468,16 +1539,14 @@ def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
     @pl.when((sb >= lo) & (sb <= hi))
     def _compute():
         for khi in range(kh):
-            k, v = _page_block(k_ref, v_ref, khi, v_dim)
             m_scr[khi], l_scr[khi], acc_scr[khi] = _prefill_accumulate(
                 q_ref[khi].reshape(group * RAGGED_BLOCK_Q, -1),
-                k, v, q_start,
+                k_ref[0, :, khi, :], v_ref[0, :, khi, :], q_start,
                 sb * page_size, valid,
                 (m_scr[khi], l_scr[khi], acc_scr[khi]), group=group,
                 block_q=RAGGED_BLOCK_Q, block_kv=page_size,
                 sliding_window=sliding_window, softcap=softcap,
-                k_scale=(ks_ref[0, :, khi, :] if quantized else None),
-                v_scale=(vs_ref[0, :, khi, :] if quantized else None),
+                k_scale=ks_ref[0, :, khi, :], v_scale=vs_ref[0, :, khi, :],
                 kv_bits=kv_bits)
 
     @pl.when(sb == num_page_blocks - 1)
@@ -1487,6 +1556,412 @@ def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, qoffs_ref, valid_ref,
             l = jnp.maximum(l_scr[khi, :, :1], 1e-30)
             o_ref[khi] = (acc_scr[khi] / l).astype(o_ref.dtype) \
                 .reshape(group, RAGGED_BLOCK_Q, d)
+
+
+def _ragged_grid_attention(qt, k_pool, v_pool, k_scale, v_scale, meta, *,
+                           kv_bits: int, sliding_window, softcap,
+                           interpret: bool):
+    """ragged_paged_attention over QUANTIZED pools (_ragged_grid_kernel):
+    `qt` [K, G, T, D], `meta` the five scalar-prefetched arrays."""
+    kh, group, t, d = qt.shape
+    page_size = k_pool.shape[1]
+
+    def kv_index(qb, sb, table_ref, blkseq_ref, blkq_ref, qoffs_ref,
+                 valid_ref):
+        seq = blkseq_ref[qb]
+        q_start = qoffs_ref[seq] + blkq_ref[qb]
+        lo_blk, hi_blk = _prefill_blk_bounds(
+            q_start, valid_ref[seq], RAGGED_BLOCK_Q, page_size,
+            sliding_window)
+        sb = jnp.clip(sb, lo_blk, jnp.maximum(hi_blk, 0))
+        return (table_ref[seq, sb], 0, 0, 0)
+
+    def rows_blk(width):
+        return pl.BlockSpec((kh, group, RAGGED_BLOCK_Q, width),
+                            lambda qb, sb, *_: (0, 0, qb, 0))
+
+    rows = group * RAGGED_BLOCK_Q
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(t // RAGGED_BLOCK_Q, meta[0].shape[1]),
+        in_specs=[rows_blk(d)] + [
+            pl.BlockSpec((1,) + p.shape[1:], kv_index)
+            for p in (k_pool, v_pool, k_scale, v_scale)],
+        out_specs=rows_blk(d),
+        scratch_shapes=[
+            pltpu.VMEM((kh, rows, _LANES), jnp.float32),
+            pltpu.VMEM((kh, rows, _LANES), jnp.float32),
+            pltpu.VMEM((kh, rows, d), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _ragged_grid_kernel, page_size=page_size,
+        num_page_blocks=meta[0].shape[1], kh=kh, group=group,
+        sliding_window=sliding_window, softcap=softcap, kv_bits=kv_bits)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        interpret=interpret,
+        name="ragged_paged_attention",
+    )(*meta, qt, k_pool, v_pool, k_scale, v_scale)
+
+
+# What the ragged walk asks the compiler for, and what its own estimate
+# is held to. A v5e core has 128 MiB of VMEM, of which a kernel gets the
+# 16 MiB default scope unless it says otherwise; the walk holds a whole
+# query block's softmax state beside its q, out and page buffers, and
+# the products' temporaries come on top — the third between the two.
+_RAGGED_VMEM_LIMIT = 48 * 1024 * 1024
+_RAGGED_VMEM_BUDGET = 32 * 1024 * 1024
+# kv columns of one product: every page more in a trip divides the
+# per-row work of a product (the rescaling of m, l and acc, their loads
+# and stores) by the pages it covers.
+_RAGGED_TRIP_COLS = 512
+
+
+def _ragged_trip_pages(page_size: int) -> int:
+    """Pages a trip of the ragged walk copies and multiplies at once."""
+    return max(1, min(_RAGGED_TRIP_COLS // page_size, 4))
+
+
+def _ragged_tile_rows(group: int, block_q: int) -> int:
+    """Query rows of one product of the ragged walk: a block is
+    multiplied tile by tile so that a short run pays for its own rows
+    and not for the block's. A tile is whole bf16 sublane tiles (16
+    rows) where the block has them, and with the GQA group 128 rows of
+    the product at least (on a v5e, kernel alone: at 32/8 x 128 a
+    leaders' segment takes 1.12 ms in tiles of 32 rows against 1.45 in
+    tiles of 16, fifteen verify tiles 0.53 against 0.46; at group 16,
+    tiles of 16 beat 32 and 64 on every shape)."""
+    r = 16
+    while r * group < 128 and r < block_q:
+        r *= 2
+    return min(r, block_q)
+
+
+def _ragged_vmem_est(block_q: int, page_size: int, d: int, kh: int,
+                     group: int, *, dk: int, dv: int, itemsize: int = 2,
+                     q_itemsize: int = 2, latent: bool = False) -> int:
+    """What the ragged walk DECLARES for a query block of `block_q`
+    rows, in bytes: the float32 softmax state of the block (m and l a
+    lane row each, acc), its q and out blocks (double-buffered by the
+    pipeline), and three times a trip's pages — two slots a pool, keys
+    and (unless latent) values as the pool holds them, and the trip's
+    kv heads once more, sliced out dense."""
+    rows = kh * group * block_q
+    state = rows * (2 * _LANES + dv) * 4
+    q_out = 2 * rows * (d + dv) * q_itemsize
+    page = page_size * kh * (dk if latent else dk + dv) * itemsize
+    return state + q_out + 3 * _ragged_trip_pages(page_size) * page
+
+
+def _ragged_block_q(t: int, page_size: int, d: int, kh: int, group: int,
+                    **shape) -> Optional[int]:
+    """Query rows a grid step of the ragged walk holds: the largest
+    block that divides the flat buffer and fits the VMEM budget — 128
+    at 32 heads x 128, 32 in latent mode (64 heads x 640) — or None
+    when not even the packing's own 8 fit."""
+    for bq in (128, 64, 32, 16, 8):
+        if t % bq == 0 and _ragged_vmem_est(
+                bq, page_size, d, kh, group,
+                **shape) <= _RAGGED_VMEM_BUDGET:
+            return bq
+    return None
+
+
+def ragged_query_block(t: int, page_size: int, d: int, kh: int,
+                       group: int, *, dk: Optional[int] = None,
+                       dv: Optional[int] = None, quantized: bool = False,
+                       **shape) -> int:
+    """Query rows the ragged kernel multiplies a page against at once
+    for a flat buffer of `t` rows and this pool shape
+    (ragged_decline_reason's arguments): the walk's block, or the
+    packing's own 8 where the grid kernel serves (quantized pools)."""
+    if quantized:
+        return RAGGED_BLOCK_Q
+    return _ragged_block_q(t, page_size, d, kh, group, dk=dk or d,
+                           dv=dv or d, **shape)
+
+
+def _ragged_tile_kinds(seq_of_block, block_qstart, tiles: int, xp):
+    """(brk, pad) per packing tile (RAGGED_BLOCK_Q rows), `xp` numpy or
+    jax.numpy: does a new stretch begin at the tile — another sequence,
+    a run that does not carry on from the tile before, or a new query
+    block of `tiles` tiles — and is the tile one of the inert pad tiles
+    behind the last run, all but the first of which repeat their
+    sequence's start (block_qstart 0 behind a tile of the same
+    sequence)."""
+    nb = seq_of_block.shape[0]
+    idx = xp.arange(nb, dtype=xp.int32)
+    prev_seq = xp.concatenate([xp.full((1,), -1, xp.int32),
+                               seq_of_block[:-1]])
+    prev_q = xp.concatenate([xp.zeros((1,), xp.int32), block_qstart[:-1]])
+    same = seq_of_block == prev_seq
+    cont = same & (block_qstart == prev_q + RAGGED_BLOCK_Q)
+    return ~cont | (idx % tiles == 0), same & (block_qstart == 0)
+
+
+def _ragged_segments(seq_of_block, block_qstart, tiles: int):
+    """Per packing tile, how many tiles the run SEGMENT that starts
+    there spans, else 0. A segment is the stretch of one sequence's run
+    inside one query block of `tiles` tiles: the kernel walks a
+    segment's pages once. Of the inert pad tiles only the first is a
+    segment — the rest cost the kernel one skipped iteration each and
+    read nothing."""
+    nb = seq_of_block.shape[0]
+    idx = jnp.arange(nb, dtype=jnp.int32)
+    brk, pad = _ragged_tile_kinds(seq_of_block, block_qstart, tiles, jnp)
+    nxt = jnp.concatenate([jnp.where(brk, idx, nb)[1:],
+                           jnp.full((1,), nb, jnp.int32)])
+    end = jax.lax.cummin(nxt, axis=0, reverse=True)
+    return jnp.where(brk & ~pad, end - idx, 0).astype(jnp.int32)
+
+
+def _ragged_next_segment(segments, tiles: int):
+    """Per packing tile, the tile OF ITS QUERY BLOCK at which the
+    block's next segment starts, else -1: the walk starts that
+    segment's first trip before it waits for this one's last."""
+    nb = segments.shape[0]
+    idx = jnp.arange(nb, dtype=jnp.int32)
+    at = jnp.concatenate([jnp.where(segments > 0, idx, nb)[1:],
+                          jnp.full((1,), nb, jnp.int32)])
+    nxt = jax.lax.cummin(at, axis=0, reverse=True)
+    return jnp.where((nxt < nb) & (nxt // tiles == idx // tiles),
+                     nxt % tiles, -1).astype(jnp.int32)
+
+
+def ragged_page_visits(batch: dict, *, page_size: int, block_q: int,
+                       sliding_window: Optional[int] = None
+                       ) -> tuple[int, int]:
+    """(page_visits, page_visits_by_eights) of one ragged dispatch, on
+    the host from serving_loop.build_ragged_batch's arrays: the sum over
+    runs of (query blocks x pages each reads) with query blocks of
+    `block_q` rows — the walk's segments — and the same sum at the
+    packing's 8-row blocks, which is what the grid kernel did (and does
+    for quantized pools). Their quotient is how far the walk reaches on
+    the traffic at hand: about 1 for a segment of decode rows and
+    verify tiles, 10-30 for a leaders' segment."""
+    import numpy as np
+    seq = np.asarray(batch["seq_of_block"], np.int32)
+    q_tile = np.asarray(batch["block_qstart"], np.int32)
+    first_pos = np.asarray(batch["query_offsets"])[seq] + q_tile
+    valid = np.asarray(batch["kv_valid"])[seq]
+
+    def visits(tiles: int) -> int:
+        brk, pad = _ragged_tile_kinds(seq, q_tile, tiles, np)
+        at = np.flatnonzero(brk)
+        rows = np.diff(np.append(at, len(seq))) * RAGGED_BLOCK_Q
+        at, rows = at[~pad[at]], rows[~pad[at]]
+        # _prefill_blk_bounds, in numpy: nothing here touches a device
+        hi = np.minimum((first_pos[at] + rows - 1) // page_size,
+                        (valid[at] - 1) // page_size)
+        lo = 0 if sliding_window is None else np.maximum(
+            0, (first_pos[at] - sliding_window + 1) // page_size)
+        return int(np.maximum(hi - lo + 1, 0).sum())
+
+    return visits(block_q // RAGGED_BLOCK_Q), visits(1)
+
+
+def _ragged_kernel(table_ref, blkseq_ref, blkq_ref, seg_ref, next_ref,
+                   qoffs_ref, valid_ref, q_ref, *rest,
+                   page_size: int, n: int, block_q: int, tile: int,
+                   kh: int, group: int, token_major: bool, words: bool,
+                   sliding_window: Optional[int],
+                   softcap: Optional[float],
+                   v_dim: Optional[int] = None):
+    # See "the ragged walk" above. Grid (T / block_q,): a step holds the
+    # q and out blocks of block_q flat rows and the softmax state of
+    # every one of them. `rest`: the pools in HBM (k, and v unless
+    # latent), the output block, one two-slot buffer of n pages a pool,
+    # m / l / acc, the DMA semaphores, `flow` (SMEM: trips so far in
+    # this step — the slot in turn is its parity — and whether the
+    # segment about to start had its first trip started already) and,
+    # for a token-major pool, a trip's kv heads sliced out dense.
+    n_pools = 2 if v_dim is None else 1
+    hbms, o_ref = rest[:n_pools], rest[n_pools]
+    bufs = rest[n_pools + 1:2 * n_pools + 1]
+    m_scr, l_scr, acc_scr, sem, flow = \
+        rest[2 * n_pools + 1:2 * n_pools + 6]
+    heads = rest[2 * n_pools + 6:]
+    tiles = block_q // RAGGED_BLOCK_Q
+    first = pl.program_id(0) * tiles
+    cols = n * page_size                # kv columns of one product
+    per_head = v_dim is None and not token_major
+    if words:
+        # A bfloat16 page as the 32-bit words it is stored in: a
+        # token's K heads are K/2 rows of D words, a word a PAIR of
+        # heads (the even one its low half).
+        hbms = [h.reshape(h.shape[0], page_size * kh, h.shape[-1])
+                .bitcast(jnp.uint32) for h in hbms]
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    flow[0] = 0
+    flow[1] = 0
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        # A trip's last pages may lie past the segment's frontier and
+        # are then not copied: what the slot holds there is masked out
+        # of the scores, but the weighted sum multiplies it by zero —
+        # which a NaN left in fresh VMEM would survive.
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+
+    def copies(seq, lo, hi, t, slot, go):
+        # Start (go) or await trip t of a segment into `slot`; a wait
+        # only needs the copy's shape, not its source page. A head-major
+        # page [K*ps, D] is K dense blocks: each lands behind its own
+        # head's blocks of the trip's other pages. (A loop, not n
+        # copies of its body: the compiler takes a tenth less time over
+        # it on a checkout's first run.)
+        page_rows = page_size * kh // 2 if words else page_size
+
+        def one_page(j, carry):
+            at = lo + t * n + j
+            rows = pl.ds(pl.multiple_of(j * page_rows, page_rows),
+                         page_rows)
+
+            @pl.when(at <= hi)
+            def _():
+                page = table_ref[seq, at] if go else 0
+                for i, (hbm, buf) in enumerate(zip(hbms, bufs)):
+                    if not per_head:
+                        pairs = [(hbm.at[page], buf.at[slot, rows])]
+                    else:
+                        pairs = [(hbm.at[page, pl.ds(khi * page_size,
+                                                     page_size)],
+                                  buf.at[slot, khi, rows])
+                                 for khi in range(kh)]
+                    for src, dst in pairs:
+                        c = pltpu.make_async_copy(src, dst,
+                                                  sem.at[i, slot])
+                        c.start() if go else c.wait()
+            return carry
+
+        jax.lax.fori_loop(0, n, one_page, 0)
+
+    def stage(slot):
+        # A token-major trip's kv heads, sliced out once into dense
+        # [n*ps, D] blocks: the strided read is paid here and not by
+        # every tile of products. As 32-bit words it is one strided
+        # load a pair of heads, which two shifts part — a bfloat16 is
+        # the high half of its float32 (on a v5e a quarter of the time
+        # the same slice takes as a read of bfloat16 rows).
+        if not token_major or v_dim is not None:
+            return
+        for buf, dense in zip(bufs, heads):
+            if not words:
+                for khi in range(kh):
+                    dense[khi] = buf[slot, :, khi, :]
+                continue
+            for pair in range(kh // 2):
+                w = buf[slot, pl.ds(pair, cols, stride=kh // 2), :]
+                for i, half in enumerate((w << 16,
+                                          w & jnp.uint32(0xFFFF0000))):
+                    dense[2 * pair + i] = jax.lax.bitcast_convert_type(
+                        half, jnp.float32).astype(dense.dtype)
+
+    def head(slot, khi):
+        # (keys, values) of kv head khi of the trip in `slot`
+        if v_dim is not None:
+            k = bufs[0][slot]
+            return k, k[:, :v_dim]
+        if token_major:
+            return tuple(dense[khi] for dense in heads)
+        return tuple(buf[slot, khi] for buf in bufs)
+
+    def span(t):
+        # (sequence, first position, valid length, first and last page,
+        # trips) of the segment that starts at tile t of this block
+        seq = blkseq_ref[first + t]
+        q_start = qoffs_ref[seq] + blkq_ref[first + t]
+        valid = valid_ref[seq]
+        lo, hi = _prefill_blk_bounds(
+            q_start, valid, seg_ref[first + t] * RAGGED_BLOCK_Q,
+            page_size, sliding_window)
+        return (seq, q_start, valid, lo, hi,
+                jax.lax.div(jnp.maximum(hi - lo + 1, 0) + (n - 1), n))
+
+    def segment(t, n_tiles):
+        seq, q_start, valid, lo, hi, trips = span(t)
+        a = t * RAGGED_BLOCK_Q              # live rows [a, b) of the block
+        b = a + n_tiles * RAGGED_BLOCK_Q
+        g0, started = flow[0], flow[1]
+        nt = next_ref[first + t]
+        nseq, _, _, nlo, nhi, ntrips = span(jnp.maximum(nt, 0))
+        hand_on = (nt >= 0) & (ntrips > 0) & (trips > 0)
+
+        @pl.when((trips > 0) & (started == 0))
+        def _():
+            copies(seq, lo, hi, 0, g0 & 1, True)
+
+        def trip(p, carry):
+            slot = (g0 + p) & 1
+
+            # under this trip's products: my own next trip, or behind
+            # my last the first of the block's next segment
+            own = p + 1 < trips
+
+            @pl.when(own | hand_on)
+            def _():
+                copies(jnp.where(own, seq, nseq), jnp.where(own, lo, nlo),
+                       jnp.where(own, hi, nhi), jnp.where(own, p + 1, 0),
+                       1 - slot, True)
+
+            copies(seq, lo, hi, p, slot, False)
+            stage(slot)
+            kv_start = (lo + p * n) * page_size
+            # rows before the first that reaches this trip attend none
+            # of it: the block's tiles from that row's on
+            t_lo = jax.lax.div(a + jnp.maximum(kv_start - q_start, 0), tile)
+            t_hi = jax.lax.div(b + (tile - 1), tile)
+
+            def one_tile(ti, c):
+                # The kv heads' products are independent of one another
+                # and share the mask: unrolled side by side, one's
+                # latency hides behind the others' work.
+                off = pl.multiple_of(ti * tile, tile)
+                mask = _prefill_mask(
+                    q_start + off - a, kv_start, valid, group=group,
+                    block_q=tile, block_kv=cols,
+                    sliding_window=sliding_window, rows=(a - off, b - off))
+                for khi in range(kh):
+                    k, v = head(slot, khi)
+                    at = (khi, slice(None), pl.ds(off, tile), slice(None))
+                    state = _prefill_accumulate(
+                        q_ref[at].reshape(group * tile, -1), k, v,
+                        None, None, None,
+                        tuple(r[at].reshape(group * tile, r.shape[-1])
+                              for r in (m_scr, l_scr, acc_scr)),
+                        group=group, block_q=tile, block_kv=cols,
+                        sliding_window=None, softcap=softcap, mask=mask)
+                    for r, x in zip((m_scr, l_scr, acc_scr), state):
+                        r[at] = x.reshape(group, tile, r.shape[-1])
+                return c
+
+            jax.lax.fori_loop(t_lo, t_hi, one_tile, 0)
+            return carry
+
+        jax.lax.fori_loop(0, trips, trip, 0)
+        flow[0] = g0 + trips
+        flow[1] = hand_on.astype(jnp.int32)
+
+    def one(t, carry):
+        n_tiles = seg_ref[first + t]
+
+        @pl.when(n_tiles > 0)
+        def _():
+            segment(t, n_tiles)
+
+        return carry
+
+    jax.lax.fori_loop(0, tiles, one, 0)
+    for khi in range(kh):
+        l = jnp.maximum(l_scr[khi][..., :1], 1e-30)
+        o_ref[khi] = (acc_scr[khi] / l).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(
@@ -1518,21 +1993,27 @@ def ragged_paged_attention(
     sequence's frontier pages already (engine/paged_forward.py). One
     compiled shape serves every prefill/decode composition of the same
     T — the no-recompile property the scheduler's ragged segments rely
-    on. Returns [T, H, D] in q's dtype; pad-row outputs are garbage and
-    must be dropped by the caller. `v_pool=None` with `v_dim`: a latent
-    pool (paged_prefill_attention), the result [T, H, v_dim].
+    on. Each run's pages are walked once a query block, up to the
+    block's causal frontier (see "the ragged walk" above). Returns
+    [T, H, D] in q's dtype; pad-row outputs are finite garbage (zeros
+    for the inert pad tiles) and must be dropped by the caller.
+    `v_pool=None` with `v_dim`: a latent pool (paged_prefill_attention),
+    the result [T, H, v_dim].
     """
     t, h, d = q.shape
     page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
     latent = v_pool is None
     dv = v_dim if latent else d
     group = h // kh
-    pages_per_seq = tables.shape[1]
     quantized = k_scale is not None
     if t % RAGGED_BLOCK_Q:
         raise ValueError(
             f"flat buffer T={t} must be a multiple of {RAGGED_BLOCK_Q}")
-    reason = ragged_decline_reason(page_size, d, kh, group)
+    shape = dict(dk=k_pool.shape[-1], dv=dv,
+                 itemsize=k_pool.dtype.itemsize,
+                 q_itemsize=q.dtype.itemsize, latent=latent)
+    reason = ragged_decline_reason(page_size, d, kh, group,
+                                   quantized=quantized, **shape)
     if reason is not None:
         raise ValueError(f"unsupported ragged shape: {reason}")
     interpret = _interpret() if interpret is None else interpret
@@ -1541,66 +2022,102 @@ def ragged_paged_attention(
     # engine seam's per-dispatch count is the exact provenance.
     note_ragged_dispatch(kernel=True)
 
-    # [T, H, D] → [K, G, T, D]: q heads grouped by their kv head, flat
-    # token axis blocked at RAGGED_BLOCK_Q.
-    qt = q.reshape(t, kh, group, d).transpose(1, 2, 0, 3)
-    num_blocks = t // RAGGED_BLOCK_Q
-
-    def kv_index(qb, sb, table_ref, blkseq_ref, blkq_ref, qoffs_ref,
-                 valid_ref):
-        seq = blkseq_ref[qb]
-        q_start = qoffs_ref[seq] + blkq_ref[qb]
-        lo_blk, hi_blk = _prefill_blk_bounds(
-            q_start, valid_ref[seq], RAGGED_BLOCK_Q, page_size,
-            sliding_window)
-        sb = jnp.clip(sb, lo_blk, jnp.maximum(hi_blk, 0))
-        return (table_ref[seq, sb],) + (0,) * (k_pool.ndim - 1)
-
-    in_specs = [
-        pl.BlockSpec((kh, group, RAGGED_BLOCK_Q, d),
-                     lambda qb, sb, t_, b_, s_, o_, v_:
-                     (0, 0, qb, 0)),
-        pl.BlockSpec((1,) + k_pool.shape[1:], kv_index),
-    ]
-    operands = [qt, k_pool]
-    if not latent:
-        in_specs.append(
-            pl.BlockSpec((1, page_size, kh, v_pool.shape[-1]), kv_index))
-        operands.append(v_pool)
+    meta = [a.astype(jnp.int32) for a in (
+        tables, seq_of_block, block_qstart, query_offsets, kv_valid)]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, page_size, kh, k_scale.shape[-1]), kv_index),
-            pl.BlockSpec((1, page_size, kh, v_scale.shape[-1]), kv_index),
-        ]
-        operands += [k_scale, v_scale]
+        # [T, H, D] → [K, G, T, D]: q heads grouped by their kv head
+        out = _ragged_grid_attention(
+            q.reshape(t, kh, group, d).transpose(1, 2, 0, 3), k_pool,
+            v_pool, k_scale, v_scale, meta, kv_bits=kv_bits,
+            sliding_window=sliding_window, softcap=softcap,
+            interpret=interpret)
+        return out.transpose(2, 0, 1, 3).reshape(t, h, dv)
+    return _ragged_walk(q, k_pool, v_pool, *meta,
+                        sliding_window=sliding_window, softcap=softcap,
+                        interpret=interpret,
+                        v_dim=v_dim if latent else None)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sliding_window", "softcap", "interpret", "v_dim"))
+def _ragged_walk(q, k_pool, v_pool, tables, seq_of_block, block_qstart,
+                 query_offsets, kv_valid, *, sliding_window, softcap,
+                 interpret: bool, v_dim: Optional[int]):
+    """ragged_paged_attention over unquantized pools (_ragged_kernel).
+    A jit of its own: a model's layers call it with the same shapes, so
+    the kernel's body is traced once a process and lowered once a
+    program — not once a layer a program, which is what an unrolled
+    layer's pallas_call costs at every start (PERF.md, set-up)."""
+    t, h, d = q.shape
+    page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
+    latent = v_pool is None
+    dv = v_dim if latent else d
+    group = h // kh
+    block_q = _ragged_block_q(
+        t, page_size, d, kh, group, dk=k_pool.shape[-1], dv=dv,
+        itemsize=k_pool.dtype.itemsize, q_itemsize=q.dtype.itemsize,
+        latent=latent)
+    segments = _ragged_segments(seq_of_block, block_qstart,
+                                block_q // RAGGED_BLOCK_Q)
+    following = _ragged_next_segment(segments,
+                                     block_q // RAGGED_BLOCK_Q)
+    n = min(_ragged_trip_pages(page_size), tables.shape[1])
+    token_major = latent or _token_major(kh, k_pool.dtype.itemsize)
+    pools = [k_pool] if latent else [k_pool, v_pool]
+    words = (token_major and not latent
+             and k_pool.dtype == jnp.bfloat16)
+    if words:
+        # a trip's pages one behind the other, as the 32-bit words a
+        # token's pairs of heads are stored in
+        bufs = [pltpu.VMEM((2, n * page_size * kh // 2, p.shape[-1]),
+                           jnp.uint32) for p in pools]
+    elif token_major:
+        # ... or as the pool holds them
+        bufs = [pltpu.VMEM((2, n * page_size) + p.shape[2:], p.dtype)
+                for p in pools]
+    else:
+        # the pool as XLA stores it, head-major: see "the walk" above
+        pools = [p.swapaxes(1, 2).reshape(
+            p.shape[0], kh * page_size, p.shape[-1]) for p in pools]
+        bufs = [pltpu.VMEM((2, kh, n * page_size, p.shape[-1]), p.dtype)
+                for p in pools]
+    dense = [pltpu.VMEM((kh, n * page_size, p.shape[-1]), p.dtype)
+             for p in pools if token_major and not latent]
+
+    def rows_blk(width):
+        return pl.BlockSpec((kh, group, block_q, width),
+                            lambda i, *_: (0, 0, i, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(num_blocks, pages_per_seq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (kh, group, RAGGED_BLOCK_Q, dv),
-            lambda qb, sb, t_, b_, s_, o_, v_: (0, 0, qb, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kh, group * RAGGED_BLOCK_Q, _LANES), jnp.float32),
-            pltpu.VMEM((kh, group * RAGGED_BLOCK_Q, _LANES), jnp.float32),
-            pltpu.VMEM((kh, group * RAGGED_BLOCK_Q, dv), jnp.float32),
-        ],
+        num_scalar_prefetch=7,
+        grid=(t // block_q,),
+        in_specs=[rows_blk(d)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=rows_blk(dv),
+        scratch_shapes=bufs + [
+            pltpu.VMEM((kh, group, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((kh, group, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((kh, group, block_q, dv), jnp.float32),
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((2,), jnp.int32)] + dense,
     )
     kernel = functools.partial(
-        _ragged_kernel, page_size=page_size,
-        num_page_blocks=pages_per_seq, kh=kh, group=group,
-        sliding_window=sliding_window, softcap=softcap,
-        kv_bits=kv_bits, quantized=quantized,
-        v_dim=v_dim if latent else None)
+        _ragged_kernel, page_size=page_size, n=n, block_q=block_q,
+        tile=_ragged_tile_rows(group, block_q), kh=kh, group=group,
+        token_major=token_major, words=words,
+        sliding_window=sliding_window, softcap=softcap, v_dim=v_dim)
+    # [T, H, D] → [K, G, T, D]: q heads grouped by their kv head
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape[:-1] + (dv,), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((kh, group, t, dv), q.dtype),
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_RAGGED_VMEM_LIMIT),
         name="mla_ragged" if latent else "ragged_paged_attention",
-    )(tables.astype(jnp.int32), seq_of_block.astype(jnp.int32),
-      block_qstart.astype(jnp.int32), query_offsets.astype(jnp.int32),
-      kv_valid.astype(jnp.int32), *operands)
+    )(tables, seq_of_block, block_qstart, segments, following,
+      query_offsets, kv_valid,
+      q.reshape(t, kh, group, d).transpose(1, 2, 0, 3), *pools)
     return out.transpose(2, 0, 1, 3).reshape(t, h, dv)
 
 

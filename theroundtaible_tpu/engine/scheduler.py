@@ -1636,14 +1636,17 @@ class SessionScheduler:
     def _end_segment(self, seg, steps: int, decode_tokens: int,
                      prefill_tokens: int = 0, drafted: int = 0,
                      accepted: int = 0, in_flight: int = 0,
-                     read_to: tuple = ()) -> None:
+                     read_to: tuple = (),
+                     ragged: Optional[dict] = None) -> None:
         """Emit a segment span with the counts its fold produced, and
         the pool's pages in use at its end (the pool's peak over any
         stretch is the maximum over that stretch's segment spans).
         `in_flight`: segments issued after this one and not yet read.
         `read_to`: how many positions each row's attention read at the
         segment's last step (a model with latent pages counts them:
-        `latent_positions`, every step's reads of every row)."""
+        `latent_positions`, every step's reads of every row).
+        `ragged`: the dispatched ragged batch, for what its attention
+        read in page visits (engine._note_page_visits)."""
         latent = None
         if getattr(self.engine.cfg, "latent", False):
             latent = sum(steps * v - steps * (steps - 1) // 2
@@ -1663,6 +1666,10 @@ class SessionScheduler:
                          accepted=accepted)
         if latent is not None:
             seg.attrs["latent_positions"] = latent
+        if ragged is not None and "page_visits" in ragged:
+            seg.attrs.update(
+                page_visits=ragged["page_visits"],
+                page_visits_by_eights=ragged["page_visits_by_eights"])
         if self.engine.kv_layout == "paged":
             seg.attrs["pages_in_use"] = self.engine.kv.pages_in_use()
         if hy is not None:
@@ -1899,7 +1906,8 @@ class SessionScheduler:
         self._end_segment(seg, 1, n_decode, n_prefill,
                           read_to=tuple(r.pos if kind != "decode"
                                         else r.valid
-                                        for kind, r, _take in rows_in))
+                                        for kind, r, _take in rows_in),
+                          ragged=batch)
         occ = len(seqs)
         self.max_occupancy = max(self.max_occupancy, occ)
         with self._cv:
@@ -2381,7 +2389,8 @@ class SessionScheduler:
         telemetry.inc("roundtable_sched_spec_segments_total",
                       engine=self._tname)
         self._note_segment_tokens(0, n_emit)
-        self._end_segment(seg, 1, n_emit, 0, drafted_tot, accepted_tot)
+        self._end_segment(seg, 1, n_emit, 0, drafted_tot, accepted_tot,
+                          ragged=batch)
         occ = len(seqs)
         self.max_occupancy = max(self.max_occupancy, occ)
         with self._cv:

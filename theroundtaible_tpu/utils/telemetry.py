@@ -1134,6 +1134,29 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "ragged_decline": "static (engine.ragged_fallback_reason)",
         "latent_positions": "roundtable_mla_latent_positions_total",
     },
+    # engine.describe()["ragged"] (ISSUE 8, 32): the ragged seam's
+    # provenance. Static but for the dispatch counts and what the
+    # dispatches' attention read, in page visits — as the kernel that
+    # served them blocks a run's rows, and at the packing's 8-row
+    # blocks (engine._note_page_visits is the one writer of both
+    # totals and both series; a ragged `segment` span carries its own
+    # dispatch's share).
+    "engine_ragged": {
+        "enabled": "static (the seam is on)",
+        "path": "static (pallas_ragged | xla_ragged)",
+        "reason": "static (why the seam is off)",
+        "fallback_reason": "static (ragged_decline_reason)",
+        "tokens_budget": "static (flat-buffer rows a dispatch)",
+        "shapes": "static (the flat-buffer shape grid)",
+        "defer_min_tokens": "static (joins below it keep the prologue)",
+        "dispatches": "roundtable_sched_ragged_segments_total / "
+                      "roundtable_sched_spec_segments_total "
+                      "(+ warmup dispatches), split by path",
+        "page_visits": "roundtable_ragged_page_visits_total",
+        "page_visits_by_eights":
+            "roundtable_ragged_page_visits_by_eights_total",
+        "recent": "ring view (the last dispatches' provenance)",
+    },
     # Gateway.describe() (ISSUE 16): the HTTP front door's admission /
     # shed / stream provenance — counters move in lockstep with the
     # registry series (AdmissionController._count is the one writer).
