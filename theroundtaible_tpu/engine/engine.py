@@ -156,6 +156,7 @@ class InferenceEngine:
             # what declines is what reads inside a page or a weight leaf.
             why = ("recurrent-state" if model_cfg.recurrent
                    else "latent-pages" if model_cfg.latent
+                   else "attn-layers" if model_cfg.attn_layers
                    else "layer-kinds")
             if kv_layout != "paged":
                 raise ValueError(
@@ -175,7 +176,8 @@ class InferenceEngine:
             if kv_quant and kv_quant != "none":
                 self.declines["kv_quant"] = (
                     f"{why}:cells-are-per-head" if model_cfg.latent
-                    else why)
+                    else why if model_cfg.recurrent
+                    else f"{why}:step-programs-carry-no-scale-pools")
                 kv_quant = None
             if seq_parallel and seq_parallel > 1:
                 self.declines["seq_parallel"] = why
@@ -617,11 +619,15 @@ class InferenceEngine:
             if self.mesh.devices.size > 1 and kh_l % max(n_model, 1) == 0:
                 kh_l //= max(n_model, 1)   # kernel sees the local shard
             group = model_cfg.num_heads // model_cfg.page_heads
+            # Every geometry the attention layers have (one, but for a
+            # model with attn_layers) must fit both kernels.
+            groups = sorted({h // model_cfg.page_heads for h, _w, _n
+                             in model_cfg.attention_classes})
             self.paged_direct = (
                 attn != "dense"
-                and paged_pool_direct_supported(
+                and all(paged_pool_direct_supported(
                     MAX_PREFILL_CHUNK, page_size, model_cfg.page_width,
-                    kh_l, group)
+                    kh_l, g) for g in groups)
                 and (self.mesh.devices.size == 1
                      or spmd_partitionable(model_cfg.num_heads,
                                            model_cfg.num_kv_heads,
@@ -892,6 +898,10 @@ class InferenceEngine:
         # arguments)
         self._ragged_visits = [0, 0]
         self._ragged_pool_shape: tuple = ()
+        # ... and what every segment's attention read by layer class,
+        # for a model whose attention layers differ (attn_layers)
+        self._window_reads = {"page_visits_full": 0,
+                              "page_visits_window": 0}
         if kv_layout == "paged":
             from .prefix_cache import env_flag
             from .pallas import attention as _pattn
@@ -931,9 +941,15 @@ class InferenceEngine:
                              q_itemsize=jnp.dtype(dtype).itemsize,
                              latent=model_cfg.latent,
                              quantized=self.kv_quant_spec is not None))
-                    decline = _pattn.ragged_decline_reason(
-                        *self._ragged_pool_shape[0],
-                        **self._ragged_pool_shape[1])
+                    # ... and every other geometry the attention
+                    # layers have: the first that declines decides.
+                    decline = next(
+                        (r for r in (
+                            _pattn.ragged_decline_reason(
+                                *self._ragged_class_shape(h),
+                                **self._ragged_pool_shape[1])
+                            for h, _w, _n in model_cfg.attention_classes)
+                         if r is not None), None)
                 if (decline is None
                         and self.kv_quant_fallback_reason is not None):
                     # Quantized pool the kernel cannot dequantize
@@ -943,6 +959,9 @@ class InferenceEngine:
                 self.ragged_path = ("pallas_ragged" if decline is None
                                     else "xla_ragged")
                 self.ragged_fallback_reason = decline
+                if decline is not None and model_cfg.attn_layers:
+                    # one of the layers' geometries does not fit
+                    self.declines["ragged_kernel"] = decline
 
             @partial(jax.jit, donate_argnums=(1,),
                      static_argnames=("greedy", "attn_path",
@@ -2072,28 +2091,120 @@ class InferenceEngine:
         self._note_page_visits(batch, kernel=path == "pallas_ragged")
         return nxt
 
+    def _ragged_class_shape(self, num_heads: int) -> tuple:
+        """ragged_decline_reason's positional arguments
+        (`_ragged_pool_shape[0]`) for attention layers of `num_heads`."""
+        page_size, width, kh_l, _group = self._ragged_pool_shape[0]
+        return page_size, width, kh_l, num_heads // self.cfg.page_heads
+
     def _note_page_visits(self, batch: dict, kernel: bool) -> None:
         """Count what this dispatch's attention read, in page visits
         (pallas.attention.ragged_page_visits): as the kernel that
-        served it blocks the runs, and at the packing's 8-row blocks.
-        One writer for the lifetime totals, their series and the two
-        fields the scheduler puts on the dispatch's `segment` span."""
+        served it blocks the runs, and at the packing's 8-row blocks —
+        of one layer at the model's own geometry (for a model whose
+        attention layers differ: the model-level fields, a layer
+        without a window). One writer for the lifetime totals, their
+        series and the two fields the scheduler puts on the dispatch's
+        `segment` span. A model with `attn_layers` also counts every
+        layer by its class (`_note_window_reads`)."""
         from ..utils import telemetry
         from .pallas import attention as pattn
-        block = pattn.RAGGED_BLOCK_Q
-        if kernel and self._ragged_pool_shape:
-            args, kw = self._ragged_pool_shape
-            block = pattn.ragged_query_block(len(batch["tokens"]), *args,
-                                             **kw)
-        visits = pattn.ragged_page_visits(
-            batch, page_size=self.kv.page_size, block_q=block,
-            sliding_window=self.cfg.sliding_window)
+        made: dict = {}
+
+        def visits(heads: int, window) -> tuple[int, int]:
+            # (a class with the model-level geometry is counted once)
+            if (heads, window) not in made:
+                block = pattn.RAGGED_BLOCK_Q
+                if kernel and self._ragged_pool_shape:
+                    block = pattn.ragged_query_block(
+                        len(batch["tokens"]),
+                        *self._ragged_class_shape(heads),
+                        **self._ragged_pool_shape[1])
+                made[heads, window] = pattn.ragged_page_visits(
+                    batch, page_size=self.kv.page_size, block_q=block,
+                    sliding_window=window)
+            return made[heads, window]
+
+        own = visits(self.cfg.num_heads, self.cfg.sliding_window)
         for i, name in enumerate(("page_visits",
                                   "page_visits_by_eights")):
-            batch[name] = visits[i]
-            self._ragged_visits[i] += visits[i]
-            telemetry.inc(f"roundtable_ragged_{name}_total", visits[i],
+            batch[name] = own[i]
+            self._ragged_visits[i] += own[i]
+            telemetry.inc(f"roundtable_ragged_{name}_total", own[i],
                           engine=self.cfg.name)
+        if self.cfg.attn_layers is None:
+            return
+        reads = {"page_visits_full": 0, "page_visits_window": 0}
+        for heads, window, layers in self.cfg.attention_classes:
+            reads["page_visits_full" if window is None
+                  else "page_visits_window"] += \
+                visits(heads, window)[0] * layers
+        batch["window_reads"] = self._note_window_reads(reads)
+
+    def plain_window_reads(self, steps: int, read_to: tuple) -> dict:
+        """What the attention layers of a plain decode segment read, by
+        layer class, in page visits (the decode walk's own span of a
+        row: pallas.attention._paged_decode_kernel), from how many
+        positions each row's cache held at its last step."""
+        ps = self.kv.page_size
+        valid = (np.asarray(read_to, np.int64)[:, None]
+                 - np.arange(steps)[None, :])               # [rows, steps]
+        valid = np.maximum(valid, 0)
+        hi = np.maximum(valid - 1, 0) // ps
+        reads = {"page_visits_full": 0, "page_visits_window": 0}
+        for _heads, window, layers in self.cfg.attention_classes:
+            lo = 0 if window is None else np.maximum(
+                0, (valid - window) // ps)
+            reads["page_visits_full" if window is None
+                  else "page_visits_window"] += layers * int(
+                np.where(valid > 0, hi - lo + 1, 0).sum())
+        return self._note_window_reads(reads)
+
+    def _note_window_reads(self, reads: dict) -> dict:
+        """The one writer of the lifetime totals of a segment's reads
+        by layer class and of their series."""
+        from ..utils import telemetry
+        for name, n in reads.items():
+            self._window_reads[name] += n
+            telemetry.inc(f"roundtable_window_{name}_total", n,
+                          engine=self.cfg.name)
+        return reads
+
+    def attention_describe(self) -> dict[str, Any]:
+        """Attention layers that differ from one another
+        (ModelConfig.attn_layers): each layer's geometry, the kernels'
+        lowerings the classes need, and what the segments' attention
+        read by class, in page visits (`_note_page_visits`,
+        `plain_window_reads`)."""
+        from .pallas import attention as pattn
+        cfg = self.cfg
+        itemsize = self.kv.pools[0][0].dtype.itemsize
+        return {
+            "kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+            "gate": "per-head" if cfg.attn_gate else None,
+            "layers": [
+                {"layer": li, "heads": v.num_heads,
+                 "window": v.sliding_window,
+                 "rope_theta": v.rope_theta,
+                 "rotary_dim": v.rotary_dim or cfg.head_dim,
+                 "rope_yarn": (None if v.rope_yarn is None
+                               else list(v.rope_yarn)),
+                 "rope_attention_factor": v.rope_attention_factor}
+                for li, v in zip(cfg.attention_layers,
+                                 cfg.attention_views)],
+            "classes": [
+                {"heads": h, "window": w, "layers": n,
+                 "decode_decline": pattn.paged_decode_decline_reason(
+                     self.kv.page_size, cfg.page_width, cfg.page_heads,
+                     h // cfg.page_heads, itemsize=itemsize),
+                 "ragged_decline": (
+                     pattn.ragged_decline_reason(
+                         *self._ragged_class_shape(h),
+                         **self._ragged_pool_shape[1])
+                     if self._ragged_pool_shape else None)}
+                for h, w, n in cfg.attention_classes],
+            **self._window_reads,
+        }
 
     def ragged_describe(self) -> dict[str, Any]:
         """Ragged-path provenance (ISSUE 8): the resolved path, why the
@@ -3258,6 +3369,8 @@ class InferenceEngine:
                            "top_k": self.cfg.moe_top_k,
                            "expert_layers": len(self.cfg.expert_layers),
                            **self.hybrid.moe_totals()}
+        if self.cfg.attn_layers is not None and self.kv_layout == "paged":
+            info["attention"] = self.attention_describe()
         if self.cfg.latent and self.kv_layout == "paged":
             info["mla"] = self.mla_describe()
         # What this model declined at build time, each with its reason.

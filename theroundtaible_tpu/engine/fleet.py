@@ -52,8 +52,16 @@ def estimate_param_count(cfg: ModelConfig) -> int:
                 + e * (r_kv + cfg.qk_rope_dim) + r_kv
                 + r_kv * h * (cfg.qk_nope_dim + cfg.v_head_dim)
                 + h * cfg.v_head_dim * e)
-        return (sum(per_kind[kind] + e for kind in cfg.layer_kinds)
-                + 2 * cfg.vocab_size * e + e)
+        total = (sum(per_kind[kind] + e for kind in cfg.layer_kinds)
+                 + 2 * cfg.vocab_size * e + e)
+        if cfg.attn_layers is not None or cfg.attn_gate:
+            # Each attention layer's own heads (q and out-projection
+            # above were counted at the model-level `h`), and its gate.
+            gate = e if cfg.attn_gate else 0
+            total += sum((v.num_heads - h) * 2 * e * d
+                         + v.num_heads * gate
+                         for v in cfg.attention_views)
+        return total
     mlp = 3 * e * f
     if cfg.num_experts:
         mlp = cfg.num_experts * 3 * e * f + e * cfg.num_experts  # + router

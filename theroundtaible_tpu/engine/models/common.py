@@ -19,6 +19,8 @@ SURVEY.md §2.3). Design rules (SURVEY.md §7, pallas_guide):
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from functools import partial
 from typing import Any, Optional
 
@@ -99,6 +101,21 @@ def _record_int4(spec: str, a, leaf, path: str, reason=None) -> None:
 
 
 @dataclasses.dataclass(frozen=True)
+class AttnLayer:
+    """The geometry of ONE attention layer where the layers of a model
+    differ (`ModelConfig.attn_layers`): its query heads over the model's
+    kv heads, its window (None: causal and unbounded) and its rotary
+    table. The fields are ModelConfig's own, a layer's worth."""
+
+    num_heads: int
+    sliding_window: Optional[int] = None
+    rope_theta: float = 10_000.0
+    rotary_dim: int = 0
+    rope_yarn: Optional[tuple[float, ...]] = None
+    rope_attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters + family behavior flags."""
 
@@ -170,6 +187,21 @@ class ModelConfig:
     # YaRN: (factor, original_max_position_embeddings, beta_fast,
     # beta_slow, mscale, mscale_all_dim), or None for plain rope.
     rope_yarn: Optional[tuple[float, ...]] = None
+    # Grouped-query attention layers of a model with `layer_kinds`:
+    # rotary embedding over the FIRST rotary_dim dimensions of a head,
+    # the rest passed through (0: all of head_dim); the multiplier YaRN
+    # puts on cos and sin, where the config gives one (None: 0.1
+    # ln(factor) + 1); and a sigmoid gate on the attention output before
+    # the out-projection, one logit a head from the layer's normed input.
+    rotary_dim: int = 0
+    rope_attention_factor: Optional[float] = None
+    attn_gate: bool = False
+    # Attention layers that differ from one another: one AttnLayer an
+    # attention layer, in order (`attention_layers`), each overriding
+    # num_heads, sliding_window and the rotary fields above for its
+    # layer (`attention_layer`). The kv heads, head_dim and so the page
+    # pools are the model's: one pool shape, one page table a sequence.
+    attn_layers: Optional[tuple[AttnLayer, ...]] = None
 
     @property
     def kv_repeat(self) -> int:
@@ -227,6 +259,27 @@ class ModelConfig:
             return tuple(range(self.num_layers))
         return self._layers_of("attention")
 
+    def attention_layer(self, ai: int) -> "ModelConfig":
+        """This config as the `ai`-th attention layer sees it: itself,
+        or with that layer's own geometry in the model-level fields."""
+        if self.attn_layers is None:
+            return self
+        return _attention_layer_view(self, ai)
+
+    @property
+    def attention_views(self) -> tuple["ModelConfig", ...]:
+        """`attention_layer(ai)` of every attention layer, in order."""
+        return tuple(self.attention_layer(ai)
+                     for ai in range(len(self.attention_layers)))
+
+    @property
+    def attention_classes(self) -> tuple[tuple[int, Optional[int], int],
+                                         ...]:
+        """The distinct (num_heads, sliding_window) among the attention
+        layers, each with how many layers have it: one lowering of each
+        paged kernel a class, one class of page visits."""
+        return _attention_classes(self)
+
     @property
     def mamba_d_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
@@ -234,6 +287,21 @@ class ModelConfig:
     @property
     def mamba_conv_dim(self) -> int:
         return self.mamba_d_inner + 2 * self.ssm_groups * self.ssm_state
+
+
+@functools.lru_cache(maxsize=None)
+def _attention_layer_view(cfg: ModelConfig, ai: int) -> ModelConfig:
+    return dataclasses.replace(
+        cfg, attn_layers=None, **dataclasses.asdict(cfg.attn_layers[ai]))
+
+
+@functools.lru_cache(maxsize=None)
+def _attention_classes(cfg: ModelConfig) -> tuple:
+    counts: dict = {}
+    for v in cfg.attention_views:
+        key = (v.num_heads, v.sliding_window)
+        counts[key] = counts.get(key, 0) + 1
+    return tuple(k + (n,) for k, n in counts.items())
 
 
 # --- primitives ---
@@ -251,10 +319,12 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float,
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float,
-         inv_freq: Optional[jax.Array] = None) -> jax.Array:
+         inv_freq: Optional[jax.Array] = None,
+         scale: float = 1.0) -> jax.Array:
     """Rotary position embedding. x: [B, T, H, D], positions: [B, T].
     `inv_freq` [D/2], where given, replaces theta's own frequencies
-    (YaRN's blend: models/mla.py)."""
+    (YaRN's blend, `yarn_inv_freq`); `scale` multiplies cos and sin
+    (YaRN's attention factor, as `transformers` applies it)."""
     head_dim = x.shape[-1]
     pos = positions[..., None].astype(jnp.float32)
     if inv_freq is None:
@@ -265,9 +335,64 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
         angles = pos * inv_freq
     angles = angles[:, :, None, :]                      # [B, T, 1, D/2]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max: float, beta_fast: float,
+                  beta_slow: float):
+    """[dim/2] rotary frequencies: theta's own where a dimension turns
+    more than beta_fast times over the original context, theta's / factor
+    where it turns less than beta_slow times, a linear ramp between."""
+    import numpy as np
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_heads(x: jax.Array, positions: jax.Array,
+               cfg: ModelConfig) -> jax.Array:
+    """The rotary embedding of one grouped-query attention layer over
+    q or k [B, T, H, D], by `cfg`'s rotary fields (a layer's own where
+    layers differ: ModelConfig.attention_layer): the first
+    `rotary_dim` dimensions turn, dimension j paired with j +
+    rotary_dim / 2, the rest pass through unscaled; YaRN's blended
+    frequencies and its factor on cos and sin where `rope_yarn` is
+    given (the first four entries; the factor is
+    `rope_attention_factor`, else 0.1 ln(factor) + 1)."""
+    d = x.shape[-1]
+    rot = cfg.rotary_dim or d
+    if cfg.rope_yarn is None and rot == d:
+        return rope(x, positions, cfg.rope_theta)
+    inv_freq, scale = None, 1.0
+    if cfg.rope_yarn is not None:
+        factor, original_max, fast, slow = cfg.rope_yarn[:4]
+        inv_freq = jnp.asarray(yarn_inv_freq(
+            rot, cfg.rope_theta, factor, original_max, fast, slow))
+        scale = (cfg.rope_attention_factor
+                 if cfg.rope_attention_factor is not None
+                 else yarn_mscale(factor, 1.0))
+    turned = rope(x[..., :rot], positions, cfg.rope_theta,
+                  inv_freq=inv_freq, scale=scale)
+    return jnp.concatenate([turned, x[..., rot:]], axis=-1)
 
 
 def _softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
@@ -450,8 +575,8 @@ def project_qkv(
         v = v + layer["v_bias"].astype(jnp.float32)
 
     if cfg.rope:
-        q = rope(q.astype(x.dtype), positions, cfg.rope_theta)
-        k = rope(k.astype(x.dtype), positions, cfg.rope_theta)
+        q = rope_heads(q.astype(x.dtype), positions, cfg)
+        k = rope_heads(k.astype(x.dtype), positions, cfg)
     else:
         q, k = q.astype(x.dtype), k.astype(x.dtype)
     v = v.astype(x.dtype)
@@ -460,6 +585,17 @@ def project_qkv(
              if cfg.query_pre_attn_scalar is not None
              else cfg.head_dim ** -0.5)
     return q * scale, k, v
+
+
+def gate_heads(out: jax.Array, x: jax.Array, layer: Params,
+               cfg: ModelConfig) -> jax.Array:
+    """`cfg.attn_gate`: the attention result [B, T, H, D] times
+    sigmoid(x W_g) — x the layer's normed input [B, T, E], W_g [E, H],
+    one logit a head — before the out-projection."""
+    if not cfg.attn_gate:
+        return out
+    g = _einsum("bte,eh->bth", x, layer["g_proj"])[..., None]
+    return (out.astype(jnp.float32) * jax.nn.sigmoid(g)).astype(out.dtype)
 
 
 def attention(
@@ -516,7 +652,8 @@ def attention(
                     sliding_window=cfg.sliding_window,
                     softcap=cfg.attn_logit_softcap)
         if out is not None:
-            out = _einsum("bthd,hde->bte", out, layer["o_proj"],
+            out = _einsum("bthd,hde->bte", gate_heads(out, x, layer, cfg),
+                          layer["o_proj"],
                           tp="row", lora="o_proj").astype(x.dtype)
             return out, (k_cache, v_cache)
 
@@ -532,7 +669,8 @@ def attention(
     logits = jnp.where(attn_mask[:, None, :, :], logits, MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     out = _einsum("bhts,bshd->bthd", probs, v_att).astype(x.dtype)
-    out = _einsum("bthd,hde->bte", out, layer["o_proj"],
+    out = _einsum("bthd,hde->bte", gate_heads(out, x, layer, cfg),
+                  layer["o_proj"],
                   tp="row", lora="o_proj").astype(x.dtype)
     return out, (k_cache, v_cache)
 
@@ -698,11 +836,13 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
     from . import hybrid
     b, t = tokens.shape
     x = embed_tokens(params["embedding"], tokens)
-    mask = make_attention_mask(positions, t, kv_valid_len,
-                               cfg.sliding_window)
     zero = hybrid.zero_state(cfg, b)
     caches = []
     for kind, layer in zip(cfg.layer_kinds, params["layers"]):
+        if kind == hybrid.ATTENTION:
+            lcfg = cfg.attention_layer(len(caches))
+            mask = make_attention_mask(positions, t, kv_valid_len,
+                                       lcfg.sliding_window)
         h = hybrid.layer_norm_in(x, layer, cfg)
         if kind == hybrid.MAMBA2:
             out, _, _ = hybrid.mamba2_prefill(
@@ -718,7 +858,7 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
                                              mask)
             caches.append(kv)
         else:
-            out, kv = attention(h, layer, cfg, positions, None, None,
+            out, kv = attention(h, layer, lcfg, positions, None, None,
                                 mask, kv_valid_len)
             caches.append(kv)
         x = x + out
@@ -754,8 +894,11 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             "embedding": jax.random.normal(
                 k_embed, (cfg.vocab_size, cfg.embed_dim),
                 jnp.float32).astype(dtype),
-            "layers": [hybrid.init_layer(cfg, kind, lk, dtype)
-                       for kind, lk in zip(cfg.layer_kinds, keys)],
+            "layers": [hybrid.init_layer(
+                cfg.attention_layer(cfg.attention_layers.index(i))
+                if kind == hybrid.ATTENTION else cfg, kind, lk, dtype)
+                for i, (kind, lk) in enumerate(zip(cfg.layer_kinds,
+                                                   keys))],
             "final_norm": jnp.ones((cfg.embed_dim,), dtype),
             "lm_head": (jax.random.normal(
                 k_head, (cfg.vocab_size, cfg.embed_dim), jnp.float32)

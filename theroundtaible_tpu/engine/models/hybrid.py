@@ -488,7 +488,8 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
                dtype) -> Params:
     """Random weights of one layer, by kind: in-projections at the
     scale that keeps activations of order one, out-projections at
-    RESIDUAL_SHARE of it."""
+    RESIDUAL_SHARE of it. An attention layer is given ITS view of the
+    config (ModelConfig.attention_layer): its own head count."""
     e = cfg.embed_dim
     ks = jax.random.split(key, 8)
 
@@ -563,6 +564,8 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
             "v_proj": dense(ks[2], (e, k_, d), e),
             "o_proj": dense(ks[3], (h_, d, e), h_ * d, RESIDUAL_SHARE),
         })
+        if cfg.attn_gate:
+            layer["g_proj"] = dense(ks[4], (e, h_), e)
     else:
         raise ValueError(f"unknown layer kind {kind!r}")
     return layer

@@ -39,38 +39,12 @@ mscale_all_dim) is 1 wherever the two are equal (`axk1`: both 1).
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .common import (MASK_VALUE, ModelConfig, Params, _einsum, rms_norm,
-                     rope)
-
-
-def yarn_mscale(factor: float, mscale: float) -> float:
-    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
-
-
-def yarn_inv_freq(dim: int, theta: float, factor: float,
-                  original_max: float, beta_fast: float,
-                  beta_slow: float) -> np.ndarray:
-    """[dim/2] rotary frequencies: theta's own where a dimension turns
-    more than beta_fast times over the original context, theta's / factor
-    where it turns less than beta_slow times, a linear ramp between."""
-    def correction_dim(rotations):
-        return (dim * math.log(original_max / (rotations * 2 * math.pi))
-                / (2 * math.log(theta)))
-
-    low = max(math.floor(correction_dim(beta_fast)), 0)
-    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / (high - low), 0.0, 1.0)
-    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+                     rope, yarn_inv_freq, yarn_mscale)
 
 
 def rope_frequencies(cfg: ModelConfig) -> tuple[np.ndarray, float]:

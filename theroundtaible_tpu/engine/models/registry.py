@@ -19,9 +19,10 @@ keys; a key or `model_type` this engine cannot run fails at once.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
-from .common import ModelConfig
+from .common import AttnLayer, ModelConfig
 from .hybrid import ATTENTION, EXPERTS, MLP, kinds_of_pattern
 
 _REGISTRY: dict[str, ModelConfig] = {}
@@ -182,6 +183,74 @@ TINY_AXK1 = register(ModelConfig(
     router_rule="sigmoid_topk", expert_act="silu", expert_gated=True))
 
 
+# --- Laguna / laguna (window and full attention layers with a geometry of
+# their own each over one GQA page pool, a per-head output gate; a dense
+# MLP or routed + shared gated experts; a published layer is TWO layers
+# here, as axk1's) ---
+
+def laguna_layers(layer_types, mlp_layer_types, heads_per_layer, *,
+                  window: int, full: AttnLayer, sliding: AttnLayer):
+    """(layer_kinds, attn_layers) of a laguna stack: `full` and `sliding`
+    carry each type's rotary table; the heads are the layer's own and
+    the window the sliding layers' alone."""
+    kinds, geometry = [], []
+    for lt, mt, heads in zip(layer_types, mlp_layer_types,
+                             heads_per_layer):
+        if lt not in ("full_attention", "sliding_attention") \
+                or mt not in ("dense", "sparse"):
+            raise ValueError(f"laguna layer types ({lt!r}, {mt!r}): "
+                             "known are full_attention / "
+                             "sliding_attention and dense / sparse")
+        kinds += [ATTENTION, MLP if mt == "dense" else EXPERTS]
+        geometry.append(dataclasses.replace(
+            full if lt == "full_attention" else sliding,
+            num_heads=int(heads),
+            sliding_window=None if lt == "full_attention" else window))
+    return tuple(kinds), tuple(geometry)
+
+
+def _laguna_preset(name, *, blocks, heads, **kw):
+    """dense + `S S S F ...`: block 0 full attention and a dense MLP,
+    then sliding, sliding, sliding, full with experts."""
+    types = ["full_attention" if b % 4 == 0 else "sliding_attention"
+             for b in range(blocks)]
+    kinds, geometry = laguna_layers(
+        types, ["dense"] + ["sparse"] * (blocks - 1),
+        [heads[0] if t == "full_attention" else heads[1] for t in types],
+        window=kw.pop("window"), full=kw.pop("full"),
+        sliding=kw.pop("sliding"))
+    return ModelConfig(
+        name=name, num_layers=2 * blocks, num_heads=heads[0],
+        norm_eps=1e-6, tie_embeddings=False, layer_kinds=kinds,
+        attn_layers=geometry, attn_gate=True, moe_top_k=kw.pop(
+            "top_k"), routed_scaling=2.5, router_rule="sigmoid_topk",
+        expert_act="silu", expert_gated=True, **kw)
+
+
+LAGUNA_XS2 = register(_laguna_preset(
+    "laguna-xs.2", blocks=40, heads=(48, 64), vocab_size=100_352,
+    embed_dim=2048, num_kv_heads=8, head_dim=128, mlp_dim=8192,
+    max_seq_len=8192, window=512,
+    full=AttnLayer(0, rope_theta=500_000.0, rotary_dim=64,
+                   rope_yarn=(64.0, 4096.0, 64.0, 1.0),
+                   rope_attention_factor=1.4158883083359672),
+    sliding=AttnLayer(0, rope_theta=10_000.0, rotary_dim=128),
+    routed_experts=256, experts_held=256, top_k=8, expert_dim=512,
+    shared_expert_dim=512))
+
+# Groups 3 and 4 over 2 kv heads, a window of two 8-wide pages, YaRN
+# over half a head on the full layers.
+TINY_LAGUNA = register(_laguna_preset(
+    "tiny-laguna", blocks=5, heads=(6, 8), vocab_size=512, embed_dim=64,
+    num_kv_heads=2, head_dim=16, mlp_dim=128, max_seq_len=512, window=16,
+    full=AttnLayer(0, rope_theta=500_000.0, rotary_dim=8,
+                   rope_yarn=(8.0, 32.0, 64.0, 1.0),
+                   rope_attention_factor=1.2079441541679836),
+    sliding=AttnLayer(0, rope_theta=10_000.0, rotary_dim=16),
+    routed_experts=8, experts_held=8, top_k=2, expert_dim=32,
+    shared_expert_dim=32))
+
+
 # --- from a published config.json -------------------------------------------
 
 # Keys of a nemotron_h config.json that say nothing this engine acts on
@@ -218,8 +287,8 @@ def _acted_on(name: str, arch: dict[str, Any], kind: str,
     return arch
 
 
-def _all_read(name: str, arch: dict[str, Any], kind: str, ep_rank: int,
-              ep_size: int) -> None:
+def _all_read(name: str, arch: dict[str, Any], kind: str,
+              ep_rank: int = 0, ep_size: int = 1) -> None:
     """What is left of `arch` when every key has been read is unknown."""
     if arch:
         raise ValueError(f"architecture of {name!r}: unknown keys "
@@ -344,6 +413,106 @@ def _axk1(name: str, arch: dict[str, Any],
     return cfg
 
 
+# Keys of a laguna config.json that say nothing this engine acts on
+# (the rotary fraction and the original context are read a layer type,
+# from rope_parameters), and the values its layer equations assume.
+# `scoring_func` is this engine's key, not a published one: the config
+# names no scoring rule, and sigmoid is what a scale of 2.5 beside a
+# shared expert goes with.
+_LAGUNA_INERT = {"model_type", "max_position_embeddings",
+                 "partial_rotary_factor"}
+_LAGUNA_FIXED = {
+    "attention_bias": False, "tie_word_embeddings": False,
+    "moe_apply_router_weight_on_input": False, "norm_topk_prob": True,
+    "moe_router_logit_softcapping": 0, "scoring_func": "sigmoid"}
+
+
+def _laguna_rotary(name: str, kind: str, params: dict[str, Any],
+                   head_dim: int) -> AttnLayer:
+    """One layer type's entry of `rope_parameters` -> its rotary table
+    (the heads and the window are filled in a layer)."""
+    params = dict(params)
+    rope_type = params.pop("rope_type", "default")
+    table = {"rope_theta": float(params.pop("rope_theta")),
+             "rotary_dim": int(head_dim * float(
+                 params.pop("partial_rotary_factor", 1)))}
+    if rope_type == "yarn":
+        table["rope_yarn"] = tuple(float(params.pop(k)) for k in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow"))
+        factor = params.pop("attention_factor", None)
+        table["rope_attention_factor"] = \
+            None if factor is None else float(factor)
+    elif rope_type != "default":
+        raise ValueError(f"architecture of {name!r}: rope_type "
+                         f"{rope_type!r} of {kind} layers is not one "
+                         "this engine runs (default, yarn)")
+    if params:
+        raise ValueError(f"architecture of {name!r}: unknown "
+                         f"rope_parameters.{kind} keys {sorted(params)}")
+    return AttnLayer(0, **table)
+
+
+def _laguna(name: str, arch: dict[str, Any],
+            max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "laguna", _LAGUNA_FIXED, _LAGUNA_INERT)
+    try:
+        n_blocks = int(arch.pop("num_hidden_layers"))
+        head_dim = int(arch.pop("head_dim"))
+        rotary = dict(arch.pop("rope_parameters"))
+        rotary.pop("original_max_position_embeddings", None)
+        full = _laguna_rotary(name, "full_attention",
+                              rotary.pop("full_attention"), head_dim)
+        sliding = _laguna_rotary(name, "sliding_attention",
+                                 rotary.pop("sliding_attention"), head_dim)
+        if rotary:
+            raise ValueError(f"architecture of {name!r}: unknown "
+                             f"rope_parameters keys {sorted(rotary)}")
+        lists = [list(arch.pop(k)) for k in (
+            "layer_types", "mlp_layer_types",
+            "num_attention_heads_per_layer")]
+        if any(len(x) != n_blocks for x in lists):
+            raise ValueError(
+                f"architecture of {name!r}: layer_types, mlp_layer_types "
+                "and num_attention_heads_per_layer have "
+                f"{[len(x) for x in lists]} entries, num_hidden_layers "
+                f"says {n_blocks}")
+        kinds, geometry = laguna_layers(
+            *lists, window=int(arch.pop("sliding_window")), full=full,
+            sliding=sliding)
+        gating = arch.pop("gating")
+        if gating is not True and gating != "per-head":
+            raise ValueError(
+                f"architecture of {name!r}: gating={gating!r}, and this "
+                "engine's laguna layers are written for true / "
+                "'per-head' (one logit a head)")
+        held = int(arch.pop("num_experts"))
+        cfg = ModelConfig(
+            name=name, vocab_size=int(arch.pop("vocab_size")),
+            num_layers=2 * n_blocks,
+            embed_dim=int(arch.pop("hidden_size")),
+            num_heads=int(arch.pop("num_attention_heads")),
+            num_kv_heads=int(arch.pop("num_key_value_heads")),
+            head_dim=head_dim, mlp_dim=int(arch.pop("intermediate_size")),
+            max_seq_len=max_seq_len,
+            norm_eps=float(arch.pop("rms_norm_eps")),
+            tie_embeddings=False, layer_kinds=kinds,
+            attn_layers=geometry, attn_gate=True,
+            routed_experts=held, experts_held=held,
+            moe_top_k=int(arch.pop("num_experts_per_tok")),
+            expert_dim=int(arch.pop("moe_intermediate_size")),
+            shared_expert_dim=int(
+                arch.pop("shared_expert_intermediate_size")),
+            routed_scaling=float(arch.pop("moe_routed_scaling_factor")),
+            router_rule="sigmoid_topk", expert_act="silu",
+            expert_gated=True)
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    _all_read(name, arch, "laguna")
+    return cfg
+
+
 def _dense_gqa(name: str, arch: dict[str, Any],
                max_seq_len: int) -> ModelConfig:
     heads = int(arch["num_attention_heads"])
@@ -381,6 +550,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
         return _nemotron_h(name, arch, max_seq_len)
     if kind == "axk1":
         return _axk1(name, arch, max_seq_len)
+    if kind == "laguna":
+        return _laguna(name, arch, max_seq_len)
     if kind in _DENSE_TYPES:
         try:
             return _dense_gqa(name, arch, max_seq_len)
@@ -389,7 +560,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
                              f"{e.args[0]!r}") from None
     raise ValueError(
         f"architecture of {name!r}: model_type {kind!r} is not one this "
-        f"engine runs (nemotron_h, axk1, {', '.join(_DENSE_TYPES)})")
+        f"engine runs (nemotron_h, axk1, laguna, "
+        f"{', '.join(_DENSE_TYPES)})")
 
 
 def get_model_config(name: str, **overrides) -> ModelConfig:

@@ -43,8 +43,8 @@ from . import kv_quant as kvq
 from .models import mla
 from .models.common import (MASK_VALUE, ModelConfig, Params, _einsum,
                             _softcap, current_spmd_mesh, embed_tokens,
-                            gather_rows, mlp, project_qkv, rms_norm,
-                            transformer_block)
+                            gate_heads, gather_rows, mlp, project_qkv,
+                            rms_norm, transformer_block)
 from .pallas import attention as pattn
 
 
@@ -413,12 +413,13 @@ def _attention_io(h, layer, cfg: ModelConfig, positions, dtype):
     return q, (k, v), {}
 
 
-def _attention_out(out, layer, cfg: ModelConfig, dtype):
-    """The kernels' result [B,T,H,*] -> the layer's output [B,T,E]."""
+def _attention_out(out, h, layer, cfg: ModelConfig, dtype):
+    """The kernels' result [B,T,H,*] -> the layer's output [B,T,E];
+    `h` the layer's normed input, which a gated layer's gate reads."""
     if cfg.latent:
         out = mla.values_of(out, layer, cfg)
-    return _einsum("bthd,hde->bte", out, layer["o_proj"],
-                   tp="row").astype(dtype)
+    return _einsum("bthd,hde->bte", gate_heads(out, h, layer, cfg),
+                   layer["o_proj"], tp="row").astype(dtype)
 
 
 def forward_paged_hybrid(
@@ -485,7 +486,10 @@ def forward_paged_hybrid(
         elif kind == hybrid.MLP:
             out = mlp(h, layer, cfg)
         else:
-            q, entries, kw = _attention_io(h, layer, cfg, positions,
+            # The layer's own heads, window and rotary table, where the
+            # attention layers differ (ModelConfig.attn_layers).
+            lcfg = cfg.attention_layer(ai)
+            q, entries, kw = _attention_io(h, layer, lcfg, positions,
                                            pools[ai][0].dtype)
             layer_pools = tuple(p.at[pages, offs].set(e)
                                 for p, e in zip(pools[ai], entries))
@@ -493,19 +497,19 @@ def forward_paged_hybrid(
             if t == 1:
                 out = pattn.paged_decode_attention(
                     q, k_pool, v_pool, table, kv_valid_len,
-                    sliding_window=cfg.sliding_window,
+                    sliding_window=lcfg.sliding_window,
                     softcap=cfg.attn_logit_softcap, **kw)
             else:
                 out = pattn.paged_prefill_attention(
                     q, k_pool, v_pool, table, positions[:, 0],
-                    kv_valid_len, sliding_window=cfg.sliding_window,
+                    kv_valid_len, sliding_window=lcfg.sliding_window,
                     softcap=cfg.attn_logit_softcap, **kw)
             if out is None:
                 raise ValueError(
                     "paged pool-direct kernels declined this shape "
                     f"(T={t}, ps={page_size}); the engine gates hybrid "
                     "models on paged_direct at build time")
-            out = _attention_out(out, layer, cfg, h.dtype)
+            out = _attention_out(out, h, layer, lcfg, h.dtype)
             new_pools.append(layer_pools)
             ai += 1
         x = x + out
@@ -560,7 +564,8 @@ def forward_ragged_hybrid(
         elif kind == hybrid.MLP:
             out = mlp(h, layer, cfg)
         else:
-            q, entries, kw = _attention_io(h, layer, cfg, pos2,
+            lcfg = cfg.attention_layer(ai)
+            q, entries, kw = _attention_io(h, layer, lcfg, pos2,
                                            pools[ai][0].dtype)
             layer_pools = tuple(
                 p.at[token_pages, token_offs].set(e[0])
@@ -570,13 +575,13 @@ def forward_ragged_hybrid(
                 out = pattn.ragged_paged_attention(
                     q[0], k_pool, v_pool, tables, seq_of_block,
                     block_qstart, query_offsets, kv_valid,
-                    sliding_window=cfg.sliding_window,
+                    sliding_window=lcfg.sliding_window,
                     softcap=cfg.attn_logit_softcap, **kw)
             else:
                 out = _ragged_xla_attention(
                     q[0], k_pool, v_pool, tables, token_seq, positions,
-                    kv_valid, cfg)
-            out = _attention_out(out[None], layer, cfg, h.dtype)
+                    kv_valid, lcfg)
+            out = _attention_out(out[None], h, layer, lcfg, h.dtype)
             new_pools.append(layer_pools)
             ai += 1
         x = x + out

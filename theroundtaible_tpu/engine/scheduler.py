@@ -1646,12 +1646,23 @@ class SessionScheduler:
         segment's last step (a model with latent pages counts them:
         `latent_positions`, every step's reads of every row).
         `ragged`: the dispatched ragged batch, for what its attention
-        read in page visits (engine._note_page_visits)."""
+        read in page visits (engine._note_page_visits); a model whose
+        attention layers differ adds `page_visits_full` and
+        `page_visits_window`, by layer class, on every kind of
+        segment."""
         latent = None
         if getattr(self.engine.cfg, "latent", False):
             latent = sum(steps * v - steps * (steps - 1) // 2
                          for v in read_to)
             self.engine.note_latent_positions(latent)
+        # Attention layers that differ (a window on some): what each
+        # class read, counted by the engine at the ragged dispatch or,
+        # for a plain segment, here from the rows' frontiers.
+        window_reads = None
+        if getattr(self.engine.cfg, "attn_layers", None) is not None:
+            window_reads = (
+                ragged.get("window_reads") if ragged is not None
+                else self.engine.plain_window_reads(steps, read_to))
         hy = getattr(self.engine, "hybrid", None)
         if hy is not None:
             # This segment has been read, so every program up to it has
@@ -1670,6 +1681,8 @@ class SessionScheduler:
             seg.attrs.update(
                 page_visits=ragged["page_visits"],
                 page_visits_by_eights=ragged["page_visits_by_eights"])
+        if window_reads is not None:
+            seg.attrs.update(window_reads)
         if self.engine.kv_layout == "paged":
             seg.attrs["pages_in_use"] = self.engine.kv.pages_in_use()
         if hy is not None:

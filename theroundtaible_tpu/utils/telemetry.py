@@ -1134,6 +1134,23 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "ragged_decline": "static (engine.ragged_fallback_reason)",
         "latent_positions": "roundtable_mla_latent_positions_total",
     },
+    # engine.describe()["attention"] (ISSUE 33): attention layers that
+    # differ from one another over one page pool. Static but for what
+    # the segments' attention read by layer class, in page visits
+    # (engine._note_window_reads is the one writer of the two totals
+    # and their series; every `segment` span carries its own share).
+    "engine_attention": {
+        "kv_heads": "static (the pools' heads, every layer's)",
+        "head_dim": "static",
+        "gate": "static (a logit a head, or none)",
+        "layers": "static (each attention layer's heads, window and "
+                  "rotary table)",
+        "classes": "static (distinct (heads, window): layers, and the "
+                   "kernels' decline reasons at that group)",
+        "page_visits_full": "roundtable_window_page_visits_full_total",
+        "page_visits_window":
+            "roundtable_window_page_visits_window_total",
+    },
     # engine.describe()["ragged"] (ISSUE 8, 32): the ragged seam's
     # provenance. Static but for the dispatch counts and what the
     # dispatches' attention read, in page visits — as the kernel that
