@@ -37,6 +37,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from theroundtaible_tpu.engine.pallas import attention as pattn
+from theroundtaible_tpu.engine.pallas import grouped
 from theroundtaible_tpu.engine.pallas import int4mm
 
 D, PAGE = 128, 128           # head_dim and page size of every case
@@ -312,7 +313,8 @@ def test_hybrid_step_of_the_nemotron_cut_compiles(one_chip, monkeypatch,
     Nemotron-3-Nano cut (the pattern's first three kinds, `ME*`, at
     published widths, 64 of 128 experts held), as the hybrid step
     programs wrap them: what the chip's compiler refuses of the scans,
-    the expert loop or the kernels' operands fails here, not there."""
+    the grouped expert product or the kernels' operands fails here, not
+    there."""
     from theroundtaible_tpu.engine.models import hybrid
     from theroundtaible_tpu.engine.models.common import init_params
     from theroundtaible_tpu.engine.models.registry import get_model_config
@@ -323,6 +325,7 @@ def test_hybrid_step_of_the_nemotron_cut_compiles(one_chip, monkeypatch,
                                                         build_ragged_batch)
 
     monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
     cfg = dataclasses.replace(
         get_model_config("nemotron-3-nano-30b-a3b"), num_layers=3,
         layer_kinds=hybrid.kinds_of_pattern("ME*"), vocab_size=65_536,
@@ -379,6 +382,49 @@ def test_hybrid_step_of_the_nemotron_cut_compiles(one_chip, monkeypatch,
                        s((ROWS + 1,), i32),
                        *[s(np.asarray(b[n]).shape, i32) for n in names])
     _assert_kernel(hlo)
+
+
+# --- the routed experts' grouped product (ISSUE 36) -------------------------
+
+# (hidden, expert width, held, top-k, gated, activation) of the three
+# expert cells: Nemotron-3-Nano's share of a pair, A.X-K1's of sixteen,
+# Laguna-XS.2 whole.
+EXPERT_WIDTHS = {
+    "nemotron-3-nano-ep2": (2688, 1856, 64, 6, False, "relu2"),
+    "a.x-k1-ep16": (7168, 2048, 12, 8, True, "silu"),
+    "laguna-xs.2": (2048, 512, 256, 8, True, "silu")}
+
+
+@pytest.mark.parametrize("tokens", [16, 256, 1024])
+@pytest.mark.parametrize("widths", list(EXPERT_WIDTHS))
+def test_the_grouped_product_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, widths, tokens):
+    """Sort, the two or three kernels and the sum back at a decode
+    step's rows (16 slots: 96 or 128 assignments), the 256-token bucket
+    and join and the 1024-token ones (6144 or 8192): two kernels where
+    the experts are not gated, three where they are — and NO copy of the
+    held experts' matrices before them: nemotron_h's up matrices lie
+    contraction-minor on the chip ([64, 2688, 1856] fills no whole lane
+    rows), and a kernel that asks for them the other way has all 638 MB
+    copied before every call (2.0 ms a layer: PERF.md, PR 36)."""
+    from theroundtaible_tpu.engine.models import hybrid
+
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    e, f, held, k, gated, act = EXPERT_WIDTHS[widths]
+    assert grouped.decline_reason(e, f, jnp.bfloat16) is None
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    experts = {"up": s((held, e, f), jnp.bfloat16),
+               "down": s((held, f, e), jnp.bfloat16)}
+    if gated:
+        experts["gate"] = experts["up"]
+    hlo = hybrid.routed_experts.lower(
+        s((tokens, e), jnp.bfloat16), experts, s((tokens, k), jnp.int32),
+        s((tokens, k), jnp.float32), held=held, act=act,
+        gated=gated).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') \
+        == 2 + gated
+    assert not [line for line in hlo.splitlines()
+                if " copy(" in line and f"bf16[{held}," in line]
 
 
 # --- latent pages (ISSUE 31) ------------------------------------------------
@@ -561,7 +607,8 @@ def test_hybrid_step_of_the_axk1_cut_compiles(one_chip, monkeypatch,
     published widths, 12 of 192 experts held, an eighth of the
     vocabulary), as the hybrid step programs wrap them with an EMPTY
     state tree: what the chip's compiler refuses of the absorbed
-    projections, the gated expert loop or the latent kernels' operands
+    projections, the gated experts' grouped product or the latent kernels'
+    operands
     fails here, not there."""
     from theroundtaible_tpu.engine.models import hybrid
     from theroundtaible_tpu.engine.models.common import init_params
@@ -573,6 +620,7 @@ def test_hybrid_step_of_the_axk1_cut_compiles(one_chip, monkeypatch,
                                                         build_ragged_batch)
 
     monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
     cfg = dataclasses.replace(
         get_model_config("a.x-k1"), num_layers=4,
         layer_kinds=axk1_kinds(2, 1), vocab_size=20_480, experts_held=12,
@@ -713,6 +761,7 @@ def test_hybrid_step_of_the_laguna_cut_compiles(one_chip, monkeypatch,
                                                         build_ragged_batch)
 
     monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
     whole = get_model_config("laguna-xs.2")
     # blocks 0 (full, dense), 1 (sliding, experts), 4 (full, experts)
     cfg = dataclasses.replace(

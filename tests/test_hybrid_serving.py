@@ -94,6 +94,23 @@ def test_prefill_then_decode_through_cache_and_state(engine):
     assert len(engine.kv.pools) == 1        # pools: attention layers only
 
 
+def test_describe_says_which_grouped_product_served_and_what_it_did(
+        engine):
+    """The form (off the chip `lax.ragged_dot`, with the reason among
+    the declines) and the rows it multiplied beside the rows a loop
+    over every held expert would have; every key bound."""
+    serve(engine, "rows", [1] + tokens_of(9, 40))
+    info = engine.describe()
+    moe = info["moe"]
+    assert set(moe) == set(telemetry.SURFACE_BINDINGS["engine_moe"])
+    assert moe["grouped_product"] == "ragged_dot"
+    assert info["declines"]["grouped_product"].startswith("not on a TPU")
+    # top-2 of 8 held: a quarter of the loop's rows, pads included, so
+    # at least the counted tokens' assignments.
+    assert moe["local_assignments"] <= moe["rows_multiplied"]
+    assert moe["rows_multiplied"] * 4 == moe["rows_dense"]
+
+
 def test_own_slot_continuation(engine):
     first = [1] + tokens_of(2, 50)
     served, _ = serve(engine, "cont", first)
@@ -325,24 +342,31 @@ def test_describe_reads_host_ints_while_the_scheduler_serves(scheduled):
 
 
 def test_counts_fold_oldest_first_and_never_the_one_in_flight():
-    from theroundtaible_tpu.engine.hybrid_state import HybridStateStore
+    from theroundtaible_tpu.engine.hybrid_state import (MOE_COUNTS,
+                                                        HybridStateStore)
     from theroundtaible_tpu.engine.models.registry import get_model_config
     store = HybridStateStore(get_model_config("tiny-nemotron-h"), 2, 16, 0)
-    one = np.asarray([1, 2, 3], np.int32)
+    names = MOE_COUNTS
+    one = np.arange(1, len(names) + 1, dtype=np.int32)
+    at = {n: int(v) for n, v in zip(names, one)}
+
+    def times(k):
+        return {n: k * v for n, v in at.items()}
+
     store.note_counts(one, pipelined=False)       # a prologue's prefill
     store.note_counts(10 * one, pipelined=True)   # segment N
     assert store.moe_totals()["experts_hit"] == 0     # nothing read yet
     store.note_counts(100 * one, pipelined=True)  # N+1, before N is read
-    assert store.moe_totals() == {"experts_hit": 1, "local_assignments": 2,
-                                  "expert_layer_steps": 3}
+    assert store.moe_totals() == times(1)
     store.fold_counts(keep=1)                     # N has been read
     assert store.moe_totals()["experts_hit"] == 11
-    assert store.moe_delta()["expert_layer_steps"] == 33
+    assert store.moe_delta()["expert_layer_steps"] \
+        == 11 * at["expert_layer_steps"]
     store.fold_counts()                           # the batch drained
     assert store.moe_totals()["local_assignments"] == 222
-    assert store.moe_delta() == {"experts_hit": 100,
-                                 "local_assignments": 200,
-                                 "expert_layer_steps": 300}
+    assert store.moe_totals()["rows_multiplied"] \
+        == 111 * at["rows_multiplied"]
+    assert store.moe_delta() == times(100)
 
 
 def test_cache_on_serves_what_cache_off_serves(scheduled):

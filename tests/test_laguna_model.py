@@ -7,8 +7,8 @@ head at a time, an expert at a time, float32 highest, no cache).
 
 Tolerances, on LOGITS whose spread over the vocabulary is about 1:
 float32 program against float32 reference 1e-4 — order of sums alone
-(blockwise online softmax against a dense one, the masked expert loop
-against an expert at a time); measured 1e-6. A bfloat16 program reads
+(blockwise online softmax against a dense one, the experts' grouped
+product against an expert at a time); measured 1e-6. A bfloat16 program reads
 0.013 to 0.18 off over these positions and FAILS 1e-4, so computing in
 one pass of bfloat16 where float32 is stated is told apart
 (`test_a_bfloat16_program_is_told_apart...`).

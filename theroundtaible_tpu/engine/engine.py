@@ -168,7 +168,7 @@ class InferenceEngine:
                 raise ValueError(
                     f"{model_cfg.name} has layer_kinds: a mesh over "
                     f"{self.mesh.devices.size} devices is not supported "
-                    "yet (the state tree and the expert loop are "
+                    "yet (the state tree and the experts' product are "
                     "single-device); give it one device")
             if quant != "none":
                 self.declines["quant"] = f"{why}:quant-leaves"
@@ -196,6 +196,15 @@ class InferenceEngine:
             self.declines["spec_decode"] = (
                 why if model_cfg.recurrent else f"{why}:no-verify-program")
             spec_decode = False
+            if model_cfg.expert_layers:
+                # The routed experts' grouped product: the Pallas kernel,
+                # or lax.ragged_dot where it declines (off the chip; a
+                # width under a lane row) — one rule, pallas/grouped.py.
+                from .pallas import grouped
+                reason = grouped.decline_reason(
+                    model_cfg.embed_dim, model_cfg.expert_dim, dtype)
+                if reason is not None:
+                    self.declines["grouped_product"] = reason
         self.max_seq_len = model_cfg.max_seq_len
         self.sampling = sampling or SamplingParams()
         self.tokenizer = load_tokenizer(checkpoint or None)
@@ -1184,7 +1193,7 @@ class InferenceEngine:
         store. Same four seams as every model (prefill_step,
         decode_loop, ragged_step; first_token is shared as is), with
         the slot states donated beside the pools."""
-        from .hybrid_state import HybridStateStore
+        from .hybrid_state import MOE_COUNTS, HybridStateStore
         from .paged_forward import (forward_paged_hybrid,
                                     forward_ragged_hybrid)
         if not self.paged_direct:
@@ -1254,7 +1263,7 @@ class InferenceEngine:
 
             out, step, last, valid, done, caches = decode_while(
                 step_fn, (pools, rows_of(state, rows),
-                          jnp.zeros((3,), jnp.int32)),
+                          jnp.zeros((len(MOE_COUNTS),), jnp.int32)),
                 first_token, start_valid, key, budget, temps, top_ks,
                 top_ps, row_budgets, done0, max_new, greedy,
                 pass_active=True)
@@ -3368,6 +3377,9 @@ class InferenceEngine:
                            "offset": self.cfg.expert_offset,
                            "top_k": self.cfg.moe_top_k,
                            "expert_layers": len(self.cfg.expert_layers),
+                           "grouped_product": (
+                               "ragged_dot" if "grouped_product"
+                               in self.declines else "kernel"),
                            **self.hybrid.moe_totals()}
         if self.cfg.attn_layers is not None and self.kv_layout == "paged":
             info["attention"] = self.attention_describe()

@@ -58,7 +58,7 @@ from .models import hybrid
 from .models.common import ModelConfig
 
 CONTINUE, SNAPSHOT, ZERO = "continue", "snapshot", "zero"
-MOE_COUNTS = ("experts_hit", "local_assignments", "expert_layer_steps")
+MOE_COUNTS = hybrid.MOE_COUNTS
 
 
 def _chain(prev: bytes, block: list[int]) -> bytes:
@@ -96,8 +96,8 @@ class HybridStateStore:
         self._snap: "OrderedDict[bytes, int]" = OrderedDict()
         self._free_snaps = list(range(self.capacity - 1, -1, -1))
         self._counts_pending: "deque[jax.Array]" = deque()
-        self._moe_total = [0, 0, 0]
-        self._moe_seen = [0, 0, 0]
+        self._moe_total = [0] * len(MOE_COUNTS)
+        self._moe_seen = [0] * len(MOE_COUNTS)
         self.hits = self.misses = self.evictions = 0
         self.snapshots_taken = 0
         self.continued_tokens = self.reused_tokens = 0
@@ -345,7 +345,7 @@ class HybridStateStore:
     # --- counters -------------------------------------------------------
 
     def note_counts(self, counts: jax.Array, pipelined: bool) -> None:
-        """Queue a dispatch's expert counts (int32 [3], an output of the
+        """Queue a dispatch's expert counts (int32 [5], an output of the
         program just issued). What was queued before is folded first —
         all of it, or all but the newest when this dispatch is a decode
         segment issued before the last one is read (the pipeline is one

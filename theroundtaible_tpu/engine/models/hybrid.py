@@ -37,21 +37,26 @@ renormalised and scaled; each expert `act(x W_up) W_down` or, gated,
 for `axk1`); the chip
 computes the part of the result its own experts give
 (`expert_offset <= id < offset + experts_held`) and the shared expert.
-The routed part loops over the held experts with the token weights as
-a mask, and skips an expert no token chose (`lax.cond`): static
-shapes, no token ever dropped, and a decode step reads only the
-experts it hit. A gathered (sorted / grouped) product would do a
-twentieth of the prefill arithmetic at 64 held experts; it is left to
-a PR that can measure it (PERF.md, PR 27).
+The routed part multiplies only the rows that chose an expert
+(`routed_experts`): the T x top-k assignments are sorted by expert
+(another chip's last), each projection is ONE grouped product over the
+sorted rows (on the chip the Pallas kernel of `pallas/grouped.py`, which
+reads an expert's matrix only for the row tiles that hold its rows;
+elsewhere `lax.ragged_dot`), and the weighted rows are summed back to
+their tokens. Static shapes, every assignment to a held expert computed,
+none dropped or capped; a decode step reads only the experts it hit, and
+a join multiplies T x top-k rows, not T x held.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..pallas import grouped
 from .common import ModelConfig, Params, _einsum, rms_norm
 
 MAMBA2, EXPERTS, ATTENTION, MLP = "mamba2", "experts", "attention", "mlp"
@@ -393,6 +398,17 @@ def _relu2(x: jax.Array) -> jax.Array:
 
 EXPERT_ACTS = {"relu2": _relu2, "silu": jax.nn.silu}
 
+# What a step program returns of its expert layers, in this order.
+MOE_COUNTS = ("experts_hit", "local_assignments", "expert_layer_steps",
+              "rows_multiplied", "rows_dense")
+
+
+def step_counts(counts: jax.Array, stepped) -> jax.Array:
+    """One expert layer's `experts_mlp` counts and whether the layer ran
+    a live step (bool or 0/1), in MOE_COUNTS' order."""
+    return jnp.concatenate([
+        counts[:2], jnp.asarray(stepped, jnp.int32)[None], counts[2:]])
+
 
 def route(h: jax.Array, layer: Params, cfg: ModelConfig):
     """The router's rule (DeepSeek-V3's with one group): s = sigmoid(h
@@ -415,53 +431,95 @@ def route(h: jax.Array, layer: Params, cfg: ModelConfig):
     return ids.astype(jnp.int32), w
 
 
+def _expert(rows: jax.Array, weights: Params, dot, act: str, gated: bool):
+    """`act(rows W_up) W_down` or, gated, `(act(rows W_gate) * rows W_up)
+    W_down`, each product through `dot(rows, matrix)` -> float32."""
+    a = EXPERT_ACTS[act](dot(rows, weights["gate" if gated else "up"]))
+    if gated:
+        a = a * dot(rows, weights["up"])
+    return dot(a.astype(rows.dtype), weights["down"])
+
+
+@functools.partial(jax.jit, static_argnames=("held", "act", "gated"))
+def routed_experts(x: jax.Array, experts: Params, local: jax.Array,
+                   w: jax.Array, *, held: int, act: str, gated: bool):
+    """What the held experts add. x [T,E]; local [T,k] = chosen id less
+    `expert_offset` (outside [0, held): another chip's); w [T,k] f32.
+    The T*k assignments are sorted by expert (another chip's last), each
+    projection is ONE grouped product over the sorted rows — each row
+    times its own expert's matrix — and the weighted rows are summed
+    back to their tokens: every assignment to a held expert is computed,
+    none dropped or capped, and no expert multiplies a row that did not
+    choose it. The product is the Pallas kernel of `pallas/grouped.py`
+    on the chip, its visits computed once here for all the projections;
+    where that declines, `lax.ragged_dot`. -> ([T,E] f32, rows
+    multiplied int32).
+
+    A jit of its own, as `pallas.attention._ragged_walk` is: a model's
+    expert layers call it with the same shapes, so the sort, the kernels
+    and the sum back are traced once a process and lowered once a
+    program, not once a layer."""
+    t, k = local.shape
+    m, mp = t * k, grouped.padded_rows(t * k)
+    here = (local >= 0) & (local < held)
+    group = jnp.pad(jnp.where(here, local, held).reshape(m), (0, mp - m),
+                    constant_values=held)
+    order = jnp.argsort(group)                 # stable: tokens in order
+    sizes = jnp.sum(group[:, None] == jnp.arange(held, dtype=group.dtype),
+                    axis=0, dtype=jnp.int32)
+    rows = x[jnp.minimum(order // k, t - 1)]               # [mp,E]
+    if grouped.decline_reason(x.shape[1], experts["up"].shape[2],
+                              x.dtype) is None:
+        dot = functools.partial(grouped.grouped_matmul,
+                                visits=grouped.group_visits(sizes, mp))
+    else:
+        def dot(rows, weights):
+            return jax.lax.ragged_dot(rows, weights, sizes,
+                                      preferred_element_type=jnp.float32)
+    y = _expert(rows, experts, dot, act, gated)
+    multiplied = jnp.sum(sizes)
+    # Another chip's rows and the padding: never multiplied, weighted 0
+    # by `where` (they are undefined, not zero).
+    weight = jnp.pad(w.reshape(m), (0, mp - m))[order]
+    y = jnp.where((jnp.arange(mp) < multiplied)[:, None],
+                  y * weight[:, None], 0.0)
+    back = jnp.zeros((mp,), jnp.int32).at[order].set(
+        jnp.arange(mp, dtype=jnp.int32))
+    return jnp.sum(y[back[:m]].reshape(t, k, -1), axis=1), multiplied
+
+
 def experts_mlp(h: jax.Array, layer: Params, cfg: ModelConfig,
                 token_mask: Optional[jax.Array] = None):
     """Routed experts held here + the shared expert. h [..., T, E] ->
-    (out, counts int32[2]): counts = (held experts some counted token
-    chose, assignments of counted tokens to held experts) — what a step
-    must read of the experts, for the `moe.*` metrics. `token_mask`
-    [..., T] says which tokens count (pads and finished rows do not);
-    every token is still computed."""
+    (out, counts int32[4]): counts = (held experts some counted token
+    chose, assignments of counted tokens to held experts, rows the
+    grouped product multiplied, rows a loop over every held expert
+    would have: T x held) — what a step must read of the experts, and
+    what it multiplied, for the `moe.*` metrics. `token_mask` [..., T]
+    says which tokens count (pads and finished rows do not); every
+    token is still computed."""
     lead = h.shape[:-1]
     x = h.reshape(-1, h.shape[-1])                        # [T,E]
     t = x.shape[0]
     ids, w = route(x, layer, cfg)
-    held, off = cfg.experts_held, cfg.expert_offset
-    local = ids - off
+    held = cfg.experts_held
+    local = ids - cfg.expert_offset
     here = (local >= 0) & (local < held)
-    # Per held expert, each token's weight (0: not chosen).
-    dense = jnp.sum(
-        jnp.where(here[..., None],
-                  jax.nn.one_hot(local, held, dtype=jnp.float32)
-                  * w[..., None], 0.0), axis=1)           # [T,held]
     counted = (jnp.ones((t,), bool) if token_mask is None
                else token_mask.reshape(-1))
-    chosen = (dense > 0) & counted[:, None]
-    counts = jnp.stack([
-        jnp.sum(jnp.any(chosen, axis=0)),
-        jnp.sum(here & counted[:, None])]).astype(jnp.int32)
+    chosen = here & counted[:, None]                      # [T,k]
+    hit = jnp.zeros((held,), bool).at[
+        jnp.where(chosen & (w > 0), local, held)].set(True, mode="drop")
+    routed, multiplied = routed_experts(
+        x, layer["experts"], local, w, held=held, act=cfg.expert_act,
+        gated=cfg.expert_gated)
+    counts = jnp.stack([jnp.sum(hit), jnp.sum(chosen), multiplied,
+                        jnp.asarray(t * held)]).astype(jnp.int32)
 
-    act = EXPERT_ACTS[cfg.expert_act]
-
-    def expert(w):
-        a = act(_einsum("te,ef->tf", x, w["gate" if cfg.expert_gated
-                                           else "up"]))
-        if cfg.expert_gated:
-            a = a * _einsum("te,ef->tf", x, w["up"])
-        return _einsum("tf,fe->te", a.astype(x.dtype), w["down"])
-
-    def one(acc, xs):
-        w, wt = xs
-        return jax.lax.cond(jnp.any(wt > 0),
-                            lambda a: a + expert(w) * wt[:, None],
-                            lambda a: a, acc), None
-
-    routed, _ = jax.lax.scan(
-        one, jnp.zeros((t, x.shape[-1]), jnp.float32),
-        (layer["experts"], dense.T))
-    out = (routed + expert(layer["shared"])).astype(h.dtype)
-    return out.reshape(*lead, -1), counts
+    out = routed + _expert(x, layer["shared"],
+                           functools.partial(_einsum, "te,ef->tf"),
+                           cfg.expert_act, cfg.expert_gated)
+    return out.astype(h.dtype).reshape(*lead, -1), counts
 
 
 # --- the block -------------------------------------------------------------

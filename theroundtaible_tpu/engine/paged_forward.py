@@ -445,8 +445,10 @@ def forward_paged_hybrid(
 
     -> (logits, new_pools, new_state, captured, counts): `captured` is
     the state after `cap_len` tokens (prefill with `cap_len`), else
-    None; counts int32[3] = (experts hit, assignments to held experts,
-    expert-layer steps) over the counted tokens."""
+    None; counts int32[5] (`hybrid.MOE_COUNTS`) = experts hit and
+    assignments to held experts over the counted tokens, rows the
+    grouped products multiplied and rows a loop over every held expert
+    would have, expert-layer steps."""
     from .models import hybrid
     page_size = pools[0][0].shape[1]
     b, t = tokens.shape
@@ -460,7 +462,7 @@ def forward_paged_hybrid(
         counted = jnp.arange(t)[None, :] < lengths[:, None]
     ssm, conv = list(state["ssm"]), list(state["conv"])
     cap = {"ssm": [], "conv": []} if cap_len is not None else None
-    counts = jnp.zeros((3,), jnp.int32)
+    counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
     ai = mi = 0
     for kind, layer in zip(cfg.layer_kinds, params["layers"]):
@@ -481,8 +483,7 @@ def forward_paged_hybrid(
             mi += 1
         elif kind == hybrid.EXPERTS:
             out, c = hybrid.experts_mlp(h, layer, cfg, counted)
-            counts = counts + jnp.concatenate(
-                [c, jnp.any(counted).astype(jnp.int32)[None]])
+            counts = counts + hybrid.step_counts(c, jnp.any(counted))
         elif kind == hybrid.MLP:
             out = mlp(h, layer, cfg)
         else:
@@ -546,7 +547,7 @@ def forward_ragged_hybrid(
     counted = (rg["token_valid"] & (token_seq != s_max - 1))[None]
     ssm, conv = list(state["ssm"]), list(state["conv"])
     cap = {"ssm": [], "conv": []}
-    counts = jnp.zeros((3,), jnp.int32)
+    counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
     ai = mi = 0
     for kind, layer in zip(cfg.layer_kinds, params["layers"]):
@@ -559,8 +560,7 @@ def forward_ragged_hybrid(
             mi += 1
         elif kind == hybrid.EXPERTS:
             out, c = hybrid.experts_mlp(h, layer, cfg, counted)
-            counts = counts + jnp.concatenate(
-                [c, jnp.ones((1,), jnp.int32)])
+            counts = counts + hybrid.step_counts(c, 1)
         elif kind == hybrid.MLP:
             out = mlp(h, layer, cfg)
         else:

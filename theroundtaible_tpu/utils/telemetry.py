@@ -1111,6 +1111,15 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "experts_hit": "roundtable_moe_experts_hit_total",
         "local_assignments": "roundtable_moe_local_assignments_total",
         "expert_layer_steps": "roundtable_moe_expert_layer_steps_total",
+        # ISSUE 36: the routed experts multiply the rows that chose
+        # them. Which form serves (the Pallas grouped matmul, or
+        # lax.ragged_dot with the reason in describe()["declines"]),
+        # and the rows it multiplied beside the rows a loop over every
+        # held expert would have (tokens x held, pads included).
+        "grouped_product": "static (kernel | ragged_dot; "
+                           "declines.grouped_product says why)",
+        "rows_multiplied": "roundtable_moe_rows_multiplied_total",
+        "rows_dense": "roundtable_moe_rows_dense_total",
     },
     # engine.describe()["mla"] (ISSUE 31): latent pages — the second
     # page shape (engine/paging.py) — and the kernels that read them.
