@@ -261,8 +261,8 @@ def child() -> int:
             # The roofline block is PRODUCED by the shared perfmodel
             # (ISSUE 6): aggregate ceilings scale with the mesh size,
             # streamed bytes come from the actual quantized tree, and
-            # the same math backs the live bw_utilization/mfu gauges —
-            # bench records and serving gauges can no longer drift.
+            # the same math backs the live ceiling gauges — bench
+            # records and serving gauges can no longer drift.
             from theroundtaible_tpu.utils import perfmodel
             run["roofline"] = perfmodel.roofline_block(
                 param_bytes=param_bytes,

@@ -749,34 +749,3 @@ class TestRaggedResolution:
             build_ragged_batch(
                 [RaggedSeq([1], 0, table)], t_budget=16, s_max=1,
                 pages_per_seq=4, scratch_page=0, pad_id=0, page_size=16)
-
-
-# ---------------------------------------------------------------------------
-# perfmodel: mixed-dispatch attribution (satellite)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.perf_obs
-def test_publish_mixed_sample_splits_phases(monkeypatch):
-    """A mixed segment's gauges split by per-row token counts: decode
-    tokens against the streaming ceiling, prefill tokens against the
-    compute peak — hand-computed against the v5e spec."""
-    from theroundtaible_tpu.utils import perfmodel, telemetry
-
-    monkeypatch.setenv(perfmodel.CHIP_ENV, "v5e")
-    perf = perfmodel.EnginePerf(
-        "mixed-test", param_bytes=10**9, num_params=5 * 10**8,
-        chip=perfmodel.V5E, chip_source="env")
-    perf.publish_mixed_sample(prefill_tokens=192, decode_tokens=8,
-                              seconds=0.5)
-    bw = telemetry.REGISTRY.gauge_value(
-        "roundtable_bw_utilization", engine="mixed-test", phase="decode")
-    mfu = telemetry.REGISTRY.gauge_value(
-        "roundtable_mfu", engine="mixed-test", phase="prefill")
-    assert bw == pytest.approx((8 / 0.5) / perf.decode_ceiling)
-    assert mfu == pytest.approx((192 / 0.5) / perf.prefill_peak)
-    # a pure-decode sample degenerates to publish_decode_sample
-    perf.publish_mixed_sample(0, 64, 0.25)
-    bw2 = telemetry.REGISTRY.gauge_value(
-        "roundtable_bw_utilization", engine="mixed-test", phase="decode")
-    assert bw2 == pytest.approx((64 / 0.25) / perf.decode_ceiling)

@@ -786,13 +786,10 @@ class TestRecompileSentinel:
         assert len(set(desc["occupancy_recent"])) >= 2, \
             "occupancy never drifted — the run proved nothing"
 
-        # Perf gauges rode along (ISSUE 6 tentpole): per-segment
-        # roofline samples and the per-session KV series, REMOVED at
-        # retirement (uuid-tagged session ids would otherwise grow the
-        # registry one dead series per session ever served).
-        assert telemetry.REGISTRY.gauge_value(
-            "roundtable_bw_utilization", engine=engine.cfg.name,
-            phase="decode") is not None
+        # Perf gauges rode along (ISSUE 6 tentpole): the per-session
+        # KV series, REMOVED at retirement (uuid-tagged session ids
+        # would otherwise grow the registry one dead series per session
+        # ever served).
         assert telemetry.REGISTRY.gauge_value(
             "roundtable_session_kv_bytes", engine=engine.cfg.name,
             session="d0") is None
@@ -915,9 +912,11 @@ class TestLoopClock:
         assert at["prefix_reused_tokens"] == stats.prefix_reused_tokens
         assert at["queue_wait_s"] >= 0.0
         assert 0.0 < at["sync_s"] <= admit["dur_s"]
-        # admission's dispatches parent under it, in the same trace
+        # admission's plan and its dispatches parent under it, in the
+        # same trace
         kids = [r for r in spans if r["parent_id"] == admit["span_id"]]
-        assert kids and {r["rung"] for r in kids} == {"dispatch"}
+        assert {r["rung"] for r in kids} == {"plan", "dispatch"}
+        assert {r["trace_id"] for r in kids} == {request.trace_id}
         segments = [r for r in spans if r["rung"] == "segment"]
         assert segments
         for seg in segments:

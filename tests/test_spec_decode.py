@@ -610,41 +610,6 @@ class TestScheduledSpec:
 
 
 # ---------------------------------------------------------------------------
-# perfmodel attribution (ISSUE 9 satellite)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.perf_obs
-def test_publish_mixed_sample_splits_accepted_vs_dispatch(monkeypatch):
-    """A 3x-accepting verify dispatch must not report 300% bandwidth
-    utilization: the roofline gauge prices the DISPATCH tokens (one
-    per row per forward), the accepted rate publishes separately."""
-    from theroundtaible_tpu.utils import perfmodel
-
-    perf = perfmodel.EnginePerf(
-        "spec-test", param_bytes=10**9, num_params=5 * 10**8,
-        chip=perfmodel.V5E, kv_token_bytes=1)
-    # 2 rows, 6 accepted tokens in 0.01 s: accepted tps 600, dispatch
-    # tps 200.
-    perf.publish_mixed_sample(0, 6, 0.01, decode_dispatch_tokens=2)
-    snap = telemetry.REGISTRY.snapshot_compact()
-    bw = next(v for k, v in snap.items()
-              if k.startswith("roundtable_bw_utilization")
-              and "spec-test" in k)
-    assert bw == pytest.approx((2 / 0.01) / perf.decode_ceiling)
-    acc = next(v for k, v in snap.items()
-               if k.startswith("roundtable_spec_accepted_tps")
-               and "spec-test" in k)
-    assert acc == pytest.approx(600.0)
-    # The plain ragged path (counts coincide) publishes no spec gauge.
-    telemetry.REGISTRY.remove_gauge("roundtable_spec_accepted_tps",
-                                    engine="spec-test")
-    perf.publish_mixed_sample(0, 4, 0.01)
-    snap = telemetry.REGISTRY.snapshot_compact()
-    assert not any(k.startswith("roundtable_spec_accepted_tps")
-                   and "spec-test" in k for k in snap)
-
-# ---------------------------------------------------------------------------
 # ISSUE 13: spec_decode dict resolution / drafter protocol / tree walk
 # ---------------------------------------------------------------------------
 

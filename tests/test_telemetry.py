@@ -459,6 +459,40 @@ class TestLexicalMirror:
         assert mirrored == [] and record["span_id"]
 
 
+# --- the round-start rungs stay out of the profiler's trace (ISSUE 37) ---
+
+
+@pytest.mark.telemetry
+@pytest.mark.parametrize("rung,mirrored", [
+    ("plan", False), ("page_copy", False), ("share", False),
+    ("pack", False), ("admit", True), ("dispatch", True)])
+def test_a_round_start_rung_is_a_record_and_no_annotation(
+        monkeypatch, fresh_buffer, rung, mirrored):
+    names = []
+    monkeypatch.setattr(telemetry, "_open_annotation",
+                        lambda name: names.append(name) or name)
+    monkeypatch.setattr(telemetry, "_close_annotation", lambda ann: None)
+    t_a = time.monotonic()
+    telemetry.set_profiling(True)
+    try:
+        with telemetry.span("admit") as outer:
+            names.clear()
+            with telemetry.span(rung, pages=1) as inner:
+                with telemetry.span("dispatch"):
+                    pass
+    finally:
+        telemetry.set_profiling(False)
+    assert names == ([rung] if mirrored else []) + ["dispatch"]
+    recs = {r["span_id"]: r for r in
+            telemetry.spans_between(t_a, time.monotonic())}
+    assert recs[inner.span_id]["parent_id"] == outer.span_id
+    assert recs[inner.span_id]["attrs"] == {"pages": 1}
+    # children still nest under it, in its trace
+    (child,) = [r for r in recs.values()
+                if r["parent_id"] == inner.span_id]
+    assert child["trace_id"] == outer.trace_id
+
+
 # --- the loop clock (ISSUE 25) ---
 
 
@@ -528,7 +562,7 @@ class TestLoopClock:
             assert a["t0"] + a["dur_s"] == pytest.approx(b["t0"],
                                                          abs=2e-6)
         assert {r["trace_id"] for r in recs} == {recs[0]["trace_id"]}
-        assert all(r["attrs"] == {"engine": "e", "tick": 7}
+        assert all(r["attrs"] == {"engine": "e", "tick": 7, "fed": 0}
                    for r in recs)
         assert len(telemetry.recorder().span_events()) == ring_before
         # disarmed mid-phase: the open stretch still ends, no new one
@@ -557,6 +591,186 @@ class TestLoopClock:
         clock.mark("wait")
         assert names == ["loop.build", "/loop.build", "loop.sync",
                          "/loop.sync"]
+
+    # --- the feed bit (ISSUE 37) ---
+
+    # (what the loop does, the tickets it holds) -> is the device fed?
+    FEEDS = [
+        ("a dispatch feeds, its read drains",
+         ["feed", "drain1"], [True, False]),
+        ("pipelined: the read of the first leaves the second outstanding",
+         ["feed", "feed", "drain1", "drain2"], [True, True, True, False]),
+        ("a prologue: one read drains every chunk's ticket",
+         ["feed", "feed", "feed", "drain"], [True, True, True, False]),
+        ("a read of an older ticket, after a later one, changes nothing",
+         ["feed", "feed", "drain2", "drain1"], [True, True, False, False]),
+        ("a failed dispatch's handler drains what nobody will read",
+         ["feed", "feed", "drain", "drain"], [True, True, False, False]),
+        ("a drain with nothing issued stays unfed",
+         ["drain", "feed"], [False, True]),
+    ]
+
+    @pytest.mark.parametrize("what,steps,fed", FEEDS,
+                             ids=[f[0] for f in FEEDS])
+    def test_fed_is_a_count_of_outstanding_tickets(self, what, steps, fed):
+        clock = telemetry.LoopClock(PHASES, "build")
+        tickets, seen = [], []
+        for step in steps:
+            if step == "feed":
+                tickets.append(clock.feed())
+            else:
+                n = step[len("drain"):]
+                clock.drain(tickets[int(n) - 1] if n else None)
+            seen.append(clock.fed)
+        assert seen == fed
+        assert tickets == list(range(1, len(tickets) + 1))
+
+    def test_fed_and_unfed_stretches_telescope_to_the_wall(self):
+        """A pipelined loop: dispatch A, dispatch B, read A (still
+        fed), read B. Every phase's starved seconds are at most its
+        seconds, the unfed and the fed parts sum to the thread's wall,
+        and what was slept unfed is what reads starved."""
+        t0 = time.monotonic()
+        clock = telemetry.LoopClock(PHASES, "wait", within=WITHIN)
+        lap = 0.004
+
+        def spend(phase):
+            clock.mark(phase)
+            time.sleep(lap)
+
+        spend("build")                      # unfed
+        spend("dispatch")                   # unfed: fed at its return
+        a = clock.feed()
+        spend("build")                      # fed
+        spend("dispatch")                   # fed
+        b = clock.feed()
+        spend("sync")                       # fed
+        clock.drain(a)
+        spend("build")                      # fed: b is outstanding
+        spend("sync")                       # fed
+        clock.drain(b)
+        spend("build")                      # unfed
+        clock.mark("wait")
+        seconds, starved = clock.snapshots()
+        wall = time.monotonic() - t0
+        assert tuple(seconds) == tuple(starved) == PHASES
+        assert all(starved[p] <= seconds[p] for p in PHASES)
+        assert sum(seconds.values()) == pytest.approx(wall, rel=0.01)
+        # (the drain comes a few microseconds before the next mark)
+        assert starved["sync"] < 1e-3
+        assert starved["dispatch"] == pytest.approx(seconds["dispatch"] / 2,
+                                                    rel=0.25)
+        assert starved["build"] == pytest.approx(seconds["build"] / 2,
+                                                 rel=0.25)
+        unfed = sum(starved.values())
+        assert 3 * lap <= unfed <= wall - 4 * lap
+
+    def test_a_within_rename_keeps_the_bit(self):
+        clock = telemetry.LoopClock(PHASES, "admit", within=WITHIN)
+        clock.feed()
+        back = clock.switch("sync")
+        assert (clock.phase, clock.fed) == ("admit_sync", True)
+        time.sleep(0.002)
+        clock.mark(back)
+        assert clock.seconds["admit_sync"] >= 0.002
+        assert clock.starved["admit_sync"] == 0.0
+        clock.drain()
+        back = clock.switch("sync")
+        time.sleep(0.002)
+        clock.mark(back)
+        assert clock.fed is False
+        assert clock.starved["admit_sync"] >= 0.002
+
+    def test_snapshots_from_another_thread_never_read_more_starved(self):
+        """describe()'s read races the loop's marks: whatever lap it
+        catches, no phase reads more starved than spent, and both dicts
+        keep every phase."""
+        import sys
+        import threading
+
+        clock = telemetry.LoopClock(PHASES, "wait")
+        stop = threading.Event()
+        bad = []
+
+        def reader():
+            while not stop.is_set():
+                seconds, starved = clock.snapshots()
+                bad.extend(p for p in PHASES if starved[p] > seconds[p])
+                if tuple(seconds) != PHASES or tuple(starved) != PHASES:
+                    bad.append("keys")
+
+        readers = [threading.Thread(target=reader) for _ in range(3)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for thread in readers:
+                thread.start()
+            bound = time.monotonic() + 5.0
+            for i in range(20000):
+                clock.mark(PHASES[i % len(PHASES)])
+                if i % 3 == 0:
+                    clock.feed()
+                elif i % 3 == 2:
+                    clock.drain()
+                if time.monotonic() > bound:
+                    break
+        finally:
+            stop.set()
+            for thread in readers:
+                thread.join(timeout=10.0)
+            sys.setswitchinterval(interval)
+        assert not any(thread.is_alive() for thread in readers)
+        assert bad == []
+
+    def test_unarmed_a_flip_creates_no_span(self):
+        telemetry.disarm()
+        before = telemetry.spans_emitted()
+        clock = telemetry.LoopClock(PHASES, "build")
+        clock.drain(clock.feed())
+        assert telemetry.spans_emitted() == before
+        assert clock._open is None
+
+    @pytest.mark.telemetry
+    def test_armed_a_stretch_also_ends_where_the_feed_changes(
+            self, fresh_buffer):
+        t_a = time.monotonic()
+        clock = telemetry.LoopClock(PHASES, "wait", engine="e")
+        clock.mark("build")
+        ticket = clock.feed()               # build goes on, fed
+        clock.feed()                        # a second handle: no flip
+        clock.mark("sync")
+        clock.drain(ticket)                 # one still outstanding
+        clock.drain()
+        clock.mark("build")
+        clock.mark("wait")
+        recs = [r for r in telemetry.spans_between(t_a, time.monotonic())
+                if r["rung"].startswith("loop.")]
+        assert [(r["rung"], r["attrs"]["fed"]) for r in recs] == [
+            ("loop.build", 0), ("loop.build", 1), ("loop.sync", 1),
+            ("loop.sync", 0), ("loop.build", 0)]
+        for a, b in zip(recs, recs[1:]):
+            assert a["t0"] + a["dur_s"] == pytest.approx(b["t0"],
+                                                         abs=2e-6)
+
+    @pytest.mark.telemetry
+    def test_while_profiling_a_flip_keeps_the_annotations_name(
+            self, monkeypatch, fresh_buffer):
+        names = []
+        monkeypatch.setattr(telemetry, "_open_annotation",
+                            lambda name: names.append(name) or name)
+        monkeypatch.setattr(telemetry, "_close_annotation",
+                            lambda ann: names.append("/" + ann))
+        telemetry.set_profiling(True)
+        try:
+            clock = telemetry.LoopClock(PHASES, "wait")
+            clock.mark("build")
+            clock.feed()
+            clock.mark("sync")
+        finally:
+            telemetry.set_profiling(False)
+        clock.mark("wait")
+        assert names == ["loop.build", "/loop.build", "loop.build",
+                         "/loop.build", "loop.sync", "/loop.sync"]
 
     def test_the_serving_seams_switch_the_threads_clock_and_back(self):
         from theroundtaible_tpu.engine.serving_loop import (host_sync,

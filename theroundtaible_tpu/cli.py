@@ -148,10 +148,14 @@ def build_parser():
     st = sub.add_parser("status", help="Show the latest session")
     st.add_argument("--telemetry", action="store_true",
                     help="Render the session's telemetry view: registry "
-                         "snapshot, span summary, flight-recorder dumps")
+                         "snapshot (the scheduler's loop and starved "
+                         "seconds by phase among it), span summary, "
+                         "flight-recorder dumps")
     st.add_argument("--perf", action="store_true",
                     help="Render live performance attribution: roofline "
-                         "table, compile observatory, memory ledger, "
+                         "table (ceiling, decode rate, seconds the "
+                         "scheduler left the device unfed by loop "
+                         "phase), compile observatory, memory ledger, "
                          "span-tree overhead breakdown")
     st.add_argument("--kv", action="store_true",
                     help="Render the KV-tier view: memory ledger with "

@@ -740,10 +740,14 @@ def _by_engine(series: dict[str, float],
     return out
 
 
+STARVED_SERIES = "roundtable_sched_starved_seconds_total"
+
+
 def perf_status(session) -> int:
     """`roundtable status --perf` — live performance attribution from
     the unified registry (ISSUE 6): the per-engine roofline table
-    (ceiling, bw_utilization, MFU), the compile observatory's history
+    (ceiling, decode rate, and the seconds the scheduler's loop left
+    the device unfed, by phase), the compile observatory's history
     and steady-state sentinel state, the memory ledger, and the
     span-tree overhead breakdown."""
     from ..utils import perfmodel, telemetry
@@ -759,34 +763,38 @@ def perf_status(session) -> int:
         | {lb.get("engine", "?") for k in perf
            for lb in [_labels(k)] if "engine" in lb})
     if engines and any(k.split("{")[0].startswith(
-            ("roundtable_decode", "roundtable_bw", "roundtable_mfu"))
-            for k in perf):
+            ("roundtable_decode", STARVED_SERIES)) for k in perf):
         print(style.bold("\n  Roofline (per engine):"))
         print(style.dim("    engine            ceiling_tps  decode_tps"
-                        "  bw_util    mfu"))
+                        "  starved_s (the device unfed, by loop phase)"))
         for eng in engines:
-            def val(name, phase=None):
+            def val(name):
                 for key, v in perf.items():
-                    if key.split("{", 1)[0] != name:
-                        continue
-                    lb = _labels(key)
-                    if lb.get("engine") != eng:
-                        continue
-                    if phase and lb.get("phase") != phase:
-                        continue
-                    return v
+                    if (key.split("{", 1)[0] == name
+                            and _labels(key).get("engine") == eng):
+                        return v
                 return None
 
-            def fmt(v, pct=False):
-                if v is None:
-                    return "      -"
-                return f"{v * 100:6.1f}%" if pct else f"{v:10.1f}"
+            def fmt(v):
+                return "         -" if v is None else f"{v:10.1f}"
 
+            # Where the scheduler's loop left the device with no step
+            # program of its own outstanding (the loop clock's feed
+            # bit), largest phase first; replicas of one engine add up.
+            starved: dict[str, float] = {}
+            for key, v in perf.items():
+                lb = _labels(key)
+                if (key.split("{", 1)[0] == STARVED_SERIES
+                        and lb.get("engine") == eng and v > 0):
+                    phase = lb.get("phase", "?")
+                    starved[phase] = starved.get(phase, 0.0) + v
+            by_phase = " ".join(
+                f"{phase}={sec:.3f}" for phase, sec in sorted(
+                    starved.items(), key=lambda kv: -kv[1]))
             print(style.dim(
                 f"    {eng:<18}{fmt(val('roundtable_decode_ceiling_tps'))}"
                 f"{fmt(val('roundtable_decode_tps'))}"
-                f"  {fmt(val('roundtable_bw_utilization', 'decode'), True)}"
-                f"{fmt(val('roundtable_mfu', 'prefill'), True)}"))
+                f"  {by_phase or '-'}"))
 
     # --- compile observatory ---
     from ..engine import compile_watch

@@ -2751,6 +2751,34 @@ class InferenceEngine:
         live decode segment instead of this blocking prologue
         (first_np is None in the returned dict). Paged, replica-free
         engines only — the flat buffer cannot mix pool replicas."""
+        from ..utils import telemetry
+        # The `plan` span (ISSUE 37): the host work of an admission up
+        # to its prologue — under the scheduler's `admit` span on its
+        # thread, under a turn's on any other; unarmed, the null span.
+        with telemetry.span("plan") as span:
+            took = getattr(self.kv, "pages_allocated", 0)
+            prep = self._plan_batch(
+                turns, max_new_padded, deadline, pre_budget,
+                sampling_per_turn, extra_pinned, defer_prefill, adapters)
+            if span is not telemetry.NULL_SPAN:
+                span.attrs.update(
+                    prompt_tokens=sum(len(t) for t in prep["all_tokens"]),
+                    matched_tokens=prep["reused_tokens"],
+                    pages_allocated=getattr(self.kv, "pages_allocated",
+                                            0) - took)
+        if prep.pop("deferred"):
+            return prep
+        return self._prologue(prep, deadline, pre_budget)
+
+    def _plan_batch(self, turns, max_new_padded, deadline, pre_budget,
+                    sampling_per_turn, extra_pinned, defer_prefill,
+                    adapters) -> dict:
+        """_prepare_batch's host half: everything up to the prologue's
+        first program of its own (the leader pass of an undeferred
+        batch dispatches its span's prefill in here) — reuse plan,
+        prefix attach, shares, the joint state plan, page allocation.
+        -> _prepare_batch's dict with `first_np` None, and `deferred`:
+        whether it stays so."""
         pinned = tuple(name for name, _ in turns) + tuple(extra_pinned)
         if self.kv_offload is not None:
             # A spilled session resumes HERE, before reuse_plan acquires
@@ -2933,45 +2961,47 @@ class InferenceEngine:
         prefill_tokens = leader_prefill + sum(len(s) for s in suffixes)
         # "reused" counts both own-slot LCP hits and copied donor spans.
         reused_tokens = sum(len(t) for t in all_tokens) - prefill_tokens
-        if defer_prefill:
-            if plan is not None:
-                raise RuntimeError(
-                    "defer_prefill requires a replica-free paged pool "
-                    "(the ragged flat buffer cannot mix pool replicas)")
-            per_row = sampling_per_turn or [self.sampling] * len(turns)
-            if len(per_row) != len(turns):
-                raise ValueError(
-                    f"sampling_per_turn has {len(per_row)} entries for "
-                    f"{len(turns)} turns")
-            return {
-                "names": names, "slot_ids": slot_ids,
-                "all_tokens": all_tokens, "offsets": offsets,
-                "plan": None, "tables_np": tables_np,
-                "per_row": per_row, "temps": None, "top_ks": None,
-                "top_ps": None,
-                "greedy": all(p.temperature <= 0.0 for p in per_row),
-                "first_np": None, "prefill_tokens": prefill_tokens,
-                "reused_tokens": reused_tokens,
-                "prefix_reused_tokens": prefix_reused,
-                "share_plan": share_plan,
-                "lora_slots": lora_slots, "adapters": ad,
-                "state_plan": state_plan,
-            }
+        if defer_prefill and plan is not None:
+            raise RuntimeError(
+                "defer_prefill requires a replica-free paged pool "
+                "(the ragged flat buffer cannot mix pool replicas)")
+        per_row = sampling_per_turn or [self.sampling] * len(turns)
+        if len(per_row) != len(turns):
+            raise ValueError(
+                f"sampling_per_turn has {len(per_row)} entries for "
+                f"{len(turns)} turns")
+        return {
+            "names": names, "slot_ids": slot_ids,
+            "all_tokens": all_tokens, "offsets": offsets,
+            "plan": plan, "tables_np": tables_np,
+            "per_row": per_row, "temps": None, "top_ks": None,
+            "top_ps": None,
+            "greedy": all(p.temperature <= 0.0 for p in per_row),
+            "first_np": None, "prefill_tokens": prefill_tokens,
+            "reused_tokens": reused_tokens,
+            "prefix_reused_tokens": prefix_reused,
+            "share_plan": share_plan,
+            "lora_slots": lora_slots, "adapters": ad,
+            "state_plan": state_plan, "deferred": defer_prefill,
+        }
+
+    def _prologue(self, prep: dict, deadline, pre_budget) -> dict:
+        """_prepare_batch's device half for a batch that is not
+        deferred: chunked/ring prefill of each row's suffix → the
+        first-token sample → ONE blocking read. -> `prep` with
+        `first_np` (ORIGINAL row order) and the sampling arrays."""
+        plan, offsets = prep["plan"], prep["offsets"]
+        suffixes = [t[o:] for t, o in zip(prep["all_tokens"], offsets)]
         p_offsets = offsets
-        p_lora = lora_slots
+        p_lora = prep["lora_slots"]
         if plan is not None:
             suffixes = plan.scatter_list(suffixes,
                                          [self.tokenizer.pad_id])
             p_offsets = plan.scatter_list(offsets, 0)
             if p_lora is not None:
                 p_lora = plan.scatter_list(p_lora, 0)
-        per_row = sampling_per_turn or [self.sampling] * len(turns)
-        if len(per_row) != len(turns):
-            raise ValueError(
-                f"sampling_per_turn has {len(per_row)} entries for "
-                f"{len(turns)} turns")
-        temps, top_ks, top_ps = sampling_arrays(per_row)
-        greedy = all(p.temperature <= 0.0 for p in per_row)
+        temps, top_ks, top_ps = sampling_arrays(prep["per_row"])
+        greedy = prep["greedy"]
         if plan is not None:
             # The whole decode phase runs in padded replica-grouped row
             # order; callers read back through plan.pos.
@@ -2983,9 +3013,11 @@ class InferenceEngine:
         # eager stands between the prefill step and the first token. A
         # greedy batch draws no key, as before.
         key = self._key if greedy else self._next_key()
-        last_logits = self._prefill(slot_ids, suffixes, p_offsets,
-                                    deadline=deadline, tables=tables_np,
+        last_logits = self._prefill(prep["slot_ids"], suffixes, p_offsets,
+                                    deadline=deadline,
+                                    tables=prep["tables_np"],
                                     budget=pre_budget, lora_ids=p_lora)
+        from ..utils import telemetry
         from . import compile_watch
         with compile_watch.label(
                 f"prefill[b={last_logits.shape[0]},first_token]",
@@ -2996,6 +3028,13 @@ class InferenceEngine:
             # Pad rows open at eos so they are done from the first step.
             first = first.at[jnp.asarray(plan.pad_positions)].set(
                 jnp.int32(self.tokenizer.eos_id))
+        # On a clocked thread (the scheduler's) the device holds the
+        # prologue's programs — each prefill chunk fed the loop clock
+        # where it was issued (serving_loop.chunked_prefill), the
+        # sampler here — until the read below has drained them.
+        clock = telemetry.loop_clock()
+        if clock is not None:
+            clock.feed()
         # ONE blocking read for the whole prologue: it waits on the
         # prefill's logits, so it also pins prefill's time before decode
         # (a PJRT transport may return from block_until_ready before the
@@ -3003,19 +3042,13 @@ class InferenceEngine:
         # (a wedged prefill program freezes the host exactly here).
         first_np = host_sync(lambda: np.asarray(first), pre_budget,
                              "prefill")
+        if clock is not None:
+            clock.drain()
         if plan is not None:
             first_np = first_np[plan.pos]
-        return {
-            "names": names, "slot_ids": slot_ids,
-            "all_tokens": all_tokens, "offsets": offsets, "plan": plan,
-            "tables_np": tables_np, "per_row": per_row, "temps": temps,
-            "top_ks": top_ks, "top_ps": top_ps, "greedy": greedy,
-            "first_np": first_np, "prefill_tokens": prefill_tokens,
-            "reused_tokens": reused_tokens,
-            "prefix_reused_tokens": prefix_reused,
-            "lora_slots": lora_slots, "adapters": ad,
-            "state_plan": state_plan,
-        }
+        prep.update(temps=temps, top_ks=top_ks, top_ps=top_ps,
+                    first_np=first_np)
+        return prep
 
     def _decode_dispatch_paged(self, tables, last, valid, key, budget,
                                temps, top_ks, top_ps, row_budgets, done0,
@@ -3320,8 +3353,7 @@ class InferenceEngine:
         # engine-stats store metrics.json/bench already read stays the
         # return value; the registry is the shared spine.
         from . import trace_hooks
-        trace_hooks.publish_gen_stats(stats, self.cfg.name,
-                                      perf=self.perf)
+        trace_hooks.publish_gen_stats(stats, self.cfg.name)
         trace_hooks.publish_int4_paths(stats.int4_paths, self.cfg.name)
         # Memory ledger at the call boundary (ISSUE 6): slot/page
         # occupancy, fragmentation, HBM — event-rate host math only.

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import telemetry
 from .models.common import ModelConfig
 
 
@@ -207,6 +208,9 @@ class PagedKVCache:
             list(range(r * per_replica + 1, (r + 1) * per_replica))
             for r in range(self.data_size)]
         self._refs: dict[int, int] = {}
+        # Pages handed out by _alloc_page, lifetime (an admission's
+        # `plan` span reports what it took: pages_allocated).
+        self.pages_allocated = 0
         # Cross-session prefix cache (engine/prefix_cache.py, ISSUE 7):
         # attached by the engine after construction. The allocator's only
         # couplings are (a) commit() publishes complete pages into it,
@@ -283,14 +287,25 @@ class PagedKVCache:
             self.pools = list(combined[:n])
             self.scales = list(combined[n:])
 
-    def _run_page_copy(self, src_ids, dst_ids) -> None:
+    def _run_page_copy(self, src_ids, dst_ids, cause: str) -> None:
         """Whole-page device copy through the engine's jit'd copier —
         scale rows ride the same dispatch on quantized pools (a COW'd
-        or adopted page without its scales would dequantize garbage)."""
+        or adopted page without its scales would dequantize garbage).
+        Nothing reads the copy back, so it does not feed the loop
+        clock: armed, its host time is a `page_copy` span (ISSUE 37)
+        under whatever the calling thread has open, with the pages
+        copied and the `cause` — `alias` (a prefix-cache attach's
+        boundary page), `share` (alias_span's, between two slots) or
+        `cow` (a shared page about to be written)."""
+        span = telemetry.NULL_SPAN
+        if telemetry.ACTIVE:
+            span = telemetry.start_span("page_copy", pages=len(src_ids),
+                                        cause=cause)
         out = self._copy_pages_fn(self.combined_pools(),
                                   jnp.asarray(src_ids, jnp.int32),
                                   jnp.asarray(dst_ids, jnp.int32))
         self.set_combined(out)
+        span.end()
 
     def slot_names(self) -> list[str]:
         return list(self._slots)
@@ -529,7 +544,7 @@ class PagedKVCache:
         fresh = self._alloc_page(pinned, state.replica)
         self._decref(p)
         state.pages[j] = fresh
-        self._run_page_copy([p], [fresh])
+        self._run_page_copy([p], [fresh], "cow")
         return fresh
 
     def _alloc_page(self, pinned_names: tuple[str, ...],
@@ -565,6 +580,7 @@ class PagedKVCache:
                 f"Page pool exhausted on data replica {replica}: all its "
                 "pages pinned by the in-flight batch — raise num_pages "
                 "(tpu-llm adapter config) or lower max_new_tokens")
+        self.pages_allocated += 1
         return free.pop(0)
 
     # --- raw page loans (ISSUE 13: tree-verify private path pages) ---
@@ -702,11 +718,13 @@ class PagedKVCache:
                 self.cow_page(name, j, pinned)
 
     def alias_span(self, src_name: str, dst_name: str, lo: int,
-                   hi: int, pinned: tuple[str, ...] = ()) -> None:
+                   hi: int, pinned: tuple[str, ...] = ()
+                   ) -> tuple[int, int]:
         """Give dst the K/V for positions [lo, hi) from src: whole pages
         alias (refcount++), the partial boundary pages are device-copied.
         Precondition: src's cache covers [0, hi) and the two token streams
-        agree on [0, hi) (guaranteed by LCP-based callers)."""
+        agree on [0, hi) (guaranteed by LCP-based callers).
+        -> (pages aliased, pages copied)."""
         # Pin BOTH endpoints: _alloc_page's eviction may otherwise release
         # the donor mid-call and the later incref loop would resurrect
         # pages already sitting in the free list — silent corruption once
@@ -728,6 +746,7 @@ class PagedKVCache:
             # misuse rather than corrupt silently.
             raise RuntimeError("alias_span: dst does not cover up to lo")
         cow_src, cow_dst = [], []
+        aliased = 0
 
         def copy_into_dst(j: int) -> None:
             """Give dst its own exclusively-held page j, filled from
@@ -758,13 +777,15 @@ class PagedKVCache:
                 else:
                     dst.pages.append(src.pages[j])
                 self._incref(src.pages[j])
+                aliased += 1
             else:
                 copy_into_dst(j)
         # partial tail [hi_page*ps, hi): device-copy src's page
         if hi % ps and hi_page < len(src.pages):
             copy_into_dst(hi_page)
         if cow_src:
-            self._run_page_copy(cow_src, cow_dst)
+            self._run_page_copy(cow_src, cow_dst, "share")
+        return aliased, len(cow_src)
 
     def adopt_span(self, dst_name: str, src_pages: list[int], lo: int,
                    hi: int, pinned: tuple[str, ...] = ()) -> None:
@@ -832,7 +853,7 @@ class PagedKVCache:
                 else:
                     copy_into_dst(j)
             if cow_src:
-                self._run_page_copy(cow_src, cow_dst)
+                self._run_page_copy(cow_src, cow_dst, "alias")
         finally:
             for j, p in guards.items():
                 if j not in transferred:

@@ -388,11 +388,17 @@ class SessionScheduler:
         # The loop clock (ISSUE 25): which phase the loop thread is in,
         # lifetime seconds per phase (describe()["loop_seconds"], the
         # roundtable_sched_loop_seconds_total series), and — armed —
-        # one `loop.<phase>` span per stretch. Marked on the loop
-        # thread only; `_loop_published` is what the series has seen.
+        # one `loop.<phase>` span per stretch. Its feed bit (ISSUE 37):
+        # the part of each phase spent with no step program of this
+        # loop's outstanding (["loop_starved_seconds"], the
+        # roundtable_sched_starved_seconds_total series) — fed where a
+        # runner's dispatch returns, drained where its read does.
+        # Marked on the loop thread only; `_loop_published` and
+        # `_starved_published` are what the two series have seen.
         self._clock = telemetry.LoopClock(
             LOOP_PHASES, "wait", within=_LOOP_WITHIN, engine=self._tname)
         self._loop_published = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._starved_published = dict.fromkeys(LOOP_PHASES, 0.0)
         # Replica identity (ISSUE 17): set by the session router when
         # this scheduler serves as one replica of a data-parallel
         # fleet. N replicas of one model share `_tname` (same config),
@@ -672,6 +678,7 @@ class SessionScheduler:
         with self._cv:
             occ = list(self._occupancy)
             events = list(self._events)
+        loop_seconds, loop_starved = self._clock.snapshots()
         return {
             "admitted": self.admitted,
             "refused": self.refused,
@@ -713,7 +720,8 @@ class SessionScheduler:
             },
             "journal_turns": self.journal_turns,
             "journal_errors": self.journal_errors,
-            "loop_seconds": self._clock.snapshot(),
+            "loop_seconds": loop_seconds,
+            "loop_starved_seconds": loop_starved,
             "events": events,
         }
 
@@ -929,6 +937,7 @@ class SessionScheduler:
                 # An unexpected scheduler bug must not wedge every
                 # submitter: fail all in-flight work with the error.
                 self._event("loop_error", error=str(e))
+                self._clock.drain()
                 for req in list(self._active_reqs):
                     self._fail_request(req, e)
                 self.reject_queued(e)
@@ -980,17 +989,22 @@ class SessionScheduler:
         self._publish_loop_seconds()
 
     def _publish_loop_seconds(self) -> None:
-        """Move roundtable_sched_loop_seconds_total{phase=} by what the
+        """Move roundtable_sched_loop_seconds_total{phase=} and
+        roundtable_sched_starved_seconds_total{phase=} by what the
         loop clock gained since the last tick's end (the _bump rule:
-        describe()["loop_seconds"] and the series are one store)."""
-        seen = self._loop_published
+        describe()["loop_seconds"], ["loop_starved_seconds"] and the
+        two series are one store)."""
         labels = self._series_labels()
-        for phase, total in self._clock.seconds.items():
-            gained = total - seen[phase]
-            if gained > 0.0:
-                seen[phase] = total
-                telemetry.inc("roundtable_sched_loop_seconds_total",
-                              gained, phase=phase, **labels)
+        for series, totals, seen in (
+                ("roundtable_sched_loop_seconds_total",
+                 self._clock.seconds, self._loop_published),
+                ("roundtable_sched_starved_seconds_total",
+                 self._clock.starved, self._starved_published)):
+            for phase, total in totals.items():
+                gained = total - seen[phase]
+                if gained > 0.0:
+                    seen[phase] = total
+                    telemetry.inc(series, gained, phase=phase, **labels)
 
     def _acquire_engine(self) -> None:
         if not self._lock_held:
@@ -1085,6 +1099,7 @@ class SessionScheduler:
                 else:
                     self._start_request(req)
             except Exception as e:  # noqa: BLE001 — per-request contain
+                self._clock.drain()     # a prologue nobody reads
                 if self._requeue_on_exhaustion(req, e):
                     return
                 # _prepare_batch may have acquired slots/pages before
@@ -1539,7 +1554,9 @@ class SessionScheduler:
         budgets/deadline/drain) and _tick takes over."""
         clock = self._clock
         clock.mark("build")
+        pack = self._open_pack()
         ctx = self._build_batch(live)
+        pack.leave()
         # The clock starts BEFORE the first dispatch (ISSUE 9 perfmodel
         # satellite): on synchronous backends the jit call itself runs
         # the compute, so starting after it attributed ~zero decode
@@ -1576,6 +1593,11 @@ class SessionScheduler:
             # stretch is the blocking read; the fold's counts ride it.
             seg = self._open_segment("plain", len(alive),
                                      int(ctx["last_d"].shape[0]))
+            if pack is not telemetry.NULL_SPAN:
+                # (The mini-loop's later segments are carried on the
+                # device from this one's outputs: nothing is packed.)
+                self._end_pack(pack, seg, "plain", len(alive), len(alive))
+                pack = telemetry.NULL_SPAN
             try:
                 arrays = self._sync_segment(ctx, handles)
             except Exception as e:  # noqa: BLE001 — preempt-isolate
@@ -1593,15 +1615,6 @@ class SessionScheduler:
             # pure decode — counted into the same series the ragged
             # mixed segments split, so the two paths share one ledger.
             self._note_segment_tokens(0, steps * len(alive))
-            # Live roofline sample at the segment boundary (ISSUE 6):
-            # this segment's aggregate decode rate vs the engine's
-            # weight-streaming ceiling, as a bw_utilization gauge.
-            perf = getattr(self.engine, "perf", None)
-            if perf is not None:
-                perf.publish_decode_sample(
-                    steps * len(alive), now - t_prev,
-                    lora_bytes_per_token=self._lora_bytes_per_token(
-                        alive))
             t_prev = now
             if spec_err is not None:
                 still = [r for r in alive
@@ -1632,6 +1645,24 @@ class SessionScheduler:
             kind=kind, label=label, tick=self._clock.tick)
         seg.__enter__()
         return seg          # every runner ends it (_end_segment)
+
+    def _open_pack(self):
+        """Open a `pack` span (ISSUE 37): building one segment's host
+        arrays, up to its dispatch. Held, not entered — the segment
+        span it packs for opens after it: `_end_pack` makes that one
+        its parent. Unarmed: the null span, nothing built."""
+        if not telemetry.ACTIVE:
+            return telemetry.NULL_SPAN
+        return telemetry.start_span("pack", engine=self._tname)
+
+    def _end_pack(self, pack, seg, kind: str, rows: int,
+                  tokens: int) -> None:
+        """Emit `pack` (its stretch already left) under `seg`, with the
+        kind of segment, its rows and the tokens they feed."""
+        if seg is not telemetry.NULL_SPAN:
+            pack.trace_id, pack.parent_id = seg.trace_id, seg.span_id
+        pack.attrs.update(kind=kind, rows=rows, tokens=tokens)
+        pack.end()
 
     def _end_segment(self, seg, steps: int, decode_tokens: int,
                      prefill_tokens: int = 0, drafted: int = 0,
@@ -1719,47 +1750,74 @@ class SessionScheduler:
         laggards' tables take the leader's span pages (whole pages
         alias, boundary pages device-copy — the same one-shape padded
         copier admission aliasing uses) and the rows unblock, their
-        pending already trimmed to the post-span tail at admission."""
+        pending already trimmed to the post-span tail at admission.
+        Armed, a request whose plans are due gets a `share` span in its
+        own trace (ISSUE 37; the pass that finds nothing due builds
+        none): followers unblocked, pages aliased, pages copied."""
         for req in list(self._active_reqs):
-            if not req.share_plans:
+            due = [p for p in req.share_plans
+                   if p["leader"].pos >= p["hi"]]
+            if not due:
                 continue
-            remaining = []
-            failed: Optional[BaseException] = None
-            for plan in req.share_plans:
-                leader = plan["leader"]
-                if leader.pos < plan["hi"]:
-                    remaining.append(plan)
-                    continue
-                pinned = tuple(r.name for r in self._active)
-                _max_new, padded = clamp_max_new(
-                    req.max_new, self.engine.max_seq_len)
-                try:
-                    for f, lo in plan["followers"]:
-                        self.engine.kv.alias_span(
-                            leader.name, f.name, lo, plan["hi"], pinned)
-                        # Tail capacity (deferred from admission so the
-                        # span pages arrive SHARED, not as transient
-                        # exclusive allocations the alias would
-                        # replace).
-                        self.engine.kv.ensure_capacity(
-                            f.name, len(f.tokens) + padded,
-                            write_from=plan["hi"], pinned=pinned)
-                        f.blocked = False
-                except Exception as e:  # noqa: BLE001 — contain per req
-                    # Pool exhaustion mid-join (the prologue path's
-                    # equivalent was a requeue at admission): fail ONLY
-                    # this request into its adapter ladder — an escape
-                    # to _loop's catch-all would take every in-flight
-                    # session down with it.
-                    failed = e
-                    break
-                self._event("share_alias", session=req.session,
-                            hi=plan["hi"],
-                            followers=len(plan["followers"]))
+            with self._open_share(req) as share:
+                failed, done = self._alias_due(req, due)
+                if share is not telemetry.NULL_SPAN:
+                    share.attrs.update(followers=done[0],
+                                       pages_aliased=done[1],
+                                       copies=done[2])
             if failed is not None:
                 self._fail_request(req, failed)
                 continue
-            req.share_plans = remaining
+            req.share_plans = [p for p in req.share_plans
+                               if p["leader"].pos < p["hi"]]
+
+    def _open_share(self, req: _Request):
+        """A `share` span in the request's own trace; unarmed, the null
+        span — positional arguments only, nothing built."""
+        if not telemetry.ACTIVE:
+            return telemetry.NULL_SPAN
+        return telemetry.start_span("share", parent=req.tele_ctx,
+                                    session=req.session,
+                                    engine=self._tname)
+
+    def _alias_due(self, req: _Request, due: list[dict]
+                   ) -> tuple[Optional[BaseException], tuple]:
+        """Alias each due plan's span into its followers. -> (the error
+        that stopped it, if one did; followers unblocked, pages
+        aliased, pages copied)."""
+        followers = aliased = copies = 0
+        pinned = tuple(r.name for r in self._active)
+        _max_new, padded = clamp_max_new(
+            req.max_new, self.engine.max_seq_len)
+        failed: Optional[BaseException] = None
+        for plan in due:
+            leader = plan["leader"]
+            try:
+                for f, lo in plan["followers"]:
+                    a, c = self.engine.kv.alias_span(
+                        leader.name, f.name, lo, plan["hi"], pinned)
+                    aliased, copies = aliased + a, copies + c
+                    # Tail capacity (deferred from admission so the
+                    # span pages arrive SHARED, not as transient
+                    # exclusive allocations the alias would
+                    # replace).
+                    self.engine.kv.ensure_capacity(
+                        f.name, len(f.tokens) + padded,
+                        write_from=plan["hi"], pinned=pinned)
+                    f.blocked = False
+                    followers += 1
+            except Exception as e:  # noqa: BLE001 — contain per req
+                # Pool exhaustion mid-join (the prologue path's
+                # equivalent was a requeue at admission): fail ONLY
+                # this request into its adapter ladder — an escape
+                # to _loop's catch-all would take every in-flight
+                # session down with it.
+                failed = e
+                break
+            self._event("share_alias", session=req.session,
+                        hi=plan["hi"],
+                        followers=len(plan["followers"]))
+        return failed, (followers, aliased, copies)
 
     def _run_ragged_segment(self, live: list[_Row],
                             filling: list[_Row]) -> None:
@@ -1799,6 +1857,7 @@ class SessionScheduler:
             if live:
                 self._run_segment(live)
             return
+        pack = self._open_pack()
         reqs = self._reqs_of(live + filling)
         remaining = min((req.turn_budget.remaining() for req in reqs),
                         default=float("inf"))
@@ -1849,15 +1908,21 @@ class SessionScheduler:
         # (A model with recurrent state finds each run's state by it.)
         batch["seq_names"] = [r.name for _k, r, _t in rows_in]
 
+        pack.leave()
         t0 = time.monotonic()
         seg = self._open_segment("ragged", len(seqs), shape)
+        if pack is not telemetry.NULL_SPAN:
+            self._end_pack(pack, seg, "ragged", len(seqs),
+                           sum(t for _k, _r, t in rows_in))
         try:
             handles = run_dispatch(
                 lambda: engine._ragged_dispatch(batch),
                 engine.retry, deadline, budget=seg_budget)
+            fed = self._clock.feed()
             self._index_in_flight(handles)
             nxt = host_sync(lambda: np.asarray(handles), seg_budget,
                             "decode")
+            self._clock.drain(fed)
         except Exception as e:  # noqa: BLE001 — preempt-isolate ladder
             seg.end(f"error:{type(e).__name__}")
             self._handle_ragged_failure(live, filling, e)
@@ -1908,9 +1973,7 @@ class SessionScheduler:
 
         # Provenance + attribution: the mixed dispatch splits its wall
         # by per-row token counts — decode rows' share lands in their
-        # requests' decode_seconds, chunk tokens in prefill_seconds —
-        # and the perfmodel gauges get the same split (a mixed batch
-        # must not mislabel its roofline fraction).
+        # requests' decode_seconds, chunk tokens in prefill_seconds.
         engine.note_lora_tokens(lora_toks)
         self.ragged_segments += 1
         telemetry.inc("roundtable_sched_ragged_segments_total",
@@ -1946,10 +2009,6 @@ class SessionScheduler:
             req.sess_max = max(req.sess_max, sessions)
         perf = getattr(engine, "perf", None)
         if perf is not None:
-            perf.publish_mixed_sample(
-                n_prefill, n_decode, wall,
-                lora_bytes_per_token=self._lora_bytes_per_token(
-                    [r for _k, r, _t in rows_in]))
             for req in reqs:
                 perf.publish_session_kv(
                     req.session, sum(r.valid for r in req.rows))
@@ -1964,6 +2023,7 @@ class SessionScheduler:
         the prompt), while decode-only sessions re-dispatch through the
         compiled segment path from intact host+KV state. Loop-thread
         only (single-writer counter bumps need no cv)."""
+        self._clock.drain()     # nobody reads the failed dispatch
         if self._supervisor_intervened(err):
             return
         if self._after_engine_failure(err):
@@ -2158,17 +2218,21 @@ class SessionScheduler:
             # Draft dispatches ride the SAME watchdog/retry/budget
             # seams the verify dispatch uses — a hang mid-propose must
             # hit the deadline ladder, not block the scheduler thread.
-            return run_dispatch(lambda: engine._ragged_dispatch(b),
-                                engine.retry, deadline,
-                                budget=seg_budget)
+            h = run_dispatch(lambda: engine._ragged_dispatch(b),
+                             engine.retry, deadline, budget=seg_budget)
+            self._clock.feed()
+            return h
 
         def draft_read(h):
             if isinstance(h, tuple):
-                return host_sync(
+                out = host_sync(
                     lambda: tuple(np.asarray(x) for x in h),
                     seg_budget, "decode")
-            return host_sync(lambda: np.asarray(h), seg_budget,
-                             "decode")
+            else:
+                out = host_sync(lambda: np.asarray(h), seg_budget,
+                                "decode")
+            self._clock.drain()
+            return out
 
         try:
             drafts_of = self._spec_drafts(live, dispatch=draft_dispatch,
@@ -2185,6 +2249,7 @@ class SessionScheduler:
         if drafts_of is None:
             return False
 
+        pack = self._open_pack()
         from .serving_loop import ragged_pick_shape
         kv = engine.kv
         ps = kv.page_size
@@ -2259,15 +2324,21 @@ class SessionScheduler:
             copy_pairs=copy_pairs,
             copy_slots=engine.spec_copy_slots)
 
+        pack.leave()
         t0 = time.monotonic()
         seg = self._open_segment("spec", len(seqs), shape)
+        if pack is not telemetry.NULL_SPAN:
+            self._end_pack(pack, seg, "spec", len(seqs),
+                           sum(len(q.tokens) for q in seqs))
         try:
             handles = run_dispatch(
                 lambda: engine._ragged_dispatch(batch),
                 engine.retry, deadline, budget=seg_budget)
+            fed = self._clock.feed()
             self._index_in_flight(handles)
             nxt = host_sync(lambda: np.asarray(handles), seg_budget,
                             "decode")
+            self._clock.drain(fed)
         except Exception as e:  # noqa: BLE001 — preempt-isolate ladder
             # Indistinguishable from a decode failure: host state is
             # untouched (the drafts are discarded with the dispatch and
@@ -2421,14 +2492,6 @@ class SessionScheduler:
             req.sess_max = max(req.sess_max, sessions)
         perf = getattr(engine, "perf", None)
         if perf is not None:
-            # Accepted vs dispatch tokens split (ISSUE 9 perfmodel
-            # satellite): the forward streamed weights ONCE for
-            # len(live) rows — that is the roofline-relevant count; the
-            # accepted total is the user-visible rate and must not
-            # report >100% bandwidth utilization.
-            perf.publish_mixed_sample(
-                0, n_emit, wall, decode_dispatch_tokens=len(live),
-                lora_bytes_per_token=self._lora_bytes_per_token(live))
             for req in reqs:
                 perf.publish_session_kv(
                     req.session, sum(r.valid for r in req.rows))
@@ -2472,18 +2535,6 @@ class SessionScheduler:
             if req.turn_budget.token.cancelled or req.turn_budget.expired:
                 return False
         return True
-
-    def _lora_bytes_per_token(self, rows: list[_Row]):
-        """This sample's mean adapter bytes streamed per decoded token
-        (ISSUE 10 perfmodel satellite): the exact mix, so the roofline
-        gauges neither overreport base-only segments against a lora-
-        discounted ceiling nor persona segments against the base one.
-        None on lora-off engines (the perf default applies)."""
-        store = getattr(self.engine, "lora", None)
-        if store is None or not rows:
-            return None
-        n_ad = sum(1 for r in rows if r.adapter_slot)
-        return store.streamed_bytes_per_token() * n_ad / len(rows)
 
     def _reqs_of(self, rows: list[_Row]) -> list[_Request]:
         seen: dict[int, _Request] = {}
@@ -2691,8 +2742,13 @@ class SessionScheduler:
                 ctx["budgets_d"], ctx["done_d"], greedy=ctx["greedy"],
                 lora=ctx["lora"])
 
-        return run_dispatch(dispatch, engine.retry, ctx["deadline"],
-                            budget=ctx["seg_budget"])
+        handles = run_dispatch(dispatch, engine.retry, ctx["deadline"],
+                               budget=ctx["seg_budget"])
+        # The device holds this segment until _sync_segment has read it
+        # (the loop clock's feed bit; a pipelined next segment is a
+        # ticket of its own).
+        ctx["fed"] = self._clock.feed()
+        return handles
 
     def _advance(self, ctx: dict, handles) -> dict:
         """The next segment's ctx from this segment's DEVICE outputs —
@@ -2726,7 +2782,9 @@ class SessionScheduler:
             return (n, np.asarray(out)[:, :n], np.asarray(l2),
                     np.asarray(v2), np.asarray(d2))
 
-        return host_sync(read, ctx["seg_budget"], "decode")
+        arrays = host_sync(read, ctx["seg_budget"], "decode")
+        self._clock.drain(ctx["fed"])
+        return arrays
 
     def _fold_segment(self, ctx: dict, arrays: tuple) -> int:
         """The host half: fold what _sync_segment read into the rows."""
@@ -2768,6 +2826,7 @@ class SessionScheduler:
         fault follows fails alone; everyone else's rows re-run their
         segment from intact host+KV state, byte-identical. Loop-thread
         only (single-writer counter bumps need no cv)."""
+        self._clock.drain()     # nobody reads the failed dispatch
         if self._supervisor_intervened(err):
             return
         if self._after_engine_failure(err):
@@ -3026,9 +3085,7 @@ class SessionScheduler:
                 req.tele.set_attr("occupancy_max", req.occ_max)
                 req.tele.end()
                 req.tele = None
-            trace_hooks.publish_gen_stats(
-                req.stats, self._tname,
-                perf=getattr(engine, "perf", None))
+            trace_hooks.publish_gen_stats(req.stats, self._tname)
             perf = getattr(engine, "perf", None)
             if perf is not None:
                 # Retired session's KV series reads empty, not stale.

@@ -353,6 +353,12 @@ def chunked_prefill(
         last_logits = run_dispatch(
             lambda: dispatch(chunk, offs, lengths), retry, deadline,
             budget=budget)
+        # On a clocked thread the device now holds a program whose
+        # result the prologue's one read waits for (LoopClock.feed; the
+        # read drains every chunk's ticket with its own).
+        clock = telemetry.loop_clock()
+        if clock is not None:
+            clock.feed()
         if final_logits is None:
             final_logits = last_logits
         else:

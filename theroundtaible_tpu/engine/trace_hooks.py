@@ -19,16 +19,11 @@ from ..utils import telemetry
 span = telemetry.span  # re-export: engine call sites read trace_hooks.span
 
 
-def publish_gen_stats(stats, engine_name: str, perf=None) -> None:
+def publish_gen_stats(stats, engine_name: str) -> None:
     """Fold one generate call's GenStats into the registry — the
-    engine-stats store metrics.json/bench records become views of.
-    `perf` (utils/perfmodel.EnginePerf) additionally publishes the
-    call's roofline gauges: decode bw_utilization and prefill MFU per
-    engine per phase (ISSUE 6)."""
+    engine-stats store metrics.json/bench records become views of."""
     if stats is None:
         return
-    if perf is not None:
-        perf.publish_call(stats)
     reg = telemetry.REGISTRY
     if stats.prefill_tokens:
         reg.inc("roundtable_prefill_tokens_total", stats.prefill_tokens,

@@ -20,12 +20,13 @@ ceiling it was, or why. This module is the ONE definition:
   bench.py embeds this dict verbatim, and the drift test pins its keys
   here so the bench schema and the live gauges can never fork again.
 - **EnginePerf** — a per-engine instance built once at engine
-  construction (param bytes + ceilings + KV bytes/token). Serving
-  publishes through it at EVENT rate: per generate call
-  (`publish_call` → `roundtable_bw_utilization{phase=decode}` /
-  `roundtable_mfu{phase=prefill}` gauges) and per scheduler decode
-  segment (`publish_decode_sample`), plus per-session KV-footprint
-  gauges (`publish_session_kv`).
+  construction (param bytes + ceilings + KV bytes/token): the two
+  ceiling gauges, and per-session KV-footprint gauges
+  (`publish_session_kv`). It publishes no utilization: a rate over
+  the host's wall clock against an assumed peak said nothing the
+  device's own trace did not contradict (ISSUE 37); how long the
+  device waits for the host is the scheduler's
+  `roundtable_sched_starved_seconds_total`.
 - **Span overheads** — `span_overheads()` folds the PR-5 span tree
   into a per-rung breakdown: how much of a decode/prefill/segment
   span's wall was inside device dispatches, host syncs, or the
@@ -269,9 +270,7 @@ class EnginePerf:
         # streams its adapter's A/B bytes on top of the base weights
         # every decode token, so the weight-streaming ceiling drops.
         # The engine's LoraStore keeps this at the per-adapter cost
-        # while any adapter is resident (a conservative default for
-        # call-level gauges); the scheduler passes the exact per-
-        # sample mix to publish_decode_sample/publish_mixed_sample.
+        # while any adapter is resident (a conservative default).
         self.lora_row_bytes = 0.0
         # Quantized-KV streamed term (ISSUE 11): decode streams each
         # row's whole context from the page pool every token on top of
@@ -345,8 +344,7 @@ class EnginePerf:
     def _decode_ceiling(self, lora_bytes_per_token=None) -> float:
         """The weight-streaming ceiling with LoRA bytes folded in
         (ISSUE 10): a K-adapter batch streams base + adapter bytes per
-        token, so judging it against the base-only ceiling would
-        overreport bw_utilization exactly when personas are active.
+        token, so the base-only ceiling would flatter a persona batch.
         The quantized-KV streamed term (ISSUE 11) folds in the same
         way: context x resident cell bytes per decoded token — int8
         pages halve it, which RAISES the ceiling this gauge divides by
@@ -361,107 +359,6 @@ class EnginePerf:
                                   kv_stream_bytes=int(kv_extra))
 
     # --- live publication seams ---
-
-    def publish_call(self, stats) -> None:
-        """Per-generate-call roofline gauges from a GenStats: decode
-        bandwidth utilization and prefill MFU, per engine per phase."""
-        if self.decode_ceiling is None:
-            return
-        n = 0
-        if stats.decode_seconds and stats.decode_tokens:
-            # bw_utilization/mfu only — roundtable_decode_tps is
-            # publish_gen_stats' series (one writer per series).
-            telemetry.set_gauge(
-                "roundtable_bw_utilization",
-                stats.decode_tps / self._decode_ceiling(),
-                engine=self.engine_name, phase="decode")
-            n += 1
-        if stats.prefill_seconds and stats.prefill_tokens:
-            telemetry.set_gauge(
-                "roundtable_mfu",
-                stats.prefill_tps / self.prefill_peak,
-                engine=self.engine_name, phase="prefill")
-            n += 1
-        if n:
-            note_published(n)
-
-    def publish_decode_sample(self, tokens: int, seconds: float,
-                              lora_bytes_per_token=None) -> None:
-        """Per-decode-segment utilization sample (the scheduler's
-        segment boundary): tokens is the segment's attributed count
-        (steps × live rows — rows finishing mid-segment emit filler,
-        so this is a slight over-attribution, stated here once).
-        `lora_bytes_per_token` (ISSUE 10): the sample's actual mean
-        adapter bytes streamed per token (None = the store-level
-        default)."""
-        if self.decode_ceiling is None or seconds <= 0 or tokens <= 0:
-            return
-        ceiling = self._decode_ceiling(lora_bytes_per_token)
-        telemetry.set_gauge("roundtable_bw_utilization",
-                            (tokens / seconds) / ceiling,
-                            engine=self.engine_name, phase="decode")
-        note_published(1)
-
-    def publish_mixed_sample(self, prefill_tokens: int,
-                             decode_tokens: int,
-                             seconds: float,
-                             decode_dispatch_tokens: Optional[int] = None,
-                             lora_bytes_per_token=None,
-                             ) -> None:
-        """Per-RAGGED-segment attribution (ISSUE 8): a mixed dispatch
-        carries both prefill chunks and decode tokens, so the roofline
-        gauges split by per-row token counts instead of classifying the
-        whole dispatch as one phase — decode_tokens/wall against the
-        weight-streaming ceiling, prefill_tokens/wall against the
-        compute peak. Both rates run over the FULL wall (the phases
-        genuinely shared it), so each gauge is a conservative
-        lower-bound utilization and their information adds up to the
-        real mix — a pure-decode segment degenerates to exactly
-        publish_decode_sample.
-
-        `decode_dispatch_tokens` (ISSUE 9): a SPECULATIVE verify
-        dispatch commits more decode tokens than it streamed weights
-        for — the forward reads the weight tree once per ROW, not once
-        per accepted token. The roofline gauge must use the dispatch
-        count (1 per row per forward, what a 1-token decode would have
-        produced) or a 3x-accepting run reports 300% bandwidth
-        utilization; the ACCEPTED rate publishes separately as the
-        user-visible `roundtable_spec_accepted_tps`. None (the plain
-        ragged path) means the two counts coincide.
-
-        `lora_bytes_per_token` (ISSUE 10): the sample's mean adapter
-        bytes streamed per token — folds into the decode ceiling so a
-        K-adapter batch doesn't overreport bw_utilization."""
-        if self.decode_ceiling is None or seconds <= 0:
-            return
-        n = 0
-        if decode_tokens > 0:
-            roofline_tokens = (decode_tokens
-                               if decode_dispatch_tokens is None
-                               else decode_dispatch_tokens)
-            telemetry.set_gauge(
-                "roundtable_bw_utilization",
-                (roofline_tokens / seconds)
-                / self._decode_ceiling(lora_bytes_per_token),
-                engine=self.engine_name, phase="decode")
-            n += 1
-            if decode_dispatch_tokens is not None:
-                # Published on EVERY speculative sample, including the
-                # zero-accept case where the two counts coincide — a
-                # gauge updated only on acceptance would stay frozen at
-                # the last good rate exactly when acceptance collapses.
-                telemetry.set_gauge(
-                    "roundtable_spec_accepted_tps",
-                    decode_tokens / seconds, engine=self.engine_name)
-                n += 1
-        if prefill_tokens > 0:
-            telemetry.set_gauge(
-                "roundtable_mfu",
-                (prefill_tokens / seconds) / self.prefill_peak,
-                engine=self.engine_name, phase="prefill")
-            n += 1
-        if n:
-            note_published(n)
 
     def publish_session_kv(self, session: str, cached_tokens: int) -> None:
         """Per-session KV-footprint gauge (the memory ledger's
@@ -565,9 +462,9 @@ def span_overheads(spans: list[dict]) -> dict[str, dict]:
 # Registry series the perf block collects (prefix match on the series
 # name): roofline gauges, compile observatory, memory ledger.
 PERF_SERIES_PREFIXES = (
-    "roundtable_bw_utilization", "roundtable_mfu",
     "roundtable_decode_ceiling_tps", "roundtable_prefill_peak_tps",
     "roundtable_decode_tps",
+    "roundtable_sched_starved_seconds",  # ISSUE 37: the feed bit
     "roundtable_compile", "roundtable_steady_state",
     "roundtable_kv_", "roundtable_hbm_", "roundtable_session_kv_",
     "roundtable_prefix_",   # ISSUE 7: prefix-cache hit/miss/size series

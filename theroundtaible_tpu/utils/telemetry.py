@@ -80,6 +80,13 @@ _PROFILING = False
 TRACE_RUNGS = ("profile", "request", "resume", "discussion", "round",
                "turn", "prefill", "decode", "segment", "dispatch")
 
+# What a round's start does on the host (ISSUE 37) — children of
+# `admit` and `segment`, records of the armed buffer (and the ring and
+# sinks, as any span) that never mirror into the profiler's trace: an
+# `rt:pack` there would take `rt:loop.build`'s idle gaps in the trace's
+# reduction, whose names readers and the ledger's rows are built on.
+UNMIRRORED_RUNGS = frozenset(("plan", "page_copy", "share", "pack"))
+
 _INF = float("inf")
 
 # While armed, every finished span also lands in one in-memory buffer,
@@ -609,7 +616,8 @@ class Span:
     a lexical scope (the scheduler's per-request turn spans).
 
     Only a LEXICAL span (entered with `with`) mirrors into the device
-    profile: a profiler annotation is meant to nest on its thread, and
+    profile, and none of `UNMIRRORED_RUNGS` does: a profiler annotation
+    is meant to nest on its thread, and
     a span held across scheduler ticks (`request`, `resume`, `turn`)
     would lie open over every instant in which any row is live and win
     every idle gap no inner span covers. Held spans keep their records,
@@ -642,7 +650,7 @@ class Span:
     def __enter__(self) -> "Span":
         _stack().append(self)
         self._on_stack = True
-        if _PROFILING:
+        if _PROFILING and self.rung not in UNMIRRORED_RUNGS:
             self._annotation = _open_annotation(self.rung)
         return self
 
@@ -830,7 +838,8 @@ def emit_span(rung: str, dur_s: float, **attrs) -> None:
 
 
 class LoopClock:
-    """Which phase one loop thread is in, at every instant.
+    """Which phase one loop thread is in, at every instant — and whether
+    the device has work of this loop's outstanding.
 
     The idiom of `tracing.RequestTrace.stage()`: a mark attributes the
     time since the previous mark, so the phases telescope to the
@@ -844,37 +853,66 @@ class LoopClock:
     phase by the phase it is entered from (a blocking read inside
     admission is `admit_sync`, not `sync`).
 
-    Always on: `seconds`, per-phase lifetime totals (a clock read and
-    a float add per mark). Armed (`ACTIVE`): each stretch of a phase is
-    also a span record `loop.<phase>` carrying the tick's index — in
-    the armed buffer only: ten a tick would push the flight ring's
-    request and segment spans out — and, while a profile is taken, an
-    `rt:loop.<phase>` annotation on the loop's thread. The stretches
-    lie end to end, each starting on the clock read that ended the one
-    before, so clipped to any stretch of time they sum to it."""
+    The feed bit (ISSUE 37): the loop tells the clock where it holds
+    the handles. `feed()` when a dispatch that issued a step program
+    has returned (→ that program's ticket), `drain(ticket)` when the
+    blocking read of its result has returned. The device runs one
+    loop's programs in the order they were issued, so a read drains
+    every ticket up to its own; the clock is `fed` while a ticket is
+    outstanding — a count of handles, not a flag: a pipelined loop
+    issues the next segment before it reads the current one, and that
+    read leaves it fed. A program nothing reads back (a page copy)
+    takes no ticket. Unfed time is time the device can only have spent
+    idle for want of work from this loop.
 
-    __slots__ = ("seconds", "phase", "tick", "_within", "_last",
-                 "_attrs", "_trace_id", "_open")
+    Always on: `seconds`, per-phase lifetime totals, and `starved`,
+    the part of each spent unfed (a clock read and a float add or two
+    per mark; one clock read more where the bit flips). Armed
+    (`ACTIVE`): each stretch of a phase is also a span record
+    `loop.<phase>` carrying the tick's index and `fed` (0 | 1) — a
+    stretch also ends where the bit flips, so each is wholly one or
+    the other — in the armed buffer only: ten a tick would push the
+    flight ring's request and segment spans out — and, while a profile
+    is taken, an `rt:loop.<phase>` annotation on the loop's thread.
+    The stretches lie end to end, each starting on the clock read that
+    ended the one before, so clipped to any stretch of time they sum
+    to it."""
+
+    __slots__ = ("seconds", "starved", "phase", "tick", "fed", "_issued",
+                 "_drained", "_within", "_last", "_attrs", "_trace_id",
+                 "_open")
 
     def __init__(self, phases: tuple[str, ...], start: str,
                  within: Optional[dict[str, dict[str, str]]] = None,
                  **attrs) -> None:
         self.seconds: dict[str, float] = dict.fromkeys(phases, 0.0)
+        self.starved: dict[str, float] = dict.fromkeys(phases, 0.0)
         self.phase = start
         self.tick = 0
+        self.fed = False
+        self._issued = self._drained = 0
         self._within = within or {}
         self._last = time.monotonic()
         self._attrs = attrs
         self._trace_id = uuid.uuid4().hex[:16]
-        # The open stretch's span: (phase, tick, t0, wall0, mirror).
+        # The open stretch's span: (phase, tick, fed, t0, wall0, mirror).
         self._open: Optional[tuple] = None
+
+    def _lap(self) -> float:
+        """Attribute the time since the last clock read to the open
+        phase (and to its starved part while unfed); → now."""
+        now = time.monotonic()
+        gained = now - self._last
+        self.seconds[self.phase] += gained
+        if not self.fed:
+            self.starved[self.phase] += gained
+        self._last = now
+        return now
 
     def mark(self, phase: str) -> None:
         """Everything since the last mark was the phase that was open;
         from here on it is `phase`."""
-        now = time.monotonic()
-        self.seconds[self.phase] += now - self._last
-        self._last = now
+        now = self._lap()
         changed = phase != self.phase
         self.phase = phase
         if self._open is None:
@@ -891,29 +929,68 @@ class LoopClock:
         self.mark(renames.get(phase, phase) if renames else phase)
         return prev
 
+    def feed(self) -> int:
+        """A dispatch that issued a step program has returned, and this
+        loop will read the program's result: → its ticket."""
+        self._issued += 1
+        if not self.fed:
+            self._flip(True)
+        return self._issued
+
+    def drain(self, ticket: Optional[int] = None) -> None:
+        """The blocking read of `ticket`'s result has returned (None:
+        of the last one issued — also what a failed dispatch's handler
+        calls, whose handles nobody will read). Programs end in the
+        order they were issued, so every earlier ticket is drained
+        with it."""
+        if ticket is None or ticket > self._drained:
+            self._drained = self._issued if ticket is None else ticket
+        if self.fed and self._drained >= self._issued:
+            self._flip(False)
+
+    def _flip(self, fed: bool) -> None:
+        now = self._lap()
+        self.fed = fed
+        if self._open is not None or ACTIVE:
+            self._respan(now)
+
+    def snapshots(self) -> tuple[dict[str, float], dict[str, float]]:
+        """(`seconds`, `starved`) by phase, lifetime, the open phase's
+        counted up to now — read from other threads (describe()); a
+        read that races a mark is off by that one lap at most. One
+        clock read serves both and `starved` is copied first, so for
+        every phase starved <= seconds."""
+        starved = dict(self.starved)
+        seconds = dict(self.seconds)
+        phase, fed = self.phase, self.fed
+        open_s = max(time.monotonic() - self._last, 0.0)
+        seconds[phase] += open_s
+        if not fed:
+            starved[phase] += open_s
+        return ({k: round(v, 6) for k, v in seconds.items()},
+                {k: round(min(v, seconds[k]), 6)
+                 for k, v in starved.items()})
+
     def snapshot(self) -> dict[str, float]:
-        """Per-phase lifetime seconds, the open phase's counted up to
-        now — read from other threads (describe()); a read that races
-        a mark is off by that one lap at most."""
-        out = dict(self.seconds)
-        out[self.phase] += max(time.monotonic() - self._last, 0.0)
-        return {k: round(v, 6) for k, v in out.items()}
+        """Per-phase lifetime seconds (`snapshots()[0]`)."""
+        return self.snapshots()[0]
 
     def _respan(self, now: float) -> None:
         """Close the open stretch's span at `now` and, while armed,
-        open the new phase's at the same instant."""
+        open the next one (the phase and feed bit as they stand) at the
+        same instant."""
         if self._open is not None:
-            phase, tick, t0, wall0, mirror = self._open
+            phase, tick, fed, t0, wall0, mirror = self._open
             self._open = None
             if mirror is not None:
                 _close_annotation(mirror)
             _keep_span(_span_record(
                 self._trace_id, uuid.uuid4().hex[:12], "",
                 "loop." + phase, wall0, t0, now - t0, "ok",
-                dict(self._attrs, tick=tick)))
+                dict(self._attrs, tick=tick, fed=fed)))
         if ACTIVE:
             self._open = (
-                self.phase, self.tick, now, time.time(),
+                self.phase, self.tick, int(self.fed), now, time.time(),
                 _open_annotation("loop." + self.phase)
                 if _PROFILING else None)
 
@@ -1021,6 +1098,11 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         # end of every tick by what the clock gained since the last.
         "loop_seconds": "roundtable_sched_loop_seconds_total"
                         "{phase=...}",
+        # ISSUE 37: the part of each phase the loop spent with no step
+        # program of its own outstanding on the device (the clock's
+        # feed bit); moves with loop_seconds, by the same rule.
+        "loop_starved_seconds":
+            "roundtable_sched_starved_seconds_total{phase=...}",
         "events": "flight recorder ring (sched_* kinds)",
     },
     # engine.describe()["spec_decode"] (ISSUE 9 + 13): the speculation
