@@ -5,10 +5,11 @@ The loop clock's feed bit in the real scheduler — fed where a runner's
 dispatch returns, drained where its read does, pipelined segments and
 prologues included — and the four rungs of what a round's start does on
 the host: `plan` (an admission's host half, `engine._prepare_batch`),
-`page_copy` (`paging._run_page_copy`, by cause), `share`
-(`_apply_share_plans`, a request whose plans are due) and `pack` (a
-segment's host arrays). Each is emitted once per cause with its
-attributes and parent, is absent when nothing is due, and nothing is
+`page_copy` (`paging._issue_pending`: the flush of the page copies
+queued since the pools were last taken, counted by cause — ISSUE 38),
+`share` (`_apply_share_plans`, a request whose plans are due) and
+`pack` (a segment's host arrays). Each is emitted once per cause with
+its attributes and parent, is absent when nothing is due, and nothing is
 built for it unarmed. The clock's own arithmetic is in
 tests/test_telemetry.py; the readers are in
 tests/benchmarks/test_benchmark_feed_readers.py.
@@ -126,10 +127,30 @@ class TestRoundStartRungs:
             # the common span ends inside its first page: nothing to
             # alias whole, the boundary page is copied
             assert (at["pages_aliased"], at["copies"]) == (0, 1)
-            (copy,) = [r for r in by_rung(spans, "page_copy")
-                       if r["parent_id"] == s["span_id"]]
-            assert copy["attrs"] == {"pages": 1, "cause": "share"}
-            assert copy["trace_id"] == request.trace_id
+        # the copies themselves wait on the page cache: no `page_copy`
+        # under a `share`, and every follower's copy in some later
+        # flush (the prologue's follower took its own in the plan)
+        copies = by_rung(spans, "page_copy")
+        share_ids = {s["span_id"] for s in shares}
+        assert not any(c["parent_id"] in share_ids for c in copies)
+        assert sum(c["attrs"]["share"] for c in copies) == len(ROUND)
+
+    def test_page_copy_is_a_flush_of_what_the_round_queued(self,
+                                                           cold_round):
+        """One `page_copy` span a flush — where a program takes the
+        pools — with the copies it gathered by cause: fewer flushes
+        than copies, and every pair in one program."""
+        spans, _requests, _desc = cold_round
+        copies = by_rung(spans, "page_copy")
+        assert copies
+        for c in copies:
+            at = c["attrs"]
+            assert set(at) == {"copies", "alias", "share", "cow",
+                               "pages", "programs"}
+            assert at["copies"] == (at["alias"] + at["share"]
+                                    + at["cow"]) >= 1
+            assert (at["pages"], at["programs"]) == (at["copies"], 1)
+        assert len(copies) < sum(c["attrs"]["copies"] for c in copies)
 
     def test_pack_once_a_segment_with_what_it_packed(self, cold_round):
         spans, _requests, _desc = cold_round
@@ -215,8 +236,8 @@ CAUSES = [
 @pytest.mark.telemetry
 @pytest.mark.parametrize("cause,act,copies", CAUSES,
                          ids=[str(c[0]) for c in CAUSES])
-def test_page_copy_names_its_cause_under_whatever_is_open(cause, act,
-                                                          copies):
+def test_page_copy_counts_by_cause_under_whatever_takes_the_pools(
+        cause, act, copies):
     kv = make_cache()
     for name in ("a", "b", "c"):
         kv.acquire(name)
@@ -226,15 +247,74 @@ def test_page_copy_names_its_cause_under_whatever_is_open(cause, act,
     telemetry.disarm()
     telemetry.arm()
     t_a = time.monotonic()
-    with telemetry.span("admit") as admit:
-        act(kv)
+    act(kv)                    # queued: no span where nothing is issued
+    assert by_rung(telemetry.spans_between(t_a, time.monotonic()),
+                   "page_copy") == []
+    with telemetry.span("segment") as segment:
+        kv.combined_pools()
     recs = by_rung(telemetry.spans_between(t_a, time.monotonic()),
                    "page_copy")
     assert len(recs) == len(kv._recorded_copies) == copies
     for r in recs:
-        assert r["attrs"] == {"pages": 1, "cause": cause}
-        assert (r["trace_id"], r["parent_id"]) == (admit.trace_id,
-                                                   admit.span_id)
+        assert r["attrs"] == {"copies": 1, "alias": 0, "share": 0,
+                              "cow": 0, "pages": 1, "programs": 1,
+                              cause: 1}
+        assert (r["trace_id"], r["parent_id"]) == (segment.trace_id,
+                                                   segment.span_id)
+
+
+KNIGHTS = ("lancelot", "galahad", "percival")
+THREE_KNIGHTS = {
+    f"t{i}": [(knight, "The round table met at dusk to weigh the "
+               f"ferry tolls and the mill race. Discussion {i}: "
+               + f"{knight} speaks of the miller's share. " * (i + 1))
+              for knight in KNIGHTS]
+    for i in range(3)}
+
+
+def serve_three_knights(eng):
+    """THREE_KNIGHTS, nothing of it cached, admitted by one tick.
+    -> (texts by session, what describe()["paging"] gained)."""
+    before = eng.describe()["paging"]
+    sched = SessionScheduler(eng)
+    try:
+        sched.pause_admission("line up")
+        reqs = {sid: sched.submit_async(sid, turns, max_new_tokens=16)
+                for sid, turns in THREE_KNIGHTS.items()}
+        sched.reopen_admission()
+        said = {sid: sched.wait(r)[0] for sid, r in reqs.items()}
+    finally:
+        sched.close()
+    after = eng.describe()["paging"]
+    return said, {k: after[k] - before[k]
+                  for k in ("page_copies", "page_copy_programs")}
+
+
+def test_a_three_knight_round_gathers_its_copies_and_says_the_same(
+        engine):
+    """ISSUE 38 at the scheduler: three knights a session share a
+    prefix that ends inside a page, so every follower's boundary page
+    is copied — queued on the page cache, and issued with whatever
+    else is pending when a program takes the pools. Against an engine
+    that issues each copy where it is queued (what the cache did
+    before), the same tokens from fewer programs."""
+    assert set(engine.describe()["paging"]) == set(
+        telemetry.SURFACE_BINDINGS["engine_paging"])
+    said, gained = serve_three_knights(engine)
+    assert 0 < gained["page_copy_programs"] < gained["page_copies"]
+
+    before = make_engine()
+    queue = before.kv._run_page_copy
+
+    def issue_at_once(src, dst, cause):
+        queue(src, dst, cause)
+        before.kv.combined_pools()
+
+    before.kv._run_page_copy = issue_at_once
+    said_before, gained_before = serve_three_knights(before)
+    assert said == said_before
+    assert gained_before["page_copy_programs"] \
+        > gained["page_copy_programs"]
 
 
 def test_alias_span_counts_what_it_aliased_and_copied():
@@ -335,6 +415,38 @@ class TestDescribeAndSeries:
                 assert moved == pytest.approx(gained[p], abs=1e-4), p
         assert "loop_starved_seconds" in telemetry.SURFACE_BINDINGS[
             "scheduler_describe"]
+
+    def test_page_copies_and_their_programs_one_store_each(self):
+        """describe()["paging"]'s counts and the two series have one
+        writer each and move together (ISSUE 38): a copy where it is
+        queued, by cause; a program where the flush issues it."""
+        def series():
+            total = telemetry.REGISTRY.counter_total
+            return ({c: total("roundtable_page_copies_total",
+                              engine="tiny-gemma", cause=c)
+                     for c in ("alias", "share", "cow")},
+                    total("roundtable_page_copy_programs_total",
+                          engine="tiny-gemma"))
+
+        kv = make_cache()
+        for name in ("a", "b", "c"):
+            kv.acquire(name)
+        kv.ensure_capacity("a", 48, write_from=0)
+        kv.commit("a", list(range(48)))
+        kv.alias_span("a", "b", 0, 48)
+        copies_0, programs_0 = series()
+        kv.alias_span("a", "c", 0, 40)                  # share
+        kv.ensure_capacity("b", 80, write_from=40)      # cow
+        copies_1, programs_1 = series()
+        assert {c: copies_1[c] - copies_0[c] for c in copies_0} \
+            == {"alias": 0, "share": 1, "cow": 1} \
+            == kv.describe()["page_copies_by_cause"]
+        assert programs_1 == programs_0         # queued, nothing issued
+        kv.combined_pools()
+        assert series()[1] - programs_0 == 1 \
+            == kv.describe()["page_copy_programs"]
+        assert set(kv.describe()) == set(
+            telemetry.SURFACE_BINDINGS["engine_paging"])
 
     @pytest.mark.chaos
     def test_a_failed_dispatch_leaves_the_clock_unfed(self, engine):

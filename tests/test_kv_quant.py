@@ -481,6 +481,7 @@ class TestSharingTiers:
         kv.adopt_span("b", [page], 0, 16)
         fresh = kv.cow_page("a", 0, pinned=("a", "b"))
         assert fresh != page
+        kv.combined_pools()        # the queued copy goes out here
         for li in range(cfg.num_layers):
             k, _ = kv.pools[li]
             ks, _ = kv.scales[li]

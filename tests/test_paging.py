@@ -16,25 +16,43 @@ from theroundtaible_tpu.engine.sampling import SamplingParams
 PS = 16  # small pages so tiny prompts span several
 
 
-def make_cache(num_slots=4, max_seq=128, num_pages=None, copies=None,
-               data_size=1):
-    cfg = get_model_config("tiny-gemma", max_seq_len=max_seq)
-    recorded = []
+def recording_copier(recorded, raw):
+    """The engine's copier (every source gathered, then scattered) for
+    a cache's tests: each call's id arrays go to `raw` as they came,
+    and its real pairs — the pad rows, a scratch page onto itself,
+    dropped — to `recorded`. A call never names a destination twice."""
 
     def copy_fn(pools, src, dst):
-        recorded.append((np.asarray(src), np.asarray(dst)))
-        out = []
-        for k, v in pools:
-            out.append((k.at[dst].set(k[src]), v.at[dst].set(v[src])))
-        return out
+        assert isinstance(src, np.ndarray) and isinstance(dst, np.ndarray)
+        raw.append((src.copy(), dst.copy()))
+        real = src != dst
+        assert len(set(dst[real])) == real.sum()
+        recorded.append((src[real], dst[real]))
+        return [tuple(p.at[dst].set(p[src]) for p in layer)
+                for layer in pools]
 
+    return copy_fn
+
+
+def make_cache(num_slots=4, max_seq=128, num_pages=None, copies=None,
+               data_size=1, kv_quant=None):
+    cfg = get_model_config("tiny-gemma", max_seq_len=max_seq)
+    recorded, raw = [], []
     kv = PagedKVCache(cfg, num_slots, max_seq, jnp.float32,
                       page_size=PS, num_pages=num_pages,
-                      copy_pages_fn=copy_fn, data_size=data_size)
+                      copy_pages_fn=recording_copier(recorded, raw),
+                      data_size=data_size, kv_quant=kv_quant)
     if copies is not None:
         copies.extend([recorded])  # alias for inspection
-    kv._recorded_copies = recorded
+    kv._recorded_copies = recorded   # one entry a program of the copier
+    kv._recorded_raw = raw
     return kv
+
+
+def issued(kv):
+    """The copier's calls so far, whatever was pending issued first."""
+    kv.combined_pools()
+    return kv._recorded_copies
 
 
 class TestAllocator:
@@ -70,7 +88,7 @@ class TestAllocator:
         # aliasing added ZERO new pages (pure refcount)
         assert kv.pages_in_use() == before
         assert kv._slots["b"].pages == kv._slots["a"].pages[:3]
-        assert not kv._recorded_copies
+        assert not issued(kv)
 
     def test_alias_span_copies_partial_boundary(self):
         kv = make_cache()
@@ -82,7 +100,7 @@ class TestAllocator:
         assert kv._slots["b"].pages[:2] == kv._slots["a"].pages[:2]
         # boundary page is a COPY, not an alias
         assert kv._slots["b"].pages[2] != kv._slots["a"].pages[2]
-        assert len(kv._recorded_copies) == 1
+        assert len(issued(kv)) == 1
 
     def test_cow_on_write_into_shared_page(self):
         kv = make_cache()
@@ -200,6 +218,254 @@ class TestPageLoans:
         assert loan[0] in kv._free_by_replica[0]
 
 
+def mark_pages(kv):
+    """Give every page of every pool bytes of its own: page p holds p
+    in a layer's first pool and -p in its second, and p + 0.5 / p +
+    0.25 in a quantized cache's scale pools (int8 payload: p < 128)."""
+
+    def fill(pool, sign, plus):
+        p = jnp.arange(pool.shape[0]).reshape(
+            (-1,) + (1,) * (pool.ndim - 1))
+        return jnp.broadcast_to(sign * p + plus, pool.shape).astype(
+            pool.dtype)
+
+    kv.pools = [(fill(k, 1, 0), fill(v, -1, 0)) for k, v in kv.pools]
+    if kv.scales is not None:
+        kv.scales = [(fill(k, 1, 0.5), fill(v, 1, 0.25))
+                     for k, v in kv.scales]
+
+
+def page_origins(kv):
+    """Which page's first bytes each page holds now ([P] ints), read
+    from a layer's first pool once every pool of every layer — scale
+    pools too — has been seen to agree."""
+    kv.combined_pools()
+    origin = np.asarray(kv.pools[0][0]).reshape(kv.num_pages, -1)[:, 0]
+    origin = origin.astype(np.int64)
+    for k, v in kv.pools:
+        for pool, sign in ((k, 1), (v, -1)):
+            flat = np.asarray(pool).reshape(kv.num_pages, -1)
+            assert (flat == sign * origin[:, None]).all()
+    for k, v in kv.scales or ():
+        for pool, plus in ((k, 0.5), (v, 0.25)):
+            flat = np.asarray(pool).reshape(kv.num_pages, -1)
+            assert (flat == origin[:, None] + plus).all()
+    return origin
+
+
+# Thirty-one pairs that touch nothing the cases name: with one pair
+# before them they fill the widest call, so the pair after opens the
+# next.
+FILLERS = [([10 + i], [60 + i]) for i in range(31)]
+
+# Each a script of _run_page_copy's arguments ("flush": the pools are
+# taken in between) and the programs it must go out as.
+QUEUE_CASES = {
+    # a pending destination becomes a source: 3 ends with 1's bytes
+    "chain": ([([1], [2]), ([2], [3])], 1),
+    "chain_across_calls": ([([1], [2])] + FILLERS + [([2], [3])], 2),
+    # a destination queued twice keeps the last pair, and whoever
+    # copied from it in between keeps what it saw
+    "destination_twice": ([([1], [2]), ([3], [2])], 1),
+    "chain_past_a_dropped_pair":
+        ([([1], [2]), ([2], [4]), ([3], [2])], 1),
+    "there_and_back": ([([1], [2]), ([2], [1])], 1),
+    # a source freed, handed out again and made a destination while
+    # its copy is pending: the earlier pair read it first
+    "freed_source_same_call": ([([5], [6]), ([7], [5])], 1),
+    "freed_source_across_calls":
+        ([([5], [6])] + FILLERS + [([7], [5])], 2),
+    "two_pages_a_copy": ([([1, 2], [3, 4]), ([3, 4], [5, 6])], 1),
+    "taken_in_between":
+        ([([1], [2]), "flush", ([2], [3]), ([4], [2])], 2),
+}
+
+POOL_KINDS = {
+    "bf16": {},
+    "int8_scales": {"kv_quant": "int8"},
+    "two_replicas": {"data_size": 2},
+}
+
+
+class TestCopyQueue:
+    """ISSUE 38: a page copy is a pair of host integers on the cache
+    until `combined_pools()` — the one way the pool tree leaves it —
+    issues everything pending as one call of the copier."""
+
+    @staticmethod
+    def shared_setup(kv):
+        """a: three full pages; b aliases all three (so a's are
+        shared); c is empty."""
+        for name in ("a", "b", "c"):
+            kv.acquire(name)
+        kv.ensure_capacity("a", 48, write_from=0)
+        kv.commit("a", list(range(48)))
+        assert kv.alias_span("a", "b", 0, 48) == (3, 0)
+
+    # What queues one copy, by cause -> (src page, dst page).
+
+    def _cow(kv):
+        shared = kv._slots["b"].pages[2]
+        kv.ensure_capacity("b", 80, write_from=40)
+        return shared, kv._slots["b"].pages[2]
+
+    def _share(kv):
+        kv.alias_span("a", "c", 0, 40)
+        return kv._slots["a"].pages[2], kv._slots["c"].pages[2]
+
+    def _alias(kv):
+        kv.ensure_capacity("c", 8, write_from=0)
+        kv.commit("c", list(range(8)))
+        kv.adopt_span("c", kv._slots["a"].pages, 8, 48)
+        return kv._slots["a"].pages[0], kv._slots["c"].pages[0]
+
+    ONE_COPY = {"cow": _cow, "share": _share, "alias": _alias}
+
+    @pytest.mark.parametrize("cause", sorted(ONE_COPY))
+    def test_a_copy_waits_for_the_pools(self, cause):
+        kv = make_cache()
+        self.shared_setup(kv)
+        mark_pages(kv)
+        src, dst = self.ONE_COPY[cause](kv)
+        assert src != dst
+        # queued: host integers, no program, the pools as they were
+        assert kv._pending == [(src, dst, cause)]
+        assert not kv._recorded_copies and kv.page_copy_programs == 0
+        assert kv.page_copies[cause] == 1
+        assert page_origins(kv)[dst] == src       # taking them issues it
+        assert kv._pending == [] and kv.page_copy_programs == 1
+        ((s, d),) = kv._recorded_copies
+        assert (list(s), list(d)) == ([src], [dst])
+        kv.combined_pools()                       # nothing left to issue
+        assert len(kv._recorded_copies) == 1
+
+    def test_what_several_operations_queued_goes_out_as_one_program(self):
+        kv = make_cache(num_slots=5)
+        self.shared_setup(kv)
+        pairs = [self.ONE_COPY["share"](kv), self.ONE_COPY["cow"](kv)]
+        kv.acquire("d")
+        kv.alias_span("a", "d", 0, 40)
+        pairs.append((kv._slots["a"].pages[2], kv._slots["d"].pages[2]))
+        assert kv._pending == [
+            pair + (cause,)
+            for pair, cause in zip(pairs, ("share", "cow", "share"))]
+        assert not kv._recorded_copies
+        ((s, d),) = issued(kv)
+        assert list(zip(s, d)) == pairs
+        assert kv.describe() == {
+            "pages_allocated": kv.pages_allocated,
+            "page_copies": 3,
+            "page_copies_by_cause": {"alias": 0, "share": 2, "cow": 1},
+            "page_copy_programs": 1,
+            "copy_widths": [8, 32],
+        }
+
+    @pytest.mark.parametrize("pools", sorted(POOL_KINDS))
+    @pytest.mark.parametrize("case", sorted(QUEUE_CASES))
+    def test_the_bytes_equal_what_immediate_copies_give(self, case,
+                                                        pools):
+        from theroundtaible_tpu.engine.kv_quant import KVQuantSpec
+        kw = dict(POOL_KINDS[pools])
+        if "kv_quant" in kw:
+            kw["kv_quant"] = KVQuantSpec(bits=8)
+        kv = make_cache(num_pages=100, **kw)
+        mark_pages(kv)
+        script, programs = QUEUE_CASES[case]
+        want = np.arange(kv.num_pages)
+        for step in script:
+            if step == "flush":
+                kv.combined_pools()
+                continue
+            src, dst = step
+            kv._run_page_copy(src, dst, "share")
+            for a, b in zip(src, dst):     # the copy made on the spot
+                want[b] = want[a]
+        assert not (want == np.arange(kv.num_pages)).all() \
+            or case == "there_and_back"
+        np.testing.assert_array_equal(page_origins(kv), want)
+        assert kv.page_copy_programs == programs
+        assert kv.page_copies["share"] == sum(
+            len(step[0]) for step in script if step != "flush")
+        # pad rows: replica 0's scratch page onto itself, whatever the
+        # replicas, in numpy to the program's door
+        for src, dst in kv._recorded_raw:
+            assert src.dtype == dst.dtype == np.int32
+            pad = src == dst
+            assert (src[pad] == kv.scratch_page(0)).all() \
+                or case == "there_and_back"    # 1 onto itself: its own
+
+    @pytest.mark.parametrize("pairs,widths", [
+        (1, [8]), (8, [8]), (9, [32]), (32, [32]), (33, [32, 8]),
+        (70, [32, 32, 8])])
+    def test_the_smallest_width_that_holds_and_chunks_beyond(self, pairs,
+                                                             widths):
+        kv = make_cache(num_pages=200)
+        mark_pages(kv)
+        src = list(range(1, pairs + 1))
+        dst = list(range(101, pairs + 101))
+        for i in range(0, pairs, 2):          # one or two pages a copy
+            kv._run_page_copy(src[i:i + 2], dst[i:i + 2], "alias")
+        origin = page_origins(kv)
+        assert list(origin[dst]) == src
+        assert [len(s) for s, _ in kv._recorded_raw] == widths
+        assert kv.page_copy_programs == len(widths)
+        # queue order is kept across the calls
+        assert [int(x) for s, _ in kv._recorded_copies for x in s] == src
+
+    def test_a_failed_join_leaves_a_pair_that_harms_nothing(self):
+        """A join that fails after its span was aliased in
+        (scheduler._alias_due) releases the follower with the boundary
+        page's copy pending; the page is handed out again and made the
+        destination of another copy. The last pair wins."""
+        kv = make_cache(num_slots=5, num_pages=9)    # eight usable pages
+        for name in ("a", "b", "c", "d", "e"):
+            kv.acquire(name)
+        kv.ensure_capacity("a", 40, write_from=0)
+        kv.commit("a", list(range(40)))
+        mark_pages(kv)
+        kv.alias_span("a", "b", 0, 40)
+        stale = kv._slots["b"].pages[2]
+        kv.release("b")                              # the join failed
+        assert kv._pending == [(kv._slots["a"].pages[2], stale, "share")]
+        kv.ensure_capacity("d", 40, write_from=0)
+        kv.commit("d", list(range(100, 140)))
+        kv.ensure_capacity("e", 16, write_from=0)    # the free list's
+        kv.alias_span("d", "c", 0, 40)               # head is `stale`
+        assert kv._slots["c"].pages[2] == stale
+        src = kv._slots["d"].pages[2]
+        assert page_origins(kv)[stale] == src
+        ((s, d),) = kv._recorded_copies
+        assert (list(s), list(d)) == ([src], [stale])
+        assert kv.page_copies["share"] == 2 and kv.page_copy_programs == 1
+
+    @pytest.mark.parametrize("how", ["flush", "revive"])
+    def test_dropping_the_pools_drops_what_is_pending(self, how):
+        kv = make_cache()
+        self.shared_setup(kv)
+        self.ONE_COPY["share"](kv)
+        assert kv._pending
+        if how == "flush":
+            assert kv.flush() == 3
+        else:
+            for layer in kv.pools:
+                for pool in layer:
+                    pool.delete()        # a failed donated dispatch
+            assert kv.revive_if_dead()
+        assert kv._pending == []
+        kv.combined_pools()
+        assert not kv._recorded_copies and kv.page_copy_programs == 0
+        assert kv.page_copies["share"] == 1          # it was queued
+
+    def test_warm_copier_runs_every_width_and_counts_nothing(self):
+        kv = make_cache()
+        self.shared_setup(kv)
+        self.ONE_COPY["cow"](kv)
+        kv.warm_copier()                 # issues what was pending first
+        assert [len(s) for s, _ in kv._recorded_raw] == [8, 8, 8, 32, 32]
+        assert kv.page_copy_programs == 1
+        assert all(len(s) == 0 for s, _ in kv._recorded_copies[1:])
+
+
 class TestPagedEngineParity:
     """The paged engine must produce byte-identical greedy output to the
     contiguous engine — same model, same seed, every serving feature."""
@@ -219,6 +485,34 @@ class TestPagedEngineParity:
         p = "the knights debate the session store design at length"
         assert (paged.generate(p, slot_name="a", max_new_tokens=8)
                 == dense.generate(p, slot_name="a", max_new_tokens=8))
+
+    def test_warmup_compiles_every_width_of_the_copier(self,
+                                                      monkeypatch):
+        """ISSUE 38: a flush pads to a width of paging.COPY_WIDTHS, and
+        warmup() has compiled each — so under
+        ROUNDTABLE_RECOMPILE_STRICT=1 neither a short flush, a long one
+        nor one in chunks compiles once the engine serves."""
+        from theroundtaible_tpu.engine import compile_watch
+        from theroundtaible_tpu.engine.paging import COPY_WIDTHS
+        paged = InferenceEngine(
+            get_model_config("tiny-gemma", max_seq_len=256), num_slots=4,
+            kv_layout="paged", page_size=32, num_pages=64,
+            sampling=SamplingParams(temperature=0.0, max_new_tokens=8))
+        paged.warmup(max_prompt_tokens=32, batch_sizes=(1,))
+        monkeypatch.setenv("ROUNDTABLE_RECOMPILE_STRICT", "1")
+        before = compile_watch.steady_state_compiles()
+        kv = paged.kv
+        free = kv._free_by_replica[0]
+        assert len(free) > COPY_WIDTHS[-1] + 2
+        for pairs in (1, COPY_WIDTHS[0] + 1, COPY_WIDTHS[-1] + 2):
+            # free pages onto free pages: bytes nothing reads
+            kv._run_page_copy(free[:pairs], free[1:pairs + 1], "cow")
+            kv.combined_pools()
+        assert kv.page_copy_programs >= 4
+        assert compile_watch.steady_state_compiles() == before, [
+            e.get("label") for e in compile_watch.history()[-6:]]
+        assert {e["label"] for e in compile_watch.history()} >= {
+            f"page_copy[w={w}]" for w in COPY_WIDTHS}
 
     def test_multiturn_delta_prefill_parity(self):
         paged, dense = self._engines()
@@ -449,13 +743,13 @@ class TestPerReplicaPools:
         assert kv.pages_in_use() == in_use
         # b is on the OTHER replica: same span arrives as page COPIES
         # into b's own range — distinct ids, b's replica, one dispatch
-        n_copies_before = len(kv._recorded_copies)
+        n_copies_before = len(issued(kv))
         kv.alias_span("a", "b", 0, 2 * PS)
         b_pages = kv._slots["b"].pages
         assert len(b_pages) == 2
         assert not set(b_pages) & set(kv._slots["a"].pages)
         assert all(p // kv._per_replica == 1 for p in b_pages)
-        assert len(kv._recorded_copies) == n_copies_before + 1
+        assert len(issued(kv)) == n_copies_before + 1
         src, dst = kv._recorded_copies[-1]
         assert list(src) == kv._slots["a"].pages[:2]
         assert list(dst) == b_pages

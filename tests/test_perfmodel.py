@@ -271,6 +271,11 @@ class TestStatusPerfRender:
             'phase="dispatch",replica="r1"} 0.5\n'
             'roundtable_sched_starved_seconds_total{engine="knight",'
             'phase="sync"} 0\n'
+            'roundtable_page_copies_total{cause="share",'
+            'engine="knight"} 60\n'
+            'roundtable_page_copies_total{cause="alias",'
+            'engine="knight"} 90\n'
+            'roundtable_page_copy_programs_total{engine="knight"} 20\n'
             'roundtable_kv_pages_in_use{engine="knight"} 12\n'
             'roundtable_session_kv_bytes{engine="knight",'
             'session="s0"} 4194304\n')
@@ -290,6 +295,10 @@ class TestStatusPerfRender:
         assert "starved_s" in out
         assert "dispatch=3.000 build=1.250" in out and "sync=" not in out
         assert "bw_util" not in out and "mfu" not in out
+        # what each program of the page cache's copier gathered
+        assert "Page copies" in out and "copies/program" in out
+        assert "knight 150 20 7.5 alias=90 share=60" in " ".join(
+            out.split())
         assert "Compile observatory" in out
         assert "Memory ledger" in out
         assert "roundtable_kv_pages_in_use" in out
@@ -298,6 +307,7 @@ class TestStatusPerfRender:
 
     @pytest.mark.parametrize("says", [
         "seconds the scheduler left the device unfed by loop phase",
+        "page copies a program of the page cache's copier",
         "the scheduler's loop and starved seconds by phase"])
     def test_the_help_text_follows_the_columns(self, says, capsys):
         from theroundtaible_tpu.cli import build_parser

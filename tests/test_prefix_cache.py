@@ -29,6 +29,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 import numpy as np
 
+from test_paging import issued, recording_copier
 from theroundtaible_tpu.engine import deadlines, faults
 from theroundtaible_tpu.engine.engine import InferenceEngine
 from theroundtaible_tpu.engine.kvcache import scoped_slot
@@ -61,17 +62,10 @@ def make_cache(num_slots=4, max_seq=128, num_pages=None, data_size=1,
                max_pages=None):
     cfg = get_model_config("tiny-gemma", max_seq_len=max_seq)
     recorded = []
-
-    def copy_fn(pools, src, dst):
-        recorded.append((np.asarray(src), np.asarray(dst)))
-        out = []
-        for k, v in pools:
-            out.append((k.at[dst].set(k[src]), v.at[dst].set(v[src])))
-        return out
-
     kv = PagedKVCache(cfg, num_slots, max_seq, jnp.float32,
                       page_size=16, num_pages=num_pages,
-                      copy_pages_fn=copy_fn, data_size=data_size)
+                      copy_pages_fn=recording_copier(recorded, []),
+                      data_size=data_size)
     kv._recorded_copies = recorded
     cache = PrefixCache(kv, engine="unit", max_pages=max_pages)
     kv.prefix_cache = cache
@@ -168,7 +162,7 @@ class TestRadixIndex:
         assert kv._slots["b"].tokens == tokens[:32]
         assert cache.hits == 1 and cache.reused_tokens == 32
         # pure aliasing — no device copies at page-aligned lo=0
-        assert not kv._recorded_copies
+        assert not issued(kv)
 
     def test_attach_respects_feed_one_token_rule(self):
         kv, cache = make_cache()
@@ -199,7 +193,7 @@ class TestRadixIndex:
         fresh = kv.cow_page("b", 0)
         assert fresh != shared and kv._slots["b"].pages[0] == fresh
         assert kv._slots["a"].pages[0] == shared
-        assert len(kv._recorded_copies) == 1
+        assert len(issued(kv)) == 1
         # index-only share: a releases; its remaining index-shared page
         # goes exclusive via forget, no copy, same id
         kv.release("b")
@@ -207,7 +201,7 @@ class TestRadixIndex:
         assert kv.refcount(p0) == 2              # a + index
         assert kv.cow_page("a", 0) == p0
         assert not cache.holds_page(p0)
-        assert len(kv._recorded_copies) == 1     # no new dispatch
+        assert len(issued(kv)) == 1              # no new copy
         # exclusive: no-op
         assert kv.cow_page("a", 0) == p0
 

@@ -155,7 +155,9 @@ def build_parser():
                     help="Render live performance attribution: roofline "
                          "table (ceiling, decode rate, seconds the "
                          "scheduler left the device unfed by loop "
-                         "phase), compile observatory, memory ledger, "
+                         "phase), page copies a program of the page "
+                         "cache's copier, compile observatory, memory "
+                         "ledger, "
                          "span-tree overhead breakdown")
     st.add_argument("--kv", action="store_true",
                     help="Render the KV-tier view: memory ledger with "

@@ -741,13 +741,16 @@ def _by_engine(series: dict[str, float],
 
 
 STARVED_SERIES = "roundtable_sched_starved_seconds_total"
+PAGE_COPIES_SERIES = "roundtable_page_copies_total"
+PAGE_COPY_PROGRAMS_SERIES = "roundtable_page_copy_programs_total"
 
 
 def perf_status(session) -> int:
     """`roundtable status --perf` — live performance attribution from
     the unified registry (ISSUE 6): the per-engine roofline table
     (ceiling, decode rate, and the seconds the scheduler's loop left
-    the device unfed, by phase), the compile observatory's history
+    the device unfed, by phase), the page copies each program of the
+    page cache's copier gathered, the compile observatory's history
     and steady-state sentinel state, the memory ledger, and the
     span-tree overhead breakdown."""
     from ..utils import perfmodel, telemetry
@@ -795,6 +798,33 @@ def perf_status(session) -> int:
                 f"    {eng:<18}{fmt(val('roundtable_decode_ceiling_tps'))}"
                 f"{fmt(val('roundtable_decode_tps'))}"
                 f"  {by_phase or '-'}"))
+
+    # --- page copies (ISSUE 38) ---
+    # A copy waits on the page cache for the next program that takes
+    # the pools; copies a program says how much each flush gathered.
+    copies: dict[str, dict[str, float]] = {}
+    programs: dict[str, float] = {}
+    for key, v in perf.items():
+        name, lb = key.split("{", 1)[0], _labels(key)
+        eng = lb.get("engine", "?")
+        if name == PAGE_COPIES_SERIES:
+            by_cause = copies.setdefault(eng, {})
+            cause = lb.get("cause", "?")
+            by_cause[cause] = by_cause.get(cause, 0.0) + v
+        elif name == PAGE_COPY_PROGRAMS_SERIES:
+            programs[eng] = programs.get(eng, 0.0) + v
+    if copies:
+        print(style.bold("\n  Page copies (per engine):"))
+        print(style.dim("    engine              copies  programs"
+                        "  copies/program  by cause"))
+        for eng in sorted(copies):
+            n, progs = sum(copies[eng].values()), programs.get(eng, 0.0)
+            per = f"{n / progs:14.1f}" if progs else "             -"
+            by_cause = " ".join(
+                f"{cause}={v:g}" for cause, v in sorted(
+                    copies[eng].items(), key=lambda kv: -kv[1]))
+            print(style.dim(f"    {eng:<18}{n:8g}{progs:10g}  {per}"
+                            f"  {by_cause}"))
 
     # --- compile observatory ---
     from ..engine import compile_watch

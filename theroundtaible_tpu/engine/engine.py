@@ -317,15 +317,15 @@ class InferenceEngine:
 
             @partial(jax.jit, donate_argnums=(0,))
             def copy_pages(pools, src_ids, dst_ids):
-                # Whole-page copies (copy-on-write + alias boundaries).
-                # Callers pad the id lists to a fixed width so this
-                # compiles exactly one shape (pad rows copy the scratch
-                # page onto itself — identical bytes, any scatter order).
+                # Whole-page copies (copy-on-write + alias boundaries):
+                # every source gathered, then scattered. The cache
+                # queues its copies and issues them here as numpy ids
+                # padded to one of paging.COPY_WIDTHS, so this compiles
+                # those shapes and no other (pad rows copy a scratch
+                # page onto itself — identical bytes, any scatter
+                # order); kv.warm_copier compiles each in warmup().
                 return [tuple(p.at[dst_ids].set(p[src_ids]) for p in layer)
                         for layer in pools]
-
-            from .paging import make_padded_copier
-            copy_pages_padded = make_padded_copier(copy_pages)
 
             # Default pool HALVES the contiguous HBM budget — and since
             # the page axis shards over "data", that is the TOTAL across
@@ -342,7 +342,7 @@ class InferenceEngine:
             self.kv = PagedKVCache(
                 model_cfg, num_slots, self.max_seq_len, dtype,
                 pool_sharding, page_size=page_size, num_pages=num_pages,
-                copy_pages_fn=copy_pages_padded, data_size=data_size,
+                copy_pages_fn=copy_pages, data_size=data_size,
                 kv_quant=self.kv_quant_spec)
         else:
             cache_sharding = None
@@ -1642,6 +1642,10 @@ class InferenceEngine:
         # scheduler joins compile nothing in steady state.
         if self.ragged_enabled:
             self._warm_ragged()
+        # Every width of the page copier (ISSUE 38): the queue's first
+        # long flush must not be the one that compiles it.
+        if self.kv_layout == "paged":
+            self.kv.warm_copier()
         # Warm the offload tier's fetch/write programs (ONE fixed shape
         # each, ISSUE 7): a first idle-session spill/restore in steady
         # state must compile nothing under ROUNDTABLE_RECOMPILE_STRICT.
@@ -3384,6 +3388,9 @@ class InferenceEngine:
             info["kv_hbm_bytes"] = self.kv.hbm_bytes()
             info["paged_decode"] = ("pool-direct" if self.paged_direct
                                     else "gather-view")
+            # ISSUE 38: pages handed out, page copies queued and the
+            # programs that issued them.
+            info["paging"] = self.kv.describe()
             # ISSUE 7: the cross-session sharing subsystems' state.
             if self.prefix_cache is not None:
                 info["prefix_cache"] = self.prefix_cache.describe()

@@ -45,8 +45,8 @@ from ..utils import telemetry
 from .kvcache import session_of
 
 # Pages moved per fetch/write dispatch. Spills are rare (idle-session
-# boundaries, not the serving hot path); 8 keeps padding waste small and
-# matches paging.make_padded_copier's chunking rationale.
+# boundaries, not the serving hot path); 8 keeps padding waste small
+# (the narrowest of paging.COPY_WIDTHS, for the same reason).
 WIDTH = 8
 
 

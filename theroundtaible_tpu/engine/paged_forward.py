@@ -292,8 +292,8 @@ def forward_ragged(
     device-copied pool->pool per layer BEFORE the K/V scatter — the
     pre-COW that gives each tree path's private frontier page the
     committed cells its causal reads need (pads are scratch->scratch
-    self-copies; scales ride with their pages, the _run_page_copy
-    contract). With this, a token TREE is just more sequences of the
+    self-copies; scales ride with their pages, as in the page cache's
+    own copier). With this, a token TREE is just more sequences of the
     same flat buffer: per-path tables keep sibling writes apart, the
     ordinary causal mask is exact along every root-to-leaf path, and
     no kernel changes at all."""

@@ -58,35 +58,54 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import telemetry
+from . import compile_watch
 from .models.common import ModelConfig
 
 
-def make_padded_copier(copy_fn: Callable, width: int = 8) -> Callable:
-    """Wrap a jit'd whole-page copy `copy_fn(pools, src_ids, dst_ids)`
-    so it compiles exactly ONE shape: copies run in fixed-width chunks,
-    short chunks zero-padded (pad rows copy the scratch page onto
-    itself — identical bytes, any scatter order). COW/boundary copies
-    are typically 1-2 pages, so width=8 keeps padding waste small and
-    bounds per-dispatch traffic (vs padding to pages_per_seq, which
-    would move a whole sequence's worth of pages for a 1-page copy).
-    Shared by both engines' paged layouts — the driver is layout-
-    agnostic, only the jit'd copy differs."""
+# Widths of the page copier's id arrays (ISSUE 38): a flush of the
+# pending copies pads to the smallest that holds them and goes out in
+# chunks of the largest beyond it, so the engine's jitted copier
+# compiles these shapes and no other — every one inside
+# `engine.warmup()` (`PagedKVCache.warm_copier`). A copy is one or two
+# pages and a round's start queues a few dozen; pad rows move a scratch
+# page's bytes on the device, so the ladder stays short of a
+# sequence's worth of pages.
+COPY_WIDTHS = (8, 32)
 
-    def padded(pools, src_ids, dst_ids):
-        n = int(src_ids.shape[0])
-        for start in range(0, n, width):
-            s_ids = src_ids[start:start + width]
-            d_ids = dst_ids[start:start + width]
-            pad = width - int(s_ids.shape[0])
-            if pad:
-                s_ids = jnp.concatenate(
-                    [s_ids, jnp.zeros((pad,), jnp.int32)])
-                d_ids = jnp.concatenate(
-                    [d_ids, jnp.zeros((pad,), jnp.int32)])
-            pools = copy_fn(pools, s_ids, d_ids)
-        return pools
+# Why a page is copied: a prefix-cache attach's boundary page
+# (adopt_span), alias_span's between two slots, a shared page about to
+# be written (cow_page).
+COPY_CAUSES = ("alias", "share", "cow")
 
-    return padded
+
+def plan_copy_calls(pairs: list[tuple[int, int]],
+                    width: int) -> list[dict[int, int]]:
+    """Queued (src, dst) page copies, in queue order, as calls of a
+    copier that GATHERS every source before it scatters (inside one
+    call all reads precede all writes, and a repeated destination has
+    no defined winner). -> one {dst: src} a call, at most `width`
+    destinations each, whose calls in order leave every page with the
+    bytes the copies made one by one would have left.
+
+    Inside a call a source names the page as it was BEFORE the call: a
+    pair whose source an earlier pair of the call wrote (A→B, then
+    B→C) reads that pair's own source (C takes A's bytes), and a
+    destination queued twice keeps the last pair (the earlier never
+    lands; whoever copied from it in between was resolved past it). A
+    full call closes and the next starts with nothing resolved, so
+    queue order holds across calls: a source freed, handed out again
+    and overwritten by a later pair was read in the same or an earlier
+    call."""
+    calls: list[dict[int, int]] = []
+    origin: dict[int, int] = {}
+    for src, dst in pairs:
+        if dst not in origin and len(origin) == width:
+            calls.append(origin)
+            origin = {}
+        origin[dst] = origin.get(src, src)
+    if origin:
+        calls.append(origin)
+    return calls
 
 
 @dataclass
@@ -103,8 +122,16 @@ class PagedKVCache:
     """Page-pool KV cache with the same slot interface as KVCache.
 
     `copy_pages_fn(pools, src_ids, dst_ids)` is the engine-provided jit'd
-    program that copies whole pages (used for copy-on-write); it is the
-    only device operation the allocator itself triggers.
+    program that copies whole pages; it is the only device operation the
+    allocator itself triggers, and it triggers it in ONE place (ISSUE
+    38). A page copy — a copy-on-write, an alias's or an attach's
+    boundary page — is a pair of host integers on a pending list
+    (`_run_page_copy`); `combined_pools()`, the one way the pool tree
+    leaves the cache, issues everything pending as one call of the
+    copier (a few beyond `COPY_WIDTHS[-1]` pairs) and installs the
+    result before it hands the tree out. So no program that reads or
+    writes pages, and no fetch of the offload tier, sees a pool with a
+    copy outstanding, and no caller knows of the queue.
 
     Two page shapes, both owned here. `pools[l]` is the tuple of one
     attention layer's pools, every one indexed by page id on its first
@@ -199,6 +226,13 @@ class PagedKVCache:
         self.scales = (self._make_scales(self.num_pages)
                        if kv_quant is not None else None)
         self._copy_pages_fn = copy_pages_fn
+        # Page copies queued and not yet issued: (src, dst, cause) in
+        # queue order.
+        self._pending: list[tuple[int, int, str]] = []
+        # Lifetime: pairs queued by cause, and calls of the copier —
+        # the quotient says how often the queue gathers anything.
+        self.page_copies = dict.fromkeys(COPY_CAUSES, 0)
+        self.page_copy_programs = 0
         self._slots: dict[str, PagedSlot] = {}
         # Replica r owns pages [r*per, (r+1)*per); the range's FIRST page
         # is that replica's scratch (never allocated, never aliased).
@@ -275,6 +309,14 @@ class PagedKVCache:
     # the old list — the kill-switch byte-identity hinges on that.
 
     def combined_pools(self) -> list:
+        """The pool tree, every queued page copy made: the ONE place
+        the tree leaves the cache (step programs, the scatter, the
+        offload tier's fetch and restore, the audits)."""
+        if self._pending:
+            self._issue_pending()
+        return self._combined()
+
+    def _combined(self) -> list:
         if self.scales is None:
             return self.pools
         return list(self.pools) + list(self.scales)
@@ -288,24 +330,90 @@ class PagedKVCache:
             self.scales = list(combined[n:])
 
     def _run_page_copy(self, src_ids, dst_ids, cause: str) -> None:
-        """Whole-page device copy through the engine's jit'd copier —
-        scale rows ride the same dispatch on quantized pools (a COW'd
-        or adopted page without its scales would dequantize garbage).
-        Nothing reads the copy back, so it does not feed the loop
-        clock: armed, its host time is a `page_copy` span (ISSUE 37)
-        under whatever the calling thread has open, with the pages
-        copied and the `cause` — `alias` (a prefix-cache attach's
-        boundary page), `share` (alias_span's, between two slots) or
-        `cow` (a shared page about to be written)."""
+        """Queue whole-page copies src_ids[i] → dst_ids[i]: host
+        integers on the pending list, no program (ISSUE 38 — a copy
+        issued alone cost 3.3 ms of host time for microseconds of
+        bytes, 23 times a round). `combined_pools()` issues them. The
+        one writer of `page_copies` and its series; `cause` is one of
+        COPY_CAUSES."""
+        n = len(src_ids)
+        self._pending.extend((s, d, cause)
+                             for s, d in zip(src_ids, dst_ids))
+        self.page_copies[cause] += n
+        telemetry.inc("roundtable_page_copies_total", n,
+                      engine=self.cfg.name, cause=cause)
+
+    def _issue_pending(self) -> None:
+        """Issue the pending copies through the engine's jit'd copier
+        as `plan_copy_calls` lays them out — scale rows ride the same
+        call on quantized pools (a COW'd or adopted page without its
+        scales would dequantize garbage). The id arrays are numpy,
+        padded to a width of the ladder with a scratch page (pad rows
+        copy it onto itself — identical bytes, any scatter order), and
+        go to the program as they are. The list is dropped first: a
+        donated call that fails takes the pools with it
+        (`revive_if_dead`). Nothing reads the copies back, so the
+        flush does not feed the loop clock: armed, its host time is a
+        `page_copy` span (ISSUE 37) under whatever the calling thread
+        has open — `copies` queued, of them by cause, `pages` the
+        `programs` moved. The one writer of `page_copy_programs` and
+        its series."""
+        pending, self._pending = self._pending, []
         span = telemetry.NULL_SPAN
         if telemetry.ACTIVE:
-            span = telemetry.start_span("page_copy", pages=len(src_ids),
-                                        cause=cause)
-        out = self._copy_pages_fn(self.combined_pools(),
-                                  jnp.asarray(src_ids, jnp.int32),
-                                  jnp.asarray(dst_ids, jnp.int32))
-        self.set_combined(out)
+            causes = dict.fromkeys(COPY_CAUSES, 0)
+            for _src, _dst, cause in pending:
+                causes[cause] += 1
+            span = telemetry.start_span("page_copy", copies=len(pending),
+                                        **causes)
+        calls = plan_copy_calls([(s, d) for s, d, _cause in pending],
+                                COPY_WIDTHS[-1])
+        pools = self._combined()
+        for origin in calls:
+            n = len(origin)
+            width = next(w for w in COPY_WIDTHS if w >= n)
+            ids = np.full((2, width), self._scratch[0], np.int32)
+            ids[0, :n] = list(origin.values())
+            ids[1, :n] = list(origin)
+            pools = self._copy(pools, ids[0], ids[1])
+        self.set_combined(pools)
+        self.page_copy_programs += len(calls)
+        telemetry.inc("roundtable_page_copy_programs_total", len(calls),
+                      engine=self.cfg.name)
+        if span is not telemetry.NULL_SPAN:
+            span.attrs.update(pages=sum(len(c) for c in calls),
+                              programs=len(calls))
         span.end()
+
+    def warm_copier(self) -> None:
+        """Compile the copier at every width of the ladder (scratch
+        onto itself), twice each like every program that takes the
+        pools donated — `engine.warmup()` calls this, so no flush
+        compiles once the engine serves. Counted nowhere."""
+        pools = self.combined_pools()
+        for width in COPY_WIDTHS:
+            pad = np.full((width,), self._scratch[0], np.int32)
+            for _ in range(2):
+                pools = self._copy(pools, pad, pad)
+        self.set_combined(pools)
+
+    def _copy(self, pools: list, src: np.ndarray, dst: np.ndarray) -> list:
+        """One call of the copier, a compile of it named for what it
+        is (and not for the step program whose dispatch flushed)."""
+        with compile_watch.label(f"page_copy[w={len(src)}]",
+                                 engine=self.cfg.name):
+            return self._copy_pages_fn(pools, src, dst)
+
+    def describe(self) -> dict:
+        """engine.describe()["paging"] (keys bound in
+        telemetry.SURFACE_BINDINGS["engine_paging"])."""
+        return {
+            "pages_allocated": self.pages_allocated,
+            "page_copies": sum(self.page_copies.values()),
+            "page_copies_by_cause": dict(self.page_copies),
+            "page_copy_programs": self.page_copy_programs,
+            "copy_widths": list(COPY_WIDTHS),
+        }
 
     def slot_names(self) -> list[str]:
         return list(self._slots)
@@ -405,6 +513,7 @@ class PagedKVCache:
             self.scales = self._make_scales(self.num_pages)
         self._slots.clear()
         self._refs.clear()
+        self._pending = []      # their pages' bytes are gone too
         per = self._per_replica
         self._free_by_replica = [
             list(range(r * per + 1, (r + 1) * per))
@@ -463,6 +572,8 @@ class PagedKVCache:
             self.release(name)
         if self.prefix_cache is not None:
             self.prefix_cache.drop_all()
+        # Every destination of a pending copy has just been freed.
+        self._pending = []
         return len(names)
 
     def reset_slot(self, name: str) -> None:
@@ -709,10 +820,9 @@ class PagedKVCache:
         # ONE definition of the fork policy (cow_page): index-only
         # shares go exclusive by forgetting the index entry (no copy,
         # no alloc — under a full pool the COW alloc may be the page
-        # that doesn't exist), real shares device-copy into a fresh
-        # page. Write ranges are typically 0-1 shared pages (the attach
-        # frontier is page-aligned), so per-page dispatch costs nothing
-        # measurable.
+        # that doesn't exist), real shares copy into a fresh page.
+        # Write ranges are typically 0-1 shared pages (the attach
+        # frontier is page-aligned); each copy is queued, not issued.
         for j in range(write_from // self.page_size, len(state.pages)):
             if self._shared(state.pages[j]):
                 self.cow_page(name, j, pinned)
@@ -737,7 +847,7 @@ class PagedKVCache:
         # Aliasing requires both slots on the SAME data replica (an
         # aliased page cannot be resident in two replicas' pool shards);
         # cross-replica sharing degrades to whole-page device COPIES into
-        # dst's replica — still one dispatch, still skips the prefill.
+        # dst's replica — queued like every copy, still skips the prefill.
         same_replica = src.replica == dst.replica
         # dst keeps its own pages below lo; drop anything it holds beyond.
         self._trim_pages(dst, lo)

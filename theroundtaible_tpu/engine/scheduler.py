@@ -1748,8 +1748,9 @@ class SessionScheduler:
         """Alias deferred leader spans whose leader chunks have written
         the common span (kvcache.share_prefixes defer_span contract):
         laggards' tables take the leader's span pages (whole pages
-        alias, boundary pages device-copy — the same one-shape padded
-        copier admission aliasing uses) and the rows unblock, their
+        alias, boundary pages are copied: queued on the page cache and
+        issued with every other pending copy before the segment's
+        program takes the pools, ISSUE 38) and the rows unblock, their
         pending already trimmed to the post-span tail at admission.
         Armed, a request whose plans are due gets a `share` span in its
         own trace (ISSUE 37; the pass that finds nothing due builds

@@ -465,6 +465,7 @@ PERF_SERIES_PREFIXES = (
     "roundtable_decode_ceiling_tps", "roundtable_prefill_peak_tps",
     "roundtable_decode_tps",
     "roundtable_sched_starved_seconds",  # ISSUE 37: the feed bit
+    "roundtable_page_cop",  # ISSUE 38: page copies and their programs
     "roundtable_compile", "roundtable_steady_state",
     "roundtable_kv_", "roundtable_hbm_", "roundtable_session_kv_",
     "roundtable_prefix_",   # ISSUE 7: prefix-cache hit/miss/size series

@@ -1203,6 +1203,22 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "rows_multiplied": "roundtable_moe_rows_multiplied_total",
         "rows_dense": "roundtable_moe_rows_dense_total",
     },
+    # engine.describe()["paging"] (ISSUE 38): the page cache's own
+    # counts. A page copy is queued on the cache and issued with
+    # whatever else is pending before the next program that takes the
+    # pools (PagedKVCache._run_page_copy is the one writer of the
+    # copies and their series, _issue_pending of the programs and
+    # theirs); copies over programs says how much a flush gathers.
+    "engine_paging": {
+        "pages_allocated": "describe-only (pages handed out, lifetime; "
+                           "a `plan` span carries its admission's)",
+        "page_copies": "roundtable_page_copies_total (every cause)",
+        "page_copies_by_cause":
+            "roundtable_page_copies_total{cause=alias|share|cow}",
+        "page_copy_programs": "roundtable_page_copy_programs_total",
+        "copy_widths": "static (paging.COPY_WIDTHS, each compiled in "
+                       "warmup)",
+    },
     # engine.describe()["mla"] (ISSUE 31): latent pages — the second
     # page shape (engine/paging.py) — and the kernels that read them.
     # Static but for the positions read, which the scheduler's segment
