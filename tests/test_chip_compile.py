@@ -388,11 +388,12 @@ def test_hybrid_step_of_the_nemotron_cut_compiles(one_chip, monkeypatch,
 
 # (hidden, expert width, held, top-k, gated, activation) of the three
 # expert cells: Nemotron-3-Nano's share of a pair, A.X-K1's of sixteen,
-# Laguna-XS.2 whole.
+# Laguna-XS.2 whole, Mellum2-12B whole.
 EXPERT_WIDTHS = {
     "nemotron-3-nano-ep2": (2688, 1856, 64, 6, False, "relu2"),
     "a.x-k1-ep16": (7168, 2048, 12, 8, True, "silu"),
-    "laguna-xs.2": (2048, 512, 256, 8, True, "silu")}
+    "laguna-xs.2": (2048, 512, 256, 8, True, "silu"),
+    "mellum2-12b": (2304, 896, 64, 8, True, "silu")}
 
 
 @pytest.mark.parametrize("tokens", [16, 256, 1024])
@@ -696,10 +697,11 @@ def test_hybrid_step_of_the_axk1_cut_compiles(one_chip, monkeypatch,
 LAGUNA_CLASSES = {"full": (48, None), "sliding": (64, 512)}
 
 
-def _laguna_case(kernel: str, h: int, window, t: int, one_chip):
+def _laguna_case(kernel: str, h: int, window, t: int, one_chip,
+                 pool_pages: int = CELL_POOL, kh: int = 8):
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     i32 = jnp.int32
-    pool = s((CELL_POOL, PAGE, 8, D), jnp.bfloat16)
+    pool = s((pool_pages, PAGE, kh, D), jnp.bfloat16)
     kw = dict(sliding_window=window, interpret=False)
     if kernel == "paged_decode":
         return (functools.partial(pattn.paged_decode_attention, **kw),
@@ -743,34 +745,18 @@ def test_laguna_kernel_compiles_for_v5e(one_chip, kernel, t, layers):
     assert not copies, copies
 
 
-@pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
-def test_hybrid_step_of_the_laguna_cut_compiles(one_chip, monkeypatch,
-                                                program):
-    """One decode step, one ragged join and one prologue chunk of the
-    benchmark's Laguna-XS.2 cut at published widths, with all 256
-    experts of a layer held (the dense block, one sliding and one full
-    block with experts: both kernels' lowerings, the gate, both rotary
-    tables, the masked loop over 256): what the chip's compiler refuses
-    fails here, not there."""
+def _window_step_hlo(cfg, pool, program: str, one_chip, check,
+                     join_at: int = 900) -> str:
+    """The compiled text of one decode step, one ragged join (a 150-token
+    run at `join_at` beside a decode row) or one 1024-token prologue
+    chunk of `cfg` over `pool` a layer; `check(params)` sees the
+    parameter shapes first."""
     from theroundtaible_tpu.engine.models import hybrid
     from theroundtaible_tpu.engine.models.common import init_params
-    from theroundtaible_tpu.engine.models.registry import get_model_config
     from theroundtaible_tpu.engine.paged_forward import (
         forward_paged_hybrid, forward_ragged_hybrid)
     from theroundtaible_tpu.engine.serving_loop import (RaggedSeq,
                                                         build_ragged_batch)
-
-    monkeypatch.setattr(pattn, "_interpret", lambda: False)
-    monkeypatch.setattr(grouped, "_interpret", lambda: False)
-    whole = get_model_config("laguna-xs.2")
-    # blocks 0 (full, dense), 1 (sliding, experts), 4 (full, experts)
-    cfg = dataclasses.replace(
-        whole, num_layers=6,
-        layer_kinds=whole.layer_kinds[:4] + whole.layer_kinds[8:10],
-        attn_layers=whole.attn_layers[:2] + whole.attn_layers[4:5],
-        attn_impl="flash")
-    assert cfg.attention_classes == ((48, None, 2), (64, 512, 1))
-    assert cfg.experts_held == cfg.routed_experts == 256
 
     def placed(tree):
         return jax.tree_util.tree_map(
@@ -782,10 +768,8 @@ def test_hybrid_step_of_the_laguna_cut_compiles(one_chip, monkeypatch,
     params = placed(jax.eval_shape(
         lambda k: init_params(cfg, k, jnp.bfloat16),
         jax.random.PRNGKey(0)))
-    assert params["layers"][2]["q_proj"].shape == (2048, 64, D)
-    assert params["layers"][4]["g_proj"].shape == (2048, 48)
-    pool = s((CELL_POOL, PAGE, 8, D), jnp.bfloat16)
-    pools = [(pool, pool)] * 3
+    check(params)
+    pools = [(pool, pool)] * len(cfg.attention_layers)
     state = hybrid.zero_state(cfg, ROWS)
     if program == "decode":
         def step(params, pools, tokens, positions, table, valid, active):
@@ -810,7 +794,7 @@ def test_hybrid_step_of_the_laguna_cut_compiles(one_chip, monkeypatch,
     else:
         table = np.zeros((PAGES_PER_SEQ,), np.int32)
         b = build_ragged_batch(
-            [RaggedSeq([5] * 150, 900, table), RaggedSeq([7], 1300, table)],
+            [RaggedSeq([5] * 150, join_at, table), RaggedSeq([7], 1300, table)],
             t_budget=RAGGED_T, s_max=ROWS + 1,
             pages_per_seq=PAGES_PER_SEQ, scratch_page=0, pad_id=0,
             page_size=PAGE)
@@ -830,7 +814,116 @@ def test_hybrid_step_of_the_laguna_cut_compiles(one_chip, monkeypatch,
         hlo = _compile(step, params, pools, s((ROWS + 1,), i32),
                        s((ROWS + 1,), i32),
                        *[s(np.asarray(b[n]).shape, i32) for n in names])
+    return hlo
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
+def test_hybrid_step_of_the_laguna_cut_compiles(one_chip, monkeypatch,
+                                                program):
+    """One decode step, one ragged join and one prologue chunk of the
+    benchmark's Laguna-XS.2 cut at published widths, with all 256
+    experts of a layer held (the dense block, one sliding and one full
+    block with experts: both kernels' lowerings, the gate, both rotary
+    tables, the masked loop over 256): what the chip's compiler refuses
+    fails here, not there."""
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    whole = get_model_config("laguna-xs.2")
+    # blocks 0 (full, dense), 1 (sliding, experts), 4 (full, experts)
+    cfg = dataclasses.replace(
+        whole, num_layers=6,
+        layer_kinds=whole.layer_kinds[:4] + whole.layer_kinds[8:10],
+        attn_layers=whole.attn_layers[:2] + whole.attn_layers[4:5],
+        attn_impl="flash")
+    assert cfg.attention_classes == ((48, None, 2), (64, 512, 1))
+    assert cfg.experts_held == cfg.routed_experts == 256
+
+    def check(params):
+        assert params["layers"][2]["q_proj"].shape == (2048, 64, D)
+        assert params["layers"][4]["g_proj"].shape == (2048, 48)
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    hlo = _window_step_hlo(cfg, s((CELL_POOL, PAGE, 8, D), jnp.bfloat16),
+                           program, one_chip, check)
     assert hlo.count("tpu_custom_call") >= 3        # a kernel a block
+
+
+# --- window 1024 and none at 32 heads over 4 kv heads (ISSUE 40) ------------
+#
+# Mellum2-12B: every layer 32 query heads over FOUR kv heads of 128
+# (group 8 over a pool `[1024,128,4,128]`: no served model has had four),
+# three layers of four behind a 1024 window (8 pages and the one it
+# starts in). harness/kernel_cost.py finds attention by the pool among a
+# call's operands, so the operand is pinned as the cell holds it.
+
+MELLUM_POOL, MELLUM_KV, MELLUM_HEADS = 1024, 4, 32
+MELLUM_CLASSES = {"full": None, "sliding": 1024}
+
+
+@pytest.mark.parametrize("layers", list(MELLUM_CLASSES))
+@pytest.mark.parametrize("kernel,t", [
+    ("paged_decode", 1), ("ragged", 1024), ("ragged", 256),
+    ("paged_prefill", 1024), ("paged_prefill", 256)])
+def test_mellum_kernel_compiles_for_v5e(one_chip, kernel, t, layers):
+    """The decode walk, the ragged walk and the prefill kernel at both
+    layer classes of the new cell, on its pool `[1024,128,4,128]` as
+    the cell holds it: twice among the call's operands, uncopied (the
+    benchmark's `kernel.attn_busy_share` finds the calls by it)."""
+    import re
+    window = MELLUM_CLASSES[layers]
+    group = MELLUM_HEADS // MELLUM_KV
+    assert pattn.paged_decode_decline_reason(
+        PAGE, D, MELLUM_KV, group) is None
+    assert pattn.ragged_decline_reason(PAGE, D, MELLUM_KV, group) is None
+    fn, shapes = _laguna_case(kernel, MELLUM_HEADS, window, t, one_chip,
+                              MELLUM_POOL, MELLUM_KV)
+    hlo = _compile(fn, *shapes)
+    _assert_kernel(hlo)
+    call = next(line for line in hlo.splitlines()
+                if "tpu_custom_call" in line)
+    assert call.count(f"bf16[{MELLUM_POOL},{PAGE},{MELLUM_KV},{D}]") == 2
+    name = {"ragged": "ragged_paged"}.get(kernel, kernel) + "_attention"
+    assert f"%{name}" in hlo
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if re.search(
+                  rf"= \w+\[{MELLUM_POOL},{PAGE},[0-9,]+\]\S* "
+                  r"copy(-start)?\(", line)]   # (q is [1024, 32, 128])
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
+def test_hybrid_step_of_the_mellum_cut_compiles(one_chip, monkeypatch,
+                                                program):
+    """One decode step, one ragged join and one prologue chunk of the
+    benchmark's Mellum2-12B cut at published widths (one sliding and
+    one full block: both kernels' lowerings, both rotary tables, the
+    softmax router, 64 held experts and NO shared expert)."""
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    whole = get_model_config("mellum2-12b-a2.5b")
+    # blocks 2 (sliding) and 3 (full)
+    cfg = dataclasses.replace(
+        whole, num_layers=4, layer_kinds=whole.layer_kinds[4:8],
+        attn_layers=whole.attn_layers[2:4], attn_impl="flash")
+    assert cfg.attention_classes == ((32, 1024, 1), (32, None, 1))
+    assert cfg.experts_held == cfg.routed_experts == 64
+    assert cfg.router_rule == "softmax_topk" and not cfg.shared_expert_dim
+
+    def check(params):
+        assert params["layers"][0]["k_proj"].shape == (2304, MELLUM_KV, D)
+        assert set(params["layers"][1]) == {"norm", "router", "experts"}
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    hlo = _window_step_hlo(
+        cfg, s((MELLUM_POOL, PAGE, MELLUM_KV, D), jnp.bfloat16), program,
+        one_chip, check, join_at=5900)
+    # attention a block, and the grouped products of its experts
+    assert hlo.count("tpu_custom_call") >= 2
+    assert "grouped_matmul" in hlo
 
 
 # --- the int4 kernels the compiler refuses --------------------------------

@@ -1,17 +1,18 @@
-"""A model whose attention layers differ (window and full layers, 6 or 8
-heads over one GQA page pool, two rotary tables, a per-head gate) with
-all its experts held, through engine and scheduler on the CPU: prologue
-and decode through `PagedKVCache`, own-slot reuse, the prefix index with
-the 16-token window spanning aliased pages, three knights over two
-rounds with ragged joins and the leader pass, the segment spans' reads
-by layer class, and the decline table. (The offload tier moves whole
-pages by id whatever the layers: tests/test_prefix_cache.py.)
+"""A `mellum` decoder (window and full layers at ONE head count over one
+GQA page pool, two rotary tables by layer type, a softmax top-k router
+over held experts and no shared one) built from its `architecture`
+block, through engine and scheduler on the CPU: prologue and decode
+through `PagedKVCache`, own-slot reuse, three knights over three rounds
+with ragged joins and the leader pass, what the segments' rows hold
+behind their windows (`pages_held`, `pages_behind_window`), and the
+decline table.
 
 Every served token is compared with the plain reference
-(benchmarks/configs/laguna_reference.py) on the engine's own weights: a
+(benchmarks/configs/mellum_reference.py) on the engine's own weights: a
 float32 engine serves the reference's own maximum at every position
 (gap 0 but for rounding-level ties, held to 1e-3 of a logit whose spread
-is about 1). Logit-for-logit comparisons: tests/test_laguna_model.py."""
+is about 1). Logit-for-logit comparisons:
+tests/benchmarks/test_benchmark_reference_mellum.py."""
 import os
 import sys
 import threading
@@ -24,9 +25,7 @@ jax = pytest.importorskip("jax")
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
-from configs import laguna_reference as ref  # noqa: E402
-
-from test_laguna_model import PUBLISHED, tokens_of  # noqa: E402
+from configs import mellum_reference as ref  # noqa: E402
 
 from theroundtaible_tpu.engine.engine import InferenceEngine  # noqa: E402
 from theroundtaible_tpu.engine.scheduler import SessionScheduler  # noqa: E402
@@ -36,12 +35,39 @@ GAP = 1e-3
 PAGE = 8
 WINDOW = 16
 KNIGHTS = ["lancelot", "galahad", "percival"]
+PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+
+# one period of tiny-mellum as a published config.json would state it
+PUBLISHED = {
+    "model_type": "mellum", "vocab_size": 512, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 4,
+    "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 16,
+    "max_position_embeddings": 512, "max_window_layers": 0,
+    "attention_bias": False, "hidden_act": "silu", "rms_norm_eps": 1e-6,
+    "num_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32, "norm_topk_prob": True,
+    "tie_word_embeddings": False, "sliding_window": WINDOW,
+    "use_sliding_window": True,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 8,
+            "original_max_position_embeddings": 32, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2079441541679836},
+        "sliding_attention": {"rope_type": "default",
+                              "rope_theta": 500000}},
+    "layer_types": PERIOD, "mlp_layer_types": ["sparse"] * 4,
+}
+
+
+def tokens_of(seed, n):
+    return [int(t) for t in
+            np.random.RandomState(seed).randint(3, 250, size=(n,))]
 
 
 def make_engine(**kw):
-    config = {"model": "tiny-laguna", "dtype": "float32",
-              "kv_layout": "paged", "page_size": PAGE, "num_slots": 8,
-              "max_seq_len": 512, "seed": 3,
+    config = {"model": "tiny-mellum-d4", "architecture": PUBLISHED,
+              "dtype": "float32", "kv_layout": "paged", "page_size": PAGE,
+              "num_slots": 8, "max_seq_len": 512, "seed": 3,
               "sampling": {"temperature": 0.0},
               "mesh": {"data": 1, "model": 1}}
     config.update(kw)
@@ -90,22 +116,31 @@ def test_prologue_then_decode_through_the_pages(engine):
     attn = info["attention"]
     assert set(attn) == set(telemetry.SURFACE_BINDINGS["engine_attention"])
     assert (attn["kv_heads"], attn["head_dim"], attn["gate"]) \
-        == (2, 16, "per-head")
-    assert [(a["layer"], a["heads"], a["window"], a["rotary_dim"])
-            for a in attn["layers"]] == [
-        (0, 6, None, 8), (2, 8, WINDOW, 16), (4, 8, WINDOW, 16),
-        (6, 8, WINDOW, 16), (8, 6, None, 8)]
-    assert attn["layers"][0]["rope_yarn"] == [8.0, 32.0, 64.0, 1.0]
-    assert attn["layers"][1]["rope_yarn"] is None
+        == (2, 16, None)
+    assert [(a["layer"], a["heads"], a["window"], a["rotary_dim"],
+             a["rope_theta"]) for a in attn["layers"]] == [
+        (0, 8, WINDOW, 16, 500000.0), (2, 8, WINDOW, 16, 500000.0),
+        (4, 8, WINDOW, 16, 500000.0), (6, 8, None, 16, 500000.0)]
+    assert attn["layers"][3]["rope_yarn"] == [8.0, 32.0, 32.0, 1.0]
+    assert attn["layers"][3]["rope_attention_factor"] \
+        == 1.2079441541679836
+    assert attn["layers"][0]["rope_yarn"] is None
+    # both classes were asked of both kernel gates, and neither declined
     assert [(c["heads"], c["window"], c["layers"], c["decode_decline"],
              c["ragged_decline"]) for c in attn["classes"]] == [
-        (6, None, 2, None, None), (8, WINDOW, 3, None, None)]
-    # Every expert of a layer is held: the deployment's own token share.
-    assert info["moe"]["held"] == 8 and info["moe"]["experts_hit"] > 0
-    # One pool shape for five layers that differ: [P, ps, 2, 16] twice.
+        (8, WINDOW, 3, None, None), (8, None, 1, None, None)]
+    moe = info["moe"]
+    assert set(moe) == set(telemetry.SURFACE_BINDINGS["engine_moe"])
+    assert (moe["held"], moe["published"], moe["top_k"],
+            moe["router_rule"], moe["shared_expert"]) == (
+        8, 8, 2, "softmax_topk", False)
+    assert moe["experts_hit"] > 0
+    # One pool shape for four layers of two classes: [P, ps, 2, 16] twice.
     assert [tuple(p.shape for p in layer) for layer in engine.kv.pools] \
-        == [((engine.kv.num_pages, PAGE, 2, 16),) * 2] * 5
+        == [((engine.kv.num_pages, PAGE, 2, 16),) * 2] * 4
     assert engine.hybrid.state == {"ssm": [], "conv": []}
+    assert all("shared" not in engine.params["layers"][i]
+               for i in (1, 3, 5, 7))
 
 
 def test_own_slot_reuse_prefills_only_the_new_tokens(engine):
@@ -115,20 +150,6 @@ def test_own_slot_reuse_prefills_only_the_new_tokens(engine):
     again, stats = serve(engine, "cont", longer)
     assert stats.prefill_tokens == 30
     assert worst_gap(engine, longer, again) < GAP
-
-
-def test_the_prefix_index_hands_whole_pages_to_another_slot(engine):
-    """Window layers keep whole pages under the one page table, so the
-    index stays exact page-id work: the taker's window (16) spans the
-    donor's last two pages and its own first."""
-    base = [1] + tokens_of(4, 70)
-    serve(engine, "donor", base)
-    other = base[:64] + tokens_of(5, 25)
-    served, stats = serve(engine, "taker", other)
-    assert stats.prefill_tokens == 25           # eight whole pages by alias
-    assert engine.kv._slots["taker"].pages[:8] \
-        == engine.kv._slots["donor"].pages[:8]
-    assert worst_gap(engine, other, served) < GAP
 
 
 # --- what declines -----------------------------------------------------------
@@ -165,35 +186,11 @@ def test_what_cannot_be_served_declines_with_a_reason(
 @pytest.mark.parametrize("config,message", [
     ({"kv_layout": "contiguous"}, "paged"),
     ({"mesh": {"data": 1, "model": 2}}, "mesh"),
-    ({"attn": "dense"}, "pool-direct"),
 ])
 def test_what_the_model_cannot_serve_without_fails_at_build(config,
                                                             message):
     with pytest.raises(ValueError, match=message):
         make_engine(num_slots=2, **config)
-
-
-def test_a_group_the_ragged_kernel_declines_is_named(monkeypatch):
-    """One class's decline decides the path and is written down: the
-    cell may not run on it (`degraded_paths` reads the same fields)."""
-    from theroundtaible_tpu.engine.pallas import attention as pattn
-    real = pattn.ragged_decline_reason
-
-    def declines_group_three(page_size, d, kh=1, group=1, **kw):
-        if group == 3:
-            return f"vmem:ps={page_size},d={d},kh={kh},g={group}"
-        return real(page_size, d, kh, group, **kw)
-
-    monkeypatch.setattr(pattn, "ragged_decline_reason",
-                        declines_group_three)
-    eng = make_engine(num_slots=2)
-    assert eng.ragged_path == "xla_ragged"
-    info = eng.describe()
-    assert info["declines"]["ragged_kernel"] == "vmem:ps=8,d=16,kh=2,g=3"
-    assert info["ragged"]["fallback_reason"] \
-        == info["declines"]["ragged_kernel"]
-    assert [c["ragged_decline"] for c in info["attention"]["classes"]] \
-        == ["vmem:ps=8,d=16,kh=2,g=3", None]
 
 
 # --- scheduler ---------------------------------------------------------------
@@ -203,7 +200,7 @@ def cue(knight, round_no):
     return [3 + ord(c) for c in f"\n[r{round_no}] {knight}: "]
 
 
-def discussion(sched, eng, sid, opening, rounds=3, new=12):
+def discussion(sched, eng, sid, opening, rounds, new=12):
     transcript, served = list(opening), []
     for r in range(1, rounds + 1):
         turns = [(k, transcript + cue(k, r)) for k in KNIGHTS]
@@ -219,9 +216,11 @@ def discussion(sched, eng, sid, opening, rounds=3, new=12):
     return served
 
 
-def test_three_knights_two_rounds_and_the_reads_by_layer_class(engine):
+def test_three_knights_three_rounds_and_what_lies_behind_the_windows(
+        engine):
     eng = engine
     sched = SessionScheduler(eng)
+    before = dict(eng.describe()["attention"])
     telemetry.arm()
     t_a = time.monotonic()
     results, errors = {}, []
@@ -229,7 +228,7 @@ def test_three_knights_two_rounds_and_the_reads_by_layer_class(engine):
     def run(sid, seed, n_open):
         try:
             results[sid] = discussion(
-                sched, eng, sid, [1] + tokens_of(seed, n_open), rounds=2)
+                sched, eng, sid, [1] + tokens_of(seed, n_open), rounds=3)
         except BaseException as e:  # noqa: BLE001 — asserted below
             errors.append(e)
 
@@ -248,54 +247,36 @@ def test_three_knights_two_rounds_and_the_reads_by_layer_class(engine):
         sched.close()
     assert not errors, errors
     d = sched.describe()
-    assert d["failed"] == 0 and d["completed"] == 4
+    assert d["failed"] == 0 and d["completed"] == 6
     assert d["ragged_joins"] >= 1
-    # round 2 prefills the knights' deltas alone: the leader pass,
+    # later rounds prefill the knights' deltas alone: the leader pass,
     # own-slot reuse and the prefix index are on over window layers
     prompts = sum(len(p) for served in results.values()
                   for p, _a in served)
     assert d["segment_prefill_tokens"] < prompts / 2
     assert eng.hybrid.describe()["share_declined"] == 0
     segs = [s["attrs"] for s in spans if s["rung"] == "segment"]
-    names = {"page_visits_full", "page_visits_window"}
+    names = {"page_visits_full", "page_visits_window", "pages_held",
+             "pages_behind_window"}
     assert segs and all(names <= set(a) for a in segs)
-    assert all({"experts_hit", "local_assignments", "expert_layer_steps"}
-               <= set(a) for a in segs[1:])
-    # A plain segment of `steps` steps over rows whose contexts (60 to
-    # 250 positions) pass the 16-token window: each of the 3 window
-    # layers reads 2 or 3 pages a row a step, each of the 2 full layers
-    # every page the row holds.
+    # Contexts of 60 to 400 positions over 8-wide pages against a
+    # 16-token window on 3 layers of 4: a row at context L holds
+    # ceil(L / 8) pages on every layer, of which (L - 16) // 8 lie
+    # wholly behind the window on each window layer — so a segment's
+    # share is under 3/4 and, past 60 positions, over 3/4 x 5/8.
+    for a in segs:
+        assert 0 < a["pages_behind_window"] < 0.75 * a["pages_held"]
+        assert a["pages_held"] % 4 == 0 and a["pages_behind_window"] % 3 == 0
+        assert a["pages_behind_window"] > 0.75 * 0.6 * a["pages_held"]
+    # a plain segment's rows: each holds at least 60 / 8 pages a layer
     plain = [a for a in segs if a["kind"] == "plain" and a["steps"] > 1]
-    assert plain
-    for a in plain:
-        row_steps = a["steps"] * a["rows"]
-        assert 3 * 2 * row_steps <= a["page_visits_window"] \
-            <= 3 * 3 * row_steps
-        assert a["page_visits_full"] >= 2 * (60 // PAGE) * row_steps
-        assert a["page_visits_full"] * 3 > a["page_visits_window"] * 2
-    ragged = [a for a in segs if a["kind"] == "ragged"]
-    assert ragged and all(a["page_visits_window"] > 0
-                          and a["page_visits"] > 0 for a in ragged)
+    assert plain and all(a["pages_held"] >= 4 * 8 * a["rows"]
+                         for a in plain)
     attn = eng.describe()["attention"]
     for name in names:
-        assert attn[name] >= sum(a[name] for a in segs) > 0
+        assert attn[name] - before[name] >= sum(a[name] for a in segs) > 0
     assert telemetry.REGISTRY.counter_total(
-        "roundtable_window_page_visits_window_total") > 0
+        "roundtable_window_pages_held_total") >= attn["pages_held"] > 0
     assert telemetry.REGISTRY.counter_total(
-        "roundtable_window_page_visits_full_total") > 0
-
-
-def test_a_model_without_attn_layers_carries_none_of_it():
-    """Mistral's path is as it was: no class counts on its spans, no
-    `attention` in its describe()."""
-    eng = InferenceEngine.from_config({
-        "model": "tiny-mistral", "dtype": "float32", "kv_layout": "paged",
-        "page_size": PAGE, "num_slots": 2, "max_seq_len": 256, "seed": 3,
-        "sampling": {"temperature": 0.0},
-        "mesh": {"data": 1, "model": 1}})
-    eng.generate_batch_with_stats([("a", [1] + tokens_of(1, 20))],
-                                  max_new_tokens=3)
-    assert "attention" not in eng.describe()
-    assert eng._window_reads == {"page_visits_full": 0,
-                                 "page_visits_window": 0,
-                                 "pages_held": 0, "pages_behind_window": 0}
+        "roundtable_window_pages_behind_total") \
+        >= attn["pages_behind_window"] > 0

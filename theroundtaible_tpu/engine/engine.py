@@ -910,7 +910,8 @@ class InferenceEngine:
         # ... and what every segment's attention read by layer class,
         # for a model whose attention layers differ (attn_layers)
         self._window_reads = {"page_visits_full": 0,
-                              "page_visits_window": 0}
+                              "page_visits_window": 0,
+                              "pages_held": 0, "pages_behind_window": 0}
         if kv_layout == "paged":
             from .prefix_cache import env_flag
             from .pallas import attention as _pattn
@@ -2173,14 +2174,36 @@ class InferenceEngine:
                 np.where(valid > 0, hi - lo + 1, 0).sum())
         return self._note_window_reads(reads)
 
+    def window_page_holdings(self, read_to: tuple) -> dict:
+        """What a segment's live rows HOLD, in pages x attention layers
+        (`pages_held`), and how much of it lies wholly behind a row's
+        window on the window layers (`pages_behind_window`: pages whose
+        every position is more than the window back from the row's
+        frontier — the pages the decode walk starts after), from how
+        many positions each row's cache held at the segment's last
+        step. Window layers keep whole pages under the one page table;
+        the quotient is what a frontier a layer class would free."""
+        ps = self.kv.page_size
+        valid = np.maximum(np.asarray(read_to, np.int64), 0)
+        held = -(-valid // ps)
+        n_layers = len(self.cfg.attention_layers)
+        behind = sum(
+            layers * int((np.maximum(valid - window, 0) // ps).sum())
+            for _heads, window, layers in self.cfg.attention_classes
+            if window is not None)
+        return self._note_window_reads({
+            "pages_held": int(held.sum()) * n_layers,
+            "pages_behind_window": behind})
+
     def _note_window_reads(self, reads: dict) -> dict:
         """The one writer of the lifetime totals of a segment's reads
-        by layer class and of their series."""
+        by layer class, of what its rows hold, and of their series."""
         from ..utils import telemetry
         for name, n in reads.items():
             self._window_reads[name] += n
-            telemetry.inc(f"roundtable_window_{name}_total", n,
-                          engine=self.cfg.name)
+            telemetry.inc(
+                telemetry.SURFACE_BINDINGS["engine_attention"][name], n,
+                engine=self.cfg.name)
         return reads
 
     def attention_describe(self) -> dict[str, Any]:
@@ -3415,6 +3438,9 @@ class InferenceEngine:
                            "published": self.cfg.routed_experts,
                            "offset": self.cfg.expert_offset,
                            "top_k": self.cfg.moe_top_k,
+                           "router_rule": self.cfg.router_rule,
+                           "shared_expert": bool(
+                               self.cfg.shared_expert_dim),
                            "expert_layers": len(self.cfg.expert_layers),
                            "grouped_product": (
                                "ragged_dot" if "grouped_product"

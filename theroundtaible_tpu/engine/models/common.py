@@ -173,7 +173,9 @@ class ModelConfig:
     expert_dim: int = 0
     shared_expert_dim: int = 0
     routed_scaling: float = 1.0
-    router_rule: str = "sigmoid_bias_topk"   # | "sigmoid_topk": no bias
+    # "sigmoid_bias_topk" | "sigmoid_topk" (no bias) | "softmax_topk"
+    # (models/hybrid.py: route says which model uses which)
+    router_rule: str = "sigmoid_bias_topk"
     expert_act: str = "relu2"                # | "silu"
     expert_gated: bool = False               # (act(x W_gate) * x W_up) W_down
     # Multi-head latent attention (models/mla.py), on where kv_lora_rank

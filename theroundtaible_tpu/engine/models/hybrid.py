@@ -397,6 +397,7 @@ def _relu2(x: jax.Array) -> jax.Array:
 
 
 EXPERT_ACTS = {"relu2": _relu2, "silu": jax.nn.silu}
+ROUTER_RULES = ("sigmoid_bias_topk", "sigmoid_topk", "softmax_topk")
 
 # What a step program returns of its expert layers, in this order.
 MOE_COUNTS = ("experts_hit", "local_assignments", "expert_layer_steps",
@@ -411,13 +412,20 @@ def step_counts(counts: jax.Array, stepped) -> jax.Array:
 
 
 def route(h: jax.Array, layer: Params, cfg: ModelConfig):
-    """The router's rule (DeepSeek-V3's with one group): s = sigmoid(h
-    W_r) in float32 over ALL published experts; choose top-k of s +
-    bias ("sigmoid_bias_topk": `nemotron_h`) or of s alone
-    ("sigmoid_topk": `axk1`, which declares no bias); weights
-    s[chosen] / (sum + 1e-20) * scale. h [T,E] -> (ids [T,k] int32,
-    weights [T,k] f32)."""
-    s = jax.nn.sigmoid(jnp.einsum(
+    """The router's rule, scores in float32 over ALL published experts.
+    DeepSeek-V3's with one group: s = sigmoid(h W_r); choose top-k of
+    s + bias ("sigmoid_bias_topk": `nemotron_h`) or of s alone
+    ("sigmoid_topk": `axk1` and `laguna`, which declare no bias).
+    Mixtral's and Qwen-MoE's ("softmax_topk": `mellum`): s = softmax(h
+    W_r) over all of them, top-k of s. Weights s[chosen] / (sum +
+    1e-20) * scale (`routed_scaling` 1 where the config has none).
+    h [T,E] -> (ids [T,k] int32, weights [T,k] f32)."""
+    if cfg.router_rule not in ROUTER_RULES:
+        raise ValueError(f"router_rule {cfg.router_rule!r}: known are "
+                         f"{', '.join(ROUTER_RULES)}")
+    score = (jax.nn.softmax if cfg.router_rule == "softmax_topk"
+             else jax.nn.sigmoid)
+    s = score(jnp.einsum(
         "te,ex->tx", h.astype(jnp.float32),
         layer["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -490,7 +498,9 @@ def routed_experts(x: jax.Array, experts: Params, local: jax.Array,
 
 def experts_mlp(h: jax.Array, layer: Params, cfg: ModelConfig,
                 token_mask: Optional[jax.Array] = None):
-    """Routed experts held here + the shared expert. h [..., T, E] ->
+    """Routed experts held here + the shared expert (a model without
+    one, `shared_expert_dim` 0, has no such leaf and no such product).
+    h [..., T, E] ->
     (out, counts int32[4]): counts = (held experts some counted token
     chose, assignments of counted tokens to held experts, rows the
     grouped product multiplied, rows a loop over every held expert
@@ -516,9 +526,11 @@ def experts_mlp(h: jax.Array, layer: Params, cfg: ModelConfig,
     counts = jnp.stack([jnp.sum(hit), jnp.sum(chosen), multiplied,
                         jnp.asarray(t * held)]).astype(jnp.int32)
 
-    out = routed + _expert(x, layer["shared"],
-                           functools.partial(_einsum, "te,ef->tf"),
-                           cfg.expert_act, cfg.expert_gated)
+    out = routed
+    if cfg.shared_expert_dim:
+        out = out + _expert(x, layer["shared"],
+                            functools.partial(_einsum, "te,ef->tf"),
+                            cfg.expert_act, cfg.expert_gated)
     return out.astype(h.dtype).reshape(*lead, -1), counts
 
 
@@ -585,16 +597,18 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
             "experts": {"up": dense(ks[2], (held, e, f), e),
                         "down": dense(ks[3], (held, f, e), f,
                                       RESIDUAL_SHARE)},
-            "shared": {"up": dense(ks[4], (e, fs), e),
-                       "down": dense(ks[5], (fs, e), fs,
-                                     RESIDUAL_SHARE)},
         })
+        if fs:
+            layer["shared"] = {"up": dense(ks[4], (e, fs), e),
+                               "down": dense(ks[5], (fs, e), fs,
+                                             RESIDUAL_SHARE)}
         if cfg.router_rule == "sigmoid_bias_topk":
             layer["router_bias"] = jax.random.normal(
                 ks[1], (cfg.routed_experts,), jnp.float32) * 0.02
         if cfg.expert_gated:
             layer["experts"]["gate"] = dense(ks[6], (held, e, f), e)
-            layer["shared"]["gate"] = dense(ks[7], (e, fs), e)
+            if fs:
+                layer["shared"]["gate"] = dense(ks[7], (e, fs), e)
     elif kind == MLP:
         f = cfg.mlp_dim
         layer.update({

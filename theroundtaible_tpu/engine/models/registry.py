@@ -198,7 +198,7 @@ def laguna_layers(layer_types, mlp_layer_types, heads_per_layer, *,
                              heads_per_layer):
         if lt not in ("full_attention", "sliding_attention") \
                 or mt not in ("dense", "sparse"):
-            raise ValueError(f"laguna layer types ({lt!r}, {mt!r}): "
+            raise ValueError(f"layer types ({lt!r}, {mt!r}): "
                              "known are full_attention / "
                              "sliding_attention and dense / sparse")
         kinds += [ATTENTION, MLP if mt == "dense" else EXPERTS]
@@ -249,6 +249,54 @@ TINY_LAGUNA = register(_laguna_preset(
     sliding=AttnLayer(0, rope_theta=10_000.0, rotary_dim=16),
     routed_experts=8, experts_held=8, top_k=2, expert_dim=32,
     shared_expert_dim=32))
+
+
+# --- Mellum 2 / mellum (window and full attention layers at ONE head
+# count over one GQA page pool, two rotary tables by layer type; every
+# layer's MLP softmax-routed gated experts with NO shared expert; a
+# published layer is TWO layers here, as laguna's) ---
+
+def _mellum_config(name, layer_types, *, heads, window, full, sliding,
+                   **kw):
+    """Every block `layer_types` names, each with experts and `heads`
+    query heads; no gate, no shared expert, the router a softmax."""
+    n = len(layer_types)
+    kinds, geometry = laguna_layers(
+        layer_types, ["sparse"] * n, [heads] * n, window=window, full=full,
+        sliding=sliding)
+    return ModelConfig(
+        name=name, num_layers=2 * n, num_heads=heads, tie_embeddings=False,
+        layer_kinds=kinds, attn_layers=geometry,
+        router_rule="softmax_topk", expert_act="silu", expert_gated=True,
+        **kw)
+
+
+def _mellum_preset(name, *, blocks, **kw):
+    """`S S S F` repeated."""
+    return _mellum_config(
+        name, ["full_attention" if b % 4 == 3 else "sliding_attention"
+               for b in range(blocks)], norm_eps=1e-6, **kw)
+
+
+MELLUM2_12B = register(_mellum_preset(
+    "mellum2-12b-a2.5b", blocks=28, heads=32, vocab_size=98_304,
+    embed_dim=2304, num_kv_heads=4, head_dim=128, mlp_dim=7168,
+    max_seq_len=8192, window=1024,
+    full=AttnLayer(0, rope_theta=500_000.0, rotary_dim=128,
+                   rope_yarn=(16.0, 8192.0, 32.0, 1.0),
+                   rope_attention_factor=1.2772588722239782),
+    sliding=AttnLayer(0, rope_theta=500_000.0, rotary_dim=128),
+    routed_experts=64, experts_held=64, moe_top_k=8, expert_dim=896))
+
+# Group 4 over 2 kv heads, a window of two 8-wide pages, two periods.
+TINY_MELLUM = register(_mellum_preset(
+    "tiny-mellum", blocks=8, heads=8, vocab_size=512, embed_dim=64,
+    num_kv_heads=2, head_dim=16, mlp_dim=128, max_seq_len=512, window=16,
+    full=AttnLayer(0, rope_theta=500_000.0, rotary_dim=16,
+                   rope_yarn=(8.0, 32.0, 32.0, 1.0),
+                   rope_attention_factor=1.2079441541679836),
+    sliding=AttnLayer(0, rope_theta=500_000.0, rotary_dim=16),
+    routed_experts=8, experts_held=8, moe_top_k=2, expert_dim=32))
 
 
 # --- from a published config.json -------------------------------------------
@@ -453,21 +501,27 @@ def _laguna_rotary(name: str, kind: str, params: dict[str, Any],
     return AttnLayer(0, **table)
 
 
+def _rotary_tables(name: str, rotary: dict[str, Any],
+                   head_dim: int) -> tuple[AttnLayer, AttnLayer]:
+    """`rope_parameters` -> the (full, sliding) layers' rotary tables."""
+    rotary = dict(rotary)
+    rotary.pop("original_max_position_embeddings", None)
+    tables = [_laguna_rotary(name, kind, rotary.pop(kind), head_dim)
+              for kind in ("full_attention", "sliding_attention")]
+    if rotary:
+        raise ValueError(f"architecture of {name!r}: unknown "
+                         f"rope_parameters keys {sorted(rotary)}")
+    return tables[0], tables[1]
+
+
 def _laguna(name: str, arch: dict[str, Any],
             max_seq_len: int) -> ModelConfig:
     arch = _acted_on(name, arch, "laguna", _LAGUNA_FIXED, _LAGUNA_INERT)
     try:
         n_blocks = int(arch.pop("num_hidden_layers"))
         head_dim = int(arch.pop("head_dim"))
-        rotary = dict(arch.pop("rope_parameters"))
-        rotary.pop("original_max_position_embeddings", None)
-        full = _laguna_rotary(name, "full_attention",
-                              rotary.pop("full_attention"), head_dim)
-        sliding = _laguna_rotary(name, "sliding_attention",
-                                 rotary.pop("sliding_attention"), head_dim)
-        if rotary:
-            raise ValueError(f"architecture of {name!r}: unknown "
-                             f"rope_parameters keys {sorted(rotary)}")
+        full, sliding = _rotary_tables(
+            name, arch.pop("rope_parameters"), head_dim)
         lists = [list(arch.pop(k)) for k in (
             "layer_types", "mlp_layer_types",
             "num_attention_heads_per_layer")]
@@ -513,6 +567,62 @@ def _laguna(name: str, arch: dict[str, Any],
     return cfg
 
 
+# Keys of a mellum config.json that say nothing this engine acts on
+# (`intermediate_size` is the width of a dense MLP and no layer has one;
+# `max_window_layers: 0` beside `layer_types` names no layer), and the
+# values its layer equations assume. The config names no scoring
+# function: `norm_topk_prob: true` beside no scaling factor is softmax,
+# top-k, renormalise (`softmax_topk`), and a key that names another
+# rule is unknown here and fails as every unknown key does.
+_MELLUM_INERT = {"model_type", "max_position_embeddings",
+                 "max_window_layers"}
+_MELLUM_FIXED = {
+    "attention_bias": False, "tie_word_embeddings": False,
+    "hidden_act": "silu", "norm_topk_prob": True,
+    "use_sliding_window": True}
+
+
+def _mellum(name: str, arch: dict[str, Any],
+            max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "mellum", _MELLUM_FIXED, _MELLUM_INERT)
+    try:
+        n_blocks = int(arch.pop("num_hidden_layers"))
+        head_dim = int(arch.pop("head_dim"))
+        heads = int(arch.pop("num_attention_heads"))
+        full, sliding = _rotary_tables(
+            name, arch.pop("rope_parameters"), head_dim)
+        lists = [list(arch.pop(k)) for k in ("layer_types",
+                                             "mlp_layer_types")]
+        if any(len(x) != n_blocks for x in lists):
+            raise ValueError(
+                f"architecture of {name!r}: layer_types and "
+                f"mlp_layer_types have {[len(x) for x in lists]} entries, "
+                f"num_hidden_layers says {n_blocks}")
+        if set(lists[1]) != {"sparse"}:
+            raise ValueError(
+                f"architecture of {name!r}: a dense layer among "
+                "mlp_layer_types, and this engine's mellum layers are "
+                "written for every layer sparse")
+        held = int(arch.pop("num_experts"))
+        cfg = _mellum_config(
+            name, lists[0], heads=heads,
+            window=int(arch.pop("sliding_window")), full=full,
+            sliding=sliding, vocab_size=int(arch.pop("vocab_size")),
+            embed_dim=int(arch.pop("hidden_size")),
+            num_kv_heads=int(arch.pop("num_key_value_heads")),
+            head_dim=head_dim, mlp_dim=int(arch.pop("intermediate_size")),
+            max_seq_len=max_seq_len,
+            norm_eps=float(arch.pop("rms_norm_eps")),
+            routed_experts=held, experts_held=held,
+            moe_top_k=int(arch.pop("num_experts_per_tok")),
+            expert_dim=int(arch.pop("moe_intermediate_size")))
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    _all_read(name, arch, "mellum")
+    return cfg
+
+
 def _dense_gqa(name: str, arch: dict[str, Any],
                max_seq_len: int) -> ModelConfig:
     heads = int(arch["num_attention_heads"])
@@ -552,6 +662,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
         return _axk1(name, arch, max_seq_len)
     if kind == "laguna":
         return _laguna(name, arch, max_seq_len)
+    if kind == "mellum":
+        return _mellum(name, arch, max_seq_len)
     if kind in _DENSE_TYPES:
         try:
             return _dense_gqa(name, arch, max_seq_len)
@@ -560,7 +672,7 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
                              f"{e.args[0]!r}") from None
     raise ValueError(
         f"architecture of {name!r}: model_type {kind!r} is not one this "
-        f"engine runs (nemotron_h, axk1, laguna, "
+        f"engine runs (nemotron_h, axk1, laguna, mellum, "
         f"{', '.join(_DENSE_TYPES)})")
 
 

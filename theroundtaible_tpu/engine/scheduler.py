@@ -1679,7 +1679,8 @@ class SessionScheduler:
         `ragged`: the dispatched ragged batch, for what its attention
         read in page visits (engine._note_page_visits); a model whose
         attention layers differ adds `page_visits_full` and
-        `page_visits_window`, by layer class, on every kind of
+        `page_visits_window`, by layer class, and what the rows hold
+        (`pages_held`, of it `pages_behind_window`), on every kind of
         segment."""
         latent = None
         if getattr(self.engine.cfg, "latent", False):
@@ -1691,9 +1692,10 @@ class SessionScheduler:
         # for a plain segment, here from the rows' frontiers.
         window_reads = None
         if getattr(self.engine.cfg, "attn_layers", None) is not None:
-            window_reads = (
+            window_reads = dict(
                 ragged.get("window_reads") if ragged is not None
-                else self.engine.plain_window_reads(steps, read_to))
+                else self.engine.plain_window_reads(steps, read_to),
+                **self.engine.window_page_holdings(read_to))
         hy = getattr(self.engine, "hybrid", None)
         if hy is not None:
             # This segment has been read, so every program up to it has

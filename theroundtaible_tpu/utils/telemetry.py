@@ -1189,6 +1189,8 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "published": "static (experts the router scores)",
         "offset": "static (first held expert's published id)",
         "top_k": "static (experts a token)",
+        "router_rule": "static (models/hybrid.py: route)",
+        "shared_expert": "static (an expert every token takes, or none)",
         "expert_layers": "static (layer pattern)",
         "experts_hit": "roundtable_moe_experts_hit_total",
         "local_assignments": "roundtable_moe_local_assignments_total",
@@ -1257,6 +1259,11 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "page_visits_full": "roundtable_window_page_visits_full_total",
         "page_visits_window":
             "roundtable_window_page_visits_window_total",
+        # ISSUE 40: what the segments' rows held, in pages x attention
+        # layers, and how much of it lay wholly behind a window layer's
+        # window (engine.window_page_holdings; the same one writer).
+        "pages_held": "roundtable_window_pages_held_total",
+        "pages_behind_window": "roundtable_window_pages_behind_total",
     },
     # engine.describe()["ragged"] (ISSUE 8, 32): the ragged seam's
     # provenance. Static but for the dispatch counts and what the
