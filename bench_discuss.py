@@ -904,13 +904,14 @@ def child() -> int:
     rounds = 5
 
     # Sampler provenance (ISSUE 3 satellite): config 2 records WHICH
-    # sampler path its decode ran — greedy (the temp=0 default), the
-    # sort-free candidate-pool fast path, or the exact full-vocab sort —
-    # so the unmeasured sort-free sampler gets an attributable number in
-    # the same window. Flip the env knobs to measure the sampled paths:
-    # ROUNDTABLE_BENCH_TEMPERATURE=0.7 [ROUNDTABLE_BENCH_TOP_P=0.95,
-    # ROUNDTABLE_BENCH_TOP_K=40] turns the run sort-free;
-    # ROUNDTABLE_BENCH_TOP_K>128 forces the sort fallback.
+    # sampler path its decode ran — greedy (the temp=0 default), plain
+    # (sampled, no filter: no candidate pool), the sort-free candidate
+    # pool, or the exact full-vocab sort — so each gets an attributable
+    # number in the same window. Flip the env knobs to measure the
+    # sampled paths: ROUNDTABLE_BENCH_TEMPERATURE=0.7 alone runs plain;
+    # with ROUNDTABLE_BENCH_TOP_P=0.95 or ROUNDTABLE_BENCH_TOP_K=40 the
+    # run is sort-free; ROUNDTABLE_BENCH_TOP_K>128 forces the sort
+    # fallback.
     temp = float(os.environ.get("ROUNDTABLE_BENCH_TEMPERATURE", "0.0"))
     top_p = float(os.environ.get("ROUNDTABLE_BENCH_TOP_P", "1.0"))
     top_k = int(os.environ.get("ROUNDTABLE_BENCH_TOP_K", "0"))
@@ -1030,7 +1031,7 @@ def child() -> int:
             "warmup_s": round(warmup_s, 1),
             "engine_wall_s": totals.get("wall_s"),
             "platform": jax.devices()[0].platform,
-            # Per-run sampler attribution: greedy / sort-free / sort
+            # Per-run sampler attribution: greedy / plain / sort-free / sort
             # (engine/sampling.sampler_mode) + the knobs that chose it.
             "sampler": {"mode": mode, "temperature": temp,
                         "top_k": top_k, "top_p": top_p},

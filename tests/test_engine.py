@@ -263,6 +263,127 @@ class TestSampling:
             assert a.tolist() == b.tolist()
 
 
+# One sampled batch for the conditional pool's tests (ISSUE 41): sixteen
+# rows of mixed temperatures, greedy ones among them.
+POOL_ROWS = 16
+POOL_TEMPS = (0.0, 0.3, 0.7, 1.0, 1.5, 0.7, 0.0, 0.9,
+              0.7, 1.2, 0.7, 0.5, 0.7, 2.0, 0.7, 0.7)
+FILTERED_ROW = 5
+# kind -> (the one filtered row's params, how wide its logits spread)
+FILTERED_KINDS = {
+    "top_k": (dict(temperature=0.9, top_k=7), 3.0),
+    "top_p": (dict(temperature=0.8, top_p=0.9), 3.0),
+    "both": (dict(temperature=1.1, top_k=40, top_p=0.8), 3.0),
+    # beyond the pool's 128: the two-sort tail, for that row alone
+    "exact_top_k": (dict(temperature=1.0, top_k=200), 3.0),
+    "exact_top_p": (dict(temperature=1.0, top_p=0.99), 0.01),
+}
+
+
+@pytest.fixture(scope="module")
+def sampler_program():
+    """sample_token_batch as the step programs run it: compiled."""
+    from theroundtaible_tpu.engine.sampling import sample_token_batch
+    return jax.jit(sample_token_batch)
+
+
+def _plain_draw(logits, key, temps):
+    """What a batch with no filter must draw, bit for bit."""
+    temps = jnp.asarray(temps, jnp.float32)
+    drawn = jax.random.categorical(
+        key, logits / jnp.maximum(temps[:, None], 1e-6), axis=-1)
+    return jnp.where(temps <= 0.0, jnp.argmax(logits, axis=-1), drawn)
+
+
+def _reachable_primitives(jaxpr, enter_cond: bool) -> set:
+    """Names of the primitives of `jaxpr` and of every jaxpr nested in
+    its equations — a `cond`'s branches only where `enter_cond`."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        if eqn.primitive.name == "cond" and not enter_cond:
+            continue
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names |= _reachable_primitives(inner, enter_cond)
+    return names
+
+
+class TestConditionalPool:
+    """The sampler draws its candidate pool only when a sampled row of
+    the batch asks for top_k or top_p (ISSUE 41)."""
+
+    @pytest.mark.parametrize("seed", range(5))
+    @pytest.mark.parametrize("vocab", [257, 4096])
+    def test_an_unfiltered_batch_is_the_divide_and_the_draw(
+            self, sampler_program, vocab, seed):
+        from theroundtaible_tpu.engine.sampling import sampling_arrays
+        rng = np.random.default_rng(1000 * vocab + seed)
+        logits = jnp.asarray(rng.normal(size=(POOL_ROWS, vocab)) * 3,
+                             jnp.float32)
+        arrays = sampling_arrays(
+            [SamplingParams(temperature=t) for t in POOL_TEMPS])
+        key = jax.random.PRNGKey(seed)
+        got = sampler_program(logits, key, *arrays)
+        assert got.tolist() == _plain_draw(logits, key,
+                                           POOL_TEMPS).tolist()
+
+    @pytest.mark.parametrize("vocab", [257, 4096])
+    @pytest.mark.parametrize("kind", sorted(FILTERED_KINDS))
+    def test_one_filtered_row_among_plain_ones(self, sampler_program,
+                                               kind, vocab):
+        """The filtered row draws what sample_token draws for it, and
+        its batchmates what they drew without it."""
+        from theroundtaible_tpu.engine.sampling import sampling_arrays
+        filtered, spread = FILTERED_KINDS[kind]
+        filtered = SamplingParams(**filtered)
+        rng = np.random.default_rng(vocab + len(kind))
+        rows = rng.normal(size=(POOL_ROWS, vocab)) * 3
+        rows[FILTERED_ROW] *= spread / 3
+        logits = jnp.asarray(rows, jnp.float32)
+        params = [SamplingParams(temperature=t) for t in POOL_TEMPS]
+        params[FILTERED_ROW] = filtered
+        arrays = sampling_arrays(params)
+        for seed in range(6):
+            key = jax.random.PRNGKey(seed)
+            got = sampler_program(logits, key, *arrays).tolist()
+            want = _plain_draw(logits, key, POOL_TEMPS).tolist()
+            want[FILTERED_ROW] = sample_token(
+                logits, key, filtered).tolist()[FILTERED_ROW]
+            assert got == want, seed
+
+    def test_the_pool_stands_inside_the_conditional(self):
+        """No vocabulary-wide top_k, sort or cumsum is reachable from
+        the program's top level but through the cond: an edit that
+        pulls the pool back out fails here, on the CPU."""
+        from theroundtaible_tpu.engine.sampling import (sample_token_batch,
+                                                        sampling_arrays)
+        arrays = sampling_arrays(
+            [SamplingParams(temperature=t) for t in POOL_TEMPS])
+        jaxpr = jax.make_jaxpr(sample_token_batch)(
+            jnp.zeros((POOL_ROWS, 4096), jnp.float32),
+            jax.random.PRNGKey(0), *arrays).jaxpr
+        pool = {"top_k", "sort", "cumsum"}
+        outside = _reachable_primitives(jaxpr, enter_cond=False)
+        assert "cond" in outside and not outside & pool
+        assert pool <= _reachable_primitives(jaxpr, enter_cond=True)
+
+    @pytest.mark.parametrize("mode,rows", [
+        ("greedy", [dict(temperature=0.0), dict(temperature=0.0, top_k=5)]),
+        ("plain", [dict(temperature=0.7), dict(temperature=0.0, top_p=0.5)]),
+        ("sort-free", [dict(temperature=0.7), dict(temperature=0.9,
+                                                   top_p=0.9)]),
+        ("sort", [dict(temperature=0.7, top_k=5),
+                  dict(temperature=1.0, top_k=129)]),
+    ])
+    def test_sampler_mode_names_the_branch(self, mode, rows):
+        from theroundtaible_tpu.engine.sampling import sampler_mode
+        assert sampler_mode([SamplingParams(**r) for r in rows]) == mode
+
+
 class TestKVCacheSlots:
     def test_acquire_release(self):
         cfg = get_model_config("tiny-gemma")

@@ -31,7 +31,8 @@ from . import ENGINE_CONFIG_KEYS, deadlines, faults
 from .kvcache import KVCache
 from .models.common import ModelConfig, forward, param_count, spmd_mesh
 from .models.registry import resolve_model_config
-from .sampling import SamplingParams, sample_token_batch, sampling_arrays
+from .sampling import (SamplingParams, row_filtered, sample_token_batch,
+                       sampling_arrays)
 from .serving_loop import (DECODE_SEGMENT, MAX_PREFILL_CHUNK,
                            PREFILL_BUCKETS, ReplicaGroupPlan,
                            bucket_for as _bucket,
@@ -150,6 +151,8 @@ class InferenceEngine:
         # without fails now.
         self.declines: dict[str, str] = {}
         self._latent_positions = 0
+        self._sampler = {"segments": 0, "filtered_segments": 0,
+                         "filtered_rows": 0}
         if model_cfg.layer_kinds is not None:
             # Without recurrent state everything that addresses pages by
             # id stays on (the leader pass, the prefix cache, offload);
@@ -499,8 +502,10 @@ class InferenceEngine:
             eos — while hungrier rows keep decoding; no recompile per
             config) — except the all-greedy common case, where the
             STATIC greedy flag keeps the hot path a single argmax
-            instead of two full-vocab sorts + softmax + cumsum per token
-            (one extra compiled variant total, not one per config).
+            instead of the sampler's divide and draw over the vocabulary
+            (one extra compiled variant total, not one per config; what
+            a sampled batch's filters cost is decided on the device:
+            sampling.sample_token_batch).
             row_budgets count REMAINING tokens at this segment's start
             (the host loop decrements across segments)."""
             b = first_token.shape[0]
@@ -2679,6 +2684,22 @@ class InferenceEngine:
         telemetry.inc("roundtable_mla_latent_positions_total", n,
                       engine=self.cfg.name)
 
+    def note_sampler_segment(self, filtered_rows: int) -> None:
+        """One scheduled segment and how many of its rows engaged the
+        sampler's filters (sampling.row_filtered: the rows that take
+        sample_token_batch's pool branch on the device). The one writer
+        of `describe()["sampler"]` and its series; the segment span
+        carries its own count."""
+        self._sampler["segments"] += 1
+        if filtered_rows:
+            self._sampler["filtered_segments"] += 1
+            self._sampler["filtered_rows"] += filtered_rows
+            from ..utils import telemetry
+            telemetry.inc(
+                telemetry.SURFACE_BINDINGS["engine_sampler"][
+                    "filtered_rows"],
+                filtered_rows, engine=self.cfg.name)
+
     def mla_describe(self) -> dict[str, Any]:
         """Latent pages and the kernels that read them (models/mla.py,
         engine/paging.py): the second page shape's provenance."""
@@ -3326,7 +3347,9 @@ class InferenceEngine:
             out_np = decode_segments(decode_dispatch, first, cur_valid,
                                      self.tokenizer.eos_id, max_new,
                                      deadline, timeout_s, retry=self.retry,
-                                     budget=dec_budget)
+                                     budget=dec_budget,
+                                     filtered_rows=sum(
+                                         row_filtered(p) for p in per_row))
         stats.decode_seconds = time.monotonic() - t1
         if plan is not None:
             out_np = out_np[plan.pos]
@@ -3450,6 +3473,9 @@ class InferenceEngine:
             info["attention"] = self.attention_describe()
         if self.cfg.latent and self.kv_layout == "paged":
             info["mla"] = self.mla_describe()
+        # ISSUE 41: the scheduler's segments, and those in which a
+        # sampled row's top_k / top_p engaged the candidate pool.
+        info["sampler"] = dict(self._sampler)
         # What this model declined at build time, each with its reason.
         info["declines"] = dict(self.declines)
         # ISSUE 10: multi-LoRA persona provenance — the resolved

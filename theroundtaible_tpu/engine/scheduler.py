@@ -73,7 +73,7 @@ import numpy as np
 from ..utils import telemetry
 from . import deadlines, faults, trace_hooks
 from .kvcache import scoped_slot
-from .sampling import SamplingParams, sampling_arrays
+from .sampling import SamplingParams, row_filtered, sampling_arrays
 from .serving_loop import (DECODE_SEGMENT, RAGGED_BLOCK_Q, RaggedSeq,
                            ReplicaGroupPlan, build_ragged_batch,
                            clamp_max_new, eos_trim, host_sync,
@@ -1608,7 +1608,9 @@ class SessionScheduler:
             steps = self._fold_segment(ctx, arrays)
             self._end_segment(seg, steps, steps * len(alive),
                               in_flight=int(spec_handles is not None),
-                              read_to=tuple(r.valid for r in alive))
+                              read_to=tuple(r.valid for r in alive),
+                              filtered_rows=sum(
+                                  row_filtered(r.sampling) for r in alive))
             now = time.monotonic()
             self._attribute_wall(counts, now - t_prev)
             # Per-phase token split (ISSUE 8): a while-loop segment is
@@ -1668,7 +1670,8 @@ class SessionScheduler:
                      prefill_tokens: int = 0, drafted: int = 0,
                      accepted: int = 0, in_flight: int = 0,
                      read_to: tuple = (),
-                     ragged: Optional[dict] = None) -> None:
+                     ragged: Optional[dict] = None,
+                     filtered_rows: int = 0) -> None:
         """Emit a segment span with the counts its fold produced, and
         the pool's pages in use at its end (the pool's peak over any
         stretch is the maximum over that stretch's segment spans).
@@ -1681,7 +1684,12 @@ class SessionScheduler:
         attention layers differ adds `page_visits_full` and
         `page_visits_window`, by layer class, and what the rows hold
         (`pages_held`, of it `pages_behind_window`), on every kind of
-        segment."""
+        segment. `filtered_rows`: the segment's rows whose top_k or
+        top_p engages the sampler's candidate pool (a ragged batch
+        brings its own count)."""
+        if ragged is not None:
+            filtered_rows = ragged["filtered_rows"]
+        self.engine.note_sampler_segment(filtered_rows)
         latent = None
         if getattr(self.engine.cfg, "latent", False):
             latent = sum(steps * v - steps * (steps - 1) // 2
@@ -1707,7 +1715,7 @@ class SessionScheduler:
             return
         seg.attrs.update(steps=steps, decode_tokens=decode_tokens,
                          prefill_tokens=prefill_tokens, drafted=drafted,
-                         accepted=accepted)
+                         accepted=accepted, filtered_rows=filtered_rows)
         if latent is not None:
             seg.attrs["latent_positions"] = latent
         if ragged is not None and "page_visits" in ragged:

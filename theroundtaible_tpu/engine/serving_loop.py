@@ -18,6 +18,7 @@ import numpy as np
 
 from ..utils import telemetry
 from . import deadlines, faults
+from .sampling import row_filtered
 
 PREFILL_BUCKETS = (64, 128, 256, 512, 1024, 2048)
 MAX_PREFILL_CHUNK = 2048
@@ -406,6 +407,7 @@ def decode_segments(
     timeout_s: float,
     retry=None,
     budget=None,
+    filtered_rows: int = 0,
 ) -> np.ndarray:
     """Segmented decode: one device program per DECODE_SEGMENT tokens with
     host-side timeout/early-exit checks in between (a single XLA program
@@ -417,7 +419,8 @@ def decode_segments(
     valid, done) runs one segment; budget may be a DEVICE scalar, done0
     is the [B] done mask carried ACROSS segments (rows at eos / their
     row budget skip further decode). Returns the concatenated token
-    matrix [B, produced].
+    matrix [B, produced]. `filtered_rows`: how many of the rows engage
+    the sampler's filters (sampling.row_filtered), for the spans.
 
     PIPELINED: the next segment is queued from the previous segment's
     DEVICE outputs (budget decremented and done carried with device
@@ -444,7 +447,8 @@ def decode_segments(
         # "segment" span (ISSUE 5): one per consumed decode segment —
         # the null-span singleton when telemetry is disarmed, so the
         # hot loop pays one module-flag check inside span().
-        with telemetry.span("segment", index=seg_idx, rows=b):
+        with telemetry.span("segment", index=seg_idx, rows=b,
+                            filtered_rows=filtered_rows):
             out, steps, last, valid, done = cur
             budget_dev = budget_dev - steps
             # Speculative queue while the device results are still in
@@ -545,7 +549,9 @@ def build_ragged_batch(seqs: list[RaggedSeq], *, t_budget: int,
     tokens/positions/token_pages/token_offs/token_seq [t_budget],
     per-block seq_of_block/block_qstart [t_budget/8], per-seq
     tables/query_offsets/kv_valid/last_rows/temps/top_ks/top_ps
-    [s_max, ...], `greedy`, and the accounting fields n_seqs/n_tokens.
+    [s_max, ...], `greedy`, and the accounting fields n_seqs/n_tokens/
+    filtered_rows (sequences whose top_k or top_p engages the sampler's
+    candidate pool).
 
     `score_width` > 0 (ISSUE 9, the speculative verify): the dict also
     carries `sample_rows` [s_max, score_width] — for each sequence, the
@@ -662,6 +668,8 @@ def build_ragged_batch(seqs: list[RaggedSeq], *, t_budget: int,
         "last_rows": last_rows, "temps": temps, "top_ks": top_ks,
         "top_ps": top_ps, "token_adapter": token_adapter,
         "greedy": all(s.temperature <= 0.0 for s in seqs),
+        # (sequences that take the sampler's pool: the segment span's)
+        "filtered_rows": sum(row_filtered(s) for s in seqs),
         "n_seqs": len(seqs), "n_tokens": n_tokens,
         "score_width": score_width,
         **({"sample_rows": sample_rows} if sample_rows is not None

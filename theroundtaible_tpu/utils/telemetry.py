@@ -1265,6 +1265,20 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "pages_held": "roundtable_window_pages_held_total",
         "pages_behind_window": "roundtable_window_pages_behind_total",
     },
+    # engine.describe()["sampler"] (ISSUE 41): how often the sampler's
+    # candidate pool engages. The step programs draw it only when a
+    # sampled row of the batch set top_k or top_p (sampling.
+    # sample_token_batch); the scheduler counts such rows on the host
+    # as it ends a segment (engine.note_sampler_segment is the one
+    # writer of the totals and the series; every `segment` span
+    # carries its own `filtered_rows`).
+    "engine_sampler": {
+        "segments": "derived (scheduled segments of every kind; "
+                    "describe-only)",
+        "filtered_segments": "derived (segments with a filtered row; "
+                             "describe-only)",
+        "filtered_rows": "roundtable_sampler_filtered_rows_total",
+    },
     # engine.describe()["ragged"] (ISSUE 8, 32): the ragged seam's
     # provenance. Static but for the dispatch counts and what the
     # dispatches' attention read, in page visits — as the kernel that
