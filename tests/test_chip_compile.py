@@ -926,6 +926,149 @@ def test_hybrid_step_of_the_mellum_cut_compiles(one_chip, monkeypatch,
     assert "grouped_matmul" in hlo
 
 
+# --- power retention: the step kernel and the chunked runs (ISSUE 42) --------
+
+RETENTION_ROWS = 17          # 16 slots and the scratch row
+RETENTION_SNAPS = 15         # 14 snapshots and the scratch one
+
+
+def test_retention_step_kernel_compiles_for_v5e(one_chip, monkeypatch):
+    """The decode step of one retention layer as the cell runs it: 16
+    batch rows scattered over 17 state rows, 8 kv heads, group 5, the
+    laid-out state (65 lane rows of 128 a head), donated. The compiled
+    program keeps no second copy of the state: what it aliases is the
+    two state arrays, and its temporaries are a rounding of them."""
+    from theroundtaible_tpu.engine.models import retention as rmodel
+    from theroundtaible_tpu.engine.pallas import retention as rkernel
+
+    monkeypatch.setattr(rkernel, "_interpret", lambda: False)
+    assert rkernel.decline_reason(D, 5) is None
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    f32, nd = jnp.float32, rmodel.feature_rows(D)
+    assert (nd, rmodel.state_rows(D)) == (65, 8320)
+    compiled = jax.jit(rkernel.retention_step, donate_argnums=(4, 5)).lower(
+        s((DECODE_ROWS, 8, 5, D), f32), s((DECODE_ROWS, 8, D), f32),
+        s((DECODE_ROWS, 8, D), f32), s((DECODE_ROWS, 8), f32),
+        s((RETENTION_ROWS, 8, nd, D, D), f32),
+        s((RETENTION_ROWS, 8, nd, D), f32),
+        s((DECODE_ROWS,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    _assert_kernel(hlo)
+    assert "retention_step" in hlo
+    mem = compiled.memory_analysis()
+    state = RETENTION_ROWS * 8 * nd * D * (D + 1) * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 100
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
+def test_hybrid_step_of_the_brumby_cut_compiles(one_chip, monkeypatch,
+                                                program):
+    """Decode steps in a loop, one ragged join and one prologue chunk of
+    the benchmark's Brumby-14B cut at published widths (one block: a
+    retention layer and its MLP, the whole vocabulary), with NO pool,
+    the slot states and the snapshot store donated. The state is 584 MB
+    a layer: the programs must update it in place, so what they keep
+    beside their arguments stays under a layer's state (phi(Q) of one
+    128-token chunk is 170 MB of it)."""
+    from theroundtaible_tpu.engine.models import hybrid
+    from theroundtaible_tpu.engine.models.common import init_params
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+    from theroundtaible_tpu.engine.pallas import retention as rkernel
+    from theroundtaible_tpu.engine.paged_forward import (
+        forward_paged_hybrid, forward_ragged_hybrid)
+    from theroundtaible_tpu.engine.serving_loop import (RaggedSeq,
+                                                        build_ragged_batch)
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(rkernel, "_interpret", lambda: False)
+    cfg = dataclasses.replace(
+        get_model_config("brumby-14b"), num_layers=2,
+        layer_kinds=(hybrid.RETENTION, hybrid.MLP), attn_impl="flash")
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    params = placed(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    assert params["layers"][0]["g_proj"].shape == (5120, 8)
+    state = placed(jax.eval_shape(
+        lambda: hybrid.zero_state(cfg, RETENTION_ROWS)))
+    snaps = placed(jax.eval_shape(
+        lambda: hybrid.zero_state(cfg, RETENTION_SNAPS)))
+    layer_state = RETENTION_ROWS * 8 * 65 * D * (D + 1) * 4
+    if program == "decode":
+        def step(params, state, tokens, positions, table, valid, active,
+                 rows):
+            def body(i, carry):
+                st, tok = carry
+                logits, _p, st, _c, _n = forward_paged_hybrid(
+                    params, cfg, tok, positions + i, [], table, valid, st,
+                    active=active, page_size=PAGE, rows=rows)
+                return st, jnp.argmax(logits[:, 0], -1)[:, None].astype(i32)
+            return jax.lax.fori_loop(0, 4, body, (state, tokens))
+
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            params, state, s((DECODE_ROWS, 1), i32),
+            s((DECODE_ROWS, 1), i32), s((DECODE_ROWS, PAGES_PER_SEQ), i32),
+            s((DECODE_ROWS,), i32), s((DECODE_ROWS,), jnp.bool_),
+            s((DECODE_ROWS,), i32)).compile()
+        _assert_kernel(compiled.as_text())
+    elif program == "prefill":
+        rows, t = 4, 1024
+
+        def step(params, state, snaps, tokens, offsets, lengths, table,
+                 at, cap_len, snap_idx):
+            positions = offsets[:, None] + jnp.arange(t)[None]
+            logits, _p, st, cap, _n = forward_paged_hybrid(
+                params, cfg, tokens, positions, [], table,
+                offsets + lengths, state, lengths=lengths, cap_len=cap_len,
+                last_pos=jnp.maximum(lengths - 1, 0), page_size=PAGE,
+                rows=at, snaps=snaps, snap_idx=snap_idx)
+            return logits, st, cap
+
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            params, state, snaps, s((rows, t), i32), s((rows,), i32),
+            s((rows,), i32), s((rows, PAGES_PER_SEQ), i32), s((rows,), i32),
+            s((rows,), i32), s((rows,), i32)).compile()
+    else:
+        table = np.zeros((PAGES_PER_SEQ,), np.int32)
+        b = build_ragged_batch(
+            [RaggedSeq([5] * 150, 100, table), RaggedSeq([7], 300, table)],
+            t_budget=1536, s_max=RETENTION_ROWS,     # the cell's buffer
+            pages_per_seq=PAGES_PER_SEQ, scratch_page=0, pad_id=0,
+            page_size=PAGE)
+        names = ("tokens", "positions", "tables", "seq_of_block",
+                 "block_qstart", "query_offsets", "kv_valid",
+                 "token_pages", "token_offs", "token_seq", "last_rows")
+
+        def step(params, state, snaps, seq_slot, cap_n, snap_idx, *arrays):
+            kw = dict(zip(names, arrays))
+            logits, _p, st, cap, _n = forward_ragged_hybrid(
+                params, cfg, kw["tokens"], kw["positions"], [],
+                kw["tables"], kw["seq_of_block"], kw["block_qstart"],
+                kw["query_offsets"], kw["kv_valid"], kw["token_pages"],
+                kw["token_offs"], kw["token_seq"], kw["last_rows"], state,
+                seq_slot, cap_n, page_size=PAGE, snaps=snaps,
+                snap_idx=snap_idx)
+            return logits, st, cap
+
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            params, state, snaps, s((RETENTION_ROWS,), i32),
+            s((RETENTION_ROWS,), i32), s((RETENTION_ROWS,), i32),
+            *[s(np.asarray(b[n]).shape, i32) for n in names]).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= layer_state
+    assert mem.temp_size_in_bytes < 1.25 * layer_state, (
+        f"{program}: {mem.temp_size_in_bytes / 1e6:.0f} MB of temporaries "
+        f"beside a state of {layer_state / 1e6:.0f} MB a layer")
+
+
 # --- the int4 kernels the compiler refuses --------------------------------
 #
 # Shapes below come from a real Int4Leaf (quant.quantize_params on a

@@ -937,3 +937,29 @@ class TestLoopClock:
         assert any(s["t0"] <= seg["t0"] + seg["dur_s"]
                    and seg["t0"] <= s["t0"] + s["dur_s"]
                    for s in sync for seg in segments)
+
+
+def test_warm_heap_leaves_the_collectors_sight_until_close(shared_engine):
+    """declare_warmup_complete freezes what warm-up built (a full
+    collection under traffic then walks traffic's objects alone: the
+    chip lost 0.22 s of a window to one, PERF.md Findings PR 42), and
+    close() hands it back so a rebuilt engine's predecessor can go."""
+    import gc
+    sched = SessionScheduler(shared_engine, max_rows=2)
+    try:
+        alive = len(gc.get_objects())
+        sched.declare_warmup_complete()
+        frozen = gc.get_freeze_count()
+        assert frozen > alive // 2
+        # Nothing frozen is walked: a full collection sees what came
+        # after, and a cycle made now is still collected.
+        assert len(gc.get_objects()) < frozen // 10
+        loop = []
+        loop.append(loop)
+        del loop
+        assert gc.collect() >= 1
+        # (a frozen object freed by its reference count leaves the count)
+        assert gc.get_freeze_count() > frozen // 2
+    finally:
+        sched.close()
+    assert gc.get_freeze_count() == 0

@@ -156,7 +156,7 @@ OTHER_VALUE = {
     "kv_layout": "paged", "page_size": 64, "num_pages": 33,
     "quant": "int8", "dcn_axis": "data", "prefix_cache": False,
     "prefix_cache_pages": 16, "kv_offload": False, "ragged_attn": False,
-    "spec_decode": False, "spec_max_draft": 2,
+    "ragged_tokens": 1536, "spec_decode": False, "spec_max_draft": 2,
     "lora": {"rank": 4, "max_adapters": 2}, "kv_quant": "int8",
     "state_snapshot_bytes": 0,
 }

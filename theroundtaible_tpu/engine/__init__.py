@@ -101,8 +101,9 @@ ENGINE_CONFIG_KEYS = (
     "seq_parallel", "long_scheme", "long_threshold", "devices", "attn",
     "num_slots", "sampling", "seed", "kv_layout", "page_size",
     "num_pages", "quant", "dcn_axis", "prefix_cache",
-    "prefix_cache_pages", "kv_offload", "ragged_attn", "spec_decode",
-    "spec_max_draft", "lora", "kv_quant", "state_snapshot_bytes")
+    "prefix_cache_pages", "kv_offload", "ragged_attn", "ragged_tokens",
+    "spec_decode", "spec_max_draft", "lora", "kv_quant",
+    "state_snapshot_bytes")
 
 
 def _cache_key(config: dict[str, Any]) -> str:

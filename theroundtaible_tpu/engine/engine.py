@@ -114,6 +114,7 @@ class InferenceEngine:
                  prefix_cache_pages: Optional[int] = None,
                  kv_offload: Optional[bool] = None,
                  ragged_attn: Optional[bool] = None,
+                 ragged_tokens: Optional[int] = None,
                  spec_decode: Optional[bool] = None,
                  spec_max_draft: Optional[int] = None,
                  lora: Optional[dict] = None,
@@ -208,6 +209,20 @@ class InferenceEngine:
                     model_cfg.embed_dim, model_cfg.expert_dim, dtype)
                 if reason is not None:
                     self.declines["grouped_product"] = reason
+            if model_cfg.retention_layers:
+                # The decode step of a retention layer: one Pallas pass
+                # over a state, or the jax.numpy recurrence where that
+                # declines — one rule, pallas/retention.py. What else a
+                # state of this size rules out is said here too.
+                from .pallas import retention as pret
+                reason = pret.decline_reason(model_cfg.head_dim,
+                                             model_cfg.kv_repeat)
+                if reason is not None:
+                    self.declines["retention_step"] = reason
+                self.declines["leader_state_handover"] = (
+                    f"{why}:a-state-is-copied-whole-not-shared")
+                self.declines["evacuation"] = (
+                    f"{why}:no-host-copy-of-a-state")
         self.max_seq_len = model_cfg.max_seq_len
         self.sampling = sampling or SamplingParams()
         self.tokenizer = load_tokenizer(checkpoint or None)
@@ -912,6 +927,7 @@ class InferenceEngine:
         # arguments)
         self._ragged_visits = [0, 0]
         self._ragged_pool_shape: tuple = ()
+        self.joins_ragged_alone = False
         # ... and what every segment's attention read by layer class,
         # for a model whose attention layers differ (attn_layers)
         self._window_reads = {"page_visits_full": 0,
@@ -936,7 +952,8 @@ class InferenceEngine:
                 from .serving_loop import (ragged_defer_min,
                                            ragged_shape_grid)
                 self.ragged_enabled = True
-                self.ragged_tokens = ragged_token_budget(num_slots)
+                self.ragged_tokens = ragged_token_budget(
+                    num_slots, int(ragged_tokens or 0))
                 self.ragged_shapes = ragged_shape_grid(self.ragged_tokens)
                 self.ragged_defer_min = ragged_defer_min()
                 if attn == "dense":
@@ -974,6 +991,20 @@ class InferenceEngine:
                 self.ragged_path = ("pallas_ragged" if decline is None
                                     else "xla_ragged")
                 self.ragged_fallback_reason = decline
+                if model_cfg.retention_layers and decline is None:
+                    # State on the slot arrays: a prologue's [B, T]
+                    # program is the ragged program's chunked runs again
+                    # (the same kernel a page of a run), at a shape a
+                    # batch size a bucket — nothing a join gains, and a
+                    # compile each: 35 s of a warm set-up, more of a
+                    # cold one, 9.3 s inside a window at a bucket no
+                    # warm-up met (PERF.md, PR 42). So the scheduler's
+                    # joins take the ragged program whatever the batch
+                    # holds and however few tokens they bring; the
+                    # prologue serves generate_batch, which no scheduler
+                    # stands behind.
+                    self.joins_ragged_alone = True
+                    self.ragged_defer_min = 0
                 if decline is not None and model_cfg.attn_layers:
                     # one of the layers' geometries does not fit
                     self.declines["ragged_kernel"] = decline
@@ -1215,21 +1246,25 @@ class InferenceEngine:
         self.hybrid = HybridStateStore(
             cfg, num_slots, page_size, state_snapshot_bytes,
             engine=cfg.name)
+        from .models.hybrid import ROW_PARTS
+
+        # ROW_PARTS are gathered to the batch's rows and scattered back;
+        # the others (hybrid.SLOT_PARTS) ride whole: their layers
+        # address the slot array by row and write captures into the
+        # store themselves.
         def rows_of(state, rows):
-            return {"ssm": [a[rows] for a in state["ssm"]],
-                    "conv": [a[rows] for a in state["conv"]]}
+            return {p: [a[rows] for a in v] if p in ROW_PARTS else v
+                    for p, v in state.items()}
 
         def put_rows(state, rows, new):
-            return {
-                "ssm": [a.at[rows].set(n)
-                        for a, n in zip(state["ssm"], new["ssm"])],
-                "conv": [a.at[rows].set(n)
-                         for a, n in zip(state["conv"], new["conv"])]}
+            return {p: [a.at[rows].set(n) for a, n in zip(v, new[p])]
+                    if p in ROW_PARTS else new[p]
+                    for p, v in state.items()}
 
         def put_snaps(snaps, idx, cap):
-            return {part: [s.at[idx].set(c)
-                           for s, c in zip(snaps[part], cap[part])]
-                    for part in ("ssm", "conv")}
+            return {p: [s.at[idx].set(c) for s, c in zip(v, cap[p])]
+                    if p in ROW_PARTS else cap[p]
+                    for p, v in snaps.items()}
 
         @partial(jax.jit, donate_argnums=(1, 2, 3))
         def prefill_step_hybrid(params, pools, state, snaps, tables,
@@ -1245,7 +1280,9 @@ class InferenceEngine:
                     params, cfg, tokens, positions, pools, tables,
                     offsets + lengths, rows_of(state, rows),
                     lengths=lengths, cap_len=cap_len,
-                    last_pos=jnp.maximum(lengths - 1, 0))
+                    last_pos=jnp.maximum(lengths - 1, 0),
+                    page_size=page_size, rows=rows, snaps=snaps,
+                    snap_idx=snap_idx)
                 return (host_read(logits[:, 0]), new_pools,
                         put_rows(state, rows, new),
                         put_snaps(snaps, snap_idx, cap), host_read(counts))
@@ -1264,7 +1301,8 @@ class InferenceEngine:
                 pools_c, st, counts = caches
                 logits, pools_c, st, _cap, c = forward_paged_hybrid(
                     params, cfg, last[:, None], valid[:, None], pools_c,
-                    tables, valid + 1, st, active=active)
+                    tables, valid + 1, st, active=active,
+                    page_size=page_size, rows=rows)
                 return logits, (pools_c, st, counts + c)
 
             out, step, last, valid, done, caches = decode_while(
@@ -1294,7 +1332,8 @@ class InferenceEngine:
                         seq_of_block, block_qstart, query_offsets,
                         kv_valid, token_pages, token_offs, token_seq,
                         last_rows, state, seq_slot, cap_n,
-                        attn_path=attn_path)
+                        attn_path=attn_path, page_size=page_size,
+                        snaps=snaps, snap_idx=snap_idx)
                 lf = logits.astype(jnp.float32)
                 if greedy:
                     nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
@@ -1486,6 +1525,7 @@ class InferenceEngine:
                                 else None),
             kv_offload=cfg.get("kv_offload"),
             ragged_attn=cfg.get("ragged_attn"),
+            ragged_tokens=cfg.get("ragged_tokens"),
             spec_decode=cfg.get("spec_decode"),
             # `is not None`, not truthiness: spec_max_draft: 0 must
             # surface the constructor's ValueError, not silently run
@@ -2761,7 +2801,9 @@ class InferenceEngine:
                 self.kv._trim_pages(slot, start)
                 offsets[i] = start
             plans.append((name, source, snap))
+        before = hy.copy_bytes["restore"]
         hy.attach(plans, self._live_slots())
+        out["state_copy_bytes"] = hy.copy_bytes["restore"] - before
         out["rows"] = [hy.row_of(n) for n in names]
         return out
 
@@ -3469,6 +3511,18 @@ class InferenceEngine:
                                "ragged_dot" if "grouped_product"
                                in self.declines else "kernel"),
                            **self.hybrid.moe_totals()}
+        if self.cfg.retention_layers:
+            from .models import retention
+            d = self.cfg.head_dim
+            info["retention"] = {
+                "power": retention.POWER,
+                "layers": len(self.cfg.retention_layers),
+                "state_rows": retention.state_rows(d),
+                "state_rows_min": retention.state_rows_min(d),
+                "bytes_per_state": retention.bytes_per_state(self.cfg),
+                "kernel": ("jnp" if "retention_step" in self.declines
+                           else "retention_step"),
+            }
         if self.cfg.attn_layers is not None and self.kv_layout == "paged":
             info["attention"] = self.attention_describe()
         if self.cfg.latent and self.kv_layout == "paged":

@@ -44,6 +44,8 @@ def estimate_param_count(cfg: ModelConfig) -> int:
                 * cfg.routed_experts),
             "attention": 2 * e * h * d + 2 * e * k * d,
             "mlp": 3 * e * f,
+            # q, k, v, o, one gate a kv head, the two head norms
+            "retention": 2 * e * h * d + 2 * e * k * d + e * k + 2 * d,
         }
         if cfg.latent:
             r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank

@@ -1,14 +1,20 @@
 """Recurrent state beside paged KV: the second kind of cache.
 
-A model with Mamba-2 layers (`ModelConfig.recurrent`) keeps, for every
-sequence, a state that is not pages: per Mamba-2 layer the SSM state
-`[H, P, N]` float32 and the last K-1 inputs of the causal conv. Pages
+A model with recurrent layers (`ModelConfig.recurrent`) keeps, for every
+sequence, a state that is not pages — whatever parts `hybrid.zero_state`
+gives its layers: per Mamba-2 layer the SSM state `[H, P, N]` float32
+and the last K-1 inputs of the causal conv; per retention layer
+(models/retention.py) the feature-map state and its normaliser, 34 MB a
+layer a sequence at published widths. Pages
 can be truncated to any prefix and shared by reference; a state is valid
 at exactly ONE position — the number of tokens it has consumed — and can
-only be copied whole. This module owns both halves of that:
+only be copied whole. This module owns both halves of that, and knows
+the parts only as small ones a program gathers by row
+(`hybrid.ROW_PARTS`) and large ones updated in place on the slot array
+(`hybrid.SLOT_PARTS`):
 
-- **Slot states** — one row a knight slot (`state["ssm"][l][row]`,
-  `state["conv"][l][row]`; the last row is scratch, where pad rows of a
+- **Slot states** — one row a knight slot (`state[part][l][row]`; the
+  last row is scratch, where pad rows of a
   batch land). The tree is donated through the engine's step programs
   beside the page pools. What the expert layers counted in a dispatch
   (experts hit, assignments to held experts, expert-layer steps) comes
@@ -21,7 +27,10 @@ only be copied whole. This module owns both halves of that:
   another session's — whose prompt starts with those pages can start
   from it). Snapshots are taken where the chunked scan yields them for
   one more small product: the last page boundary a prefill chunk or a
-  ragged join crosses. They are bound to the radix node of their page
+  ragged join crosses — and, for a prompt whose pages were cached and
+  for which no state stood anywhere (a new session behind the preamble
+  every session opens with), the end of that cached span, once: the
+  boundary the next such prompt starts from. They are bound to the radix node of their page
   when the slot commits (prefix_cache.insert) and dropped with that
   node; unbound ones age out LRU under the byte budget.
 
@@ -31,7 +40,14 @@ prompt extends exactly what it consumed, (ii) else the deepest snapshot
 at a page boundary inside the matched pages, (iii) else zero. The
 caller lowers the row's KV offset to that position (attention layers
 re-write their few pages from there) and the state is copied in before
-the first dispatch.
+the first dispatch. A model whose layers are ALL recurrent has an empty
+pool tree: its pages are ids that hold no bytes, and everything above
+reads them as it does for every model.
+
+What a restore wrote into slot rows and what the programs' captures
+wrote into the store are counted in bytes (`copy_bytes`, one writer:
+`_note_copy`): at 206 MB a state they are device work an admission
+waits for.
 
 A model with `layer_kinds` and NO recurrent layer (`axk1`: attention,
 MLP and expert layers) runs the same step programs with an EMPTY state
@@ -103,21 +119,43 @@ class HybridStateStore:
         self.continued_tokens = self.reused_tokens = 0
         self.rescanned_tokens = 0
         self.share_declined = 0
+        self.copy_bytes = {"restore": 0, "capture": 0}
 
         @partial(jax.jit, donate_argnums=(0,))
-        def restore(state, snaps, dst_rows, src_snaps, zero):
-            # state[dst] = zero ? 0 : snaps[src]; unused lanes copy the
-            # scratch snapshot onto the scratch row.
-            out = dict(state)
-            for part in ("ssm", "conv"):
-                out[part] = [
-                    a.at[dst_rows].set(jnp.where(
-                        zero.reshape((-1,) + (1,) * (a.ndim - 1)),
-                        0.0, s[src_snaps]))
-                    for a, s in zip(state[part], snaps[part])]
+        def restore(state, snaps, dst_rows, src_snaps, zero, n):
+            # state[dst] = zero ? 0 : snaps[src], over whatever parts the
+            # tree has. Small parts in one scatter: unused lanes copy
+            # the scratch snapshot onto the scratch row. Large parts a
+            # lane at a time, the first `n` lanes only, in place: a
+            # gather of every lane would stand beside the state.
+            out = {part: [
+                a.at[dst_rows].set(jnp.where(
+                    zero.reshape((-1,) + (1,) * (a.ndim - 1)),
+                    0.0, s[src_snaps]))
+                for a, s in zip(state[part], snaps[part])]
+                for part in state if part in hybrid.ROW_PARTS}
+            slot_parts = [p for p in state if p in hybrid.SLOT_PARTS]
+            large = [a for part in slot_parts for a in state[part]]
+            held = [s for part in slot_parts for s in snaps[part]]
+
+            def lane(i, arrays):
+                return [jax.lax.dynamic_update_index_in_dim(
+                    a, jnp.where(zero[i], 0.0,
+                                 jax.lax.dynamic_index_in_dim(
+                                     s, src_snaps[i], 0, keepdims=False)),
+                    dst_rows[i], 0) for a, s in zip(arrays, held)]
+
+            if large:
+                large = jax.lax.fori_loop(0, n, lane, large)
+            it = iter(large)
+            for part in slot_parts:
+                out[part] = [next(it) for _ in state[part]]
             return out
 
         self._restore = restore
+        # Per slot: the page-aligned end of a span the pages held and no
+        # state did (plan), until a run of the slot crosses it.
+        self._shared_to: dict[str, int] = {}
 
     def _alloc(self) -> None:
         self.state: dict[str, Any] = hybrid.zero_state(
@@ -186,7 +224,8 @@ class HybridStateStore:
         return {row: name for name, row in self._row_of.items()}
 
     def forget(self, name: str) -> None:
-        for table in (self._row_of, self._consumed, self._keys):
+        for table in (self._row_of, self._consumed, self._keys,
+                      self._shared_to):
             table.pop(name, None)
 
     def forget_all(self) -> None:
@@ -220,6 +259,12 @@ class HybridStateStore:
                 and tokens[:len(own)] == own):
             best, source, snap = len(own), CONTINUE, None
         self._consumed[name] = None
+        # Pages matched and no state at all: another sequence shares
+        # that span (a preamble every session opens with), and the scan
+        # from zero is about to cross its end — the boundary the next
+        # such prompt needs (capture_slot takes it once).
+        self._shared_to[name] = (cap // self.page_size * self.page_size
+                                 if source == ZERO else 0)
         if source == ZERO:
             self.misses += 1
         else:
@@ -260,7 +305,17 @@ class HybridStateStore:
                     zero[i] = True
             self.state = self._restore(
                 self.state, self.snaps, jnp.asarray(dst),
-                jnp.asarray(src), jnp.asarray(zero))
+                jnp.asarray(src), jnp.asarray(zero),
+                jnp.int32(len(part)))
+        self._note_copy("restore", len(todo))
+
+    def _note_copy(self, cause: str, states: int) -> None:
+        """`states` whole states written: into slot rows by a restore,
+        or into the store by the programs' captures."""
+        n = states * self.bytes_per_state
+        self.copy_bytes[cause] += n
+        telemetry.inc("roundtable_state_copy_bytes_total", n,
+                      engine=self.engine, cause=cause)
 
     def on_commit(self, name: str, tokens: list[int], exact: bool) -> None:
         """The slot committed `tokens`. `exact`: its state has consumed
@@ -275,13 +330,26 @@ class HybridStateStore:
                      ) -> tuple[int, int, Optional[bytes]]:
         """For a dispatch that feeds slot `name` tokens [start, start +
         n): -> (cap_len, snapshot index, key) — after how many of them
-        the state stands at the last page boundary the run crosses, and
-        where the program stores it; (0, scratch, None) when there is
-        none, it is already held, or the prompt is unknown. A dispatch
-        that fails drops the keys it reserved (`drop`)."""
+        the state stands at the last page boundary the run crosses (or
+        at the end of a span `plan` found held by the pages and by no
+        state, the once it is crossed), and where the program stores
+        it; (0, scratch, None) when there is none, it is already held,
+        or the prompt is unknown. A dispatch that fails drops the keys
+        it reserved (`drop`)."""
         ps = self.page_size
         b = (start + n_tokens) // ps * ps
         keys = self._keys.get(name)
+        shared = self._shared_to.get(name, 0)
+        if 0 < shared <= start + n_tokens:
+            # The run crosses the end of a span that was re-scanned from
+            # zero though its pages were held: that boundary, if no run
+            # before this one took it (its siblings, behind it in the
+            # same admission, take the last one as ever).
+            self._shared_to[name] = 0
+            if (start < shared and keys is not None
+                    and shared // ps <= len(keys)
+                    and keys[shared // ps - 1] not in self._snap):
+                b = shared
         if (self.capacity == 0 or b <= start or keys is None
                 or b // ps > len(keys) or name.startswith("__warmup_")):
             return 0, self.scratch_snap, None
@@ -296,6 +364,7 @@ class HybridStateStore:
         self.snapshots_taken += 1
         telemetry.inc("roundtable_state_snapshots_total",
                       engine=self.engine)
+        self._note_copy("capture", 1)
         self._publish()
         return b - start, idx, key
 
@@ -392,4 +461,6 @@ class HybridStateStore:
             "reused_tokens": self.reused_tokens,
             "rescanned_tokens": self.rescanned_tokens,
             "share_declined": self.share_declined,
+            "restore_bytes": self.copy_bytes["restore"],
+            "capture_bytes": self.copy_bytes["capture"],
         }

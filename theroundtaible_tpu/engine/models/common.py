@@ -198,6 +198,9 @@ class ModelConfig:
     rotary_dim: int = 0
     rope_attention_factor: Optional[float] = None
     attn_gate: bool = False
+    # RMS norm of every q and k head (weights `q_norm`, `k_norm`
+    # [head_dim]) ahead of the rotary embedding.
+    qk_norm: bool = False
     # Attention layers that differ from one another: one AttnLayer an
     # attention layer, in order (`attention_layers`), each overriding
     # num_heads, sliding_window and the rotary fields above for its
@@ -244,7 +247,12 @@ class ModelConfig:
     @property
     def recurrent(self) -> bool:
         """Some layer keeps state that is not pages."""
-        return bool(self._layers_of("mamba2"))
+        return bool(self._layers_of("mamba2")
+                    or self._layers_of("retention"))
+
+    @property
+    def retention_layers(self) -> tuple[int, ...]:
+        return self._layers_of("retention")
 
     @property
     def mamba_layers(self) -> tuple[int, ...]:
@@ -576,6 +584,9 @@ def project_qkv(
         k = k + layer["k_bias"].astype(jnp.float32)
         v = v + layer["v_bias"].astype(jnp.float32)
 
+    if cfg.qk_norm:
+        q = rms_norm(q, layer["q_norm"], cfg.norm_eps, False)
+        k = rms_norm(k, layer["k_norm"], cfg.norm_eps, False)
     if cfg.rope:
         q = rope_heads(q.astype(x.dtype), positions, cfg)
         k = rope_heads(k.astype(x.dtype), positions, cfg)
@@ -850,6 +861,10 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
             out, _, _ = hybrid.mamba2_prefill(
                 h, layer, cfg, zero["ssm"][0], zero["conv"][0],
                 kv_valid_len)
+        elif kind == hybrid.RETENTION:
+            out = hybrid.retention.retention_prefill(
+                h, layer, cfg, positions, zero["ret"][0], zero["retn"][0],
+                jnp.arange(b), kv_valid_len, hybrid.RETENTION_CHUNK)[0]
         elif kind == hybrid.EXPERTS:
             out, _ = hybrid.experts_mlp(h, layer, cfg)
         elif kind == hybrid.MLP:
@@ -892,13 +907,19 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         scale = cfg.embed_dim ** -0.5
         # The embedding at unit rms: the residual stream the mixers add
         # their share to (hybrid.RESIDUAL_SHARE).
+        embedding = jax.random.normal(
+            k_embed, (cfg.vocab_size, cfg.embed_dim),
+            jnp.float32).astype(dtype)
+        if cfg.retention_layers:
+            # The channel a bias-free gate reads its level from
+            # (hybrid.init_layer): the same for every token.
+            embedding = embedding.at[:, hybrid.GATE_CHANNEL].set(1.0)
         return {
-            "embedding": jax.random.normal(
-                k_embed, (cfg.vocab_size, cfg.embed_dim),
-                jnp.float32).astype(dtype),
+            "embedding": embedding,
             "layers": [hybrid.init_layer(
                 cfg.attention_layer(cfg.attention_layers.index(i))
-                if kind == hybrid.ATTENTION else cfg, kind, lk, dtype)
+                if kind == hybrid.ATTENTION else cfg, kind, lk, dtype,
+                depth=cfg.layer_kinds[:i].count(hybrid.RETENTION))
                 for i, (kind, lk) in enumerate(zip(cfg.layer_kinds,
                                                    keys))],
             "final_norm": jnp.ones((cfg.embed_dim,), dtype),

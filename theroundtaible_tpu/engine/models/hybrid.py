@@ -5,7 +5,10 @@ which every layer is ONE mixer behind one RMSNorm and a residual
 latent pages, `models/mla.py`) and the paged kernels; a plain MLP layer
 reuses `common.mlp`. A pre-norm block of attention and MLP, each behind
 its own norm, is two such layers (`axk1`: attention + mlp, then
-attention + experts): one wiring for both families.
+attention + experts): one wiring for both families. A power-retention
+layer (`retention`, models/retention.py: a feature-map state and no keys
+or values) is a fourth kind of mixer behind the same norm; a model whose
+mixers are all of that kind has no attention layer and so no page pool.
 
 Mamba-2 (state-space duality form). Per head, with state S in
 R^{P x N} kept in float32:
@@ -57,9 +60,18 @@ import jax
 import jax.numpy as jnp
 
 from ..pallas import grouped
+from . import retention
 from .common import ModelConfig, Params, _einsum, rms_norm
 
 MAMBA2, EXPERTS, ATTENTION, MLP = "mamba2", "experts", "attention", "mlp"
+RETENTION = "retention"
+# State parts gathered to the batch's rows and scattered back by a step
+# program (small), and parts a layer updates in place on the whole slot
+# array (models/retention.py: 34 MB a row a layer).
+ROW_PARTS, SLOT_PARTS = ("ssm", "conv"), ("ret", "retn")
+# The chunk of a retention layer where no page size says it (the
+# whole-sequence forward): the serving paths chunk by the page.
+RETENTION_CHUNK = 128
 PATTERN_LETTERS = {"M": MAMBA2, "E": EXPERTS, "*": ATTENTION}
 
 
@@ -383,6 +395,7 @@ def ragged_meta(positions, token_seq, query_offsets, kv_valid, last_rows,
         "token_valid": run_idx < seq_len[token_seq],
         "seq_slot": seq_slot, "seq_len": seq_len,
         "seq_start": last_rows - (seq_len - 1),
+        "seq_pos0": query_offsets,
         "block_slot": seq_slot[seq_of_block],
         "seq_of_block": seq_of_block, "block_qstart": block_qstart,
         "cap_n": cap_n,
@@ -552,20 +565,42 @@ def layer_norm_in(x: jax.Array, layer: Params, cfg: ModelConfig):
 # init, 1/sqrt(2 x 52)) the worst of 4608 positions lay 0.22 sigma off,
 # at 0.07 the worst of 2304 lay 0.07 off.
 RESIDUAL_SHARE = 0.07
+# A model whose mixers are retention layers has no router for rounding
+# to tip, and at RESIDUAL_SHARE its logits are the token's own
+# embedding: six retention layers moved the served token by hundredths
+# of a sigma, so no comparison of tokens could tell a wrong state, a
+# gate left out or a lower precision from a sound run (PERF.md, PR 42).
+# There EVERY out-projection (o_proj, down_proj) is at RETENTION_SHARE
+# of unit scale: the mixers carry the stream (after six published layers
+# 97 % of its mean square is theirs), whose mean square then grows by
+# about RETENTION_GROWTH a published layer.
+RETENTION_SHARE, RETENTION_GROWTH = 3.0, 4.5
+# The seeded gate of a retention layer (init_layer): the embedding
+# channel held at 1.0 (no out-projection writes to it), W_g's row there
+# over the kv heads, and the scale of its other rows (of unit scale).
+GATE_CHANNEL, GATE_LEVELS, GATE_NOISE = 0, (4.0, 6.5), 0.25
 
 
 def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
-               dtype) -> Params:
+               dtype, depth: int = 0) -> Params:
     """Random weights of one layer, by kind: in-projections at the
     scale that keeps activations of order one, out-projections at
-    RESIDUAL_SHARE of it. An attention layer is given ITS view of the
-    config (ModelConfig.attention_layer): its own head count."""
+    RESIDUAL_SHARE of it (RETENTION_SHARE in a model with retention
+    layers; `depth` counts the retention layers ahead of this one). An
+    attention layer is given ITS view of the config
+    (ModelConfig.attention_layer): its own head count."""
     e = cfg.embed_dim
     ks = jax.random.split(key, 8)
 
     def dense(key, shape, fan_in, share=1.0):
         return (jax.random.normal(key, shape, jnp.float32)
                 * (share * fan_in ** -0.5)).astype(dtype)
+
+    def out(key, shape, fan_in):
+        if not cfg.retention_layers:
+            return dense(key, shape, fan_in, RESIDUAL_SHARE)
+        return dense(key, shape, fan_in, RETENTION_SHARE).at[
+            ..., GATE_CHANNEL].set(0)
 
     layer: Params = {"norm": jnp.ones((e,), dtype)}
     if kind == MAMBA2:
@@ -614,7 +649,30 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
         layer.update({
             "gate_proj": dense(ks[0], (e, f), e),
             "up_proj": dense(ks[1], (e, f), e),
-            "down_proj": dense(ks[2], (f, e), f, RESIDUAL_SHARE),
+            "down_proj": out(ks[2], (f, e), f),
+        })
+    elif kind == RETENTION:
+        h_, k_, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        # A gate without a bias reads its level from the one channel
+        # the embedding holds constant (common.init_params): g =
+        # sigmoid(level + noise) with the levels GATE_LEVELS over the kv
+        # heads, half-lives of tens to hundreds of tokens. A random W_g
+        # on zero-mean inputs gives g near 0.5: a memory of a few
+        # tokens, under which no comparison could see the state. The
+        # layer's norm divides that channel by the stream's rms, so the
+        # row is written times what the rms has grown to at this depth.
+        g_proj = dense(ks[4], (e, k_), e, GATE_NOISE).astype(jnp.float32)
+        g_proj = g_proj.at[GATE_CHANNEL].set(
+            jnp.linspace(*GATE_LEVELS, k_)
+            * (1.0 + RETENTION_GROWTH * depth) ** 0.5).astype(dtype)
+        layer.update({
+            "q_proj": dense(ks[0], (e, h_, d), e),
+            "k_proj": dense(ks[1], (e, k_, d), e),
+            "v_proj": dense(ks[2], (e, k_, d), e),
+            "g_proj": g_proj,
+            "q_norm": jnp.ones((d,), dtype),
+            "k_norm": jnp.ones((d,), dtype),
+            "o_proj": out(ks[3], (h_, d, e), h_ * d),
         })
     elif kind == ATTENTION and cfg.latent:
         h_, r_q, r_kv = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
@@ -644,8 +702,11 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
 
 
 def zero_state(cfg: ModelConfig, rows: int) -> dict:
-    """The recurrent state of `rows` sequences, one entry a Mamba-2
-    layer: {"ssm": [[rows,H,P,N] f32...], "conv": [[rows,K-1,C] f32...]}."""
+    """The recurrent state of `rows` sequences, float32, one entry a
+    layer that keeps one. Mamba-2: {"ssm": [[rows,H,P,N]...], "conv":
+    [[rows,K-1,C]...]}; retention (models/retention.py), where the
+    model has such layers: {"ret": [[rows,K,D/2+1,D,D]...], "retn":
+    [[rows,K,D/2+1,D]...]}."""
     n = len(cfg.mamba_layers)
     return {
         "ssm": [jnp.zeros((rows, cfg.mamba_heads, cfg.mamba_head_dim,
@@ -653,10 +714,14 @@ def zero_state(cfg: ModelConfig, rows: int) -> dict:
         "conv": [jnp.zeros((rows, cfg.conv_kernel - 1,
                             cfg.mamba_conv_dim), jnp.float32)
                  for _ in range(n)],
+        # (a part only where some layer keeps it)
+        **(retention.zero_state(cfg, rows) if cfg.retention_layers
+           else {}),
     }
 
 
 def state_bytes_per_sequence(cfg: ModelConfig) -> int:
     per = (cfg.mamba_heads * cfg.mamba_head_dim * cfg.ssm_state
            + (cfg.conv_kernel - 1) * cfg.mamba_conv_dim) * 4
-    return per * len(cfg.mamba_layers)
+    return per * len(cfg.mamba_layers) \
+        + retention.bytes_per_state(cfg) * len(cfg.retention_layers)

@@ -6,7 +6,8 @@ Gemma-2B/7B, Llama-3-8B/3.2, Mistral-7B), Mixtral (compute-dense MoE),
 Qwen2.5 (attention bias), Nemotron-3-Nano (hybrid: Mamba-2, routed and
 shared experts, attention — models/hybrid.py), A.X-K1 (latent attention
 — models/mla.py — beside a dense MLP or gated routed and shared
-experts) and tiny test presets.
+experts), Brumby (power retention and no attention layer —
+models/retention.py) and tiny test presets.
 Architecture behavior lives in ModelConfig fields (common.py).
 
 `resolve_model_config(adapter_config)` is the one way an engine gets its
@@ -23,7 +24,7 @@ import dataclasses
 from typing import Any
 
 from .common import AttnLayer, ModelConfig
-from .hybrid import ATTENTION, EXPERTS, MLP, kinds_of_pattern
+from .hybrid import ATTENTION, EXPERTS, MLP, RETENTION, kinds_of_pattern
 
 _REGISTRY: dict[str, ModelConfig] = {}
 
@@ -297,6 +298,29 @@ TINY_MELLUM = register(_mellum_preset(
                    rope_attention_factor=1.2079441541679836),
     sliding=AttnLayer(0, rope_theta=500_000.0, rotary_dim=16),
     routed_experts=8, experts_held=8, moe_top_k=2, expert_dim=32))
+
+
+# --- Brumby / brumby (Qwen3's block with the attention product replaced
+# by power retention — models/retention.py: NO attention layer, so no
+# page holds a byte; q and k normed a head ahead of full rotary; a
+# published layer is TWO layers here, retention then a SwiGLU MLP) ---
+
+def _brumby_config(name, *, blocks, **kw):
+    return ModelConfig(
+        name=name, num_layers=2 * blocks, tie_embeddings=False,
+        layer_kinds=(RETENTION, MLP) * blocks, qk_norm=True, **kw)
+
+
+BRUMBY_14B = register(_brumby_config(
+    "brumby-14b", blocks=40, vocab_size=151_936, embed_dim=5120,
+    num_heads=40, num_kv_heads=8, head_dim=128, mlp_dim=17_408,
+    max_seq_len=8192, rope_theta=1_000_000.0, norm_eps=1e-6))
+
+# Group 3 over 2 kv heads of 16: a state of 9 x 16 x 17 floats a head.
+TINY_BRUMBY = register(_brumby_config(
+    "tiny-brumby", blocks=3, vocab_size=512, embed_dim=64, num_heads=6,
+    num_kv_heads=2, head_dim=16, mlp_dim=128, max_seq_len=512,
+    rope_theta=1_000_000.0, norm_eps=1e-6))
 
 
 # --- from a published config.json -------------------------------------------
@@ -623,6 +647,43 @@ def _mellum(name: str, arch: dict[str, Any],
     return cfg
 
 
+# Keys of a brumby config.json that say nothing this engine acts on
+# (Qwen3's window keys, kept by the config: `use_sliding_window: false`
+# names no layer), and the values its layer equations assume.
+_BRUMBY_INERT = {"model_type", "max_position_embeddings",
+                 "max_window_layers"}
+_BRUMBY_FIXED = {
+    "attention_bias": False, "tie_word_embeddings": False,
+    "hidden_act": "silu", "use_sliding_window": False,
+    "sliding_window": None, "rope_scaling": None}
+
+
+def _brumby(name: str, arch: dict[str, Any],
+            max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "brumby", _BRUMBY_FIXED, _BRUMBY_INERT)
+    try:
+        cfg = _brumby_config(
+            name, blocks=int(arch.pop("num_hidden_layers")),
+            vocab_size=int(arch.pop("vocab_size")),
+            embed_dim=int(arch.pop("hidden_size")),
+            num_heads=int(arch.pop("num_attention_heads")),
+            num_kv_heads=int(arch.pop("num_key_value_heads")),
+            head_dim=int(arch.pop("head_dim")),
+            mlp_dim=int(arch.pop("intermediate_size")),
+            max_seq_len=max_seq_len,
+            rope_theta=float(arch.pop("rope_theta")),
+            norm_eps=float(arch.pop("rms_norm_eps")))
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    _all_read(name, arch, "brumby")
+    if cfg.head_dim % 2 or cfg.num_heads % cfg.num_kv_heads:
+        raise ValueError(
+            f"architecture of {name!r}: power retention needs an even "
+            "head_dim and whole groups of query heads a kv head")
+    return cfg
+
+
 def _dense_gqa(name: str, arch: dict[str, Any],
                max_seq_len: int) -> ModelConfig:
     heads = int(arch["num_attention_heads"])
@@ -664,6 +725,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
         return _laguna(name, arch, max_seq_len)
     if kind == "mellum":
         return _mellum(name, arch, max_seq_len)
+    if kind == "brumby":
+        return _brumby(name, arch, max_seq_len)
     if kind in _DENSE_TYPES:
         try:
             return _dense_gqa(name, arch, max_seq_len)
@@ -672,7 +735,7 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
                              f"{e.args[0]!r}") from None
     raise ValueError(
         f"architecture of {name!r}: model_type {kind!r} is not one this "
-        f"engine runs (nemotron_h, axk1, laguna, mellum, "
+        f"engine runs (nemotron_h, axk1, laguna, mellum, brumby, "
         f"{', '.join(_DENSE_TYPES)})")
 
 

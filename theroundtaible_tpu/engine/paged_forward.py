@@ -398,6 +398,12 @@ def _hybrid_head(params, cfg, x):
     return _softcap(logits, cfg.final_logit_softcap)
 
 
+def _state_like(state: dict, ssm, conv, ret, retn) -> dict:
+    """The layers' new states under the parts `state` came with."""
+    parts = {"ssm": ssm, "conv": conv, "ret": ret, "retn": retn}
+    return {p: parts[p] for p in state}
+
+
 def _attention_io(h, layer, cfg: ModelConfig, positions, dtype):
     """What an attention layer of a model with `layer_kinds` asks of its
     pages and writes to them: (q [B,T,H,D], entries — one array a pool
@@ -435,22 +441,31 @@ def forward_paged_hybrid(
     cap_len: Optional[jax.Array] = None,   # [B] prefill: snapshot after
     active: Optional[jax.Array] = None,    # [B] decode: rows that advance
     last_pos: Optional[jax.Array] = None,
+    page_size: Optional[int] = None,       # a model with no pool to ask
+    rows: Optional[jax.Array] = None,      # [B] state rows (SLOT_PARTS)
+    snaps: Optional[dict] = None,          # the store's SLOT_PARTS
+    snap_idx: Optional[jax.Array] = None,  # [B] where a capture goes
 ):
     """forward_paged for a model with `layer_kinds` (models/hybrid.py):
     every layer is one mixer behind one norm and a residual. Attention
     layers scatter into their own pools and attend through the same
     page-table kernels; Mamba-2 layers advance the rows' recurrent
     `state` (batch-row order: the program gathers and scatters the
-    slot rows); expert layers count what they touched.
+    slot rows); retention layers (models/retention.py) advance theirs
+    IN PLACE on every slot's array (`state["ret"]`, `["retn"]`: whole,
+    addressed by `rows`) and write a capture straight into `snaps` at
+    `snap_idx`; expert layers count what they touched.
 
     -> (logits, new_pools, new_state, captured, counts): `captured` is
-    the state after `cap_len` tokens (prefill with `cap_len`), else
+    the state after `cap_len` tokens (prefill with `cap_len`; for the
+    retention parts the store's arrays with it written), else
     None; counts int32[5] (`hybrid.MOE_COUNTS`) = experts hit and
     assignments to held experts over the counted tokens, rows the
     grouped products multiplied and rows a loop over every held expert
     would have, expert-layer steps."""
-    from .models import hybrid
-    page_size = pools[0][0].shape[1]
+    from .models import hybrid, retention
+    if pools:
+        page_size = pools[0][0].shape[1]
     b, t = tokens.shape
     pages = table[jnp.arange(b)[:, None], positions // page_size]
     offs = positions % page_size
@@ -461,13 +476,30 @@ def forward_paged_hybrid(
     else:
         counted = jnp.arange(t)[None, :] < lengths[:, None]
     ssm, conv = list(state["ssm"]), list(state["conv"])
-    cap = {"ssm": [], "conv": []} if cap_len is not None else None
+    ret, retn = list(state.get("ret", ())), list(state.get("retn", ()))
+    cap = {p: [] for p in state} if cap_len is not None else None
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = 0
+    ai = mi = ri = 0
     for kind, layer in zip(cfg.layer_kinds, params["layers"]):
         h = hybrid.layer_norm_in(x, layer, cfg)
-        if kind == hybrid.MAMBA2:
+        if kind == hybrid.RETENTION:
+            if decode:
+                out, ret[ri], retn[ri] = retention.retention_step(
+                    h, layer, cfg, positions, ret[ri], retn[ri], rows,
+                    active)
+            else:
+                out, ret[ri], retn[ri], held = retention.retention_prefill(
+                    h, layer, cfg, positions, ret[ri], retn[ri], rows,
+                    lengths, page_size,
+                    None if cap is None else (snaps["ret"][ri],
+                                              snaps["retn"][ri]),
+                    cap_len, snap_idx)
+                if cap is not None:
+                    cap["ret"].append(held[0])
+                    cap["retn"].append(held[1])
+            ri += 1
+        elif kind == hybrid.MAMBA2:
             if decode:
                 out, ssm[mi], conv[mi] = hybrid.mamba2_step(
                     h, layer, cfg, ssm[mi], conv[mi], active)
@@ -517,8 +549,8 @@ def forward_paged_hybrid(
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
     if last_pos is not None:
         x = gather_rows(x, last_pos)
-    return (_hybrid_head(params, cfg, x), new_pools,
-            {"ssm": ssm, "conv": conv}, cap, counts)
+    new = _state_like(state, ssm, conv, ret, retn)
+    return _hybrid_head(params, cfg, x), new_pools, new, cap, counts
 
 
 def forward_ragged_hybrid(
@@ -529,15 +561,22 @@ def forward_ragged_hybrid(
     seq_slot: jax.Array,          # [S] state row of each sequence
     cap_n: jax.Array,             # [S] snapshot after this many tokens (0: none)
     attn_path: str = "kernel",
+    page_size: Optional[int] = None,       # a model with no pool to ask
+    snaps: Optional[dict] = None,          # the store's SLOT_PARTS
+    snap_idx: Optional[jax.Array] = None,  # [S] where a capture goes
 ):
     """forward_ragged for a model with `layer_kinds`: the flat buffer's
     Mamba-2 layers run the block-chunked scan straight on the slot
     array (each block reads its sequence's state and writes it back),
-    attention layers the ragged page-table kernel. -> (logits [S, V],
-    new_pools, new_state, captured {"ssm": [[S,...]..], "conv": ..},
-    counts)."""
-    from .models import hybrid
+    retention layers every run a page's chunk at a time, also straight
+    on the slot array and with a capture written into `snaps` at
+    `snap_idx`; attention layers the ragged page-table kernel. ->
+    (logits [S, V], new_pools, new_state, captured {"ssm": [[S,...]..],
+    "conv": .., "ret" / "retn": the store's arrays}, counts)."""
+    from .models import hybrid, retention
     from .serving_loop import RAGGED_BLOCK_Q
+    if pools:
+        page_size = pools[0][0].shape[1]
     s_max = tables.shape[0]
     x = embed_tokens(params["embedding"], tokens[None])  # [1, T, E]
     pos2 = positions[None]
@@ -546,13 +585,21 @@ def forward_ragged_hybrid(
                             seq_slot, cap_n, RAGGED_BLOCK_Q)
     counted = (rg["token_valid"] & (token_seq != s_max - 1))[None]
     ssm, conv = list(state["ssm"]), list(state["conv"])
-    cap = {"ssm": [], "conv": []}
+    ret, retn = list(state.get("ret", ())), list(state.get("retn", ()))
+    cap = {p: [] for p in state}
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = 0
+    ai = mi = ri = 0
     for kind, layer in zip(cfg.layer_kinds, params["layers"]):
         h = hybrid.layer_norm_in(x, layer, cfg)
-        if kind == hybrid.MAMBA2:
+        if kind == hybrid.RETENTION:
+            out, ret[ri], retn[ri], held = retention.retention_ragged(
+                h, layer, cfg, pos2, ret[ri], retn[ri], rg, page_size,
+                (snaps["ret"][ri], snaps["retn"][ri]), snap_idx)
+            cap["ret"].append(held[0])
+            cap["retn"].append(held[1])
+            ri += 1
+        elif kind == hybrid.MAMBA2:
             out, ssm[mi], conv[mi], s_cap, c_cap = hybrid.mamba2_ragged(
                 h, layer, cfg, ssm[mi], conv[mi], rg)
             cap["ssm"].append(s_cap)
@@ -587,7 +634,8 @@ def forward_ragged_hybrid(
         x = x + out
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
     logits = _hybrid_head(params, cfg, x[0, last_rows][None])
-    return (logits[0], new_pools, {"ssm": ssm, "conv": conv}, cap, counts)
+    new = _state_like(state, ssm, conv, ret, retn)
+    return logits[0], new_pools, new, cap, counts
 
 
 # ---------------------------------------------------------------------------

@@ -506,7 +506,7 @@ class PagedKVCache:
         them (KVCache.revive_if_dead's paged counterpart). Every slot,
         page mapping and refcount is dropped — the bytes are gone — so
         later prefills start from scratch. Returns True iff revived."""
-        if not self.pools[0][0].is_deleted():
+        if not any(p.is_deleted() for layer in self.pools for p in layer):
             return False
         self.pools = self._make_pools(self.num_pages)
         if self.scales is not None:

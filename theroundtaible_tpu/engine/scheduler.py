@@ -755,13 +755,13 @@ class SessionScheduler:
 
     def declare_warmup_complete(self) -> None:
         """Declare this scheduler's compile set closed (ISSUE 6): the
-        caller has warmed every bucket composition it intends to serve
-        (engine.warmup(batch_sizes=...) + representative traffic), so
-        any later compile is a mid-serve recompile — counted and
-        flight-dumped always, fatal under ROUNDTABLE_RECOMPILE_STRICT=1
-        (the pow2-bucket invariant, enforced instead of assumed)."""
+        caller has warmed every bucket composition it serves, so any
+        later compile is a mid-serve recompile — counted and dumped
+        always, fatal under ROUNDTABLE_RECOMPILE_STRICT=1. What warm-up
+        built is then taken out of the collector's sight (_settle_heap)."""
         from . import compile_watch
         compile_watch.warmup_complete(self._tname)
+        _settle_heap()
 
     # ------------------------------------------------------------------
     # drain / lifecycle
@@ -875,15 +875,15 @@ class SessionScheduler:
         """Point this scheduler at a REBUILT engine (the supervisor's
         step 5). Caller contract: admission is paused, no requests are
         active, and the old engine's serve lock is not held by this
-        scheduler. The rebuilt engine re-enters warmup (reopen_warmup)
-        so its fresh compiles are sanctioned under
-        ROUNDTABLE_RECOMPILE_STRICT — the caller re-declares via
-        declare_warmup_complete() once post-restart traffic is warm."""
+        scheduler. The rebuilt engine re-enters warmup (reopen_warmup:
+        its compiles are sanctioned, the old engine's frozen heap goes
+        back to the collector) until declare_warmup_complete() again."""
         from . import compile_watch
         self.engine = new_engine
         new_engine._scheduler = self
         self.max_rows = min(self.max_rows, new_engine.kv.num_slots)
         compile_watch.reopen_warmup(self._tname)
+        _release_heap()
         self._event("reattach_engine")
 
     def attach_journal(self, journal) -> None:
@@ -896,8 +896,7 @@ class SessionScheduler:
         return self._journal
 
     def close(self, timeout_s: float = 30.0) -> None:
-        """Stop the loop: queued requests are rejected, active requests
-        are allowed `timeout_s` to finish, then the thread exits."""
+        """Stop the loop: reject the queue, give active ones `timeout_s`."""
         self.closed = True
         self.reject_queued(SchedulerClosed(
             "scheduler closed before this session was admitted"))
@@ -905,6 +904,7 @@ class SessionScheduler:
             self._stop = True
             self._cv.notify_all()
         self._thread.join(timeout=timeout_s)
+        _release_heap()
 
     # ------------------------------------------------------------------
     # the scheduler loop
@@ -1402,7 +1402,9 @@ class SessionScheduler:
         # prologue for joins; the fallback still serves fills already
         # in flight when a mid-serve degrade flips the path.
         deferred = (getattr(engine, "ragged_path", None)
-                    == "pallas_ragged" and bool(self._active))
+                    == "pallas_ragged"
+                    and (bool(self._active)
+                         or getattr(engine, "joins_ragged_alone", False)))
         prep = engine._prepare_batch(
             scoped_turns, max_new_padded, deadline, pre_budget,
             req.sampling_per_turn, extra_pinned=active_names,
@@ -1526,7 +1528,8 @@ class SessionScheduler:
                     state_zero=sp["zero"],
                     prompt_tokens=sp["prompt_tokens"],
                     kv_matched_tokens=sp["kv_matched_tokens"],
-                    state_reused_tokens=sp["state_reused_tokens"])
+                    state_reused_tokens=sp["state_reused_tokens"],
+                    state_copy_bytes=sp["state_copy_bytes"])
         if telemetry.ACTIVE:
             # The request's "turn" span: lives across segments (ended at
             # retire/fail), parented to the SUBMITTER's trace so spans
@@ -1734,7 +1737,9 @@ class SessionScheduler:
             delta, taken = hy.moe_delta(), hy.snapshots_taken
             if self._hy_counting:
                 seg.attrs.update(
-                    delta, snapshots_taken=taken - self._snaps_seen)
+                    delta, snapshots_taken=taken - self._snaps_seen,
+                    state_capture_bytes=(taken - self._snaps_seen)
+                    * hy.bytes_per_state)
             self._hy_counting, self._snaps_seen = True, taken
             seg.attrs["snapshot_bytes"] = hy.snapshot_bytes()
         seg.end()
@@ -3195,3 +3200,23 @@ def acquire_scheduler(engine, **opts) -> tuple[SessionScheduler, bool]:
 def scheduler_for(engine, **opts) -> SessionScheduler:
     """acquire_scheduler for callers that don't track ownership."""
     return acquire_scheduler(engine, **opts)[0]
+
+
+def _settle_heap() -> None:
+    """Collect once, then freeze what is left. Programs, parameter
+    trees, modules and warm-up's own records live as long as the
+    process; a full collection under traffic walked all of them with
+    the loop's thread stopped — once a 45 s window, 0.22 s on the chip,
+    a whole round's first tokens late by that much (PERF.md, Findings
+    PR 42). Frozen objects are freed by their reference counts as ever;
+    only a cycle among them is kept, until _release_heap."""
+    import gc
+    gc.collect()
+    gc.freeze()
+
+
+def _release_heap() -> None:
+    """Hand the frozen objects back to the collector: the scheduler
+    closes, or its engine was rebuilt and the old one is garbage."""
+    import gc
+    gc.unfreeze()

@@ -35,15 +35,16 @@ RAGGED_TOKENS_ENV = "ROUNDTABLE_RAGGED_TOKENS"
 RAGGED_DEFER_MIN_ENV = "ROUNDTABLE_RAGGED_DEFER_MIN"
 
 
-def ragged_token_budget(num_slots: int) -> int:
+def ragged_token_budget(num_slots: int, asked: int = 0) -> int:
     """Flat-buffer capacity per ragged dispatch: big enough that a
     typical cold join's leader span streams in ONE dispatch — chunk
     throughput must be bucket-class or deferral just slows the joiner
     down — floored so every resident row's 8-row decode block still
-    leaves chunk room. ROUNDTABLE_RAGGED_TOKENS overrides (rounded up
-    to a block multiple)."""
+    leaves chunk room. ROUNDTABLE_RAGGED_TOKENS, or else the engine
+    config's `ragged_tokens` (`asked`), overrides (rounded up to a
+    block multiple)."""
     import os
-    forced = int(os.environ.get(RAGGED_TOKENS_ENV, "0") or 0)
+    forced = int(os.environ.get(RAGGED_TOKENS_ENV, "0") or 0) or asked
     if forced > 0:
         return -(-forced // RAGGED_BLOCK_Q) * RAGGED_BLOCK_Q
     return max(1024, RAGGED_BLOCK_Q * num_slots + 64)

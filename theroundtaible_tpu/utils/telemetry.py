@@ -1176,6 +1176,15 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
                             "(over roundtable_state_prompt_tokens_total)",
         "share_declined": "roundtable_state_share_declined_total"
                           "{reason=recurrent-state}",
+        # ISSUE 42: whole states written into slot rows by a restore
+        # and into the store by the programs' captures, in bytes
+        # (HybridStateStore._note_copy is the one writer; an `admit`
+        # span carries its own restore, a `segment` span the captures
+        # of the programs it covers).
+        "restore_bytes": "roundtable_state_copy_bytes_total"
+                         "{cause=restore}",
+        "capture_bytes": "roundtable_state_copy_bytes_total"
+                         "{cause=capture}",
         "deduped_pages": "derived (prefix_cache.insert: re-written "
                          "pages given back for the index's; "
                          "describe-only)",
