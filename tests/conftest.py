@@ -526,14 +526,26 @@ def _tracing_guard(request):
     was_active = telemetry.ACTIVE
     telemetry.arm()
     tracing.store().reset()
-    before = len(telemetry.recorder().span_events())
+    # The span ring is bounded: once a worker's earlier files have
+    # filled it its length stands still, so this test's spans are the
+    # ones after the newest one there now — found by identity.
+    ring = telemetry.recorder().span_events()
+    newest = ring[-1] if ring else None
+
+    def spans_since():
+        ring = telemetry.recorder().span_events()
+        for i in range(len(ring) - 1, -1, -1):
+            if ring[i] is newest:
+                return ring[i + 1:]
+        return ring             # turned over: all of it is newer
+
     yield
     # The request/turn spans end asynchronously (pump thread, scheduler
     # loop) after the client reads its terminal event — give them a
     # moment to land in the flight ring before judging.
     deadline = time.monotonic() + 3.0
     while True:
-        spans = telemetry.recorder().span_events()[before:]
+        spans = spans_since()
         if (tracing.cross_layer_count(spans) > 0
                 or time.monotonic() > deadline):
             break

@@ -32,8 +32,10 @@ from theroundtaible_tpu.engine.kvcache import scoped_slot
 from theroundtaible_tpu.engine.models.registry import get_model_config
 from theroundtaible_tpu.engine.sampling import SamplingParams
 from theroundtaible_tpu.engine.scheduler import SessionScheduler
-from theroundtaible_tpu.engine.serving_loop import (RaggedSeq,
-                                                    build_ragged_batch)
+from theroundtaible_tpu.engine.serving_loop import (DECODE_SEGMENT,
+                                                    RaggedSeq,
+                                                    build_ragged_batch,
+                                                    eos_trim)
 from theroundtaible_tpu.engine.spec_decode import (NGramDrafter, RowSpec,
                                                    accept_prefix)
 from theroundtaible_tpu.utils import telemetry
@@ -787,7 +789,355 @@ class TestReprobeHysteresis:
         assert sd.reprobes_seen() == before + 1
         rs.mark_idle(4)
         assert not rs.should_draft(6), "failed probe must not re-arm"
-        assert rs.should_draft(8)
+        # ... and, having accepted nothing, twice as long (ISSUE 43).
+        assert not rs.should_draft(8)
+        assert rs.should_draft(12)
+
+
+def _probe_points(th, upto, accepted=0):
+    """Walk a throttled owner's clock a unit at a time, as the
+    scheduler would, and answer every probe that comes due with
+    `accepted` of four drafted tokens: the marks the probes came at."""
+    points = []
+    for n in range(1, upto + 1):
+        if isinstance(th, sd.BatchThrottle):
+            th.advance(1)
+        if th.should_draft(n):
+            points.append(n)
+            th.note(4, accepted)
+            th.mark_idle(n)
+    return points
+
+
+def _tripped(th):
+    for _ in range(sd.SPEC_MIN_DISPATCHES):
+        th.note(4, 0)
+    assert th.disabled
+    th.mark_idle(0)
+    return th
+
+
+BACKOFF_TURNS = [[("lancelot", "The round table met at dawn to discuss "
+                               "the castle walls.")],
+                 [("lancelot", "And then the eastern gate, and who "
+                               "should keep its keys.")]]
+MIXED_TURNS = [("lancelot", "The round table met at dawn to discuss "
+                            "the castle walls."),
+               ("galahad", "Who keeps the keys of the eastern gate, "
+                           "and who the ledger of the granary?"),
+               ("percival", "Name the three roads that lead from the "
+                            "castle to the sea.")]
+SEG_KEYS = ("kind", "label", "drafted", "accepted", "probes",
+            "probes_accepted_none")
+LIVE = []   # the scheduler _rounds is serving with, for a draft to ask
+
+
+def _rounds(engine, rounds, max_new, parent=False, shared=None):
+    """Requests through one scheduler, one after the other; `rounds`
+    is a list of (session, turns, draft) and each `draft` is patched
+    onto the n-gram drafter for its round. `parent`: the controller as
+    it was before ISSUE 43 — the batch's throttle never bars a row and
+    no interval grows. `shared`: the batch throttle the engine starts
+    with, a fresh one if none. Returns, a round, the rows' tokens,
+    the rows' `RowSpec`s and the `segment` spans' SEG_KEYS."""
+    mp = pytest.MonkeyPatch()
+    if parent:
+        mp.setattr(sd.BatchThrottle, "asks", lambda self, *a, **k: True)
+        mp.setattr(sd, "SPEC_REPROBE_CEILING", 0)
+    engine.spec_batch = shared or sd.BatchThrottle(DECODE_SEGMENT)
+    telemetry.disarm()
+    telemetry.arm()
+    sched = SessionScheduler(engine)
+    LIVE[:] = [sched]
+    served = []
+    try:
+        for session, turns, draft in rounds:
+            if draft is not None:
+                mp.setattr(NGramDrafter, "draft", draft)
+            t_a = time.monotonic()
+            req = sched.submit_async(session, turns,
+                                     max_new_tokens=max_new)
+            sched.wait(req)
+            outs = [eos_trim(list(r.produced), engine.tokenizer.eos_id,
+                             max_new) for r in req.rows]
+            specs = [r.spec for r in req.rows]
+            segs = [tuple(r["attrs"][k] for k in SEG_KEYS)
+                    for r in telemetry.spans_between(t_a,
+                                                     time.monotonic())
+                    if r["rung"] == "segment"]
+            served.append((outs, specs, segs))
+    finally:
+        sched.close()
+        telemetry.disarm()
+        mp.undo()
+    return served
+
+
+def _oracle(answers, lands=lambda k, said: True, bad=0):
+    """A `draft` that knows what the model will say: for a row whose
+    committed tokens are the start of one of `answers` (key -> tokens)
+    it proposes that answer's continuation where `lands(key,
+    len(said))`, and `bad` tokens everywhere else."""
+    def draft(self, n):
+        if not len(self):
+            return []
+        said = self._toks[self._plen:]
+        for k, out in answers.items():
+            if out[:len(said)] == said and lands(k, len(said)):
+                return out[len(said):len(said) + n] or [bad] * n
+        return [bad] * n
+    return draft
+
+
+class _Marked(NGramDrafter):
+    """An n-gram drafter that remembers where its prompt ended."""
+    __slots__ = ("_plen",)
+
+    def __init__(self, tokens=None):
+        super().__init__(tokens)
+        self._plen = len(self)
+
+
+class TestBackoff:
+    """ISSUE 43: a throttled owner's re-probe backs off while its
+    probes accept nothing, any accepted draft puts it back, and the
+    rows with no verdict of their own are judged together by a
+    throttle that is the engine's — it outlives the request."""
+
+    @pytest.fixture(autouse=True)
+    def marked(self, monkeypatch, spec_engine):
+        monkeypatch.setattr(sd, "NGramDrafter", _Marked)
+        yield
+        spec_engine.spec_batch = sd.BatchThrottle(DECODE_SEGMENT)
+
+    @staticmethod
+    def _wrong(engine):
+        bad = engine.cfg.vocab_size - 1
+        return lambda self, n: [bad] * n if len(self) else []
+
+    @pytest.mark.parametrize("case", [
+        "never_lands", "always_lands", "success_resets",
+        "survives_the_request", "lands_sometimes", "lands_among_wrong",
+        "lands_after_backoff"])
+    def test_backoff(self, case, spec_engine, nospec_engine):
+        getattr(self, "_" + case)(spec_engine, nospec_engine)
+
+    def _never_lands(self, spec_engine, nospec_engine):
+        # A throttled row's probes, in committed tokens from the trip:
+        # 16 on, then 32, 64, 128, then the ceiling of 256.
+        row = _tripped(RowSpec([1, 2, 3]))
+        assert _probe_points(row, 1100) == [16, 48, 112, 240, 496, 752,
+                                            1008]
+        assert row.interval() == sd.SPEC_REPROBE_CEILING == 256
+        # The batch's, in decode steps: a segment on, then 128, 256.
+        shared = _tripped(sd.BatchThrottle(DECODE_SEGMENT))
+        assert _probe_points(shared, 1000) == [64, 192, 448, 704, 960]
+        assert shared.counts == {"probes": 5, "probes_accepted_none": 5,
+                                 "probes_backed_off": 0}
+        assert not shared.asks(7, 512) and not shared.asks(7, 512)
+        assert shared.counts["probes_backed_off"] == 1
+        # Its ceiling is the shortest answer among the rows it is
+        # asked for, so a row is asked at least once an answer; and a
+        # segment in flight counts towards the interval.
+        assert shared.interval() == 256
+        assert not shared.asks(8, 128) and shared.interval() == 128
+        shared.advance(64)
+        assert not shared.asks(9, 128) and shared.asks(9, 128, ahead=64)
+        # Through the scheduler: a drafter that is always wrong costs
+        # verifies and changes no token — the plain scheduler's, token
+        # for token — and the window of SPEC_MIN_DISPATCHES judges it
+        # as it did; the probe that follows doubles the row's interval.
+        # (A lone row's probes wait for a segment boundary under either
+        # controller: _survives_the_request counts the verifies saved.)
+        wrong, seen = self._wrong(spec_engine), []
+
+        def peeking(drafter, n):
+            seen.append(LIVE[0].describe()["probe_intervals"])
+            return wrong(drafter, n)
+
+        turns = BACKOFF_TURNS[0]
+        [([base], _p, _s)] = _rounds(nospec_engine,
+                                     [("nl-base", turns, None)], 400)
+        [([p_got], _p, p_segs)] = _rounds(
+            spec_engine, [("nl-parent", turns, wrong)], 400, parent=True)
+        [([got], [row], segs)] = _rounds(
+            spec_engine, [("nl", turns, peeking)], 400)
+        assert got == base == p_got, "verify corrections diverged"
+        spec = [a for a in segs if a[0] == "spec"]
+        p_spec = [a for a in p_segs if a[0] == "spec"]
+        assert row.accepted == 0 < row.drafted and row.disabled
+        assert sd.SPEC_MIN_DISPATCHES < len(spec) <= len(p_spec)
+        assert row.interval() == sd.SPEC_REPROBE_DISPATCHES << (
+            len(spec) - sd.SPEC_MIN_DISPATCHES)
+        # The scheduler's histogram of its live rows' own intervals.
+        assert seen[0] == {0: 1} and seen[-1] == {16: 1}
+        # The row's own window judged it, so its probes are its own
+        # and none is the batch's.
+        assert all(a[4:] == (0, 0) for a in spec)
+        info = spec_engine.spec_describe()
+        assert info["probe_interval"] == DECODE_SEGMENT
+        assert info["probes"] == 0
+
+    def _always_lands(self, spec_engine, nospec_engine):
+        # Greedy, and the drafter proposes what the model will say: no
+        # throttle ever engages and the programs dispatched are the
+        # parent's, call for call, over both requests.
+        plain = [("al-base", t, None) for t in BACKOFF_TURNS]
+        answers = {i: r[0][0] for i, r in enumerate(
+            _rounds(nospec_engine, plain, 40))}
+        draft = _oracle(answers)
+        change = _rounds(spec_engine,
+                         [("al", t, draft) for t in BACKOFF_TURNS], 40)
+        info = spec_engine.spec_describe()
+        parent = _rounds(spec_engine,
+                         [("al-parent", t, draft) for t in BACKOFF_TURNS],
+                         40, parent=True)
+        for i, (got, p_got) in enumerate(zip(change, parent)):
+            assert got[0] == [answers[i]] == p_got[0]
+            decode = [a for a in got[2] if a[0] != "ragged"]
+            assert decode == [a for a in p_got[2] if a[0] != "ragged"]
+            assert decode and all(a[0] == "spec" and a[2] == a[3] > 0
+                                  and a[4:] == (0, 0) for a in decode)
+        assert [info[k] for k in ("probes", "probes_accepted_none",
+                                  "probes_backed_off", "probe_interval",
+                                  "throttled_rows")][:4] == [0, 0, 0, 0]
+
+    def _success_resets(self, spec_engine, nospec_engine):
+        row = _tripped(RowSpec([1, 2, 3]))
+        assert _probe_points(row, 112) == [16, 48, 112]
+        assert row.interval() == 128
+        # One accepted draft of a probe's twenty is under the floor:
+        # the row stays throttled, and its interval is 16 again.
+        assert not row.should_draft(239) and row.should_draft(240)
+        assert not row.note(20, 1)
+        assert row.disabled and row.interval() == 16
+        row.mark_idle(240)
+        assert not row.should_draft(255) and row.should_draft(256)
+        # A probe at the floor recovers, with a fresh window.
+        row.note(4, 1)
+        assert not row.disabled and row.accepting()
+        assert list(row.recent) == [(4, 1)]
+
+    def _survives_the_request(self, spec_engine, nospec_engine):
+        wrong = self._wrong(spec_engine)
+        base = _rounds(nospec_engine,
+                       [("sr-base", t, None) for t in BACKOFF_TURNS], 150)
+        first, second = _rounds(
+            spec_engine, [("sr", t, wrong) for t in BACKOFF_TURNS], 150)
+        assert [first[0], second[0]] == [r[0] for r in base]
+        # The first request fills the window and trips the row's
+        # throttle and the batch's with it; the second request's row
+        # is new and has no verdict, so it waits on the batch's
+        # probes — every verify of it is one, and accepts nothing.
+        spec1, spec2 = ([a for a in r[2] if a[0] == "spec"]
+                        for r in (first, second))
+        assert len(spec1) >= sd.SPEC_MIN_DISPATCHES
+        assert all(a[4:] == (0, 0) for a in spec1)
+        assert spec2 and all(a[4:] == (1, 1) for a in spec2)
+        info = spec_engine.spec_describe()
+        # The parent's new row fills its own window first.
+        _p1, p_second = _rounds(
+            spec_engine, [("sr-p", t, wrong) for t in BACKOFF_TURNS], 150,
+            parent=True)
+        assert len(spec2) < sd.SPEC_MIN_DISPATCHES <= sum(
+            a[0] == "spec" for a in p_second[2])
+        assert info["probes"] == len(spec2) == info[
+            "probes_accepted_none"]
+        # ... each doubling the interval, up to the answer's length.
+        assert info["probe_interval"] == min(
+            DECODE_SEGMENT << len(spec2), 150) == 150
+        assert info["probes_backed_off"] > 0
+        assert set(info) <= set(
+            telemetry.SURFACE_BINDINGS["engine_spec_decode"]) | {
+                "enabled", "reason", "max_draft", "recent"}
+
+    @staticmethod
+    def _third(_key, n):
+        return n % 3 == 0
+
+    def _lands_sometimes(self, spec_engine, nospec_engine):
+        # A lone row whose drafts land on some verifies and miss on
+        # others, above the floor: the controller never engages, and
+        # drafts, acceptances and programs are the parent's.
+        turns = BACKOFF_TURNS[0]
+        [([base], _p, _s)] = _rounds(nospec_engine,
+                                     [("ls-base", turns, None)], 120)
+        draft = _oracle({0: base}, self._third,
+                        spec_engine.cfg.vocab_size - 1)
+        [change] = _rounds(spec_engine, [("ls", turns, draft)], 120)
+        info = spec_engine.spec_describe()
+        [parent] = _rounds(spec_engine, [("ls-parent", turns, draft)],
+                           120, parent=True)
+        assert change[0] == [base] == parent[0]
+        [row], [p_row] = change[1], parent[1]
+        assert 0.2 <= row.accepted / row.drafted < 0.8
+        assert (row.drafted, row.accepted) == (p_row.drafted,
+                                               p_row.accepted)
+        assert change[2] == parent[2]
+        assert info["probes_backed_off"] == 0 == info["probe_interval"]
+
+    def _lands_among_wrong(self, spec_engine, nospec_engine):
+        # The same row in one batch with two rows whose drafts never
+        # land: their windows throttle them (and the batch's with
+        # them), and the row whose drafts land loses nothing — it
+        # drafts and accepts what it did under the parent.
+        [(base, _p, _s)] = _rounds(nospec_engine,
+                                   [("lw-base", MIXED_TURNS, None)], 120)
+        firsts = [b[0] for b in base]
+        i = next(i for i, f in enumerate(firsts) if firsts.count(f) == 1)
+        draft = _oracle({i: base[i]}, self._third,
+                        spec_engine.cfg.vocab_size - 1)
+        [change] = _rounds(spec_engine, [("lw", MIXED_TURNS, draft)], 120)
+        info = spec_engine.spec_describe()
+        [parent] = _rounds(spec_engine,
+                           [("lw-parent", MIXED_TURNS, draft)], 120,
+                           parent=True)
+        assert change[0] == base == parent[0]
+        row, p_row = change[1][i], parent[1][i]
+        assert (row.drafted, row.accepted) == (p_row.drafted,
+                                               p_row.accepted)
+        assert row.accepted > 0 and not row.disabled
+        assert all(r.accepted == 0 < r.drafted and r.disabled
+                   for r in change[1] if r is not row)
+        assert info["probe_interval"] > 0
+        n_spec, p_spec = (sum(a[0] == "spec" for a in r[2])
+                          for r in (change, parent))
+        assert n_spec <= p_spec
+
+    def _lands_after_backoff(self, spec_engine, nospec_engine):
+        # What a row with no verdict can lose: it joins an engine whose
+        # batch throttle non-accepting traffic has backed off a moment
+        # ago, and though every draft of it would land it is not
+        # asked before the batch's next probe. That
+        # probe lands, the throttle recovers, and from there it is the
+        # parent's row.
+        turns = BACKOFF_TURNS[1]
+        [([base], _p, _s)] = _rounds(nospec_engine,
+                                     [("la-base", turns, None)], 400)
+        draft = _oracle({0: base})
+        shared = _tripped(sd.BatchThrottle(DECODE_SEGMENT))
+        shared.level = 1
+        wait = shared.interval()
+        assert wait == 2 * DECODE_SEGMENT
+        [change] = _rounds(spec_engine, [("la", turns, draft)], 400,
+                           shared=shared)
+        info = spec_engine.spec_describe()
+        [parent] = _rounds(spec_engine, [("la-p", turns, draft)], 400,
+                           parent=True)
+        assert change[0] == [base] == parent[0]
+        decode = [a for a in change[2] if a[0] != "ragged"]
+        first = next(i for i, a in enumerate(decode) if a[0] == "spec")
+        assert decode[first][4:] == (1, 0), "the batch's probe, landed"
+        assert all(a[0] == "plain" for a in decode[:first])
+        assert all(a[0] == "spec" and a[2] == a[3]
+                   for a in decode[first:])
+        assert info["probe_interval"] == 0, "recovered"
+        # The loss: the interval's tokens, decoded one a step, which
+        # the parent's row drafted for from its first tick.
+        assert all(a[0] == "spec" for a in parent[2] if a[0] != "ragged")
+        assert sum(a[0] == "plain" for a in decode) * DECODE_SEGMENT == wait
+        lost = parent[1][0].accepted - change[1][0].accepted
+        assert 0 < lost <= wait
 
 
 class TestTreeBatchBuilder:

@@ -1090,8 +1090,8 @@ class InferenceEngine:
         # host and the static score_width program scores every draft
         # position in one forward. ROUNDTABLE_SPEC_DECODE=0 /
         # spec_decode: False restores 1-token decode byte-identically.
-        from .spec_decode import (DEFAULT_MAX_DRAFT, SpecOptions,
-                                  spec_enabled)
+        from .spec_decode import (DEFAULT_MAX_DRAFT, BatchThrottle,
+                                  SpecOptions, spec_enabled)
         self.spec_decode = False
         self.spec_reason: Optional[str] = None
         # The resolved `spec_decode:` block (ISSUE 13): dict configs
@@ -1125,6 +1125,10 @@ class InferenceEngine:
         self._spec_drafted = 0
         self._spec_accepted = 0
         self._spec_throttled = 0
+        # The throttle of the rows that have no verdict of their own
+        # (ISSUE 43): the scheduler's loop writes it, spec_describe
+        # reads it.
+        self.spec_batch = BatchThrottle(DECODE_SEGMENT)
         self._spec_dispatches = 0
         self._spec_tree_nodes = 0
         self._spec_tree_rows = 0
@@ -2433,6 +2437,9 @@ class InferenceEngine:
             "acceptance_rate": (round(rate, 3)
                                 if rate is not None else None),
             "throttled_rows": self._spec_throttled,
+            **self.spec_batch.counts,
+            "probe_interval": (self.spec_batch.interval()
+                               if self.spec_batch.disabled else 0),
             "by_drafter": {k: {"drafted": v[0], "accepted": v[1]}
                            for k, v in self._spec_by_drafter.items()},
             "tree_nodes": self._spec_tree_nodes,

@@ -63,7 +63,7 @@ from __future__ import annotations
 import threading
 import time
 import weakref
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -694,6 +694,9 @@ class SessionScheduler:
             "indexed_in_flight": self.indexed_in_flight,
             "indexed_at_draft": self.indexed_at_draft,
             "spec_segments": self.spec_segments,
+            "probe_intervals": dict(Counter(
+                r.spec.interval() if r.spec.disabled else 0
+                for r in list(self._active) if r.spec is not None)),
             "segment_prefill_tokens": self.segment_prefill_tokens,
             "segment_decode_tokens": self.segment_decode_tokens,
             "queued": len(self._queue),
@@ -1674,7 +1677,8 @@ class SessionScheduler:
                      accepted: int = 0, in_flight: int = 0,
                      read_to: tuple = (),
                      ragged: Optional[dict] = None,
-                     filtered_rows: int = 0) -> None:
+                     filtered_rows: int = 0, probes: int = 0,
+                     probes_accepted_none: int = 0) -> None:
         """Emit a segment span with the counts its fold produced, and
         the pool's pages in use at its end (the pool's peak over any
         stretch is the maximum over that stretch's segment spans).
@@ -1689,9 +1693,13 @@ class SessionScheduler:
         (`pages_held`, of it `pages_behind_window`), on every kind of
         segment. `filtered_rows`: the segment's rows whose top_k or
         top_p engages the sampler's candidate pool (a ragged batch
-        brings its own count)."""
+        brings its own count). `probes`: 1 on a verify that was the
+        batch throttle's re-probe (spec_decode.BatchThrottle),
+        `probes_accepted_none` 1 if its rows accepted no drafted
+        token."""
         if ragged is not None:
             filtered_rows = ragged["filtered_rows"]
+        self.engine.spec_batch.advance(steps)
         self.engine.note_sampler_segment(filtered_rows)
         latent = None
         if getattr(self.engine.cfg, "latent", False):
@@ -1718,7 +1726,9 @@ class SessionScheduler:
             return
         seg.attrs.update(steps=steps, decode_tokens=decode_tokens,
                          prefill_tokens=prefill_tokens, drafted=drafted,
-                         accepted=accepted, filtered_rows=filtered_rows)
+                         accepted=accepted, filtered_rows=filtered_rows,
+                         probes=probes,
+                         probes_accepted_none=probes_accepted_none)
         if latent is not None:
             seg.attrs["latent_positions"] = latent
         if ragged is not None and "page_visits" in ragged:
@@ -2098,8 +2108,26 @@ class SessionScheduler:
             else engine.spec_max_draft
         dd = getattr(engine, "spec_device_drafter", None)
 
+        pooled = None   # the batch throttle's answer, asked once
+
         def cap_of(r: _Row) -> int:
-            if r.spec is None or not r.spec.should_draft(len(r.produced)):
+            nonlocal pooled
+            if r.spec is None:
+                return 0
+            if r.spec.judged():
+                ok = r.spec.should_draft(len(r.produced))
+            else:
+                # No verdict of its own yet: the batch's (ISSUE 43).
+                # _may_speculate's probe asks with a segment in flight
+                # whose steps the batch's clock has not been given.
+                if pooled is None:
+                    pooled = engine.spec_batch.asks(
+                        self._clock.tick,
+                        min(x.max_new for x in live if x.spec is not None
+                            and not x.spec.judged()),
+                        ahead=DECODE_SEGMENT if probe else 0)
+                ok = pooled
+            if not ok:
                 return 0
             return min(depth, r.max_new - len(r.produced) - 1)
 
@@ -2376,6 +2404,7 @@ class SessionScheduler:
         lora_toks = 0
         drafted_tot = 0
         accepted_tot = 0
+        pooled = [0, 0]   # drafted, accepted of the unjudged rows
         tree_nodes_tot = 0
         tree_rows_tot = 0
         emits: dict[int, tuple[_Request, int]] = {}
@@ -2453,6 +2482,11 @@ class SessionScheduler:
             if drafted_row and r.spec is not None:
                 drafted_tot += drafted_row
                 accepted_tot += acc
+                if not r.spec.judged():
+                    # It drafted on the batch throttle's word, so its
+                    # outcome is that throttle's evidence (ISSUE 43).
+                    pooled[0] += drafted_row
+                    pooled[1] += acc
                 tripped = r.spec.note(drafted_row, acc)
                 if r.spec.disabled:
                     # Throttled (now or still): restart the re-probe
@@ -2484,13 +2518,25 @@ class SessionScheduler:
                                   rows=len(live),
                                   tree_nodes=tree_nodes_tot,
                                   tree_rows=tree_rows_tot)
+        shared = engine.spec_batch
+        probe = shared.disabled and pooled[0] > 0
+        if shared.note(*pooled):
+            # The rows with no verdict of their own are throttled
+            # together: one flight event, as for a row's own trip.
+            telemetry.recorder().record(
+                "spec_throttle", engine=self._tname, session="",
+                row="(batch)", rate=round(shared.rate(), 3))
+            self._event("spec_throttle", row="(batch)",
+                        rate=round(shared.rate(), 3))
 
         self.spec_segments += 1
         telemetry.inc("roundtable_sched_spec_segments_total",
                       engine=self._tname)
         self._note_segment_tokens(0, n_emit)
         self._end_segment(seg, 1, n_emit, 0, drafted_tot, accepted_tot,
-                          ragged=batch)
+                          ragged=batch, probes=int(probe),
+                          probes_accepted_none=int(
+                              probe and not pooled[1]))
         occ = len(seqs)
         self.max_occupancy = max(self.max_occupancy, occ)
         with self._cv:

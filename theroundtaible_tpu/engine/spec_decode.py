@@ -132,6 +132,24 @@ SPEC_ACCEPT_FLOOR = 0.2
 # re-trip; a failed probe waits a whole interval again.
 SPEC_REPROBE_DISPATCHES = 16
 
+# Re-probe back-off and pooled evidence (ISSUE 43). A throttled owner's
+# probe that accepts nothing doubles the distance to its next one, up
+# to SPEC_REPROBE_CEILING of its clock; a probe that accepts any
+# drafted token puts the distance back to its base. And the throttle's
+# evidence is pooled where a row has none of its own: a verify is ONE
+# program for the whole batch, so what a non-accepting batch pays is a
+# verify a TICK, however few of its rows drafted — fifteen rows that
+# each find a stray n-gram match once an answer keep every tick a
+# verify while none of them ever fills its own window. So the rows
+# with no verdict of their own (not throttled, no draft of theirs has
+# landed: RowSpec.judged) are judged together, by the same rule on the
+# sum of what they drafted and accepted a verify (BatchThrottle): the
+# same window, the same floor, the same re-probe with the same
+# back-off, on the clock of decode steps. A row whose own drafts land
+# is exempt from the batch's verdict, and a row its own window has
+# throttled keeps its own re-probe.
+SPEC_REPROBE_CEILING = 256
+
 
 def accept_floor() -> float:
     import os
@@ -353,18 +371,128 @@ class NGramDrafter:
         return paths
 
 
-class RowSpec:
-    """Per-row speculation state: the drafter plus the adaptive
-    throttle's acceptance window and re-probe hysteresis (ISSUE 13:
+class Throttle:
+    """The adaptive throttle, for whoever's evidence it is given: the
+    window of (drafted, accepted) a verify, the trip once at least
+    SPEC_MIN_DISPATCHES of them read under the floor, and the re-probe
+    — once an `interval()` of the owner's clock while throttled, with
+    the hysteresis of ISSUE 13 (a probe whose own acceptance clears
+    the floor re-enables with a FRESH window) and the back-off of
+    ISSUE 43: every probe that accepts nothing doubles the interval,
+    up to SPEC_REPROBE_CEILING; a probe that accepts any drafted token
+    puts it back to its base. The clock is the caller's (`mark`): a
+    row's committed tokens, the batch's decode steps. A `RowSpec` is
+    one, the engine's `BatchThrottle` is the other."""
+
+    __slots__ = ("recent", "disabled", "probing", "level", "_idle_mark",
+                 "_base")
+
+    def __init__(self, base: Optional[int] = None):
+        # (drafted, accepted) per verify dispatch that actually drafted.
+        self.recent: deque = deque(maxlen=SPEC_WINDOW)
+        self.disabled = False
+        # Re-probe bookkeeping: the clock's mark at throttle time —
+        # pure function of the owner's state, so the probe decision is
+        # idempotent across the scheduler's probe and real calls.
+        self.probing = False
+        self.level = 0      # probes in a row that accepted nothing
+        self._idle_mark = 0
+        self._base = base   # None: reprobe_interval(), read when asked
+
+    def rate(self) -> float:
+        d = sum(x for x, _ in self.recent)
+        return (sum(a for _, a in self.recent) / d) if d else 0.0
+
+    def accepting(self) -> bool:
+        """Whether the window holds evidence of drafts that land: at
+        least one drafted dispatch, and a rate at or above the floor."""
+        return bool(self.recent) and self.rate() >= accept_floor()
+
+    def interval(self) -> int:
+        """The clock's distance to the next probe while throttled."""
+        base = self._base or reprobe_interval()
+        return min(base << self.level, max(self.ceiling(), base))
+
+    def ceiling(self) -> int:
+        return SPEC_REPROBE_CEILING
+
+    def should_draft(self, mark: int) -> bool:
+        """Whether the owner drafts now: always while unthrottled; once
+        every `interval()` of its clock while throttled (the re-probe).
+        Once a probe fires it stays armed until the next note(), so the
+        scheduler's probe call and the real segment see the same
+        answer."""
+        if not self.disabled:
+            return True
+        if self.probing:
+            return True
+        if mark - self._idle_mark >= self.interval():
+            self.probing = True
+            return True
+        return False
+
+    def mark_idle(self, mark: int) -> None:
+        """Restart the re-probe interval (called when a dispatch leaves
+        the owner throttled)."""
+        self._idle_mark = mark
+
+    def probe_failed(self, mark: int) -> None:
+        """Resolve an armed probe that never reached a verify dispatch
+        (the drafter proposed NOTHING for the probing row): clear the
+        arm and restart the interval — otherwise `probing` stays True
+        forever and the row pays per-tick draft host work for the rest
+        of its turn, exactly the overhead the throttle exists to
+        remove."""
+        if self.probing:
+            self.probing = False
+            self._idle_mark = mark
+            note_spec_reprobe(recovered=False)
+
+    def note(self, drafted: int, accepted: int) -> bool:
+        """Record one verify dispatch's outcome. Returns True when THIS
+        call tripped the throttle (the caller emits the one flight
+        event). A throttled owner's re-probe RECOVERS here: when the
+        probe's own acceptance clears the floor, it re-enables with a
+        fresh window (hysteresis — the stale all-zero window must not
+        immediately re-trip it)."""
+        if drafted <= 0:
+            return False
+        if self.disabled:
+            self.probing = False
+            if accepted / drafted >= accept_floor():
+                self.disabled = False
+                self.level = 0
+                self.recent.clear()
+                self.recent.append((drafted, accepted))
+                note_spec_reprobe(recovered=True)
+            else:
+                self.recent.append((drafted, accepted))
+                if accepted:
+                    self.level = 0
+                else:
+                    self.level += self.interval() < self.ceiling()
+                note_spec_reprobe(recovered=False)
+            return False
+        self.recent.append((drafted, accepted))
+        if (len(self.recent) >= SPEC_MIN_DISPATCHES
+                and self.rate() < accept_floor()):
+            self.disabled = True
+            return True
+        return False
+
+
+class RowSpec(Throttle):
+    """Per-row speculation state: the drafter plus the row's own
+    throttle, on the clock of its committed tokens (ISSUE 13:
     drafter-aware — `kind` labels the metrics, and a throttled row
     periodically re-probes instead of staying dark for its whole
     turn)."""
 
-    __slots__ = ("drafter", "kind", "drafted", "accepted", "recent",
-                 "disabled", "probing", "_idle_mark", "ctx")
+    __slots__ = ("drafter", "kind", "drafted", "accepted", "ctx")
 
     def __init__(self, prompt_tokens: Optional[list[int]] = None,
                  kind: str = "ngram"):
+        super().__init__()
         # Device-batched drafters (model/lora) keep their state in the
         # draft slots the DeviceDrafter coordinator owns; only the
         # ngram drafter lives here per row — and only once its prompt
@@ -376,83 +504,83 @@ class RowSpec:
         self.kind = kind
         self.drafted = 0
         self.accepted = 0
-        # (drafted, accepted) per verify dispatch that actually drafted.
-        self.recent: deque = deque(maxlen=SPEC_WINDOW)
-        self.disabled = False
-        # Re-probe bookkeeping: produced-token mark at throttle time —
-        # pure function of row state, so the probe decision is
-        # idempotent across the scheduler's probe and real calls.
-        self.probing = False
-        self._idle_mark = 0
         # Device-drafter context cache (prompt + produced), extended
         # O(delta) per tick by the scheduler instead of re-concatenated
         # O(transcript) — read-only inside DeviceDrafter.propose.
         self.ctx: Optional[list[int]] = None
 
-    def rate(self) -> float:
-        d = sum(x for x, _ in self.recent)
-        return (sum(a for _, a in self.recent) / d) if d else 0.0
-
-    def should_draft(self, produced_len: int) -> bool:
-        """Whether this row drafts this tick: unthrottled rows always;
-        throttled rows once every `reprobe_interval()` committed tokens
-        (the re-probe — ISSUE 13 satellite). Once a probe fires it
-        stays armed until the next note(), so the scheduler's probe
-        call and the real segment see the same answer."""
-        if not self.disabled:
-            return True
-        if self.probing:
-            return True
-        if produced_len - self._idle_mark >= reprobe_interval():
-            self.probing = True
-            return True
-        return False
-
-    def mark_idle(self, produced_len: int) -> None:
-        """Restart the re-probe interval (called by the scheduler when
-        a dispatch leaves the row throttled)."""
-        self._idle_mark = produced_len
-
-    def probe_failed(self, produced_len: int) -> None:
-        """Resolve an armed probe that never reached a verify dispatch
-        (the drafter proposed NOTHING for the probing row): clear the
-        arm and restart the interval — otherwise `probing` stays True
-        forever and the row pays per-tick draft host work for the rest
-        of its turn, exactly the overhead the throttle exists to
-        remove."""
-        if self.probing:
-            self.probing = False
-            self._idle_mark = produced_len
-            note_spec_reprobe(recovered=False)
+    def judged(self) -> bool:
+        """Whether the row's own window decides if it drafts: it is
+        throttled (its own re-probe runs), or its drafts land. A row
+        that is neither has no verdict of its own yet and drafts when
+        the batch's throttle says so."""
+        return self.disabled or self.accepting()
 
     def note(self, drafted: int, accepted: int) -> bool:
-        """Record one verify dispatch's outcome. Returns True when THIS
-        call tripped the throttle (the caller emits the one flight
-        event). A throttled row's re-probe RECOVERS here: when the
-        probe's own acceptance clears the floor, the row re-enables
-        with a fresh window (hysteresis — the stale all-zero window
-        must not immediately re-trip it)."""
-        if drafted <= 0:
-            return False
-        self.drafted += drafted
-        self.accepted += accepted
-        if self.disabled:
-            self.probing = False
-            if accepted / drafted >= accept_floor():
-                self.disabled = False
-                self.recent.clear()
-                self.recent.append((drafted, accepted))
-                note_spec_reprobe(recovered=True)
-            else:
-                self.recent.append((drafted, accepted))
-                note_spec_reprobe(recovered=False)
-            return False
-        self.recent.append((drafted, accepted))
-        if (len(self.recent) >= SPEC_MIN_DISPATCHES
-                and self.rate() < accept_floor()):
-            self.disabled = True
+        if drafted > 0:
+            self.drafted += drafted
+            self.accepted += accepted
+        return super().note(drafted, accepted)
+
+
+class BatchThrottle(Throttle):
+    """The throttle of the rows that have no verdict of their own
+    (ISSUE 43; the constants above say why their evidence is pooled).
+    Its window holds what those rows drafted and accepted, summed, a
+    verify; its clock is the decode steps the scheduler has run, and
+    its base interval one decode segment, which is what a tick without
+    a verify runs. The engine owns one — it outlives requests, sessions
+    and schedulers, as the traffic's habit of accepting or not does —
+    and the scheduler's loop thread is its one writer: `advance` with
+    every segment's decode steps, `asks` before the unjudged rows are
+    asked for drafts, `note` with what they drafted and accepted.
+    `counts` are lifetime totals (describe()["spec_decode"]): `probes`
+    — verifies issued for it while throttled; `probes_accepted_none` —
+    those in which its rows accepted no drafted token;
+    `probes_backed_off` — ticks on which its rows were not asked
+    because the interval had not passed. `budget` is the shortest
+    answer among the rows it was last asked for, and its ceiling: a
+    row that decodes its whole budget is asked at least once."""
+
+    __slots__ = ("steps", "budget", "counts", "_barred_tick")
+
+    def __init__(self, segment: int):
+        super().__init__(base=segment)
+        self.steps = 0
+        self.budget = SPEC_REPROBE_CEILING
+        self.counts = {"probes": 0, "probes_accepted_none": 0,
+                       "probes_backed_off": 0}
+        self._barred_tick = None
+
+    def advance(self, steps: int) -> None:
+        self.steps += steps
+
+    def ceiling(self) -> int:
+        return min(SPEC_REPROBE_CEILING, self.budget)
+
+    def asks(self, tick: int, budget: int, ahead: int = 0) -> bool:
+        """Whether the unjudged rows draft now — or `ahead` steps on,
+        where the loop asks with a segment of that many in flight.
+        `budget`: the shortest max_new among them. A barred tick
+        counts once, however often the loop asks in it."""
+        self.budget = budget
+        if self.should_draft(self.steps + ahead):
             return True
+        if tick != self._barred_tick:
+            self._barred_tick = tick
+            self.counts["probes_backed_off"] += 1
         return False
+
+    def note(self, drafted: int, accepted: int) -> bool:
+        if drafted <= 0:
+            return False    # a verify of judged rows alone: not its
+        if self.disabled:
+            self.counts["probes"] += 1
+            self.counts["probes_accepted_none"] += not accepted
+        tripped = super().note(drafted, accepted)
+        if self.disabled:
+            self.mark_idle(self.steps)
+        return tripped
 
 
 def accept_prefix(drafts: list[int],

@@ -1067,6 +1067,9 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "indexed_in_flight": "roundtable_sched_indexed_in_flight_total",
         "indexed_at_draft": "roundtable_sched_indexed_at_draft_total",
         "spec_segments": "roundtable_sched_spec_segments_total",
+        "probe_intervals": "derived (the live rows' own re-probe "
+                           "intervals, committed tokens -> rows; 0 = "
+                           "not throttled; describe-only)",
         "segment_prefill_tokens":
             "roundtable_segment_prefill_tokens_total",
         "segment_decode_tokens":
@@ -1131,6 +1134,18 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "by_drafter": "per-drafter split of the drafted/accepted "
                       "counters (same writer)",
         "throttled_rows": "spec_throttle flight events (one per trip)",
+        "probes": "derived (verifies that were the batch throttle's "
+                  "re-probe; `probes` on the spec `segment` spans)",
+        "probes_accepted_none": "derived (probes in which its rows "
+                                "accepted no drafted token; "
+                                "`probes_accepted_none` on the spec "
+                                "`segment` spans)",
+        "probes_backed_off": "derived (ticks on which the batch "
+                             "throttle kept its rows from being asked "
+                             "for drafts; describe-only)",
+        "probe_interval": "derived (decode steps between the batch "
+                          "throttle's probes, 0 = not throttled; "
+                          "describe-only)",
         "tree_nodes": "roundtable_spec_tree_nodes_total{drafter=...}",
         "tree_rows": "derived (tree-row share of verify dispatches)",
         "draft_dispatches": "ragged provenance ring entries with "
