@@ -961,6 +961,43 @@ def test_retention_step_kernel_compiles_for_v5e(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < state // 100
 
 
+# The cells' pools as the page copier takes them (ISSUE 45): pages, a
+# page's trailing shape, pools a layer.
+COPIER_POOLS = {
+    "mistral-7b": (640, (PAGE, 8, D), 2),
+    "nemotron-3-nano": (640, (PAGE, 2, D), 2),
+    "mellum2-12b": (1024, (PAGE, 4, D), 2),
+    "a.x-k1": (640, (PAGE, 640), 1),
+}
+
+
+@pytest.mark.parametrize("width", [8, 32])
+@pytest.mark.parametrize("cell", list(COPIER_POOLS))
+def test_page_copier_compiles_and_copies_no_pool_whole(one_chip, cell,
+                                                       width):
+    """engine/pallas/page_copy.py takes the pools where they lie: no
+    temporary, and no `copy` of a pool on the way in or out — what
+    XLA's scatter holds at Mellum's `[1024, 128, 4, 128]`, a whole pool
+    in and a whole pool out a call (PERF.md, PR 45)."""
+    from theroundtaible_tpu.engine.pallas import page_copy
+    pages, tail, per_layer = COPIER_POOLS[cell]
+    pools = [tuple(jax.ShapeDtypeStruct((pages, *tail), jnp.bfloat16,
+                                        sharding=one_chip)
+                   for _ in range(per_layer)) for _ in range(2)]
+    ids = jax.ShapeDtypeStruct((width,), jnp.int32, sharding=one_chip)
+    compiled = page_copy.copy_pages.lower(pools, ids, ids).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and " copy(" not in text
+    if cell == "mellum2-12b" and width == 8:
+        scatter = jax.jit(lambda pools, src, dst: [
+            tuple(p.at[dst].set(p[src]) for p in layer)
+            for layer in pools], donate_argnums=(0,))
+        held = scatter.lower(pools, ids, ids).compile()
+        assert held.memory_analysis().temp_size_in_bytes >= (
+            pages * PAGE * 4 * D * 2)
+
+
 @pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
 def test_hybrid_step_of_the_brumby_cut_compiles(one_chip, monkeypatch,
                                                 program):

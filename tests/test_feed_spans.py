@@ -135,7 +135,7 @@ class TestRoundStartRungs:
         assert not any(c["parent_id"] in share_ids for c in copies)
         assert sum(c["attrs"]["share"] for c in copies) == len(ROUND)
 
-    def test_page_copy_is_a_flush_of_what_the_round_queued(self,
+    def test_page_copy_is_a_flush_of_what_the_round_queued(self, engine,
                                                            cold_round):
         """One `page_copy` span a flush — where a program takes the
         pools — with the copies it gathered by cause: fewer flushes
@@ -146,7 +146,10 @@ class TestRoundStartRungs:
         for c in copies:
             at = c["attrs"]
             assert set(at) == {"copies", "alias", "share", "cow",
-                               "pages", "programs"}
+                               "pages", "programs", "path"}
+            # the CPU has no Mosaic: XLA's gather and scatter, and why
+            assert at["path"] == engine.kv.page_copy_path \
+                == engine.declines["page_copy"]
             assert at["copies"] == (at["alias"] + at["share"]
                                     + at["cow"]) >= 1
             assert (at["pages"], at["programs"]) == (at["copies"], 1)
@@ -258,7 +261,7 @@ def test_page_copy_counts_by_cause_under_whatever_takes_the_pools(
     for r in recs:
         assert r["attrs"] == {"copies": 1, "alias": 0, "share": 0,
                               "cow": 0, "pages": 1, "programs": 1,
-                              cause: 1}
+                              "path": "unnamed", cause: 1}
         assert (r["trace_id"], r["parent_id"]) == (segment.trace_id,
                                                    segment.span_id)
 
@@ -426,7 +429,7 @@ class TestDescribeAndSeries:
                               engine="tiny-gemma", cause=c)
                      for c in ("alias", "share", "cow")},
                     total("roundtable_page_copy_programs_total",
-                          engine="tiny-gemma"))
+                          engine="tiny-gemma", path="unnamed"))
 
         kv = make_cache()
         for name in ("a", "b", "c"):

@@ -86,7 +86,8 @@ def test_prologue_then_decode_through_the_pages(engine):
     assert info["ragged"]["fallback_reason"] is None
     assert info["declines"] == {
         "spec_decode": "attn-layers:no-verify-program",
-        "grouped_product": "not on a TPU (no Mosaic): lax.ragged_dot"}
+        "grouped_product": "not on a TPU (no Mosaic): lax.ragged_dot",
+        "page_copy": "not on a TPU (no Mosaic): XLA's gather and scatter"}
     attn = info["attention"]
     assert set(attn) == set(telemetry.SURFACE_BINDINGS["engine_attention"])
     assert (attn["kv_heads"], attn["head_dim"], attn["gate"]) \

@@ -275,7 +275,8 @@ class TestStatusPerfRender:
             'engine="knight"} 60\n'
             'roundtable_page_copies_total{cause="alias",'
             'engine="knight"} 90\n'
-            'roundtable_page_copy_programs_total{engine="knight"} 20\n'
+            'roundtable_page_copy_programs_total{engine="knight",'
+            'path="dma"} 20\n'
             'roundtable_kv_pages_in_use{engine="knight"} 12\n'
             'roundtable_session_kv_bytes{engine="knight",'
             'session="s0"} 4194304\n')
@@ -297,7 +298,7 @@ class TestStatusPerfRender:
         assert "bw_util" not in out and "mfu" not in out
         # what each program of the page cache's copier gathered
         assert "Page copies" in out and "copies/program" in out
-        assert "knight 150 20 7.5 alias=90 share=60" in " ".join(
+        assert "knight 150 20 7.5 alias=90 share=60 dma" in " ".join(
             out.split())
         assert "Compile observatory" in out
         assert "Memory ledger" in out

@@ -750,9 +750,9 @@ def perf_status(session) -> int:
     the unified registry (ISSUE 6): the per-engine roofline table
     (ceiling, decode rate, and the seconds the scheduler's loop left
     the device unfed, by phase), the page copies each program of the
-    page cache's copier gathered, the compile observatory's history
-    and steady-state sentinel state, the memory ledger, and the
-    span-tree overhead breakdown."""
+    page cache's copier gathered and which copier that is, the compile
+    observatory's history and steady-state sentinel state, the memory
+    ledger, and the span-tree overhead breakdown."""
     from ..utils import perfmodel, telemetry
 
     print(style.bold(f"\n  Performance — session {session.name}"))
@@ -804,6 +804,7 @@ def perf_status(session) -> int:
     # the pools; copies a program says how much each flush gathered.
     copies: dict[str, dict[str, float]] = {}
     programs: dict[str, float] = {}
+    paths: dict[str, set[str]] = {}
     for key, v in perf.items():
         name, lb = key.split("{", 1)[0], _labels(key)
         eng = lb.get("engine", "?")
@@ -813,18 +814,21 @@ def perf_status(session) -> int:
             by_cause[cause] = by_cause.get(cause, 0.0) + v
         elif name == PAGE_COPY_PROGRAMS_SERIES:
             programs[eng] = programs.get(eng, 0.0) + v
+            paths.setdefault(eng, set()).add(lb.get("path", "?"))
     if copies:
         print(style.bold("\n  Page copies (per engine):"))
         print(style.dim("    engine              copies  programs"
-                        "  copies/program  by cause"))
+                        "  copies/program  by cause  path (dma, or why"
+                        " XLA's gather and scatter)"))
         for eng in sorted(copies):
             n, progs = sum(copies[eng].values()), programs.get(eng, 0.0)
             per = f"{n / progs:14.1f}" if progs else "             -"
             by_cause = " ".join(
                 f"{cause}={v:g}" for cause, v in sorted(
                     copies[eng].items(), key=lambda kv: -kv[1]))
+            path = " | ".join(sorted(paths.get(eng, "?")))
             print(style.dim(f"    {eng:<18}{n:8g}{progs:10g}  {per}"
-                            f"  {by_cause}"))
+                            f"  {by_cause}  {path}"))
 
     # --- compile observatory ---
     from ..engine import compile_watch

@@ -315,6 +315,7 @@ class InferenceEngine:
 
         if kv_layout == "paged":
             from jax.sharding import NamedSharding, PartitionSpec as P
+            from .pallas import page_copy
             from .paging import PagedKVCache
             from .sharding import DATA_AXIS, MODEL_AXIS, _fallback_replicated
             data_size = dict(self.mesh.shape).get("data", 1)
@@ -334,16 +335,27 @@ class InferenceEngine:
                 pool_sharding = NamedSharding(self.mesh, spec)
 
             @partial(jax.jit, donate_argnums=(0,))
-            def copy_pages(pools, src_ids, dst_ids):
-                # Whole-page copies (copy-on-write + alias boundaries):
-                # every source gathered, then scattered. The cache
-                # queues its copies and issues them here as numpy ids
-                # padded to one of paging.COPY_WIDTHS, so this compiles
-                # those shapes and no other (pad rows copy a scratch
-                # page onto itself — identical bytes, any scatter
-                # order); kv.warm_copier compiles each in warmup().
+            def scatter_pages(pools, src_ids, dst_ids):
+                # Whole-page copies as XLA has them: every source
+                # gathered, then scattered — what runs where the DMA
+                # copier declines. Over some layouts the scatter
+                # re-lays the whole pool out and back (PERF.md, PR 45).
                 return [tuple(p.at[dst_ids].set(p[src_ids]) for p in layer)
                         for layer in pools]
+
+            def copy_pages(pools, src_ids, dst_ids):
+                # Whole-page copies (copy-on-write + alias boundaries):
+                # the cache queues its copies and issues them here as
+                # numpy ids padded to one of paging.COPY_WIDTHS, so
+                # this compiles those shapes and no other (a pad row
+                # names a scratch page twice and moves no byte that
+                # differs); kv.warm_copier compiles each in warmup().
+                # DMAs in place where the pools allow them, one rule:
+                # pallas/page_copy.py.
+                copier = (page_copy.copy_pages
+                          if self.kv.page_copy_path == page_copy.PATH
+                          else scatter_pages)
+                return copier(pools, src_ids, dst_ids)
 
             # Default pool HALVES the contiguous HBM budget — and since
             # the page axis shards over "data", that is the TOTAL across
@@ -362,6 +374,11 @@ class InferenceEngine:
                 pool_sharding, page_size=page_size, num_pages=num_pages,
                 copy_pages_fn=copy_pages, data_size=data_size,
                 kv_quant=self.kv_quant_spec)
+            reason = page_copy.decline_reason(
+                jax.tree.leaves(self.kv.combined_pools()))
+            if reason is not None:
+                self.declines["page_copy"] = reason
+            self.kv.page_copy_path = reason or page_copy.PATH
         else:
             cache_sharding = None
             if self.mesh.devices.size > 1:

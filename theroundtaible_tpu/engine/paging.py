@@ -50,6 +50,7 @@ equivalent of vLLM/tpu-inference paged attention, re-designed for XLA.
 
 from __future__ import annotations
 
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -81,9 +82,10 @@ COPY_CAUSES = ("alias", "share", "cow")
 def plan_copy_calls(pairs: list[tuple[int, int]],
                     width: int) -> list[dict[int, int]]:
     """Queued (src, dst) page copies, in queue order, as calls of a
-    copier that GATHERS every source before it scatters (inside one
-    call all reads precede all writes, and a repeated destination has
-    no defined winner). -> one {dst: src} a call, at most `width`
+    copier whose pairs a call are INDEPENDENT: no destination is named
+    twice or is a source of the same call, so the copier may gather
+    first and scatter after, or move each pair in place in any order
+    (pallas/page_copy.py). -> one {dst: src} a call, at most `width`
     destinations each, whose calls in order leave every page with the
     bytes the copies made one by one would have left.
 
@@ -92,14 +94,15 @@ def plan_copy_calls(pairs: list[tuple[int, int]],
     B→C) reads that pair's own source (C takes A's bytes), and a
     destination queued twice keeps the last pair (the earlier never
     lands; whoever copied from it in between was resolved past it). A
-    full call closes and the next starts with nothing resolved, so
-    queue order holds across calls: a source freed, handed out again
-    and overwritten by a later pair was read in the same or an earlier
-    call."""
+    call closes when it is full, or when a pair's destination is a
+    page the call reads (A→B, then C→A: a page freed, handed out again
+    and overwritten while its copy is pending); the next starts with
+    nothing resolved, so queue order holds across calls."""
     calls: list[dict[int, int]] = []
     origin: dict[int, int] = {}
     for src, dst in pairs:
-        if dst not in origin and len(origin) == width:
+        if (dst not in origin and len(origin) == width) \
+                or dst in origin.values():
             calls.append(origin)
             origin = {}
         origin[dst] = origin.get(src, src)
@@ -121,11 +124,13 @@ class PagedSlot:
 class PagedKVCache:
     """Page-pool KV cache with the same slot interface as KVCache.
 
-    `copy_pages_fn(pools, src_ids, dst_ids)` is the engine-provided jit'd
-    program that copies whole pages; it is the only device operation the
-    allocator itself triggers, and it triggers it in ONE place (ISSUE
-    38). A page copy — a copy-on-write, an alias's or an attach's
-    boundary page — is a pair of host integers on a pending list
+    `copy_pages_fn(pools, src_ids, dst_ids)` is the engine-provided
+    program that copies whole pages (`page_copy_path` names which: DMAs
+    in place, or XLA's gather and scatter and why); it is the only
+    device operation the allocator itself triggers, and it triggers it
+    in ONE place (ISSUE 38). A page copy — a copy-on-write, an alias's
+    or an attach's boundary page — is a pair of host integers on a
+    pending list
     (`_run_page_copy`); `combined_pools()`, the one way the pool tree
     leaves the cache, issues everything pending as one call of the
     copier (a few beyond `COPY_WIDTHS[-1]` pairs) and installs the
@@ -226,6 +231,10 @@ class PagedKVCache:
         self.scales = (self._make_scales(self.num_pages)
                        if kv_quant is not None else None)
         self._copy_pages_fn = copy_pages_fn
+        # Which program that is: "dma" (pallas/page_copy.py), or why
+        # that declined these pools and XLA's gather and scatter runs.
+        # The engine names it once the pools exist.
+        self.page_copy_path = "unnamed"
         # Page copies queued and not yet issued: (src, dst, cause) in
         # queue order.
         self._pending: list[tuple[int, int, str]] = []
@@ -233,6 +242,7 @@ class PagedKVCache:
         # the quotient says how often the queue gathers anything.
         self.page_copies = dict.fromkeys(COPY_CAUSES, 0)
         self.page_copy_programs = 0
+        self.page_copy_programs_by_path: Counter[str] = Counter()
         self._slots: dict[str, PagedSlot] = {}
         # Replica r owns pages [r*per, (r+1)*per); the range's FIRST page
         # is that replica's scratch (never allocated, never aliased).
@@ -348,16 +358,16 @@ class PagedKVCache:
         as `plan_copy_calls` lays them out — scale rows ride the same
         call on quantized pools (a COW'd or adopted page without its
         scales would dequantize garbage). The id arrays are numpy,
-        padded to a width of the ladder with a scratch page (pad rows
-        copy it onto itself — identical bytes, any scatter order), and
-        go to the program as they are. The list is dropped first: a
-        donated call that fails takes the pools with it
-        (`revive_if_dead`). Nothing reads the copies back, so the
+        padded to a width of the ladder with a scratch page (a pad row
+        names it twice: identical bytes under any scatter order, and
+        no DMA at all), and go to the program as they are. The list is
+        dropped first: a donated call that fails takes the pools with
+        it (`revive_if_dead`). Nothing reads the copies back, so the
         flush does not feed the loop clock: armed, its host time is a
         `page_copy` span (ISSUE 37) under whatever the calling thread
         has open — `copies` queued, of them by cause, `pages` the
-        `programs` moved. The one writer of `page_copy_programs` and
-        its series."""
+        `programs` moved, by which `path`. The one writer of
+        `page_copy_programs`, of them by path, and their series."""
         pending, self._pending = self._pending, []
         span = telemetry.NULL_SPAN
         if telemetry.ACTIVE:
@@ -365,6 +375,7 @@ class PagedKVCache:
             for _src, _dst, cause in pending:
                 causes[cause] += 1
             span = telemetry.start_span("page_copy", copies=len(pending),
+                                        path=self.page_copy_path,
                                         **causes)
         calls = plan_copy_calls([(s, d) for s, d, _cause in pending],
                                 COPY_WIDTHS[-1])
@@ -378,8 +389,9 @@ class PagedKVCache:
             pools = self._copy(pools, ids[0], ids[1])
         self.set_combined(pools)
         self.page_copy_programs += len(calls)
+        self.page_copy_programs_by_path[self.page_copy_path] += len(calls)
         telemetry.inc("roundtable_page_copy_programs_total", len(calls),
-                      engine=self.cfg.name)
+                      engine=self.cfg.name, path=self.page_copy_path)
         if span is not telemetry.NULL_SPAN:
             span.attrs.update(pages=sum(len(c) for c in calls),
                               programs=len(calls))
@@ -412,6 +424,9 @@ class PagedKVCache:
             "page_copies": sum(self.page_copies.values()),
             "page_copies_by_cause": dict(self.page_copies),
             "page_copy_programs": self.page_copy_programs,
+            "page_copy_path": self.page_copy_path,
+            "page_copy_programs_by_path":
+                dict(self.page_copy_programs_by_path),
             "copy_widths": list(COPY_WIDTHS),
         }
 

@@ -1241,7 +1241,13 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "page_copies": "roundtable_page_copies_total (every cause)",
         "page_copies_by_cause":
             "roundtable_page_copies_total{cause=alias|share|cow}",
-        "page_copy_programs": "roundtable_page_copy_programs_total",
+        "page_copy_programs": "roundtable_page_copy_programs_total "
+                              "(every path)",
+        "page_copy_path": "static (\"dma\": pallas/page_copy.py; or why "
+                          "that declined and XLA's gather and scatter "
+                          "runs — describe()[\"declines\"][\"page_copy\"])",
+        "page_copy_programs_by_path":
+            "roundtable_page_copy_programs_total{path=...}",
         "copy_widths": "static (paging.COPY_WIDTHS, each compiled in "
                        "warmup)",
     },
