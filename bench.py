@@ -122,9 +122,8 @@ def child() -> int:
     failed: list[dict] = []  # configs that errored (emit records them)
     base_key = f"decode_tokens_per_sec_per_chip[{cfg.name}]"
 
-    def config_label(quant: str, kv_layout: str) -> str:
-        return ("bf16" if quant == "none" else quant) + \
-            ("-paged" if kv_layout == "paged" else "")
+    def config_label(quant: str) -> str:
+        return "bf16" if quant == "none" else quant
 
     def emit(run: dict, headline: bool) -> None:
         """Print one complete result record for `run` (flushed).
@@ -171,7 +170,7 @@ def child() -> int:
         }
         print(json.dumps(rec), flush=True)
 
-    def measure(quant: str, kv_layout: str = "contiguous") -> dict:
+    def measure(quant: str) -> dict:
         """Build + minimally warm one engine, return its measured run.
 
         Warmup serves the bench prompt itself on a throwaway slot: this
@@ -182,7 +181,7 @@ def child() -> int:
         so each is an honest full prefill."""
         t_build = time.monotonic()
         engine = InferenceEngine(
-            cfg, num_slots=4, quant=quant, kv_layout=kv_layout,
+            cfg, num_slots=4, quant=quant,
             sampling=SamplingParams(temperature=0.0,
                                     max_new_tokens=decode_tokens))
         build_s = time.monotonic() - t_build
@@ -213,7 +212,7 @@ def child() -> int:
 
         med, spread, repeats = timed_repeats(run_once)
         s = engine.last_stats
-        label = config_label(quant, kv_layout)
+        label = config_label(quant)
         # Path provenance (ISSUE 3): which einsum dispatches compiled to
         # the fused w4a16 kernels vs the XLA dequant fallback — the
         # window's int4 number must be attributable to the kernel, and
@@ -239,7 +238,7 @@ def child() -> int:
         run = {
             "label": label,
             "quant": quant,
-            "kv_layout": kv_layout,
+            "kv_layout": "paged",
             "decode_tps": round(med["decode_tps"], 2),
             "prefill_tps": round(med["prefill_tps"], 1),
             "prefill_tokens": s.prefill_tokens,
@@ -274,9 +273,7 @@ def child() -> int:
         return run
 
     # Measure bf16, int8 (the reference's llama.cpp baseline serves
-    # quantized weights, so int8 is the apples-to-apples config),
-    # int8+paged (the pool-direct decode kernel vs the contiguous layout
-    # — the paged-vs-contiguous delta VERDICT r2 #7 asks for) and int4
+    # quantized weights, so int8 is the apples-to-apples config) and int4
     # (grouped w4a16, engine/quant.py bits=4 — the llama.cpp default
     # precision CLASS, and another ~2× decode ceiling over int8 if the
     # unpack fuses into the matmul operand; its roofline block derives
@@ -290,10 +287,7 @@ def child() -> int:
     # re-measures. Its record carries `int4_paths` so the number is
     # attributable to the kernel path, never a silent XLA fallback.
     runs: list[dict] = []
-    for quant, kv_layout in (("int4", "contiguous"),
-                             ("none", "contiguous"),
-                             ("int8", "contiguous"),
-                             ("int8", "paged")):
+    for quant in ("int4", "none", "int8"):
         # One config failing (e.g. a TPU-compile surprise in a config
         # whose kernels only ever ran on CPU) must not cost the others
         # their records — and above all must not cost the HEADLINE line,
@@ -302,15 +296,14 @@ def child() -> int:
         # isolates each sub-bench in its own watchdogged child, so this
         # loop does not belong in bench_common.)
         try:
-            run = measure(quant, kv_layout)
+            run = measure(quant)
         except Exception as e:  # noqa: BLE001 — recorded, not hidden
             # Full traceback to stderr: run_watchdogged surfaces its
             # tail, so a hardware-window failure stays diagnosable.
             import traceback
             traceback.print_exc(file=sys.stderr)
-            label = config_label(quant, kv_layout)
-            failed.append({"quant": quant, "kv_layout": kv_layout,
-                           "label": label,
+            label = config_label(quant)
+            failed.append({"quant": quant, "label": label,
                            "error": f"{type(e).__name__}: {e}"[:300]})
             # Complete record under a DISTINCT key: [label][failed] so
             # the forwarder attempt-stamps and dedups it, while a

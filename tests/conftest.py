@@ -334,8 +334,8 @@ def _kv_quant_guard(request):
     test that CLAIMS quantized-KV-page coverage must not silently serve
     bf16 pools — if no serving dispatch during the test ever READ a
     quantized page (kernel-dequant or XLA-dequant), the `kv_quant:`
-    config silently resolved off (kill-switch left armed, contiguous
-    layout, spec declined at construction) and the test's compression
+    config silently resolved off (kill-switch left armed, spec declined
+    at construction) and the test's compression
     claims are vacuous; fail LOUD. Decline/fallback/kill-switch unit
     tests (which legitimately serve bf16) mark allow_bf16=True."""
     marker = request.node.get_closest_marker("kv_quant")
@@ -350,8 +350,8 @@ def _kv_quant_guard(request):
         return
     assert kvq_mod.quant_dispatches() > 0, (
         "kv_quant-marked test recorded ZERO quantized-page dispatches: "
-        "serving silently ran bf16 pools (kill-switch armed? layout "
-        "contiguous? spec declined?) — mark allow_bf16=True only for "
+        "serving silently ran bf16 pools (kill-switch armed? spec "
+        "declined?) — mark allow_bf16=True only for "
         "decline/fallback/kill-switch units")
 
 

@@ -359,11 +359,11 @@ def paged_engine():
 
 
 @pytest.fixture(scope="module")
-def contiguous_engine():
+def hybrid_engine():
     from theroundtaible_tpu.engine.engine import InferenceEngine
     from theroundtaible_tpu.engine.models.registry import get_model_config
-    cfg = get_model_config("tiny-gemma", max_seq_len=512)
-    return InferenceEngine(cfg, num_slots=4, kv_layout="contiguous",
+    return InferenceEngine(get_model_config("tiny-nemotron-h"), num_slots=4,
+                           page_size=16,
                            mesh_shape={"data": 1, "model": 1})
 
 
@@ -377,10 +377,13 @@ class TestEngineAudit:
         found = audit_engine(paged_engine)
         assert found == [], "\n".join(f.render() for f in found)
 
-    def test_contiguous_engine_audits_clean(self, contiguous_engine):
-        names = {s.name for s in collect_programs(contiguous_engine)}
-        assert names == {"prefill[slots]", "decode[slots]"}
-        found = audit_engine(contiguous_engine)
+    def test_hybrid_engine_audits_clean(self, hybrid_engine):
+        """`lint --jaxpr`'s second engine: the three step programs of a
+        model with recurrent state."""
+        names = {s.name for s in collect_programs(hybrid_engine)}
+        assert names == {"prefill[hybrid]", "decode[hybrid]",
+                         "ragged[hybrid]"}
+        found = audit_engine(hybrid_engine)
         assert found == [], "\n".join(f.render() for f in found)
 
     def test_decode_grid_replays_same_bucket_occupancies(self,

@@ -225,7 +225,7 @@ class TestWatchdog:
 
 def _drain_cfg(seed):
     return {"model": "tiny-gemma", "max_seq_len": 256, "num_slots": 2,
-            "seed": seed,
+            "attn": "dense", "page_size": 32, "seed": seed,
             "sampling": {"temperature": 0.0, "max_new_tokens": 8}}
 
 
@@ -262,7 +262,7 @@ class TestDrain:
         drain's KV flush must release its slots through the paged
         release path — pages decref and free back to their replica
         ranges, not just slot records dropped."""
-        cfg = dict(_drain_cfg(202), kv_layout="paged", page_size=32)
+        cfg = _drain_cfg(202)
         eng = get_engine(cfg)
         eng.generate("warm the paged slot", slot_name="P",
                      max_new_tokens=4)

@@ -12,7 +12,6 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from theroundtaible_tpu.engine.engine import InferenceEngine, _bucket
-from theroundtaible_tpu.engine.kvcache import KVCache
 from theroundtaible_tpu.engine.models.common import (
     forward,
     init_params,
@@ -134,6 +133,7 @@ class TestModelCore:
         from theroundtaible_tpu.engine.engine import InferenceEngine
         eng = InferenceEngine(
             get_model_config("tiny-llama", max_seq_len=64), num_slots=2,
+            page_size=32,
             sampling=SamplingParams(temperature=0.0, max_new_tokens=6))
         with pytest.raises(ValueError, match="decode\\s+reserve"):
             eng.generate("any prompt at all", slot_name="x",
@@ -384,61 +384,6 @@ class TestConditionalPool:
         assert sampler_mode([SamplingParams(**r) for r in rows]) == mode
 
 
-class TestKVCacheSlots:
-    def test_acquire_release(self):
-        cfg = get_model_config("tiny-gemma")
-        kv = KVCache(cfg, num_slots=2)
-        a = kv.acquire("A")
-        b = kv.acquire("B")
-        assert {a.slot_id, b.slot_id} == {0, 1}
-        assert kv.acquire("A").slot_id == a.slot_id  # stable
-        kv.release("A")
-        c = kv.acquire("C")
-        assert c.slot_id == a.slot_id  # recycled
-
-    def test_eviction_on_overflow(self):
-        cfg = get_model_config("tiny-gemma")
-        kv = KVCache(cfg, num_slots=1)
-        kv.acquire("A")
-        kv.commit("A", [1, 2, 3])
-        kv.acquire("B")  # evicts A
-        assert kv.slot_names() == ["B"]
-
-    def test_reuse_plan_prefix(self):
-        cfg = get_model_config("tiny-gemma")
-        kv = KVCache(cfg, num_slots=2)
-        kv.commit("A", [1, 2, 3, 4])
-        _, reuse = kv.reuse_plan("A", [1, 2, 3, 4, 5, 6])
-        assert reuse == 4
-        kv.commit("A", [1, 2, 3, 4])
-        _, reuse = kv.reuse_plan("A", [1, 2, 9, 9])
-        assert reuse == 2
-        # full-match capped at len-1 so one token is always fed
-        kv.commit("A", [1, 2, 3, 4])
-        _, reuse = kv.reuse_plan("A", [1, 2, 3, 4])
-        assert reuse == 3
-
-    def test_reuse_plan_truncates_record_for_crash_safety(self):
-        # Positions >= reuse get overwritten by the in-flight turn; if that
-        # turn dies (timeout) before commit, the slot must not still claim
-        # the clobbered region as valid cache.
-        cfg = get_model_config("tiny-gemma")
-        kv = KVCache(cfg, num_slots=2)
-        kv.commit("A", [1, 2, 3, 4])
-        kv.reuse_plan("A", [1, 2, 9, 9])  # turn starts, then "crashes"
-        _, reuse = kv.reuse_plan("A", [1, 2, 3, 4])
-        assert reuse == 2  # only the untouched prefix survives
-
-    def test_eviction_is_lru_not_fifo(self):
-        cfg = get_model_config("tiny-gemma")
-        kv = KVCache(cfg, num_slots=2)
-        kv.acquire("A")
-        kv.acquire("B")
-        kv.acquire("A")  # A is now most recently used
-        kv.acquire("C")  # must evict B, the LRU — not A, the first-inserted
-        assert set(kv.slot_names()) == {"A", "C"}
-
-
 class TestEngineGenerate:
     def test_generate_deterministic_greedy(self, tiny_engine):
         tiny_engine.kv.reset_slot("g1")
@@ -673,7 +618,7 @@ class TestFirstTokenProgram:
 
 class TestSharedPrefix:
     """Cross-knight shared-prefix reuse (SURVEY §7.3 hard part 2,
-    VERDICT r1 #3): K/V spans copied between slots instead of
+    VERDICT r1 #3): K/V spans shared between slots instead of
     re-prefilling the common context+transcript preamble."""
 
     SHARED = ("The roundtable context: the codebase uses a session store "
@@ -681,15 +626,17 @@ class TestSharedPrefix:
               "Transcript so far: knight A proposed caching; knight B "
               "objected on memory grounds; scores were 7 and 5. ")
 
-    def _fresh_engine(self):
+    def _fresh_engine(self, **kw):
         return InferenceEngine(
             get_model_config("tiny-gemma"), num_slots=4,
-            sampling=SamplingParams(temperature=0.0, max_new_tokens=8))
+            sampling=SamplingParams(temperature=0.0, max_new_tokens=8),
+            **kw)
 
     def _control(self, prompts):
-        """Full-prefill outputs: every slot's record cleared between calls
-        so neither own-slot LCP nor donor copies can kick in."""
-        eng = self._fresh_engine()
+        """Full-prefill outputs: every slot released between calls and no
+        prefix index to keep its pages, so neither own-slot LCP, donor
+        shares nor an index hit can kick in."""
+        eng = self._fresh_engine(prefix_cache=False)
         outs = []
         for name, p in prompts:
             for n in list(eng.kv.slot_names()):
@@ -699,7 +646,7 @@ class TestSharedPrefix:
         return outs
 
     def test_donor_reuse_across_slot_names(self):
-        """Knight B's FRESH slot copies knight A's committed K/V for the
+        """Knight B's FRESH slot takes knight A's committed K/V for the
         shared preamble — reuse across different slot names."""
         eng = self._fresh_engine()
         prompts = [("knight-a", self.SHARED + "You are A. Respond."),
@@ -715,7 +662,7 @@ class TestSharedPrefix:
 
     def test_batch_leader_shares_prefix(self):
         """3-knight fresh batch: the shared span prefills once, the other
-        rows copy it — prefill_tokens ≈ shared + Σ small deltas."""
+        rows alias it — prefill_tokens ≈ shared + Σ small deltas."""
         eng = self._fresh_engine()
         tails = ["You are A. Speak.", "You are B. Speak.",
                  "You are C. Speak."]
@@ -744,7 +691,7 @@ class TestSharedPrefix:
         assert self._control(p2) == outs
 
     def test_short_prefix_not_shared(self):
-        """Below MIN_SHARED_PREFIX the copy program must not dispatch."""
+        """Below MIN_SHARED_PREFIX nothing is shared."""
         eng = self._fresh_engine()
         outs, stats = eng.generate_batch_with_stats(
             [("x", "tiny common A"), ("y", "tiny common B")],
@@ -930,6 +877,7 @@ class TestReviewRegressions:
         corrupt the position-aligned cache (offsets would be clamped)."""
         engine = InferenceEngine(
             get_model_config("tiny-gemma", max_seq_len=160), num_slots=2,
+            page_size=32,
             sampling=SamplingParams(temperature=0.0, max_new_tokens=8))
         # turn 1 fills most of the cache; turn 2 adds a short suffix whose
         # 64-bucket pad would overrun 160 without the shrink logic.
@@ -962,9 +910,9 @@ class TestReviewRegressions:
         engine.generate_batch([("n1", "x"), ("n2", "y")], max_new_tokens=4)
         names = set(engine.kv.slot_names())
         assert names == {"n1", "n2"}
-        s1 = engine.kv.acquire("n1").slot_id
-        s2 = engine.kv.acquire("n2").slot_id
-        assert s1 != s2
+        s1, s2 = engine.kv.acquire("n1"), engine.kv.acquire("n2")
+        assert s1.pages and s2.pages
+        assert not set(s1.pages) & set(s2.pages)
 
     def test_oversized_max_new_clamped_not_garbage(self):
         engine = InferenceEngine(

@@ -580,21 +580,6 @@ class TestKvRevive:
             get_model_config
         return get_model_config("tiny-gemma", max_seq_len=64)
 
-    def test_kvcache_revive_after_donation_death(self):
-        from theroundtaible_tpu.engine.kvcache import KVCache
-        kv = KVCache(self._model_cfg(), num_slots=2, max_seq_len=64)
-        kv.acquire("Sage")
-        kv.commit("Sage", [1, 2, 3])
-        assert kv.revive_if_dead() is False     # alive ⇒ no-op
-        assert kv.slot_names() == ["Sage"]
-        for k, v in kv.layers:
-            k.delete()
-            v.delete()
-        assert kv.revive_if_dead() is True
-        assert not kv.layers[0][0].is_deleted()
-        assert kv.slot_names() == []            # nothing cached survives
-        kv.acquire("Sage")                      # slots usable again
-
     def test_paged_revive_resets_pages(self):
         from theroundtaible_tpu.engine.paging import PagedKVCache
         kv = PagedKVCache(self._model_cfg(), 2, max_seq_len=64,

@@ -74,8 +74,12 @@ def clean_faults():
 
 
 def make_engine(**kw):
+    """The suite's subject is not the kernels: the gather view (XLA
+    alone) over small pages."""
     cfg = get_model_config("tiny-gemma", **MODEL_KW)
     kw.setdefault("num_slots", 8)
+    kw.setdefault("attn", "dense")
+    kw.setdefault("page_size", 32)
     return InferenceEngine(cfg, **kw)
 
 

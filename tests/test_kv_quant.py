@@ -544,14 +544,6 @@ class TestDeclineAndLowering:
                                           bits=5)
         assert r == "kv_bits:5"
 
-    def test_engine_contiguous_layout_declines(self):
-        eng = InferenceEngine(
-            get_model_config("tiny-gemma", **MODEL_KW), num_slots=2,
-            kv_layout="contiguous", kv_quant="int8",
-            mesh_shape={"data": 1, "model": 1})
-        assert eng.kv_quant_spec is None
-        assert eng.kv_quant_reason == "kv_layout:contiguous"
-
     def _quant_pool(self, bits=8):
         spec = kvq.KVQuantSpec(bits=bits, group=32)
         pool_pages = 16

@@ -190,28 +190,29 @@ class TestEngineRingPath:
     def test_paged_ring_prefill_matches_chunked(self):
         """paged + seq_parallel (VERDICT r2 weak #5, last hole): the ring
         program's whole-sequence K/V scatters through the page tables;
-        decode + the follow-up delta turn must match the contiguous
-        chunked engine token for token."""
+        decode + the follow-up delta turn must match the cache-free
+        decode (tests/reference_decode.py) token for token."""
+        from reference_decode import assert_greedy
         cfg = get_model_config("tiny-gemma")
         sampling = SamplingParams(temperature=0.0, max_new_tokens=8)
         paged_ring = InferenceEngine(
             cfg, num_slots=2, sampling=sampling, seq_parallel=4,
-            long_threshold=32, kv_layout="paged", page_size=32)
-        chunked = InferenceEngine(cfg, num_slots=2, sampling=sampling)
-        prompt = "the quick brown fox jumps over the lazy dog " * 12
-        a = paged_ring.generate(prompt, slot_name="k")
-        assert a == chunked.generate(prompt, slot_name="k")
+            long_threshold=32, page_size=32, dtype=jnp.float32)
+        prompt = "the quick brown fox jumps over the lazy dog " * 8
+        assert_greedy(paged_ring, [("k", prompt)], 8)
+        a = paged_ring.generate(prompt, slot_name="k2")
         follow = prompt + a + " and then what happened next was "
-        a2 = paged_ring.generate(follow, slot_name="k")
+        assert_greedy(paged_ring, [("k", follow)], 8)
         assert paged_ring.last_stats.reused_tokens > 0
-        assert a2 == chunked.generate(follow, slot_name="k")
 
     def test_ring_prefill_then_decode_matches_chunked_engine(self):
         cfg = get_model_config("tiny-gemma")
         sampling = SamplingParams(temperature=0.0, max_new_tokens=8)
         ring_engine = InferenceEngine(cfg, num_slots=2, sampling=sampling,
-                                      seq_parallel=4, long_threshold=32)
-        chunked = InferenceEngine(cfg, num_slots=2, sampling=sampling)
+                                      seq_parallel=4, long_threshold=32,
+                                      page_size=32)
+        chunked = InferenceEngine(cfg, num_slots=2, sampling=sampling,
+                                  page_size=32)
         prompt = "the quick brown fox jumps over the lazy dog " * 12
         a = ring_engine.generate(prompt, slot_name="k")
         b = chunked.generate(prompt, slot_name="k")

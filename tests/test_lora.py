@@ -49,9 +49,11 @@ def _cfg(max_seq_len=256):
 
 @pytest.fixture(scope="module")
 def engine():
-    """One contiguous-layout LoRA engine shared by the direct-serving
-    tests (greedy sampling → deterministic parity)."""
+    """One LoRA engine on the gather view (XLA alone; `paged_engine`
+    takes the kernels) shared by the direct-serving tests (greedy
+    sampling → deterministic parity)."""
     return InferenceEngine(_cfg(), num_slots=6, mesh_shape=MESH1,
+                           attn="dense", page_size=32,
                            lora=dict(LORA_CFG))
 
 

@@ -27,8 +27,8 @@ class TestLcp:
         assert lcp(a, b) == 4096
 
     def test_kvcache_uses_it(self):
-        from theroundtaible_tpu.engine.kvcache import KVCache
-        assert KVCache.common_prefix_len([1, 2, 3], [1, 2, 5]) == 2
+        from theroundtaible_tpu.engine.paging import PagedKVCache
+        assert PagedKVCache.common_prefix_len([1, 2, 3], [1, 2, 5]) == 2
 
 
 @needs_native

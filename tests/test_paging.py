@@ -1,6 +1,6 @@
 """Paged KV cache: allocator invariants, HBM accounting, and end-to-end
-parity with the contiguous layout (VERDICT r1 missing #3; PAPERS.md
-"Ragged Paged Attention")."""
+token identity with the cache-free greedy decode of
+tests/reference_decode.py (PAPERS.md "Ragged Paged Attention")."""
 
 import numpy as np
 import pytest
@@ -12,6 +12,8 @@ from theroundtaible_tpu.engine.engine import InferenceEngine
 from theroundtaible_tpu.engine.models.registry import get_model_config
 from theroundtaible_tpu.engine.paging import PagedKVCache
 from theroundtaible_tpu.engine.sampling import SamplingParams
+
+from reference_decode import assert_greedy
 
 PS = 16  # small pages so tiny prompts span several
 
@@ -75,7 +77,7 @@ class TestAllocator:
         big = PagedKVCache(cfg, 8, 128, jnp.float32, page_size=PS,
                            num_pages=65, copy_pages_fn=None)
         assert small.hbm_bytes() * 7 < big.hbm_bytes()
-        # contiguous equivalent: 8 slots × 128 positions = 64 pages worth;
+        # a slot cache's equivalent: 8 slots × 128 positions = 64 pages worth;
         # the small pool serves the same slot COUNT in 1/7th the HBM
         assert small.num_pages == 9
 
@@ -149,6 +151,63 @@ class TestAllocator:
         kv.acquire("b")
         with pytest.raises(RuntimeError, match="exhaust"):
             kv.ensure_capacity("b", 32, write_from=0, pinned=("a", "b"))
+
+
+class TestSlotSurface:
+    """Named slots: acquire / release, LRU eviction, and the own-slot
+    reuse plan with its crash-safe truncation."""
+
+    def test_acquire_release(self):
+        kv = make_cache(num_slots=2)
+        a = kv.acquire("A")
+        kv.acquire("B")
+        assert kv.acquire("A") is a  # stable
+        kv.ensure_capacity("A", 2 * PS, write_from=0)
+        held = kv.pages_in_use()
+        kv.release("A")
+        assert kv.pages_in_use() == held - 2  # its pages went with it
+        kv.acquire("C")
+        assert set(kv.slot_names()) == {"B", "C"}
+
+    def test_eviction_on_overflow(self):
+        kv = make_cache(num_slots=1)
+        kv.acquire("A")
+        kv.commit("A", [1, 2, 3])
+        kv.acquire("B")  # evicts A
+        assert kv.slot_names() == ["B"]
+
+    def test_reuse_plan_prefix(self):
+        kv = make_cache(num_slots=2)
+        kv.commit("A", [1, 2, 3, 4])
+        _, reuse = kv.reuse_plan("A", [1, 2, 3, 4, 5, 6])
+        assert reuse == 4
+        kv.commit("A", [1, 2, 3, 4])
+        _, reuse = kv.reuse_plan("A", [1, 2, 9, 9])
+        assert reuse == 2
+        # full-match capped at len-1 so one token is always fed
+        kv.commit("A", [1, 2, 3, 4])
+        _, reuse = kv.reuse_plan("A", [1, 2, 3, 4])
+        assert reuse == 3
+
+    def test_reuse_plan_truncates_record_for_crash_safety(self):
+        # Positions >= reuse get overwritten by the in-flight turn; if that
+        # turn dies (timeout) before commit, the slot must not still claim
+        # the clobbered region as valid cache — nor hold pages beyond it.
+        kv = make_cache(num_slots=2)
+        kv.ensure_capacity("A", 3 * PS, write_from=0)
+        kv.commit("A", list(range(1, 3 * PS + 1)))
+        kv.reuse_plan("A", [1, 2, 900, 900])  # turn starts, then "crashes"
+        assert len(kv.acquire("A").pages) == 1
+        _, reuse = kv.reuse_plan("A", list(range(1, 3 * PS + 1)))
+        assert reuse == 2  # only the untouched prefix survives
+
+    def test_eviction_is_lru_not_fifo(self):
+        kv = make_cache(num_slots=2)
+        kv.acquire("A")
+        kv.acquire("B")
+        kv.acquire("A")  # A is now most recently used
+        kv.acquire("C")  # must evict B, the LRU — not A, the first-inserted
+        assert set(kv.slot_names()) == {"A", "C"}
 
 
 class TestPageLoans:
@@ -471,24 +530,20 @@ class TestCopyQueue:
 
 
 class TestPagedEngineParity:
-    """The paged engine must produce byte-identical greedy output to the
-    contiguous engine — same model, same seed, every serving feature."""
+    """The paged engine must produce the cache-free greedy decode's
+    tokens (reference_decode) — same parameters, every serving feature."""
 
-    def _engines(self, mesh=None, **kw):
-        def build(layout):
-            return InferenceEngine(
-                get_model_config("tiny-gemma", max_seq_len=256),
-                mesh_shape=mesh,
-                num_slots=4, kv_layout=layout, page_size=32,
-                sampling=SamplingParams(temperature=0.0, max_new_tokens=8),
-                **kw)
-        return build("paged"), build("contiguous")
+    def _engine(self, mesh=None, **kw):
+        return InferenceEngine(
+            get_model_config("tiny-gemma", max_seq_len=256),
+            mesh_shape=mesh, num_slots=4, page_size=32,
+            dtype=jnp.float32,
+            sampling=SamplingParams(temperature=0.0, max_new_tokens=8),
+            **kw)
 
     def test_generate_parity(self):
-        paged, dense = self._engines()
         p = "the knights debate the session store design at length"
-        assert (paged.generate(p, slot_name="a", max_new_tokens=8)
-                == dense.generate(p, slot_name="a", max_new_tokens=8))
+        assert_greedy(self._engine(), [("a", p)], 8)
 
     def test_warmup_compiles_every_width_of_the_copier(self,
                                                       monkeypatch):
@@ -500,7 +555,7 @@ class TestPagedEngineParity:
         from theroundtaible_tpu.engine.paging import COPY_WIDTHS
         paged = InferenceEngine(
             get_model_config("tiny-gemma", max_seq_len=256), num_slots=4,
-            kv_layout="paged", page_size=32, num_pages=64,
+            page_size=32, num_pages=64,
             sampling=SamplingParams(temperature=0.0, max_new_tokens=8))
         paged.warmup(max_prompt_tokens=32, batch_sizes=(1,))
         monkeypatch.setenv("ROUNDTABLE_RECOMPILE_STRICT", "1")
@@ -519,56 +574,48 @@ class TestPagedEngineParity:
             f"page_copy[w={w}]" for w in COPY_WIDTHS}
 
     def test_multiturn_delta_prefill_parity(self):
-        paged, dense = self._engines()
+        paged = self._engine()
         base = "round one establishes the shared context for everyone here."
         ext = base + " round two adds new arguments and asks for a score."
-        outs = []
-        for eng in (paged, dense):
-            eng.generate(base, slot_name="k", max_new_tokens=8)
-            outs.append(eng.generate(ext, slot_name="k", max_new_tokens=8))
-            assert eng.last_stats.reused_tokens > 0
-        assert outs[0] == outs[1]
+        paged.generate(base, slot_name="k", max_new_tokens=8)
+        assert_greedy(paged, [("k", ext)], 8)
+        assert paged.last_stats.reused_tokens > 0
 
     def test_batch_with_shared_prefix_parity(self):
-        paged, dense = self._engines()
+        paged = self._engine()
         shared = ("the common context paragraph that every knight receives "
                   "before their personal instructions begin here. ")
         prompts = [(f"kn{i}", shared + f"You are knight {i}.")
                    for i in range(3)]
-        out_p, stats_p = paged.generate_batch_with_stats(
-            prompts, max_new_tokens=8)
-        out_d, stats_d = dense.generate_batch_with_stats(
-            prompts, max_new_tokens=8)
-        assert out_p == out_d
-        # both layouts shared the prefix; paged did it by aliasing
-        assert stats_p.reused_tokens > 0
-        assert stats_p.reused_tokens == stats_d.reused_tokens
+        ids = assert_greedy(paged, prompts, 8)
+        # the leader prefilled the common span once; the other two
+        # aliased its pages
+        common = len(paged.tokenizer.encode(shared)) - 1
+        assert paged.last_stats.reused_tokens >= 2 * (common - 1)
+        assert paged.last_stats.prefill_tokens < sum(map(len, ids))
 
     def test_ring_prefill_with_replica_padding(self):
         """data>1 pool-direct + seq_parallel: fresh long prompts take the
         ring program with replica-PADDED rows (regression: _prefill_ring
         sized its arrays from the unpadded slot_ids and crashed on any
-        padded batch). Uneven groups + a pad row, parity vs chunked."""
+        padded batch). Uneven groups + a pad row, against the
+        cache-free decode."""
         cfg = get_model_config("tiny-llama", max_seq_len=512)
         sp = SamplingParams(temperature=0.0, max_new_tokens=8)
         ring = InferenceEngine(
             cfg, mesh_shape={"data": 2, "model": 2}, num_slots=4,
-            kv_layout="paged", page_size=32, num_pages=40,
+            page_size=32, num_pages=40,
             dtype=jnp.float32, seed=3,
             seq_parallel=4, long_threshold=32, sampling=sp)
-        ref = InferenceEngine(cfg, mesh_shape={"data": 2, "model": 2},
-                              num_slots=4, dtype=jnp.float32, seed=3,
-                              sampling=sp)
         assert ring.paged_direct and ring._paged_replicas == 2
         bos = ring.tokenizer.bos_id
         prompts = [("a", [bos] + [7] * 255),   # tpad 256 → ring path
                    ("b", [bos] + [9] * 199),
                    ("c", [bos] + [11] * 179)]  # 3 rows / 2 replicas → pad
-        assert (ring.generate_batch(prompts, max_new_tokens=8)
-                == ref.generate_batch(prompts, max_new_tokens=8))
+        assert_greedy(ring, prompts, 8)
 
     def test_paged_engine_pages_scale_with_use(self):
-        paged, _ = self._engines()
+        paged = self._engine()
         paged.generate("short", slot_name="s", max_new_tokens=8)
         used_short = paged.kv.pages_in_use()
         paged.generate("a much longer prompt " * 8, slot_name="l",
@@ -581,39 +628,32 @@ class TestPagedEngineParity:
     def test_single_device_uses_pool_direct_decode(self):
         """On a 1-device mesh the decode segment must run the page-table-
         aware kernel (no [B,S,K,D] gather view) and stay token-identical
-        to the contiguous engine — incl. multi-turn delta prefill and a
+        to the cache-free decode — incl. multi-turn delta prefill and a
         batch, so frontier-page writes and table-following reads are both
         proven. (The suite's other parity tests run the default 8-device
         mesh = the gather-view path.)"""
         one_dev = {"data": 1, "model": 1}
-        paged, dense = self._engines(mesh=one_dev)
+        paged = self._engine(mesh=one_dev)
         assert paged.paged_direct is True
         assert paged.describe()["paged_decode"] == "pool-direct"
         base = "the pool direct decode must follow the page table exactly."
         ext = base + " a second turn extends across a page boundary here."
-        for eng in (paged, dense):
-            eng.generate(base, slot_name="k", max_new_tokens=8)
-        assert (paged.generate(ext, slot_name="k", max_new_tokens=8)
-                == dense.generate(ext, slot_name="k", max_new_tokens=8))
+        paged.generate(base, slot_name="k", max_new_tokens=8)
+        assert_greedy(paged, [("k", ext)], 8)
         assert paged.last_stats.reused_tokens > 0
-        prompts = [(f"kn{i}", base + f" knight {i} speaks.")
-                   for i in range(3)]
-        assert (paged.generate_batch(prompts, max_new_tokens=8)
-                == dense.generate_batch(prompts, max_new_tokens=8))
+        assert_greedy(paged, [(f"kn{i}", base + f" knight {i} speaks.")
+                              for i in range(3)], 8)
 
-    def test_tp_mesh_pool_direct_matches_contiguous(self):
+    def test_tp_mesh_pool_direct_matches_the_cache_free_decode(self):
         """Multi-device pool-direct (paged_decode_spmd: kv heads on the
         model axis, matching the pool's sharding) must stay token-
-        identical to the contiguous engine on the same TP mesh."""
-        mesh = {"data": 1, "model": 2}
-        paged, dense = self._engines(mesh=mesh)
+        identical to the cache-free decode of the same parameters."""
+        paged = self._engine(mesh={"data": 1, "model": 2})
         assert paged.paged_direct is True
         base = "the sharded pool direct decode follows its page table."
         ext = base + " the second turn crosses a page boundary again."
-        for eng in (paged, dense):
-            eng.generate(base, slot_name="k", max_new_tokens=8)
-        assert (paged.generate(ext, slot_name="k", max_new_tokens=8)
-                == dense.generate(ext, slot_name="k", max_new_tokens=8))
+        paged.generate(base, slot_name="k", max_new_tokens=8)
+        assert_greedy(paged, [("k", ext)], 8)
         assert paged.last_stats.reused_tokens > 0
 
     def test_tp_mesh_pool_direct_mqa_replicated_kv(self):
@@ -621,26 +661,19 @@ class TestPagedEngineParity:
         replicates, only q heads shard; pool-direct must still match."""
         cfg = get_model_config("tiny-gemma", max_seq_len=256,
                                num_kv_heads=1)
-        mesh = {"data": 1, "model": 2}
-
-        def build(layout):
-            return InferenceEngine(
-                cfg, mesh_shape=mesh, num_slots=2, kv_layout=layout,
-                page_size=32,
-                sampling=SamplingParams(temperature=0.0,
-                                        max_new_tokens=8))
-
-        paged, dense = build("paged"), build("contiguous")
+        paged = InferenceEngine(
+            cfg, mesh_shape={"data": 1, "model": 2}, num_slots=2,
+            page_size=32, dtype=jnp.float32,
+            sampling=SamplingParams(temperature=0.0, max_new_tokens=8))
         assert paged.paged_direct is True
         p = "one kv head shared by every query head across two devices"
-        assert (paged.generate(p, slot_name="m", max_new_tokens=8)
-                == dense.generate(p, slot_name="m", max_new_tokens=8))
+        assert_greedy(paged, [("m", p)], 8)
 
     def test_timeout_mid_serve_leaves_engine_serviceable(self):
         """A deadline hit mid-call must leave the pool/allocator in a
         state where the next call serves normally (slot records are
         truncated first, so interrupted turns only under-claim)."""
-        paged, _ = self._engines(mesh={"data": 1, "model": 1})
+        paged = self._engine(mesh={"data": 1, "model": 1})
         # >1 decode segment so work is genuinely unfinished at the
         # deadline check (a single-segment run that completes its whole
         # budget goes all-done and rightly does NOT time out)
@@ -649,7 +682,7 @@ class TestPagedEngineParity:
                            max_new_tokens=120, timeout_s=0.0)
         p = "recovery prompt after the timeout"
         out = paged.generate(p, slot_name="t", max_new_tokens=8)
-        fresh, _ = self._engines(mesh={"data": 1, "model": 1})
+        fresh = self._engine(mesh={"data": 1, "model": 1})
         assert out == fresh.generate(p, slot_name="f", max_new_tokens=8)
 
     def test_nonpartitionable_heads_fall_back_to_gather_view(self):
@@ -659,7 +692,7 @@ class TestPagedEngineParity:
         eng = InferenceEngine(
             get_model_config("tiny-gemma", max_seq_len=256),
             mesh_shape={"data": 1, "model": 3}, num_slots=4,
-            kv_layout="paged", page_size=32,
+            page_size=32,
             sampling=SamplingParams(temperature=0.0, max_new_tokens=8))
         assert eng.paged_direct is False
         assert eng.describe()["paged_decode"] == "gather-view"
@@ -674,7 +707,7 @@ class TestPagedEngineParity:
             return InferenceEngine(
                 get_model_config("tiny-gemma", max_seq_len=256),
                 mesh_shape={"data": 1, "model": 2}, num_slots=4,
-                kv_layout="paged", page_size=32, attn=attn,
+                page_size=32, attn=attn,
                 sampling=SamplingParams(temperature=0.0,
                                         max_new_tokens=8))
 
@@ -690,15 +723,49 @@ class TestPagedEngineParity:
         assert out_f == out_d
         assert stats_f.reused_tokens == stats_d.reused_tokens > 0
 
+    @pytest.mark.parametrize("mesh,model", [
+        ({"data": 1, "model": 1}, "tiny-gemma"),
+        ({"data": 1, "model": 2}, "tiny-gemma"),
+        ({"data": 2, "model": 2}, "tiny-llama"),
+    ], ids=["one-device", "tp", "data-sharded"])
+    def test_bf16_pool_direct_matches_the_gather_view(self, mesh, model):
+        """The dtype the cells serve in: at bfloat16 (the default) the
+        pool-direct kernels give the gather view's tokens on every mesh
+        kind — two knights that share a prefix, then both again with a
+        longer prompt (the delta alone prefills), same seed, greedy.
+        (The float32 cases above hold both to the cache-free decode.)"""
+        def build(attn):
+            return InferenceEngine(
+                get_model_config(model, max_seq_len=256),
+                mesh_shape=mesh, num_slots=4, page_size=32, attn=attn,
+                seed=3, sampling=SamplingParams(temperature=0.0,
+                                                max_new_tokens=8))
+
+        direct, view = build("auto"), build("dense")
+        assert direct.paged_direct is True and view.paged_direct is False
+        assert direct.kv.pools[0][0].dtype == jnp.bfloat16
+        shared = ("a long enough shared preamble that the aliasing path "
+                  "fires for every knight in the batch today. ")
+        first = [(f"kn{i}", shared + f"knight {i}") for i in range(2)]
+        second = [(n, p + " and the second round asks them both again.")
+                  for n, p in first]
+        for turns in (first, second):
+            out_d, stats_d = direct.generate_batch_with_stats(
+                turns, max_new_tokens=8)
+            out_v, stats_v = view.generate_batch_with_stats(
+                turns, max_new_tokens=8)
+            assert out_d == out_v
+            assert stats_d.reused_tokens == stats_v.reused_tokens > 0
+
     def test_paged_accepts_seq_parallel(self):
         """paged + seq_parallel now composes (ring K/V scatters through
         the page tables); the token-parity proof lives in
         test_longcontext.TestEngineRingPath."""
         eng = InferenceEngine(
             get_model_config("tiny-gemma", max_seq_len=256),
-            num_slots=2, kv_layout="paged", page_size=32, seq_parallel=8)
+            num_slots=2, page_size=32, seq_parallel=8)
         assert eng.seq_mesh is not None
-        assert eng.kv_layout == "paged"
+        assert eng.describe()["kv_layout"] == "paged"
 
 
 class TestPerReplicaPools:
@@ -820,24 +887,19 @@ class TestPerReplicaPools:
 class TestDataShardedPagedEngine:
     """End-to-end: on a (data, model) mesh the pool's page axis is
     physically sharded over "data" (per-device pool HBM = total/data) and
-    serving stays token-identical to the contiguous layout."""
+    serving stays token-identical to the cache-free decode."""
 
     MESH = {"data": 2, "model": 2}
 
-    def _engines(self):
-        cfg = get_model_config("tiny-llama", max_seq_len=256)
-        sp = SamplingParams(temperature=0.0, max_new_tokens=10)
-        paged = InferenceEngine(
-            cfg, mesh_shape=self.MESH, num_slots=4, kv_layout="paged",
+    def _engine(self):
+        return InferenceEngine(
+            get_model_config("tiny-llama", max_seq_len=256),
+            mesh_shape=self.MESH, num_slots=4,
             page_size=32, num_pages=34, dtype=jnp.float32, seed=3,
-            sampling=sp)
-        ref = InferenceEngine(
-            cfg, mesh_shape=self.MESH, num_slots=4, dtype=jnp.float32,
-            seed=3, sampling=sp)
-        return paged, ref
+            sampling=SamplingParams(temperature=0.0, max_new_tokens=10))
 
     def test_pool_page_axis_sharded_over_data(self):
-        paged, _ = self._engines()
+        paged = self._engine()
         k0 = paged.kv.pools[0][0]
         spec = tuple(k0.sharding.spec)
         assert spec[0] == "data"
@@ -850,17 +912,15 @@ class TestDataShardedPagedEngine:
 
     def test_odd_batch_pads_replica_groups(self):
         """3 rows over data=2 replicas (groups 2/1) force a pad row;
-        generations must be unaffected and identical to contiguous."""
-        paged, ref = self._engines()
+        generations must be unaffected: the cache-free decode's."""
+        paged = self._engine()
         prompts = [("a", "knight a considers the design."),
                    ("b", "knight b considers the design."),
                    ("c", "knight c considers the design.")]
-        assert (paged.generate_batch(prompts, max_new_tokens=10)
-                == ref.generate_batch(prompts, max_new_tokens=10))
+        assert_greedy(paged, prompts, 10)
         # single-row follow-up turn pads to one row per replica
-        one = [("b", prompts[1][1] + " and now a follow-up turn.")]
-        assert (paged.generate_batch(one, max_new_tokens=8)
-                == ref.generate_batch(one, max_new_tokens=8))
+        assert_greedy(
+            paged, [("b", prompts[1][1] + " and now a follow-up turn.")], 8)
 
     def test_warmup_covers_skewed_compositions(self):
         """b_padded depends on batch COMPOSITION (a 2-row batch on one
@@ -871,7 +931,7 @@ class TestDataShardedPagedEngine:
         cfg = get_model_config("tiny-llama", max_seq_len=256)
         eng = InferenceEngine(
             cfg, mesh_shape={"data": 2, "model": 2}, num_slots=3,
-            kv_layout="paged", page_size=32, dtype=jnp.float32, seed=3,
+            page_size=32, dtype=jnp.float32, seed=3,
             sampling=SamplingParams(temperature=0.0, max_new_tokens=4))
         # Record every padded DEVICE batch shape (ReplicaGroupPlan
         # b_padded) warmup compiles, then assert the skewed serve's
@@ -924,19 +984,16 @@ class TestDataShardedPagedEngine:
         assert list(padded[2]) == [100, 100]
 
     def test_batch_parity_with_cross_replica_sharing(self):
-        paged, ref = self._engines()
+        paged = self._engine()
         shared = ("a shared context preamble every knight receives "
                   "before its own tail marker. ")
         prompts = [("a", shared + "you are knight A"),
                    ("b", shared + "you are knight B"),
                    ("c", "a totally different question about pools"),
                    ("d", shared + "you are knight D")]
-        assert (paged.generate_batch(prompts, max_new_tokens=10)
-                == ref.generate_batch(prompts, max_new_tokens=10))
+        assert_greedy(paged, prompts, 10)
         replicas = {n: paged.kv._slots[n].replica for n, _ in prompts}
         assert sorted(replicas.values()) == [0, 0, 1, 1]
         # second turn: LCP delta against the replica-local pages
-        ext = [("a", prompts[0][1] + " and a follow-up")]
-        assert (paged.generate_batch(ext, max_new_tokens=8)
-                == ref.generate_batch(ext, max_new_tokens=8))
+        assert_greedy(paged, [("a", prompts[0][1] + " and a follow-up")], 8)
         assert paged.last_stats.reused_tokens > 0

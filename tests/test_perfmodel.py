@@ -191,7 +191,7 @@ class TestLiveGauges:
             "roundtable_decode_ceiling_tps", engine=eng.cfg.name) \
             == pytest.approx(eng.perf.decode_ceiling)
 
-    def test_memory_ledger_gauges_contiguous(self, monkeypatch):
+    def test_memory_ledger_gauges(self, monkeypatch):
         eng = _tiny_engine(monkeypatch)
         eng.generate("knights discuss the eastern gate",
                      slot_name="m", max_new_tokens=4)

@@ -726,12 +726,6 @@ class TestRaggedResolution:
         assert batch["page_visits"] == eights
         assert set(after) == set(telemetry.SURFACE_BINDINGS["engine_ragged"])
 
-    def test_contiguous_engine_has_no_ragged_seam(self):
-        eng = InferenceEngine(get_model_config("tiny-gemma", **MODEL_KW),
-                              num_slots=2, kv_layout="contiguous")
-        assert eng.ragged_enabled is False
-        assert "ragged" not in eng.describe()
-
     def test_dense_attn_resolves_xla_path(self):
         eng = make_engine(num_slots=2, attn="dense")
         assert eng.ragged_enabled is True

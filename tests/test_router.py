@@ -171,7 +171,7 @@ class TestRoutingUnits:
         series (no replica key appears anywhere at N=1)."""
         sched = SimpleNamespace(
             paused=None,
-            engine=SimpleNamespace(kv_layout="contiguous", lora=None),
+            engine=SimpleNamespace(lora=None),
             journal=None,
             describe=lambda: {"admission": {"queued": 0}})
         adm = AdmissionController(sched, max_inflight=4,
@@ -194,8 +194,7 @@ class TestRoutingUnits:
         takes every series labeled with it to the grave."""
         def fake_replica(name, tname):
             eng = SimpleNamespace(
-                cfg=SimpleNamespace(name="tiny-gemma"),
-                kv_layout="contiguous")
+                cfg=SimpleNamespace(name="tiny-gemma"))
             sched = SimpleNamespace(
                 _tname=tname, replica=None, engine=eng,
                 describe=lambda: {"admission": {"paused": None,

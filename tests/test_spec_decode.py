@@ -277,13 +277,6 @@ class TestResolution:
         monkeypatch.delenv("ROUNDTABLE_SPEC_DECODE")
         assert sd.spec_enabled(None)  # default ON
 
-    def test_contiguous_engine_declines(self):
-        cfg = get_model_config("tiny-gemma", **MODEL_KW)
-        eng = InferenceEngine(cfg, num_slots=2,
-                              mesh_shape={"data": 1, "model": 1})
-        assert not eng.spec_decode
-        assert eng.spec_reason == "kv_layout:contiguous"
-
     def test_spec_max_draft_validation(self):
         cfg = get_model_config("tiny-gemma", **MODEL_KW)
         for bad in (0, 8):

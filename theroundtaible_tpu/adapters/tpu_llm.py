@@ -318,7 +318,7 @@ class TpuLlmAdapter(BaseAdapter):
             "prefill_tokens": stats.prefill_tokens,
             "reused_tokens": stats.reused_tokens,
             # Of which the CROSS-SESSION prefix cache served (ISSUE 7) —
-            # 0 on contiguous / cache-off engines.
+            # 0 on cache-off engines.
             "prefix_reused_tokens": stats.prefix_reused_tokens,
             "decode_tokens": stats.decode_tokens,
             "prefill_seconds": round(stats.prefill_seconds, 3),
@@ -437,7 +437,7 @@ class TpuLlmAdapter(BaseAdapter):
             # actually allocated). Scheduled sessions skip this: the
             # scheduler's _fail_request already released the failed
             # round's slots ON ITS OWN THREAD — releasing here would
-            # mutate shared SlotBook/PagedKVCache host state from the
+            # mutate shared PagedKVCache host state from the
             # session thread while the scheduler thread iterates it
             # (dict-changed-during-iteration crashes the loop and fails
             # every other session).

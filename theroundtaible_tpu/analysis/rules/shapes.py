@@ -31,7 +31,6 @@ STATIC_PARAMS: dict[str, frozenset[str]] = {
         {"t_budget", "s_max", "score_width", "copy_slots",
          "propose_width"}),
     "_decode_dispatch_paged": frozenset({"max_new"}),
-    "_decode_dispatch_slots": frozenset({"max_new"}),
     "_ragged_step": frozenset({"score_width", "propose_width"}),
 }
 

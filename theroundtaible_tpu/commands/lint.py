@@ -2,11 +2,11 @@
 
 Runs the AST rule engine (analysis/rules, allowlist-filtered) over the
 source tree and, with --jaxpr, the device-free jaxpr audit of every
-registered serving program on two toy CPU engines (contiguous, and
-paged + ragged + spec-tree + LoRA — together they register every
-program family: prefill, decode, ragged, spec-verify, propose,
-LoRA-setter). Exit code 1 on any unallowlisted finding — the CI /
-pre-chip contract: a statically detectable violation must never cost
+registered serving program on two toy CPU engines (a dense one with
+ragged + spec-tree + LoRA, and a hybrid one — together they register
+every program family: prefill, decode, ragged, spec-verify, propose,
+LoRA-setter, and the hybrid three). Exit code 1 on any unallowlisted
+finding — the CI / pre-chip contract: a statically detectable violation must never cost
 chip time.
 """
 
@@ -28,7 +28,7 @@ def _source_root() -> str:
 
 
 def _audit_findings() -> tuple[list, list[str]]:
-    """Build the three toy CPU engines and run the jaxpr audit; returns
+    """Build the two toy CPU engines and run the jaxpr audit; returns
     (findings, audited program names). Forces the CPU platform BEFORE
     first jax import — the audit is device-free by construction and
     must never touch (or wait on) a TPU."""
@@ -40,9 +40,7 @@ def _audit_findings() -> tuple[list, list[str]]:
 
     cfg = get_model_config("tiny-gemma", max_seq_len=512)
     engines = [
-        InferenceEngine(cfg, num_slots=4, kv_layout="contiguous",
-                        mesh_shape={"data": 1, "model": 1}),
-        InferenceEngine(cfg, num_slots=4, kv_layout="paged",
+        InferenceEngine(cfg, num_slots=4,
                         mesh_shape={"data": 1, "model": 1},
                         spec_decode={"drafter": "ngram",
                                      "tree": {"branch": 2, "depth": 2}},
@@ -50,7 +48,7 @@ def _audit_findings() -> tuple[list, list[str]]:
         # Recurrent state beside the pools (models/hybrid.py): the
         # hybrid step programs and their donated state trees.
         InferenceEngine(get_model_config("tiny-nemotron-h"), num_slots=4,
-                        kv_layout="paged", page_size=16,
+                        page_size=16,
                         mesh_shape={"data": 1, "model": 1}),
     ]
     findings, names = [], []

@@ -688,8 +688,8 @@ def kv_status(session) -> int:
         any_out = True
     if not any_out:
         print(style.dim(
-            "\n  No KV series captured. Serve a paged engine with "
-            "ROUNDTABLE_TELEMETRY=1 (kv_layout: paged) to populate the "
+            "\n  No KV series captured. Serve an engine with "
+            "ROUNDTABLE_TELEMETRY=1 to populate the "
             "ledger, prefix-cache and offload series.\n"))
     print("")
     return 0

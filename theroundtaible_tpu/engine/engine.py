@@ -1,4 +1,4 @@
-"""InferenceEngine — sharded prefill + decode with persistent KV slots.
+"""InferenceEngine — sharded prefill + decode over a paged KV pool.
 
 The TPU-native serving stack replacing Ollama/LM Studio llama.cpp
 (SURVEY.md §3.4): tokenize → chunked, bucketed prefill (delta-only thanks to
@@ -9,7 +9,7 @@ XLA discipline:
   across rounds does NOT trigger recompiles (SURVEY.md §7.3 hard part 5)
 - the decode loop is ONE device program (lax.while_loop with an on-device
   all-done predicate), not a Python token loop — no per-token dispatch
-- cache buffers are donated, so slot updates are in-place on HBM
+- the page pools are donated, so page writes are in-place on HBM
 - batch rows = knight slots; generate_batch serves N knights in the same
   programs with per-row offsets (SURVEY.md §7 Phase 5)
 """
@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ENGINE_CONFIG_KEYS, deadlines, faults
-from .kvcache import KVCache
 from .models.common import ModelConfig, forward, param_count, spmd_mesh
 from .models.registry import resolve_model_config
 from .sampling import (SamplingParams, row_filtered, sample_token_batch,
@@ -38,8 +37,7 @@ from .serving_loop import (DECODE_SEGMENT, MAX_PREFILL_CHUNK,
                            bucket_for as _bucket,
                            chunked_prefill, decode_segments,
                            finalize_outputs, host_sync, prompt_budget)
-from .sharding import (build_mesh, init_sharded_params, kv_cache_spec,
-                       shard_params)
+from .sharding import build_mesh, init_sharded_params, shard_params
 from .tokenizer import load_tokenizer
 
 # Cross-slot K/V copies are bandwidth-cheap but still a program dispatch;
@@ -69,7 +67,7 @@ class GenStats:
     reused_tokens: int = 0
     # Of reused_tokens, how many the CROSS-SESSION prefix cache served
     # (ISSUE 7) — own-slot LCP hits and intra-session donation make up
-    # the rest. 0 on contiguous / cache-off engines.
+    # the rest. 0 on cache-off engines.
     prefix_reused_tokens: int = 0
     decode_tokens: int = 0
     prefill_seconds: float = 0.0
@@ -97,7 +95,7 @@ class GenStats:
 
 
 class InferenceEngine:
-    """One resident model + its slot cache + compiled step programs."""
+    """One resident model + its page pool + compiled step programs."""
 
     def __init__(self, model_cfg: ModelConfig, *, checkpoint: str = "",
                  mesh_shape: Optional[dict[str, int]] = None,
@@ -107,7 +105,7 @@ class InferenceEngine:
                  long_threshold: int = 2048,
                  long_scheme: str = "ring", attn: str = "auto",
                  devices: Optional[list[int]] = None,
-                 kv_layout: str = "contiguous", page_size: int = 128,
+                 kv_layout: str = "paged", page_size: int = 128,
                  num_pages: Optional[int] = None, quant: str = "none",
                  dcn_axis: Optional[str] = None,
                  prefix_cache: Optional[bool] = None,
@@ -120,6 +118,13 @@ class InferenceEngine:
                  lora: Optional[dict] = None,
                  kv_quant: Any = None,
                  state_snapshot_bytes: Optional[int] = None):
+        if kv_layout != "paged":
+            # One KV layout. The key stays accepted with its one value
+            # until the benchmark's files stop passing it (ROADMAP D1b).
+            raise ValueError(
+                f"kv_layout {kv_layout!r} is not served: every model "
+                "serves through kv_layout 'paged' only (the contiguous "
+                "layout was removed in PR 46)")
         # Multi-host: join the process group BEFORE any backend/device
         # call when ROUNDTABLE_COORDINATOR is set (engine/distributed.py);
         # jax.devices() below then spans every host's chips.
@@ -162,12 +167,6 @@ class InferenceEngine:
                    else "latent-pages" if model_cfg.latent
                    else "attn-layers" if model_cfg.attn_layers
                    else "layer-kinds")
-            if kv_layout != "paged":
-                raise ValueError(
-                    f"{model_cfg.name} has layer_kinds: it serves through "
-                    "kv_layout 'paged' only (its KV pools hold the "
-                    "attention layers alone, its recurrent state lives "
-                    "beside them)")
             if self.mesh.devices.size > 1:
                 raise ValueError(
                     f"{model_cfg.name} has layer_kinds: a mesh over "
@@ -285,16 +284,10 @@ class InferenceEngine:
                 model_shards=model_axis_size(self.mesh))
         self.num_params = param_count(self.params)
 
-        if kv_layout not in ("contiguous", "paged"):
-            raise ValueError(
-                f"kv_layout must be contiguous|paged, got {kv_layout!r}")
-        self.kv_layout = kv_layout
-
         # Quantized KV pages (ISSUE 11): resolve the `kv_quant:` config
         # against the ROUNDTABLE_KV_QUANT kill-switch BEFORE the pool is
         # built — the pool's dtype, its scale arrays, and its
-        # byte-budget-equal default page count all follow the spec.
-        # Contiguous layouts decline (no page unit to quantize); the
+        # byte-budget-equal default page count all follow the spec; the
         # reason is machine-readable like every other path decision.
         from .kv_quant import resolve_spec as _kvq_resolve
         self.kv_quant_spec = None
@@ -303,100 +296,80 @@ class InferenceEngine:
         self._kv_quant_dispatches: dict[str, int] = {}
         from collections import deque as _dq
         self._kv_quant_recent = _dq(maxlen=32)
-        if kv_layout != "paged":
-            self.kv_quant_reason = ("kv_layout:contiguous"
-                                    if kv_quant and kv_quant != "none"
-                                    else "disabled:config")
-        else:
-            self.kv_quant_spec, self.kv_quant_reason = \
-                _kvq_resolve(kv_quant)
-            if "kv_quant" in self.declines:
-                self.kv_quant_reason = self.declines["kv_quant"]
+        self.kv_quant_spec, self.kv_quant_reason = _kvq_resolve(kv_quant)
+        if "kv_quant" in self.declines:
+            self.kv_quant_reason = self.declines["kv_quant"]
 
-        if kv_layout == "paged":
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            from .pallas import page_copy
-            from .paging import PagedKVCache
-            from .sharding import DATA_AXIS, MODEL_AXIS, _fallback_replicated
-            data_size = dict(self.mesh.shape).get("data", 1)
-            pool_sharding = None
-            if self.mesh.devices.size > 1:
-                # Per-replica pools (VERDICT r3 #7): the PAGE axis shards
-                # over "data" (the allocator rounds num_pages to a
-                # multiple of data_size and keeps every slot's pages on
-                # one replica), kv heads over "model" — each device holds
-                # pages/data x heads/model, not a full replicated pool.
-                spec = _fallback_replicated(
-                    P(DATA_AXIS if data_size > 1 else None, None,
-                      MODEL_AXIS, None),
-                    (data_size, page_size, model_cfg.num_kv_heads,
-                     model_cfg.head_dim),
-                    self.mesh)
-                pool_sharding = NamedSharding(self.mesh, spec)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from .pallas import page_copy
+        from .paging import PagedKVCache
+        from .sharding import DATA_AXIS, MODEL_AXIS, _fallback_replicated
+        data_size = dict(self.mesh.shape).get("data", 1)
+        pool_sharding = None
+        if self.mesh.devices.size > 1:
+            # Per-replica pools (VERDICT r3 #7): the PAGE axis shards
+            # over "data" (the allocator rounds num_pages to a
+            # multiple of data_size and keeps every slot's pages on
+            # one replica), kv heads over "model" — each device holds
+            # pages/data x heads/model, not a full replicated pool.
+            spec = _fallback_replicated(
+                P(DATA_AXIS if data_size > 1 else None, None,
+                  MODEL_AXIS, None),
+                (data_size, page_size, model_cfg.num_kv_heads,
+                 model_cfg.head_dim),
+                self.mesh)
+            pool_sharding = NamedSharding(self.mesh, spec)
 
-            @partial(jax.jit, donate_argnums=(0,))
-            def scatter_pages(pools, src_ids, dst_ids):
-                # Whole-page copies as XLA has them: every source
-                # gathered, then scattered — what runs where the DMA
-                # copier declines. Over some layouts the scatter
-                # re-lays the whole pool out and back (PERF.md, PR 45).
-                return [tuple(p.at[dst_ids].set(p[src_ids]) for p in layer)
-                        for layer in pools]
+        @partial(jax.jit, donate_argnums=(0,))
+        def scatter_pages(pools, src_ids, dst_ids):
+            # Whole-page copies as XLA has them: every source
+            # gathered, then scattered — what runs where the DMA
+            # copier declines. Over some layouts the scatter
+            # re-lays the whole pool out and back (PERF.md, PR 45).
+            return [tuple(p.at[dst_ids].set(p[src_ids]) for p in layer)
+                    for layer in pools]
 
-            def copy_pages(pools, src_ids, dst_ids):
-                # Whole-page copies (copy-on-write + alias boundaries):
-                # the cache queues its copies and issues them here as
-                # numpy ids padded to one of paging.COPY_WIDTHS, so
-                # this compiles those shapes and no other (a pad row
-                # names a scratch page twice and moves no byte that
-                # differs); kv.warm_copier compiles each in warmup().
-                # DMAs in place where the pools allow them, one rule:
-                # pallas/page_copy.py.
-                copier = (page_copy.copy_pages
-                          if self.kv.page_copy_path == page_copy.PATH
-                          else scatter_pages)
-                return copier(pools, src_ids, dst_ids)
+        def copy_pages(pools, src_ids, dst_ids):
+            # Whole-page copies (copy-on-write + alias boundaries):
+            # the cache queues its copies and issues them here as
+            # numpy ids padded to one of paging.COPY_WIDTHS, so
+            # this compiles those shapes and no other (a pad row
+            # names a scratch page twice and moves no byte that
+            # differs); kv.warm_copier compiles each in warmup().
+            # DMAs in place where the pools allow them, one rule:
+            # pallas/page_copy.py.
+            copier = (page_copy.copy_pages
+                      if self.kv.page_copy_path == page_copy.PATH
+                      else scatter_pages)
+            return copier(pools, src_ids, dst_ids)
 
-            # Default pool HALVES the contiguous HBM budget — and since
-            # the page axis shards over "data", that is the TOTAL across
-            # replicas (each device holds total/data), not a replicated
-            # per-device cost. Worst case that FITS the default:
-            # ceil(num_slots/2) sequences simultaneously resident at full
-            # max_seq_len, spread over the replicas their slots pin to. A
-            # batch pinning MORE than that, all near max_seq_len, exhausts
-            # a replica's range mid-serve with an actionable RuntimeError
-            # ("raise num_pages / lower max_new_tokens") — set num_pages
-            # explicitly (up to num_slots*max_seq_len/page_size +
-            # data_size for contiguous-equal capacity) when every knight
-            # runs long.
-            self.kv = PagedKVCache(
-                model_cfg, num_slots, self.max_seq_len, dtype,
-                pool_sharding, page_size=page_size, num_pages=num_pages,
-                copy_pages_fn=copy_pages, data_size=data_size,
-                kv_quant=self.kv_quant_spec)
-            reason = page_copy.decline_reason(
-                jax.tree.leaves(self.kv.combined_pools()))
-            if reason is not None:
-                self.declines["page_copy"] = reason
-            self.kv.page_copy_path = reason or page_copy.PATH
-        else:
-            cache_sharding = None
-            if self.mesh.devices.size > 1:
-                from jax.sharding import NamedSharding
-                from .sharding import _fallback_replicated
-                spec = _fallback_replicated(
-                    kv_cache_spec(),
-                    (num_slots, self.max_seq_len, model_cfg.num_kv_heads,
-                     model_cfg.head_dim),
-                    self.mesh)
-                cache_sharding = NamedSharding(self.mesh, spec)
-            self.kv = KVCache(model_cfg, num_slots, self.max_seq_len, dtype,
-                              cache_sharding)
+        # Default pool: HALF of num_slots sequences at max_seq_len — and
+        # since the page axis shards over "data", that is the TOTAL across
+        # replicas (each device holds total/data), not a replicated
+        # per-device cost. Worst case that FITS the default:
+        # ceil(num_slots/2) sequences simultaneously resident at full
+        # max_seq_len, spread over the replicas their slots pin to. A
+        # batch pinning MORE than that, all near max_seq_len, exhausts
+        # a replica's range mid-serve with an actionable RuntimeError
+        # ("raise num_pages / lower max_new_tokens") — set num_pages
+        # explicitly (up to num_slots*max_seq_len/page_size +
+        # data_size: every slot at full length) when every knight
+        # runs long.
+        self.kv = PagedKVCache(
+            model_cfg, num_slots, self.max_seq_len, dtype,
+            pool_sharding, page_size=page_size, num_pages=num_pages,
+            copy_pages_fn=copy_pages, data_size=data_size,
+            kv_quant=self.kv_quant_spec)
+        reason = page_copy.decline_reason(
+            jax.tree.leaves(self.kv.combined_pools()))
+        if reason is not None:
+            self.declines["page_copy"] = reason
+        self.kv.page_copy_path = reason or page_copy.PATH
 
         self._key = jax.random.PRNGKey(seed + 1)
         self._chars_per_token: Optional[float] = None
         self.last_stats = GenStats()
-        # Serving mutates the slot cache (donated buffers): one generation
+        # Serving mutates the page pools (donated buffers): one generation
         # at a time per engine. Distinct engines (fleet submeshes) still
         # run concurrently — each has its own lock.
         self._serve_lock = threading.Lock()
@@ -423,39 +396,6 @@ class InferenceEngine:
             self._ring_prefill_fn = make_ring_prefill(
                 model_cfg, self.seq_mesh, scheme=long_scheme)
 
-        @partial(jax.jit, donate_argnums=(0,))
-        def scatter_kv(cache_layers, slot_idx, new_layers):
-            # Write whole-sequence K/V from sequence-parallel prefill into
-            # the slot cache at offset 0 (ring path only runs offset-0).
-            out = []
-            for (k, v), (nk, nv) in zip(cache_layers, new_layers):
-                t = nk.shape[1]
-                out.append((k.at[slot_idx, :t].set(nk.astype(k.dtype)),
-                            v.at[slot_idx, :t].set(nv.astype(v.dtype))))
-            return out
-
-        self._scatter_kv = scatter_kv
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def copy_spans(cache_layers, src_idx, dst_idx, lo, hi):
-            # Copy K/V positions [lo_i, hi_i) from slot src_idx[i] into
-            # slot dst_idx[i], per layer — the device side of cross-knight
-            # prefix sharing. Positions are cache-aligned (entry s holds
-            # position s) so a row-masked where is an exact copy; the
-            # whole-row traffic is bandwidth-trivial next to a prefill.
-            s_len = cache_layers[0][0].shape[1]
-            pos = jnp.arange(s_len)[None, :, None, None]
-            mask = ((pos >= lo[:, None, None, None])
-                    & (pos < hi[:, None, None, None]))
-            out = []
-            for k, v in cache_layers:
-                nk = jnp.where(mask, k[src_idx], k[dst_idx])
-                nv = jnp.where(mask, v[src_idx], v[dst_idx])
-                out.append((k.at[dst_idx].set(nk), v.at[dst_idx].set(nv)))
-            return out
-
-        self._copy_spans = copy_spans
-
         # compiled closures (per (batch, bucket) shapes, cached by jit)
         cfg = model_cfg
 
@@ -474,31 +414,6 @@ class InferenceEngine:
             out = tuple(jax.lax.with_sharding_constraint(x, _rep)
                         for x in xs)
             return out if len(out) > 1 else out[0]
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def prefill_step(params, cache_layers, slot_idx, tokens, offsets,
-                         lengths, lora=None):
-            # spmd_mesh is a TRACE-time context: it tells attention() which
-            # mesh to shard_map the Pallas kernels over (models/common.py).
-            # `lora` ((stacked, per-row ids) or None) rides the same
-            # pattern: adapter identity is a VALUE argument, so swaps
-            # and mixed-adapter batches compile nothing (ISSUE 10).
-            with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
-                    self._lora_scope(lora):
-                caches_b = [(k[slot_idx], v[slot_idx])
-                            for k, v in cache_layers]
-                t = tokens.shape[1]
-                positions = offsets[:, None] + jnp.arange(t)[None, :]
-                valid = offsets + lengths
-                logits, new_b = forward(params, cfg, tokens, positions,
-                                        caches_b, offsets, valid,
-                                        last_pos=lengths - 1)
-                new_layers = [
-                    (k.at[slot_idx].set(nk), v.at[slot_idx].set(nv))
-                    for (k, v), (nk, nv) in zip(cache_layers, new_b)]
-                return host_read(logits[:, 0]), new_layers
-
-        self._prefill_step = prefill_step
 
         @partial(jax.jit, static_argnames=("greedy",))
         def first_token(last_logits, key, temps, top_ks, top_ps, greedy):
@@ -521,10 +436,10 @@ class InferenceEngine:
                          budget, temps, top_ks, top_ps, row_budgets,
                          done0, max_new, greedy, lora=None,
                          pass_active=False):
-            """The decode while_loop, ONCE for all three cache layouts
-            (contiguous, paged gather-view, paged pool-direct) —
+            """The decode while_loop, ONCE for every family of step
+            programs (gather view, pool-direct, hybrid) —
             `step_fn(last, valid, caches) -> (logits [B,1,V], caches)` is
-            the only layout-specific piece. max_new is the STATIC segment
+            the only family-specific piece. max_new is the STATIC segment
             size (one compiled program per value — always DECODE_SEGMENT
             in serving); budget is the DYNAMIC number of tokens actually
             wanted from this segment, so short tails exit early without a
@@ -590,54 +505,25 @@ class InferenceEngine:
             return out, step, last, valid, done, caches
 
         def cached_step(params):
-            """step_fn for the position-aligned [B, S, K, D] layouts."""
+            """step_fn over the position-aligned [B, S, K, D] gather
+            view."""
             def step(last, valid, caches_b):
                 return forward(params, cfg, last[:, None], valid[:, None],
                                caches_b, valid, valid + 1)
             return step
 
-        @partial(jax.jit, donate_argnums=(1,),
-                 static_argnames=("max_new", "greedy"))
-        def decode_loop(params, cache_layers, slot_idx, first_token,
-                        start_valid, key, budget, temps, top_ks, top_ps,
-                        row_budgets, done0, max_new, greedy, lora=None):
-            # The all-done guard skips the per-layer slot gather/scatter
-            # too (not just the while_loop) — an all-done segment (the
-            # pipelined speculative dispatch's discard case) would
-            # otherwise still copy the batch's whole KV.
-            def run(cache_layers):
-                caches_b = [(k[slot_idx], v[slot_idx])
-                            for k, v in cache_layers]
-                out, step, last, valid, done, caches_b = decode_while(
-                    cached_step(params), caches_b, first_token,
-                    start_valid, key, budget, temps, top_ks, top_ps,
-                    row_budgets, done0, max_new, greedy, lora=lora)
-                new_layers = [
-                    (k.at[slot_idx].set(nk), v.at[slot_idx].set(nv))
-                    for (k, v), (nk, nv) in zip(cache_layers, caches_b)]
-                return out, step, last, valid, done, new_layers
-
-            def skip(cache_layers):
-                b = first_token.shape[0]
-                return (jnp.zeros((b, max_new), jnp.int32), jnp.int32(0),
-                        first_token, start_valid, done0, cache_layers)
-
-            return jax.lax.cond(jnp.all(done0), skip, run, cache_layers)
-
-        self._decode_loop = decode_loop
-
-        # --- paged variants ---
-        # Prefill: pool[table] materializes the SAME position-aligned
-        # [B, S, K, D] view the contiguous path gathers per slot, so
-        # forward() and the Pallas kernels are layout-agnostic; the
+        # --- the step programs ---
+        # Gather view: pool[table] materializes a position-aligned
+        # [B, S, K, D] view of the batch's pages, which forward() and
+        # the flash kernels read as a plain cache; the
         # updated view scatters back through the same table. Aliased
         # (shared-prefix) pages are never in any row's write range
         # (ensure_capacity copy-on-writes them), so duplicate-index
         # scatters only ever rewrite identical bytes.
         # Decode: POOL-DIRECT where supported — the page-table-aware
         # kernel reads only pages below each row's frontier and the
-        # gather view (which would temporarily recreate the full
-        # contiguous HBM budget) is never built
+        # gather view (every row's whole max_seq_len span, copied out
+        # of the pool and back) is never built
         # (engine/paged_forward.py). On multi-device meshes the kernel
         # runs under shard_map (kv heads on "model", matching the pool's
         # sharding; pallas.paged_decode_spmd); head layouts that don't
@@ -645,279 +531,277 @@ class InferenceEngine:
         self.paged_direct = False
         self.paged_degraded_reason: Optional[str] = None
         self._paged_replicas = 1
-        if kv_layout == "paged":
-            from .pallas.attention import (paged_pool_direct_supported,
-                                           spmd_partitionable)
-            # attn="dense" is an explicit opt-out of every Pallas kernel
-            # (the _resolve_attn contract) — the pool-direct decode IS a
-            # Pallas kernel, so it honors the same switch. "auto" still
-            # takes pool-direct even where auto resolves the view path to
-            # dense (CPU): there is no dense pool-direct equivalent, and
-            # the kernel runs in interpret mode there.
-            n_model = dict(self.mesh.shape).get("model", 1)
-            # data > 1 (VERDICT r4 #4): the pool's page axis is
-            # data-sharded and the spmd kernel shards BATCH rows over
-            # "data" — generate_batch groups rows by their slot's
-            # replica (ReplicaGroupPlan) so each shard_map block reads
-            # only its local pages; the kernels rebase tables to the
-            # local range via axis_index. No gather view on any mesh.
-            kh_l = model_cfg.page_heads
-            if self.mesh.devices.size > 1 and kh_l % max(n_model, 1) == 0:
-                kh_l //= max(n_model, 1)   # kernel sees the local shard
-            group = model_cfg.num_heads // model_cfg.page_heads
-            # Every geometry the attention layers have (one, but for a
-            # model with attn_layers) must fit both kernels.
-            groups = sorted({h // model_cfg.page_heads for h, _w, _n
-                             in model_cfg.attention_classes})
-            self.paged_direct = (
-                attn != "dense"
-                and all(paged_pool_direct_supported(
-                    MAX_PREFILL_CHUNK, page_size, model_cfg.page_width,
-                    kh_l, g) for g in groups)
-                and (self.mesh.devices.size == 1
-                     or spmd_partitionable(model_cfg.num_heads,
-                                           model_cfg.num_kv_heads,
-                                           n_model)))
-            # Quantized pages (ISSUE 11): can the Pallas kernels
-            # dequantize this pool shape IN-KERNEL? A decline (int4
-            # packing/grouping on this head_dim) routes serving to the
-            # XLA dequant paths — gather view for the batched
-            # programs — with the machine-readable reason recorded,
-            # the int4mm plan/decline discipline: no dispatch can
-            # reach a Mosaic failure on chip.
-            if self.kv_quant_spec is not None:
-                from .pallas.attention import kv_quant_decline_reason
-                self.kv_quant_fallback_reason = kv_quant_decline_reason(
-                    page_size, model_cfg.head_dim, kh_l, group,
-                    self.kv_quant_spec.bits, self.kv_quant_spec.group)
-                if (self.kv_quant_fallback_reason is not None
-                        and self.paged_direct):
-                    self.paged_direct = False
-                    self.paged_degraded_reason = (
-                        f"kv_quant:{self.kv_quant_fallback_reason}")
-            self._paged_replicas = data_size if self.paged_direct else 1
-            n_pages_seq = self.max_seq_len // page_size
-            _kvq_spec = self.kv_quant_spec
-            _n_layers = model_cfg.num_layers
-            from .kv_quant import (dequantize_cells as _kvq_deq,
-                                   quantize_cells as _kvq_q,
-                                   split_combined as _kvq_split)
+        from .pallas.attention import (paged_pool_direct_supported,
+                                       spmd_partitionable)
+        # attn="dense" is an explicit opt-out of every Pallas kernel
+        # (the _resolve_attn contract) — the pool-direct decode IS a
+        # Pallas kernel, so it honors the same switch. "auto" still
+        # takes pool-direct even where auto resolves the view path to
+        # dense (CPU): there is no dense pool-direct equivalent, and
+        # the kernel runs in interpret mode there.
+        n_model = dict(self.mesh.shape).get("model", 1)
+        # data > 1 (VERDICT r4 #4): the pool's page axis is
+        # data-sharded and the spmd kernel shards BATCH rows over
+        # "data" — generate_batch groups rows by their slot's
+        # replica (ReplicaGroupPlan) so each shard_map block reads
+        # only its local pages; the kernels rebase tables to the
+        # local range via axis_index. No gather view on any mesh.
+        kh_l = model_cfg.page_heads
+        if self.mesh.devices.size > 1 and kh_l % max(n_model, 1) == 0:
+            kh_l //= max(n_model, 1)   # kernel sees the local shard
+        group = model_cfg.num_heads // model_cfg.page_heads
+        # Every geometry the attention layers have (one, but for a
+        # model with attn_layers) must fit both kernels.
+        groups = sorted({h // model_cfg.page_heads for h, _w, _n
+                         in model_cfg.attention_classes})
+        self.paged_direct = (
+            attn != "dense"
+            and all(paged_pool_direct_supported(
+                MAX_PREFILL_CHUNK, page_size, model_cfg.page_width,
+                kh_l, g) for g in groups)
+            and (self.mesh.devices.size == 1
+                 or spmd_partitionable(model_cfg.num_heads,
+                                       model_cfg.num_kv_heads,
+                                       n_model)))
+        # Quantized pages (ISSUE 11): can the Pallas kernels
+        # dequantize this pool shape IN-KERNEL? A decline (int4
+        # packing/grouping on this head_dim) routes serving to the
+        # XLA dequant paths — gather view for the batched
+        # programs — with the machine-readable reason recorded,
+        # the int4mm plan/decline discipline: no dispatch can
+        # reach a Mosaic failure on chip.
+        if self.kv_quant_spec is not None:
+            from .pallas.attention import kv_quant_decline_reason
+            self.kv_quant_fallback_reason = kv_quant_decline_reason(
+                page_size, model_cfg.head_dim, kh_l, group,
+                self.kv_quant_spec.bits, self.kv_quant_spec.group)
+            if (self.kv_quant_fallback_reason is not None
+                    and self.paged_direct):
+                self.paged_direct = False
+                self.paged_degraded_reason = (
+                    f"kv_quant:{self.kv_quant_fallback_reason}")
+        self._paged_replicas = data_size if self.paged_direct else 1
+        n_pages_seq = self.max_seq_len // page_size
+        _kvq_spec = self.kv_quant_spec
+        _n_layers = model_cfg.num_layers
+        from .kv_quant import (dequantize_cells as _kvq_deq,
+                               quantize_cells as _kvq_q,
+                               split_combined as _kvq_split)
 
-            def gather_view(combined, tables, b):
-                # Combined pools (+ scales when quantized) -> the
-                # position-aligned bf16 [B, S, K, D] view forward()
-                # consumes — quantized pools dequantize AT THE GATHER
-                # (kv_quant.dequantize_cells, the XLA read seam).
-                pools, scales = _kvq_split(combined, _n_layers)
-                caches_b = []
-                for li, (k_pool, v_pool) in enumerate(pools):
-                    if scales is not None:
-                        ks, vs = scales[li]
-                        kb = _kvq_deq(k_pool[tables], ks[tables],
-                                      _kvq_spec, dtype)
-                        vb = _kvq_deq(v_pool[tables], vs[tables],
-                                      _kvq_spec, dtype)
-                        tail = (k_pool.shape[2], model_cfg.head_dim)
-                    else:
-                        kb, vb = k_pool[tables], v_pool[tables]
-                        tail = k_pool.shape[2:]
-                    caches_b.append(
-                        (kb.reshape(b, n_pages_seq * page_size, *tail),
-                         vb.reshape(b, n_pages_seq * page_size, *tail)))
-                return caches_b
+        def gather_view(combined, tables, b):
+            # Combined pools (+ scales when quantized) -> the
+            # position-aligned bf16 [B, S, K, D] view forward()
+            # consumes — quantized pools dequantize AT THE GATHER
+            # (kv_quant.dequantize_cells, the XLA read seam).
+            pools, scales = _kvq_split(combined, _n_layers)
+            caches_b = []
+            for li, (k_pool, v_pool) in enumerate(pools):
+                if scales is not None:
+                    ks, vs = scales[li]
+                    kb = _kvq_deq(k_pool[tables], ks[tables],
+                                  _kvq_spec, dtype)
+                    vb = _kvq_deq(v_pool[tables], vs[tables],
+                                  _kvq_spec, dtype)
+                    tail = (k_pool.shape[2], model_cfg.head_dim)
+                else:
+                    kb, vb = k_pool[tables], v_pool[tables]
+                    tail = k_pool.shape[2:]
+                caches_b.append(
+                    (kb.reshape(b, n_pages_seq * page_size, *tail),
+                     vb.reshape(b, n_pages_seq * page_size, *tail)))
+            return caches_b
 
-            def scatter_view(combined, tables, new_b, b):
-                # The inverse write seam: the updated bf16 view
-                # RE-QUANTIZES cell-by-cell before scattering back.
-                # Unwritten cells round-trip exactly (requantizing a
-                # dequantized cell reproduces its payload and scale —
-                # the pinned stability property), so repeated
-                # gather/scatter segments cannot drift.
-                pools, scales = _kvq_split(combined, _n_layers)
-                out_p, out_s = [], []
-                for li, ((k_pool, v_pool), (nk, nv)) in enumerate(
-                        zip(pools, new_b)):
-                    if scales is not None:
-                        ks, vs = scales[li]
-                        nk_q, nk_s = _kvq_q(nk, _kvq_spec)
-                        nv_q, nv_s = _kvq_q(nv, _kvq_spec)
-                        qtail = k_pool.shape[2:]
-                        stail = ks.shape[2:]
-                        out_p.append((
-                            k_pool.at[tables].set(nk_q.reshape(
-                                b, n_pages_seq, page_size, *qtail)),
-                            v_pool.at[tables].set(nv_q.reshape(
-                                b, n_pages_seq, page_size, *qtail))))
-                        out_s.append((
-                            ks.at[tables].set(nk_s.reshape(
-                                b, n_pages_seq, page_size, *stail)),
-                            vs.at[tables].set(nv_s.reshape(
-                                b, n_pages_seq, page_size, *stail))))
-                    else:
-                        tail = k_pool.shape[2:]
-                        nk5 = nk.reshape(b, n_pages_seq, page_size,
-                                         *tail)
-                        nv5 = nv.reshape(b, n_pages_seq, page_size,
-                                         *tail)
-                        out_p.append((k_pool.at[tables].set(nk5),
-                                      v_pool.at[tables].set(nv5)))
-                return out_p + out_s
+        def scatter_view(combined, tables, new_b, b):
+            # The inverse write seam: the updated bf16 view
+            # RE-QUANTIZES cell-by-cell before scattering back.
+            # Unwritten cells round-trip exactly (requantizing a
+            # dequantized cell reproduces its payload and scale —
+            # the pinned stability property), so repeated
+            # gather/scatter segments cannot drift.
+            pools, scales = _kvq_split(combined, _n_layers)
+            out_p, out_s = [], []
+            for li, ((k_pool, v_pool), (nk, nv)) in enumerate(
+                    zip(pools, new_b)):
+                if scales is not None:
+                    ks, vs = scales[li]
+                    nk_q, nk_s = _kvq_q(nk, _kvq_spec)
+                    nv_q, nv_s = _kvq_q(nv, _kvq_spec)
+                    qtail = k_pool.shape[2:]
+                    stail = ks.shape[2:]
+                    out_p.append((
+                        k_pool.at[tables].set(nk_q.reshape(
+                            b, n_pages_seq, page_size, *qtail)),
+                        v_pool.at[tables].set(nv_q.reshape(
+                            b, n_pages_seq, page_size, *qtail))))
+                    out_s.append((
+                        ks.at[tables].set(nk_s.reshape(
+                            b, n_pages_seq, page_size, *stail)),
+                        vs.at[tables].set(nv_s.reshape(
+                            b, n_pages_seq, page_size, *stail))))
+                else:
+                    tail = k_pool.shape[2:]
+                    nk5 = nk.reshape(b, n_pages_seq, page_size,
+                                     *tail)
+                    nv5 = nv.reshape(b, n_pages_seq, page_size,
+                                     *tail)
+                    out_p.append((k_pool.at[tables].set(nk5),
+                                  v_pool.at[tables].set(nv5)))
+            return out_p + out_s
 
-            @partial(jax.jit, donate_argnums=(1,))
-            def prefill_step_paged(params, pools, tables, tokens, offsets,
-                                   lengths, lora=None):
-                with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
-                        self._lora_scope(lora):
-                    b, t = tokens.shape
-                    caches_b = gather_view(pools, tables, b)
-                    positions = offsets[:, None] + jnp.arange(t)[None, :]
-                    valid = offsets + lengths
-                    logits, new_b = forward(params, cfg, tokens, positions,
-                                            caches_b, offsets, valid,
-                                            last_pos=lengths - 1)
-                    new_pools = scatter_view(pools, tables, new_b, b)
-                    return host_read(logits[:, 0]), new_pools
+        @partial(jax.jit, donate_argnums=(1,))
+        def prefill_step_paged(params, pools, tables, tokens, offsets,
+                               lengths, lora=None):
+            with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
+                    self._lora_scope(lora):
+                b, t = tokens.shape
+                caches_b = gather_view(pools, tables, b)
+                positions = offsets[:, None] + jnp.arange(t)[None, :]
+                valid = offsets + lengths
+                logits, new_b = forward(params, cfg, tokens, positions,
+                                        caches_b, offsets, valid,
+                                        last_pos=lengths - 1)
+                new_pools = scatter_view(pools, tables, new_b, b)
+                return host_read(logits[:, 0]), new_pools
 
-            @partial(jax.jit, donate_argnums=(1,))
-            def prefill_step_paged_direct(params, pools, tables, tokens,
-                                          offsets, lengths, lora=None):
-                from .paged_forward import forward_paged
-                with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
-                        self._lora_scope(lora):
-                    t = tokens.shape[1]
-                    positions = offsets[:, None] + jnp.arange(t)[None, :]
-                    valid = offsets + lengths
-                    pools_l, scales_l = _kvq_split(pools, _n_layers)
-                    logits, new_pools = forward_paged(
-                        params, cfg, tokens, positions, pools_l, tables,
-                        valid, pool_replicas=data_size,
-                        last_pos=lengths - 1,
-                        scales=scales_l, quant_spec=_kvq_spec)
-                    return host_read(logits[:, 0]), new_pools
-
-            # Keep BOTH compiled-closure pairs: the gather-view programs
-            # are the runtime degradation target when a pool-direct
-            # kernel fails on chip (_degrade_paged_direct).
-            self._prefill_step_paged_gather = prefill_step_paged
-            self._prefill_step_paged = (prefill_step_paged_direct
-                                        if self.paged_direct
-                                        else prefill_step_paged)
-
-            @partial(jax.jit, donate_argnums=(1,),
-                     static_argnames=("max_new", "greedy"))
-            def decode_loop_paged(params, pools, tables, first_token,
-                                  start_valid, key, budget, temps, top_ks,
-                                  top_ps, row_budgets, done0, max_new,
-                                  greedy, lora=None):
-                b = first_token.shape[0]
-
-                # All-done guard: skip the full gather view + scatter
-                # (the paged layout's whole-cache copy), not just the
-                # while_loop — see decode_loop.
-                def run(pools):
-                    caches_b = gather_view(pools, tables, b)
-                    out, step, last, valid, done, caches_b = decode_while(
-                        cached_step(params), caches_b, first_token,
-                        start_valid, key, budget, temps, top_ks, top_ps,
-                        row_budgets, done0, max_new, greedy, lora=lora)
-                    new_pools = scatter_view(pools, tables, caches_b, b)
-                    return out, step, last, valid, done, new_pools
-
-                def skip(pools):
-                    return (jnp.zeros((b, max_new), jnp.int32),
-                            jnp.int32(0), first_token, start_valid,
-                            done0, pools)
-
-                return jax.lax.cond(jnp.all(done0), skip, run, pools)
-
-            @partial(jax.jit, donate_argnums=(1,),
-                     static_argnames=("max_new", "greedy"))
-            def decode_loop_paged_direct(params, pools, tables, first_token,
-                                         start_valid, key, budget, temps,
-                                         top_ks, top_ps, row_budgets,
-                                         done0, max_new, greedy,
-                                         lora=None):
-                from .paged_forward import forward_paged
-
-                def step_fn(last, valid, pools):
-                    pools_l, scales_l = _kvq_split(pools, _n_layers)
-                    return forward_paged(
-                        params, cfg, last[:, None], valid[:, None],
-                        pools_l, tables, valid + 1,
-                        pool_replicas=data_size,
-                        scales=scales_l, quant_spec=_kvq_spec)
-
-                return decode_while(
-                    step_fn, pools, first_token, start_valid, key, budget,
-                    temps, top_ks, top_ps, row_budgets, done0, max_new,
-                    greedy, lora=lora)
-
-            self._decode_loop_paged_gather = decode_loop_paged
-            self._decode_loop_paged = (decode_loop_paged_direct
-                                       if self.paged_direct
-                                       else decode_loop_paged)
-
-            @partial(jax.jit, donate_argnums=(0,))
-            def scatter_kv_paged(pools, tables, new_layers):
-                # Ring-prefill writeback: whole-sequence K/V [B, Tp, K, D]
-                # (Tp a multiple of page_size — _prefill enforces it)
-                # scattered through each row's page table. Rows' pages are
-                # write-exclusive (ensure_capacity COW'd the offset-0
-                # write range); table entries past a row's allocation are
-                # the scratch page, which absorbs the pad-tail garbage and
-                # is never read — same contract as scatter_view.
-                # Quantized pools quantize-on-write here too (ISSUE 11).
+        @partial(jax.jit, donate_argnums=(1,))
+        def prefill_step_paged_direct(params, pools, tables, tokens,
+                                      offsets, lengths, lora=None):
+            from .paged_forward import forward_paged
+            with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
+                    self._lora_scope(lora):
+                t = tokens.shape[1]
+                positions = offsets[:, None] + jnp.arange(t)[None, :]
+                valid = offsets + lengths
                 pools_l, scales_l = _kvq_split(pools, _n_layers)
-                out_p, out_s = [], []
-                for li, ((k_pool, v_pool), (nk, nv)) in enumerate(
-                        zip(pools_l, new_layers)):
-                    b, t = nk.shape[0], nk.shape[1]
-                    n = t // page_size
-                    if scales_l is not None:
-                        ks, vs = scales_l[li]
-                        nk_q, nk_s = _kvq_q(nk.astype(dtype), _kvq_spec)
-                        nv_q, nv_s = _kvq_q(nv.astype(dtype), _kvq_spec)
-                        qtail = k_pool.shape[2:]
-                        stail = ks.shape[2:]
-                        out_p.append((
-                            k_pool.at[tables[:, :n]].set(
-                                nk_q.reshape(b, n, page_size, *qtail)),
-                            v_pool.at[tables[:, :n]].set(
-                                nv_q.reshape(b, n, page_size, *qtail))))
-                        out_s.append((
-                            ks.at[tables[:, :n]].set(
-                                nk_s.reshape(b, n, page_size, *stail)),
-                            vs.at[tables[:, :n]].set(
-                                nv_s.reshape(b, n, page_size, *stail))))
-                    else:
-                        tail = k_pool.shape[2:]
-                        nk5 = nk.reshape(b, n, page_size, *tail) \
-                            .astype(k_pool.dtype)
-                        nv5 = nv.reshape(b, n, page_size, *tail) \
-                            .astype(v_pool.dtype)
-                        out_p.append((k_pool.at[tables[:, :n]].set(nk5),
-                                      v_pool.at[tables[:, :n]].set(nv5)))
-                return out_p + out_s
+                logits, new_pools = forward_paged(
+                    params, cfg, tokens, positions, pools_l, tables,
+                    valid, pool_replicas=data_size,
+                    last_pos=lengths - 1,
+                    scales=scales_l, quant_spec=_kvq_spec)
+                return host_read(logits[:, 0]), new_pools
 
-            self._scatter_kv_paged = scatter_kv_paged
+        # Keep BOTH compiled-closure pairs: the gather-view programs
+        # are the runtime degradation target when a pool-direct
+        # kernel fails on chip (_degrade_paged_direct).
+        self._prefill_step_paged_gather = prefill_step_paged
+        self._prefill_step_paged = (prefill_step_paged_direct
+                                    if self.paged_direct
+                                    else prefill_step_paged)
+
+        @partial(jax.jit, donate_argnums=(1,),
+                 static_argnames=("max_new", "greedy"))
+        def decode_loop_paged(params, pools, tables, first_token,
+                              start_valid, key, budget, temps, top_ks,
+                              top_ps, row_budgets, done0, max_new,
+                              greedy, lora=None):
+            b = first_token.shape[0]
+
+            # All-done guard: skip the full gather view + scatter
+            # (the view's whole-cache copy), not just the while_loop —
+            # an all-done segment (the pipelined speculative dispatch's
+            # discard case) would otherwise still copy the batch's KV.
+            def run(pools):
+                caches_b = gather_view(pools, tables, b)
+                out, step, last, valid, done, caches_b = decode_while(
+                    cached_step(params), caches_b, first_token,
+                    start_valid, key, budget, temps, top_ks, top_ps,
+                    row_budgets, done0, max_new, greedy, lora=lora)
+                new_pools = scatter_view(pools, tables, caches_b, b)
+                return out, step, last, valid, done, new_pools
+
+            def skip(pools):
+                return (jnp.zeros((b, max_new), jnp.int32),
+                        jnp.int32(0), first_token, start_valid,
+                        done0, pools)
+
+            return jax.lax.cond(jnp.all(done0), skip, run, pools)
+
+        @partial(jax.jit, donate_argnums=(1,),
+                 static_argnames=("max_new", "greedy"))
+        def decode_loop_paged_direct(params, pools, tables, first_token,
+                                     start_valid, key, budget, temps,
+                                     top_ks, top_ps, row_budgets,
+                                     done0, max_new, greedy,
+                                     lora=None):
+            from .paged_forward import forward_paged
+
+            def step_fn(last, valid, pools):
+                pools_l, scales_l = _kvq_split(pools, _n_layers)
+                return forward_paged(
+                    params, cfg, last[:, None], valid[:, None],
+                    pools_l, tables, valid + 1,
+                    pool_replicas=data_size,
+                    scales=scales_l, quant_spec=_kvq_spec)
+
+            return decode_while(
+                step_fn, pools, first_token, start_valid, key, budget,
+                temps, top_ks, top_ps, row_budgets, done0, max_new,
+                greedy, lora=lora)
+
+        self._decode_loop_paged_gather = decode_loop_paged
+        self._decode_loop_paged = (decode_loop_paged_direct
+                                   if self.paged_direct
+                                   else decode_loop_paged)
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def scatter_kv_paged(pools, tables, new_layers):
+            # Ring-prefill writeback: whole-sequence K/V [B, Tp, K, D]
+            # (Tp a multiple of page_size — _prefill enforces it)
+            # scattered through each row's page table. Rows' pages are
+            # write-exclusive (ensure_capacity COW'd the offset-0
+            # write range); table entries past a row's allocation are
+            # the scratch page, which absorbs the pad-tail garbage and
+            # is never read — same contract as scatter_view.
+            # Quantized pools quantize-on-write here too (ISSUE 11).
+            pools_l, scales_l = _kvq_split(pools, _n_layers)
+            out_p, out_s = [], []
+            for li, ((k_pool, v_pool), (nk, nv)) in enumerate(
+                    zip(pools_l, new_layers)):
+                b, t = nk.shape[0], nk.shape[1]
+                n = t // page_size
+                if scales_l is not None:
+                    ks, vs = scales_l[li]
+                    nk_q, nk_s = _kvq_q(nk.astype(dtype), _kvq_spec)
+                    nv_q, nv_s = _kvq_q(nv.astype(dtype), _kvq_spec)
+                    qtail = k_pool.shape[2:]
+                    stail = ks.shape[2:]
+                    out_p.append((
+                        k_pool.at[tables[:, :n]].set(
+                            nk_q.reshape(b, n, page_size, *qtail)),
+                        v_pool.at[tables[:, :n]].set(
+                            nv_q.reshape(b, n, page_size, *qtail))))
+                    out_s.append((
+                        ks.at[tables[:, :n]].set(
+                            nk_s.reshape(b, n, page_size, *stail)),
+                        vs.at[tables[:, :n]].set(
+                            nv_s.reshape(b, n, page_size, *stail))))
+                else:
+                    tail = k_pool.shape[2:]
+                    nk5 = nk.reshape(b, n, page_size, *tail) \
+                        .astype(k_pool.dtype)
+                    nv5 = nv.reshape(b, n, page_size, *tail) \
+                        .astype(v_pool.dtype)
+                    out_p.append((k_pool.at[tables[:, :n]].set(nk5),
+                                  v_pool.at[tables[:, :n]].set(nv5)))
+            return out_p + out_s
+
+        self._scatter_kv_paged = scatter_kv_paged
 
         # Cross-session prefix cache + host-RAM offload tier (ISSUE 7):
-        # both are paged-pool subsystems — the contiguous layout has no
-        # page-granular sharing unit. The cache attaches to the pool
-        # (commit-inserts, alloc-reclaims ride the kv object); the tier
-        # needs the engine (mesh, compile labels), so it lives here.
+        # the cache attaches to the pool (commit-inserts, alloc-reclaims
+        # ride the kv object); the tier needs the engine (mesh, compile
+        # labels), so it lives here.
         self.prefix_cache = None
         self.kv_offload = None
-        if kv_layout == "paged":
-            from .prefix_cache import PrefixCache, cache_enabled
-            if cache_enabled(prefix_cache):
-                self.prefix_cache = PrefixCache(
-                    self.kv, engine=model_cfg.name,
-                    max_pages=prefix_cache_pages)
-                self.kv.prefix_cache = self.prefix_cache
-            from .kv_offload import HostOffloadTier, offload_enabled
-            if offload_enabled(kv_offload):
-                self.kv_offload = HostOffloadTier(self)
+        from .prefix_cache import PrefixCache, cache_enabled
+        if cache_enabled(prefix_cache):
+            self.prefix_cache = PrefixCache(
+                self.kv, engine=model_cfg.name,
+                max_pages=prefix_cache_pages)
+            self.kv.prefix_cache = self.prefix_cache
+        from .kv_offload import HostOffloadTier, offload_enabled
+        if offload_enabled(kv_offload):
+            self.kv_offload = HostOffloadTier(self)
 
         # Ragged paged attention (ISSUE 8): mixed prefill/decode in ONE
         # dispatch over a flat token buffer — the scheduler's chunk-
@@ -950,143 +834,139 @@ class InferenceEngine:
         self._window_reads = {"page_visits_full": 0,
                               "page_visits_window": 0,
                               "pages_held": 0, "pages_behind_window": 0}
-        if kv_layout == "paged":
-            from .prefix_cache import env_flag
-            from .pallas import attention as _pattn
-            from .serving_loop import ragged_token_budget
-            n_model = dict(self.mesh.shape).get("model", 1)
-            kh_l = model_cfg.page_heads
-            if self.mesh.devices.size > 1 and kh_l % max(n_model, 1) == 0:
-                kh_l //= max(n_model, 1)
-            group = model_cfg.num_heads // model_cfg.page_heads
-            if not env_flag(ragged_attn, "ROUNDTABLE_RAGGED_ATTN"):
-                self.ragged_reason = "disabled:config/env"
-            elif dict(self.mesh.shape).get("data", 1) > 1:
-                # The pool's page axis shards over "data" on these
-                # meshes; a flat buffer mixing replicas' rows cannot.
-                self.ragged_reason = "mesh:data-axis"
+        from .prefix_cache import env_flag
+        from .pallas import attention as _pattn
+        from .serving_loop import ragged_token_budget
+        # (n_model, kh_l, group: the local pool shape the kernels see,
+        # as the step programs above took it)
+        if not env_flag(ragged_attn, "ROUNDTABLE_RAGGED_ATTN"):
+            self.ragged_reason = "disabled:config/env"
+        elif dict(self.mesh.shape).get("data", 1) > 1:
+            # The pool's page axis shards over "data" on these
+            # meshes; a flat buffer mixing replicas' rows cannot.
+            self.ragged_reason = "mesh:data-axis"
+        else:
+            from .serving_loop import (ragged_defer_min,
+                                       ragged_shape_grid)
+            self.ragged_enabled = True
+            self.ragged_tokens = ragged_token_budget(
+                num_slots, int(ragged_tokens or 0))
+            self.ragged_shapes = ragged_shape_grid(self.ragged_tokens)
+            self.ragged_defer_min = ragged_defer_min()
+            if attn == "dense":
+                decline = "attn=dense"
+            elif (self.mesh.devices.size > 1
+                  and not _pattn.spmd_partitionable(
+                      model_cfg.num_heads, model_cfg.num_kv_heads,
+                      n_model)):
+                decline = "heads:model-axis"
             else:
-                from .serving_loop import (ragged_defer_min,
-                                           ragged_shape_grid)
-                self.ragged_enabled = True
-                self.ragged_tokens = ragged_token_budget(
-                    num_slots, int(ragged_tokens or 0))
-                self.ragged_shapes = ragged_shape_grid(self.ragged_tokens)
-                self.ragged_defer_min = ragged_defer_min()
-                if attn == "dense":
-                    decline = "attn=dense"
-                elif (self.mesh.devices.size > 1
-                      and not _pattn.spmd_partitionable(
-                          model_cfg.num_heads, model_cfg.num_kv_heads,
-                          n_model)):
-                    decline = "heads:model-axis"
-                else:
-                    self._ragged_pool_shape = (
-                        (page_size, model_cfg.page_width, kh_l, group),
-                        dict(dv=(model_cfg.kv_lora_rank if model_cfg.latent
-                                 else model_cfg.head_dim),
-                             itemsize=(1 if self.kv_quant_spec is not None
-                                       else jnp.dtype(dtype).itemsize),
-                             q_itemsize=jnp.dtype(dtype).itemsize,
-                             latent=model_cfg.latent,
-                             quantized=self.kv_quant_spec is not None))
-                    # ... and every other geometry the attention
-                    # layers have: the first that declines decides.
-                    decline = next(
-                        (r for r in (
-                            _pattn.ragged_decline_reason(
-                                *self._ragged_class_shape(h),
-                                **self._ragged_pool_shape[1])
-                            for h, _w, _n in model_cfg.attention_classes)
-                         if r is not None), None)
-                if (decline is None
-                        and self.kv_quant_fallback_reason is not None):
-                    # Quantized pool the kernel cannot dequantize
-                    # in-kernel (ISSUE 11): ragged dispatches serve the
-                    # XLA dense path with the quant decline recorded.
-                    decline = f"kv_quant:{self.kv_quant_fallback_reason}"
-                self.ragged_path = ("pallas_ragged" if decline is None
-                                    else "xla_ragged")
-                self.ragged_fallback_reason = decline
-                if model_cfg.retention_layers and decline is None:
-                    # State on the slot arrays: a prologue's [B, T]
-                    # program is the ragged program's chunked runs again
-                    # (the same kernel a page of a run), at a shape a
-                    # batch size a bucket — nothing a join gains, and a
-                    # compile each: 35 s of a warm set-up, more of a
-                    # cold one, 9.3 s inside a window at a bucket no
-                    # warm-up met (PERF.md, PR 42). So the scheduler's
-                    # joins take the ragged program whatever the batch
-                    # holds and however few tokens they bring; the
-                    # prologue serves generate_batch, which no scheduler
-                    # stands behind.
-                    self.joins_ragged_alone = True
-                    self.ragged_defer_min = 0
-                if decline is not None and model_cfg.attn_layers:
-                    # one of the layers' geometries does not fit
-                    self.declines["ragged_kernel"] = decline
+                self._ragged_pool_shape = (
+                    (page_size, model_cfg.page_width, kh_l, group),
+                    dict(dv=(model_cfg.kv_lora_rank if model_cfg.latent
+                             else model_cfg.head_dim),
+                         itemsize=(1 if self.kv_quant_spec is not None
+                                   else jnp.dtype(dtype).itemsize),
+                         q_itemsize=jnp.dtype(dtype).itemsize,
+                         latent=model_cfg.latent,
+                         quantized=self.kv_quant_spec is not None))
+                # ... and every other geometry the attention
+                # layers have: the first that declines decides.
+                decline = next(
+                    (r for r in (
+                        _pattn.ragged_decline_reason(
+                            *self._ragged_class_shape(h),
+                            **self._ragged_pool_shape[1])
+                        for h, _w, _n in model_cfg.attention_classes)
+                     if r is not None), None)
+            if (decline is None
+                    and self.kv_quant_fallback_reason is not None):
+                # Quantized pool the kernel cannot dequantize
+                # in-kernel (ISSUE 11): ragged dispatches serve the
+                # XLA dense path with the quant decline recorded.
+                decline = f"kv_quant:{self.kv_quant_fallback_reason}"
+            self.ragged_path = ("pallas_ragged" if decline is None
+                                else "xla_ragged")
+            self.ragged_fallback_reason = decline
+            if model_cfg.retention_layers and decline is None:
+                # State on the slot arrays: a prologue's [B, T]
+                # program is the ragged program's chunked runs again
+                # (the same kernel a page of a run), at a shape a
+                # batch size a bucket — nothing a join gains, and a
+                # compile each: 35 s of a warm set-up, more of a
+                # cold one, 9.3 s inside a window at a bucket no
+                # warm-up met (PERF.md, PR 42). So the scheduler's
+                # joins take the ragged program whatever the batch
+                # holds and however few tokens they bring; the
+                # prologue serves generate_batch, which no scheduler
+                # stands behind.
+                self.joins_ragged_alone = True
+                self.ragged_defer_min = 0
+            if decline is not None and model_cfg.attn_layers:
+                # one of the layers' geometries does not fit
+                self.declines["ragged_kernel"] = decline
 
-            @partial(jax.jit, donate_argnums=(1,),
-                     static_argnames=("greedy", "attn_path",
-                                      "score_width", "propose_width"))
-            def ragged_step(params, pools, tables, tokens, positions,
-                            token_pages, token_offs, token_seq,
-                            seq_of_block, block_qstart, query_offsets,
-                            kv_valid, last_rows, key, temps, top_ks,
-                            top_ps, sample_rows=None, greedy=True,
-                            attn_path="kernel", score_width=0,
-                            lora=None, copy_src=None, copy_dst=None,
-                            propose_width=0):
-                from .paged_forward import forward_ragged
-                with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
-                        self._lora_scope(lora):
-                    pools_l, scales_l = _kvq_split(pools, _n_layers)
-                    logits, new_pools = forward_ragged(
-                        params, cfg,
-                        tokens, positions, pools_l, tables, seq_of_block,
-                        block_qstart, query_offsets, kv_valid,
-                        token_pages, token_offs, token_seq, last_rows,
-                        attn_path=attn_path,
-                        sample_rows=(sample_rows if score_width
-                                     else None),
-                        scales=scales_l, quant_spec=_kvq_spec,
-                        copy_src=copy_src, copy_dst=copy_dst)
-                    lf = logits.astype(jnp.float32)
-                    if score_width:
-                        # Speculative verify (ISSUE 9): per-position
-                        # tokens [S, R] — greedy argmax, or an exact
-                        # per-position sample through the SAME
-                        # sample_token_batch the decode loop uses (one
-                        # categorical key draws S*R independent rows).
-                        s, r, v = lf.shape
-                        if greedy:
-                            nxt = jnp.argmax(lf, axis=-1)
-                        else:
-                            nxt = sample_token_batch(
-                                lf.reshape(s * r, v), key,
-                                jnp.repeat(temps, r),
-                                jnp.repeat(top_ks, r),
-                                jnp.repeat(top_ps, r)).reshape(s, r)
-                        nxt = nxt.astype(jnp.int32)
-                    elif greedy:
-                        nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+        @partial(jax.jit, donate_argnums=(1,),
+                 static_argnames=("greedy", "attn_path",
+                                  "score_width", "propose_width"))
+        def ragged_step(params, pools, tables, tokens, positions,
+                        token_pages, token_offs, token_seq,
+                        seq_of_block, block_qstart, query_offsets,
+                        kv_valid, last_rows, key, temps, top_ks,
+                        top_ps, sample_rows=None, greedy=True,
+                        attn_path="kernel", score_width=0,
+                        lora=None, copy_src=None, copy_dst=None,
+                        propose_width=0):
+            from .paged_forward import forward_ragged
+            with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
+                    self._lora_scope(lora):
+                pools_l, scales_l = _kvq_split(pools, _n_layers)
+                logits, new_pools = forward_ragged(
+                    params, cfg,
+                    tokens, positions, pools_l, tables, seq_of_block,
+                    block_qstart, query_offsets, kv_valid,
+                    token_pages, token_offs, token_seq, last_rows,
+                    attn_path=attn_path,
+                    sample_rows=(sample_rows if score_width
+                                 else None),
+                    scales=scales_l, quant_spec=_kvq_spec,
+                    copy_src=copy_src, copy_dst=copy_dst)
+                lf = logits.astype(jnp.float32)
+                if score_width:
+                    # Speculative verify (ISSUE 9): per-position
+                    # tokens [S, R] — greedy argmax, or an exact
+                    # per-position sample through the SAME
+                    # sample_token_batch the decode loop uses (one
+                    # categorical key draws S*R independent rows).
+                    s, r, v = lf.shape
+                    if greedy:
+                        nxt = jnp.argmax(lf, axis=-1)
                     else:
                         nxt = sample_token_batch(
-                            lf, key, temps, top_ks,
-                            top_ps).astype(jnp.int32)
-                if propose_width:
-                    # Draft-model propose dispatch (ISSUE 13): alongside
-                    # the greedy next token, the top-`propose_width` ids
-                    # of each row's tip distribution seed the root
-                    # branches of the token tree. score_width==0 here
-                    # (propose batches are plain ragged dispatches), so
-                    # lf is [S, V].
-                    tops = jax.lax.top_k(
-                        lf, propose_width)[1].astype(jnp.int32)
-                    return host_read(nxt, tops), new_pools
-                return host_read(nxt), new_pools
+                            lf.reshape(s * r, v), key,
+                            jnp.repeat(temps, r),
+                            jnp.repeat(top_ks, r),
+                            jnp.repeat(top_ps, r)).reshape(s, r)
+                    nxt = nxt.astype(jnp.int32)
+                elif greedy:
+                    nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+                else:
+                    nxt = sample_token_batch(
+                        lf, key, temps, top_ks,
+                        top_ps).astype(jnp.int32)
+            if propose_width:
+                # Draft-model propose dispatch (ISSUE 13): alongside
+                # the greedy next token, the top-`propose_width` ids
+                # of each row's tip distribution seed the root
+                # branches of the token tree. score_width==0 here
+                # (propose batches are plain ragged dispatches), so
+                # lf is [S, V].
+                tops = jax.lax.top_k(
+                    lf, propose_width)[1].astype(jnp.int32)
+                return host_read(nxt, tops), new_pools
+            return host_read(nxt), new_pools
 
-            self._ragged_step = ragged_step
+        self._ragged_step = ragged_step
 
         # Recurrent state beside the pools (models/hybrid.py,
         # engine/hybrid_state.py): a model with layer_kinds serves
@@ -1152,11 +1032,8 @@ class InferenceEngine:
         # drafter kind -> [drafted, accepted] (per-proposer attribution
         # for the labeled acceptance-rate gauge).
         self._spec_by_drafter: dict[str, list[int]] = {}
-        self._spec_recent = _deque(maxlen=32) if kv_layout == "paged" \
-            else None
-        if kv_layout != "paged":
-            self.spec_reason = "kv_layout:contiguous"
-        elif "spec_decode" in self.declines:
+        self._spec_recent = _deque(maxlen=32)
+        if "spec_decode" in self.declines:
             # A rejected draft cannot be un-consumed from a recurrent
             # state: chain, tree and draft rows all decline.
             self.spec_reason = self.declines["spec_decode"]
@@ -1441,8 +1318,6 @@ class InferenceEngine:
         away from a device drafter; the per-row path at retire is the
         scheduler's _drop_request)."""
         from .spec_decode import DRAFT_SCOPE
-        if self.kv_layout != "paged":
-            return
         for name in list(self.kv._slots):
             if name.startswith(DRAFT_SCOPE):
                 self.kv.release(name)
@@ -1534,7 +1409,7 @@ class InferenceEngine:
             long_scheme=cfg.get("long_scheme", "ring"),
             attn=cfg.get("attn", "auto"),
             devices=cfg.get("devices"),
-            kv_layout=cfg.get("kv_layout", "contiguous"),
+            kv_layout=cfg.get("kv_layout", "paged"),
             page_size=int(cfg.get("page_size", 128)),
             num_pages=(int(cfg["num_pages"])
                        if cfg.get("num_pages") else None),
@@ -1642,9 +1517,9 @@ class InferenceEngine:
             for b in batch_sizes:
                 if b > self.kv.num_slots:
                     continue
-                # Paged pools (default: HALF the contiguous budget) can't
-                # pin every batch size at the full prompt limit — cap the
-                # warm length at what the pool can hold, exactly like real
+                # The pool (default: half of every slot at full length)
+                # can't pin every batch size at the full prompt limit —
+                # cap the warm length at what it can hold, exactly like real
                 # serving: prompts past the cap exhaust the pool at THIS
                 # batch size anyway, so their buckets are unreachable and
                 # need no warming.
@@ -1689,10 +1564,11 @@ class InferenceEngine:
                     if length >= cap_b:
                         break
                     length *= 2
-        # Warm the shared-prefix copy program (copy_spans is ONE shape
-        # thanks to _apply_copies' padding) and the layout fixpoint of the
-        # prefill/decode programs that run right after a copy — otherwise
-        # the first real round with a shared preamble compiles mid-serve.
+        # Warm the shared-prefix path (the leader's span prefill, the
+        # laggards' alias and boundary-page copy) and the layout fixpoint
+        # of the prefill/decode programs that run right after it —
+        # otherwise the first real round with a shared preamble compiles
+        # mid-serve.
         if (self.kv.num_slots >= 2
                 and min(limit, self._warm_prompt_cap(2))
                 > MIN_SHARED_PREFIX + 8):
@@ -1711,8 +1587,7 @@ class InferenceEngine:
             self._warm_ragged()
         # Every width of the page copier (ISSUE 38): the queue's first
         # long flush must not be the one that compiles it.
-        if self.kv_layout == "paged":
-            self.kv.warm_copier()
+        self.kv.warm_copier()
         # Warm the offload tier's fetch/write programs (ONE fixed shape
         # each, ISSUE 7): a first idle-session spill/restore in steady
         # state must compile nothing under ROUNDTABLE_RECOMPILE_STRICT.
@@ -1822,13 +1697,10 @@ class InferenceEngine:
         """Longest prompt a b-row warm batch can pin without exhausting
         the paged pool (each row pins ceil((len + DECODE_SEGMENT) /
         page_size) pages; warm slots balance over replicas, so the
-        tightest replica hosts ceil(b / data) rows). Contiguous layouts
-        have no cap. Real serving past this length exhausts the pool at
-        this batch size with the allocator's actionable RuntimeError —
-        warming those buckets would crash warmup for shapes serving can
-        never reach."""
-        if self.kv_layout != "paged":
-            return self.max_seq_len
+        tightest replica hosts ceil(b / data) rows). Real serving past
+        this length exhausts the pool at this batch size with the
+        allocator's actionable RuntimeError — warming those buckets would
+        crash warmup for shapes serving can never reach."""
         rows = -(-b // max(self.kv.data_size, 1))
         return ((self.kv.pages_per_replica() // max(rows, 1))
                 * self.kv.page_size - DECODE_SEGMENT)
@@ -2369,7 +2241,7 @@ class InferenceEngine:
             "dispatches": dict(self._kv_quant_dispatches),
             "recent": list(self._kv_quant_recent)[-8:],
         }
-        if spec is not None and self.kv_layout == "paged":
+        if spec is not None:
             info["group"] = spec.effective_group(self.cfg.head_dim)
             info["bytes_saved"] = max(
                 self.kv.hbm_bytes_logical() - self.kv.hbm_bytes(), 0)
@@ -2400,14 +2272,13 @@ class InferenceEngine:
         d_tot = self._spec_by_drafter.setdefault(drafter, [0, 0])
         d_tot[0] += drafted
         d_tot[1] += accepted
-        if self._spec_recent is not None:
-            entry = {"drafted": drafted, "accepted": accepted,
-                     "rows": rows, "path": self.ragged_path,
-                     "drafter": drafter}
-            if tree_rows:
-                entry["tree_rows"] = tree_rows
-                entry["tree_nodes"] = tree_nodes
-            self._spec_recent.append(entry)
+        entry = {"drafted": drafted, "accepted": accepted,
+                 "rows": rows, "path": self.ragged_path,
+                 "drafter": drafter}
+        if tree_rows:
+            entry["tree_rows"] = tree_rows
+            entry["tree_nodes"] = tree_nodes
+        self._spec_recent.append(entry)
         _sd.note_spec_dispatch(drafted, accepted)
         from ..utils import telemetry
         name = self.cfg.name
@@ -2463,8 +2334,7 @@ class InferenceEngine:
             "tree_rows": self._spec_tree_rows,
             "draft_dispatches": (dd.draft_dispatches
                                  if dd is not None else 0),
-            "recent": (list(self._spec_recent)[-8:]
-                       if self._spec_recent is not None else []),
+            "recent": list(self._spec_recent)[-8:],
         }
 
     def chars_per_token(self) -> float:
@@ -2479,9 +2349,9 @@ class InferenceEngine:
         self._key, sub = jax.random.split(self._key)
         return sub
 
-    def _prefill(self, slot_ids: list[int], token_lists: list[list[int]],
-                 offsets: list[int], deadline: float = float("inf"),
-                 tables: Optional[np.ndarray] = None,
+    def _prefill(self, state_rows: list[int],
+                 token_lists: list[list[int]], offsets: list[int],
+                 tables: np.ndarray, deadline: float = float("inf"),
                  budget=None, lora_ids=None) -> jax.Array:
         """Prefill dispatch: fresh long prompts go to the sequence-parallel
         ring program; everything else (short prompts, delta prefills on a
@@ -2493,31 +2363,27 @@ class InferenceEngine:
             n_seq = self.seq_mesh.shape[SEQ_AXIS]
             tpad = pad_to_ring(max(len(t) for t in token_lists), n_seq,
                                self.kv.max_seq_len)
-            # Paged writeback scatters whole pages, so the padded length
+            # The writeback scatters whole pages, so the padded length
             # must also land on a page boundary — when the bucket doesn't
             # (tpad below page_size for near-threshold prompts, or the
             # cache-cap clamp), chunked prefill is the correct fallback,
             # not an error.
-            if tpad and (self.kv_layout != "paged"
-                         or tpad % self.kv.page_size == 0):
+            if tpad and tpad % self.kv.page_size == 0:
                 # (lora engines never build a ring program — the
                 # constructor declines the feature on seq-parallel
                 # engines, so lora_ids cannot reach this branch.)
-                return self._prefill_ring(slot_ids, token_lists, tpad,
-                                          tables)
-        return self._prefill_chunked(slot_ids, token_lists, offsets,
-                                     deadline, tables, budget,
+                return self._prefill_ring(token_lists, tpad, tables)
+        return self._prefill_chunked(state_rows, token_lists, offsets,
+                                     tables, deadline, budget,
                                      lora_ids=lora_ids)
 
-    def _prefill_ring(self, slot_ids: list[int],
-                      token_lists: list[list[int]], tpad: int,
-                      tables: Optional[np.ndarray] = None) -> jax.Array:
+    def _prefill_ring(self, token_lists: list[list[int]], tpad: int,
+                      tables: np.ndarray) -> jax.Array:
         """One sequence-parallel program prefills the whole batch; the
-        full-sequence K/V is scattered into the slot cache (or through
-        the page tables) so decode and later delta-prefills continue on
-        the normal path. Under data>1 pool-direct the caller passes
-        replica-padded token_lists/tables (slot_ids stay unpadded — the
-        paged branch never indexes by slot), so B comes from the rows."""
+        full-sequence K/V is scattered through the page tables so decode
+        and later delta-prefills continue on the normal path. Under
+        data>1 pool-direct the caller passes replica-padded
+        token_lists/tables, so B comes from the rows."""
         b = len(token_lists)
         tokens = np.full((b, tpad), self.tokenizer.pad_id, np.int32)
         for i, t in enumerate(token_lists):
@@ -2531,31 +2397,24 @@ class InferenceEngine:
             logits, caches = self._ring_prefill_fn(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(lengths))
-        if self.kv_layout == "paged":
-            self.kv.set_combined(self._scatter_kv_paged(
-                self.kv.combined_pools(), jnp.asarray(tables), caches))
-        else:
-            slot_idx = jnp.asarray(slot_ids, jnp.int32)
-            self.kv.layers = self._scatter_kv(self.kv.layers, slot_idx,
-                                              caches)
+        self.kv.set_combined(self._scatter_kv_paged(
+            self.kv.combined_pools(), jnp.asarray(tables), caches))
         return logits
 
-    def _prefill_chunked(self, slot_ids: list[int],
+    def _prefill_chunked(self, state_rows: list[int],
                          token_lists: list[list[int]], offsets: list[int],
+                         tables: np.ndarray,
                          deadline: float = float("inf"),
-                         tables: Optional[np.ndarray] = None,
                          budget=None, lora_ids=None) -> jax.Array:
         """Chunked, bucketed prefill for B rows (serving_loop loop with
         this engine's step program). Returns last-token logits [B, V].
 
         `tables` is the caller-built page table for the whole call
         (capacity is ensured before any prefill dispatch; under data>1
-        pool-direct it is already replica-grouped and padded)."""
-        slot_idx = jnp.asarray(slot_ids, jnp.int32)
-        if self.kv_layout == "paged":
-            tables = jnp.asarray(tables)
-        else:
-            tables = None
+        pool-direct it is already replica-grouped and padded).
+        `state_rows`: each row's state row of a model with layer_kinds
+        (hybrid_state; -1: none, the scratch row)."""
+        tables = jnp.asarray(tables)
         # Per-row adapter slots for the whole call (ISSUE 10): chunk
         # composition varies, the ids do not — one device arg serves
         # every chunk dispatch.
@@ -2576,7 +2435,7 @@ class InferenceEngine:
                 takes = [max(min(e - o, chunk.shape[1]), 0)
                          for e, o in zip(ends, offs)]
                 return self._hybrid_prefill(tables, chunk, offs, takes,
-                                            slot_ids)
+                                            state_rows)
             return self._prefill_step_paged(
                 self.params, self.kv.combined_pools(), tables,
                 jnp.asarray(chunk), jnp.asarray(offs, jnp.int32),
@@ -2590,65 +2449,33 @@ class InferenceEngine:
             with compile_watch.label(
                     f"prefill[b={chunk.shape[0]},bucket={chunk.shape[1]}]",
                     engine=self.cfg.name):
-                if tables is not None:
-                    try:
-                        last, pools = paged_prefill(chunk, offs, lengths)
-                    except Exception as e:
-                        # Kernel-path failure on a pool-direct engine:
-                        # degrade to the gather-view programs and
-                        # re-dispatch this chunk (inputs are host arrays,
-                        # pools were not consumed by a failed compile).
-                        # Anything else goes to the retry policy / the
-                        # adapter ladder.
-                        if not (faults.is_kernel_failure(e)
-                                and self._degrade_paged_direct(str(e))):
-                            raise
-                        last, pools = paged_prefill(chunk, offs, lengths)
-                    # A watchdog-abandoned dispatch completing late must
-                    # NOT commit onto pools the recovery path may have
-                    # revived (the guard holds the ticket lock across
-                    # the commit).
-                    with deadlines.commit_guard():
-                        self.kv.set_combined(pools)
-                    self._note_kv_quant("prefill",
-                                        kernel=self.paged_direct)
-                else:
-                    last, layers = self._prefill_step(
-                        self.params, self.kv.layers, slot_idx,
-                        jnp.asarray(chunk), jnp.asarray(offs, jnp.int32),
-                        jnp.asarray(lengths), lora=lora_arg)
-                    with deadlines.commit_guard():
-                        self.kv.layers = layers
+                try:
+                    last, pools = paged_prefill(chunk, offs, lengths)
+                except Exception as e:
+                    # Kernel-path failure on a pool-direct engine:
+                    # degrade to the gather-view programs and
+                    # re-dispatch this chunk (inputs are host arrays,
+                    # pools were not consumed by a failed compile).
+                    # Anything else goes to the retry policy / the
+                    # adapter ladder.
+                    if not (faults.is_kernel_failure(e)
+                            and self._degrade_paged_direct(str(e))):
+                        raise
+                    last, pools = paged_prefill(chunk, offs, lengths)
+                # A watchdog-abandoned dispatch completing late must
+                # NOT commit onto pools the recovery path may have
+                # revived (the guard holds the ticket lock across
+                # the commit).
+                with deadlines.commit_guard():
+                    self.kv.set_combined(pools)
+                self._note_kv_quant("prefill", kernel=self.paged_direct)
                 return last
 
         return chunked_prefill(dispatch, token_lists, offsets,
                                self.kv.max_seq_len, self.tokenizer.pad_id,
                                deadline, retry=self.retry, budget=budget)
 
-    def _apply_copies(self, copies: list[tuple[int, int, int, int]]) -> None:
-        """Dispatch queued (src_slot, dst_slot, lo, hi) K/V span copies.
-
-        The list is padded to num_slots rows so copy_spans compiles exactly
-        ONE shape per engine (no mid-serve recompiles as batch compositions
-        vary). Pad rows self-copy an empty span of a slot that is NOT a
-        real destination — dst indices must stay distinct because scatter
-        order among duplicate indices is unspecified."""
-        if not copies:
-            return
-        width = self.kv.num_slots
-        if len(copies) < width:
-            used = {c[1] for c in copies}
-            pad_dst = next(i for i in range(width) if i not in used)
-            copies = copies + [(pad_dst, pad_dst, 0, 0)] * (width -
-                                                            len(copies))
-        self.kv.layers = self._copy_spans(
-            self.kv.layers,
-            jnp.asarray([c[0] for c in copies], jnp.int32),
-            jnp.asarray([c[1] for c in copies], jnp.int32),
-            jnp.asarray([c[2] for c in copies], jnp.int32),
-            jnp.asarray([c[3] for c in copies], jnp.int32))
-
-    def _share_prefixes(self, names: list[str], slot_ids: list[int],
+    def _share_prefixes(self, names: list[str],
                         all_tokens: list[list[int]], offsets: list[int],
                         deadline: float, budget=None,
                         extra_pinned: tuple[str, ...] = (),
@@ -2659,65 +2486,50 @@ class InferenceEngine:
         knights share the giant context+transcript preamble, which the
         orchestrator here lays out as a common PREFIX).
 
-        Two mechanisms, both copying position-aligned K/V between slots:
+        Two mechanisms, both sharing position-aligned K/V between slots:
         (a) donor pass — a slot committed by an earlier call (another
             knight's turn) that shares a longer token prefix than this
             row's own history donates its K/V span;
         (b) leader pass — within one batch of fresh rows, the row with the
             most cache coverage prefills the batch-wide common span ONCE
-            (ring-eligible when long) and the others copy it.
+            (ring-eligible when long) and the others take it.
 
         Returns (updated offsets, leader-prefilled token count). Prefill
-        FLOPs for the shared span are paid once instead of N times; HBM
-        still holds per-slot copies (true page-level dedup is the paged-KV
-        allocator's job). The pass structure itself lives in
+        FLOPs for the shared span are paid once instead of N times, and
+        its whole pages are held once. The pass structure itself lives in
         kvcache.share_prefixes; this method provides the device
-        mechanics: paged caches ALIAS the donor's
-        whole pages (refcount, zero copy; partial boundary pages are
-        device-copied), contiguous caches queue K/V span copies, and the
+        mechanics: a share ALIASES the donor's whole pages (refcount,
+        zero copy; partial boundary pages are device-copied), and the
         leader span prefills via _prefill so a fresh long shared span
         takes the ring path on sequence-parallel engines."""
         from .kvcache import share_prefixes
-        paged = self.kv_layout == "paged"
         pinned = tuple(names) + tuple(extra_pinned)
-        copies: list[tuple[int, int, int, int]] = []
 
         def add_share(donor, i, lo, hi):
-            if paged:
-                self.kv.alias_span(donor.name, names[i], lo, hi, pinned)
-            else:
-                copies.append((donor.slot_id, slot_ids[i], lo, hi))
-
-        def flush_shares():
-            self._apply_copies(copies)
-            copies.clear()
+            self.kv.alias_span(donor.name, names[i], lo, hi, pinned)
 
         def prefill_span(m, lo, hi):
             l_ids = ([row_lora_slots[m]] if row_lora_slots is not None
                      else None)
-            if paged:
-                self.kv.ensure_capacity(names[m], hi, write_from=lo,
-                                        pinned=pinned)
-                table = self.kv.table_for([names[m]])
-                toks, offs = [all_tokens[m][lo:hi]], [lo]
-                if self.paged_direct and self._paged_replicas > 1:
-                    # Single-row leader prefill under data>1 pool-direct
-                    # pads to one row per replica, like generate_batch.
-                    p = ReplicaGroupPlan(
-                        [self.kv.replica_of(names[m])],
-                        self._paged_replicas)
-                    table = p.pad_table(table, self.kv.scratch_page)
-                    toks = p.scatter_list(toks, [self.tokenizer.pad_id])
-                    offs = p.scatter_list(offs, 0)
-                    if l_ids is not None:
-                        l_ids = p.scatter_list(l_ids, 0)
-                self._prefill([slot_ids[m]], toks, offs, deadline,
-                              tables=table, budget=budget,
-                              lora_ids=l_ids)
-            else:
-                self._prefill([slot_ids[m]], [all_tokens[m][lo:hi]],
-                              [lo], deadline, budget=budget,
-                              lora_ids=l_ids)
+            self.kv.ensure_capacity(names[m], hi, write_from=lo,
+                                    pinned=pinned)
+            table = self.kv.table_for([names[m]])
+            toks, offs = [all_tokens[m][lo:hi]], [lo]
+            if self.paged_direct and self._paged_replicas > 1:
+                # Single-row leader prefill under data>1 pool-direct
+                # pads to one row per replica, like generate_batch.
+                p = ReplicaGroupPlan(
+                    [self.kv.replica_of(names[m])],
+                    self._paged_replicas)
+                table = p.pad_table(table, self.kv.scratch_page)
+                toks = p.scatter_list(toks, [self.tokenizer.pad_id])
+                offs = p.scatter_list(offs, 0)
+                if l_ids is not None:
+                    l_ids = p.scatter_list(l_ids, 0)
+            # (no state row: a model with recurrent state declines the
+            # leader pass)
+            self._prefill([-1], toks, offs, table, deadline,
+                          budget=budget, lora_ids=l_ids)
 
         # Adapter-identity donor filter (ISSUE 10): K/V baked under one
         # adapter is WRONG under another, so a donor only serves rows
@@ -2734,7 +2546,7 @@ class InferenceEngine:
         return share_prefixes(
             self.kv, names, all_tokens, offsets,
             min_shared=MIN_SHARED_PREFIX, add_share=add_share,
-            flush_shares=flush_shares, prefill_span=prefill_span,
+            prefill_span=prefill_span,
             extra_pinned=extra_pinned, defer_span=defer_span,
             donor_ok=donor_ok,
             decline_leader=(self._decline_leader_share
@@ -2845,7 +2657,7 @@ class InferenceEngine:
 
         `extra_pinned` names survive every eviction this phase can
         trigger (the scheduler pins its actively-decoding rows).
-        Returns a dict with: names, slot_ids, all_tokens, offsets
+        Returns a dict with: names, state_rows, all_tokens, offsets
         (post-share), plan, tables_np (plan-padded when plan is set),
         per_row, temps/top_ks/top_ps (plan-scattered), greedy,
         first_np (ORIGINAL row order), prefill_tokens, reused_tokens.
@@ -2870,7 +2682,7 @@ class InferenceEngine:
         # to its prologue — under the scheduler's `admit` span on its
         # thread, under a turn's on any other; unarmed, the null span.
         with telemetry.span("plan") as span:
-            took = getattr(self.kv, "pages_allocated", 0)
+            took = self.kv.pages_allocated
             prep = self._plan_batch(
                 turns, max_new_padded, deadline, pre_budget,
                 sampling_per_turn, extra_pinned, defer_prefill, adapters)
@@ -2878,8 +2690,7 @@ class InferenceEngine:
                 span.attrs.update(
                     prompt_tokens=sum(len(t) for t in prep["all_tokens"]),
                     matched_tokens=prep["reused_tokens"],
-                    pages_allocated=getattr(self.kv, "pages_allocated",
-                                            0) - took)
+                    pages_allocated=self.kv.pages_allocated - took)
         if prep.pop("deferred"):
             return prep
         return self._prologue(prep, deadline, pre_budget)
@@ -2949,7 +2760,7 @@ class InferenceEngine:
                 self._slot_adapters = {
                     n: a_ for n, a_ in self._slot_adapters.items()
                     if n in live or session_of(n) in spilled}
-        slot_ids, offsets, all_tokens = [], [], []
+        offsets, all_tokens = [], []
         for name, prompt in turns:
             # A list of ids is accepted as a pre-tokenized prompt (warmup
             # uses this to hit exact bucket shapes).
@@ -2962,9 +2773,7 @@ class InferenceEngine:
                 # intent).
                 tokens = (tokens[:1]
                           + tokens[len(tokens) - budget_tok + 1:])
-            slot_id, reuse = self.kv.reuse_plan(name, tokens, pinned)
-            slot_ids.append(slot_id)
-            offsets.append(reuse)
+            offsets.append(self.kv.reuse_plan(name, tokens, pinned)[1])
             all_tokens.append(tokens)
 
         names = [name for name, _ in turns]
@@ -3005,8 +2814,8 @@ class InferenceEngine:
             est = sum(len(t) - o for t, o in zip(all_tokens, offsets))
             if est < self.ragged_defer_min:
                 defer_prefill = False
-        # Cross-knight shared-prefix reuse raises offsets by copying (or,
-        # paged, aliasing) other slots' K/V; only the per-knight deltas
+        # Cross-knight shared-prefix reuse raises offsets by aliasing
+        # other slots' pages; only the per-knight deltas
         # remain to prefill. Under defer_prefill the LEADER pass defers
         # too (ISSUE 8 — it was the last blocking prologue dispatch):
         # the span is recorded here and the scheduler aliases the
@@ -3025,14 +2834,15 @@ class InferenceEngine:
             leader_prefill = 0
         else:
             offsets, leader_prefill = self._share_prefixes(
-                names, slot_ids, all_tokens, offsets, deadline,
+                names, all_tokens, offsets, deadline,
                 budget=pre_budget, extra_pinned=tuple(extra_pinned),
                 defer_span=defer_span, row_adapters=ad,
                 row_lora_slots=lora_slots)
         state_plan = None
+        state_rows = [-1] * len(names)
         if self.hybrid is not None:
             state_plan = self._plan_states(names, all_tokens, offsets)
-            slot_ids = state_plan.pop("rows")
+            state_rows = state_plan.pop("rows")
             if defer_by_state:
                 # What a join has to scan is decided by where its STATE
                 # stands, not its pages: the cold-or-warm question
@@ -3040,37 +2850,35 @@ class InferenceEngine:
                 est = sum(len(t) - o for t, o in zip(all_tokens, offsets))
                 defer_prefill = est >= self.ragged_defer_min
         plan = None
-        tables_np = None
-        if self.kv_layout == "paged":
-            # Allocate pages for the whole call (prompt + padded decode)
-            # and copy-on-write any shared page in the write range, so
-            # the jit'd programs below never allocate or touch aliased
-            # pages. Deferred-share LAGGARDS skip this: their span pages
-            # arrive by ALIAS once the leader's chunks write them —
-            # allocating exclusive pages now would transiently demand
-            # more pool than the prologue path ever did (the alias
-            # would immediately replace them), and their tail capacity
-            # is ensured at alias time (scheduler._apply_share_plans).
-            deferred_followers = {i for p in share_plan
-                                  for i, _lo in p["followers"]}
-            for i, name in enumerate(names):
-                if i in deferred_followers:
-                    continue
-                self.kv.ensure_capacity(
-                    name, len(all_tokens[i]) + max_new_padded,
-                    write_from=offsets[i], pinned=pinned)
-            tables_np = self.kv.table_for(names)
-            if self.paged_direct and self._paged_replicas > 1:
-                # Pool-direct under data>1 (VERDICT r4 #4): shard_map
-                # splits batch rows into contiguous per-data-index
-                # blocks, so rows are permuted into the block of the
-                # replica owning their slot's pages; pad rows point at
-                # that replica's scratch page and start done.
-                plan = ReplicaGroupPlan(
-                    [self.kv.replica_of(n) for n in names],
-                    self._paged_replicas)
-                tables_np = plan.pad_table(tables_np,
-                                           self.kv.scratch_page)
+        # Allocate pages for the whole call (prompt + padded decode)
+        # and copy-on-write any shared page in the write range, so
+        # the jit'd programs below never allocate or touch aliased
+        # pages. Deferred-share LAGGARDS skip this: their span pages
+        # arrive by ALIAS once the leader's chunks write them —
+        # allocating exclusive pages now would transiently demand
+        # more pool than the prologue path ever did (the alias
+        # would immediately replace them), and their tail capacity
+        # is ensured at alias time (scheduler._apply_share_plans).
+        deferred_followers = {i for p in share_plan
+                              for i, _lo in p["followers"]}
+        for i, name in enumerate(names):
+            if i in deferred_followers:
+                continue
+            self.kv.ensure_capacity(
+                name, len(all_tokens[i]) + max_new_padded,
+                write_from=offsets[i], pinned=pinned)
+        tables_np = self.kv.table_for(names)
+        if self.paged_direct and self._paged_replicas > 1:
+            # Pool-direct under data>1 (VERDICT r4 #4): shard_map
+            # splits batch rows into adjacent per-data-index
+            # blocks, so rows are permuted into the block of the
+            # replica owning their slot's pages; pad rows point at
+            # that replica's scratch page and start done.
+            plan = ReplicaGroupPlan(
+                [self.kv.replica_of(n) for n in names],
+                self._paged_replicas)
+            tables_np = plan.pad_table(tables_np,
+                                       self.kv.scratch_page)
         suffixes = [t[o:] for t, o in zip(all_tokens, offsets)]
         prefill_tokens = leader_prefill + sum(len(s) for s in suffixes)
         # "reused" counts both own-slot LCP hits and copied donor spans.
@@ -3085,7 +2893,7 @@ class InferenceEngine:
                 f"sampling_per_turn has {len(per_row)} entries for "
                 f"{len(turns)} turns")
         return {
-            "names": names, "slot_ids": slot_ids,
+            "names": names, "state_rows": state_rows,
             "all_tokens": all_tokens, "offsets": offsets,
             "plan": plan, "tables_np": tables_np,
             "per_row": per_row, "temps": None, "top_ks": None,
@@ -3127,9 +2935,9 @@ class InferenceEngine:
         # eager stands between the prefill step and the first token. A
         # greedy batch draws no key, as before.
         key = self._key if greedy else self._next_key()
-        last_logits = self._prefill(prep["slot_ids"], suffixes, p_offsets,
+        last_logits = self._prefill(prep["state_rows"], suffixes,
+                                    p_offsets, prep["tables_np"],
                                     deadline=deadline,
-                                    tables=prep["tables_np"],
                                     budget=pre_budget, lora_ids=p_lora)
         from ..utils import telemetry
         from . import compile_watch
@@ -3202,22 +3010,6 @@ class InferenceEngine:
         with deadlines.commit_guard():
             self.kv.set_combined(pools)
         self._note_kv_quant("decode", kernel=self.paged_direct)
-        return out, steps, l2, v2, d2
-
-    def _decode_dispatch_slots(self, slot_idx, last, valid, key, budget,
-                               temps, top_ks, top_ps, row_budgets, done0,
-                               *, greedy, max_new=DECODE_SEGMENT,
-                               lora=None):
-        """Contiguous-layout counterpart of _decode_dispatch_paged."""
-        from . import compile_watch
-        with compile_watch.label(f"decode[b={last.shape[0]}]",
-                                 engine=self.cfg.name):
-            out, steps, l2, v2, d2, layers = self._decode_loop(
-                self.params, self.kv.layers, slot_idx, last, valid, key,
-                budget, temps, top_ks, top_ps, row_budgets, done0,
-                max_new=max_new, greedy=greedy, lora=lora)
-        with deadlines.commit_guard():
-            self.kv.layers = layers
         return out, steps, l2, v2, d2
 
     def generate(self, prompt: str, slot_name: str = "default",
@@ -3376,9 +3168,7 @@ class InferenceEngine:
         # Decode rung budget is derived NOW, not at call start, so a
         # configured "decode" cap times the decode phase alone.
         dec_budget = turn_budget.child("decode")
-        slot_idx = jnp.asarray(prep["slot_ids"], jnp.int32)
-        tables = (jnp.asarray(prep["tables_np"])
-                  if self.kv_layout == "paged" else None)
+        tables = jnp.asarray(prep["tables_np"])
         # Per-row decode budgets (knight_sampling max_new_tokens): a row
         # whose own budget is smaller than the batch's stops early (goes
         # done, emits eos) while the rest keep decoding
@@ -3398,15 +3188,10 @@ class InferenceEngine:
             row_budgets = row_remaining(budget)
             if plan is not None:
                 row_budgets = plan.scatter_rows(row_budgets, 0)
-            if tables is not None:
-                return self._decode_dispatch_paged(
-                    tables, cur_last, cur_valid, self._next_key(),
-                    budget, temps, top_ks, top_ps, row_budgets, done0,
-                    greedy=greedy, lora=dec_lora, names=prep["names"])
-            return self._decode_dispatch_slots(
-                slot_idx, cur_last, cur_valid, self._next_key(),
+            return self._decode_dispatch_paged(
+                tables, cur_last, cur_valid, self._next_key(),
                 budget, temps, top_ks, top_ps, row_budgets, done0,
-                greedy=greedy, lora=dec_lora)
+                greedy=greedy, lora=dec_lora, names=prep["names"])
 
         with telemetry.span("decode", engine=self.cfg.name,
                             max_new=max_new):
@@ -3486,7 +3271,7 @@ class InferenceEngine:
             "max_seq_len": self.max_seq_len,
             "mesh": dict(self.mesh.shape),
             "num_slots": self.kv.num_slots,
-            "kv_layout": self.kv_layout,
+            "kv_layout": "paged",
             "quant": (self.quant + " (auto-degraded)"
                       if getattr(self, "quant_auto_degraded", False)
                       else self.quant),
@@ -3494,28 +3279,27 @@ class InferenceEngine:
         }
         if self.quant == "int4":
             info["int4_paths"] = self.int4_path_report()
-        if self.kv_layout == "paged":
-            info["page_size"] = self.kv.page_size
-            info["num_pages"] = self.kv.num_pages
-            info["kv_hbm_bytes"] = self.kv.hbm_bytes()
-            info["paged_decode"] = ("pool-direct" if self.paged_direct
-                                    else "gather-view")
-            # ISSUE 38: pages handed out, page copies queued and the
-            # programs that issued them.
-            info["paging"] = self.kv.describe()
-            # ISSUE 7: the cross-session sharing subsystems' state.
-            if self.prefix_cache is not None:
-                info["prefix_cache"] = self.prefix_cache.describe()
-            if self.kv_offload is not None:
-                info["kv_offload"] = self.kv_offload.describe()
-            # ISSUE 8: ragged mixed-dispatch path provenance.
-            info["ragged"] = self.ragged_describe()
-            # ISSUE 9: speculative-decoding provenance (drafter,
-            # per-dispatch drafted/accepted, throttle state).
-            info["spec_decode"] = self.spec_describe()
-            # ISSUE 11: quantized-KV-page provenance (spec, per-seam
-            # dispatch paths, kernel-decline reason, bytes saved).
-            info["kv_quant"] = self.kv_quant_describe()
+        info["page_size"] = self.kv.page_size
+        info["num_pages"] = self.kv.num_pages
+        info["kv_hbm_bytes"] = self.kv.hbm_bytes()
+        info["paged_decode"] = ("pool-direct" if self.paged_direct
+                                else "gather-view")
+        # ISSUE 38: pages handed out, page copies queued and the
+        # programs that issued them.
+        info["paging"] = self.kv.describe()
+        # ISSUE 7: the cross-session sharing subsystems' state.
+        if self.prefix_cache is not None:
+            info["prefix_cache"] = self.prefix_cache.describe()
+        if self.kv_offload is not None:
+            info["kv_offload"] = self.kv_offload.describe()
+        # ISSUE 8: ragged mixed-dispatch path provenance.
+        info["ragged"] = self.ragged_describe()
+        # ISSUE 9: speculative-decoding provenance (drafter,
+        # per-dispatch drafted/accepted, throttle state).
+        info["spec_decode"] = self.spec_describe()
+        # ISSUE 11: quantized-KV-page provenance (spec, per-seam
+        # dispatch paths, kernel-decline reason, bytes saved).
+        info["kv_quant"] = self.kv_quant_describe()
         if self.hybrid is not None:
             # Recurrent state beside the pools, and the chip's share of
             # the experts (models/hybrid.py, engine/hybrid_state.py).
@@ -3547,9 +3331,9 @@ class InferenceEngine:
                 "kernel": ("jnp" if "retention_step" in self.declines
                            else "retention_step"),
             }
-        if self.cfg.attn_layers is not None and self.kv_layout == "paged":
+        if self.cfg.attn_layers is not None:
             info["attention"] = self.attention_describe()
-        if self.cfg.latent and self.kv_layout == "paged":
+        if self.cfg.latent:
             info["mla"] = self.mla_describe()
         # ISSUE 41: the scheduler's segments, and those in which a
         # sampled row's top_k / top_p engaged the candidate pool.
@@ -3607,20 +3391,18 @@ def _analysis_engine_programs(engine) -> list:
     static argument leaking occupancy shows up as an extra distinct
     jaxpr under that label (the RECOMPILE_STRICT invariant, proven
     without a device). Argument construction mirrors
-    `_prefill`/`_decode_dispatch_*`; drift between the twins fails the
+    `_prefill`/`_decode_dispatch_paged`; drift between the twins fails the
     audit's trace step loudly rather than silently auditing nothing.
     """
     if not isinstance(engine, InferenceEngine) \
             or engine.hybrid is not None:
         return []       # (a hybrid engine: _analysis_hybrid_programs)
     from .serving_loop import pow2_bucket
-    paged = engine.kv_layout == "paged"
     params = _audit_sds(engine.params)
-    pools = _audit_sds(engine.kv.combined_pools()) if paged else None
-    layers = None if paged else _audit_sds(engine.kv.layers)
+    pools = _audit_sds(engine.kv.combined_pools())
     key = jax.random.PRNGKey(0)
     num_slots = engine.kv.num_slots
-    pps = engine.kv.pages_per_seq if paged else 0
+    pps = engine.kv.pages_per_seq
 
     def ints(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
@@ -3630,13 +3412,9 @@ def _analysis_engine_programs(engine) -> list:
 
     def prefill_variant(b: int, bucket: int) -> Variant:
         def thunk():
-            tokens = ints(b, bucket)
-            if paged:
-                return jax.make_jaxpr(engine._prefill_step_paged)(
-                    params, pools, ints(b, pps), tokens, ints(b),
-                    ints(b))
-            return jax.make_jaxpr(engine._prefill_step)(
-                params, layers, ints(b), tokens, ints(b), ints(b))
+            return jax.make_jaxpr(engine._prefill_step_paged)(
+                params, pools, ints(b, pps), ints(b, bucket), ints(b),
+                ints(b))
         return Variant(label=f"b{b}x{bucket}", thunk=thunk,
                        situation=f"batch {b}, bucket {bucket}")
 
@@ -3646,34 +3424,25 @@ def _analysis_engine_programs(engine) -> list:
         def thunk():
             budget = jnp.int32(DECODE_SEGMENT)
             # first_token, start_valid, key, budget, temps, top_ks,
-            # top_ps, row_budgets, done0 — _decode_dispatch_*'s order.
+            # top_ps, row_budgets, done0 — _decode_dispatch_paged's order.
             args = (ints(b), ints(b), key, budget, floats(b), ints(b),
                     floats(b), ints(b),
                     jax.ShapeDtypeStruct((b,), jnp.bool_))
-            if paged:
-                fn = engine._decode_loop_paged
-                return jax.make_jaxpr(
-                    lambda p, pl, t, *a: fn(
-                        p, pl, t, *a, max_new=DECODE_SEGMENT,
-                        greedy=True))(params, pools, ints(b, pps),
-                                      *args)
-            fn = engine._decode_loop
+            fn = engine._decode_loop_paged
             return jax.make_jaxpr(
-                lambda p, cl, s, *a: fn(
-                    p, cl, s, *a, max_new=DECODE_SEGMENT,
-                    greedy=True))(params, layers, ints(b), *args)
+                lambda p, pl, t, *a: fn(
+                    p, pl, t, *a, max_new=DECODE_SEGMENT,
+                    greedy=True))(params, pools, ints(b, pps), *args)
         return Variant(label=f"b{b}", thunk=thunk,
                        situation=f"occupancy {occ}")
 
     bucket = PREFILL_BUCKETS[0]
     prefill = ProgramSpec(
-        name=f"prefill[{'paged' if paged else 'slots'}]",
-        phase="prefill",
+        name="prefill[paged]", phase="prefill",
         variants=[prefill_variant(b, bucket)
                   for b in (1, 2) if b <= num_slots])
     decode = ProgramSpec(
-        name=f"decode[{'paged' if paged else 'slots'}]",
-        phase="decode",
+        name="decode[paged]", phase="decode",
         variants=[decode_variant(occ)
                   for occ in (1, 2, 3, 4) if occ <= num_slots])
     return [prefill, decode]

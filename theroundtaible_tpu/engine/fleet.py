@@ -98,34 +98,32 @@ def estimate_engine_hbm_bytes(engine_cfg: dict[str, Any],
                               else 0.58 if quant == "int4"
                               else dtype_b))
     num_slots = int(engine_cfg.get("num_slots", 4))
+    # Default pool: half of every slot at max_seq_len. Total across the
+    # submesh: the page axis shards over "data" and kv heads over
+    # "model" (engine/paging.py per-replica pools), so
+    # check_fleet_fits' whole-estimate/group-size division is exact —
+    # the pool is not replicated per data replica (advisor r3
+    # underestimate, closed).
     kv_bytes = (num_slots * max_seq * len(model_cfg.attention_layers)
-                * model_cfg.page_cells * dtype_b)
-    if engine_cfg.get("kv_layout") == "paged":
-        # Default pool halves the contiguous budget. Total across the
-        # submesh: the page axis shards over "data" and kv heads over
-        # "model" (engine/paging.py per-replica pools), so
-        # check_fleet_fits' whole-estimate/group-size division is exact
-        # for paged KV too — the pool is no longer replicated per
-        # data replica (advisor r3 underestimate, closed).
-        kv_bytes //= 2
-        # Quantized KV pages (ISSUE 11): charge cells at the CONFIGURED
-        # page dtype width, not bf16. resolve_spec applies the same
-        # ROUNDTABLE_KV_QUANT kill-switch the engine applies, so the
-        # plan matches what construction will actually allocate. With
-        # an explicit num_pages the pool bytes follow the quantized
-        # cell directly; the DEFAULT pool keeps the bf16 byte budget by
-        # design (page_ratio x more pages in the same bytes — the
-        # 2-4x-sessions payoff), so kv_bytes stays the halved budget.
-        num_pages = engine_cfg.get("num_pages")
-        if num_pages is not None:
-            from .kv_quant import cell_bytes_per_token, resolve_spec
-            kvq = engine_cfg.get("kv_quant")
-            spec = (resolve_spec(kvq)[0] if kvq and kvq != "none"
-                    else None)
-            page_size = int(engine_cfg.get("page_size", 128))
-            kv_bytes = int(int(num_pages) * page_size
-                           * cell_bytes_per_token(model_cfg, spec,
-                                                  dtype_b))
+                * model_cfg.page_cells * dtype_b) // 2
+    # Quantized KV pages (ISSUE 11): charge cells at the CONFIGURED
+    # page dtype width, not bf16. resolve_spec applies the same
+    # ROUNDTABLE_KV_QUANT kill-switch the engine applies, so the
+    # plan matches what construction will actually allocate. With
+    # an explicit num_pages the pool bytes follow the quantized
+    # cell directly; the DEFAULT pool keeps the bf16 byte budget by
+    # design (page_ratio x more pages in the same bytes — the
+    # 2-4x-sessions payoff), so kv_bytes stays the halved budget.
+    num_pages = engine_cfg.get("num_pages")
+    if num_pages is not None:
+        from .kv_quant import cell_bytes_per_token, resolve_spec
+        kvq = engine_cfg.get("kv_quant")
+        spec = (resolve_spec(kvq)[0] if kvq and kvq != "none"
+                else None)
+        page_size = int(engine_cfg.get("page_size", 128))
+        kv_bytes = int(int(num_pages) * page_size
+                       * cell_bytes_per_token(model_cfg, spec,
+                                              dtype_b))
     state_bytes = 0
     if model_cfg.layer_kinds is not None:
         # Recurrent state beside the pools (engine/hybrid_state.py): a
@@ -416,11 +414,11 @@ def drain(timeout_s: float = 30.0, flush_kv: bool = True) -> dict[str, Any]:
     2. For each resident engine, acquire its serve lock within
        `timeout_s` — acquisition IS the proof that in-flight work
        finished — and, holding it, flush every per-knight slot through
-       the cache's normal release path (SlotBook.flush: paged pools
-       decref/free their pages — including the cross-session prefix
+       the cache's normal release path (PagedKVCache.flush: the pool
+       decrefs/frees their pages — including the cross-session prefix
        cache's index, which UNREFS its held pages rather than
-       force-freeing (ISSUE 7), so a drained paged pool reads zero
-       pages in use; contiguous slots return to the free list). An
+       force-freeing (ISSUE 7), so a drained pool reads zero
+       pages in use). An
        engine whose in-flight turn outlives the timeout is reported
        `in_flight_drained: False` and left unflushed. Host-RAM spill
        records (kv_offload) survive a drain — a resumed fleet restores

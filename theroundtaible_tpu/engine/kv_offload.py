@@ -114,8 +114,6 @@ class HostOffloadTier:
 
     def __init__(self, engine):
         self.engine = engine
-        if getattr(engine, "kv_layout", None) != "paged":
-            raise TypeError("HostOffloadTier requires a paged engine")
         self._spilled: dict[str, SpilledSession] = {}
         self.spills = 0
         self.restores = 0

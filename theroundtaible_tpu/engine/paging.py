@@ -1,9 +1,9 @@
 """Paged KV cache — page-pool allocation with copy-on-write sharing.
 
-Replaces the contiguous `[num_slots, max_seq_len, K, D]` per-layer cache
-(kvcache.py) whose HBM cost is num_slots × max_seq_len regardless of use
-(VERDICT r1 missing #3; PAPERS.md "Ragged Paged Attention"). Here each
-layer owns a page POOL `[num_pages, page_size, K, D]` and each slot maps
+The engine's one KV layout (PR 46 removed the `[num_slots, max_seq_len,
+K, D]` per-layer slot cache, whose HBM cost was num_slots × max_seq_len
+regardless of use; PAPERS.md "Ragged Paged Attention"). Each layer
+owns a page POOL `[num_pages, page_size, K, D]` and each slot maps
 its logical positions onto pool pages through a page table:
 
 - HBM scales with tokens actually cached, not slots × max_seq_len — the
@@ -37,11 +37,10 @@ gather view survives only as the non-partitionable-heads / attn="dense"
 fallback.
 
 The device side stays simple on purpose: the engine's jit'd programs
-gather `pool[table]` into the same position-aligned `[B, S, K, D]` view
-the contiguous path uses — forward() and the Pallas kernels are layout-
-agnostic — and scatter the updated view back through the same table. The
-gather/scatter traffic equals the contiguous path's per-slot row
-gather/scatter; the win is RESIDENT memory, not per-step traffic.
+gather `pool[table]` into a position-aligned `[B, S, K, D]` view
+that forward() and the flash kernels read as a plain cache, and scatter
+the updated view back through the same table (the gather view; the
+pool-direct programs of paged_forward.py never build it).
 
 The reference has no counterpart (its KV memory lives inside Ollama's
 llama.cpp, reference src/adapters/local-llm.ts); this is the engine-side
@@ -122,7 +121,7 @@ class PagedSlot:
 
 
 class PagedKVCache:
-    """Page-pool KV cache with the same slot interface as KVCache.
+    """Page-pool KV cache: named slots over refcounted pages.
 
     `copy_pages_fn(pools, src_ids, dst_ids)` is the engine-provided
     program that copies whole pages (`page_copy_path` names which: DMAs
@@ -174,9 +173,9 @@ class PagedKVCache:
         # carries them with the page for free.
         self.kv_quant = kv_quant
         self._kv_dtype_bytes = jnp.dtype(dtype).itemsize
-        # Default pool: HALF the contiguous budget — the honest claim of
-        # paging is serving the same slots in less HBM — plus one scratch
-        # page per data replica (data_size == 1: page 0, as before).
+        # Default pool: HALF of every slot at max_seq_len — the honest
+        # claim of paging is serving the same slots in less HBM — plus
+        # one scratch page per data replica (data_size == 1: page 0).
         # Quantized pools keep the SAME BYTE budget (the bf16 default's
         # bytes), so the freed bytes become MORE PAGES — the
         # 2-4x-resident-sessions payoff. Page demand math everywhere is
@@ -518,7 +517,8 @@ class PagedKVCache:
 
     def revive_if_dead(self) -> bool:
         """Reallocate the page pools if a failed donated dispatch deleted
-        them (KVCache.revive_if_dead's paged counterpart). Every slot,
+        them (jax donate_argnums consumes inputs even when the program
+        faults after transfer). Every slot,
         page mapping and refcount is dropped — the bytes are gone — so
         later prefills start from scratch. Returns True iff revived."""
         if not any(p.is_deleted() for layer in self.pools for p in layer):
@@ -539,7 +539,7 @@ class PagedKVCache:
             self.prefix_cache.clear(unref=False)
         return True
 
-    # --- slot lifecycle (KVCache-compatible surface) ---
+    # --- slot lifecycle ---
 
     def acquire(self, name: str, pinned: tuple[str, ...] = ()) -> PagedSlot:
         if name in self._slots:
@@ -576,7 +576,7 @@ class PagedKVCache:
 
     def flush(self) -> int:
         """Release every per-knight slot (graceful drain's KV flush,
-        fleet.drain — SlotBook.flush's paged counterpart): each slot's
+        fleet.drain): each slot's
         pages decref and free back to their replica ranges, and the
         prefix cache drops its index the same way — every holder UNREFS
         (never force-frees), so a page momentarily shared between a slot
@@ -752,9 +752,12 @@ class PagedKVCache:
 
     def reuse_plan(self, name: str, tokens: list[int],
                    pinned: tuple[str, ...] = ()) -> tuple[int, int]:
-        """(-1, reuse_len) — same shape as KVCache.reuse_plan, but paged
-        rows are keyed by table_for(names), never by a device slot id (the
-        -1 sentinel fails loudly if ever used as an index). Truncates the
+        """(-1, reuse_len): how many leading tokens are already baked
+        into the slot's pages; the caller prefills only
+        tokens[reuse_len:], capped at len(tokens)-1 so at least one
+        token is always fed (the model needs a last-token logit to start
+        decoding). Rows are keyed by table_for(names), never by a device
+        slot id (the -1 fails loudly if ever used as an index). Truncates the
         record now (crash safety) and drops whole pages beyond the reuse
         frontier."""
         state = self.acquire(name, pinned)
@@ -762,9 +765,6 @@ class PagedKVCache:
         reuse = min(reuse, len(tokens) - 1)
         state.tokens = state.tokens[:reuse]
         self._trim_pages(state, reuse)
-        # Paged layout has no device slot id — every program keys rows by
-        # table_for(names). Return a sentinel so a future caller indexing
-        # device arrays with it fails loudly instead of corrupting rows.
         return -1, reuse
 
     def _trim_pages(self, state: PagedSlot, tokens_kept: int) -> None:

@@ -18,8 +18,8 @@ src/adapters/local-llm.ts):
 - paged_decode_attention: the same ragged decode DIRECTLY against the page
   POOL [P, page_size, K, D] (engine/paging.py), so decode never
   materializes the position-aligned [B, S, K, D] gather view — during
-  decode the paged layout keeps its whole resident-memory advantage (the
-  gather view temporarily recreated the full contiguous budget). It is a
+  decode the pool keeps its whole resident-memory advantage (the
+  gather view copies every row's whole max_seq_len span out). It is a
   WALK, not a grid over the table's width: a grid step loops over a
   block of batch rows, and each row loops over the pages it holds
   (table[b, lo..hi] from the scalar-prefetched page table), a few pages
@@ -647,9 +647,9 @@ def flash_attention_spmd(
 
     A plain pallas_call inside a pjit'd program is not SPMD-partitionable;
     this wrapper partitions the problem the way TP shards it anyway — kv
-    heads on "model" (each device already holds its heads' slice of the KV
-    cache, sharding.kv_cache_spec), batch rows on "data" — and runs the
-    kernel per-device on its local heads. Attention is embarrassingly
+    heads on "model" (each device already holds its heads' slice of the
+    page pools, the engine's pool_sharding), batch rows on "data" — and
+    runs the kernel per-device on its local heads. Attention is embarrassingly
     parallel over (batch, kv head), so the body needs NO collectives; the
     o_proj contraction after (sharded over query heads) stays outside and
     gets its all-reduce from XLA as usual.

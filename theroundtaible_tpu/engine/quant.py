@@ -36,7 +36,7 @@ is needed. Absmax over a sharded contracted axis costs one all-reduce at
 load time.
 
 Scope: every serving path — InferenceEngine (dense + flash attention,
-contiguous + paged KV, MoE) and the ring/Ulysses sequence-parallel
+paged KV, MoE) and the ring/Ulysses sequence-parallel
 prefill — all of which reach weights exclusively through the
 quant-aware _einsum/embed_tokens accessors.
 """

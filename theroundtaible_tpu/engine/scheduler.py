@@ -18,10 +18,9 @@ Design, shaped by JAX's static-shape constraints (ISSUE 4 tentpole):
   valid, done, budget) state, so rows can retire and join freely there
   without touching the device programs. The batch pads to a power-of-two
   bucket (capped at max_rows) with MASKED pad rows — done from step 0,
-  zero budget, writes landing on a throwaway slot / the paged scratch
-  page — so the compiled decode shapes are {1, 2, 4, ..., max_rows}
-  and a retire/join that moves occupancy within a bucket compiles
-  nothing mid-serve.
+  zero budget, writes landing on the scratch page — so the compiled
+  decode shapes are {1, 2, 4, ..., max_rows} and a retire/join that
+  moves occupancy within a bucket compiles nothing mid-serve.
 - **Join = chunked prefill into freed capacity.** A queued turn admits at
   a segment boundary: its rows run the same reuse_plan → share_prefixes
   (intra-session cross-knight reuse) → chunked/ring prefill path as
@@ -34,7 +33,7 @@ Design, shaped by JAX's static-shape constraints (ISSUE 4 tentpole):
   for next-round prefix reuse. No whole-batch barrier: one session's
   long monologue never holds another session's finished rows hostage.
 - **Admission queue with capacity-aware backpressure.** A request whose
-  rows cannot fit the SlotBook right now (or whose pages cannot fit the
+  rows cannot fit the batch right now (or whose pages cannot fit the
   PagedKVCache pool next to the pinned live rows) stays queued until
   retirement frees capacity; a request that could NEVER fit this engine
   is refused outright (SchedulerRefused) instead of deadlocking the
@@ -93,6 +92,23 @@ _EVENT_LOG_CAP = 64
 LOOP_PHASES = ("wait", "health", "admit", "admit_sync", "build",
                "dispatch", "sync", "accept", "flush", "retire")
 _LOOP_WITHIN = {"admit": {"sync": "admit_sync", "dispatch": "admit"}}
+
+# The slots of the loop's own frame (PERF.md, Findings PR 46). CPython
+# 3.11+ keeps a thread's interpreter frames in 16 KiB chunks and frees a
+# chunk the moment its first frame returns, so a loop whose calls cross
+# a chunk's end pays one mmap and one munmap A CALL. Tracing and lowering
+# are such loops, hundreds of frames deep, and the loop's thread runs
+# them at every new shape: 19 s of Laguna's 40 s warm-up on the chip's
+# machine, and 22 s more once an unrelated edit made the frames under
+# them ten slots smaller. A frame this large opens one 512 KiB chunk that
+# lives as long as the thread and holds every frame above it.
+_LOOP_FRAME_SLOTS = 40_000
+
+
+def _roomy_frame(fn):
+    fn.__code__ = fn.__code__.replace(co_stacksize=_LOOP_FRAME_SLOTS)
+    return fn
+
 
 # Test-visibility counter (tests/conftest.py `scheduler` marker guard):
 # the maximum number of live rows any scheduler dispatched in one decode
@@ -184,7 +200,6 @@ class _Row:
     tokens: list[int]            # truncated prompt ids (committed base)
     sampling: SamplingParams
     max_new: int                 # per-row token cap (<= request cap)
-    slot_id: int = -1            # contiguous layouts only (paged: -1)
     produced: list[int] = field(default_factory=list)  # [first, ...]
     last: int = 0
     valid: int = 0
@@ -536,21 +551,20 @@ class SessionScheduler:
                     f"most {store.max_adapters} — raise "
                     "lora.max_adapters", reason="adapters_never_fit")
             store.validate(adapters_per_turn, len(turns))
-        if engine.kv_layout == "paged":
-            # Never-fits = LOWER bound (1-token prompts): a request
-            # generate_batch could serve must never be refused here.
-            need = self._pages_needed(turns, max_new, minimal=True)
-            if need > engine.kv.usable_pages():
-                with self._cv:
-                    self._bump("refused")
-                self._event("refuse", session=session,
-                            reason=f"{need} pages > pool "
-                                   f"{engine.kv.usable_pages()}")
-                raise SchedulerRefused(
-                    f"session {session!r} needs at least {need} KV pages "
-                    f"but the pool holds {engine.kv.usable_pages()} — "
-                    "raise num_pages or lower max_new_tokens",
-                    reason="pages_never_fit")
+        # Never-fits = LOWER bound (1-token prompts): a request
+        # generate_batch could serve must never be refused here.
+        need = self._pages_needed(turns, max_new, minimal=True)
+        if need > engine.kv.usable_pages():
+            with self._cv:
+                self._bump("refused")
+            self._event("refuse", session=session,
+                        reason=f"{need} pages > pool "
+                               f"{engine.kv.usable_pages()}")
+            raise SchedulerRefused(
+                f"session {session!r} needs at least {need} KV pages "
+                f"but the pool holds {engine.kv.usable_pages()} — "
+                "raise num_pages or lower max_new_tokens",
+                reason="pages_never_fit")
         req = _Request(session, list(turns), sampling_per_turn, max_new,
                        timeout_s, budget, self._fresh_stats(),
                        adapters=adapters_per_turn)
@@ -913,6 +927,7 @@ class SessionScheduler:
     # the scheduler loop
     # ------------------------------------------------------------------
 
+    @_roomy_frame
     def _loop(self) -> None:
         clock = self._clock
         telemetry.bind_loop_clock(clock)
@@ -1203,7 +1218,7 @@ class SessionScheduler:
             # slot is referenced by live rows — retirement frees refs,
             # then the LRU evicts and this request's personas load.
             return False
-        if engine.kv_layout == "paged" and self._active:
+        if self._active:
             # Pages the live rows have pinned are untouchable; the rest
             # of the pool (free or held by idle evictable slots) is what
             # a join can claim.
@@ -1328,8 +1343,7 @@ class SessionScheduler:
         cheap. The admission itself then proceeds instead of queueing
         behind capacity that idle sessions were hoarding."""
         engine = self.engine
-        if (getattr(engine, "kv_offload", None) is None
-                or engine.kv_layout != "paged"):
+        if getattr(engine, "kv_offload", None) is None:
             return
         # NEW-page demand, not the whole-prompt estimate: in steady
         # state a session's next turn is mostly its own committed
@@ -1450,7 +1464,7 @@ class SessionScheduler:
                         pinned=tuple(prep["names"]) + active_names)
                 rows.append(_Row(
                     name=scoped, tokens=toks, sampling=per_row[i],
-                    max_new=row_cap, slot_id=prep["slot_ids"][i],
+                    max_new=row_cap,
                     pending=list(toks[off:]), pos=off, valid=off,
                     adapter_slot=(row_slots[i] if row_slots else 0)))
             else:
@@ -1458,7 +1472,7 @@ class SessionScheduler:
                 rows.append(_Row(
                     name=scoped, tokens=toks,
                     sampling=per_row[i], max_new=row_cap,
-                    slot_id=prep["slot_ids"][i], produced=[tok],
+                    produced=[tok],
                     last=tok, valid=len(toks),
                     done=(tok == eos),
                     adapter_slot=(row_slots[i] if row_slots else 0)))
@@ -1642,12 +1656,8 @@ class SessionScheduler:
         positional arguments only, so nothing is built for it."""
         if not telemetry.ACTIVE:
             return telemetry.NULL_SPAN
-        if kind != "plain":
-            label = f"ragged[t={size}]"
-        elif self.engine.kv_layout == "paged":
-            label = f"decode[b={size},paged]"
-        else:
-            label = f"decode[b={size}]"
+        label = (f"decode[b={size},paged]" if kind == "plain"
+                 else f"ragged[t={size}]")
         seg = telemetry.start_span(
             "segment", engine=self._tname, rows=rows, scheduled=True,
             kind=kind, label=label, tick=self._clock.tick)
@@ -1737,8 +1747,7 @@ class SessionScheduler:
                 page_visits_by_eights=ragged["page_visits_by_eights"])
         if window_reads is not None:
             seg.attrs.update(window_reads)
-        if self.engine.kv_layout == "paged":
-            seg.attrs["pages_in_use"] = self.engine.kv.pages_in_use()
+        seg.attrs["pages_in_use"] = self.engine.kv.pages_in_use()
         if hy is not None:
             # What the expert layers touched since the last segment
             # span ended (a prologue's prefill in between counts with
@@ -2670,10 +2679,9 @@ class SessionScheduler:
         """Device arrays for one DECODE_SEGMENT over `rows`.
 
         The batch pads to _row_bucket with MASKED pad rows (done from
-        step 0, zero budget): contiguous pads point at a throwaway slot
-        (SlotBook.scratch_slot — identical bytes from every pad row, so
-        the duplicate-index scatter is deterministic), paged pads point
-        their whole table at the scratch page. Under data>1 pool-direct
+        step 0, zero budget) whose whole table points at the scratch
+        page (identical bytes from every pad row, so the duplicate-index
+        scatter is deterministic). Under data>1 pool-direct
         the ReplicaGroupPlan already dictates the padded shape, so
         bucketing is skipped there."""
         engine = self.engine
@@ -2699,41 +2707,27 @@ class SessionScheduler:
         greedy = all(t <= 0.0 for t in temps_l)
 
         plan = None
-        tables = None
-        slot_idx = None
         pad = 0
-        if engine.kv_layout == "paged":
-            tables_np = engine.kv.table_for(names)
-            if engine.paged_direct and engine._paged_replicas > 1:
-                # bucket_group: the plan's padded shape must stay on the
-                # {R*1, R*2, R*4, ...} grid as occupancy drifts, or
-                # every retire/join would compile a fresh decode program
-                # mid-serve on exactly the multi-replica engines where
-                # that stall hurts most.
-                plan = ReplicaGroupPlan(
-                    [engine.kv.replica_of(n) for n in names],
-                    engine._paged_replicas, bucket_group=True)
-                tables_np = plan.pad_table(tables_np,
-                                           engine.kv.scratch_page)
-            else:
-                pad = self._row_bucket(len(rows)) - len(rows)
-                if pad:
-                    scratch = np.full(
-                        (pad, tables_np.shape[1]),
-                        engine.kv.scratch_page(0), tables_np.dtype)
-                    tables_np = np.concatenate([tables_np, scratch])
-            tables = jnp.asarray(tables_np)
+        tables_np = engine.kv.table_for(names)
+        if engine.paged_direct and engine._paged_replicas > 1:
+            # bucket_group: the plan's padded shape must stay on the
+            # {R*1, R*2, R*4, ...} grid as occupancy drifts, or
+            # every retire/join would compile a fresh decode program
+            # mid-serve on exactly the multi-replica engines where
+            # that stall hurts most.
+            plan = ReplicaGroupPlan(
+                [engine.kv.replica_of(n) for n in names],
+                engine._paged_replicas, bucket_group=True)
+            tables_np = plan.pad_table(tables_np,
+                                       engine.kv.scratch_page)
         else:
-            slots = [r.slot_id for r in rows]
             pad = self._row_bucket(len(rows)) - len(rows)
             if pad:
-                pad_slot = engine.kv.scratch_slot(
-                    pinned=tuple(r.name for r in self._active))
-                if pad_slot is None:
-                    pad = 0  # every slot pinned: exact-size dispatch
-                else:
-                    slots = slots + [pad_slot] * pad
-            slot_idx = jnp.asarray(slots, jnp.int32)
+                scratch = np.full(
+                    (pad, tables_np.shape[1]),
+                    engine.kv.scratch_page(0), tables_np.dtype)
+                tables_np = np.concatenate([tables_np, scratch])
+        tables = jnp.asarray(tables_np)
         if pad:
             last = np.concatenate([last, np.full(pad, eos, np.int32)])
             valid = np.concatenate([valid, np.ones(pad, np.int32)])
@@ -2771,7 +2765,7 @@ class SessionScheduler:
             budgets_d = jnp.asarray(budgets)
         return {
             "rows": rows, "reqs": reqs, "plan": plan, "tables": tables,
-            "slot_idx": slot_idx, "last_d": last_d, "valid_d": valid_d,
+            "last_d": last_d, "valid_d": valid_d,
             "done_d": done_d, "budgets_d": budgets_d, "temps": temps,
             "top_ks": top_ks, "top_ps": top_ps, "greedy": greedy,
             "seg_budget": seg_budget, "deadline": deadline,
@@ -2781,7 +2775,7 @@ class SessionScheduler:
 
     def _dispatch(self, ctx: dict):
         """Dispatch one segment for `ctx` through the engine's shared
-        decode seams (_decode_dispatch_paged/_slots — same degrade rung
+        decode seam (_decode_dispatch_paged — same degrade rung
         + commit_guard as generate_batch) and the run_dispatch
         retry/watchdog seam. Returns DEVICE handles; the host read
         happens in _read_segment, possibly after the next segment is
@@ -2789,20 +2783,13 @@ class SessionScheduler:
         engine = self.engine
 
         def dispatch():
-            if ctx["tables"] is not None:
-                return engine._decode_dispatch_paged(
-                    ctx["tables"], ctx["last_d"], ctx["valid_d"],
-                    engine._next_key(), jnp.int32(DECODE_SEGMENT),
-                    ctx["temps"], ctx["top_ks"], ctx["top_ps"],
-                    ctx["budgets_d"], ctx["done_d"],
-                    greedy=ctx["greedy"], lora=ctx["lora"],
-                    names=ctx["names"])
-            return engine._decode_dispatch_slots(
-                ctx["slot_idx"], ctx["last_d"], ctx["valid_d"],
+            return engine._decode_dispatch_paged(
+                ctx["tables"], ctx["last_d"], ctx["valid_d"],
                 engine._next_key(), jnp.int32(DECODE_SEGMENT),
                 ctx["temps"], ctx["top_ks"], ctx["top_ps"],
-                ctx["budgets_d"], ctx["done_d"], greedy=ctx["greedy"],
-                lora=ctx["lora"])
+                ctx["budgets_d"], ctx["done_d"],
+                greedy=ctx["greedy"], lora=ctx["lora"],
+                names=ctx["names"])
 
         handles = run_dispatch(dispatch, engine.retry, ctx["deadline"],
                                budget=ctx["seg_budget"])

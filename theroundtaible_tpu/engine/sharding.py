@@ -229,11 +229,6 @@ def lora_stack_specs(tp: Optional[str]) -> tuple[P, P]:
     return a_spec, b_spec
 
 
-def kv_cache_spec() -> P:
-    """KV cache [B, S, K, D]: slots on data axis, kv heads on model axis."""
-    return P(DATA_AXIS, None, MODEL_AXIS, None)
-
-
 def shardable(cfg: ModelConfig, mesh: Mesh) -> bool:
     """True when every TP/EP dimension divides by the model-axis size."""
     m = mesh.shape[MODEL_AXIS]

@@ -86,51 +86,46 @@ def publish_memory_ledger(engine) -> dict[str, Any]:
     Event-rate cheap: host dict math over slot bookkeeping only."""
     reg = telemetry.REGISTRY
     name = engine.cfg.name
-    ledger: dict[str, Any] = {}
-    led_fn = getattr(engine.kv, "memory_ledger", None)
-    if led_fn is not None:
-        ledger = led_fn()
-        reg.set_gauge("roundtable_kv_slots_in_use",
-                      ledger["slots_in_use"], engine=name)
-        reg.set_gauge("roundtable_kv_slot_occupancy",
-                      ledger["slot_occupancy"], engine=name)
-        reg.set_gauge("roundtable_kv_cached_tokens",
-                      ledger["cached_tokens"], engine=name)
-        if ledger.get("layout") == "paged":
-            reg.set_gauge("roundtable_kv_pages_in_use",
-                          ledger["pages_in_use"], engine=name)
-            reg.set_gauge("roundtable_kv_pages_total",
-                          ledger["usable_pages"], engine=name)
-            reg.set_gauge("roundtable_kv_page_utilization",
-                          ledger["page_utilization"], engine=name)
-            reg.set_gauge("roundtable_kv_fragmentation",
-                          ledger["fragmentation"], engine=name)
-            # ISSUE 7: the cross-session sharing split — shared pages
-            # counted ONCE in pages_in_use; this makes the dedup
-            # visible (and auditable) on a dashboard.
-            reg.set_gauge("roundtable_kv_shared_pages",
-                          ledger.get("shared_pages", 0), engine=name)
-            reg.set_gauge("roundtable_kv_exclusive_pages",
-                          ledger.get("exclusive_pages", 0), engine=name)
-            reg.set_gauge("roundtable_prefix_cache_pages",
-                          ledger.get("prefix_cache_pages", 0),
-                          engine=name)
-            # ISSUE 11: the quantized-page split — resident (payload +
-            # scales, what the pools actually cost) vs logical (the
-            # same pools at bf16 cells); bits=0 marks a bf16 pool so a
-            # dashboard can tell "quantization off" from "no data".
-            reg.set_gauge("roundtable_kv_quant_bits",
-                          ledger.get("kv_quant_bits", 0), engine=name)
-            reg.set_gauge("roundtable_kv_bytes_logical",
-                          ledger.get("kv_bytes_logical",
-                                     ledger.get("hbm_bytes", 0)),
-                          engine=name)
-            reg.set_gauge("roundtable_kv_quant_bytes_saved",
-                          ledger.get("kv_quant_bytes_saved", 0),
-                          engine=name)
-        if ledger.get("hbm_bytes") is not None:
-            reg.set_gauge("roundtable_kv_hbm_bytes",
-                          ledger["hbm_bytes"], engine=name)
+    ledger: dict[str, Any] = engine.kv.memory_ledger()
+    reg.set_gauge("roundtable_kv_slots_in_use",
+                  ledger["slots_in_use"], engine=name)
+    reg.set_gauge("roundtable_kv_slot_occupancy",
+                  ledger["slot_occupancy"], engine=name)
+    reg.set_gauge("roundtable_kv_cached_tokens",
+                  ledger["cached_tokens"], engine=name)
+    reg.set_gauge("roundtable_kv_pages_in_use",
+                  ledger["pages_in_use"], engine=name)
+    reg.set_gauge("roundtable_kv_pages_total",
+                  ledger["usable_pages"], engine=name)
+    reg.set_gauge("roundtable_kv_page_utilization",
+                  ledger["page_utilization"], engine=name)
+    reg.set_gauge("roundtable_kv_fragmentation",
+                  ledger["fragmentation"], engine=name)
+    # ISSUE 7: the cross-session sharing split — shared pages
+    # counted ONCE in pages_in_use; this makes the dedup
+    # visible (and auditable) on a dashboard.
+    reg.set_gauge("roundtable_kv_shared_pages",
+                  ledger.get("shared_pages", 0), engine=name)
+    reg.set_gauge("roundtable_kv_exclusive_pages",
+                  ledger.get("exclusive_pages", 0), engine=name)
+    reg.set_gauge("roundtable_prefix_cache_pages",
+                  ledger.get("prefix_cache_pages", 0),
+                  engine=name)
+    # ISSUE 11: the quantized-page split — resident (payload +
+    # scales, what the pools actually cost) vs logical (the
+    # same pools at bf16 cells); bits=0 marks a bf16 pool so a
+    # dashboard can tell "quantization off" from "no data".
+    reg.set_gauge("roundtable_kv_quant_bits",
+                  ledger.get("kv_quant_bits", 0), engine=name)
+    reg.set_gauge("roundtable_kv_bytes_logical",
+                  ledger.get("kv_bytes_logical",
+                             ledger.get("hbm_bytes", 0)),
+                  engine=name)
+    reg.set_gauge("roundtable_kv_quant_bytes_saved",
+                  ledger.get("kv_quant_bytes_saved", 0),
+                  engine=name)
+    reg.set_gauge("roundtable_kv_hbm_bytes", ledger["hbm_bytes"],
+                  engine=name)
     # ISSUE 10: the multi-LoRA adapter store's HBM footprint rides
     # the same ledger publish — resident personas and what each costs,
     # next to the KV split they multiply scenario coverage against.
@@ -172,7 +167,6 @@ def publish_memory_ledger(engine) -> dict[str, Any]:
             cfg_dict: dict[str, Any] = {
                 "max_seq_len": engine.max_seq_len,
                 "num_slots": engine.kv.num_slots,
-                "kv_layout": getattr(engine, "kv_layout", "contiguous"),
             }
             if getattr(engine, "quant", "none") != "none":
                 cfg_dict["quant"] = engine.quant

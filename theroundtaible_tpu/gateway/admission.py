@@ -189,9 +189,9 @@ class SchedulerSignals:
 
     def kv_pressure(self, headroom: float) -> bool:
         engine = self.sched.engine
-        if getattr(engine, "kv_layout", None) != "paged":
+        kv = getattr(engine, "kv", None)
+        if kv is None:
             return False
-        kv = engine.kv
         floor = int(kv.usable_pages() * headroom)
         return (kv.free_pages() <= floor
                 and getattr(engine, "kv_offload", None) is None)

@@ -221,8 +221,8 @@ class SessionRouter:
         score += self.queue_weight * (d["admission"]["queued"]
                                       + d["active_rows"])
         engine = rep.engine
-        if getattr(engine, "kv_layout", None) == "paged":
-            kv = engine.kv
+        kv = getattr(engine, "kv", None)
+        if kv is not None:
             usable = max(kv.usable_pages(), 1)
             score += self.page_weight * (1.0 - kv.free_pages() / usable)
         store = getattr(engine, "lora", None)

@@ -70,19 +70,16 @@ class FleetSignals:
         live = self._live()
         if not live:
             return False
-        pressured = 0
-        paged = 0
         for r in live:
             engine = r.engine
-            if getattr(engine, "kv_layout", None) != "paged":
-                return False   # a contiguous replica never pressures
-            paged += 1
-            kv = engine.kv
+            kv = getattr(engine, "kv", None)
+            if kv is None:
+                return False   # a replica without a pool never pressures
             floor = int(kv.usable_pages() * headroom)
-            if (kv.free_pages() <= floor
-                    and getattr(engine, "kv_offload", None) is None):
-                pressured += 1
-        return paged > 0 and pressured == paged
+            if (kv.free_pages() > floor
+                    or getattr(engine, "kv_offload", None) is not None):
+                return False
+        return True
 
     def adapters_busy(self, adapters) -> bool:
         live = self._live()
