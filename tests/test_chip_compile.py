@@ -1106,6 +1106,137 @@ def test_hybrid_step_of_the_brumby_cut_compiles(one_chip, monkeypatch,
         f"beside a state of {layer_state / 1e6:.0f} MB a layer")
 
 
+# --- Jamba2-3B: the scan kernel, one kv head at group 20, the whole model --
+
+JAMBA_ROWS, JAMBA_SNAPS = 17, 198    # 16 slots / 197 snapshots + scratch
+JAMBA_POOL = (640, PAGE, 1, D)       # the cell's pool: ONE kv head
+
+
+@pytest.mark.parametrize("tokens,block", [(128, 8), (1024, 8), (17, 1)])
+def test_mamba1_scan_kernel_compiles_for_v5e(one_chip, tokens, block):
+    """The selective scan at the published widths (5120 channels as 40
+    lane rows, 16 state indices) over a page's chunk, a 1 024-token
+    join buffer and a decode step's 17 slots (one token a block), on
+    the 13-layer run's state, donated: the program keeps no second copy
+    of the state beside what it aliases."""
+    from theroundtaible_tpu.engine.pallas import mamba1 as m1
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    f32, i32 = jnp.float32, jnp.int32
+    g, w = m1.fold(5120)
+    assert (g, w) == (40, 128)
+    nb = tokens // block
+    state = (JAMBA_ROWS, 13, 16, g, w)
+    compiled = jax.jit(
+        functools.partial(m1.mamba1_scan, block=block, n_seqs=JAMBA_ROWS,
+                          interpret=False), donate_argnums=(4,)).lower(
+        s((tokens, g, w), f32), s((tokens, g, w), f32),
+        s((tokens, 32), f32), s((16, g, w), f32), s(state, f32),
+        s((), i32), s((nb,), i32), s((nb,), i32), s((nb,), i32)).compile()
+    hlo = compiled.as_text()
+    _assert_kernel(hlo)
+    assert ("mamba1_scan" if block > 1 else "mamba1_step") in hlo
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(state)) * 4
+    assert mem.temp_size_in_bytes < int(np.prod(state)) * 4 // 10
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "ragged",
+                                    "paged_prefill"])
+def test_one_kv_head_at_group_20_compiles_for_v5e(one_chip, kernel):
+    """The three paged kernels at Jamba's attention geometry over the
+    cell's pool [640, 128, 1, 128]: `_token_major(1, 2)` is false, so
+    they take the head-major form, which no cell had compiled."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32, h = jnp.int32, 20
+    pool = s(JAMBA_POOL, jnp.bfloat16)
+    assert not pattn._token_major(1, 2)
+    if kernel == "paged_decode":
+        fn, shapes = pattn.paged_decode_attention, (
+            s((DECODE_ROWS, 1, h, D), jnp.bfloat16), pool, pool,
+            s((DECODE_ROWS, PAGES_PER_SEQ), i32), s((DECODE_ROWS,), i32))
+    elif kernel == "ragged":
+        blocks = 1024 // pattn.RAGGED_BLOCK_Q
+        fn, shapes = pattn.ragged_paged_attention, (
+            s((1024, h, D), jnp.bfloat16), pool, pool,
+            s((JAMBA_ROWS, PAGES_PER_SEQ), i32), s((blocks,), i32),
+            s((blocks,), i32), s((JAMBA_ROWS,), i32),
+            s((JAMBA_ROWS,), i32))
+    else:
+        fn, shapes = pattn.paged_prefill_attention, (
+            s((4, CHUNK, h, D), jnp.bfloat16), pool, pool,
+            s((4, PAGES_PER_SEQ), i32), s((4,), i32), s((4,), i32))
+    hlo = _compile(functools.partial(fn, interpret=False), *shapes)
+    _assert_kernel(hlo)
+
+
+def test_whole_jamba_decode_step_compiles_with_its_runs_scanned(
+        one_chip, monkeypatch):
+    """Four decode steps in a loop of the WHOLE model at published
+    widths — 28 published layers, 3.03 G parameters — pools, slot states
+    donated. Its Mamba-1 blocks run as THREE `lax.scan`s over stacked
+    parameters (runs of 7, 13 and 6): the program holds four loops (the
+    steps' and one a run) whatever the depth, aliases pools and states,
+    and keeps no copy of a run's state beside them."""
+    import re
+
+    from theroundtaible_tpu.engine.models import hybrid
+    from theroundtaible_tpu.engine.models.common import init_params
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+    from theroundtaible_tpu.engine.pallas import mamba1 as m1
+    from theroundtaible_tpu.engine.paged_forward import forward_paged_hybrid
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(m1, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_model_config("jamba2-3b"),
+                              attn_impl="flash")
+    assert cfg.scan_runs == (7, 13, 6)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    params = placed(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    state = placed(jax.eval_shape(
+        lambda: hybrid.zero_state(cfg, JAMBA_ROWS)))
+    pools = [(s(JAMBA_POOL, jnp.bfloat16), s(JAMBA_POOL, jnp.bfloat16))
+             for _ in range(2)]
+
+    def step(params, pools, state, tokens, positions, table, valid, active,
+             rows):
+        def body(i, carry):
+            pl, st, tok = carry
+            logits, pl, st, _c, _n = forward_paged_hybrid(
+                params, cfg, tok, positions + i, pl, table, valid + i, st,
+                active=active, page_size=PAGE, rows=rows)
+            return pl, st, jnp.argmax(logits[:, 0], -1)[:, None].astype(i32)
+        return jax.lax.fori_loop(0, 4, body, (pools, state, tokens))
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pools, state, s((DECODE_ROWS, 1), i32),
+        s((DECODE_ROWS, 1), i32), s((DECODE_ROWS, PAGES_PER_SEQ), i32),
+        s((DECODE_ROWS,), i32), s((DECODE_ROWS,), jnp.bool_),
+        s((DECODE_ROWS,), i32)).compile()
+    hlo = compiled.as_text()
+    _assert_kernel(hlo)
+    assert "mamba1_step" in hlo
+    assert len(re.findall(r" while\(", hlo)) == 4
+    mem = compiled.memory_analysis()
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves((state, pools)))
+    assert mem.alias_size_in_bytes >= held
+    run_state = JAMBA_ROWS * 13 * 16 * 5120 * 4
+    assert mem.temp_size_in_bytes < 2 * run_state, (
+        f"{mem.temp_size_in_bytes / 1e6:.0f} MB of temporaries beside a "
+        f"run's state of {run_state / 1e6:.0f} MB")
+    assert mem.argument_size_in_bytes > 6.0e9       # the model, whole
+
+
 # --- the int4 kernels the compiler refuses --------------------------------
 #
 # Shapes below come from a real Int4Leaf (quant.quantize_params on a

@@ -218,6 +218,17 @@ class InferenceEngine:
                                              model_cfg.kv_repeat)
                 if reason is not None:
                     self.declines["retention_step"] = reason
+            if model_cfg.mamba1_layers:
+                # The selective scan of a join: one Pallas call a layer,
+                # or the jax.numpy recurrence where that declines — one
+                # rule, pallas/mamba1.py.
+                from .pallas import mamba1 as pm1
+                reason = pm1.decline_reason(model_cfg.mamba1_dim,
+                                            model_cfg.ssm_state)
+                if reason is not None:
+                    self.declines["mamba1_scan"] = reason
+            if model_cfg.retention_layers or model_cfg.mamba1_layers:
+                # (a state that lives whole on the slot arrays)
                 self.declines["leader_state_handover"] = (
                     f"{why}:a-state-is-copied-whole-not-shared")
                 self.declines["evacuation"] = (
@@ -888,7 +899,8 @@ class InferenceEngine:
             self.ragged_path = ("pallas_ragged" if decline is None
                                 else "xla_ragged")
             self.ragged_fallback_reason = decline
-            if model_cfg.retention_layers and decline is None:
+            if (model_cfg.retention_layers or model_cfg.mamba1_layers) \
+                    and decline is None:
                 # State on the slot arrays: a prologue's [B, T]
                 # program is the ragged program's chunked runs again
                 # (the same kernel a page of a run), at a shape a
@@ -1876,6 +1888,8 @@ class InferenceEngine:
             hy.commit_state(state)
             hy.commit_snaps(snaps)
         hy.note_counts(counts, pipelined=False)
+        hy.note_scan(sum(int(takes[i]) for i in range(b)
+                         if rows_np[i] != hy.scratch_row))
         return last, pools
 
     def _hybrid_decode(self, tables, names, last, valid, key, budget,
@@ -1949,6 +1963,7 @@ class InferenceEngine:
             hy.commit_state(state)
             hy.commit_snaps(snaps)
         hy.note_counts(counts, pipelined=False)
+        hy.note_scan(int((ends - starts)[:len(names)].sum()))
         return nxt, pools
 
     def _ragged_dispatch(self, batch: dict):
@@ -3330,6 +3345,20 @@ class InferenceEngine:
                 "bytes_per_state": retention.bytes_per_state(self.cfg),
                 "kernel": ("jnp" if "retention_step" in self.declines
                            else "retention_step"),
+            }
+        if self.cfg.mamba1_layers:
+            from .models import mamba1
+            n, g, w, _k1 = mamba1.dims(self.cfg)
+            info["mamba1"] = {
+                "layers": len(self.cfg.mamba1_layers),
+                "d_inner": self.cfg.mamba1_dim, "d_state": n,
+                "bytes_per_state": mamba1.bytes_per_state(self.cfg),
+                "state_layout": f"[rows, run_layers, {n}, {g}, {w}] "
+                                "float32 a run",
+                "kernel": ("jnp" if "mamba1_scan" in self.declines
+                           else "mamba1_scan"),
+                "scan_runs": list(self.cfg.scan_runs),
+                "scan_tokens": self.hybrid.scan_tokens,
             }
         if self.cfg.attn_layers is not None:
             info["attention"] = self.attention_describe()

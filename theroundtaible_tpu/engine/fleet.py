@@ -47,6 +47,14 @@ def estimate_param_count(cfg: ModelConfig) -> int:
             # q, k, v, o, one gate a kv head, the two head norms
             "retention": 2 * e * h * d + 2 * e * k * d + e * k + 2 * d,
         }
+        if cfg.mamba1_layers:
+            # in, conv and its bias, x, the three small norms, dt and
+            # its bias, A_log, D, out (models/mamba1.py)
+            d1, n, r = cfg.mamba1_dim, cfg.ssm_state, cfg.dt_rank
+            per_kind["mamba1"] = (
+                2 * e * d1 + (cfg.conv_kernel + 1) * d1
+                + d1 * (r + 2 * n) + r + 2 * n + (r + 1) * d1
+                + n * d1 + d1 + d1 * e)
         if cfg.latent:
             r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
             per_kind["attention"] = (
@@ -55,7 +63,8 @@ def estimate_param_count(cfg: ModelConfig) -> int:
                 + r_kv * h * (cfg.qk_nope_dim + cfg.v_head_dim)
                 + h * cfg.v_head_dim * e)
         total = (sum(per_kind[kind] + e for kind in cfg.layer_kinds)
-                 + 2 * cfg.vocab_size * e + e)
+                 + (1 if cfg.tie_embeddings else 2) * cfg.vocab_size * e
+                 + e)
         if cfg.attn_layers is not None or cfg.attn_gate:
             # Each attention layer's own heads (q and out-projection
             # above were counted at the model-level `h`), and its gate.

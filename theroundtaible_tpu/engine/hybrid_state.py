@@ -5,13 +5,16 @@ sequence, a state that is not pages — whatever parts `hybrid.zero_state`
 gives its layers: per Mamba-2 layer the SSM state `[H, P, N]` float32
 and the last K-1 inputs of the causal conv; per retention layer
 (models/retention.py) the feature-map state and its normaliser, 34 MB a
-layer a sequence at published widths. Pages
+layer a sequence at published widths; per scanned RUN of Mamba-1 layers
+(models/mamba1.py) ONE leaf a part, `[rows, run_layers, ...]`, rows
+ahead of layers so that a slot's whole state is one index here. Pages
 can be truncated to any prefix and shared by reference; a state is valid
 at exactly ONE position — the number of tokens it has consumed — and can
 only be copied whole. This module owns both halves of that, and knows
 the parts only as small ones a program gathers by row
-(`hybrid.ROW_PARTS`) and large ones updated in place on the slot array
-(`hybrid.SLOT_PARTS`):
+(`hybrid.ROW_PARTS`) and ones updated in place on the slot array
+(`hybrid.SLOT_PARTS`), whatever leaves the tree has under each — a leaf
+a layer or a leaf a run of layers, the row is the first axis of all:
 
 - **Slot states** — one row a knight slot (`state[part][l][row]`; the
   last row is scratch, where pad rows of a
@@ -120,6 +123,9 @@ class HybridStateStore:
         self.rescanned_tokens = 0
         self.share_declined = 0
         self.copy_bytes = {"restore": 0, "capture": 0}
+        # Tokens x Mamba-1 layers the join programs scanned (pads left
+        # out; a decode loop's steps advance states without the scan).
+        self.scan_tokens = 0
 
         @partial(jax.jit, donate_argnums=(0,))
         def restore(state, snaps, dst_rows, src_snaps, zero, n):
@@ -316,6 +322,15 @@ class HybridStateStore:
         self.copy_bytes[cause] += n
         telemetry.inc("roundtable_state_copy_bytes_total", n,
                       engine=self.engine, cause=cause)
+
+    def note_scan(self, tokens: int) -> None:
+        """A join dispatch (a prologue chunk, a ragged step) fed its
+        runs `tokens` tokens: what the model's Mamba-1 layers scanned."""
+        n = tokens * len(self.cfg.mamba1_layers)
+        if n:
+            self.scan_tokens += n
+            telemetry.inc("roundtable_mamba1_scan_tokens_total", n,
+                          engine=self.engine)
 
     def on_commit(self, name: str, tokens: list[int], exact: bool) -> None:
         """The slot committed `tokens`. `exact`: its state has consumed

@@ -150,10 +150,11 @@ class ModelConfig:
     # stream KV through VMEM and skip blocks beyond each row's valid length
     attn_impl: str = "dense"
     # Hybrid decoders (models/hybrid.py): one kind a layer, each layer ONE
-    # mixer behind one norm and a residual — "mamba2" | "experts" |
-    # "attention" | "mlp". None = the attention-plus-MLP block above,
-    # untouched. A pre-norm block whose two halves have a norm each IS two
-    # such layers (attention, then mlp or experts): `axk1`.
+    # mixer behind one norm and a residual — "mamba2" | "mamba1" |
+    # "experts" | "attention" | "mlp" | "retention". None = the
+    # attention-plus-MLP block above, untouched. A pre-norm block whose two
+    # halves have a norm each IS two such layers (attention, then mlp or
+    # experts): `axk1`.
     layer_kinds: Optional[tuple[str, ...]] = None
     rope: bool = True                 # False: no position embedding at all
     # Mamba-2 mixer
@@ -163,6 +164,12 @@ class ModelConfig:
     ssm_groups: int = 1
     conv_kernel: int = 4
     mamba_chunk: int = 128
+    # Mamba-1 mixer ("mamba1", models/mamba1.py): a decay for every
+    # (state index, channel) pair. Its inner width and the rank of the
+    # projection its step size comes through; `ssm_state` and
+    # `conv_kernel` above are shared with Mamba-2.
+    mamba1_dim: int = 0
+    dt_rank: int = 0
     # Routed + shared experts, as ONE chip's share of an expert-parallel
     # group: the router scores all `routed_experts`; this chip computes
     # ids [expert_offset, expert_offset + experts_held).
@@ -248,7 +255,8 @@ class ModelConfig:
     def recurrent(self) -> bool:
         """Some layer keeps state that is not pages."""
         return bool(self._layers_of("mamba2")
-                    or self._layers_of("retention"))
+                    or self._layers_of("retention")
+                    or self._layers_of("mamba1"))
 
     @property
     def retention_layers(self) -> tuple[int, ...]:
@@ -257,6 +265,26 @@ class ModelConfig:
     @property
     def mamba_layers(self) -> tuple[int, ...]:
         return self._layers_of("mamba2")
+
+    @property
+    def mamba1_layers(self) -> tuple[int, ...]:
+        return self._layers_of("mamba1")
+
+    @property
+    def layer_runs(self) -> tuple[tuple[tuple[str, ...], int], ...]:
+        """`layer_kinds` as runs: (the kinds of one block, how many such
+        blocks follow one another). A run of Mamba-1 blocks — the mixer
+        and the MLP behind it, where one follows — is ONE `lax.scan`
+        over parameters and state stacked along a leading layer axis
+        (engine/paged_forward.py); every other layer is a run of one
+        and is traced where it stands."""
+        return _layer_runs(self.layer_kinds or ())
+
+    @property
+    def scan_runs(self) -> tuple[int, ...]:
+        """The lengths of the scanned runs, in order."""
+        return tuple(n for kinds, n in self.layer_runs
+                     if kinds[0] == "mamba1")
 
     @property
     def expert_layers(self) -> tuple[int, ...]:
@@ -297,6 +325,24 @@ class ModelConfig:
     @property
     def mamba_conv_dim(self) -> int:
         return self.mamba_d_inner + 2 * self.ssm_groups * self.ssm_state
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_runs(kinds: tuple[str, ...]) -> tuple:
+    runs, i = [], 0
+    while i < len(kinds):
+        if kinds[i] != "mamba1":
+            runs.append(((kinds[i],), 1))
+            i += 1
+            continue
+        block = kinds[i:i + 2] if kinds[i + 1:i + 2] == ("mlp",) \
+            else kinds[i:i + 1]
+        n = 1
+        while kinds[i + n * len(block):i + (n + 1) * len(block)] == block:
+            n += 1
+        runs.append((block, n))
+        i += n * len(block)
+    return tuple(runs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -851,7 +897,7 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
     x = embed_tokens(params["embedding"], tokens)
     zero = hybrid.zero_state(cfg, b)
     caches = []
-    for kind, layer in zip(cfg.layer_kinds, params["layers"]):
+    for kind, layer in hybrid.layers_unrolled(cfg, params):
         if kind == hybrid.ATTENTION:
             lcfg = cfg.attention_layer(len(caches))
             mask = make_attention_mask(positions, t, kv_valid_len,
@@ -865,6 +911,11 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
             out = hybrid.retention.retention_prefill(
                 h, layer, cfg, positions, zero["ret"][0], zero["retn"][0],
                 jnp.arange(b), kv_valid_len, hybrid.RETENTION_CHUNK)[0]
+        elif kind == hybrid.MAMBA1:
+            # (a zero state of one layer: the run's first, at l = 0)
+            out = hybrid.mamba1.mamba1_prefill(
+                h, layer, cfg, zero["ssm1"][0][:, :1],
+                zero["conv1"][0][:, :1], 0, jnp.arange(b), kv_valid_len)[0]
         elif kind == hybrid.EXPERTS:
             out, _ = hybrid.experts_mlp(h, layer, cfg)
         elif kind == hybrid.MLP:
@@ -882,7 +933,8 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
     if last_pos is not None:
         x = gather_rows(x, last_pos)
-    logits = _einsum("bte,ve->btv", x, params["lm_head"], tp="col")
+    head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
+    logits = _einsum("bte,ve->btv", x, head, tp="col")
     return logits, caches
 
 
@@ -914,19 +966,41 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             # The channel a bias-free gate reads its level from
             # (hybrid.init_layer): the same for every token.
             embedding = embedding.at[:, hybrid.GATE_CHANNEL].set(1.0)
-        return {
-            "embedding": embedding,
-            "layers": [hybrid.init_layer(
+
+        def one(i, kind, lk):
+            return hybrid.init_layer(
                 cfg.attention_layer(cfg.attention_layers.index(i))
                 if kind == hybrid.ATTENTION else cfg, kind, lk, dtype,
                 depth=cfg.layer_kinds[:i].count(hybrid.RETENTION))
-                for i, (kind, lk) in enumerate(zip(cfg.layer_kinds,
-                                                   keys))],
-            "final_norm": jnp.ones((cfg.embed_dim,), dtype),
-            "lm_head": (jax.random.normal(
+
+        layers, i = [], 0
+        for kinds, n in cfg.layer_runs:
+            if kinds[0] != hybrid.MAMBA1:
+                layers.append(one(i, kinds[0], keys[i]))
+                i += 1
+                continue
+            # A scanned run is BORN stacked: one leaf a parameter with
+            # a leading layer axis, never a stack of per-layer leaves.
+            width = len(kinds)
+            run_keys = keys[i:i + n * width].reshape(n, width, -1)
+            layers.append(jax.vmap(lambda ks, i=i, kinds=kinds: {
+                kind: one(i, kind, ks[j])
+                for j, kind in enumerate(kinds)})(run_keys))
+            i += n * width
+        params = {"embedding": embedding, "layers": layers,
+                  "final_norm": jnp.ones((cfg.embed_dim,), dtype)}
+        if cfg.tie_embeddings:
+            # The head IS the embedding: at the initialiser's range, so
+            # that a token's own row does not decide its own logit
+            # (hybrid.TIED_EMBED_STD).
+            params["embedding"] = (
+                embedding.astype(jnp.float32)
+                * hybrid.TIED_EMBED_STD).astype(dtype)
+        else:
+            params["lm_head"] = (jax.random.normal(
                 k_head, (cfg.vocab_size, cfg.embed_dim), jnp.float32)
-                * scale).astype(dtype),
-        }
+                * scale).astype(dtype)
+        return params
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, dtype=jnp.float32)

@@ -9,6 +9,13 @@ attention + experts): one wiring for both families. A power-retention
 layer (`retention`, models/retention.py: a feature-map state and no keys
 or values) is a fourth kind of mixer behind the same norm; a model whose
 mixers are all of that kind has no attention layer and so no page pool.
+A Mamba-1 layer (`mamba1`, models/mamba1.py: a decay for every (state
+index, channel) pair, so a scan kernel and no product form) is a fifth.
+`layer_kinds` keeps one entry a layer; the layers are stored and run as
+`ModelConfig.layer_runs` derives them: consecutive (mamba1, mlp) blocks
+are ONE entry of `params["layers"]` whose leaves carry a leading layer
+axis and one `lax.scan` in every program, every other layer an entry and
+a trace of its own (`layers_unrolled` gives either a layer at a time).
 
 Mamba-2 (state-space duality form). Per head, with state S in
 R^{P x N} kept in float32:
@@ -60,15 +67,16 @@ import jax
 import jax.numpy as jnp
 
 from ..pallas import grouped
-from . import retention
+from . import mamba1, retention
 from .common import ModelConfig, Params, _einsum, rms_norm
 
 MAMBA2, EXPERTS, ATTENTION, MLP = "mamba2", "experts", "attention", "mlp"
-RETENTION = "retention"
+RETENTION, MAMBA1 = "retention", mamba1.KIND
 # State parts gathered to the batch's rows and scattered back by a step
 # program (small), and parts a layer updates in place on the whole slot
-# array (models/retention.py: 34 MB a row a layer).
-ROW_PARTS, SLOT_PARTS = ("ssm", "conv"), ("ret", "retn")
+# array (models/retention.py: 34 MB a row a layer; models/mamba1.py: one
+# leaf a scanned run, [rows, layers, ...]).
+ROW_PARTS, SLOT_PARTS = ("ssm", "conv"), ("ret", "retn") + mamba1.PARTS
 # The chunk of a retention layer where no page size says it (the
 # whole-sequence forward): the serving paths chunk by the page.
 RETENTION_CHUNK = 128
@@ -575,6 +583,15 @@ RESIDUAL_SHARE = 0.07
 # 97 % of its mean square is theirs), whose mean square then grows by
 # about RETENTION_GROWTH a published layer.
 RETENTION_SHARE, RETENTION_GROWTH = 3.0, 4.5
+# A model whose head IS its embedding (`tie_embeddings` beside
+# `layer_kinds`): at unit rms a token's own row would decide its own
+# logit (|e|^2 = E against sigma sqrt(E) for every other token) and the
+# served token would be the last one read, whatever the layers hold. The
+# table stands at the family's `initializer_range` instead, and every
+# out-projection of a model with Mamba-1 layers at MAMBA1_SHARE of unit
+# scale, so that the mixers carry the stream from the first one on and
+# it stays of order one through the published 56 (about 1 / sqrt(56)).
+TIED_EMBED_STD, MAMBA1_SHARE = 0.02, 0.134
 # The seeded gate of a retention layer (init_layer): the embedding
 # channel held at 1.0 (no out-projection writes to it), W_g's row there
 # over the kv heads, and the scale of its other rows (of unit scale).
@@ -597,6 +614,8 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
                 * (share * fan_in ** -0.5)).astype(dtype)
 
     def out(key, shape, fan_in):
+        if cfg.mamba1_layers:
+            return dense(key, shape, fan_in, MAMBA1_SHARE)
         if not cfg.retention_layers:
             return dense(key, shape, fan_in, RESIDUAL_SHARE)
         return dense(key, shape, fan_in, RETENTION_SHARE).at[
@@ -623,6 +642,8 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
             "gate_norm": jnp.ones((d_in,), dtype),
             "out_proj": dense(ks[4], (d_in, e), d_in, RESIDUAL_SHARE),
         })
+    elif kind == MAMBA1:
+        layer.update(mamba1.init_mixer(cfg, ks, dense, out, dtype))
     elif kind == EXPERTS:
         f, fs, held = cfg.expert_dim, cfg.shared_expert_dim, \
             cfg.experts_held
@@ -692,7 +713,7 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
             "q_proj": dense(ks[0], (e, h_, d), e),
             "k_proj": dense(ks[1], (e, k_, d), e),
             "v_proj": dense(ks[2], (e, k_, d), e),
-            "o_proj": dense(ks[3], (h_, d, e), h_ * d, RESIDUAL_SHARE),
+            "o_proj": out(ks[3], (h_, d, e), h_ * d),
         })
         if cfg.attn_gate:
             layer["g_proj"] = dense(ks[4], (e, h_), e)
@@ -703,7 +724,9 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
 
 def zero_state(cfg: ModelConfig, rows: int) -> dict:
     """The recurrent state of `rows` sequences, float32, one entry a
-    layer that keeps one. Mamba-2: {"ssm": [[rows,H,P,N]...], "conv":
+    layer that keeps one (Mamba-1: one a scanned RUN, models/mamba1.py:
+    {"ssm1": [[rows,L,N,G,W]...], "conv1": [[rows,L,K-1,G,W]...]}).
+    Mamba-2: {"ssm": [[rows,H,P,N]...], "conv":
     [[rows,K-1,C]...]}; retention (models/retention.py), where the
     model has such layers: {"ret": [[rows,K,D/2+1,D,D]...], "retn":
     [[rows,K,D/2+1,D]...]}."""
@@ -717,6 +740,7 @@ def zero_state(cfg: ModelConfig, rows: int) -> dict:
         # (a part only where some layer keeps it)
         **(retention.zero_state(cfg, rows) if cfg.retention_layers
            else {}),
+        **(mamba1.zero_state(cfg, rows) if cfg.mamba1_layers else {}),
     }
 
 
@@ -724,4 +748,19 @@ def state_bytes_per_sequence(cfg: ModelConfig) -> int:
     per = (cfg.mamba_heads * cfg.mamba_head_dim * cfg.ssm_state
            + (cfg.conv_kernel - 1) * cfg.mamba_conv_dim) * 4
     return per * len(cfg.mamba_layers) \
-        + retention.bytes_per_state(cfg) * len(cfg.retention_layers)
+        + retention.bytes_per_state(cfg) * len(cfg.retention_layers) \
+        + mamba1.bytes_per_state(cfg) * len(cfg.mamba1_layers)
+
+
+def layers_unrolled(cfg: ModelConfig, params: Params):
+    """(kind, layer) of every layer in order, a scanned run's stacked
+    leaves indexed by layer: for the whole-sequence forward and whoever
+    reads the tree a layer at a time (a reference, a test)."""
+    for (kinds, n), entry in zip(cfg.layer_runs, params["layers"]):
+        if kinds[0] != MAMBA1:
+            yield kinds[0], entry
+            continue
+        for j in range(n):
+            for kind in kinds:
+                yield kind, jax.tree_util.tree_map(lambda a, j=j: a[j],
+                                                   entry[kind])

@@ -7,7 +7,9 @@ Qwen2.5 (attention bias), Nemotron-3-Nano (hybrid: Mamba-2, routed and
 shared experts, attention — models/hybrid.py), A.X-K1 (latent attention
 — models/mla.py — beside a dense MLP or gated routed and shared
 experts), Brumby (power retention and no attention layer —
-models/retention.py) and tiny test presets.
+models/retention.py), Jamba (Mamba-1 layers in scanned runs beside
+attention layers of one kv head — models/mamba1.py) and tiny test
+presets.
 Architecture behavior lives in ModelConfig fields (common.py).
 
 `resolve_model_config(adapter_config)` is the one way an engine gets its
@@ -24,7 +26,8 @@ import dataclasses
 from typing import Any
 
 from .common import AttnLayer, ModelConfig
-from .hybrid import ATTENTION, EXPERTS, MLP, RETENTION, kinds_of_pattern
+from .hybrid import (ATTENTION, EXPERTS, MAMBA1, MLP, RETENTION,
+                     kinds_of_pattern)
 
 _REGISTRY: dict[str, ModelConfig] = {}
 
@@ -321,6 +324,39 @@ TINY_BRUMBY = register(_brumby_config(
     "tiny-brumby", blocks=3, vocab_size=512, embed_dim=64, num_heads=6,
     num_kv_heads=2, head_dim=16, mlp_dim=128, max_seq_len=512,
     rope_theta=1_000_000.0, norm_eps=1e-6))
+
+
+# --- Jamba / jamba (a published layer is TWO layers here: a Mamba-1
+# mixer — models/mamba1.py — or, where i % period == offset, attention
+# over the model's kv heads WITHOUT position embedding, then a SwiGLU
+# MLP; tied head. Consecutive (mamba1, mlp) blocks are one scanned run:
+# ModelConfig.layer_runs) ---
+
+def jamba_kinds(blocks: int, period: int, offset: int) -> tuple[str, ...]:
+    return tuple(k for i in range(blocks)
+                 for k in (ATTENTION if i % period == offset else MAMBA1,
+                           MLP))
+
+
+def _jamba_config(name, *, blocks, period, offset, **kw):
+    return ModelConfig(
+        name=name, num_layers=2 * blocks, tie_embeddings=True, rope=False,
+        layer_kinds=jamba_kinds(blocks, period, offset), **kw)
+
+
+JAMBA2_3B = register(_jamba_config(
+    "jamba2-3b", blocks=28, period=14, offset=7, vocab_size=65_536,
+    embed_dim=2560, num_heads=20, num_kv_heads=1, head_dim=128,
+    mlp_dim=8192, max_seq_len=8192, norm_eps=1e-6, mamba1_dim=5120,
+    ssm_state=16, conv_kernel=4, dt_rank=160))
+
+# One period: attention at 7 of 14, so runs of 7 and 6 blocks; group 4
+# over one kv head.
+TINY_JAMBA = register(_jamba_config(
+    "tiny-jamba", blocks=14, period=14, offset=7, vocab_size=512,
+    embed_dim=64, num_heads=4, num_kv_heads=1, head_dim=16, mlp_dim=128,
+    max_seq_len=512, norm_eps=1e-6, mamba1_dim=128, ssm_state=8,
+    conv_kernel=4, dt_rank=4))
 
 
 # --- from a published config.json -------------------------------------------
@@ -684,6 +720,54 @@ def _brumby(name: str, arch: dict[str, Any],
     return cfg
 
 
+# Keys of a jamba config.json that say nothing this engine acts on (with
+# num_experts 1 the expert_layer_* keys select nothing and the router's
+# are unused), and the values its layer equations assume. `rope`,
+# `rope_theta` and `head_dim` are not the model's keys: a configuration
+# file may state them beside the published ones (`rope: true` is the
+# other reading of "no rotary key", one key).
+_JAMBA_INERT = {
+    "model_type", "max_position_embeddings", "num_logits_to_keep",
+    "use_mamba_kernels", "expert_layer_offset", "expert_layer_period",
+    "num_experts_per_tok", "rope_theta"}
+_JAMBA_FIXED = {
+    "hidden_act": "silu", "mamba_conv_bias": True, "mamba_proj_bias": False,
+    "sliding_window": None, "tie_word_embeddings": True, "num_experts": 1,
+    "rope": False}
+
+
+def _jamba(name: str, arch: dict[str, Any],
+           max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "jamba", _JAMBA_FIXED, _JAMBA_INERT)
+    try:
+        e, heads = int(arch.pop("hidden_size")), \
+            int(arch.pop("num_attention_heads"))
+        cfg = _jamba_config(
+            name, blocks=int(arch.pop("num_hidden_layers")),
+            period=int(arch.pop("attn_layer_period")),
+            offset=int(arch.pop("attn_layer_offset")),
+            vocab_size=int(arch.pop("vocab_size")), embed_dim=e,
+            num_heads=heads,
+            num_kv_heads=int(arch.pop("num_key_value_heads")),
+            head_dim=int(arch.pop("head_dim", e // heads)),
+            mlp_dim=int(arch.pop("intermediate_size")),
+            max_seq_len=max_seq_len,
+            norm_eps=float(arch.pop("rms_norm_eps")),
+            mamba1_dim=int(arch.pop("mamba_expand")) * e,
+            ssm_state=int(arch.pop("mamba_d_state")),
+            conv_kernel=int(arch.pop("mamba_d_conv")),
+            dt_rank=int(arch.pop("mamba_dt_rank")))
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    _all_read(name, arch, "jamba")
+    if cfg.num_heads % cfg.num_kv_heads:
+        raise ValueError(
+            f"architecture of {name!r}: {cfg.num_heads} query heads are "
+            f"not whole groups over {cfg.num_kv_heads} kv heads")
+    return cfg
+
+
 def _dense_gqa(name: str, arch: dict[str, Any],
                max_seq_len: int) -> ModelConfig:
     heads = int(arch["num_attention_heads"])
@@ -727,6 +811,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
         return _mellum(name, arch, max_seq_len)
     if kind == "brumby":
         return _brumby(name, arch, max_seq_len)
+    if kind == "jamba":
+        return _jamba(name, arch, max_seq_len)
     if kind in _DENSE_TYPES:
         try:
             return _dense_gqa(name, arch, max_seq_len)
@@ -735,7 +821,7 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
                              f"{e.args[0]!r}") from None
     raise ValueError(
         f"architecture of {name!r}: model_type {kind!r} is not one this "
-        f"engine runs (nemotron_h, axk1, laguna, mellum, brumby, "
+        f"engine runs (nemotron_h, axk1, laguna, mellum, brumby, jamba, "
         f"{', '.join(_DENSE_TYPES)})")
 
 

@@ -391,17 +391,52 @@ def forward_ragged(
 
 
 # --- hybrid decoders: one mixer a layer, state beside the pools -------------
+#
+# The loops below walk `ModelConfig.layer_runs`, not `layer_kinds`: a run
+# of Mamba-1 blocks is one entry of `params["layers"]` (leaves stacked
+# along a layer axis) and ONE `lax.scan` (`_scan_run`), so a program
+# holds one body a run whatever the depth; every other layer is a run of
+# one and is traced where it stands, as before.
 
 
 def _hybrid_head(params, cfg, x):
-    logits = _einsum("bte,ve->btv", x, params["lm_head"], tp="col")
+    head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
+    logits = _einsum("bte,ve->btv", x, head, tp="col")
     return _softcap(logits, cfg.final_logit_softcap)
 
 
-def _state_like(state: dict, ssm, conv, ret, retn) -> dict:
-    """The layers' new states under the parts `state` came with."""
-    parts = {"ssm": ssm, "conv": conv, "ret": ret, "retn": retn}
-    return {p: parts[p] for p in state}
+def _state_lists(state: dict) -> dict:
+    """Every part the layers may advance, as a list they assign into
+    (a part the model's layers do not keep: empty)."""
+    return {p: list(state.get(p, ()))
+            for p in ("ssm", "conv", "ret", "retn", "ssm1", "conv1")}
+
+
+def _scan_run(x, run, kinds, cfg: ModelConfig, ssm, conv, held, mixer):
+    """One run of Mamba-1 blocks (`ModelConfig.layer_runs`) as ONE
+    `lax.scan` over its stacked parameters: whatever the run's length
+    the program holds one body — a mixer and, where the block has one,
+    the MLP behind it. `ssm` / `conv` (every slot's state of the run,
+    [rows, L, ...]) and `held` (the store's two arrays of the run, or
+    None) ride in the carry and are updated in place at layer `l`;
+    `mixer(h, layer, ssm, conv, l, held) -> (out, ssm, conv, held)`."""
+    from .models import hybrid
+
+    def block(carry, xs):
+        x, ssm, conv, held = carry
+        l, layers = xs
+        layer = layers[hybrid.MAMBA1]
+        out, ssm, conv, held = mixer(
+            hybrid.layer_norm_in(x, layer, cfg), layer, ssm, conv, l, held)
+        x = x + out
+        for kind in kinds[1:]:                  # (the MLP behind it)
+            x = x + mlp(hybrid.layer_norm_in(x, layers[kind], cfg),
+                        layers[kind], cfg)
+        return (x, ssm, conv, held), None
+
+    (x, ssm, conv, held), _ = jax.lax.scan(
+        block, (x, ssm, conv, held), (jnp.arange(ssm.shape[1]), run))
+    return x, ssm, conv, held
 
 
 def _attention_io(h, layer, cfg: ModelConfig, positions, dtype):
@@ -451,10 +486,12 @@ def forward_paged_hybrid(
     layers scatter into their own pools and attend through the same
     page-table kernels; Mamba-2 layers advance the rows' recurrent
     `state` (batch-row order: the program gathers and scatters the
-    slot rows); retention layers (models/retention.py) advance theirs
-    IN PLACE on every slot's array (`state["ret"]`, `["retn"]`: whole,
-    addressed by `rows`) and write a capture straight into `snaps` at
-    `snap_idx`; expert layers count what they touched.
+    slot rows); retention layers (models/retention.py) and the scanned
+    runs of Mamba-1 layers (models/mamba1.py) advance theirs IN PLACE on
+    every slot's array (`state["ret"]`, `["retn"]`; `["ssm1"]`,
+    `["conv1"]`, a leaf a run: whole, addressed by `rows`) and write a
+    capture straight into `snaps` at `snap_idx`; expert layers count
+    what they touched.
 
     -> (logits, new_pools, new_state, captured, counts): `captured` is
     the state after `cap_len` tokens (prefill with `cap_len`; for the
@@ -463,7 +500,7 @@ def forward_paged_hybrid(
     assignments to held experts over the counted tokens, rows the
     grouped products multiplied and rows a loop over every held expert
     would have, expert-layer steps."""
-    from .models import hybrid, retention
+    from .models import hybrid, mamba1, retention
     if pools:
         page_size = pools[0][0].shape[1]
     b, t = tokens.shape
@@ -475,13 +512,34 @@ def forward_paged_hybrid(
         counted = active[:, None]
     else:
         counted = jnp.arange(t)[None, :] < lengths[:, None]
-    ssm, conv = list(state["ssm"]), list(state["conv"])
-    ret, retn = list(state.get("ret", ())), list(state.get("retn", ()))
+    st = _state_lists(state)
+    ssm, conv, ret, retn = st["ssm"], st["conv"], st["ret"], st["retn"]
     cap = {p: [] for p in state} if cap_len is not None else None
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = ri = 0
-    for kind, layer in zip(cfg.layer_kinds, params["layers"]):
+    ai = mi = ri = si = 0
+    for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
+        kind = kinds[0]
+        if kind == hybrid.MAMBA1:
+            if decode:
+                def mixer(h, layer, s, c, l, held):
+                    return mamba1.mamba1_step(h, layer, cfg, s, c, l, rows,
+                                              active) + (held,)
+            else:
+                def mixer(h, layer, s, c, l, held):
+                    return mamba1.mamba1_prefill(
+                        h, layer, cfg, s, c, l, rows, lengths, held,
+                        cap_len, snap_idx)
+            held = None if cap is None else (snaps["ssm1"][si],
+                                             snaps["conv1"][si])
+            x, st["ssm1"][si], st["conv1"][si], held = _scan_run(
+                x, layer, kinds, cfg, st["ssm1"][si], st["conv1"][si],
+                held, mixer)
+            if cap is not None:
+                cap["ssm1"].append(held[0])
+                cap["conv1"].append(held[1])
+            si += 1
+            continue
         h = hybrid.layer_norm_in(x, layer, cfg)
         if kind == hybrid.RETENTION:
             if decode:
@@ -549,7 +607,7 @@ def forward_paged_hybrid(
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
     if last_pos is not None:
         x = gather_rows(x, last_pos)
-    new = _state_like(state, ssm, conv, ret, retn)
+    new = {p: st[p] for p in state}
     return _hybrid_head(params, cfg, x), new_pools, new, cap, counts
 
 
@@ -570,10 +628,12 @@ def forward_ragged_hybrid(
     array (each block reads its sequence's state and writes it back),
     retention layers every run a page's chunk at a time, also straight
     on the slot array and with a capture written into `snaps` at
-    `snap_idx`; attention layers the ragged page-table kernel. ->
+    `snap_idx`, a scanned run of Mamba-1 layers one selective-scan
+    kernel a layer over the whole buffer, likewise in place; attention
+    layers the ragged page-table kernel. ->
     (logits [S, V], new_pools, new_state, captured {"ssm": [[S,...]..],
     "conv": .., "ret" / "retn": the store's arrays}, counts)."""
-    from .models import hybrid, retention
+    from .models import hybrid, mamba1, retention
     from .serving_loop import RAGGED_BLOCK_Q
     if pools:
         page_size = pools[0][0].shape[1]
@@ -584,13 +644,25 @@ def forward_ragged_hybrid(
                             last_rows, seq_of_block, block_qstart,
                             seq_slot, cap_n, RAGGED_BLOCK_Q)
     counted = (rg["token_valid"] & (token_seq != s_max - 1))[None]
-    ssm, conv = list(state["ssm"]), list(state["conv"])
-    ret, retn = list(state.get("ret", ())), list(state.get("retn", ()))
+    st = _state_lists(state)
+    ssm, conv, ret, retn = st["ssm"], st["conv"], st["ret"], st["retn"]
     cap = {p: [] for p in state}
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = ri = 0
-    for kind, layer in zip(cfg.layer_kinds, params["layers"]):
+    ai = mi = ri = si = 0
+    for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
+        kind = kinds[0]
+        if kind == hybrid.MAMBA1:
+            def mixer(h, layer, s, c, l, held):
+                return mamba1.mamba1_ragged(h, layer, cfg, s, c, l, rg,
+                                            held, snap_idx)
+            x, st["ssm1"][si], st["conv1"][si], held = _scan_run(
+                x, layer, kinds, cfg, st["ssm1"][si], st["conv1"][si],
+                (snaps["ssm1"][si], snaps["conv1"][si]), mixer)
+            cap["ssm1"].append(held[0])
+            cap["conv1"].append(held[1])
+            si += 1
+            continue
         h = hybrid.layer_norm_in(x, layer, cfg)
         if kind == hybrid.RETENTION:
             out, ret[ri], retn[ri], held = retention.retention_ragged(
@@ -634,7 +706,7 @@ def forward_ragged_hybrid(
         x = x + out
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
     logits = _hybrid_head(params, cfg, x[0, last_rows][None])
-    new = _state_like(state, ssm, conv, ret, retn)
+    new = {p: st[p] for p in state}
     return logits[0], new_pools, new, cap, counts
 
 

@@ -1204,6 +1204,21 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
                          "pages given back for the index's; "
                          "describe-only)",
     },
+    # engine.describe()["mamba1"] (ISSUE 47): the Mamba-1 layers of a
+    # model that has them (models/mamba1.py), their scanned runs, and
+    # what the join programs scanned (HybridStateStore.note_scan is the
+    # one writer; a `segment` span carries `scan_tokens` for the
+    # programs it covers).
+    "engine_mamba1": {
+        "layers": "static (Mamba-1 layers)",
+        "d_inner": "static (model sizes)",
+        "d_state": "static (model sizes)",
+        "bytes_per_state": "static (a layer's state and conv tail)",
+        "state_layout": "static (the state leaf of a scanned run)",
+        "kernel": "static (mamba1_scan, or jnp where it declines)",
+        "scan_runs": "static (lengths of the scanned runs)",
+        "scan_tokens": "roundtable_mamba1_scan_tokens_total",
+    },
     # engine.describe()["moe"] (ISSUE 27): the chip's share of the
     # routed experts and what the steps touched (each step program
     # returns its counts; HybridStateStore.fold_counts adds them to
