@@ -271,7 +271,12 @@ def test_three_knights_three_rounds_with_joins_mid_decode(scheduled):
     # Round 2 and 3: the first knight continues its own state, the
     # others start from a snapshot of the shared transcript.
     assert info["continued_tokens"] > 0 and info["reused_tokens"] > 0
-    assert info["share_declined"] >= 1  # the leader pass, declined
+    # The leader pass: s1's opening passes MIN_SHARED_PREFIX (no later
+    # round's new span does, at these answers): its two laggards were
+    # handed the leader's state if the round joined s0's live rows, and
+    # scanned for themselves if it ran a prologue
+    # (tests/test_state_handover.py holds it to two a round).
+    assert info["share_handed"] + info["share_declined"] == 2
     admits = [s["attrs"] for s in spans if s["rung"] == "admit"]
     assert admits and all(
         {"state_from", "kv_matched_tokens", "state_reused_tokens",
@@ -409,7 +414,10 @@ def test_no_compile_in_steady_state_across_occupancy_drift(monkeypatch):
                    for i in range(2)]
         for t in threads:
             t.start()
-            time.sleep(0.2)
+            # (a round's joins take the ragged program whatever the
+            # batch holds and compile nothing: a discussion alone is
+            # over in a fifth of a second here)
+            time.sleep(0.02)
         for t in threads:
             t.join()
         assert not errors, errors

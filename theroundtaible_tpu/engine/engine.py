@@ -229,8 +229,6 @@ class InferenceEngine:
                     self.declines["mamba1_scan"] = reason
             if model_cfg.retention_layers or model_cfg.mamba1_layers:
                 # (a state that lives whole on the slot arrays)
-                self.declines["leader_state_handover"] = (
-                    f"{why}:a-state-is-copied-whole-not-shared")
                 self.declines["evacuation"] = (
                     f"{why}:no-host-copy-of-a-state")
         self.max_seq_len = model_cfg.max_seq_len
@@ -914,6 +912,17 @@ class InferenceEngine:
                 # stands behind.
                 self.joins_ragged_alone = True
                 self.ragged_defer_min = 0
+            elif model_cfg.recurrent and decline is None:
+                # State beside pages (Mamba-2): the prologue stays for
+                # a join that brings little, but only a deferred
+                # admission can hand a leader's state to its laggards
+                # (kvcache.share_prefixes) — a round admitted into an
+                # empty batch by the prologue scans its shared span
+                # three times. So a join may take the ragged program
+                # whatever the batch holds; whether it does is decided
+                # after the state plan (_plan_batch: a recorded share,
+                # or ragged_defer_min tokens left to scan).
+                self.joins_ragged_alone = True
             if decline is not None and model_cfg.attn_layers:
                 # one of the layers' geometries does not fit
                 self.declines["ragged_kernel"] = decline
@@ -1882,7 +1891,7 @@ class InferenceEngine:
                 jnp.asarray(cap_len), jnp.asarray(snap_idx))
         except Exception:
             for key in keys:
-                hy.drop(key)
+                hy.drop(key, unwritten=True)
             raise
         with deadlines.commit_guard():
             hy.commit_state(state)
@@ -1957,7 +1966,7 @@ class InferenceEngine:
                 attn_path="kernel" if path == "pallas_ragged" else "xla")
         except Exception:
             for key in keys:
-                hy.drop(key)
+                hy.drop(key, unwritten=True)
             raise
         with deadlines.commit_guard():
             hy.commit_state(state)
@@ -2541,8 +2550,8 @@ class InferenceEngine:
                 offs = p.scatter_list(offs, 0)
                 if l_ids is not None:
                     l_ids = p.scatter_list(l_ids, 0)
-            # (no state row: a model with recurrent state declines the
-            # leader pass)
+            # (no state row: a model with recurrent state takes the
+            # leader pass deferred or not at all)
             self._prefill([-1], toks, offs, table, deadline,
                           budget=budget, lora_ids=l_ids)
 
@@ -2558,14 +2567,19 @@ class InferenceEngine:
             def donor_ok(donor, i):
                 return labels.get(donor.name) == row_adapters[i]
 
+        decline_leader = None
+        if self.cfg.recurrent:
+            def decline_leader(n_laggards):
+                self.hybrid.note_declined(
+                    n_laggards, "prologue" if defer_span is None
+                    else "no-state-to-hand")
+
         return share_prefixes(
             self.kv, names, all_tokens, offsets,
             min_shared=MIN_SHARED_PREFIX, add_share=add_share,
             prefill_span=prefill_span,
             extra_pinned=extra_pinned, defer_span=defer_span,
-            donor_ok=donor_ok,
-            decline_leader=(self._decline_leader_share
-                            if self.cfg.recurrent else None))
+            donor_ok=donor_ok, decline_leader=decline_leader)
 
     def note_latent_positions(self, n: int) -> None:
         """Positions of latent pages the rows of a segment read (the
@@ -2624,39 +2638,94 @@ class InferenceEngine:
             "latent_positions": self._latent_positions,
         }
 
-    def _decline_leader_share(self, n_laggards: int) -> None:
-        self.hybrid.share_declined += n_laggards
-        from ..utils import telemetry
-        telemetry.inc("roundtable_state_share_declined_total", n_laggards,
-                      engine=self.cfg.name, reason="recurrent-state")
-
-    def _plan_states(self, names, all_tokens, offsets) -> dict:
+    def _plan_states(self, names, all_tokens, offsets,
+                     blocked=frozenset()) -> dict:
         """The joint reuse plan of one admission (hybrid_state.plan): each
         row starts where BOTH its pages and a state stand. Lowers
         `offsets` in place to that position, drops the slot's pages
         beyond it (the attention layers re-write theirs from there),
-        and copies the states in. -> what the admit span reports."""
+        and copies the states in. The rows in `blocked` wait for a
+        leader (a deferred share): theirs is planned when they unblock
+        (`join_laggard`), behind what the leader leaves.
+        -> what the admit span reports."""
         hy = self.hybrid
         plans = []
         out = {"continue": 0, "snapshot": 0, "zero": 0,
                "kv_matched_tokens": 0, "state_reused_tokens": 0,
                "prompt_tokens": sum(len(t) for t in all_tokens)}
         for i, name in enumerate(names):
+            if i in blocked:
+                continue
             start, source, snap = hy.plan(name, all_tokens[i], offsets[i])
             out[source] += 1
             out["kv_matched_tokens"] += offsets[i]
             out["state_reused_tokens"] += start
             if start < offsets[i]:
-                slot = self.kv.acquire(name)
-                slot.tokens = slot.tokens[:start]
-                self.kv._trim_pages(slot, start)
+                self._rewind_slot(name, start)
                 offsets[i] = start
             plans.append((name, source, snap))
         before = hy.copy_bytes["restore"]
-        hy.attach(plans, self._live_slots())
+        live = self._live_slots()
+        hy.attach(plans, live)
         out["state_copy_bytes"] = hy.copy_bytes["restore"] - before
-        out["rows"] = [hy.row_of(n) for n in names]
+        out["rows"] = [hy.row_of(n, live) for n in names]
         return out
+
+    def _rewind_slot(self, name: str, start: int) -> None:
+        """The slot's state stands at `start`, under its pages' end:
+        drop what lies beyond (the attention layers re-write theirs as
+        the re-scan passes)."""
+        slot = self.kv.acquire(name)
+        slot.tokens = slot.tokens[:start]
+        self.kv._trim_pages(slot, start)
+
+    def join_laggard(self, leader: str, name: str, tokens: list[int],
+                     lo: int, hi: int, upto: int, pinned: tuple,
+                     hand: Optional[tuple[bytes, int]] = None) -> dict:
+        """A deferred share falls due for one laggard: the leader has
+        written the common span [.., hi) and row `name`, whose own pages
+        stand at `lo`, unblocks. Its state is planned NOW — what the
+        leader's run left at the hand-over boundary (`hand`:
+        hybrid_state.hand_over's key and boundary) is ahead of this in
+        program order, and `plan` finds it by its key like any other
+        snapshot — the leader's pages alias in up to where the row
+        starts (whole pages: a state stands at a boundary), its tail to
+        `upto` is allocated, and the state is copied in; then the row
+        no longer waits for that snapshot. A model without recurrent
+        state starts at `hi`. -> `start`, the pages `aliased` and
+        `copies` queued, the restore's `state_copy_bytes`, and whether
+        the leader's state was `handed` (False: the row scans from the
+        deepest state it found, counted as a decline)."""
+        hy = self.hybrid
+        start = hi
+        if hy is not None:
+            start, source, snap = hy.plan(name, tokens, hi)
+        aliased = copies = 0
+        if start > lo:
+            aliased, copies = self.kv.alias_span(leader, name, lo, start,
+                                                 pinned)
+        else:
+            self._rewind_slot(name, start)
+        # Tail capacity (deferred from admission so the span pages
+        # arrive SHARED, not as transient exclusive allocations the
+        # alias would replace).
+        self.kv.ensure_capacity(name, upto, write_from=start,
+                                pinned=pinned)
+        copied, handed = 0, False
+        if hy is not None:
+            before = hy.copy_bytes["restore"]
+            hy.attach([(name, source, snap)], self._live_slots())
+            copied = hy.copy_bytes["restore"] - before
+        if hand is not None:
+            key, at = hand
+            hy.unpin(key)
+            handed = start == at
+            if handed:
+                hy.note_handed()
+            else:
+                hy.note_declined(1, "state-not-left")
+        return {"start": start, "aliased": aliased, "copies": copies,
+                "state_copy_bytes": copied, "handed": handed}
 
     def _prepare_batch(self, turns, max_new_padded, deadline, pre_budget,
                        sampling_per_turn=None,
@@ -2839,8 +2908,15 @@ class InferenceEngine:
         defer_span = None
         if defer_prefill:
             def defer_span(m, lo, hi, followers):  # noqa: F811
+                hand = None
+                if self.cfg.recurrent:
+                    # The laggards need the leader's STATE: deferred
+                    # only if the store can keep it for them.
+                    hand = self.hybrid.hand_over(all_tokens[m], lo, hi)
+                    if hand is None:
+                        return False
                 share_plan.append({"leader": m, "lo": lo, "hi": hi,
-                                   "followers": followers})
+                                   "followers": followers, "hand": hand})
         if lora_slots is not None and len(set(lora_slots)) > 1:
             # Mixed-adapter batch: no donor/leader span is valid
             # across rows with different adapters, so the share passes
@@ -2855,15 +2931,21 @@ class InferenceEngine:
                 row_lora_slots=lora_slots)
         state_plan = None
         state_rows = [-1] * len(names)
+        deferred_followers = {i for p in share_plan
+                              for i, _lo in p["followers"]}
         if self.hybrid is not None:
-            state_plan = self._plan_states(names, all_tokens, offsets)
+            state_plan = self._plan_states(names, all_tokens, offsets,
+                                           deferred_followers)
             state_rows = state_plan.pop("rows")
             if defer_by_state:
                 # What a join has to scan is decided by where its STATE
                 # stands, not its pages: the cold-or-warm question
-                # above, asked after the joint plan.
+                # above, asked after the joint plan. An admission whose
+                # laggards wait for a leader stays deferred whatever is
+                # left to scan: a prologue cannot unblock them.
                 est = sum(len(t) - o for t, o in zip(all_tokens, offsets))
-                defer_prefill = est >= self.ragged_defer_min
+                defer_prefill = (bool(share_plan)
+                                 or est >= self.ragged_defer_min)
         plan = None
         # Allocate pages for the whole call (prompt + padded decode)
         # and copy-on-write any shared page in the write range, so
@@ -2873,9 +2955,7 @@ class InferenceEngine:
         # allocating exclusive pages now would transiently demand
         # more pool than the prologue path ever did (the alias
         # would immediately replace them), and their tail capacity
-        # is ensured at alias time (scheduler._apply_share_plans).
-        deferred_followers = {i for p in share_plan
-                              for i, _lo in p["followers"]}
+        # is ensured at alias time (join_laggard).
         for i, name in enumerate(names):
             if i in deferred_followers:
                 continue

@@ -47,6 +47,24 @@ the first dispatch. A model whose layers are ALL recurrent has an empty
 pool tree: its pages are ids that hold no bytes, and everything above
 reads them as it does for every model.
 
+**The leader pass hands a state on** (kvcache.share_prefixes, the
+deferred pass of a scheduled admission): the span a request's rows have
+in common is scanned ONCE, by the leader; the laggards block, and start
+from the state the leader left at the last page boundary at or under
+the span's end. The seams are the ones above and know no layer kind:
+`hand_over` says at admission whether the store can hold that one
+state pinned; `expect` marks the boundary for the leader's slot (the
+first of its runs that crosses it leaves its one snapshot there,
+`capture_slot`) and pins the snapshot — no capture and no radix node
+evicts it — until the last laggard has planned or the request is
+dropped (`unpin`); a laggard's `plan` runs when it unblocks, behind the
+leader's capture in program order, and finds the snapshot by its key
+like any other. Where that state cannot be had — an admission that is
+not deferred, no boundary ahead of the leader, a store full of pins, a
+boundary the leader's run gave to an earlier one — the rows scan the
+span themselves as they did before, counted (`share_declined` beside
+`share_handed`).
+
 What a restore wrote into slot rows and what the programs' captures
 wrote into the store are counted in bytes (`copy_bytes`, one writer:
 `_note_copy`): at 206 MB a state they are device work an admission
@@ -121,7 +139,9 @@ class HybridStateStore:
         self.snapshots_taken = 0
         self.continued_tokens = self.reused_tokens = 0
         self.rescanned_tokens = 0
-        self.share_declined = 0
+        # The leader pass: laggards that started from the state a leader
+        # handed on, and those that scanned the span themselves.
+        self.share_handed = self.share_declined = 0
         self.copy_bytes = {"restore": 0, "capture": 0}
         # Tokens x Mamba-1 layers the join programs scanned (pads left
         # out; a decode loop's steps advance states without the scan).
@@ -162,6 +182,10 @@ class HybridStateStore:
         # Per slot: the page-aligned end of a span the pages held and no
         # state did (plan), until a run of the slot crosses it.
         self._shared_to: dict[str, int] = {}
+        # Per leader slot: the boundary its laggards wait at (expect);
+        # per key: the laggards a snapshot is pinned for.
+        self._hand_at: dict[str, int] = {}
+        self._pins: dict[bytes, int] = {}
 
     def _alloc(self) -> None:
         self.state: dict[str, Any] = hybrid.zero_state(
@@ -231,7 +255,7 @@ class HybridStateStore:
 
     def forget(self, name: str) -> None:
         for table in (self._row_of, self._consumed, self._keys,
-                      self._shared_to):
+                      self._shared_to, self._hand_at):
             table.pop(name, None)
 
     def forget_all(self) -> None:
@@ -271,6 +295,7 @@ class HybridStateStore:
         # such prompt needs (capture_slot takes it once).
         self._shared_to[name] = (cap // self.page_size * self.page_size
                                  if source == ZERO else 0)
+        self._hand_at.pop(name, None)   # an older admission's
         if source == ZERO:
             self.misses += 1
         else:
@@ -339,6 +364,57 @@ class HybridStateStore:
         if name in self._row_of:
             self._consumed[name] = list(tokens) if exact else None
 
+    # --- the leader pass ------------------------------------------------
+
+    def hand_over(self, tokens: list[int], lo: int,
+                  hi: int) -> Optional[tuple[bytes, int]]:
+        """-> (key, boundary) of the state a leader whose pages stand at
+        `lo` can hand the laggards of its admission: the one after the
+        last page boundary at or under `hi`, the end of the span they
+        have in common. None when there is none to hand — the leader
+        has passed every such boundary — or none left to pin."""
+        at = hi // self.page_size * self.page_size
+        if at <= lo or not self.capacity:
+            return None
+        key = page_keys(tokens, self.page_size, at)[-1]
+        if key not in self._pins and len(self._pins) >= self.capacity:
+            return None
+        return key, at
+
+    def expect(self, leader: str, key: bytes, at: int,
+               laggards: int) -> None:
+        """Slot `leader` is about to scan across `hand_over`'s boundary
+        and `laggards` blocked rows will start from its state there:
+        the first of its runs that crosses it leaves its one snapshot
+        there (capture_slot), pinned — as one that stands there already
+        is — until each has planned or been dropped (`unpin`)."""
+        self._hand_at[leader] = at
+        self._pins[key] = self._pins.get(key, 0) + laggards
+        if key in self._snap:
+            self._snap.move_to_end(key)
+
+    def unpin(self, key: bytes, laggards: int = 1) -> None:
+        """`laggards` rows no longer wait for the snapshot: with the
+        last of them it is the store's to evict again, in LRU order."""
+        left = self._pins.get(key, 0) - laggards
+        if left > 0:
+            self._pins[key] = left
+        else:
+            self._pins.pop(key, None)
+
+    def note_handed(self) -> None:
+        """A laggard started from the state its leader handed on."""
+        self.share_handed += 1
+        telemetry.inc("roundtable_state_share_handed_total",
+                      engine=self.engine)
+
+    def note_declined(self, laggards: int, reason: str) -> None:
+        """`laggards` rows scan a span they share with a leader
+        themselves, from the deepest snapshot each finds."""
+        self.share_declined += laggards
+        telemetry.inc("roundtable_state_share_declined_total", laggards,
+                      engine=self.engine, reason=reason)
+
     # --- snapshots ------------------------------------------------------
 
     def capture_slot(self, name: str, start: int, n_tokens: int
@@ -347,24 +423,29 @@ class HybridStateStore:
         n): -> (cap_len, snapshot index, key) — after how many of them
         the state stands at the last page boundary the run crosses (or
         at the end of a span `plan` found held by the pages and by no
-        state, the once it is crossed), and where the program stores
-        it; (0, scratch, None) when there is none, it is already held,
-        or the prompt is unknown. A dispatch that fails drops the keys
-        it reserved (`drop`)."""
+        state, or at the boundary `expect` marked, the once it is
+        crossed), and where the program stores it; (0, scratch, None)
+        when there is none, it is already held, every state held is
+        pinned, or the prompt is unknown. A dispatch that fails drops
+        the keys it reserved (`drop`, unwritten)."""
         ps = self.page_size
-        b = (start + n_tokens) // ps * ps
+        end = start + n_tokens
+        b = end // ps * ps
         keys = self._keys.get(name)
-        shared = self._shared_to.get(name, 0)
-        if 0 < shared <= start + n_tokens:
-            # The run crosses the end of a span that was re-scanned from
-            # zero though its pages were held: that boundary, if no run
-            # before this one took it (its siblings, behind it in the
-            # same admission, take the last one as ever).
-            self._shared_to[name] = 0
-            if (start < shared and keys is not None
-                    and shared // ps <= len(keys)
-                    and keys[shared // ps - 1] not in self._snap):
-                b = shared
+        # A boundary the slot owes comes before the last one: the end of
+        # a span re-scanned from zero though its pages were held (plan),
+        # then the one its laggards wait at (expect) — the first of them
+        # this run crosses and no state stands at yet (its siblings,
+        # behind it in the same admission, take the last one as ever).
+        # Nothing is struck off here: the next run starts beyond it, and
+        # a dispatch that failed and is issued again owes it again.
+        for at in sorted((self._shared_to.get(name, 0),
+                          self._hand_at.get(name, 0))):
+            if (start < at <= end and keys is not None
+                    and at // ps <= len(keys)
+                    and keys[at // ps - 1] not in self._snap):
+                b = at
+                break
         if (self.capacity == 0 or b <= start or keys is None
                 or b // ps > len(keys) or name.startswith("__warmup_")):
             return 0, self.scratch_snap, None
@@ -372,8 +453,8 @@ class HybridStateStore:
         if key in self._snap:
             self._snap.move_to_end(key)
             return 0, self.scratch_snap, None
-        if not self._free_snaps:
-            self._evict_lru()
+        if not self._free_snaps and not self._evict_lru():
+            return 0, self.scratch_snap, None   # every state is pinned
         idx = self._free_snaps.pop()
         self._snap[key] = idx
         self.snapshots_taken += 1
@@ -383,15 +464,25 @@ class HybridStateStore:
         self._publish()
         return b - start, idx, key
 
-    def _evict_lru(self) -> None:
-        key, idx = self._snap.popitem(last=False)
-        self._free_snaps.append(idx)
+    def _evict_lru(self) -> bool:
+        """Free the oldest snapshot no laggard waits for; False when
+        every one is pinned."""
+        key = next((k for k in self._snap if k not in self._pins), None)
+        if key is None:
+            return False
+        self._free_snaps.append(self._snap.pop(key))
         self.evictions += 1
         telemetry.inc("roundtable_state_snapshot_evictions_total",
                       engine=self.engine)
+        return True
 
-    def drop(self, key: Optional[bytes]) -> None:
-        """The radix node this snapshot was bound to is gone."""
+    def drop(self, key: Optional[bytes], unwritten: bool = False) -> None:
+        """The radix node this snapshot was bound to is gone — a pinned
+        one stays, unbound, for the laggards that wait for it — or
+        (`unwritten`) the program that was to write it failed: it goes
+        whoever waits."""
+        if key in self._pins and not unwritten:
+            return
         idx = self._snap.pop(key, None) if key is not None else None
         if idx is not None:
             self._free_snaps.append(idx)
@@ -475,6 +566,7 @@ class HybridStateStore:
             "continued_tokens": self.continued_tokens,
             "reused_tokens": self.reused_tokens,
             "rescanned_tokens": self.rescanned_tokens,
+            "share_handed": self.share_handed,
             "share_declined": self.share_declined,
             "restore_bytes": self.copy_bytes["restore"],
             "capture_bytes": self.copy_bytes["capture"],

@@ -78,7 +78,9 @@ def share_prefixes(kv, names, all_tokens, offsets, *, min_shared: int,
     alias the laggards AFTER the leader's chunks have written the span
     (aliasing unwritten pages would be copy-on-write'd away by the
     leader's own write-exclusivity). A leader that already covers the
-    span aliases immediately — the content exists.
+    span aliases immediately — the content exists. A callback that
+    returns False has recorded nothing: the span cannot be deferred
+    (below), and `decline_leader` takes it.
 
     `donor_ok(donor_state, row_i)` (ISSUE 10): extra donor gate —
     multi-LoRA engines pass an adapter-identity check, since K/V baked
@@ -89,13 +91,17 @@ def share_prefixes(kv, names, all_tokens, offsets, *, min_shared: int,
     uniform-adapter batches (engine._prepare_batch suppresses mixed
     ones).
 
-    `decline_leader(n_laggards)`: when given, a leader whose cache
-    does not cover the common span yet prefills NOTHING for the others
-    and the callback counts the decline — a model with recurrent state
-    cannot hand laggards the leader's state at the span's end through
-    this pass (every row scans the span itself, from the deepest
-    snapshot it finds). A leader that already covers the span still
-    aliases its pages: that is KV alone.
+    `decline_leader(n_laggards)`: given by a model with recurrent
+    state, whose laggards need the leader's STATE at the span's end and
+    not its pages alone. Its deferred pass is the one above — the
+    leader scans the span once and leaves its state at a page boundary,
+    the laggards start from there when they unblock
+    (hybrid_state.expect) — wherever `defer_span` can have that state
+    kept. Where it cannot (it returns False), or the admission is not
+    deferred, the leader prefills NOTHING for the others and the
+    callback counts the decline: every row scans the span itself, from
+    the deepest snapshot it finds. A leader that already covers the
+    span still aliases its pages: that is KV alone.
 
     Returns (updated offsets, leader-prefilled token count)."""
     b = len(names)
@@ -126,14 +132,14 @@ def share_prefixes(kv, names, all_tokens, offsets, *, min_shared: int,
     if not laggards:
         return offsets, extra_prefill
     if offsets[m] < l_shared:
-        if decline_leader is not None:
-            decline_leader(len(laggards))
-            return offsets, extra_prefill
-        if defer_span is not None:
-            defer_span(m, offsets[m], l_shared,
-                       [(i, offsets[i]) for i in laggards])
+        if defer_span is not None and defer_span(
+                m, offsets[m], l_shared,
+                [(i, offsets[i]) for i in laggards]) is not False:
             for i in laggards:
                 offsets[i] = l_shared
+            return offsets, extra_prefill
+        if decline_leader is not None:
+            decline_leader(len(laggards))
             return offsets, extra_prefill
         prefill_span(m, offsets[m], l_shared)
         extra_prefill += l_shared - offsets[m]

@@ -279,7 +279,9 @@ class _Request:
         # Deferred leader-span share plans (ragged admission): the
         # laggards alias the common span once the leader's chunks have
         # written it. [{"leader": _Row, "hi": int,
-        # "followers": [(_Row, lo), ...]}]
+        # "followers": [(_Row, lo), ...] — those still blocked,
+        # "hand": (key, boundary) of the snapshot kept for them where
+        # the model holds recurrent state, else None}]
         self.share_plans: list[dict] = []
         # Speculation provenance (ISSUE 9): this request's drafted /
         # accepted totals — lands in GenStats.sched["spec"] at retire.
@@ -1502,11 +1504,20 @@ class SessionScheduler:
             req.share_plans = [
                 {"leader": rows[p["leader"]], "hi": p["hi"],
                  "followers": [(rows[i], lo) for i, lo in
-                               p["followers"]]}
+                               p["followers"]],
+                 "hand": p.get("hand")}
                 for p in prep.get("share_plan", [])]
             for plan in req.share_plans:
                 for f, _lo in plan["followers"]:
                     f.blocked = True
+                if plan["hand"] is not None:
+                    # A model with recurrent state: the leader leaves
+                    # its state where the laggards start, kept for them
+                    # from here until each has joined (_alias_due) or
+                    # the request is dropped (_drop_request).
+                    engine.hybrid.expect(plan["leader"].name,
+                                         *plan["hand"],
+                                         len(plan["followers"]))
         req.turn_budget = turn_budget
         req.dec_budget = turn_budget.child("decode")
         req.deadline = deadline
@@ -1790,10 +1801,15 @@ class SessionScheduler:
         alias, boundary pages are copied: queued on the page cache and
         issued with every other pending copy before the segment's
         program takes the pools, ISSUE 38) and the rows unblock, their
-        pending already trimmed to the post-span tail at admission.
-        Armed, a request whose plans are due gets a `share` span in its
-        own trace (ISSUE 37; the pass that finds nothing due builds
-        none): followers unblocked, pages aliased, pages copied."""
+        pending trimmed to the post-span tail at admission — or, where
+        the model holds recurrent state, from the page boundary under
+        the span's end at which the leader left its state
+        (engine.join_laggard). Armed, a request whose plans are due gets
+        a `share` span in its own trace (ISSUE 37; the pass that finds
+        nothing due builds none): followers unblocked, pages aliased,
+        pages copied, and with state the followers `handed` the
+        leader's, the prompt tokens they were spared and the bytes
+        their restores wrote."""
         for req in list(self._active_reqs):
             due = [p for p in req.share_plans
                    if p["leader"].pos >= p["hi"]]
@@ -1802,9 +1818,7 @@ class SessionScheduler:
             with self._open_share(req) as share:
                 failed, done = self._alias_due(req, due)
                 if share is not telemetry.NULL_SPAN:
-                    share.attrs.update(followers=done[0],
-                                       pages_aliased=done[1],
-                                       copies=done[2])
+                    share.attrs.update(done)
             if failed is not None:
                 self._fail_request(req, failed)
                 continue
@@ -1821,31 +1835,48 @@ class SessionScheduler:
                                     engine=self._tname)
 
     def _alias_due(self, req: _Request, due: list[dict]
-                   ) -> tuple[Optional[BaseException], tuple]:
-        """Alias each due plan's span into its followers. -> (the error
-        that stopped it, if one did; followers unblocked, pages
-        aliased, pages copied)."""
-        followers = aliased = copies = 0
+                   ) -> tuple[Optional[BaseException], dict]:
+        """Join each due plan's followers behind their leader. -> (the
+        error that stopped it, if one did; the `share` span's counts)."""
+        done = {"followers": 0, "pages_aliased": 0, "copies": 0}
+        with_state = any(p["hand"] is not None for p in due)
+        if with_state:
+            # (`kv_matched_tokens` / `state_reused_tokens`: as an `admit`
+            # span says them of the rows it plans, here of the laggards)
+            done.update(handed=0, tokens_spared=0, state_copy_bytes=0,
+                        kv_matched_tokens=0, state_reused_tokens=0)
         pinned = tuple(r.name for r in self._active)
         _max_new, padded = clamp_max_new(
             req.max_new, self.engine.max_seq_len)
         failed: Optional[BaseException] = None
         for plan in due:
-            leader = plan["leader"]
+            leader, hi = plan["leader"], plan["hi"]
+            n = len(plan["followers"])
             try:
-                for f, lo in plan["followers"]:
-                    a, c = self.engine.kv.alias_span(
-                        leader.name, f.name, lo, plan["hi"], pinned)
-                    aliased, copies = aliased + a, copies + c
-                    # Tail capacity (deferred from admission so the
-                    # span pages arrive SHARED, not as transient
-                    # exclusive allocations the alias would
-                    # replace).
-                    self.engine.kv.ensure_capacity(
-                        f.name, len(f.tokens) + padded,
-                        write_from=plan["hi"], pinned=pinned)
+                while plan["followers"]:
+                    f, lo = plan["followers"][0]
+                    got = self.engine.join_laggard(
+                        leader.name, f.name, f.tokens, lo, hi,
+                        len(f.tokens) + padded, pinned, plan["hand"])
+                    del plan["followers"][0]
+                    start = got["start"]
+                    if start < hi:
+                        # The row's state stands under the span's end:
+                        # it scans from there.
+                        f.pending = list(f.tokens[start:])
+                        f.pos = f.valid = start
+                        req.stats.prefill_tokens += hi - start
+                        req.stats.reused_tokens -= hi - start
                     f.blocked = False
-                    followers += 1
+                    done["followers"] += 1
+                    done["pages_aliased"] += got["aliased"]
+                    done["copies"] += got["copies"]
+                    if with_state:
+                        done["handed"] += got["handed"]
+                        done["tokens_spared"] += max(start - lo, 0)
+                        done["state_copy_bytes"] += got["state_copy_bytes"]
+                        done["kv_matched_tokens"] += hi
+                        done["state_reused_tokens"] += start
             except Exception as e:  # noqa: BLE001 — contain per req
                 # Pool exhaustion mid-join (the prologue path's
                 # equivalent was a requeue at admission): fail ONLY
@@ -1855,9 +1886,8 @@ class SessionScheduler:
                 failed = e
                 break
             self._event("share_alias", session=req.session,
-                        hi=plan["hi"],
-                        followers=len(plan["followers"]))
-        return failed, (followers, aliased, copies)
+                        hi=hi, followers=n)
+        return failed, done
 
     def _run_ragged_segment(self, live: list[_Row],
                             filling: list[_Row]) -> None:
@@ -2973,6 +3003,15 @@ class SessionScheduler:
     def _drop_request(self, req: _Request) -> None:
         if req in self._active_reqs:
             self._active_reqs.remove(req)
+        for plan in req.share_plans:
+            # Laggards that never joined: nothing waits for the state
+            # their leader was to leave.
+            if plan["hand"] is not None and plan["followers"]:
+                self.engine.hybrid.unpin(plan["hand"][0],
+                                         len(plan["followers"]))
+                self.engine.hybrid.note_declined(
+                    len(plan["followers"]), "dropped")
+        req.share_plans = []
         dd = getattr(self.engine, "spec_device_drafter", None)
         for r in req.rows:
             self._row_req.pop(id(r), None)

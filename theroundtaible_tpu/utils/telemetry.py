@@ -1189,8 +1189,13 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "reused_tokens": "derived (plan totals; describe-only)",
         "rescanned_tokens": "roundtable_state_rescanned_tokens_total "
                             "(over roundtable_state_prompt_tokens_total)",
+        # ISSUE 48: the leader pass of a model with state — laggards
+        # that started from the state their leader handed on at a page
+        # boundary, and those that scanned the span themselves.
+        "share_handed": "roundtable_state_share_handed_total",
         "share_declined": "roundtable_state_share_declined_total"
-                          "{reason=recurrent-state}",
+                          "{reason=prologue|no-state-to-hand|"
+                          "state-not-left|dropped}",
         # ISSUE 42: whole states written into slot rows by a restore
         # and into the store by the programs' captures, in bytes
         # (HybridStateStore._note_copy is the one writer; an `admit`
