@@ -770,7 +770,9 @@ def _window_step_hlo(cfg, pool, program: str, one_chip, check,
         jax.random.PRNGKey(0)))
     check(params)
     pools = [(pool, pool)] * len(cfg.attention_layers)
-    state = hybrid.zero_state(cfg, ROWS)
+    # (a part gathered by row rides in batch order: hybrid.ROW_PARTS)
+    state = hybrid.zero_state(cfg, {"decode": DECODE_ROWS, "prefill": 1,
+                                    "ragged": ROWS + 1}[program])
     if program == "decode":
         def step(params, pools, tokens, positions, table, valid, active):
             return forward_paged_hybrid(
@@ -1235,6 +1237,145 @@ def test_whole_jamba_decode_step_compiles_with_its_runs_scanned(
         f"{mem.temp_size_in_bytes / 1e6:.0f} MB of temporaries beside a "
         f"run's state of {run_state / 1e6:.0f} MB")
     assert mem.argument_size_in_bytes > 6.0e9       # the model, whole
+
+
+# --- 64-wide heads, two a lane row (ISSUE 52) -------------------------------
+#
+# LFM2-24B-A2B: 32 query heads over 8 kv heads of SIXTY-FOUR. The pool
+# holds two heads of one token a 128-lane row, `[640, 128, 4, 128]`
+# (pallas/attention.py: lane_pack), the queries arrive 64 wide, and the
+# wrappers hand the kernels rows that carry a head's half and zeros.
+
+LFM2_POOL, LFM2_HEADS, LFM2_KV, LFM2_D = (640, PAGE, 4, 128), 32, 8, 64
+
+
+@pytest.mark.parametrize("kernel,t", [
+    ("paged_decode", 1), ("ragged", 1024), ("ragged", 64),
+    ("paged_prefill", 256)])
+def test_heads_of_64_compile_over_the_packed_pool(one_chip, kernel, t):
+    """The decode walk, the ragged walk and the prefill kernel at LFM2's
+    attention geometry: the gates accept (K, D) = (8, 64) outside
+    interpret mode, the pool is an operand as the cell holds it — twice,
+    uncopied, 2 KB a position — and the result is 64 wide."""
+    import re
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32, h, d = jnp.int32, LFM2_HEADS, LFM2_D
+    pool = s(LFM2_POOL, jnp.bfloat16)
+    assert pattn.lane_pack(LFM2_KV, d) == 2
+    assert int(np.prod(LFM2_POOL[2:])) * 2 * 2 == 2048
+    if kernel == "paged_decode":
+        fn, shapes = pattn.paged_decode_attention, (
+            s((DECODE_ROWS, 1, h, d), jnp.bfloat16), pool, pool,
+            s((DECODE_ROWS, PAGES_PER_SEQ), i32), s((DECODE_ROWS,), i32))
+    elif kernel == "ragged":
+        blocks = t // pattn.RAGGED_BLOCK_Q
+        fn, shapes = pattn.ragged_paged_attention, (
+            s((t, h, d), jnp.bfloat16), pool, pool,
+            s((ROWS + 1, PAGES_PER_SEQ), i32), s((blocks,), i32),
+            s((blocks,), i32), s((ROWS + 1,), i32), s((ROWS + 1,), i32))
+    else:
+        fn, shapes = pattn.paged_prefill_attention, (
+            s((4, t, h, d), jnp.bfloat16), pool, pool,
+            s((4, PAGES_PER_SEQ), i32), s((4,), i32), s((4,), i32))
+    compiled = jax.jit(functools.partial(fn, interpret=False)).lower(
+        *shapes).compile()
+    hlo = compiled.as_text()
+    _assert_kernel(hlo)
+    call = next(line for line in hlo.splitlines()
+                if "tpu_custom_call" in line)
+    assert call.count("bf16[640,128,4,128]") == 2
+    assert compiled.out_info.shape[-2:] == (h, d)
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if re.search(r"= \w+\[640,128,[0-9,]+\]\S* copy(-start)?\(",
+                           line)]
+    assert not copies, copies
+
+
+def test_the_gates_take_64_wide_pairs_and_decline_the_rest(monkeypatch):
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    group = LFM2_HEADS // LFM2_KV
+    assert pattn.paged_decode_decline_reason(PAGE, 64, 8, group) is None
+    assert pattn.ragged_decline_reason(PAGE, 64, 8, group) is None
+    assert pattn.supported(256, 1024, 64, 8)
+    assert pattn.paged_pool_direct_supported(1024, PAGE, 64, 8, group)
+    # ... asked about the cell itself, the same answer
+    assert pattn.paged_decode_decline_reason(PAGE, 128, 4, 2 * group) is None
+    # an odd head count has no pairs; 32 and 96 are no half of a lane row;
+    # a quantized pool keeps plain cells (its scales are a head's)
+    assert pattn.paged_decode_decline_reason(PAGE, 64, 3, 1) == "head_dim:64"
+    assert pattn.ragged_decline_reason(PAGE, 64, 1, 4) == "head_dim:64"
+    assert pattn.ragged_decline_reason(PAGE, 32, 8, 4) == "head_dim:32"
+    assert pattn.paged_decode_decline_reason(PAGE, 96, 8, 4) == "head_dim:96"
+    assert pattn.paged_decode_decline_reason(
+        PAGE, 64, 8, group, itemsize=1, scale_groups=2) == "head_dim:64"
+    assert pattn.ragged_decline_reason(
+        PAGE, 64, 8, group, quantized=True) == "head_dim:64"
+    assert not pattn.supported(256, 1024, 64, 3)
+    # a plain [K, 64] pool handed to a kernel for the chip is refused
+    pool = jnp.zeros((4, PAGE, 8, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="2 heads a lane row"):
+        pattn.paged_decode_attention(
+            jnp.zeros((2, 1, 32, 64), jnp.bfloat16), pool, pool,
+            jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32),
+            interpret=False)
+
+
+@pytest.mark.parametrize("kernel", ["flash_prefill", "ragged_decode"])
+def test_the_cache_kernels_take_64_wide_pairs_on_the_chip(one_chip, kernel):
+    """`supported` accepts (8, 64): the position-aligned kernels view the
+    cache [B, S, 4, 128] on the chip."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32, b, length = jnp.int32, 4, 1024
+    cache = s((b, length, LFM2_KV, LFM2_D), jnp.bfloat16)
+    if kernel == "flash_prefill":
+        fn, shapes = pattn.flash_prefill_attention, (
+            s((b, CHUNK, LFM2_HEADS, LFM2_D), jnp.bfloat16), cache, cache,
+            s((b,), i32), s((b,), i32))
+    else:
+        fn, shapes = pattn.ragged_decode_attention, (
+            s((b, 1, LFM2_HEADS, LFM2_D), jnp.bfloat16), cache, cache,
+            s((b,), i32))
+    _assert_kernel(_compile(functools.partial(fn, interpret=False), *shapes))
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
+def test_hybrid_step_of_the_lfm2_cut_compiles(one_chip, monkeypatch,
+                                              program):
+    """One decode step, one ragged join and one prologue chunk of
+    LFM2-24B-A2B at published widths: a conv block with the dense SwiGLU,
+    an attention block and a conv block with all 64 experts held — the
+    short convolution's three forms, the packed pool through both walks
+    and the prefill kernel, the head norms, the biased sigmoid router."""
+    from theroundtaible_tpu.engine.models import hybrid
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    whole = get_model_config("lfm2-24b-a2b")
+    # blocks 0 (conv, dense), 2 (attention, experts), 3 (conv, experts)
+    cfg = dataclasses.replace(
+        whole, num_layers=6,
+        layer_kinds=whole.layer_kinds[:2] + whole.layer_kinds[4:8],
+        attn_impl="flash")
+    assert cfg.layer_kinds == (
+        hybrid.SHORTCONV, hybrid.MLP, hybrid.ATTENTION, hybrid.EXPERTS,
+        hybrid.SHORTCONV, hybrid.EXPERTS)
+    assert (cfg.page_heads, cfg.page_width) == LFM2_POOL[2:]
+    assert cfg.experts_held == cfg.routed_experts == 64
+
+    def check(params):
+        assert params["layers"][0]["in_proj"].shape == (2048, 6144)
+        assert params["layers"][0]["conv_w"].shape == (3, 2048)
+        assert params["layers"][2]["k_proj"].shape == (2048, LFM2_KV, LFM2_D)
+        assert params["layers"][2]["q_norm"].shape == (LFM2_D,)
+        assert params["layers"][3]["router_bias"].shape == (64,)
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    hlo = _window_step_hlo(cfg, s(LFM2_POOL, jnp.bfloat16), program,
+                           one_chip, check)
+    assert hlo.count("tpu_custom_call") >= 2
+    assert "grouped_matmul" in hlo
+    assert "bf16[640,128,4,128]" in hlo
 
 
 # --- the int4 kernels the compiler refuses --------------------------------

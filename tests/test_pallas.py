@@ -336,7 +336,12 @@ def test_paged_decode_gate_reckons_what_vmem_stores(monkeypatch):
     assert not pattn._token_major(2, 1) and pattn._token_major(4, 1)
     assert pattn.paged_decode_supported(128, 128, 8, 4, **int8)
     assert pattn.paged_decode_supported(128, 128, 2, 4, **int8)
-    assert pattn.paged_decode_decline_reason(128, 64, 8, 4) \
+    # (64-wide heads in pairs are stored two a lane row and served:
+    # ISSUE 52, lane_pack; an odd count or a quantized pool is not)
+    assert pattn.paged_decode_decline_reason(128, 64, 8, 4) is None
+    assert pattn.paged_decode_decline_reason(128, 64, 3, 4) \
+        == "head_dim:64"
+    assert pattn.paged_decode_decline_reason(128, 64, 8, 4, **int8) \
         == "head_dim:64"
     assert pattn.paged_decode_decline_reason(64, 128, 8, 4, **int8) \
         == "scale_page:64"

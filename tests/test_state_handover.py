@@ -3,10 +3,11 @@ a request's rows have in common is scanned ONCE — the leader leaves its
 state at the last page boundary under the span's end, pinned in the
 snapshot store, and the laggards start from it when they unblock
 (kvcache.share_prefixes, hybrid_state.expect, engine.join_laggard,
-scheduler._alias_due). One set of cases over the three kinds of state
+scheduler._alias_due). One set of cases over the four kinds of state
 behind the one store — tiny Nemotron-H (Mamba-2 beside attention pages),
 tiny Brumby (retention, pages that hold no bytes), tiny Jamba (scanned
-runs of Mamba-1 beside one-kv-head pages) — each through its own serving
+runs of Mamba-1 beside one-kv-head pages), tiny LFM2 (conv tails of two
+rows beside packed 64-wide pages) — each through its own serving
 test's engine, traffic and plain reference: the mechanism knows no layer
 kind, so neither do the cases.
 
@@ -32,7 +33,8 @@ from theroundtaible_tpu.utils import telemetry  # noqa: E402
 
 MODELS = {"nemotron-h": ("test_hybrid_serving", "tiny-nemotron-h"),
           "brumby": ("test_brumby_serving", "tiny-brumby"),
-          "jamba": ("test_jamba_serving", "tiny-jamba")}
+          "jamba": ("test_jamba_serving", "tiny-jamba"),
+          "lfm2": ("test_lfm2_serving", "tiny-lfm2")}
 PAGE = 16
 NEW = 24      # an answer: a round's new span (3 cues + 3 answers) passes
               # MIN_SHARED_PREFIX, as the benchmark's 128-token ones do

@@ -614,7 +614,9 @@ class InferenceEngine:
                     tail = (k_pool.shape[2], model_cfg.head_dim)
                 else:
                     kb, vb = k_pool[tables], v_pool[tables]
-                    tail = k_pool.shape[2:]
+                    # (a pool's own cell may hold two heads a lane
+                    # row: ModelConfig.lane_pack)
+                    tail = (model_cfg.num_kv_heads, model_cfg.head_dim)
                 caches_b.append(
                     (kb.reshape(b, n_pages_seq * page_size, *tail),
                      vb.reshape(b, n_pages_seq * page_size, *tail)))
@@ -1161,10 +1163,11 @@ class InferenceEngine:
             # Default: room for four snapshots a slot.
             from .models.hybrid import state_bytes_per_sequence
             state_snapshot_bytes = (4 * num_slots
-                                    * state_bytes_per_sequence(cfg))
+                                    * state_bytes_per_sequence(cfg,
+                                                               self.dtype))
         self.hybrid = HybridStateStore(
             cfg, num_slots, page_size, state_snapshot_bytes,
-            engine=cfg.name)
+            engine=cfg.name, dtype=self.dtype)
         from .models.hybrid import ROW_PARTS
 
         # ROW_PARTS are gathered to the batch's rows and scattered back;
@@ -3439,6 +3442,17 @@ class InferenceEngine:
                            else "mamba1_scan"),
                 "scan_runs": list(self.cfg.scan_runs),
                 "scan_tokens": self.hybrid.scan_tokens,
+            }
+        if self.cfg.shortconv_layers:
+            from .models import shortconv
+            info["shortconv"] = {
+                "layers": len(self.cfg.shortconv_layers),
+                "channels": self.cfg.embed_dim,
+                "taps": self.cfg.conv_kernel,
+                "bytes_per_state": shortconv.bytes_per_state(
+                    self.cfg, self.dtype),
+                "state_dtype": jnp.dtype(self.dtype).name,
+                "conv_tokens": self.hybrid.conv_tokens,
             }
         if self.cfg.attn_layers is not None:
             info["attention"] = self.attention_describe()

@@ -42,8 +42,12 @@ def estimate_param_count(cfg: ModelConfig) -> int:
                 cfg.experts_held * cfg.expert_dim + cfg.shared_expert_dim)
                 + (e + (cfg.router_rule == "sigmoid_bias_topk"))
                 * cfg.routed_experts),
-            "attention": 2 * e * h * d + 2 * e * k * d,
+            # (and the two head norms, where q and k are normed a head)
+            "attention": (2 * e * h * d + 2 * e * k * d
+                          + (2 * d if cfg.qk_norm else 0)),
             "mlp": 3 * e * f,
+            # in (E -> 3E), the taps, out (models/shortconv.py)
+            "shortconv": 4 * e * e + cfg.conv_kernel * e,
             # q, k, v, o, one gate a kv head, the two head norms
             "retention": 2 * e * h * d + 2 * e * k * d + e * k + 2 * d,
         }

@@ -7,7 +7,9 @@ and the last K-1 inputs of the causal conv; per retention layer
 (models/retention.py) the feature-map state and its normaliser, 34 MB a
 layer a sequence at published widths; per scanned RUN of Mamba-1 layers
 (models/mamba1.py) ONE leaf a part, `[rows, run_layers, ...]`, rows
-ahead of layers so that a slot's whole state is one index here. Pages
+ahead of layers so that a slot's whole state is one index here; per
+gated short-convolution layer (models/shortconv.py) two rows of the
+model's width, 8 KB, the whole of it. Pages
 can be truncated to any prefix and shared by reference; a state is valid
 at exactly ONE position — the number of tokens it has consumed — and can
 only be copied whole. This module owns both halves of that, and knows
@@ -115,13 +117,17 @@ def page_keys(tokens: list[int], page_size: int, upto: int) -> list[bytes]:
 
 class HybridStateStore:
     def __init__(self, cfg: ModelConfig, num_slots: int, page_size: int,
-                 snapshot_bytes: int, engine: str = "engine"):
+                 snapshot_bytes: int, engine: str = "engine",
+                 dtype=jnp.bfloat16):
+        # `dtype`: the engine's, which a part kept in the activations'
+        # dtype takes (hybrid.zero_state).
         self.cfg = cfg
+        self.dtype = dtype
         self.engine = engine
         self.page_size = page_size
         self.num_slots = num_slots
         self.scratch_row = num_slots
-        self.bytes_per_state = hybrid.state_bytes_per_sequence(cfg)
+        self.bytes_per_state = hybrid.state_bytes_per_sequence(cfg, dtype)
         self.budget_bytes = int(snapshot_bytes) if cfg.recurrent else 0
         self.capacity = (self.budget_bytes // self.bytes_per_state
                          if cfg.recurrent else 0)
@@ -144,8 +150,10 @@ class HybridStateStore:
         self.share_handed = self.share_declined = 0
         self.copy_bytes = {"restore": 0, "capture": 0}
         # Tokens x Mamba-1 layers the join programs scanned (pads left
-        # out; a decode loop's steps advance states without the scan).
+        # out; a decode loop's steps advance states without the scan),
+        # and tokens x short-convolution layers they ran.
         self.scan_tokens = 0
+        self.conv_tokens = 0
 
         @partial(jax.jit, donate_argnums=(0,))
         def restore(state, snaps, dst_rows, src_snaps, zero, n):
@@ -189,8 +197,9 @@ class HybridStateStore:
 
     def _alloc(self) -> None:
         self.state: dict[str, Any] = hybrid.zero_state(
-            self.cfg, self.num_slots + 1)
-        self.snaps = hybrid.zero_state(self.cfg, self.capacity + 1)
+            self.cfg, self.num_slots + 1, self.dtype)
+        self.snaps = hybrid.zero_state(self.cfg, self.capacity + 1,
+                                       self.dtype)
 
     # --- device trees ---------------------------------------------------
 
@@ -350,11 +359,18 @@ class HybridStateStore:
 
     def note_scan(self, tokens: int) -> None:
         """A join dispatch (a prologue chunk, a ragged step) fed its
-        runs `tokens` tokens: what the model's Mamba-1 layers scanned."""
+        runs `tokens` tokens: what the model's Mamba-1 layers scanned,
+        and what its short-convolution layers ran (re-scanned tokens
+        among them)."""
         n = tokens * len(self.cfg.mamba1_layers)
         if n:
             self.scan_tokens += n
             telemetry.inc("roundtable_mamba1_scan_tokens_total", n,
+                          engine=self.engine)
+        n = tokens * len(self.cfg.shortconv_layers)
+        if n:
+            self.conv_tokens += n
+            telemetry.inc("roundtable_shortconv_tokens_total", n,
                           engine=self.engine)
 
     def on_commit(self, name: str, tokens: list[int], exact: bool) -> None:

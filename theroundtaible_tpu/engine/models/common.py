@@ -151,10 +151,10 @@ class ModelConfig:
     attn_impl: str = "dense"
     # Hybrid decoders (models/hybrid.py): one kind a layer, each layer ONE
     # mixer behind one norm and a residual — "mamba2" | "mamba1" |
-    # "experts" | "attention" | "mlp" | "retention". None = the
-    # attention-plus-MLP block above, untouched. A pre-norm block whose two
-    # halves have a norm each IS two such layers (attention, then mlp or
-    # experts): `axk1`.
+    # "experts" | "attention" | "mlp" | "retention" |
+    # "shortconv". None = the attention-plus-MLP block above, untouched. A
+    # pre-norm block whose two halves have a norm each IS two such layers
+    # (attention, then mlp or experts): `axk1`.
     layer_kinds: Optional[tuple[str, ...]] = None
     rope: bool = True                 # False: no position embedding at all
     # Mamba-2 mixer
@@ -229,18 +229,32 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def lane_pack(self) -> int:
+        """Heads of one token that share a 128-lane row of a page: 2
+        where the heads are 64 wide and come in pairs (pallas/
+        attention.py, "heads narrower than a lane row"), else 1. (A
+        quantized pool keeps plain [K, D] cells, its scales a head's:
+        engine/paging.py.)"""
+        from ..pallas.attention import lane_pack
+        if self.latent:
+            return 1
+        return lane_pack(self.num_kv_heads, self.head_dim)
+
+    @property
     def page_heads(self) -> int:
-        """The kv heads a page holds, as the paged kernels see them."""
-        return 1 if self.latent else self.num_kv_heads
+        """The kv heads a page holds, as the paged kernels see them:
+        rows of `lane_pack` heads each."""
+        return 1 if self.latent else self.num_kv_heads // self.lane_pack
 
     @property
     def page_width(self) -> int:
-        """The cells of one head of one position of a page: head_dim, or
-        a latent entry (kv_lora_rank + qk_rope_dim) padded to whole lane
-        rows — 576 is 4.5 of them, and the kernels copy and multiply
-        pages as they lie."""
+        """The cells of one head of one position of a page: head_dim
+        (times `lane_pack`: a whole lane row), or a latent entry
+        (kv_lora_rank + qk_rope_dim) padded to whole lane rows — 576 is
+        4.5 of them, and the kernels copy and multiply pages as they
+        lie."""
         if not self.latent:
-            return self.head_dim
+            return self.head_dim * self.lane_pack
         return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
 
     @property
@@ -256,7 +270,8 @@ class ModelConfig:
         """Some layer keeps state that is not pages."""
         return bool(self._layers_of("mamba2")
                     or self._layers_of("retention")
-                    or self._layers_of("mamba1"))
+                    or self._layers_of("mamba1")
+                    or self._layers_of("shortconv"))
 
     @property
     def retention_layers(self) -> tuple[int, ...]:
@@ -269,6 +284,10 @@ class ModelConfig:
     @property
     def mamba1_layers(self) -> tuple[int, ...]:
         return self._layers_of("mamba1")
+
+    @property
+    def shortconv_layers(self) -> tuple[int, ...]:
+        return self._layers_of("shortconv")
 
     @property
     def layer_runs(self) -> tuple[tuple[tuple[str, ...], int], ...]:
@@ -699,7 +718,8 @@ def attention(
                 mesh, q, k_all, v_all, positions[:, 0], kv_valid,
                 sliding_window=cfg.sliding_window,
                 softcap=cfg.attn_logit_softcap)
-        elif pattn.supported(t, k_all.shape[1], cfg.head_dim):
+        elif pattn.supported(t, k_all.shape[1], cfg.head_dim,
+                             cfg.num_kv_heads):
             if t > 1:
                 out = pattn.flash_prefill_attention(
                     q, k_all, v_all, positions[:, 0], kv_valid,
@@ -895,7 +915,7 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
     from . import hybrid
     b, t = tokens.shape
     x = embed_tokens(params["embedding"], tokens)
-    zero = hybrid.zero_state(cfg, b)
+    zero = hybrid.zero_state(cfg, b, x.dtype)
     caches = []
     for kind, layer in hybrid.layers_unrolled(cfg, params):
         if kind == hybrid.ATTENTION:
@@ -916,6 +936,9 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
             out = hybrid.mamba1.mamba1_prefill(
                 h, layer, cfg, zero["ssm1"][0][:, :1],
                 zero["conv1"][0][:, :1], 0, jnp.arange(b), kv_valid_len)[0]
+        elif kind == hybrid.SHORTCONV:
+            out = hybrid.shortconv.shortconv_prefill(
+                h, layer, cfg, zero["sconv"][0], kv_valid_len)[0]
         elif kind == hybrid.EXPERTS:
             out, _ = hybrid.experts_mlp(h, layer, cfg)
         elif kind == hybrid.MLP:

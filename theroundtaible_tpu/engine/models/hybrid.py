@@ -10,7 +10,9 @@ layer (`retention`, models/retention.py: a feature-map state and no keys
 or values) is a fourth kind of mixer behind the same norm; a model whose
 mixers are all of that kind has no attention layer and so no page pool.
 A Mamba-1 layer (`mamba1`, models/mamba1.py: a decay for every (state
-index, channel) pair, so a scan kernel and no product form) is a fifth.
+index, channel) pair, so a scan kernel and no product form) is a fifth,
+a gated short convolution (`shortconv`, models/shortconv.py: three taps
+between two elementwise gates, whose whole state is two rows) a sixth.
 `layer_kinds` keeps one entry a layer; the layers are stored and run as
 `ModelConfig.layer_runs` derives them: consecutive (mamba1, mlp) blocks
 are ONE entry of `params["layers"]` whose leaves carry a leading layer
@@ -67,16 +69,17 @@ import jax
 import jax.numpy as jnp
 
 from ..pallas import grouped
-from . import mamba1, retention
+from . import mamba1, retention, shortconv
 from .common import ModelConfig, Params, _einsum, rms_norm
 
 MAMBA2, EXPERTS, ATTENTION, MLP = "mamba2", "experts", "attention", "mlp"
-RETENTION, MAMBA1 = "retention", mamba1.KIND
+RETENTION, MAMBA1, SHORTCONV = "retention", mamba1.KIND, shortconv.KIND
 # State parts gathered to the batch's rows and scattered back by a step
 # program (small), and parts a layer updates in place on the whole slot
 # array (models/retention.py: 34 MB a row a layer; models/mamba1.py: one
 # leaf a scanned run, [rows, layers, ...]).
-ROW_PARTS, SLOT_PARTS = ("ssm", "conv"), ("ret", "retn") + mamba1.PARTS
+ROW_PARTS = ("ssm", "conv", shortconv.PART)
+SLOT_PARTS = ("ret", "retn") + mamba1.PARTS
 # The chunk of a retention layer where no page size says it (the
 # whole-sequence forward): the serving paths chunk by the page.
 RETENTION_CHUNK = 128
@@ -119,11 +122,9 @@ def _split_xbc(xbc: jax.Array, cfg: ModelConfig):
 def _conv_taps(rows: list, layer: Params) -> jax.Array:
     """silu(sum_k w[k] * rows[k] + b): rows[k] is the input K-1-k tokens
     back (rows[-1] the token itself), float32."""
-    w = layer["conv_w"].astype(jnp.float32)              # [K, C]
-    acc = layer["conv_b"].astype(jnp.float32)
-    for k, r in enumerate(rows):
-        acc = acc + w[k] * r
-    return jax.nn.silu(acc)
+    return jax.nn.silu(shortconv.taps_sum(
+        rows, layer["conv_w"].astype(jnp.float32),       # [K, C]
+        layer["conv_b"].astype(jnp.float32)))
 
 
 def _dt_of(dt_raw: jax.Array, layer: Params) -> jax.Array:
@@ -200,15 +201,6 @@ def _ssd_chunk(x, dt, a_neg, bm, cm, d_skip, s_in, cap_idx=None):
     return y, s_out, s_cap
 
 
-def _tail_rows(ext: jax.Array, lengths: jax.Array, k1: int) -> jax.Array:
-    """Rows [len, len + K-1) of ext = [old tail (K-1 rows); the run's
-    rows]: the last K-1 inputs of a run of `lengths` tokens.
-    ext [B, K-1+T, C], lengths [B] -> [B, K-1, C]."""
-    idx = jnp.clip(lengths[:, None] + jnp.arange(k1)[None, :], 0,
-                   ext.shape[1] - 1)
-    return jnp.take_along_axis(ext, idx[:, :, None], axis=1)
-
-
 def mamba2_prefill(h: jax.Array, layer: Params, cfg: ModelConfig,
                    ssm0: jax.Array, conv0: jax.Array,
                    lengths: jax.Array,
@@ -264,10 +256,10 @@ def mamba2_prefill(h: jax.Array, layer: Params, cfg: ModelConfig,
         (jnp.arange(n_c), chunks(x), chunks(dt), chunks(bm), chunks(cm)))
     y = jnp.moveaxis(ys, 0, 1).reshape(b_, n_c * q, *ys.shape[3:])[:, :t]
     out = _gated_out(y, z, layer, cfg, h.dtype)
-    conv = _tail_rows(ext, lengths, k1)
+    conv = shortconv.tail_rows(ext, lengths, k1)
     if not want_cap:
         return out, ssm, conv
-    return out, ssm, conv, ssm_cap, _tail_rows(ext, cap_len, k1)
+    return out, ssm, conv, ssm_cap, shortconv.tail_rows(ext, cap_len, k1)
 
 
 def mamba2_step(h: jax.Array, layer: Params, cfg: ModelConfig,
@@ -592,6 +584,14 @@ RETENTION_SHARE, RETENTION_GROWTH = 3.0, 4.5
 # scale, so that the mixers carry the stream from the first one on and
 # it stays of order one through the published 56 (about 1 / sqrt(56)).
 TIED_EMBED_STD, MAMBA1_SHARE = 0.02, 0.134
+# A model with gated short-convolution layers (`lfm2_moe`: tied head AND
+# routed experts): the out-projections of its conv mixers, attention
+# layers and dense MLPs at SHORTCONV_SHARE of unit scale — those mixers
+# carry the stream, so the tied embedding's share of it is small (a
+# token's own logit stands under a sigma up after a dozen of them) —
+# while the experts' stay at RESIDUAL_SHARE: one expert changed by
+# rounding in the router then moves the stream by a twentieth of its rms.
+SHORTCONV_SHARE = 0.3
 # The seeded gate of a retention layer (init_layer): the embedding
 # channel held at 1.0 (no out-projection writes to it), W_g's row there
 # over the kv heads, and the scale of its other rows (of unit scale).
@@ -616,6 +616,8 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
     def out(key, shape, fan_in):
         if cfg.mamba1_layers:
             return dense(key, shape, fan_in, MAMBA1_SHARE)
+        if cfg.shortconv_layers:
+            return dense(key, shape, fan_in, SHORTCONV_SHARE)
         if not cfg.retention_layers:
             return dense(key, shape, fan_in, RESIDUAL_SHARE)
         return dense(key, shape, fan_in, RETENTION_SHARE).at[
@@ -644,6 +646,8 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
         })
     elif kind == MAMBA1:
         layer.update(mamba1.init_mixer(cfg, ks, dense, out, dtype))
+    elif kind == SHORTCONV:
+        layer.update(shortconv.init_mixer(cfg, ks, dense, out))
     elif kind == EXPERTS:
         f, fs, held = cfg.expert_dim, cfg.shared_expert_dim, \
             cfg.experts_held
@@ -717,19 +721,24 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
         })
         if cfg.attn_gate:
             layer["g_proj"] = dense(ks[4], (e, h_), e)
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((d,), dtype)
+            layer["k_norm"] = jnp.ones((d,), dtype)
     else:
         raise ValueError(f"unknown layer kind {kind!r}")
     return layer
 
 
-def zero_state(cfg: ModelConfig, rows: int) -> dict:
+def zero_state(cfg: ModelConfig, rows: int, dtype=jnp.bfloat16) -> dict:
     """The recurrent state of `rows` sequences, float32, one entry a
     layer that keeps one (Mamba-1: one a scanned RUN, models/mamba1.py:
     {"ssm1": [[rows,L,N,G,W]...], "conv1": [[rows,L,K-1,G,W]...]}).
     Mamba-2: {"ssm": [[rows,H,P,N]...], "conv":
     [[rows,K-1,C]...]}; retention (models/retention.py), where the
     model has such layers: {"ret": [[rows,K,D/2+1,D,D]...], "retn":
-    [[rows,K,D/2+1,D]...]}."""
+    [[rows,K,D/2+1,D]...]}; gated short convolution
+    (models/shortconv.py): {"sconv": [[rows,K-1,E]...]} in `dtype`, the
+    activations' (every other part is float32 whatever it is)."""
     n = len(cfg.mamba_layers)
     return {
         "ssm": [jnp.zeros((rows, cfg.mamba_heads, cfg.mamba_head_dim,
@@ -741,15 +750,18 @@ def zero_state(cfg: ModelConfig, rows: int) -> dict:
         **(retention.zero_state(cfg, rows) if cfg.retention_layers
            else {}),
         **(mamba1.zero_state(cfg, rows) if cfg.mamba1_layers else {}),
+        **(shortconv.zero_state(cfg, rows, dtype) if cfg.shortconv_layers
+           else {}),
     }
 
 
-def state_bytes_per_sequence(cfg: ModelConfig) -> int:
+def state_bytes_per_sequence(cfg: ModelConfig, dtype=jnp.bfloat16) -> int:
     per = (cfg.mamba_heads * cfg.mamba_head_dim * cfg.ssm_state
            + (cfg.conv_kernel - 1) * cfg.mamba_conv_dim) * 4
     return per * len(cfg.mamba_layers) \
         + retention.bytes_per_state(cfg) * len(cfg.retention_layers) \
-        + mamba1.bytes_per_state(cfg) * len(cfg.mamba1_layers)
+        + mamba1.bytes_per_state(cfg) * len(cfg.mamba1_layers) \
+        + shortconv.bytes_per_state(cfg, dtype) * len(cfg.shortconv_layers)
 
 
 def layers_unrolled(cfg: ModelConfig, params: Params):

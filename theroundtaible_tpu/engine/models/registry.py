@@ -27,7 +27,7 @@ from typing import Any
 
 from .common import AttnLayer, ModelConfig
 from .hybrid import (ATTENTION, EXPERTS, MAMBA1, MLP, RETENTION,
-                     kinds_of_pattern)
+                     SHORTCONV, kinds_of_pattern)
 
 _REGISTRY: dict[str, ModelConfig] = {}
 
@@ -357,6 +357,56 @@ TINY_JAMBA = register(_jamba_config(
     embed_dim=64, num_heads=4, num_kv_heads=1, head_dim=16, mlp_dim=128,
     max_seq_len=512, norm_eps=1e-6, mamba1_dim=128, ssm_state=8,
     conv_kernel=4, dt_rank=4))
+
+
+# --- LFM2 / lfm2_moe (a published layer is TWO layers here: the operator
+# — a gated short convolution, models/shortconv.py, or grouped-query
+# attention with q and k normed a head ahead of plain rotary — then a
+# dense SwiGLU in the leading layers and sigmoid-routed gated experts
+# with a selection bias and NO shared expert after them; tied head) ---
+
+LFM2_OPERATORS = {"conv": SHORTCONV, "full_attention": ATTENTION}
+
+
+def lfm2_kinds(layer_types, dense_layers: int) -> tuple[str, ...]:
+    unknown = sorted(set(layer_types) - set(LFM2_OPERATORS))
+    if unknown:
+        raise ValueError(f"layer_types {unknown}: known are "
+                         f"{', '.join(LFM2_OPERATORS)}")
+    return tuple(k for i, lt in enumerate(layer_types)
+                 for k in (LFM2_OPERATORS[lt],
+                           MLP if i < dense_layers else EXPERTS))
+
+
+def _lfm2_config(name, layer_types, *, dense_layers, experts, **kw):
+    return ModelConfig(
+        name=name, num_layers=2 * len(layer_types), tie_embeddings=True,
+        layer_kinds=lfm2_kinds(layer_types, dense_layers), qk_norm=True,
+        routed_experts=experts, experts_held=experts,
+        router_rule="sigmoid_bias_topk", expert_act="silu",
+        expert_gated=True, **kw)
+
+
+def _lfm2_types(blocks: int) -> list[str]:
+    """conv conv, then `attention conv conv conv` repeated."""
+    return ["full_attention" if b % 4 == 2 else "conv"
+            for b in range(blocks)]
+
+
+LFM2_24B_A2B = register(_lfm2_config(
+    "lfm2-24b-a2b", _lfm2_types(40), dense_layers=2, experts=64,
+    vocab_size=65_536, embed_dim=2048, num_heads=32, num_kv_heads=8,
+    head_dim=64, mlp_dim=11_776, max_seq_len=8192,
+    rope_theta=1_000_000.0, norm_eps=1e-5, conv_kernel=3, moe_top_k=4,
+    expert_dim=1536))
+
+# Both dense layers and one period: group 2 over 4 kv heads of 64, so
+# that the pool packs two heads a lane row as the published widths do.
+TINY_LFM2 = register(_lfm2_config(
+    "tiny-lfm2", _lfm2_types(6), dense_layers=2, experts=8,
+    vocab_size=512, embed_dim=64, num_heads=8, num_kv_heads=4,
+    head_dim=64, mlp_dim=128, max_seq_len=512, rope_theta=1_000_000.0,
+    norm_eps=1e-5, conv_kernel=3, moe_top_k=2, expert_dim=32))
 
 
 # --- from a published config.json -------------------------------------------
@@ -768,6 +818,63 @@ def _jamba(name: str, arch: dict[str, Any],
     return cfg
 
 
+# Keys of an lfm2_moe config.json that say nothing this engine acts on,
+# and the values its layer equations assume. `head_dim` and
+# `tie_word_embeddings` are not the model's keys: a configuration file
+# may state them beside the published ones.
+_LFM2_INERT = {"model_type", "max_position_embeddings"}
+_LFM2_FIXED = {
+    "conv_bias": False, "norm_topk_prob": True, "use_expert_bias": True,
+    "tie_word_embeddings": True}
+
+
+def _lfm2_moe(name: str, arch: dict[str, Any],
+              max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "lfm2_moe", _LFM2_FIXED, _LFM2_INERT)
+    try:
+        e, heads = int(arch.pop("hidden_size")), \
+            int(arch.pop("num_attention_heads"))
+        types = list(arch.pop("layer_types"))
+        n_blocks = int(arch.pop("num_hidden_layers"))
+        if len(types) != n_blocks:
+            raise ValueError(
+                f"architecture of {name!r}: layer_types has {len(types)} "
+                f"entries, num_hidden_layers says {n_blocks}")
+        rotary = dict(arch.pop("rope_parameters"))
+        if rotary.pop("rope_type", "default") != "default":
+            raise ValueError(
+                f"architecture of {name!r}: this engine's lfm2_moe layers "
+                "are written for plain rotary (rope_type default)")
+        theta = float(rotary.pop("rope_theta"))
+        if rotary:
+            raise ValueError(f"architecture of {name!r}: unknown keys "
+                             f"{sorted(rotary)} in rope_parameters")
+        cfg = _lfm2_config(
+            name, types, dense_layers=int(arch.pop("num_dense_layers")),
+            experts=int(arch.pop("num_experts")),
+            vocab_size=int(arch.pop("vocab_size")), embed_dim=e,
+            num_heads=heads,
+            num_kv_heads=int(arch.pop("num_key_value_heads")),
+            head_dim=int(arch.pop("head_dim", e // heads)),
+            mlp_dim=int(arch.pop("intermediate_size")),
+            max_seq_len=max_seq_len, rope_theta=theta,
+            norm_eps=float(arch.pop("norm_eps")),
+            conv_kernel=int(arch.pop("conv_L_cache")),
+            moe_top_k=int(arch.pop("num_experts_per_tok")),
+            expert_dim=int(arch.pop("moe_intermediate_size")),
+            routed_scaling=float(arch.pop("routed_scaling_factor")))
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    _all_read(name, arch, "lfm2_moe")
+    if cfg.num_heads % cfg.num_kv_heads or cfg.head_dim % 2:
+        raise ValueError(
+            f"architecture of {name!r}: {cfg.num_heads} query heads over "
+            f"{cfg.num_kv_heads} kv heads of {cfg.head_dim} are not whole "
+            "groups of even heads")
+    return cfg
+
+
 def _dense_gqa(name: str, arch: dict[str, Any],
                max_seq_len: int) -> ModelConfig:
     heads = int(arch["num_attention_heads"])
@@ -813,6 +920,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
         return _brumby(name, arch, max_seq_len)
     if kind == "jamba":
         return _jamba(name, arch, max_seq_len)
+    if kind == "lfm2_moe":
+        return _lfm2_moe(name, arch, max_seq_len)
     if kind in _DENSE_TYPES:
         try:
             return _dense_gqa(name, arch, max_seq_len)
@@ -822,7 +931,7 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
     raise ValueError(
         f"architecture of {name!r}: model_type {kind!r} is not one this "
         f"engine runs (nemotron_h, axk1, laguna, mellum, brumby, jamba, "
-        f"{', '.join(_DENSE_TYPES)})")
+        f"lfm2_moe, {', '.join(_DENSE_TYPES)})")
 
 
 def get_model_config(name: str, **overrides) -> ModelConfig:

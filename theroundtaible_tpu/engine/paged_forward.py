@@ -48,6 +48,14 @@ from .models.common import (MASK_VALUE, ModelConfig, Params, _einsum,
 from .pallas import attention as pattn
 
 
+def _cells(entries: jax.Array, pool: jax.Array) -> jax.Array:
+    """A layer's keys or values [..., K, D] as the pool's cells: the same
+    bytes where the pool holds two heads a lane row ([..., K / 2, 2 D]:
+    ModelConfig.lane_pack; a latent entry [..., W] as it is)."""
+    lead = entries.ndim - (pool.ndim - 2)
+    return entries.reshape(entries.shape[:lead] + pool.shape[2:])
+
+
 def forward_paged(
     params: Params, cfg: ModelConfig,
     tokens: jax.Array,            # [B, T] token ids (T==1: decode step)
@@ -116,8 +124,8 @@ def forward_paged(
                 k_sc2 = k_sc.at[pages, offs].set(k_s)
                 v_sc2 = v_sc.at[pages, offs].set(v_s)
             else:
-                k_pool2 = k_pool.at[pages, offs].set(k)
-                v_pool2 = v_pool.at[pages, offs].set(v)
+                k_pool2 = k_pool.at[pages, offs].set(_cells(k, k_pool))
+                v_pool2 = v_pool.at[pages, offs].set(_cells(v, v_pool))
                 k_sc2 = v_sc2 = None
             if quant and not kernel_quant:
                 # Declined shape: dequantize the pool for a bf16 kernel
@@ -209,7 +217,11 @@ def _ragged_xla_attention(q, k_pool, v_pool, tables, token_seq,
     the gather (kv_quant.dequantize_cells — identical math to the
     in-kernel dequant, so kernel and fallback agree)."""
     t, h, d = q.shape
-    page_size, kh = k_pool.shape[1], pattn._pool_heads(k_pool)
+    page_size = k_pool.shape[1]
+    # (the kv heads of the model, whatever rows an unquantized pool's
+    # cell has: ModelConfig.lane_pack)
+    kh = (1 if v_pool is None else k_pool.shape[2] if k_sc is not None
+          else k_pool.shape[2] * k_pool.shape[3] // d)
     s, pp = tables.shape
     length = pp * page_size
     if v_pool is None:
@@ -333,8 +345,10 @@ def forward_ragged(
                 k_sc2 = k_sc.at[token_pages, token_offs].set(k_s)
                 v_sc2 = v_sc.at[token_pages, token_offs].set(v_s)
             else:
-                k_pool2 = k_pool.at[token_pages, token_offs].set(k[0])
-                v_pool2 = v_pool.at[token_pages, token_offs].set(v[0])
+                k_pool2 = k_pool.at[token_pages, token_offs].set(
+                    _cells(k[0], k_pool))
+                v_pool2 = v_pool.at[token_pages, token_offs].set(
+                    _cells(v[0], v_pool))
                 k_sc2 = v_sc2 = None
             if attn_path == "kernel":
                 mesh = current_spmd_mesh()
@@ -409,7 +423,8 @@ def _state_lists(state: dict) -> dict:
     """Every part the layers may advance, as a list they assign into
     (a part the model's layers do not keep: empty)."""
     return {p: list(state.get(p, ()))
-            for p in ("ssm", "conv", "ret", "retn", "ssm1", "conv1")}
+            for p in ("ssm", "conv", "ret", "retn", "ssm1", "conv1",
+                      "sconv")}
 
 
 def _scan_run(x, run, kinds, cfg: ModelConfig, ssm, conv, held, mixer):
@@ -486,7 +501,8 @@ def forward_paged_hybrid(
     layers scatter into their own pools and attend through the same
     page-table kernels; Mamba-2 layers advance the rows' recurrent
     `state` (batch-row order: the program gathers and scatters the
-    slot rows); retention layers (models/retention.py) and the scanned
+    slot rows), as gated short-convolution layers (models/shortconv.py)
+    advance their tails; retention layers (models/retention.py) and the scanned
     runs of Mamba-1 layers (models/mamba1.py) advance theirs IN PLACE on
     every slot's array (`state["ret"]`, `["retn"]`; `["ssm1"]`,
     `["conv1"]`, a leaf a run: whole, addressed by `rows`) and write a
@@ -500,7 +516,7 @@ def forward_paged_hybrid(
     assignments to held experts over the counted tokens, rows the
     grouped products multiplied and rows a loop over every held expert
     would have, expert-layer steps."""
-    from .models import hybrid, mamba1, retention
+    from .models import hybrid, mamba1, retention, shortconv
     if pools:
         page_size = pools[0][0].shape[1]
     b, t = tokens.shape
@@ -517,7 +533,7 @@ def forward_paged_hybrid(
     cap = {p: [] for p in state} if cap_len is not None else None
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = ri = si = 0
+    ai = mi = ri = si = ci = 0
     for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
         kind = kinds[0]
         if kind == hybrid.MAMBA1:
@@ -571,6 +587,18 @@ def forward_paged_hybrid(
                 cap["ssm"].append(s_cap)
                 cap["conv"].append(c_cap)
             mi += 1
+        elif kind == hybrid.SHORTCONV:
+            tails = st["sconv"]
+            if decode:
+                out, tails[ci] = shortconv.shortconv_step(
+                    h, layer, cfg, tails[ci], active)
+            else:
+                # (with `cap_len`, also the tail after that many tokens)
+                out, tails[ci], *t_cap = shortconv.shortconv_prefill(
+                    h, layer, cfg, tails[ci], lengths, cap_len)
+                if cap is not None:
+                    cap["sconv"] += t_cap
+            ci += 1
         elif kind == hybrid.EXPERTS:
             out, c = hybrid.experts_mlp(h, layer, cfg, counted)
             counts = counts + hybrid.step_counts(c, jnp.any(counted))
@@ -582,7 +610,7 @@ def forward_paged_hybrid(
             lcfg = cfg.attention_layer(ai)
             q, entries, kw = _attention_io(h, layer, lcfg, positions,
                                            pools[ai][0].dtype)
-            layer_pools = tuple(p.at[pages, offs].set(e)
+            layer_pools = tuple(p.at[pages, offs].set(_cells(e, p))
                                 for p, e in zip(pools[ai], entries))
             k_pool, v_pool = (layer_pools + (None,))[:2]
             if t == 1:
@@ -633,7 +661,7 @@ def forward_ragged_hybrid(
     layers the ragged page-table kernel. ->
     (logits [S, V], new_pools, new_state, captured {"ssm": [[S,...]..],
     "conv": .., "ret" / "retn": the store's arrays}, counts)."""
-    from .models import hybrid, mamba1, retention
+    from .models import hybrid, mamba1, retention, shortconv
     from .serving_loop import RAGGED_BLOCK_Q
     if pools:
         page_size = pools[0][0].shape[1]
@@ -649,7 +677,7 @@ def forward_ragged_hybrid(
     cap = {p: [] for p in state}
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = ri = si = 0
+    ai = mi = ri = si = ci = 0
     for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
         kind = kinds[0]
         if kind == hybrid.MAMBA1:
@@ -677,6 +705,11 @@ def forward_ragged_hybrid(
             cap["ssm"].append(s_cap)
             cap["conv"].append(c_cap)
             mi += 1
+        elif kind == hybrid.SHORTCONV:
+            out, st["sconv"][ci], t_cap = shortconv.shortconv_ragged(
+                h, layer, cfg, st["sconv"][ci], rg)
+            cap["sconv"].append(t_cap)
+            ci += 1
         elif kind == hybrid.EXPERTS:
             out, c = hybrid.experts_mlp(h, layer, cfg, counted)
             counts = counts + hybrid.step_counts(c, 1)
@@ -687,7 +720,7 @@ def forward_ragged_hybrid(
             q, entries, kw = _attention_io(h, layer, lcfg, pos2,
                                            pools[ai][0].dtype)
             layer_pools = tuple(
-                p.at[token_pages, token_offs].set(e[0])
+                p.at[token_pages, token_offs].set(_cells(e[0], p))
                 for p, e in zip(pools[ai], entries))        # e [1,T,...]
             k_pool, v_pool = (layer_pools + (None,))[:2]
             if attn_path == "kernel":

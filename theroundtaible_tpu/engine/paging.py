@@ -198,9 +198,11 @@ class PagedKVCache:
                 f"replica ({self.pages_per_seq} pages + scratch)")
         if kv_quant is None:
             latent = cfg.latent
+            # (page_heads x page_width: [K, D], or two 64-wide heads a
+            # lane row — ModelConfig.lane_pack)
             shape = ((self.num_pages, page_size, cfg.page_width) if latent
-                     else (self.num_pages, page_size, cfg.num_kv_heads,
-                           cfg.head_dim))
+                     else (self.num_pages, page_size, cfg.page_heads,
+                           cfg.page_width))
             make = (lambda: jnp.zeros(shape, dtype)) if sharding is None \
                 else (lambda: jax.device_put(jnp.zeros(shape, dtype),
                                              sharding))

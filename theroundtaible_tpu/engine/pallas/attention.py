@@ -85,6 +85,97 @@ def _pool_heads(pool) -> int:
     return pool.shape[2] if pool.ndim == 4 else 1
 
 
+# --- heads narrower than a lane row ---
+#
+# XLA tiles a bfloat16 pool [P, ps, K, D] (K, 128): at D = 64 every row of
+# a page is padded to 128 lanes in HBM and the pool is twice its bytes.
+# Such a pool is stored PACKED instead, `lane_pack` heads of one token
+# side by side in a whole lane row: [P, ps, K / f, f * D] (a free reshape
+# of what a layer writes: [.., K, D] -> [.., K / f, f * D]). To every
+# kernel below that IS a pool of K / f heads of f * D, and they serve it
+# unchanged — the wrappers hand them queries that carry their own head's
+# D values in its part of the row and zeros in the rest (`_pack_queries`),
+# so a score is the product with the own head alone, the softmax is the
+# own head's, and of the weighted sum's f * D columns the own head's part
+# is kept (`_own_part`). The group grows f times (the q heads of f kv
+# heads share a row), the MXU multiplies f times the columns, and the
+# bytes a walk copies are the heads' own.
+
+
+def lane_pack(kh: int, d: int) -> int:
+    """Heads of one token that share a 128-lane row of a pool: 2 for
+    64-wide heads that come in pairs, else 1 (the plain [K, D] cell)."""
+    return 2 if 2 * d == _LANES and kh % 2 == 0 else 1
+
+
+def _packed(d: int, k_pool, v_pool, k_scale) -> int:
+    """`lane_pack` of a pool as it is handed over with queries of `d`:
+    the pool's rows are f * d wide."""
+    if v_pool is None or k_scale is not None or k_pool.shape[-1] == d:
+        return 1
+    f, rest = divmod(k_pool.shape[-1], d)
+    if rest or f * d != _LANES:
+        raise ValueError(f"a pool of rows {k_pool.shape[-1]} wide cannot "
+                         f"hold heads of {d}")
+    return f
+
+
+def _pack_parts(h: int, rows: int, f: int):
+    """[H, f] bool: the part of its pool row query head h's kv head lies
+    in (`rows` pool rows a token: kv head k is part k % f of row
+    k // f)."""
+    part = (jnp.arange(h) // (h // (rows * f))) % f
+    return part[:, None] == jnp.arange(f)[None, :]
+
+
+def _pack_queries(q: jax.Array, f: int, rows: int) -> jax.Array:
+    """q [..., H, D] -> [..., H, f * D]: each head's values in its own
+    part of the row, zeros in the rest."""
+    own = _pack_parts(q.shape[-2], rows, f)[..., None]     # [H, f, 1]
+    return jnp.where(own, q[..., None, :], jnp.zeros((), q.dtype)) \
+        .reshape(*q.shape[:-1], f * q.shape[-1])
+
+
+def _own_part(out: jax.Array, f: int, rows: int) -> jax.Array:
+    """out [..., H, f * D] -> [..., H, D]: each head's own part."""
+    h = out.shape[-2]
+    parts = out.reshape(*out.shape[:-1], f, out.shape[-1] // f)
+    own = _pack_parts(h, rows, f)[..., None]
+    return jnp.sum(jnp.where(own, parts, jnp.zeros((), out.dtype)),
+                   axis=-2)
+
+
+def _cache_packed(kernel, q, k, v, *args, **kw):
+    """`kernel(q, k, v, ...)` over a position-aligned cache [B, S, K, D]
+    of heads that pack: the cache viewed [B, S, K / f, f * D] (the
+    kernels transpose it anyway), for the chip's sake alone."""
+    f = lane_pack(k.shape[2], q.shape[-1])
+    rows = k.shape[2] // f
+    view = (k.shape[0], k.shape[1], rows, f * k.shape[3])
+    return _own_part(kernel(_pack_queries(q, f, rows), k.reshape(view),
+                            v.reshape(view), *args, **kw), f, rows)
+
+
+def _on_packed(kernel, q, k_pool, v_pool, *args, **kw):
+    """`kernel(q, k_pool, v_pool, ...)` over a packed pool, or None
+    where the pool holds plain [K, D] cells (which the chip's kernels do
+    not take at a width that packs: the rows would be padded)."""
+    d, scaled, interpret = q.shape[-1], kw.get("k_scale"), kw.get("interpret")
+    f = _packed(d, k_pool, v_pool, scaled)
+    if f > 1:
+        rows = k_pool.shape[-2]
+        return _own_part(kernel(_pack_queries(q, f, rows), k_pool, v_pool,
+                                *args, **kw), f, rows)
+    if (v_pool is not None and scaled is None
+            and lane_pack(k_pool.shape[-2], d) > 1
+            and not (_interpret() if interpret is None else interpret)):
+        raise ValueError(
+            f"a pool of {d}-wide heads is stored "
+            f"{lane_pack(k_pool.shape[-2], d)} heads a lane row "
+            "(pallas/attention.py: lane_pack)")
+    return None
+
+
 def _page_block(k_ref, v_ref, khi: int, v_dim: Optional[int]):
     """(keys, values) of kv head `khi` from one page's block refs: a
     latent page [1, ps, W] (`v_dim`; no v ref) is keys and, in its first
@@ -114,14 +205,15 @@ def spmd_partitionable(num_heads: int, num_kv_heads: int,
     return num_kv_heads % n_model == 0 or num_kv_heads == 1
 
 
-def supported(t: int, s: int, d: int) -> bool:
-    """Can the kernels serve these shapes? (TPU wants lane-aligned D; any
-    shape goes in interpret mode.)"""
+def supported(t: int, s: int, d: int, kh: int = 1) -> bool:
+    """Can the kernels serve these shapes? (TPU wants lane-aligned D, or
+    heads that pair up into lane rows — `lane_pack`; any shape goes in
+    interpret mode.)"""
     if _pick_block(s, (512, 256, 128, 64, 32, 16, 8)) is None:
         return False
     if t > 1 and _pick_block(t, (128, 64, 32, 16, 8)) is None:
         return False
-    if not _interpret() and d % 128 != 0:
+    if not _interpret() and d % 128 != 0 and lane_pack(kh, d) == 1:
         return False
     return True
 
@@ -294,6 +386,11 @@ def flash_prefill_attention(
     if block_q is None or block_kv is None:
         raise ValueError(f"unsupported shapes T={t} S={s}")
     interpret = _interpret() if interpret is None else interpret
+    if not interpret and lane_pack(kh, d) > 1:
+        return _cache_packed(
+            flash_prefill_attention, q, k, v, offsets, kv_valid,
+            sliding_window=sliding_window, softcap=softcap,
+            interpret=interpret)
 
     # [B, T, H, D] → [B, K, G, T, D]: q heads grouped by their kv head
     # (head kh*G+g shares kv head kh, matching the dense path's repeat)
@@ -469,6 +566,13 @@ def paged_prefill_attention(
     `v_pool=None` with `v_dim` (a latent pool [P, ps, W], _pool_heads):
     one kv head whose values are the first `v_dim` columns of its keys;
     each page is copied once and the result is [B, T, H, v_dim]."""
+    packed = _on_packed(
+        paged_prefill_attention, q, k_pool, v_pool, table, offsets,
+        kv_valid, sliding_window=sliding_window, softcap=softcap,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+        kv_bits=kv_bits, v_dim=v_dim)
+    if packed is not None:
+        return packed
     b, t, h, d = q.shape
     page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
     latent = v_pool is None
@@ -664,7 +768,7 @@ def flash_attention_spmd(
     b, t, h, d = q.shape
     s, kh = k.shape[1], k.shape[2]
     axes_t = _spmd_axes(mesh, h, kh, b)
-    if axes_t is None or not supported(t, s, d):
+    if axes_t is None or not supported(t, s, d, kh):
         return None
     batch_ax, head_ax, kv_head_ax = axes_t
 
@@ -937,11 +1041,18 @@ def paged_decode_decline_reason(page_size: int, d: int, kh: int = 1,
     pools carry `scale_groups` groups a cell). Page size must be a
     legal block for the prefill kernels that share the pool; one page a
     trip must fit the VMEM budget; and on the chip (any shape goes in
-    interpret mode) D must be lane-aligned, and a quantized pool's
-    pages must fill whole lane rows of scales (a scale page is K*G rows
-    of ps lanes). Every head count is served: see _token_major."""
+    interpret mode) D must be lane-aligned — or 64 with the kv heads in
+    pairs, which an unquantized pool stores two heads a lane row
+    (`lane_pack`; asked about (K, D) it answers for that cell) — and a
+    quantized pool's pages must fill whole lane rows of scales (a scale
+    page is K*G rows of ps lanes). Every head count is served: see
+    _token_major."""
     if page_size not in (512, 256, 128, 64, 32, 16, 8):
         return f"page_size:{page_size}"
+    if dk is None and not latent and not scale_groups:
+        # (the cell such heads are stored in: lane_pack)
+        f = lane_pack(kh, d)
+        d, kh, group = d * f, kh // f, group * f
     if _walk_pages(page_size, d, kh, group, dk, itemsize,
                    scale_groups, latent) is None:
         return f"vmem:ps={page_size},d={d},kh={kh},g={group}"
@@ -1248,6 +1359,13 @@ def paged_decode_attention(
     (_pool_heads) — one pool walks, each page is copied once, and the
     result is [B, 1, H, v_dim].
     """
+    packed = _on_packed(
+        paged_decode_attention, q, k_pool, v_pool, table, kv_valid,
+        sliding_window=sliding_window, softcap=softcap,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+        kv_bits=kv_bits, v_dim=v_dim)
+    if packed is not None:
+        return packed
     b, t, h, d = q.shape
     assert t == 1, "decode kernel serves exactly one position"
     page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
@@ -1423,9 +1541,14 @@ def ragged_decline_reason(page_size: int, d: int, kh: int = 1,
     `itemsize` a page cell's. The VMEM estimate is the walk's own
     (_ragged_vmem_est) at the packing's 8-row block, the smallest query
     block it can take — or, for a `quantized` pool, the grid kernel's
-    (_paged_vmem_est)."""
+    (_paged_vmem_est). Asked about 64-wide heads in pairs it answers for
+    the cell an unquantized pool stores them in (`lane_pack`)."""
     if page_size not in (512, 256, 128, 64, 32, 16, 8):
         return f"page_size:{page_size}"
+    if dk is None and not latent and not quantized:
+        # (the cell such heads are stored in: lane_pack)
+        f = lane_pack(kh, d)
+        d, kh, group = d * f, kh // f, group * f
     if quantized:
         fits = _paged_vmem_est(page_size, d, kh, group,
                                RAGGED_BLOCK_Q) <= _VMEM_BUDGET
@@ -2000,6 +2123,14 @@ def ragged_paged_attention(
     `v_pool=None` with `v_dim`: a latent pool (paged_prefill_attention),
     the result [T, H, v_dim].
     """
+    packed = _on_packed(
+        ragged_paged_attention, q, k_pool, v_pool, tables, seq_of_block,
+        block_qstart, query_offsets, kv_valid,
+        sliding_window=sliding_window, softcap=softcap,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+        kv_bits=kv_bits, v_dim=v_dim)
+    if packed is not None:
+        return packed
     t, h, d = q.shape
     page_size, kh = k_pool.shape[1], _pool_heads(k_pool)
     latent = v_pool is None
@@ -2216,6 +2347,11 @@ def ragged_decode_attention(
     if block_kv is None:
         raise ValueError(f"unsupported cache length S={s}")
     interpret = _interpret() if interpret is None else interpret
+    if not interpret and lane_pack(kh, d) > 1:
+        return _cache_packed(
+            ragged_decode_attention, q, k, v, kv_valid,
+            sliding_window=sliding_window, softcap=softcap,
+            interpret=interpret)
 
     # [B, 1, H, D] → [B, K, G, D]: rows of one kv-head's query group
     qt = q[:, 0].reshape(b, kh, group, d)

@@ -384,6 +384,7 @@ class SessionScheduler:
         self.ragged_segments = 0
         self._snaps_seen = 0        # hybrid: snapshots at the last span's end
         self._scan_seen = 0         # ... and Mamba-1 tokens x layers scanned
+        self._conv_seen = 0         # ... and short-conv tokens x layers run
         self._hy_counting = False   # ... and whether that span was armed
         self.ragged_joins = 0
         # N-gram prompt indices by where they were built (ISSUE 30):
@@ -1766,7 +1767,7 @@ class SessionScheduler:
             # the segment after it) and the snapshot store now. The
             # first span after arming only sets the base.
             delta, taken = hy.moe_delta(), hy.snapshots_taken
-            scanned = hy.scan_tokens
+            scanned, conved = hy.scan_tokens, hy.conv_tokens
             if self._hy_counting:
                 seg.attrs.update(
                     delta, snapshots_taken=taken - self._snaps_seen,
@@ -1774,8 +1775,10 @@ class SessionScheduler:
                     * hy.bytes_per_state)
                 if self.engine.cfg.mamba1_layers:
                     seg.attrs["scan_tokens"] = scanned - self._scan_seen
+                if self.engine.cfg.shortconv_layers:
+                    seg.attrs["conv_tokens"] = conved - self._conv_seen
             self._hy_counting, self._snaps_seen = True, taken
-            self._scan_seen = scanned
+            self._scan_seen, self._conv_seen = scanned, conved
             seg.attrs["snapshot_bytes"] = hy.snapshot_bytes()
         seg.end()
 

@@ -1224,6 +1224,19 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "scan_runs": "static (lengths of the scanned runs)",
         "scan_tokens": "roundtable_mamba1_scan_tokens_total",
     },
+    # engine.describe()["shortconv"] (ISSUE 52): the gated
+    # short-convolution layers of a model that has them
+    # (models/shortconv.py) and the tokens the join programs ran through
+    # them (HybridStateStore.note_scan is the one writer; a `segment`
+    # span carries `conv_tokens` for the programs it covers).
+    "engine_shortconv": {
+        "layers": "static (short-convolution layers)",
+        "channels": "static (model sizes)",
+        "taps": "static (model sizes)",
+        "bytes_per_state": "static (a layer's tail: taps - 1 rows)",
+        "state_dtype": "static (the dtype the tail is kept in)",
+        "conv_tokens": "roundtable_shortconv_tokens_total",
+    },
     # engine.describe()["moe"] (ISSUE 27): the chip's share of the
     # routed experts and what the steps touched (each step program
     # returns its counts; HybridStateStore.fold_counts adds them to
