@@ -1172,21 +1172,13 @@ def test_one_kv_head_at_group_20_compiles_for_v5e(one_chip, kernel):
     _assert_kernel(hlo)
 
 
-def test_whole_jamba_decode_step_compiles_with_its_runs_scanned(
-        one_chip, monkeypatch):
-    """Four decode steps in a loop of the WHOLE model at published
-    widths — 28 published layers, 3.03 G parameters — pools, slot states
-    donated. Its Mamba-1 blocks run as THREE `lax.scan`s over stacked
-    parameters (runs of 7, 13 and 6): the program holds four loops (the
-    steps' and one a run) whatever the depth, aliases pools and states,
-    and keeps no copy of a run's state beside them."""
-    import re
-
+def _whole_jamba(one_chip, monkeypatch):
+    """-> (cfg, params, state, pools, s): the WHOLE model at published
+    widths as shapes on the described chip, kernels not interpreted."""
     from theroundtaible_tpu.engine.models import hybrid
     from theroundtaible_tpu.engine.models.common import init_params
     from theroundtaible_tpu.engine.models.registry import get_model_config
     from theroundtaible_tpu.engine.pallas import mamba1 as m1
-    from theroundtaible_tpu.engine.paged_forward import forward_paged_hybrid
 
     monkeypatch.setattr(pattn, "_interpret", lambda: False)
     monkeypatch.setattr(m1, "_interpret", lambda: False)
@@ -1200,7 +1192,6 @@ def test_whole_jamba_decode_step_compiles_with_its_runs_scanned(
                                            sharding=one_chip), tree)
 
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    i32 = jnp.int32
     params = placed(jax.eval_shape(
         lambda k: init_params(cfg, k, jnp.bfloat16),
         jax.random.PRNGKey(0)))
@@ -1208,6 +1199,23 @@ def test_whole_jamba_decode_step_compiles_with_its_runs_scanned(
         lambda: hybrid.zero_state(cfg, JAMBA_ROWS)))
     pools = [(s(JAMBA_POOL, jnp.bfloat16), s(JAMBA_POOL, jnp.bfloat16))
              for _ in range(2)]
+    return cfg, params, state, pools, s
+
+
+def test_whole_jamba_decode_step_compiles_with_its_runs_scanned(
+        one_chip, monkeypatch):
+    """Four decode steps in a loop of the WHOLE model at published
+    widths — 28 published layers, 3.03 G parameters — pools, slot states
+    donated. Its Mamba-1 blocks run as THREE `lax.scan`s over stacked
+    parameters (runs of 7, 13 and 6): the program holds four loops (the
+    steps' and one a run) whatever the depth, aliases pools and states,
+    and keeps no copy of a run's state beside them."""
+    import re
+
+    from theroundtaible_tpu.engine.paged_forward import forward_paged_hybrid
+
+    cfg, params, state, pools, s = _whole_jamba(one_chip, monkeypatch)
+    i32 = jnp.int32
 
     def step(params, pools, state, tokens, positions, table, valid, active,
              rows):
@@ -1237,6 +1245,97 @@ def test_whole_jamba_decode_step_compiles_with_its_runs_scanned(
         f"{mem.temp_size_in_bytes / 1e6:.0f} MB of temporaries beside a "
         f"run's state of {run_state / 1e6:.0f} MB")
     assert mem.argument_size_in_bytes > 6.0e9       # the model, whole
+
+
+def _moved_between_memory_spaces(hlo: str, at_least: int) -> list:
+    """(name, shape, MB) of every `copy-start` in `hlo` that moves
+    `at_least` bytes or more: what the compiler's memory-space
+    assignment prefetches and writes back."""
+    import re
+    sizes = {"bf16": 2, "f16": 2, "s8": 1, "u8": 1, "pred": 1}
+    moved = []
+    for m in re.finditer(r"(%?copy-start[.\w]*) = \((\w+)\[([\d,]+)\]", hlo):
+        n = int(np.prod([int(d) for d in m.group(3).split(",")]))
+        if n * sizes.get(m.group(2), 4) >= at_least:
+            moved.append((m.group(1), f"{m.group(2)}[{m.group(3)}]",
+                          n * sizes.get(m.group(2), 4) // 10 ** 6))
+    return moved
+
+
+def test_jamba_decode_segment_moves_no_run_state_between_memory_spaces(
+        one_chip, monkeypatch):
+    """A decode SEGMENT of the whole model as `engine.decode_loop_hybrid`
+    issues it since ISSUE 53 — the packed buffer cut apart at the head,
+    the carried rows, the sampler in the loop, the engine's pair of keys
+    in and the next pair out — compiles with every run's slot state left
+    where it is. With `jax.random.split(key)` at the program's head (a
+    computed loop key AND a computed output key) the compiler moved the
+    13-layer run's whole slot state, 72 MB, into its alternate memory
+    and back inside the run's loop: `copy-done.49` / `.50`, 0.9 of 5.7
+    decode seconds on the chip, `tokens_per_s` 1193 -> 1022 (PERF.md,
+    PR 53). The pair — the split made one program ahead, so that the
+    loop starts from an argument — does not. (The program is rebuilt
+    here from the engine's pieces: an engine needs devices to build.)"""
+    from theroundtaible_tpu.engine import dispatch_pack
+    from theroundtaible_tpu.engine.hybrid_state import MOE_COUNTS
+    from theroundtaible_tpu.engine.models.hybrid import ROW_PARTS
+    from theroundtaible_tpu.engine.paged_forward import forward_paged_hybrid
+    from theroundtaible_tpu.engine.sampling import sample_token_batch
+
+    cfg, params, state, pools, s = _whole_jamba(one_chip, monkeypatch)
+    layout = dispatch_pack.decode_layout(DECODE_ROWS, PAGES_PER_SEQ,
+                                         rows=True)
+    max_new, eos = 64, jnp.int32(2)
+
+    def segment(params, pools, state, buf, carry, keys):
+        f = layout.unpack(buf)
+        last, valid, done, budgets = (
+            jnp.where(f["carried"], c, f[name])
+            for c, (name, _k) in zip(carry, dispatch_pack.CARRY))
+        tables, rows = f["tables"], f["rows"]
+        keys, sub = jax.random.split(keys[0]), keys[1]     # chain_key
+
+        def cond(st):
+            return ((st[0] < max_new) & (st[0] < f["budget"])
+                    & ~jnp.all(st[3]))
+
+        def body(st):
+            step, last, valid, done, out, (pl, rs, counts), key = st
+            logits, pl, rs, _cap, c = forward_paged_hybrid(
+                params, cfg, last[:, None], valid[:, None], pl, tables,
+                valid + 1, rs, active=~done & (step < budgets),
+                page_size=PAGE, rows=rows)
+            key, draw = jax.random.split(key)
+            nxt = sample_token_batch(
+                logits[:, 0].astype(jnp.float32), draw, f["temps"],
+                f["top_ks"], f["top_ps"]).astype(jnp.int32)
+            nxt = jnp.where(done | (step >= budgets), eos, nxt)
+            return (step + 1, nxt, jnp.where(done, valid, valid + 1),
+                    done | (nxt == eos), out.at[:, step].set(nxt),
+                    (pl, rs, counts + c), key)
+
+        rows_state = {p: [a[rows] for a in v] if p in ROW_PARTS else v
+                      for p, v in state.items()}
+        step, last, valid, done, out, (pl, rs, counts), _ = \
+            jax.lax.while_loop(cond, body, (
+                jnp.int32(0), last, valid, done,
+                jnp.zeros((DECODE_ROWS, max_new), jnp.int32),
+                (pools, rows_state,
+                 jnp.zeros((len(MOE_COUNTS),), jnp.int32)), sub))
+        new_state = {p: [a.at[rows].set(n) for a, n in zip(v, rs[p])]
+                     if p in ROW_PARTS else rs[p] for p, v in state.items()}
+        return (out, step, last, valid, done,
+                jnp.maximum(budgets - step, 0), pl, new_state, counts, keys)
+
+    compiled = jax.jit(segment, donate_argnums=(1, 2)).lower(
+        params, pools, state, s((layout.size,), jnp.int32),
+        tuple(s((DECODE_ROWS,), kind) for _n, kind in dispatch_pack.CARRY),
+        s((2, 2), jnp.uint32)).compile()
+    hlo = compiled.as_text()
+    _assert_kernel(hlo)
+    assert "mamba1_step" in hlo
+    moved = _moved_between_memory_spaces(hlo, 50 * 10 ** 6)
+    assert not moved, moved
 
 
 # --- 64-wide heads, two a lane row (ISSUE 52) -------------------------------

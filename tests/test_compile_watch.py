@@ -286,3 +286,36 @@ class TestCompilationCacheDecision:
         assert info["compile_cache"]["backend"] == "cpu"
         assert info["compile_observatory"]["mode"] == "monitoring"
         assert info["perf"]["param_bytes"] > 0
+
+
+# --- a warmed engine serves a scheduled round without a compile ---------
+
+
+@pytest.mark.parametrize("model", ["gemma", "jamba"])
+def test_after_warmup_a_scheduled_round_compiles_nothing(model):
+    """`engine.warmup()` calls the step programs with the argument kinds
+    serving uses (ISSUE 53): the packed buffer as a numpy array, the
+    state a decode segment carries and the key as arrays placed the way
+    the programs return them. So two scheduled rounds — a join, first
+    segments and the pipelined segments issued from their device
+    outputs, greedy and sampled — meet no signature the warm-up has
+    not. Before, a pipelined decode segment's committed arguments were
+    one the warm-up never met: a second compile of `decode[b=4,paged]`
+    in the first round served (on the chip, Jamba: 8 s a bucket)."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import discussion_play as dp
+
+    # The engine's own sampling is sampled, so that warm-up warms both
+    # modes (the mode is a static argument of every program).
+    eng, knights, cue = dp.build(model, temperature=0.7)
+    eng.warmup(max_prompt_tokens=256, batch_sizes=(1, 3, 4))
+    for mode in dp.MODES:
+        seen, n0 = (compile_watch.compiles_seen(),
+                    len(compile_watch.history()))
+        dp.play(eng, knights, cue, mode)
+        assert compile_watch.compiles_seen() == seen, (
+            mode, [e["label"] for e in compile_watch.history()[n0:]])
+    d = eng.describe()["dispatch"]
+    assert d["host_buffers"] == d["launches"] == d["programs"]

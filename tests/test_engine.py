@@ -484,6 +484,19 @@ def _prologue_engine(temp, seed=0):
         sampling=SamplingParams(temperature=temp, max_new_tokens=8))
 
 
+def _first_token(engine, logits, keys, arrays, greedy):
+    """The first-token program as the prologue calls it: the engine's
+    pair of keys (`split(chain key)`: row 1 is the program's own) and
+    the rows' sampling parameters as one packed buffer (ISSUE 53).
+    -> (tokens, the next pair)."""
+    from theroundtaible_tpu.engine import dispatch_pack
+    layout = dispatch_pack.sampler_layout(len(arrays[0]))
+    return engine._first_token(
+        logits, keys,
+        layout.pack(dict(zip(("temps", "top_ks", "top_ps"), arrays))),
+        layout=layout, greedy=greedy)
+
+
 class TestFirstTokenProgram:
     """The prologue samples its first token inside jit (ISSUE 26): one
     compiled program per ([B, V], greedy) and one blocking read, where
@@ -555,13 +568,20 @@ class TestFirstTokenProgram:
         arrays = sampling_arrays([SamplingParams(temperature=temp)] * b)
         for seed in (3, 5):
             key = jax.random.PRNGKey(seed)
-            got = engines[temp]._first_token(logits, key, *arrays,
-                                             greedy=temp <= 0.0)
+            # The engine holds `split(key)`: the chain's next key and
+            # the key the host's `_next_key` would have handed out. A
+            # sampled batch draws from that one and hands back the next
+            # pair; a greedy one draws none and moves nothing.
+            keys = jax.random.split(key)
+            got, nxt = _first_token(engines[temp], logits, keys, arrays,
+                                    greedy=temp <= 0.0)
             f32 = logits.astype(jnp.float32)
             want = (jnp.argmax(f32, axis=-1) if temp <= 0.0
-                    else sample_token_batch(f32, key, *arrays))
+                    else sample_token_batch(f32, keys[1], *arrays))
             assert got.dtype == jnp.int32
             assert got.tolist() == want.tolist()
+            assert nxt.tolist() == (
+                keys if temp <= 0.0 else jax.random.split(keys[0])).tolist()
 
     def test_program_matches_the_eager_sampler_on_a_mixed_batch(
             self, engines):
@@ -576,9 +596,11 @@ class TestFirstTokenProgram:
             SamplingParams(temperature=1.1, top_p=0.7)])
         for seed in range(8):
             key = jax.random.PRNGKey(seed)
-            got = engines[0.7]._first_token(logits, key, *arrays,
-                                            greedy=False)
-            want = sample_token_batch(logits, key, *arrays)
+            got, _keys = _first_token(engines[0.7], logits,
+                                      jax.random.split(key), arrays,
+                                      greedy=False)
+            want = sample_token_batch(logits, jax.random.split(key)[1],
+                                      *arrays)
             assert got.tolist() == want.tolist(), seed
         assert got.tolist()[0] == int(jnp.argmax(logits[0]))
 
@@ -612,7 +634,8 @@ class TestFirstTokenProgram:
         finally:
             sched.close()
         assert len(direct_calls) == len(sched_calls) == 1
-        assert sched_calls[0].tolist() == direct_calls[0].tolist()
+        assert sched_calls[0][0].tolist() == direct_calls[0][0].tolist()
+        assert sched_calls[0][1].tolist() == direct_calls[0][1].tolist()
         assert scheduled == direct
 
 

@@ -987,10 +987,15 @@ def test_the_loops_frame_opens_a_chunk_that_holds_what_runs_above_it(
     room that leaves."""
     import sys
 
-    from theroundtaible_tpu.engine import scheduler as mod
+    from theroundtaible_tpu.engine import serving_loop as mod
+    from theroundtaible_tpu.engine.engine import InferenceEngine
     code = SessionScheduler._loop.__code__
-    assert code.co_stacksize == mod._LOOP_FRAME_SLOTS > 256 * 1024 // 8
-    room = 512 * 1024 // 8 - mod._LOOP_FRAME_SLOTS - 1024
+    assert code.co_stacksize == mod.FRAME_SLOTS > 256 * 1024 // 8
+    # ... and so is the frame under which an engine's build traces and
+    # lowers its ragged grid, on the thread that builds it (PR 53)
+    assert InferenceEngine._warm_ragged.__code__.co_stacksize \
+        == mod.FRAME_SLOTS
+    room = 512 * 1024 // 8 - mod.FRAME_SLOTS - 1024
     sched = SessionScheduler(shared_engine, max_rows=2)
     try:
         frame = sys._current_frames()[sched._thread.ident]

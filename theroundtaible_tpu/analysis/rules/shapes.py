@@ -26,6 +26,12 @@ import ast
 from ..astlint import Finding, ProjectIndex, Rule, call_name
 
 # callee -> static parameter names whose value expression is audited.
+# (Since ISSUE 53 every step program also takes a static `layout`
+# (engine/dispatch_pack.py). It is not audited here: a layout is built
+# from the shapes of the arrays these same seams sized — the row
+# bucket, build_ragged_batch's outputs — so it can take no value that
+# the audited parameters could not; what remains per program are the
+# statics below.)
 STATIC_PARAMS: dict[str, frozenset[str]] = {
     "build_ragged_batch": frozenset(
         {"t_budget", "s_max", "score_width", "copy_slots",

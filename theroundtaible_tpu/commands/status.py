@@ -743,6 +743,7 @@ def _by_engine(series: dict[str, float],
 STARVED_SERIES = "roundtable_sched_starved_seconds_total"
 PAGE_COPIES_SERIES = "roundtable_page_copies_total"
 PAGE_COPY_PROGRAMS_SERIES = "roundtable_page_copy_programs_total"
+DISPATCH_SERIES_PREFIX = "roundtable_dispatch_"
 
 
 def perf_status(session) -> int:
@@ -750,7 +751,8 @@ def perf_status(session) -> int:
     the unified registry (ISSUE 6): the per-engine roofline table
     (ceiling, decode rate, and the seconds the scheduler's loop left
     the device unfed, by phase), the page copies each program of the
-    page cache's copier gathered and which copier that is, the compile
+    page cache's copier gathered and which copier that is, the host
+    buffers and launches a step program cost, the compile
     observatory's history and steady-state sentinel state, the memory
     ledger, and the span-tree overhead breakdown."""
     from ..utils import perfmodel, telemetry
@@ -829,6 +831,33 @@ def perf_status(session) -> int:
             path = " | ".join(sorted(paths.get(eng, "?")))
             print(style.dim(f"    {eng:<18}{n:8g}{progs:10g}  {per}"
                             f"  {by_cause}  {path}"))
+
+    # --- dispatches (ISSUE 53) ---
+    # What the step seams sent and issued: 1.0 and 1.0 where a dispatch
+    # travels as one packed buffer; more where a path still sends its
+    # arrays one by one or issues helpers of its own.
+    issued: dict[str, dict[str, float]] = {}
+    for key, v in perf.items():
+        name = key.split("{", 1)[0]
+        if name.startswith(DISPATCH_SERIES_PREFIX):
+            what = name[len(DISPATCH_SERIES_PREFIX):-len("_total")]
+            by = issued.setdefault(_labels(key).get("engine", "?"), {})
+            by[what] = by.get(what, 0.0) + v
+    if issued:
+        print(style.bold("\n  Dispatches (per engine):"))
+        print(style.dim("    engine            programs  host_buffers"
+                        "  launches  buffers/program  launches/program"))
+        for eng in sorted(issued):
+            by = issued[eng]
+            progs = by.get("programs", 0.0)
+
+            def per(n):
+                return f"{n / progs:16.2f}" if progs else "               -"
+
+            bufs, launches = (by.get("host_buffers", 0.0),
+                              by.get("launches", 0.0))
+            print(style.dim(f"    {eng:<18}{progs:8g}{bufs:14g}"
+                            f"{launches:10g} {per(bufs)} {per(launches)}"))
 
     # --- compile observatory ---
     from ..engine import compile_watch

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ENGINE_CONFIG_KEYS, deadlines, faults
+from . import ENGINE_CONFIG_KEYS, deadlines, dispatch_pack, faults
 from .models.common import ModelConfig, forward, param_count, spmd_mesh
 from .models.registry import resolve_model_config
 from .sampling import (SamplingParams, row_filtered, sample_token_batch,
@@ -36,7 +36,8 @@ from .serving_loop import (DECODE_SEGMENT, MAX_PREFILL_CHUNK,
                            PREFILL_BUCKETS, ReplicaGroupPlan,
                            bucket_for as _bucket,
                            chunked_prefill, decode_segments,
-                           finalize_outputs, host_sync, prompt_budget)
+                           finalize_outputs, host_sync, new_dispatch_totals,
+                           note_issue, prompt_budget, roomy_frame)
 from .sharding import build_mesh, init_sharded_params, shard_params
 from .tokenizer import load_tokenizer
 
@@ -375,7 +376,30 @@ class InferenceEngine:
             self.declines["page_copy"] = reason
         self.kv.page_copy_path = reason or page_copy.PATH
 
-        self._key = jax.random.PRNGKey(seed + 1)
+        # The sampler's key chain (ISSUE 53). `_keys` is
+        # `jax.random.split(chain key)`, [2, 2]: row 0 the chain's next
+        # key, row 1 the key of the next program that draws — the pair
+        # `_next_key` left on the host, one eager split a dispatch,
+        # before. A program that draws takes the pair, draws from row 1
+        # and returns `split(row 0)`, the next pair (`chain_key` below):
+        # the same splits in the same order of programs, so the draws
+        # are the same. Why a pair, and not the chain key split at the
+        # program's head: with the split at its head Jamba's decode
+        # program came out of the compiler moving a scanned run's whole
+        # state between memory spaces every step (PERF.md, PR 53); a
+        # program whose loop starts from an argument does not. The pair
+        # is placed as the programs return it, so the first dispatch
+        # and every later one meet ONE signature.
+        from jax.sharding import NamedSharding, PartitionSpec
+        self._replicated = NamedSharding(self.mesh, PartitionSpec())
+        self._keys = jax.device_put(
+            jax.random.split(jax.random.PRNGKey(seed + 1)),
+            self._replicated)
+        # What the step seams sent and issued (serving_loop.note_issue).
+        self._dispatch_totals = new_dispatch_totals()
+        # The first decode segment's stand-in for the state a segment
+        # carries to the next, a rows bucket each (`_decode_carry`).
+        self._carry0: dict[int, tuple] = {}
         self._chars_per_token: Optional[float] = None
         self.last_stats = GenStats()
         # Serving mutates the page pools (donated buffers): one generation
@@ -424,20 +448,32 @@ class InferenceEngine:
                         for x in xs)
             return out if len(out) > 1 else out[0]
 
-        @partial(jax.jit, static_argnames=("greedy",))
-        def first_token(last_logits, key, temps, top_ks, top_ps, greedy):
+        def chain_key(keys):
+            """-> (the engine's next pair, this program's key), from the
+            pair the engine holds (`_keys`): what `_next_key` did on the
+            host before ISSUE 53 — `key, sub = split(key)` — with the
+            split made one program ahead, by the program before."""
+            return host_read(jax.random.split(keys[0])), keys[1]
+
+        @partial(jax.jit, static_argnames=("layout", "greedy"))
+        def first_token(last_logits, keys, buf, layout, greedy):
             # The prologue's first token from ONE program per ([B, V],
             # greedy) — the same cast, argmax and sampler the decode
             # loop's body runs. Outside jit the sampler is some fifty
             # one-operation dispatches and its lax.cond recompiles on
-            # every call (sampling.sample_token_batch).
+            # every call (sampling.sample_token_batch). `buf`: the
+            # rows' sampling parameters (dispatch_pack.sampler_layout).
+            # -> (tokens, the next pair of keys); a greedy batch draws
+            # none and hands back the pair it was given.
             row_logits = last_logits.astype(jnp.float32)
             if greedy:
                 nxt = jnp.argmax(row_logits, axis=-1)
             else:
-                nxt = sample_token_batch(row_logits, key, temps, top_ks,
-                                         top_ps)
-            return host_read(nxt.astype(jnp.int32))
+                f = layout.unpack(buf)
+                keys, sub = chain_key(keys)
+                nxt = sample_token_batch(row_logits, sub, f["temps"],
+                                         f["top_ks"], f["top_ps"])
+            return host_read(nxt.astype(jnp.int32)), keys
 
         self._first_token = first_token
 
@@ -512,6 +548,26 @@ class InferenceEngine:
             step, last, valid, done, out = host_read(
                 step, last, valid, done, out)
             return out, step, last, valid, done, caches
+
+        def decode_inputs(layout, buf, carry, lora):
+            """A decode program's head: the packed buffer cut apart
+            (dispatch_pack.decode_layout), the rows' state taken from
+            `carry` — what the segment before handed on, on the device
+            — where the word `carried` says so and from the buffer on
+            a first segment, and the lora pair the scope takes.
+            -> (fields, (last, valid, done, budgets), lora)."""
+            f = layout.unpack(buf)
+            state = tuple(
+                jnp.where(f["carried"], c, f[name])
+                for c, (name, _kind) in zip(carry, dispatch_pack.CARRY))
+            if lora is not None:
+                lora = (lora, f["lora_ids"])
+            return f, state, lora
+
+        def budgets_left(budgets, step):
+            # (what `scheduler._advance` computed in a program of its
+            # own: the rows' budgets after this segment's steps)
+            return host_read(jnp.maximum(budgets - step, 0))
 
         def cached_step(params):
             """step_fn over the position-aligned [B, S, K, D] gather
@@ -659,9 +715,17 @@ class InferenceEngine:
                                   v_pool.at[tables].set(nv5)))
             return out_p + out_s
 
-        @partial(jax.jit, donate_argnums=(1,))
-        def prefill_step_paged(params, pools, tables, tokens, offsets,
-                               lengths, lora=None):
+        def prefill_inputs(layout, buf, lora):
+            f = layout.unpack(buf)
+            if lora is not None:
+                lora = (lora, f["lora_ids"])
+            return (f["tables"], f["tokens"], f["offsets"], f["lengths"],
+                    lora)
+
+        @partial(jax.jit, donate_argnums=(1,), static_argnames=("layout",))
+        def prefill_step_paged(params, pools, buf, layout, lora=None):
+            tables, tokens, offsets, lengths, lora = prefill_inputs(
+                layout, buf, lora)
             with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
                     self._lora_scope(lora):
                 b, t = tokens.shape
@@ -674,10 +738,12 @@ class InferenceEngine:
                 new_pools = scatter_view(pools, tables, new_b, b)
                 return host_read(logits[:, 0]), new_pools
 
-        @partial(jax.jit, donate_argnums=(1,))
-        def prefill_step_paged_direct(params, pools, tables, tokens,
-                                      offsets, lengths, lora=None):
+        @partial(jax.jit, donate_argnums=(1,), static_argnames=("layout",))
+        def prefill_step_paged_direct(params, pools, buf, layout,
+                                      lora=None):
             from .paged_forward import forward_paged
+            tables, tokens, offsets, lengths, lora = prefill_inputs(
+                layout, buf, lora)
             with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
                     self._lora_scope(lora):
                 t = tokens.shape[1]
@@ -700,11 +766,13 @@ class InferenceEngine:
                                     else prefill_step_paged)
 
         @partial(jax.jit, donate_argnums=(1,),
-                 static_argnames=("max_new", "greedy"))
-        def decode_loop_paged(params, pools, tables, first_token,
-                              start_valid, key, budget, temps, top_ks,
-                              top_ps, row_budgets, done0, max_new,
-                              greedy, lora=None):
+                 static_argnames=("layout", "max_new", "greedy"))
+        def decode_loop_paged(params, pools, buf, carry, key, layout,
+                              max_new, greedy, lora=None):
+            f, (first_token, start_valid, done0, row_budgets), lora = \
+                decode_inputs(layout, buf, carry, lora)
+            tables = f["tables"]
+            key, sub = chain_key(key)
             b = first_token.shape[0]
 
             # All-done guard: skip the full gather view + scatter
@@ -715,8 +783,9 @@ class InferenceEngine:
                 caches_b = gather_view(pools, tables, b)
                 out, step, last, valid, done, caches_b = decode_while(
                     cached_step(params), caches_b, first_token,
-                    start_valid, key, budget, temps, top_ks, top_ps,
-                    row_budgets, done0, max_new, greedy, lora=lora)
+                    start_valid, sub, f["budget"], f["temps"],
+                    f["top_ks"], f["top_ps"], row_budgets, done0,
+                    max_new, greedy, lora=lora)
                 new_pools = scatter_view(pools, tables, caches_b, b)
                 return out, step, last, valid, done, new_pools
 
@@ -725,16 +794,20 @@ class InferenceEngine:
                         jnp.int32(0), first_token, start_valid,
                         done0, pools)
 
-            return jax.lax.cond(jnp.all(done0), skip, run, pools)
+            out, step, last, valid, done, new_pools = jax.lax.cond(
+                jnp.all(done0), skip, run, pools)
+            return (out, step, last, valid, done,
+                    budgets_left(row_budgets, step), new_pools, key)
 
         @partial(jax.jit, donate_argnums=(1,),
-                 static_argnames=("max_new", "greedy"))
-        def decode_loop_paged_direct(params, pools, tables, first_token,
-                                     start_valid, key, budget, temps,
-                                     top_ks, top_ps, row_budgets,
-                                     done0, max_new, greedy,
-                                     lora=None):
+                 static_argnames=("layout", "max_new", "greedy"))
+        def decode_loop_paged_direct(params, pools, buf, carry, key,
+                                     layout, max_new, greedy, lora=None):
             from .paged_forward import forward_paged
+            f, (first_token, start_valid, done0, row_budgets), lora = \
+                decode_inputs(layout, buf, carry, lora)
+            tables = f["tables"]
+            key, sub = chain_key(key)
 
             def step_fn(last, valid, pools):
                 pools_l, scales_l = _kvq_split(pools, _n_layers)
@@ -744,10 +817,12 @@ class InferenceEngine:
                     pool_replicas=data_size,
                     scales=scales_l, quant_spec=_kvq_spec)
 
-            return decode_while(
-                step_fn, pools, first_token, start_valid, key, budget,
-                temps, top_ks, top_ps, row_budgets, done0, max_new,
-                greedy, lora=lora)
+            out, step, last, valid, done, new_pools = decode_while(
+                step_fn, pools, first_token, start_valid, sub,
+                f["budget"], f["temps"], f["top_ks"], f["top_ps"],
+                row_budgets, done0, max_new, greedy, lora=lora)
+            return (out, step, last, valid, done,
+                    budgets_left(row_budgets, step), new_pools, key)
 
         self._decode_loop_paged_gather = decode_loop_paged
         self._decode_loop_paged = (decode_loop_paged_direct
@@ -930,30 +1005,38 @@ class InferenceEngine:
                 self.declines["ragged_kernel"] = decline
 
         @partial(jax.jit, donate_argnums=(1,),
-                 static_argnames=("greedy", "attn_path",
+                 static_argnames=("layout", "greedy", "attn_path",
                                   "score_width", "propose_width"))
-        def ragged_step(params, pools, tables, tokens, positions,
-                        token_pages, token_offs, token_seq,
-                        seq_of_block, block_qstart, query_offsets,
-                        kv_valid, last_rows, key, temps, top_ks,
-                        top_ps, sample_rows=None, greedy=True,
-                        attn_path="kernel", score_width=0,
-                        lora=None, copy_src=None, copy_dst=None,
+        def ragged_step(params, pools, buf, key, layout, greedy=True,
+                        attn_path="kernel", score_width=0, lora=None,
                         propose_width=0):
+            # `buf`: dispatch_pack.ragged_layout — the flat buffer's
+            # maps, the sequences' tables and sampling parameters and,
+            # where the layout has them, the verify's score rows
+            # (score_width), the tree's page-copy pairs and the tokens'
+            # adapter slots.
             from .paged_forward import forward_ragged
+            f = layout.unpack(buf)
+            key, sub = chain_key(key)
+            temps, top_ks, top_ps = f["temps"], f["top_ks"], f["top_ps"]
+            if lora is not None:
+                lora = (lora, f["token_adapter"])
             with spmd_mesh(mesh, int4_sink=self._int4_dispatches), \
                     self._lora_scope(lora):
                 pools_l, scales_l = _kvq_split(pools, _n_layers)
                 logits, new_pools = forward_ragged(
                     params, cfg,
-                    tokens, positions, pools_l, tables, seq_of_block,
-                    block_qstart, query_offsets, kv_valid,
-                    token_pages, token_offs, token_seq, last_rows,
+                    f["tokens"], f["positions"], pools_l, f["tables"],
+                    f["seq_of_block"], f["block_qstart"],
+                    f["query_offsets"], f["kv_valid"],
+                    f["token_pages"], f["token_offs"], f["token_seq"],
+                    f["last_rows"],
                     attn_path=attn_path,
-                    sample_rows=(sample_rows if score_width
+                    sample_rows=(f["sample_rows"] if score_width
                                  else None),
                     scales=scales_l, quant_spec=_kvq_spec,
-                    copy_src=copy_src, copy_dst=copy_dst)
+                    copy_src=f.get("copy_src"),
+                    copy_dst=f.get("copy_dst"))
                 lf = logits.astype(jnp.float32)
                 if score_width:
                     # Speculative verify (ISSUE 9): per-position
@@ -966,7 +1049,7 @@ class InferenceEngine:
                         nxt = jnp.argmax(lf, axis=-1)
                     else:
                         nxt = sample_token_batch(
-                            lf.reshape(s * r, v), key,
+                            lf.reshape(s * r, v), sub,
                             jnp.repeat(temps, r),
                             jnp.repeat(top_ks, r),
                             jnp.repeat(top_ps, r)).reshape(s, r)
@@ -975,7 +1058,7 @@ class InferenceEngine:
                     nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
                 else:
                     nxt = sample_token_batch(
-                        lf, key, temps, top_ks,
+                        lf, sub, temps, top_ks,
                         top_ps).astype(jnp.int32)
             if propose_width:
                 # Draft-model propose dispatch (ISSUE 13): alongside
@@ -986,8 +1069,8 @@ class InferenceEngine:
                 # lf is [S, V].
                 tops = jax.lax.top_k(
                     lf, propose_width)[1].astype(jnp.int32)
-                return host_read(nxt, tops), new_pools
-            return host_read(nxt), new_pools
+                return host_read(nxt, tops), new_pools, key
+            return host_read(nxt), new_pools, key
 
         self._ragged_step = ragged_step
 
@@ -999,8 +1082,9 @@ class InferenceEngine:
         self.hybrid = None
         if model_cfg.layer_kinds is not None:
             self._build_hybrid_programs(
-                model_cfg, mesh, host_read, decode_while, num_slots,
-                page_size, state_snapshot_bytes)
+                model_cfg, mesh, host_read, decode_while, chain_key,
+                decode_inputs, budgets_left, num_slots, page_size,
+                state_snapshot_bytes)
 
         # Speculative decoding (ISSUE 9): self-drafting verify folded
         # into the scheduler's ragged segment loop. The verify dispatch
@@ -1145,6 +1229,7 @@ class InferenceEngine:
                     f"{self.spec_options.drafter}:{str(e)[:120]}")
 
     def _build_hybrid_programs(self, cfg, mesh, host_read, decode_while,
+                               chain_key, decode_inputs, budgets_left,
                                num_slots, page_size,
                                state_snapshot_bytes) -> None:
         """The step programs of a model with layer_kinds, and its state
@@ -1188,13 +1273,17 @@ class InferenceEngine:
                     if p in ROW_PARTS else cap[p]
                     for p, v in snaps.items()}
 
-        @partial(jax.jit, donate_argnums=(1, 2, 3))
-        def prefill_step_hybrid(params, pools, state, snaps, tables,
-                                tokens, offsets, lengths, rows, cap_len,
-                                snap_idx):
-            # rows [B]: each batch row's state row (pads: scratch).
-            # cap_len / snap_idx [B]: the snapshot this chunk yields
-            # (0 / the scratch snapshot: none).
+        @partial(jax.jit, donate_argnums=(1, 2, 3),
+                 static_argnames=("layout",))
+        def prefill_step_hybrid(params, pools, state, snaps, buf, layout):
+            # `buf` (dispatch_pack.prefill_layout, hybrid): beside the
+            # chunk, rows [B]: each batch row's state row (pads:
+            # scratch); cap_len / snap_idx [B]: the snapshot this chunk
+            # yields (0 / the scratch snapshot: none).
+            f = layout.unpack(buf)
+            tables, tokens, offsets, lengths = (
+                f["tables"], f["tokens"], f["offsets"], f["lengths"])
+            rows, cap_len, snap_idx = f["rows"], f["cap_len"], f["snap_idx"]
             with spmd_mesh(mesh):
                 t = tokens.shape[1]
                 positions = offsets[:, None] + jnp.arange(t)[None, :]
@@ -1212,13 +1301,16 @@ class InferenceEngine:
         self._prefill_step_hybrid = prefill_step_hybrid
 
         @partial(jax.jit, donate_argnums=(1, 2),
-                 static_argnames=("max_new", "greedy"))
-        def decode_loop_hybrid(params, pools, state, tables, rows,
-                               first_token, start_valid, key, budget,
-                               temps, top_ks, top_ps, row_budgets, done0,
-                               max_new, greedy):
+                 static_argnames=("layout", "max_new", "greedy"))
+        def decode_loop_hybrid(params, pools, state, buf, carry, key,
+                               layout, max_new, greedy):
             # The rows' states are gathered once, carried through the
             # loop in batch order, and scattered back once.
+            f, (first_token, start_valid, done0, row_budgets), _ = \
+                decode_inputs(layout, buf, carry, None)
+            tables, rows = f["tables"], f["rows"]
+            key, sub = chain_key(key)
+
             def step_fn(last, valid, caches, active):
                 pools_c, st, counts = caches
                 logits, pools_c, st, _cap, c = forward_paged_hybrid(
@@ -1230,30 +1322,34 @@ class InferenceEngine:
             out, step, last, valid, done, caches = decode_while(
                 step_fn, (pools, rows_of(state, rows),
                           jnp.zeros((len(MOE_COUNTS),), jnp.int32)),
-                first_token, start_valid, key, budget, temps, top_ks,
-                top_ps, row_budgets, done0, max_new, greedy,
-                pass_active=True)
+                first_token, start_valid, sub, f["budget"], f["temps"],
+                f["top_ks"], f["top_ps"], row_budgets, done0, max_new,
+                greedy, pass_active=True)
             new_pools, st, counts = caches
-            return (out, step, last, valid, done, new_pools,
-                    put_rows(state, rows, st), host_read(counts))
+            return (out, step, last, valid, done,
+                    budgets_left(row_budgets, step), new_pools,
+                    put_rows(state, rows, st), host_read(counts), key)
 
         self._decode_loop_hybrid = decode_loop_hybrid
 
         @partial(jax.jit, donate_argnums=(1, 2, 3),
-                 static_argnames=("greedy", "attn_path"))
-        def ragged_step_hybrid(params, pools, state, snaps, tables, tokens,
-                               positions, token_pages, token_offs,
-                               token_seq, seq_of_block, block_qstart,
-                               query_offsets, kv_valid, last_rows, key,
-                               temps, top_ks, top_ps, seq_slot, cap_n,
-                               snap_idx, greedy=True, attn_path="kernel"):
+                 static_argnames=("layout", "greedy", "attn_path"))
+        def ragged_step_hybrid(params, pools, state, snaps, buf, key,
+                               layout, greedy=True, attn_path="kernel"):
+            # `buf` (dispatch_pack.ragged_layout, hybrid): beside the
+            # flat buffer's maps, each sequence's state row and the
+            # snapshot its run leaves.
+            f = layout.unpack(buf)
+            key, sub = chain_key(key)
+            snap_idx = f["snap_idx"]
             with spmd_mesh(mesh):
                 logits, new_pools, new, cap, counts = \
                     forward_ragged_hybrid(
-                        params, cfg, tokens, positions, pools, tables,
-                        seq_of_block, block_qstart, query_offsets,
-                        kv_valid, token_pages, token_offs, token_seq,
-                        last_rows, state, seq_slot, cap_n,
+                        params, cfg, f["tokens"], f["positions"], pools,
+                        f["tables"], f["seq_of_block"], f["block_qstart"],
+                        f["query_offsets"], f["kv_valid"],
+                        f["token_pages"], f["token_offs"], f["token_seq"],
+                        f["last_rows"], state, f["seq_slot"], f["cap_n"],
                         attn_path=attn_path, page_size=page_size,
                         snaps=snaps, snap_idx=snap_idx)
                 lf = logits.astype(jnp.float32)
@@ -1261,9 +1357,11 @@ class InferenceEngine:
                     nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
                 else:
                     nxt = sample_token_batch(
-                        lf, key, temps, top_ks, top_ps).astype(jnp.int32)
+                        lf, sub, f["temps"], f["top_ks"],
+                        f["top_ps"]).astype(jnp.int32)
             return (host_read(nxt), new_pools, new,
-                    put_snaps(snaps, snap_idx, cap), host_read(counts))
+                    put_snaps(snaps, snap_idx, cap), host_read(counts),
+                    key)
 
         self._ragged_step_hybrid = ragged_step_hybrid
         if self.prefix_cache is not None and cfg.recurrent:
@@ -1625,6 +1723,7 @@ class InferenceEngine:
         compile_watch.warmup_complete(self.cfg.name)
         return time.monotonic() - t0
 
+    @roomy_frame
     def _warm_ragged(self) -> None:
         """Compile-and-stabilize the ragged mixed dispatch: a two-seq
         flat buffer (one prefill chunk + one decode-shaped row) through
@@ -1737,18 +1836,38 @@ class InferenceEngine:
         return lora_scope(lora, sink=self._lora_dispatches,
                           quant=self._lora_quant)
 
-    def _lora_args(self, ids):
-        """Device argument pair (stacked, adapter ids) for one
-        dispatch, or None on lora-off engines. `ids` is per-ROW for
-        batched programs and per-TOKEN for ragged dispatches; the
-        module test counter records each dispatch's adapter mix for
-        the conftest `lora` guard."""
+    def _lora_ids(self, ids):
+        """One dispatch's adapter ids as the packed buffer takes them
+        (the programs pair them with `lora.stacked` themselves), or
+        None on lora-off engines. `ids` is per-ROW for batched programs
+        and per-TOKEN for ragged dispatches; the module test counter
+        records each dispatch's adapter mix for the conftest `lora`
+        guard."""
         if self.lora is None:
             return None
         from . import lora as lora_mod
         ids_np = np.asarray(ids, np.int32)
         lora_mod.note_dispatch_ids(ids_np)
-        return (self.lora.stacked, jnp.asarray(ids_np))
+        return ids_np
+
+    def _count_issue(self, **what) -> None:
+        """What a step seam has just sent and issued
+        (serving_loop.note_issue; one program, one buffer and one
+        launch where nothing else is said)."""
+        note_issue(self._dispatch_totals, self.cfg.name, **what)
+
+    def _decode_carry(self, b: int) -> tuple:
+        """A first decode segment's `carry` argument over a rows bucket
+        of `b`: nothing is carried yet (the program reads the packed
+        buffer: `carried` is 0), but the argument has to be there, and
+        placed as a segment's own outputs are, for the first segment
+        and the pipelined ones to be ONE compiled program."""
+        carry = self._carry0.get(b)
+        if carry is None:
+            carry = self._carry0[b] = tuple(
+                jax.device_put(np.zeros((b,), kind), self._replicated)
+                for _name, kind in dispatch_pack.CARRY)
+        return carry
 
     def note_lora_tokens(self, n: int) -> None:
         """Account tokens served THROUGH a persona adapter (ISSUE 10
@@ -1886,12 +2005,16 @@ class InferenceEngine:
             cap_len[i], snap_idx[i], key = hy.capture_slot(
                 row_name[row], int(offs[i]), int(takes[i]))
             keys.append(key)
+        layout = dispatch_pack.prefill_layout(
+            b, chunk.shape[1], tables.shape[1], hybrid=True)
         try:
             last, pools, state, snaps, counts = self._prefill_step_hybrid(
                 self.params, self.kv.combined_pools(), hy.state, hy.snaps,
-                tables, jnp.asarray(chunk), jnp.asarray(offs, jnp.int32),
-                jnp.asarray(takes, jnp.int32), jnp.asarray(rows_np),
-                jnp.asarray(cap_len), jnp.asarray(snap_idx))
+                layout.pack({"tables": tables, "tokens": chunk,
+                             "offsets": offs, "lengths": takes,
+                             "rows": rows_np, "cap_len": cap_len,
+                             "snap_idx": snap_idx}), layout=layout)
+            self._count_issue()
         except Exception:
             for key in keys:
                 hy.drop(key, unwritten=True)
@@ -1904,25 +2027,24 @@ class InferenceEngine:
                          if rows_np[i] != hy.scratch_row))
         return last, pools
 
-    def _hybrid_decode(self, tables, names, last, valid, key, budget,
-                       temps, top_ks, top_ps, row_budgets, done0, max_new,
-                       greedy):
+    def _hybrid_decode(self, fields, carry, names, max_new, greedy):
         hy = self.hybrid
         if names is None:
             raise ValueError(
                 f"{self.cfg.name} keeps recurrent state: a decode "
                 "dispatch needs the rows' slot names")
-        rows = hy.rows_for(list(names), int(tables.shape[0]),
-                           self._live_slots())
-        out, steps, l2, v2, d2, pools, state, counts = \
+        b, pps = fields["tables"].shape
+        layout = dispatch_pack.decode_layout(b, pps, rows=True)
+        rows = hy.rows_for(list(names), b, self._live_slots())
+        out, steps, l2, v2, d2, left, pools, state, counts, key = \
             self._decode_loop_hybrid(
-            self.params, self.kv.combined_pools(), hy.state, tables,
-            jnp.asarray(rows), last, valid, key, budget, temps, top_ks,
-            top_ps, row_budgets, done0, max_new=max_new, greedy=greedy)
+                self.params, self.kv.combined_pools(), hy.state,
+                layout.pack(dict(fields, rows=rows)), carry, self._keys,
+                layout=layout, max_new=max_new, greedy=greedy)
         with deadlines.commit_guard():
             hy.commit_state(state)
         hy.note_counts(counts, pipelined=True)
-        return out, steps, l2, v2, d2, pools
+        return out, steps, l2, v2, d2, left, pools, key
 
     def _hybrid_ragged(self, batch: dict, path: str):
         """One ragged dispatch: every sequence's run advances its slot's
@@ -1949,24 +2071,17 @@ class InferenceEngine:
             cap_n[i], snap_idx[i], key = hy.capture_slot(
                 name, int(starts[i]), int(ends[i] - starts[i]))
             keys.append(key)
+        layout = self._ragged_layout(batch)
         try:
-            nxt, pools, state, snaps, counts = self._ragged_step_hybrid(
-                self.params, self.kv.combined_pools(), hy.state, hy.snaps,
-                jnp.asarray(batch["tables"]), jnp.asarray(batch["tokens"]),
-                jnp.asarray(batch["positions"]),
-                jnp.asarray(batch["token_pages"]),
-                jnp.asarray(batch["token_offs"]),
-                jnp.asarray(batch["token_seq"]),
-                jnp.asarray(batch["seq_of_block"]),
-                jnp.asarray(batch["block_qstart"]),
-                jnp.asarray(batch["query_offsets"]),
-                jnp.asarray(batch["kv_valid"]),
-                jnp.asarray(batch["last_rows"]), self._next_key(),
-                jnp.asarray(batch["temps"]), jnp.asarray(batch["top_ks"]),
-                jnp.asarray(batch["top_ps"]), jnp.asarray(seq_slot),
-                jnp.asarray(cap_n), jnp.asarray(snap_idx),
-                greedy=batch["greedy"],
-                attn_path="kernel" if path == "pallas_ragged" else "xla")
+            nxt, pools, state, snaps, counts, key = \
+                self._ragged_step_hybrid(
+                    self.params, self.kv.combined_pools(), hy.state,
+                    hy.snaps,
+                    layout.pack(dict(batch, seq_slot=seq_slot, cap_n=cap_n,
+                                     snap_idx=snap_idx)),
+                    self._keys, layout=layout, greedy=batch["greedy"],
+                    attn_path=("kernel" if path == "pallas_ragged"
+                               else "xla"))
         except Exception:
             for key in keys:
                 hy.drop(key, unwritten=True)
@@ -1976,7 +2091,18 @@ class InferenceEngine:
             hy.commit_snaps(snaps)
         hy.note_counts(counts, pipelined=False)
         hy.note_scan(int((ends - starts)[:len(names)].sum()))
-        return nxt, pools
+        return nxt, pools, key
+
+    def _ragged_layout(self, batch: dict) -> dispatch_pack.Layout:
+        """The packed form of a ragged batch: a function of the shapes
+        serving_loop.build_ragged_batch gave its arrays."""
+        s_max, pps = batch["tables"].shape
+        copy_src = batch.get("copy_src")
+        return dispatch_pack.ragged_layout(
+            len(batch["tokens"]), len(batch["seq_of_block"]), s_max, pps,
+            score_width=int(batch.get("score_width", 0) or 0),
+            copy_slots=0 if copy_src is None else len(copy_src),
+            hybrid=self.hybrid is not None, lora=self.lora is not None)
 
     def _ragged_dispatch(self, batch: dict):
         """One mixed prefill/decode dispatch over a flat token buffer
@@ -1998,41 +2124,22 @@ class InferenceEngine:
         params = (batch["draft_params"]
                   if batch.get("draft_params") is not None
                   else self.params)
-        copy_src = batch.get("copy_src")
 
         def run(path):
             if path == "pallas_ragged" and faults.ARMED:
                 faults.maybe_inject("mosaic_compile")
             if self.hybrid is not None:
                 return self._hybrid_ragged(batch, path)
+            layout = self._ragged_layout(batch)
+            if self.lora is not None:
+                self._lora_ids(batch["token_adapter"])
             return self._ragged_step(
-                params, self.kv.combined_pools(),
-                jnp.asarray(batch["tables"]),
-                jnp.asarray(batch["tokens"]),
-                jnp.asarray(batch["positions"]),
-                jnp.asarray(batch["token_pages"]),
-                jnp.asarray(batch["token_offs"]),
-                jnp.asarray(batch["token_seq"]),
-                jnp.asarray(batch["seq_of_block"]),
-                jnp.asarray(batch["block_qstart"]),
-                jnp.asarray(batch["query_offsets"]),
-                jnp.asarray(batch["kv_valid"]),
-                jnp.asarray(batch["last_rows"]), self._next_key(),
-                jnp.asarray(batch["temps"]),
-                jnp.asarray(batch["top_ks"]),
-                jnp.asarray(batch["top_ps"]),
-                sample_rows=(jnp.asarray(batch["sample_rows"])
-                             if score_width else None),
-                greedy=batch["greedy"],
+                params, self.kv.combined_pools(), layout.pack(batch),
+                self._keys, layout=layout, greedy=batch["greedy"],
                 attn_path=("kernel" if path == "pallas_ragged"
                            else "xla"),
                 score_width=score_width,
-                lora=self._lora_args(batch["token_adapter"])
-                if self.lora is not None else None,
-                copy_src=(jnp.asarray(copy_src)
-                          if copy_src is not None else None),
-                copy_dst=(jnp.asarray(batch["copy_dst"])
-                          if copy_src is not None else None),
+                lora=self.lora.stacked if self.lora is not None else None,
                 propose_width=propose_width)
 
         from . import compile_watch
@@ -2040,16 +2147,19 @@ class InferenceEngine:
                 f"ragged[t={len(batch['tokens'])}]",
                 engine=self.cfg.name):
             try:
-                nxt, pools = run(self.ragged_path)
+                nxt, pools, key = run(self.ragged_path)
             except Exception as e:
                 if not (faults.is_kernel_failure(e)
                         and self._degrade_ragged(str(e))):
                     raise
-                nxt, pools = run(self.ragged_path)
+                nxt, pools, key = run(self.ragged_path)
+        self._count_issue()
         # A watchdog-abandoned dispatch completing late must NOT commit
-        # onto pools the recovery path may have revived.
+        # onto pools the recovery path may have revived — nor move the
+        # key chain the retry has to draw from.
         with deadlines.commit_guard():
             self.kv.set_combined(pools)
+            self._keys = key
         path = self.ragged_path
         self._note_kv_quant("ragged", kernel=path == "pallas_ragged")
         self._ragged_dispatches[path] = \
@@ -2372,10 +2482,6 @@ class InferenceEngine:
             self._chars_per_token = max(len(sample) / max(n, 1), 0.25)
         return self._chars_per_token
 
-    def _next_key(self) -> jax.Array:
-        self._key, sub = jax.random.split(self._key)
-        return sub
-
     def _prefill(self, state_rows: list[int],
                  token_lists: list[list[int]], offsets: list[int],
                  tables: np.ndarray, deadline: float = float("inf"),
@@ -2426,6 +2532,10 @@ class InferenceEngine:
                 jnp.asarray(lengths))
         self.kv.set_combined(self._scatter_kv_paged(
             self.kv.combined_pools(), jnp.asarray(tables), caches))
+        # Left unpacked (ISSUE 53): the ring program's inputs are
+        # sharded over the sequence axis and its writeback is a program
+        # of its own — three arrays and the tables, two launches.
+        self._count_issue(host_buffers=4, launches=2)
         return logits
 
     def _prefill_chunked(self, state_rows: list[int],
@@ -2441,13 +2551,13 @@ class InferenceEngine:
         pool-direct it is already replica-grouped and padded).
         `state_rows`: each row's state row of a model with layer_kinds
         (hybrid_state; -1: none, the scratch row)."""
-        tables = jnp.asarray(tables)
+        tables = np.asarray(tables)
         # Per-row adapter slots for the whole call (ISSUE 10): chunk
-        # composition varies, the ids do not — one device arg serves
-        # every chunk dispatch.
-        lora_arg = None
+        # composition varies, the ids do not — every chunk's buffer
+        # carries the same ones.
+        lora_np = None
         if self.lora is not None:
-            lora_arg = self._lora_args(
+            lora_np = self._lora_ids(
                 lora_ids if lora_ids is not None
                 else [0] * len(token_lists))
 
@@ -2463,10 +2573,16 @@ class InferenceEngine:
                          for e, o in zip(ends, offs)]
                 return self._hybrid_prefill(tables, chunk, offs, takes,
                                             state_rows)
-            return self._prefill_step_paged(
-                self.params, self.kv.combined_pools(), tables,
-                jnp.asarray(chunk), jnp.asarray(offs, jnp.int32),
-                jnp.asarray(lengths), lora=lora_arg)
+            layout = dispatch_pack.prefill_layout(
+                *chunk.shape, tables.shape[1], lora=lora_np is not None)
+            out = self._prefill_step_paged(
+                self.params, self.kv.combined_pools(),
+                layout.pack({"tables": tables, "tokens": chunk,
+                             "offsets": offs, "lengths": lengths,
+                             "lora_ids": lora_np}), layout=layout,
+                lora=self.lora.stacked if lora_np is not None else None)
+            self._count_issue()
+            return out
 
         from . import compile_watch
 
@@ -2500,7 +2616,8 @@ class InferenceEngine:
 
         return chunked_prefill(dispatch, token_lists, offsets,
                                self.kv.max_seq_len, self.tokenizer.pad_id,
-                               deadline, retry=self.retry, budget=budget)
+                               deadline, retry=self.retry, budget=budget,
+                               note=self._count_issue)
 
     def _share_prefixes(self, names: list[str],
                         all_tokens: list[list[int]], offsets: list[int],
@@ -3028,11 +3145,6 @@ class InferenceEngine:
             temps = plan.scatter_rows(temps, 1.0)
             top_ks = plan.scatter_rows(top_ks, 0)
             top_ps = plan.scatter_rows(top_ps, 1.0)
-        # The sampler's inputs are ready BEFORE the prefill is issued
-        # (splitting the key is itself a device program), so nothing
-        # eager stands between the prefill step and the first token. A
-        # greedy batch draws no key, as before.
-        key = self._key if greedy else self._next_key()
         last_logits = self._prefill(prep["state_rows"], suffixes,
                                     p_offsets, prep["tables_np"],
                                     deadline=deadline,
@@ -3042,12 +3154,18 @@ class InferenceEngine:
         with compile_watch.label(
                 f"prefill[b={last_logits.shape[0]},first_token]",
                 engine=self.cfg.name):
-            first = self._first_token(last_logits, key, temps, top_ks,
-                                      top_ps, greedy=greedy)
-        if plan is not None and len(plan.pad_positions):
-            # Pad rows open at eos so they are done from the first step.
-            first = first.at[jnp.asarray(plan.pad_positions)].set(
-                jnp.int32(self.tokenizer.eos_id))
+            # One buffer (the rows' sampling parameters) and one
+            # launch; a sampled batch splits the key inside and hands
+            # the next one back, a greedy one draws none, as before.
+            layout = dispatch_pack.sampler_layout(len(temps))
+            first, key = self._first_token(
+                last_logits, self._keys,
+                layout.pack({"temps": temps, "top_ks": top_ks,
+                             "top_ps": top_ps}),
+                layout=layout, greedy=greedy)
+            self._count_issue()
+            if not greedy:
+                self._keys = key
         # On a clocked thread (the scheduler's) the device holds the
         # prologue's programs — each prefill chunk fed the loop clock
         # where it was issued (serving_loop.chunked_prefill), the
@@ -3070,45 +3188,63 @@ class InferenceEngine:
                     first_np=first_np)
         return prep
 
-    def _decode_dispatch_paged(self, tables, last, valid, key, budget,
-                               temps, top_ks, top_ps, row_budgets, done0,
-                               *, greedy, max_new=DECODE_SEGMENT,
-                               lora=None, names=None):
+    def _decode_dispatch_paged(self, fields: dict, carry=None, *, greedy,
+                               max_new=DECODE_SEGMENT, names=None):
         """One paged decode-segment dispatch through the kernel-
         degradation rung (mosaic chaos point; pool-direct → gather-view
         on kernel failure, re-dispatching this segment), committing the
-        donated pools under commit_guard. Shared by generate_batch's
-        segment loop and the session scheduler. `names`: the rows' slot
-        names (pad rows left out) — a model with recurrent state finds
-        each row's state by it."""
+        donated pools and the key the program hands back under
+        commit_guard. Shared by generate_batch's segment loop and the
+        session scheduler.
+
+        `fields`: what the host built for the segment, as numpy values
+        under dispatch_pack.decode_layout's names (`tables`, `last`,
+        `valid`, `done`, `budgets`, `temps`, `top_ks`, `top_ps`, the
+        segment's step `budget`, and `lora_ids` on a lora engine) — it
+        travels as ONE buffer. `carry`: the (last, valid, done,
+        budgets) the segment before returned, still on the device (a
+        pipelined segment: the buffer's own four are then not read);
+        None on a first segment. `names`: the rows' slot names (pad
+        rows left out) — a model with recurrent state finds each row's
+        state by it. -> (out, steps, last, valid, done, budgets left)."""
+        b, pps = fields["tables"].shape
+        fields = dict(fields, carried=carry is not None)
+        if carry is None:
+            carry = self._decode_carry(b)
+
         def run():
             if self.paged_direct and faults.ARMED:
                 faults.maybe_inject("mosaic_compile")
             if self.hybrid is not None:
-                return self._hybrid_decode(
-                    tables, names, last, valid, key, budget, temps,
-                    top_ks, top_ps, row_budgets, done0, max_new, greedy)
+                return self._hybrid_decode(fields, carry, names, max_new,
+                                           greedy)
+            layout = dispatch_pack.decode_layout(
+                b, pps, lora=self.lora is not None)
             return self._decode_loop_paged(
-                self.params, self.kv.combined_pools(), tables, last,
-                valid, key, budget, temps, top_ks, top_ps, row_budgets,
-                done0, max_new=max_new, greedy=greedy, lora=lora)
+                self.params, self.kv.combined_pools(), layout.pack(fields),
+                carry, self._keys, layout=layout, max_new=max_new,
+                greedy=greedy,
+                lora=self.lora.stacked if self.lora is not None else None)
 
         from . import compile_watch
         with compile_watch.label(
-                f"decode[b={last.shape[0]},paged]", engine=self.cfg.name):
+                f"decode[b={b},paged]", engine=self.cfg.name):
             try:
-                out, steps, l2, v2, d2, pools = run()
+                out, steps, l2, v2, d2, left, pools, key = run()
             except Exception as e:
                 if not (faults.is_kernel_failure(e)
                         and self._degrade_paged_direct(str(e))):
                     raise
-                out, steps, l2, v2, d2, pools = run()
+                out, steps, l2, v2, d2, left, pools, key = run()
+        self._count_issue()
         # A watchdog-abandoned dispatch completing late must NOT commit
-        # onto pools the recovery path may have revived.
+        # onto pools the recovery path may have revived — nor move the
+        # key chain the retry has to draw from.
         with deadlines.commit_guard():
             self.kv.set_combined(pools)
+            self._keys = key
         self._note_kv_quant("decode", kernel=self.paged_direct)
-        return out, steps, l2, v2, d2
+        return out, steps, l2, v2, d2, left
 
     def generate(self, prompt: str, slot_name: str = "default",
                  max_new_tokens: Optional[int] = None,
@@ -3253,48 +3389,50 @@ class InferenceEngine:
         # first_np comes back in ORIGINAL row order; the decode phase
         # runs in plan order (padded replica-grouped rows) when a plan
         # exists, so scatter it back — pad rows open at eos (done).
+        first = first_np.astype(np.int32)
+        cur_valid = np.asarray([len(t) for t in all_tokens], np.int32)
         if plan is not None:
-            first = plan.scatter_rows(
-                first_np.astype(np.int32), np.int32(self.tokenizer.eos_id))
-        else:
-            first = jnp.asarray(first_np, jnp.int32)
-        cur_valid = jnp.asarray([len(t) for t in all_tokens], jnp.int32)
-        if plan is not None:
+            first = plan.scatter_rows(first,
+                                      np.int32(self.tokenizer.eos_id))
             cur_valid = plan.scatter_rows(cur_valid, 1)
 
         t1 = time.monotonic()
         # Decode rung budget is derived NOW, not at call start, so a
         # configured "decode" cap times the decode phase alone.
         dec_budget = turn_budget.child("decode")
-        tables = jnp.asarray(prep["tables_np"])
         # Per-row decode budgets (knight_sampling max_new_tokens): a row
         # whose own budget is smaller than the batch's stops early (goes
         # done, emits eos) while the rest keep decoding
         # (serving_loop.row_budget_fn — one definition for both engines).
         from .serving_loop import row_budget_fn
-        row_remaining = row_budget_fn(per_row, sampling_per_turn, max_new)
+        row_budgets = row_budget_fn(per_row, sampling_per_turn, max_new)
+        if plan is not None:
+            row_budgets = plan.scatter_rows(row_budgets, 0)
         lora_slots = prep.get("lora_slots")
-        dec_lora = None
+        # What every segment's buffer holds; a first segment takes its
+        # rows' state from it too (the first tokens, the prompts'
+        # lengths, the budgets), a pipelined one from what the segment
+        # before carried.
+        fields = {"tables": prep["tables_np"], "last": first,
+                  "valid": cur_valid,
+                  "done": first == np.int32(self.tokenizer.eos_id),
+                  "budgets": row_budgets, "temps": temps,
+                  "top_ks": top_ks, "top_ps": top_ps}
         if self.lora is not None:
             dec_ids = list(lora_slots if lora_slots is not None
                            else [0] * len(all_tokens))
             if plan is not None:
                 dec_ids = plan.scatter_list(dec_ids, 0)
-            dec_lora = self._lora_args(dec_ids)
+            fields["lora_ids"] = self._lora_ids(dec_ids)
 
-        def decode_dispatch(cur_last, cur_valid, budget, done0):
-            row_budgets = row_remaining(budget)
-            if plan is not None:
-                row_budgets = plan.scatter_rows(row_budgets, 0)
+        def decode_dispatch(budget, carry):
             return self._decode_dispatch_paged(
-                tables, cur_last, cur_valid, self._next_key(),
-                budget, temps, top_ks, top_ps, row_budgets, done0,
-                greedy=greedy, lora=dec_lora, names=prep["names"])
+                dict(fields, budget=budget), carry, greedy=greedy,
+                names=prep["names"])
 
         with telemetry.span("decode", engine=self.cfg.name,
                             max_new=max_new):
-            out_np = decode_segments(decode_dispatch, first, cur_valid,
-                                     self.tokenizer.eos_id, max_new,
+            out_np = decode_segments(decode_dispatch, len(first), max_new,
                                      deadline, timeout_s, retry=self.retry,
                                      budget=dec_budget,
                                      filtered_rows=sum(
@@ -3385,6 +3523,9 @@ class InferenceEngine:
         # ISSUE 38: pages handed out, page copies queued and the
         # programs that issued them.
         info["paging"] = self.kv.describe()
+        # ISSUE 53: what the step seams sent and issued — one buffer
+        # and one launch a program where a dispatch is packed.
+        info["dispatch"] = dict(self._dispatch_totals)
         # ISSUE 7: the cross-session sharing subsystems' state.
         if self.prefix_cache is not None:
             info["prefix_cache"] = self.prefix_cache.describe()
@@ -3495,6 +3636,17 @@ from ..analysis.jaxpr_audit import (ProgramSpec, Variant,  # noqa: E402
                                     analysis_register)
 
 
+def _audit_buf(layout):
+    """A dispatch's packed buffer, as the trace takes it."""
+    return jax.ShapeDtypeStruct((layout.size,), jnp.int32)
+
+
+def _audit_carry(b: int) -> tuple:
+    """What a decode segment carries to the next, over `b` rows."""
+    return tuple(jax.ShapeDtypeStruct((b,), kind)
+                 for _name, kind in dispatch_pack.CARRY)
+
+
 def _audit_sds(x):
     """Pytree of ShapeDtypeStructs — the device-free trace argument:
     make_jaxpr abstracts by aval, so no buffer is ever materialized."""
@@ -3523,21 +3675,21 @@ def _analysis_engine_programs(engine) -> list:
     from .serving_loop import pow2_bucket
     params = _audit_sds(engine.params)
     pools = _audit_sds(engine.kv.combined_pools())
-    key = jax.random.PRNGKey(0)
+    key = jax.random.split(jax.random.PRNGKey(0))
     num_slots = engine.kv.num_slots
     pps = engine.kv.pages_per_seq
-
-    def ints(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    def floats(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32)
+    lora = (_audit_sds(engine.lora.stacked)
+            if engine.lora is not None else None)
 
     def prefill_variant(b: int, bucket: int) -> Variant:
         def thunk():
-            return jax.make_jaxpr(engine._prefill_step_paged)(
-                params, pools, ints(b, pps), ints(b, bucket), ints(b),
-                ints(b))
+            layout = dispatch_pack.prefill_layout(
+                b, bucket, pps, lora=lora is not None)
+            fn = engine._prefill_step_paged
+            return jax.make_jaxpr(
+                lambda p, pl, buf, lo: fn(p, pl, buf, layout=layout,
+                                          lora=lo))(
+                params, pools, _audit_buf(layout), lora)
         return Variant(label=f"b{b}x{bucket}", thunk=thunk,
                        situation=f"batch {b}, bucket {bucket}")
 
@@ -3545,17 +3697,17 @@ def _analysis_engine_programs(engine) -> list:
         b = pow2_bucket(occ)
 
         def thunk():
-            budget = jnp.int32(DECODE_SEGMENT)
-            # first_token, start_valid, key, budget, temps, top_ks,
-            # top_ps, row_budgets, done0 — _decode_dispatch_paged's order.
-            args = (ints(b), ints(b), key, budget, floats(b), ints(b),
-                    floats(b), ints(b),
-                    jax.ShapeDtypeStruct((b,), jnp.bool_))
+            # The packed buffer, what a segment carries, the key —
+            # _decode_dispatch_paged's order.
+            layout = dispatch_pack.decode_layout(
+                b, pps, lora=lora is not None)
             fn = engine._decode_loop_paged
             return jax.make_jaxpr(
-                lambda p, pl, t, *a: fn(
-                    p, pl, t, *a, max_new=DECODE_SEGMENT,
-                    greedy=True))(params, pools, ints(b, pps), *args)
+                lambda p, pl, buf, c, k, lo: fn(
+                    p, pl, buf, c, k, layout=layout,
+                    max_new=DECODE_SEGMENT, greedy=True, lora=lo))(
+                params, pools, _audit_buf(layout), _audit_carry(b), key,
+                lora)
         return Variant(label=f"b{b}", thunk=thunk,
                        situation=f"occupancy {occ}")
 
@@ -3589,21 +3741,18 @@ def _analysis_hybrid_programs(engine) -> list:
     params = _audit_sds(engine.params)
     pools = _audit_sds(kv.combined_pools())
     state, snaps = _audit_sds(hy.state), _audit_sds(hy.snaps)
-    key = jax.random.PRNGKey(0)
+    key = jax.random.split(jax.random.PRNGKey(0))
     pps = kv.pages_per_seq
-
-    def ints(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    def floats(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32)
 
     def prefill_variant(b: int, bucket: int) -> Variant:
         def thunk():
-            return jax.make_jaxpr(engine._prefill_step_hybrid)(
-                params, pools, state, snaps, ints(b, pps),
-                ints(b, bucket), ints(b), ints(b), ints(b), ints(b),
-                ints(b))
+            layout = dispatch_pack.prefill_layout(b, bucket, pps,
+                                                  hybrid=True)
+            fn = engine._prefill_step_hybrid
+            return jax.make_jaxpr(
+                lambda p, pl, st, sn, buf: fn(p, pl, st, sn, buf,
+                                              layout=layout))(
+                params, pools, state, snaps, _audit_buf(layout))
         return Variant(label=f"b{b}x{bucket}", thunk=thunk,
                        situation=f"batch {b}, bucket {bucket}")
 
@@ -3611,15 +3760,14 @@ def _analysis_hybrid_programs(engine) -> list:
         b = pow2_bucket(occ)
 
         def thunk():
+            layout = dispatch_pack.decode_layout(b, pps, rows=True)
             fn = engine._decode_loop_hybrid
             return jax.make_jaxpr(
-                lambda p, pl, st, *a: fn(p, pl, st, *a,
-                                         max_new=DECODE_SEGMENT,
-                                         greedy=True))(
-                params, pools, state, ints(b, pps), ints(b), ints(b),
-                ints(b), key, jnp.int32(DECODE_SEGMENT), floats(b),
-                ints(b), floats(b), ints(b),
-                jax.ShapeDtypeStruct((b,), jnp.bool_))
+                lambda p, pl, st, buf, c, k: fn(
+                    p, pl, st, buf, c, k, layout=layout,
+                    max_new=DECODE_SEGMENT, greedy=True))(
+                params, pools, state, _audit_buf(layout), _audit_carry(b),
+                key)
         return Variant(label=f"b{b}", thunk=thunk,
                        situation=f"occupancy {occ}")
 
@@ -3630,21 +3778,14 @@ def _analysis_hybrid_programs(engine) -> list:
                 s_max=kv.num_slots + 1, pages_per_seq=pps,
                 scratch_page=kv.scratch_page(0),
                 pad_id=engine.tokenizer.pad_id, page_size=kv.page_size)
-            s_max = b["tables"].shape[0]
-            arrays = [jnp.asarray(b[k]) for k in (
-                "tables", "tokens", "positions", "token_pages",
-                "token_offs", "token_seq", "seq_of_block",
-                "block_qstart", "query_offsets", "kv_valid", "last_rows")]
+            layout = engine._ragged_layout(b)
             fn = engine._ragged_step_hybrid
             return jax.make_jaxpr(
-                lambda p, pl, st, sn, *a: fn(
-                    p, pl, st, sn, *a, greedy=True,
+                lambda p, pl, st, sn, buf, k: fn(
+                    p, pl, st, sn, buf, k, layout=layout, greedy=True,
                     attn_path=("kernel" if engine.ragged_path
                                == "pallas_ragged" else "xla")))(
-                params, pools, state, snaps, *arrays, key,
-                jnp.asarray(b["temps"]), jnp.asarray(b["top_ks"]),
-                jnp.asarray(b["top_ps"]), ints(s_max), ints(s_max),
-                ints(s_max))
+                params, pools, state, snaps, _audit_buf(layout), key)
         return Variant(label=f"t{shape}", thunk=thunk,
                        situation=f"{n_seqs} seq(s) in shape {shape}")
 

@@ -755,7 +755,7 @@ def trace_ragged_batch(engine, batch: dict):
     """Trace one ragged dispatch's program (`engine._ragged_step`) to a
     ClosedJaxpr without dispatching — the device-free twin of
     `InferenceEngine._ragged_dispatch.run`. Argument mapping mirrors
-    that seam one-to-one (same array order, same static kwargs); if the
+    that seam one-to-one (the same layout, the same static kwargs); if the
     twins drift, the audit's trace step fails loudly, which is the
     contract — an unauditable serving program must never be skipped
     silently. Shared by the ragged provider here and the spec-decode
@@ -767,42 +767,22 @@ def trace_ragged_batch(engine, batch: dict):
     pools = _audit_sds(engine.kv.combined_pools())
     attn_path = ("kernel" if engine.ragged_path == "pallas_ragged"
                  else "xla")
-    copy_src = batch.get("copy_src")
-    arrs = dict(
-        tables=jnp.asarray(batch["tables"]),
-        tokens=jnp.asarray(batch["tokens"]),
-        positions=jnp.asarray(batch["positions"]),
-        token_pages=jnp.asarray(batch["token_pages"]),
-        token_offs=jnp.asarray(batch["token_offs"]),
-        token_seq=jnp.asarray(batch["token_seq"]),
-        seq_of_block=jnp.asarray(batch["seq_of_block"]),
-        block_qstart=jnp.asarray(batch["block_qstart"]),
-        query_offsets=jnp.asarray(batch["query_offsets"]),
-        kv_valid=jnp.asarray(batch["kv_valid"]),
-        last_rows=jnp.asarray(batch["last_rows"]),
-        key=jax.random.PRNGKey(0),
-        temps=jnp.asarray(batch["temps"]),
-        top_ks=jnp.asarray(batch["top_ks"]),
-        top_ps=jnp.asarray(batch["top_ps"]),
-    )
-    opt = {}
-    if score_width:
-        opt["sample_rows"] = jnp.asarray(batch["sample_rows"])
-    if copy_src is not None:
-        opt["copy_src"] = jnp.asarray(copy_src)
-        opt["copy_dst"] = jnp.asarray(batch["copy_dst"])
-    names = list(arrs) + list(opt)
+    # One packed buffer and the key (engine/dispatch_pack.py): the
+    # layout follows from the batch's shapes, as at the seam.
+    layout = engine._ragged_layout(batch)
+    buf = jax.ShapeDtypeStruct((layout.size,), jnp.int32)
+    lora = (_audit_sds(engine.lora.stacked)
+            if engine.lora is not None else None)
 
-    def call(p, pl, *flat):
-        kw = dict(zip(names, flat))
-        pos = [kw.pop(n) for n in arrs]
+    def call(p, pl, b, k, lo):
         return engine._ragged_step(
-            p, pl, *pos, greedy=batch["greedy"], attn_path=attn_path,
-            score_width=score_width, lora=None,
-            propose_width=propose_width, **kw)
+            p, pl, b, k, layout=layout, greedy=batch["greedy"],
+            attn_path=attn_path, score_width=score_width, lora=lo,
+            propose_width=propose_width)
 
-    return jax.make_jaxpr(call)(params, pools, *arrs.values(),
-                                *opt.values())
+    return jax.make_jaxpr(call)(params, pools, buf,
+                                jax.random.split(jax.random.PRNGKey(0)),
+                                lora)
 
 
 def analysis_warm_seqs(engine, n_seqs: int = 2):

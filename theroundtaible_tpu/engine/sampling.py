@@ -17,6 +17,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclass(frozen=True)
@@ -54,11 +55,12 @@ def sample_token(logits: jax.Array, key: jax.Array,
 
 
 def sampling_arrays(params_list: list[SamplingParams]):
-    """Per-row (temps, top_ks, top_ps) f32/i32/f32 arrays for
-    sample_token_batch."""
-    return (jnp.asarray([p.temperature for p in params_list], jnp.float32),
-            jnp.asarray([p.top_k for p in params_list], jnp.int32),
-            jnp.asarray([p.top_p for p in params_list], jnp.float32))
+    """Per-row (temps, top_ks, top_ps) f32/i32/f32 HOST arrays for
+    sample_token_batch: they reach a program inside its dispatch's one
+    packed buffer (engine/dispatch_pack.py)."""
+    return (np.asarray([p.temperature for p in params_list], np.float32),
+            np.asarray([p.top_k for p in params_list], np.int32),
+            np.asarray([p.top_p for p in params_list], np.float32))
 
 
 # Candidate-pool size for the sort-free filtered path below. Covers

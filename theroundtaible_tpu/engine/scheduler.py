@@ -66,13 +66,13 @@ from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
-import jax.numpy as jnp
 import numpy as np
 
 from ..utils import telemetry
 from . import deadlines, faults, trace_hooks
 from .kvcache import scoped_slot
 from .sampling import SamplingParams, row_filtered, sampling_arrays
+from .serving_loop import roomy_frame as _roomy_frame
 from .serving_loop import (DECODE_SEGMENT, RAGGED_BLOCK_Q, RaggedSeq,
                            ReplicaGroupPlan, build_ragged_batch,
                            clamp_max_new, eos_trim, host_sync,
@@ -93,23 +93,9 @@ LOOP_PHASES = ("wait", "health", "admit", "admit_sync", "build",
                "dispatch", "sync", "accept", "flush", "retire")
 _LOOP_WITHIN = {"admit": {"sync": "admit_sync", "dispatch": "admit"}}
 
-# The slots of the loop's own frame (PERF.md, Findings PR 46). CPython
-# 3.11+ keeps a thread's interpreter frames in 16 KiB chunks and frees a
-# chunk the moment its first frame returns, so a loop whose calls cross
-# a chunk's end pays one mmap and one munmap A CALL. Tracing and lowering
-# are such loops, hundreds of frames deep, and the loop's thread runs
-# them at every new shape: 19 s of Laguna's 40 s warm-up on the chip's
-# machine, and 22 s more once an unrelated edit made the frames under
-# them ten slots smaller. A frame this large opens one 512 KiB chunk that
-# lives as long as the thread and holds every frame above it.
-_LOOP_FRAME_SLOTS = 40_000
-
-
-def _roomy_frame(fn):
-    fn.__code__ = fn.__code__.replace(co_stacksize=_LOOP_FRAME_SLOTS)
-    return fn
-
-
+# The loop's own frame is a roomy one (serving_loop.roomy_frame; PERF.md,
+# Findings PR 46): tracing and lowering run on this thread at every new
+# shape.
 # Test-visibility counter (tests/conftest.py `scheduler` marker guard):
 # the maximum number of live rows any scheduler dispatched in one decode
 # segment since the last reset. A guard that sees < 2 here knows the
@@ -1624,8 +1610,7 @@ class SessionScheduler:
             # SEVERAL sessions' traces, so it lands in the flight
             # recorder ring rather than any one session's JSONL). Its
             # stretch is the blocking read; the fold's counts ride it.
-            seg = self._open_segment("plain", len(alive),
-                                     int(ctx["last_d"].shape[0]))
+            seg = self._open_segment("plain", len(alive), ctx["size"])
             if pack is not telemetry.NULL_SPAN:
                 # (The mini-loop's later segments are carried on the
                 # device from this one's outputs: nothing is packed.)
@@ -2765,7 +2750,6 @@ class SessionScheduler:
                     (pad, tables_np.shape[1]),
                     engine.kv.scratch_page(0), tables_np.dtype)
                 tables_np = np.concatenate([tables_np, scratch])
-        tables = jnp.asarray(tables_np)
         if pad:
             last = np.concatenate([last, np.full(pad, eos, np.int32)])
             valid = np.concatenate([valid, np.ones(pad, np.int32)])
@@ -2778,37 +2762,39 @@ class SessionScheduler:
             [SamplingParams(temperature=t, top_k=k, top_p=p)
              for t, k, p in zip(temps_l, top_ks_l, top_ps_l)])
 
-        lora = None
+        budgets_max = int(budgets.max()) if len(budgets) else 0
+        if plan is not None:
+            last = plan.scatter_rows(last, np.int32(eos))
+            valid = plan.scatter_rows(valid, 1)
+            done0 = plan.scatter_rows(done0, True)
+            budgets = plan.scatter_rows(budgets, 0)
+            temps = plan.scatter_rows(temps, 1.0)
+            top_ks = plan.scatter_rows(top_ks, 0)
+            top_ps = plan.scatter_rows(top_ps, 1.0)
+        # Everything the segment's program takes from the host, under
+        # dispatch_pack.decode_layout's names: it travels as one buffer
+        # (engine._decode_dispatch_paged packs it), so nothing here
+        # touches the device.
+        fields = {"tables": tables_np, "last": last, "valid": valid,
+                  "done": done0, "budgets": budgets, "temps": temps,
+                  "top_ks": top_ks, "top_ps": top_ps,
+                  "budget": DECODE_SEGMENT}
         if getattr(engine, "lora", None) is not None:
             # Per-row adapter slots (ISSUE 10): pad rows ride the base
             # (zero) adapter — their delta is exactly zero and their
             # outputs are masked anyway. A value, so mixed-adapter
             # recomposition compiles nothing.
             slots = [r.adapter_slot for r in rows]
-            ids = (plan.scatter_list(slots, 0) if plan is not None
-                   else slots + [0] * pad)
-            lora = engine._lora_args(ids)
-        if plan is not None:
-            last_d = plan.scatter_rows(last, np.int32(eos))
-            valid_d = plan.scatter_rows(valid, 1)
-            done_d = plan.scatter_rows(done0, True)
-            budgets_d = plan.scatter_rows(budgets, 0)
-            temps = plan.scatter_rows(np.asarray(temps), 1.0)
-            top_ks = plan.scatter_rows(np.asarray(top_ks), 0)
-            top_ps = plan.scatter_rows(np.asarray(top_ps), 1.0)
-        else:
-            last_d = jnp.asarray(last)
-            valid_d = jnp.asarray(valid)
-            done_d = jnp.asarray(done0)
-            budgets_d = jnp.asarray(budgets)
+            fields["lora_ids"] = engine._lora_ids(
+                plan.scatter_list(slots, 0) if plan is not None
+                else slots + [0] * pad)
         return {
-            "rows": rows, "reqs": reqs, "plan": plan, "tables": tables,
-            "last_d": last_d, "valid_d": valid_d,
-            "done_d": done_d, "budgets_d": budgets_d, "temps": temps,
-            "top_ks": top_ks, "top_ps": top_ps, "greedy": greedy,
+            "rows": rows, "reqs": reqs, "plan": plan, "fields": fields,
+            # What the segment before handed on, on the device (a
+            # pipelined segment: _advance); None: the buffer's own.
+            "carry": None, "size": len(last), "greedy": greedy,
             "seg_budget": seg_budget, "deadline": deadline,
-            "budgets_max": int(budgets.max()) if len(budgets) else 0,
-            "lora": lora, "names": names,
+            "budgets_max": budgets_max, "names": names,
         }
 
     def _dispatch(self, ctx: dict):
@@ -2822,11 +2808,7 @@ class SessionScheduler:
 
         def dispatch():
             return engine._decode_dispatch_paged(
-                ctx["tables"], ctx["last_d"], ctx["valid_d"],
-                engine._next_key(), jnp.int32(DECODE_SEGMENT),
-                ctx["temps"], ctx["top_ks"], ctx["top_ps"],
-                ctx["budgets_d"], ctx["done_d"],
-                greedy=ctx["greedy"], lora=ctx["lora"],
+                ctx["fields"], ctx["carry"], greedy=ctx["greedy"],
                 names=ctx["names"])
 
         handles = run_dispatch(dispatch, engine.retry, ctx["deadline"],
@@ -2838,14 +2820,13 @@ class SessionScheduler:
         return handles
 
     def _advance(self, ctx: dict, handles) -> dict:
-        """The next segment's ctx from this segment's DEVICE outputs —
-        pure device arithmetic (decode_segments' pipelining carry), no
-        host sync: done/valid/last carry, per-row budgets decrement by
-        the steps actually taken."""
-        _out, steps, l2, v2, d2 = handles
+        """The next segment's ctx from this segment's DEVICE outputs
+        (decode_segments' pipelining carry) — no host sync and no
+        program of its own: last/valid/done carry, and the per-row
+        budgets less the steps actually taken are an output of the
+        segment's program too."""
         nxt = dict(ctx)
-        nxt["last_d"], nxt["valid_d"], nxt["done_d"] = l2, v2, d2
-        nxt["budgets_d"] = jnp.maximum(ctx["budgets_d"] - steps, 0)
+        nxt["carry"] = tuple(handles[2:])
         # Host-side upper-bound estimate for _may_speculate (the device
         # value is not worth a sync): each segment consumes at most
         # DECODE_SEGMENT of every row's budget.
@@ -2862,7 +2843,7 @@ class SessionScheduler:
         """The blocking half of a segment's read, through the watchdog
         seam — this is where a wedged program freezes the host, and
         where the loop clock reads `sync`."""
-        out, steps, l2, v2, d2 = handles
+        out, steps, l2, v2, d2, _left = handles
 
         def read():
             n = int(steps)  # forces completion of the segment

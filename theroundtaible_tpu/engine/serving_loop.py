@@ -9,6 +9,7 @@ epilogue — lives here once. The caller passes its dispatch closure.
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Callable, Optional
 
@@ -33,6 +34,26 @@ DECODE_SEGMENT = 64  # tokens per decode program; timeout checks in between
 RAGGED_BLOCK_Q = 8
 RAGGED_TOKENS_ENV = "ROUNDTABLE_RAGGED_TOKENS"
 RAGGED_DEFER_MIN_ENV = "ROUNDTABLE_RAGGED_DEFER_MIN"
+
+
+# The slots of a roomy frame (PERF.md, Findings PR 46 and PR 53). CPython
+# 3.11+ keeps a thread's interpreter frames in 16 KiB chunks and frees a
+# chunk the moment its first frame returns, so a loop whose calls cross
+# a chunk's end pays one mmap and one munmap A CALL. Tracing and lowering
+# are such loops, hundreds of frames deep: 19 s of Laguna's 40 s warm-up
+# on the chip's machine, and 22 s more once an unrelated edit made the
+# frames under them ten slots smaller (PR 46, the scheduler's thread);
+# 7 s of Jamba's 33 s build when the step seams' frames changed size
+# (PR 53, the main thread under `_warm_ragged`). A frame this large opens
+# one 512 KiB chunk that lives as long as the frame and holds every
+# frame above it: the scheduler's loop has one, and so has the engine's
+# build-time warm-up of the ragged grid.
+FRAME_SLOTS = 40_000
+
+
+def roomy_frame(fn):
+    fn.__code__ = fn.__code__.replace(co_stacksize=FRAME_SLOTS)
+    return fn
 
 
 def ragged_token_budget(num_slots: int, asked: int = 0) -> int:
@@ -84,6 +105,42 @@ def ragged_pick_shape(grid: tuple[int, ...], want: int) -> int:
     return grid[-1]
 
 
+# What the dispatch being issued has cost so far (ISSUE 53): the open
+# `dispatch` span's [host_buffers, launches], on the thread that runs
+# the dispatch (the caller's, or the watchdog's worker).
+_issuing = threading.local()
+
+
+def new_dispatch_totals() -> dict:
+    """An engine's lifetime account of what it issued: `programs` (step
+    programs and the prologue's sampler), `host_buffers` (host-to-device
+    transfers made for them) and `launches` (device programs issued,
+    the step program among them). One buffer and one launch a program
+    where a dispatch is packed (engine/dispatch_pack.py); a path that
+    still sends its arrays one by one shows by how much it is not."""
+    return {"programs": 0, "host_buffers": 0, "launches": 0}
+
+
+def note_issue(totals: dict, engine: str, *, programs: int = 1,
+               host_buffers: int = 1, launches: int = 1) -> None:
+    """A step seam's account of what it has just issued, into the
+    engine's totals, the registry's series and the open `dispatch`
+    span (run_dispatch)."""
+    totals["programs"] += programs
+    totals["host_buffers"] += host_buffers
+    totals["launches"] += launches
+    telemetry.inc("roundtable_dispatch_programs_total", programs,
+                  engine=engine)
+    telemetry.inc("roundtable_dispatch_host_buffers_total", host_buffers,
+                  engine=engine)
+    telemetry.inc("roundtable_dispatch_launches_total", launches,
+                  engine=engine)
+    tally = getattr(_issuing, "tally", None)
+    if tally is not None:
+        tally[0] += host_buffers
+        tally[1] += launches
+
+
 def run_dispatch(dispatch: Callable, retry, deadline: float = float("inf"),
                  budget=None, rung: str = "dispatch"):
     """One device dispatch through the shared fault-tolerance AND
@@ -108,15 +165,22 @@ def run_dispatch(dispatch: Callable, retry, deadline: float = float("inf"),
     (engine.revive_kv_if_dead) and re-prefills from scratch
     (tpu_llm._serial_retry)."""
 
-    def call():
+    def call(tally=None):
         if faults.ARMED:
             faults.inject_dispatch_faults()
-        return dispatch()
+        if tally is None:
+            return dispatch()
+        _issuing.tally = tally
+        try:
+            return dispatch()
+        finally:
+            _issuing.tally = None
 
-    def attempt():
+    def attempt(tally=None):
         if deadlines.ACTIVE and budget is not None:
-            return deadlines.watched_wait(call, budget, rung)
-        return call()
+            return deadlines.watched_wait(lambda: call(tally), budget,
+                                          rung)
+        return call(tally)
 
     def attempt_traced():
         # "dispatch" is the span tree's leaf rung (ISSUE 5), mirroring
@@ -128,8 +192,15 @@ def run_dispatch(dispatch: Callable, retry, deadline: float = float("inf"),
         from . import compile_watch
         with compile_watch.label(f"dispatch[{rung}]", fallback=True):
             if telemetry.ACTIVE:
-                with telemetry.span("dispatch", stage=rung):
-                    return attempt()
+                # (every attempt is a span of its own, with what it
+                # sent and issued: ISSUE 53)
+                tally = [0, 0]
+                with telemetry.span("dispatch", stage=rung) as sp:
+                    try:
+                        return attempt(tally)
+                    finally:
+                        sp.set_attr("host_buffers", tally[0])
+                        sp.set_attr("launches", tally[1])
             return attempt()
 
     # The loop clock's seam (ISSUE 25): on a clocked thread (the
@@ -221,12 +292,15 @@ class ReplicaGroupPlan:
         self.pad_positions = np.asarray(pad_positions, np.int64)
         self.pad_replicas = pad_replicas
 
-    def scatter_rows(self, values, pad_value) -> jax.Array:
-        """Original-order per-row device/host array → padded array."""
-        arr = jnp.asarray(values)
-        out = jnp.full((self.b_padded,) + arr.shape[1:], pad_value,
-                       arr.dtype)
-        return out.at[jnp.asarray(self.pos)].set(arr)
+    def scatter_rows(self, values, pad_value) -> np.ndarray:
+        """Original-order per-row host array → padded host array (it
+        travels in the dispatch's packed buffer: nothing here touches
+        the device)."""
+        arr = np.asarray(values)
+        out = np.full((self.b_padded,) + arr.shape[1:], pad_value,
+                      arr.dtype)
+        out[self.pos] = arr
+        return out
 
     def scatter_list(self, items: list, pad_item) -> list:
         """Original-order per-row python values → padded list (pad rows
@@ -307,6 +381,7 @@ def chunked_prefill(
     deadline: float = float("inf"),
     retry=None,
     budget=None,
+    note=None,
 ) -> jax.Array:
     """Bucketed multi-chunk prefill. Returns last-token logits [B, V].
 
@@ -322,7 +397,10 @@ def chunked_prefill(
     chunk's dispatch runs under the watchdog at the "dispatch" rung, and
     cooperative cancellation/deadline checks run between chunks (a
     single XLA program cannot be interrupted — the boundaries are where
-    a drain or an exhausted ancestor budget takes effect).
+    a drain or an exhausted ancestor budget takes effect). `note`: the
+    caller's account of what is sent and issued (serving_loop.note_issue
+    bound to its engine) — a second and later chunk's merge of the kept
+    logits is a mask sent and a program of its own.
     """
     b = len(token_lists)
     if budget is not None:
@@ -366,6 +444,8 @@ def chunked_prefill(
         else:
             final_logits = jnp.where(jnp.asarray(takes > 0)[:, None],
                                      last_logits, final_logits)
+            if note is not None:
+                note(programs=0)
         for i in range(b):
             offs[i] += int(takes[i])
         if time.monotonic() > deadline and any(remaining):
@@ -373,36 +453,28 @@ def chunked_prefill(
     return final_logits
 
 
-def row_budget_fn(per_row, sampling_per_turn, max_new: int) -> Callable:
-    """Per-segment remaining-row-budget closure, shared by both engines.
+def row_budget_fn(per_row, sampling_per_turn, max_new: int) -> np.ndarray:
+    """The rows' token budgets as decode begins (host int32 [B]).
 
     Only an EXPLICIT sampling_per_turn carries per-row max_new_tokens
     budgets (capped by the call-level max_new) — otherwise the call
     level wins uniformly: the engine-default sampling's budget must not
     silently cap an explicit call request. The prefill-sampled first
     token has already consumed one token of every row's budget, hence
-    the -1; `budget` is decode_segments' remaining-global count — kept
-    as DEVICE arithmetic so the pipelined segment queue never forces a
-    host sync."""
+    the -1. Across segments the decode program itself hands on what is
+    left (`max(budgets - steps, 0)`), so the pipelined segment queue
+    never forces a host sync."""
     if sampling_per_turn:
         totals = np.asarray(
             [min(p.max_new_tokens, max_new) for p in per_row], np.int32)
     else:
         totals = np.full(len(per_row), max_new, np.int32)
-    totals_dev = jnp.asarray(totals, jnp.int32)
-
-    def remaining(budget) -> jax.Array:
-        consumed = jnp.int32(max_new) - jnp.asarray(budget, jnp.int32)
-        return jnp.maximum(totals_dev - 1 - consumed, 0)
-
-    return remaining
+    return np.maximum(totals - 1, 0)
 
 
 def decode_segments(
     dispatch: Callable,
-    first_token: jax.Array,
-    start_valid: jax.Array,
-    eos_id: int,
+    rows: int,
     max_new: int,
     deadline: float,
     timeout_s: float,
@@ -416,42 +488,43 @@ def decode_segments(
     contract is honored). The segment size is ALWAYS DECODE_SEGMENT — a
     variable tail would compile a fresh program per distinct length.
 
-    dispatch(cur_last, cur_valid, budget, done0) → (out, steps, last,
-    valid, done) runs one segment; budget may be a DEVICE scalar, done0
-    is the [B] done mask carried ACROSS segments (rows at eos / their
-    row budget skip further decode). Returns the concatenated token
-    matrix [B, produced]. `filtered_rows`: how many of the rows engage
-    the sampler's filters (sampling.row_filtered), for the spans.
+    dispatch(budget, carry) → (out, steps, last, valid, done, budgets)
+    runs one segment over `rows` rows: `budget` is the tokens still
+    wanted, `carry` the (last, valid, done, budgets) of the segment
+    before, on the device — None on the first, whose rows' state the
+    caller's dispatch packs itself. The done mask is carried ACROSS
+    segments (rows at eos / their row budget skip further decode).
+    Returns the concatenated token matrix [B, produced].
+    `filtered_rows`: how many of the rows engage the sampler's filters
+    (sampling.row_filtered), for the spans.
 
     PIPELINED: the next segment is queued from the previous segment's
-    DEVICE outputs (budget decremented and done carried with device
-    arithmetic) BEFORE the host reads steps/out/done — so the device
+    DEVICE outputs BEFORE the host reads steps/out/done — so the device
     never idles for the host round-trips between segments (material
-    wherever the host is slow to turn around). When the just-read
-    segment turns out to have finished the generation, the speculative
-    segment's while_loop
-    condition is false on entry and it costs microseconds; its results
+    wherever the host is slow to turn around). Its `budget` is a host
+    number all the same, max_new less DECODE_SEGMENT a segment issued:
+    a segment is queued only while that is positive, so every segment
+    before it had more than DECODE_SEGMENT to go and either took all
+    its steps (the number is exact) or stopped with every row done —
+    and then the queued segment's while_loop condition is false on
+    entry whatever its budget; it costs microseconds and its results
     are discarded.
     """
-    b = first_token.shape[0]
     if budget is not None:
         deadline = min(deadline, budget.deadline)
     segments: list[np.ndarray] = []
     produced = 0
-    budget_dev = jnp.int32(max_new)
-    first_done = first_token == jnp.int32(eos_id)
-    cur = run_dispatch(
-        lambda: dispatch(first_token, start_valid, budget_dev, first_done),
-        retry, deadline, budget=budget)
+    cur = run_dispatch(lambda: dispatch(max_new, None), retry, deadline,
+                       budget=budget)
     seg_idx = 0
     while True:
         # "segment" span (ISSUE 5): one per consumed decode segment —
         # the null-span singleton when telemetry is disarmed, so the
         # hot loop pays one module-flag check inside span().
-        with telemetry.span("segment", index=seg_idx, rows=b,
+        with telemetry.span("segment", index=seg_idx, rows=rows,
                             filtered_rows=filtered_rows):
-            out, steps, last, valid, done = cur
-            budget_dev = budget_dev - steps
+            out, steps, *carry = cur
+            done = carry[2]
             # Speculative queue while the device results are still in
             # flight — but never past the deadline (the host clock is
             # already known; queuing after it would run a whole wasted
@@ -463,7 +536,8 @@ def decode_segments(
             timed_out = time.monotonic() > deadline
             cancelled = budget is not None and budget.token.cancelled
             nxt = (run_dispatch(
-                lambda: dispatch(last, valid, budget_dev, done),
+                lambda: dispatch(
+                    max_new - DECODE_SEGMENT * (seg_idx + 1), tuple(carry)),
                 retry, deadline, budget=budget)
                 if produced + DECODE_SEGMENT < max_new and not timed_out
                 and not cancelled
@@ -493,7 +567,7 @@ def decode_segments(
                 f"({produced}/{max_new} tokens)")
         cur = nxt
     return (np.concatenate(segments, axis=1) if segments
-            else np.zeros((b, 0), np.int32))
+            else np.zeros((rows, 0), np.int32))
 
 
 class RaggedSeq:

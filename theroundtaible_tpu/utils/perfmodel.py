@@ -466,6 +466,7 @@ PERF_SERIES_PREFIXES = (
     "roundtable_decode_tps",
     "roundtable_sched_starved_seconds",  # ISSUE 37: the feed bit
     "roundtable_page_cop",  # ISSUE 38: page copies and their programs
+    "roundtable_dispatch_",  # ISSUE 53: buffers and launches a program
     "roundtable_compile", "roundtable_steady_state",
     "roundtable_kv_", "roundtable_hbm_", "roundtable_session_kv_",
     "roundtable_prefix_",   # ISSUE 7: prefix-cache hit/miss/size series

@@ -1284,6 +1284,19 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "copy_widths": "static (paging.COPY_WIDTHS, each compiled in "
                        "warmup)",
     },
+    # engine.describe()["dispatch"] (ISSUE 53): what the step seams
+    # sent and issued (serving_loop.note_issue is the one writer of the
+    # totals and the series; a `dispatch` span carries its own
+    # `host_buffers` and `launches`). One buffer and one launch a
+    # program where a dispatch is packed (engine/dispatch_pack.py).
+    "engine_dispatch": {
+        "programs": "roundtable_dispatch_programs_total (step programs "
+                    "and the prologue's sampler)",
+        "host_buffers": "roundtable_dispatch_host_buffers_total "
+                        "(host-to-device transfers made for them)",
+        "launches": "roundtable_dispatch_launches_total (device "
+                    "programs issued, the step program among them)",
+    },
     # engine.describe()["mla"] (ISSUE 31): latent pages — the second
     # page shape (engine/paging.py) — and the kernels that read them.
     # Static but for the positions read, which the scheduler's segment
