@@ -744,6 +744,50 @@ STARVED_SERIES = "roundtable_sched_starved_seconds_total"
 PAGE_COPIES_SERIES = "roundtable_page_copies_total"
 PAGE_COPY_PROGRAMS_SERIES = "roundtable_page_copy_programs_total"
 DISPATCH_SERIES_PREFIX = "roundtable_dispatch_"
+SETUP_SECONDS_SERIES = "roundtable_setup_seconds_total"
+SETUP_PROGRAMS_SERIES = "roundtable_setup_programs_total"
+
+
+def print_setup_split(setup: dict) -> None:
+    """Where a start's seconds went (compile_watch.summary()["setup"],
+    ISSUE 54): thread-seconds by stage of bringing a program up, wall
+    seconds by phase of the build, the programs compiled fresh by label
+    and those lowered more than once. Printed by `status --perf` and at
+    the end of `roundtable warmup`."""
+    stages, phases = setup["stages"], setup["phases"]
+    if not (any(stages.values()) or any(phases.values())):
+        return
+
+    def row(d: dict) -> str:
+        return "  ".join(f"{k} {v:.1f}s" for k, v in d.items())
+
+    state = "closed" if setup.get("closed") else "open"
+    head = f"    set-up: {setup['wall_s']:.1f}s wall ({state})  " \
+        if setup["wall_s"] else "    set-up:  "
+    print(style.dim(
+        f"{head}programs={setup['programs']}  "
+        f"cache_hits={setup['cache_hits']}  "
+        f"cache_misses={setup['cache_misses']}  "
+        f"saved={setup['saved_s']:.1f}s"))
+    print(style.dim(f"      stages (thread-seconds): {row(stages)}"))
+    unmarked = setup["wall_s"] - sum(phases.values())
+    print(style.dim(
+        f"      phases (wall): {row(phases)}"
+        + (f"  unmarked {unmarked:.1f}s" if setup["wall_s"] else "")))
+    if any(setup.get("staged", {}).values()):
+        print(style.dim(f"      of them in a stage: "
+                        f"{row(setup['staged'])}"))
+    for title, counts in (("compiled fresh", setup["misses"]),
+                          ("lowered more than once", setup["twice"])):
+        if counts:
+            print(style.dim(f"      {title}: " + ", ".join(
+                f"{k} x{n}" for k, n in counts.items())))
+    for r in setup["slowest"]:
+        last = "retrieve" if r.get("cache_hit") else "compile"
+        print(style.dim(
+            f"      {r['label']:<30} {r['fun_name']:<28} "
+            f"trace {r['trace_s']:.2f}  lower {r['lower_s']:.2f}  "
+            f"{last} {r.get(last + '_s', 0.0):.2f}"))
 
 
 def perf_status(session) -> int:
@@ -753,8 +797,9 @@ def perf_status(session) -> int:
     the device unfed, by phase), the page copies each program of the
     page cache's copier gathered and which copier that is, the host
     buffers and launches a step program cost, the compile
-    observatory's history and steady-state sentinel state, the memory
-    ledger, and the span-tree overhead breakdown."""
+    observatory's history, steady-state sentinel state and split of
+    the set-up, the memory ledger, and the span-tree overhead
+    breakdown."""
     from ..utils import perfmodel, telemetry
 
     print(style.bold(f"\n  Performance — session {session.name}"))
@@ -874,6 +919,23 @@ def perf_status(session) -> int:
         hit = " (cache hit)" if e.get("cache_hit") else ""
         print(style.dim(f"    {e['label']:<32} {e['dur_s']:>8.3f}s"
                         f"{hit}{flag}"))
+    setup = summary["setup"]
+    if not setup["wall_s"]:
+        # Another process served: its set-up's seconds by stage and
+        # phase, and its hits and misses, are in the exported series.
+        by = {_labels(k).get("stage"): v for k, v in perf.items()
+              if k.split("{")[0] == SETUP_SECONDS_SERIES}
+        outcomes = {_labels(k).get("outcome"): int(v)
+                    for k, v in perf.items()
+                    if k.split("{")[0] == SETUP_PROGRAMS_SERIES}
+        setup = dict(setup, staged={}, stages={k: by.get(k, 0.0)
+                                    for k in compile_watch.STAGES},
+                     phases={k: by.get(k, 0.0)
+                             for k in compile_watch.PHASES},
+                     programs=sum(outcomes.values()),
+                     cache_hits=outcomes.get("hit", 0),
+                     cache_misses=outcomes.get("miss", 0))
+    print_setup_split(setup)
     total = sum(v for k, v in perf.items()
                 if k.split("{")[0] == "roundtable_compiles_total")
     steady = sum(v for k, v in perf.items()

@@ -60,6 +60,12 @@ def warmup_command(project_root: str | None = None) -> int:
         print(f"  {style.green('✓')} {d['model']} on mesh {d['mesh']}: "
               f"built in {time.monotonic() - t0 - secs:.1f}s, "
               f"warmed in {secs:.1f}s")
+    # Where the seconds went (ISSUE 54): stages, phases, what compiled
+    # fresh — the block `status --perf` prints.
+    from ..engine import compile_watch
+    from .status import print_setup_split
+    print(style.bold("\n  Set-up:"))
+    print_setup_split(compile_watch.summary()["setup"])
     print(style.dim("\n  Programs are in the persistent compilation "
                     "cache — the next discuss starts hot.\n"))
     return 0

@@ -265,18 +265,23 @@ class InferenceEngine:
         # slots served under different adapters (the bytes differ).
         self._slot_adapters: dict[str, Optional[str]] = {}
 
-        if checkpoint:
-            from .checkpoint import load_hf_checkpoint
-            # The loader still lands the whole tree on the default
-            # device before this reshard (ROADMAP): an unsharded copy
-            # whose reference dies with this expression.
-            self.params = shard_params(
-                load_hf_checkpoint(checkpoint, model_cfg, dtype),
-                model_cfg, self.mesh)
-        else:
-            # Born sharded: no device ever holds more than its shard.
-            self.params = init_sharded_params(
-                model_cfg, jax.random.PRNGKey(seed), dtype, self.mesh)
+        # The build's own steps are phases of the set-up table (ISSUE
+        # 54): wall seconds, beside the stages the compile hooks hear.
+        with compile_watch.phase("init"):
+            if checkpoint:
+                from .checkpoint import load_hf_checkpoint
+                # The loader still lands the whole tree on the default
+                # device before this reshard (ROADMAP): an unsharded
+                # copy whose reference dies with this expression.
+                self.params = shard_params(
+                    load_hf_checkpoint(checkpoint, model_cfg, dtype),
+                    model_cfg, self.mesh)
+            else:
+                # Born sharded: no device ever holds more than its
+                # shard.
+                self.params = init_sharded_params(
+                    model_cfg, jax.random.PRNGKey(seed), dtype,
+                    self.mesh)
         if quant in ("int8", "int4"):
             # AFTER sharding: q/s are jnp ops on the sharded weights, so
             # XLA propagates the NamedShardings (engine/quant.py).
@@ -288,10 +293,11 @@ class InferenceEngine:
             # scales with whole groups per shard (engine/quant.py).
             from .quant import quantize_params
             from .sharding import model_axis_size
-            self.params = quantize_params(
-                self.params, model_cfg, act_dtype=dtype,
-                free_source=True, bits=8 if quant == "int8" else 4,
-                model_shards=model_axis_size(self.mesh))
+            with compile_watch.phase("quantize"):
+                self.params = quantize_params(
+                    self.params, model_cfg, act_dtype=dtype,
+                    free_source=True, bits=8 if quant == "int8" else 4,
+                    model_shards=model_axis_size(self.mesh))
         self.num_params = param_count(self.params)
 
         # Quantized KV pages (ISSUE 11): resolve the `kv_quant:` config
@@ -365,11 +371,12 @@ class InferenceEngine:
         # explicitly (up to num_slots*max_seq_len/page_size +
         # data_size: every slot at full length) when every knight
         # runs long.
-        self.kv = PagedKVCache(
-            model_cfg, num_slots, self.max_seq_len, dtype,
-            pool_sharding, page_size=page_size, num_pages=num_pages,
-            copy_pages_fn=copy_pages, data_size=data_size,
-            kv_quant=self.kv_quant_spec)
+        with compile_watch.phase("pools"):
+            self.kv = PagedKVCache(
+                model_cfg, num_slots, self.max_seq_len, dtype,
+                pool_sharding, page_size=page_size, num_pages=num_pages,
+                copy_pages_fn=copy_pages, data_size=data_size,
+                kv_quant=self.kv_quant_spec)
         reason = page_copy.decline_reason(
             jax.tree.leaves(self.kv.combined_pools()))
         if reason is not None:
@@ -1250,9 +1257,11 @@ class InferenceEngine:
             state_snapshot_bytes = (4 * num_slots
                                     * state_bytes_per_sequence(cfg,
                                                                self.dtype))
-        self.hybrid = HybridStateStore(
-            cfg, num_slots, page_size, state_snapshot_bytes,
-            engine=cfg.name, dtype=self.dtype)
+        from . import compile_watch
+        with compile_watch.phase("pools"):
+            self.hybrid = HybridStateStore(
+                cfg, num_slots, page_size, state_snapshot_bytes,
+                engine=cfg.name, dtype=self.dtype)
         from .models.hybrid import ROW_PARTS
 
         # ROW_PARTS are gathered to the batch's rows and scattered back;
@@ -1574,7 +1583,9 @@ class InferenceEngine:
             # composition, and traffic alone may first meet one of the
             # shapes long after start-up (a 21 s compile in the window:
             # my chip run, PR 27).
-            engine._warm_ragged()
+            from . import compile_watch
+            with compile_watch.phase("warm_programs"):
+                engine._warm_ragged()
         if "dispatch_retries" in config:
             from .faults import RetryPolicy
             engine.retry = RetryPolicy(
@@ -1602,6 +1613,8 @@ class InferenceEngine:
         # compiles as steady-state violations.
         from . import compile_watch
         compile_watch.reopen_warmup(self.cfg.name)
+        # (a phase of the set-up table; warmup_complete below ends it)
+        compile_watch.phase("warm_programs").begin()
         # Warm the adapter store's slot setters FIRST (ISSUE 10): a
         # steady-state hot-swap must compile nothing under STRICT, and
         # the serving warms below should trace against setter-produced
@@ -3625,6 +3638,8 @@ class InferenceEngine:
         info["perf"] = self.perf.describe()
         info["compile_cache"] = get_compile_cache_decision()
         info["compile_observatory"] = compile_watch.summary()
+        # ISSUE 54: the collector's pauses since the hooks went in.
+        info["gc"] = compile_watch.gc_report()
         return info
 
 

@@ -418,6 +418,9 @@ class SessionScheduler:
         # re-declares via declare_warmup_complete() once covered.
         from . import compile_watch
         compile_watch.reopen_warmup(self._tname)
+        # (the set-up table's last phase: from here to
+        # declare_warmup_complete(), which ends it)
+        compile_watch.phase("warm_traffic").begin()
         self._thread = threading.Thread(
             target=self._loop, daemon=True,
             name=f"session-scheduler-{getattr(engine.cfg, 'name', '?')}")

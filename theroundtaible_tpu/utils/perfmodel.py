@@ -468,6 +468,8 @@ PERF_SERIES_PREFIXES = (
     "roundtable_page_cop",  # ISSUE 38: page copies and their programs
     "roundtable_dispatch_",  # ISSUE 53: buffers and launches a program
     "roundtable_compile", "roundtable_steady_state",
+    "roundtable_setup_",  # ISSUE 54: a start's seconds, stage and phase
+    "roundtable_gc_",     # ISSUE 54: the collector's pauses
     "roundtable_kv_", "roundtable_hbm_", "roundtable_session_kv_",
     "roundtable_prefix_",   # ISSUE 7: prefix-cache hit/miss/size series
     "roundtable_spec_",     # ISSUE 9: speculation accept/rate series

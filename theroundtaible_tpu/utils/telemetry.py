@@ -821,15 +821,45 @@ def emit_span(rung: str, dur_s: float, **attrs) -> None:
     buffer, not to the flight ring (its caller records the event
     there) and not to the profiler, which has its own rows for such
     work. Call sites pre-guard with `if telemetry.ACTIVE:`."""
-    stack = _stack()
+    emit_span_at(rung, time.monotonic() - dur_s, dur_s, **attrs)
+
+
+def current_span_ids() -> Optional[tuple[str, str]]:
+    """(trace id, span id) of this thread's innermost open span, for a
+    caller that records under it later (`emit_span_at(parent=...)`)."""
+    stack = getattr(_tls, "stack", None)
+    return (stack[-1].trace_id, stack[-1].span_id) if stack else None
+
+
+def emit_span_at(rung: str, t0: float, dur_s: float, wait: bool = True,
+                 parent: Optional[tuple[str, str]] = None,
+                 **attrs) -> bool:
+    """`emit_span` for a span that began at `t0` (time.monotonic()) and
+    is over: one whose extent is known only some time after its end
+    (the outermost `trace` and `lower` intervals of a program, known
+    when it compiles). `wait=False` is for a caller that may not wait
+    for a lock — the collector's callback runs between any two
+    bytecodes of the thread it stopped, perhaps inside `_keep_span`
+    itself: the record then goes to the armed buffer alone, under
+    `parent`, and False means the buffer's lock was held and nothing
+    was recorded."""
+    if not wait and _spans_lock.locked():
+        # Perhaps by the very frame the collector stopped: waiting
+        # could be waiting for oneself. (Free now means this thread
+        # does not hold it, so _keep_span below cannot.)
+        return False
+    stack = _stack() if wait else ()
     top = stack[-1] if stack else None
+    if top is not None:
+        parent = (top.trace_id, top.span_id)
+    trace_id, parent_id = parent or (uuid.uuid4().hex[:16], "")
     record = _span_record(
-        top.trace_id if top else uuid.uuid4().hex[:16],
-        uuid.uuid4().hex[:12], top.span_id if top else "", rung,
-        time.time() - dur_s, time.monotonic() - dur_s, dur_s, "ok", attrs)
+        trace_id, uuid.uuid4().hex[:12], parent_id, rung,
+        time.time() - (time.monotonic() - t0), t0, dur_s, "ok", attrs)
     if top is not None and top.sink is not None:
         top.sink.write(record)
     _keep_span(record)
+    return True
 
 
 # ---------------------------------------------------------------------------
@@ -1296,6 +1326,45 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
                         "(host-to-device transfers made for them)",
         "launches": "roundtable_dispatch_launches_total (device "
                     "programs issued, the step program among them)",
+    },
+    # engine.describe()["compile_observatory"]["setup"] (ISSUE 54): the
+    # set-up table of engine/compile_watch.py, which is the one writer
+    # of both series — thread-seconds by stage (trace, lower, retrieve,
+    # compile) as JAX's monitoring events report them, wall seconds by
+    # phase (init, quantize, pools, warm_programs, warm_traffic) as
+    # `compile_watch.phase` marks them, from install() to the close.
+    "engine_setup": {
+        "closed": "derived (warmup_complete closed the table; "
+                  "describe-only)",
+        "wall_s": "derived (install() to the close, or to now; "
+                  "describe-only)",
+        "stages": "roundtable_setup_seconds_total{stage=trace|lower|"
+                  "retrieve|compile}",
+        "phases": "roundtable_setup_seconds_total{stage=init|quantize|"
+                  "pools|warm_programs|warm_traffic}",
+        "staged": "derived (the stages' seconds heard inside each "
+                  "phase; describe-only)",
+        "programs": "derived (lowerings heard; describe-only: "
+                    "hits + misses + lowerings never compiled)",
+        "cache_hits": "roundtable_setup_programs_total{outcome=hit}",
+        "cache_misses": "roundtable_setup_programs_total{outcome=miss}",
+        "saved_s": "derived (jax's compile_time_saved_sec summed; "
+                   "describe-only)",
+        "misses": "derived (labels compiled fresh, at most 32; "
+                  "describe-only)",
+        "twice": "derived (fun_names lowered more than once; "
+                 "describe-only)",
+        "slowest": "derived (the eight rows with most seconds; "
+                   "describe-only)",
+    },
+    # engine.describe()["gc"] (ISSUE 54): the collector's pauses by
+    # generation since install() — compile_watch's gc.callbacks hook
+    # sums them lock-free, gc_report() publishes both series.
+    "engine_gc": {
+        "pauses": "roundtable_gc_collections_total{generation=...}",
+        "seconds": "roundtable_gc_pause_seconds_total{generation=...}",
+        "longest_s": "derived (the longest single collection; "
+                     "describe-only)",
     },
     # engine.describe()["mla"] (ISSUE 31): latent pages — the second
     # page shape (engine/paging.py) — and the kernels that read them.
