@@ -369,6 +369,12 @@ def test_prefill_then_decode_logits_and_the_bfloat16_state_control(
                 retn.astype(jnp.bfloat16).astype(jnp.float32))
 
     monkeypatch.setattr(retention, "step_rows", rounded)
+    # (a layer's body is a function `jax.jit` has seen, and keeps the
+    # trace it made above: the control's layers are traced where they
+    # stand, so that they call what was just put in)
+    from theroundtaible_tpu.engine import paged_forward
+    monkeypatch.setattr(paged_forward, "_paged_hybrid_layer",
+                        paged_forward._paged_hybrid_layer.__wrapped__)
     off = np.abs(step_logits(engine, tokens, 41) - want).max()
     assert off > 5 * LOGIT_TOL, off
 

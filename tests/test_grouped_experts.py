@@ -195,9 +195,11 @@ def test_a_step_program_lowers_the_kernel_once_a_distinct_shape(
     defined = [line.split("@")[1].split("(")[0]
                for line in text.splitlines() if "func.func" in line]
     assert len([f for f in defined if f.startswith("routed_experts")]) == 1
-    assert text.count("tpu_custom_call") == 2 + gated
+    # (gate and up: one function of `grouped_matmul`'s own jit, called
+    # twice — ISSUE 55; before it the gated layer lowered three)
+    assert text.count("tpu_custom_call") == 2
     # ... and the visits are computed once for all of a layer's products.
-    assert text.count("kernel_name = \"grouped_matmul\"") == 2 + gated
+    assert text.count("kernel_name = \"grouped_matmul\"") == 2
 
 
 @pytest.mark.parametrize("m,k,n,groups", [

@@ -20,8 +20,8 @@ COMPILE = "/jax/core/compile/backend_compile_duration"
 RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 SAVED = "/jax/compilation_cache/compile_time_saved_sec"
 SETUP_KEYS = {"closed", "wall_s", "stages", "phases", "staged",
-              "programs", "cache_hits", "cache_misses", "saved_s", "misses",
-              "twice", "slowest"}
+              "programs", "bodies_traced", "bodies_reused", "cache_hits",
+              "cache_misses", "saved_s", "misses", "twice", "slowest"}
 
 
 @pytest.fixture(autouse=True)

@@ -746,14 +746,16 @@ PAGE_COPY_PROGRAMS_SERIES = "roundtable_page_copy_programs_total"
 DISPATCH_SERIES_PREFIX = "roundtable_dispatch_"
 SETUP_SECONDS_SERIES = "roundtable_setup_seconds_total"
 SETUP_PROGRAMS_SERIES = "roundtable_setup_programs_total"
+SETUP_BODIES_SERIES = "roundtable_setup_bodies_total"
 
 
 def print_setup_split(setup: dict) -> None:
     """Where a start's seconds went (compile_watch.summary()["setup"],
     ISSUE 54): thread-seconds by stage of bringing a program up, wall
     seconds by phase of the build, the programs compiled fresh by label
-    and those lowered more than once. Printed by `status --perf` and at
-    the end of `roundtable warmup`."""
+    and those lowered more than once, and how many calls of a layer's
+    body traced it and how many found it traced (ISSUE 55). Printed by
+    `status --perf` and at the end of `roundtable warmup`."""
     stages, phases = setup["stages"], setup["phases"]
     if not (any(stages.values()) or any(phases.values())):
         return
@@ -766,6 +768,8 @@ def print_setup_split(setup: dict) -> None:
         if setup["wall_s"] else "    set-up:  "
     print(style.dim(
         f"{head}programs={setup['programs']}  "
+        f"bodies traced={setup.get('bodies_traced', 0)} "
+        f"reused={setup.get('bodies_reused', 0)}  "
         f"cache_hits={setup['cache_hits']}  "
         f"cache_misses={setup['cache_misses']}  "
         f"saved={setup['saved_s']:.1f}s"))
@@ -928,11 +932,16 @@ def perf_status(session) -> int:
         outcomes = {_labels(k).get("outcome"): int(v)
                     for k, v in perf.items()
                     if k.split("{")[0] == SETUP_PROGRAMS_SERIES}
+        bodies = {_labels(k).get("outcome"): int(v)
+                  for k, v in perf.items()
+                  if k.split("{")[0] == SETUP_BODIES_SERIES}
         setup = dict(setup, staged={}, stages={k: by.get(k, 0.0)
                                     for k in compile_watch.STAGES},
                      phases={k: by.get(k, 0.0)
                              for k in compile_watch.PHASES},
                      programs=sum(outcomes.values()),
+                     bodies_traced=bodies.get("traced", 0),
+                     bodies_reused=bodies.get("reused", 0),
                      cache_hits=outcomes.get("hit", 0),
                      cache_misses=outcomes.get("miss", 0))
     print_setup_split(setup)

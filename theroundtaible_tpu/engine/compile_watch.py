@@ -34,10 +34,15 @@ into the PR-5 telemetry spine:
   thread-seconds by stage (per thread the outermost interval owns its
   seconds), one row a lowered program (a program lowered twice is two
   rows with one `fun_name`), cache hits and misses by
-  label, and the wall seconds of the build's own steps, marked with
-  `phase(...)` — so "why did this start take four minutes" is answered
-  by the process itself (`roundtable_setup_seconds_total{stage=...}`,
-  `roundtable_setup_programs_total{outcome=...}`);
+  label, the layer bodies a program's trace called — how many of the
+  calls traced the body (`bodies_traced`) and how many found it in
+  JAX's trace cache (`bodies_reused`: `note_body`, bumped by
+  `models/common.layer_body`) — and the wall seconds of the build's
+  own steps, marked with `phase(...)` — so "why did this start take
+  four minutes" is answered by the process itself
+  (`roundtable_setup_seconds_total{stage=...}`,
+  `roundtable_setup_programs_total{outcome=...}`,
+  `roundtable_setup_bodies_total{outcome=...}`);
 - the **steady-state sentinel**: `warmup_complete(label)` (called by
   both engines' warmup() and by SessionScheduler.declare_warmup_
   complete()) declares the compile set closed. Any compile after that
@@ -126,6 +131,10 @@ _steady_compiles = 0
 # dump — per label, so engine B's first violation still gets its
 # postmortem after engine A already dumped.
 _steady_dumped: set[str] = set()
+# Calls of a jitted layer body (models/common.layer_body) since the
+# process began: those that traced it, and those that did not have to.
+_bodies_traced = 0
+_bodies_reused = 0
 _tls = threading.local()
 
 
@@ -144,6 +153,8 @@ class _Setup:
         self.staged = dict.fromkeys(PHASES, 0.0)
         self.phase_open: Optional[tuple[str, float]] = None
         self.programs = 0
+        self.bodies_traced = 0
+        self.bodies_reused = 0
         self.cache_hits = 0
         self.cache_misses = 0
         self.saved_s = 0.0
@@ -316,7 +327,8 @@ def _new_row(lbl: str, attrs: dict, fun_name: Optional[str],
              wall_start: float) -> dict[str, Any]:
     row: dict[str, Any] = {
         "label": lbl, "fun_name": fun_name or "", "trace_s": 0.0,
-        "lower_s": 0.0, "cache_hit": None,
+        "lower_s": 0.0, "bodies_traced": 0, "bodies_reused": 0,
+        "cache_hit": None,
         "thread": threading.current_thread().name,
         # the interval's start, moved from the wall clock to
         # time.monotonic() (the span buffer's clock)
@@ -355,6 +367,40 @@ def _list_row(row: dict[str, Any]) -> None:
         _setup.rows_dropped += 1
 
 
+def note_body(traced: bool) -> None:
+    """A call of a jitted layer body (models/common.layer_body) has
+    returned on this thread: it traced the body, or found it in JAX's
+    trace cache. Counted only under a program's trace or lowering (a
+    body called eagerly is its own program); the thread's outermost
+    interval takes the tally when it ends: `bodies_traced` and
+    `bodies_reused` of that program's row."""
+    if getattr(_tls, "depth", 0) <= 0:
+        return
+    t, r = getattr(_tls, "bodies", (0, 0))
+    _tls.bodies = (t + bool(traced), r + (not traced))
+
+
+def _take_bodies(row: dict[str, Any], counted: bool) -> None:
+    """(under `_state_lock`) The body calls this thread made inside the
+    outermost interval that just ended, to its row and the totals."""
+    global _bodies_traced, _bodies_reused
+    traced, reused = getattr(_tls, "bodies", (0, 0))
+    if not (traced or reused):
+        return
+    _tls.bodies = (0, 0)
+    row["bodies_traced"] += traced
+    row["bodies_reused"] += reused
+    _bodies_traced += traced
+    _bodies_reused += reused
+    if counted:
+        _setup.bodies_traced += traced
+        _setup.bodies_reused += reused
+        telemetry.inc("roundtable_setup_bodies_total", traced,
+                      outcome="traced")
+        telemetry.inc("roundtable_setup_bodies_total", reused,
+                      outcome="reused")
+
+
 def _on_scalar(event: str, _value: float, **_kw) -> None:
     """An interval of a stage opens on this thread (JAX reports the
     start of every timed section as a scalar): one level deeper."""
@@ -390,6 +436,7 @@ def _on_time_span(event: str, start: float, end: float, **kw) -> None:
         row["fun_name"] = fun_name or row["fun_name"]
         if counted:
             _add_stage(stage, dur)
+        _take_bodies(row, counted)
         if stage == "lower":
             row["lowered"] = True
             if counted:
@@ -664,6 +711,8 @@ def setup_report() -> dict[str, Any]:
             "phases": {k: round(v, 4) for k, v in phases.items()},
             "staged": {k: round(v, 4) for k, v in t.staged.items()},
             "programs": t.programs,
+            "bodies_traced": t.bodies_traced,
+            "bodies_reused": t.bodies_reused,
             "cache_hits": t.cache_hits,
             "cache_misses": t.cache_misses,
             "saved_s": round(t.saved_s, 4),
@@ -700,6 +749,8 @@ def summary(recent: int = 0) -> dict[str, Any]:
             "compiles": _compiles,
             "cache_hits": _cache_hits,
             "cache_misses": _cache_misses,
+            "bodies_traced": _bodies_traced,
+            "bodies_reused": _bodies_reused,
             "steady_state": sorted(_steady_labels),
             "steady_state_compiles": _steady_compiles,
             "strict": strict_armed(),

@@ -48,6 +48,7 @@ from .models.common import (
     _einsum,
     _softcap,
     embed_tokens,
+    layer_body,
     project_qkv,
     rms_norm,
     transformer_block,
@@ -191,6 +192,30 @@ def ulysses_attention(q, k, v, q_pos, kv_valid, cfg: ModelConfig,
                               tiled=True)
 
 
+@layer_body(static=("cfg", "scheme", "n"))
+def _ring_block(x, layer, q_pos, lengths, *, cfg: ModelConfig, scheme: str,
+                n: int):
+    """The sequence-parallel prefill's layer as a body (models/common.
+    layer_body), traced inside the shard_map: one block over this
+    shard's rows, its attention core the ring's or Ulysses' over the
+    `seq` axis of size `n`. -> (x, this shard's (k, v))."""
+
+    def attn_fn(h, layer):
+        q, k, v = project_qkv(h, layer, cfg, q_pos)
+        if scheme == "ulysses":
+            core = ulysses_attention(q, k, v, q_pos, lengths, cfg,
+                                     SEQ_AXIS, n)
+        else:
+            core = ring_attention(q, k, v, q_pos, q_pos, lengths, cfg,
+                                  SEQ_AXIS, n)
+        out = _einsum("bthd,hde->bte", core, layer["o_proj"],
+                      tp="row").astype(h.dtype)
+        return out, (k, v)
+
+    return transformer_block(x, layer, cfg, q_pos, None, None, None,
+                             attn_fn=attn_fn)
+
+
 def make_ring_prefill(cfg: ModelConfig, mesh: Mesh, scheme: str = "ring"):
     """Build the jitted sequence-parallel prefill program.
 
@@ -209,24 +234,10 @@ def make_ring_prefill(cfg: ModelConfig, mesh: Mesh, scheme: str = "ring"):
         x = embed_tokens(params["embedding"], tokens)
         if cfg.scale_embeddings:
             x = x * jnp.sqrt(jnp.float32(cfg.embed_dim)).astype(x.dtype)
-        q_pos = positions
-
-        def attn_fn(h, layer):
-            q, k, v = project_qkv(h, layer, cfg, q_pos)
-            if scheme == "ulysses":
-                core = ulysses_attention(q, k, v, q_pos, lengths, cfg,
-                                         SEQ_AXIS, n)
-            else:
-                core = ring_attention(q, k, v, q_pos, q_pos, lengths, cfg,
-                                      SEQ_AXIS, n)
-            out = _einsum("bthd,hde->bte", core, layer["o_proj"],
-                          tp="row").astype(h.dtype)
-            return out, (k, v)
-
         caches = []
         for layer in params["layers"]:
-            x, kv = transformer_block(x, layer, cfg, q_pos, None, None,
-                                      None, attn_fn=attn_fn)
+            x, kv = _ring_block(x, layer, positions, lengths, cfg=cfg,
+                                scheme=scheme, n=n)
             caches.append(kv)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps,
                      cfg.rmsnorm_unit_offset)

@@ -79,6 +79,126 @@ def _current_int4_sink():
     return stack[-1][1] if stack else None
 
 
+# --- a layer's body is a function `jax.jit` has seen (ISSUE 55) ---
+#
+# A step program walks its layers in Python. Traced where they stand,
+# n equal blocks are n traces of the same norms, projections, mixer and
+# kernel wrapper, and n lowerings of every `pallas_call` among them — a
+# program's set-up grows with its depth (PERF.md, Findings PR 54, 55).
+# Behind `layer_body` a block is ONE jitted function of (the residual
+# stream, that layer's leaves, that layer's pools or state, the
+# dispatch's arrays) with all that is static about it passed as static,
+# hashable arguments: JAX's trace cache returns the first layer's jaxpr
+# for every later layer of the same signature, the lowering emits one
+# private function and calls it, and XLA inlines the calls — the
+# compiled program, the trees, the pools and the donation stay what
+# they were.
+#
+# THE RULE A NEW BLOCK KIND FOLLOWS: its body closes over nothing a
+# layer owns and nothing an enclosing trace made (no tracer, no
+# per-layer closure: a fresh closure a layer is a fresh cache key);
+# whatever differs between two layers is an ARGUMENT — arrays (and
+# None) positional, the rest (`ModelConfig` or `cfg.attention_layer(i)`'s
+# frozen view, a kind, a page size) by keyword, named in `static`, and
+# hashable. Two layers that differ in something static are two
+# signatures and two traces: the arguments say so, nothing tests a
+# model's name. Whatever else its code reads while it is traced — an
+# environment lever, a kernel module's `_interpret` — is listed in
+# `_switches`, which is part of every body's key.
+
+
+class _ById:
+    """A trace-time sink (an engine's own dict) as a static argument:
+    equal only to itself, so two engines never share a trace whose
+    records went into one of them."""
+
+    __slots__ = ("ref",)
+
+    def __init__(self, ref):
+        self.ref = ref
+
+    def __hash__(self):
+        return id(self.ref)
+
+    def __eq__(self, other):
+        return isinstance(other, _ById) and other.ref is self.ref
+
+
+def _switches() -> tuple:
+    """Every switch a body's trace reads that is neither an argument nor
+    a scope: the two A/B levers (ROUNDTABLE_INT4_MM, ROUNDTABLE_LORA_MM)
+    and whether each kernel module interprets its kernels (off the chip;
+    a compile test patches it). Read when the body is CALLED and part of
+    its static key, so flipping one between two calls of the same shapes
+    is another signature and another trace, never the first one's."""
+    from ..pallas import attention, grouped, int4mm, retention
+    from ..pallas import lora as plora
+    return (int4mm.enabled(), plora.enabled()) + tuple(
+        m._interpret()
+        for m in (attention, grouped, int4mm, plora, retention))
+
+
+def _announced():
+    """What a body's trace reads besides its arguments: (the static part
+    — the mesh, the two provenance sinks by identity, the adapter
+    stack's quantization, the trace-time switches — and the arrays of
+    the lora scope, which the body takes as an argument and announces
+    again as its own)."""
+    from ..lora import _current_scope
+    stack = getattr(_MESH_CTX, "stack", None)
+    mesh, sink = stack[-1] if stack else (None, None)
+    scope = _current_scope()
+    if scope is None:
+        return (mesh, _ById(sink), None, _switches()), None
+    return (mesh, _ById(sink), (_ById(scope.sink), scope.quant),
+            _switches()), scope.payload
+
+
+# Whether the innermost body call on this thread ran its Python: JAX's
+# trace cache missed, and the body was traced (`layer_body`).
+_BODY = _threading.local()
+
+
+def layer_body(static: tuple[str, ...] = ()):
+    """Decorator: `fn(*arrays, **static)` as a layer's body (the rule
+    above). A call under a program's trace counts itself on the compile
+    watch's set-up table, once: as traced if the call ran `fn`, else as
+    reused (`bodies_traced` / `bodies_reused` of that program's row).
+    `fn` itself stays at `__wrapped__`."""
+
+    def wrap(fn):
+        from .. import compile_watch
+
+        def body(lora, *args, _scopes, **kw):
+            _BODY.traced = True
+            if _scopes[2] is None:
+                return fn(*args, **kw)
+            from ..lora import lora_scope
+            sink, quant = _scopes[2]
+            with lora_scope(lora, sink=sink.ref, quant=quant):
+                return fn(*args, **kw)
+
+        body.__name__ = body.__qualname__ = fn.__name__
+        jitted = jax.jit(body, static_argnames=static + ("_scopes",))
+
+        @functools.wraps(fn)
+        def call(*args, **kw):
+            scopes, lora = _announced()
+            # (a body called from a body's trace: the outer's flag is
+            # put back when this call is done)
+            outer, _BODY.traced = getattr(_BODY, "traced", False), False
+            try:
+                out = jitted(lora, *args, _scopes=scopes, **kw)
+                compile_watch.note_body(_BODY.traced)
+            finally:
+                _BODY.traced = outer
+            return out
+
+        return call
+
+    return wrap
+
+
 # Path-provenance labels for int4 einsum dispatches (ISSUE 3): the next
 # hardware window's numbers must be attributable to the kernel, not a
 # silent fallback, so every Int4Leaf dispatch records which path it
@@ -833,6 +953,15 @@ def transformer_block(
     return x + mlp_out, new_cache
 
 
+@layer_body(static=("cfg",))
+def _cached_block(x, layer, positions, kv_cache, cache_offset, attn_mask,
+                  kv_valid, *, cfg: ModelConfig):
+    """`forward`'s layer as a body (`layer_body`): one block over the
+    position-aligned cache of that layer (None: none)."""
+    return transformer_block(x, layer, cfg, positions, kv_cache,
+                             cache_offset, attn_mask, kv_valid=kv_valid)
+
+
 def make_attention_mask(positions: jax.Array, kv_len: int,
                         kv_valid_len: jax.Array,
                         sliding_window: Optional[int]) -> jax.Array:
@@ -892,9 +1021,9 @@ def forward(
     new_caches = []
     for i, layer in enumerate(params["layers"]):
         cache_i = kv_caches[i] if kv_caches is not None else None
-        x, new_cache = transformer_block(
-            x, layer, cfg, positions, cache_i, cache_offset, mask,
-            kv_valid=kv_valid_len)
+        x, new_cache = _cached_block(
+            x, layer, positions, cache_i, cache_offset, mask,
+            kv_valid_len, cfg=cfg)
         new_caches.append(new_cache)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps,

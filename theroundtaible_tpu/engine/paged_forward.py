@@ -34,6 +34,7 @@ so this module never traces an unpartitionable kernel.
 
 from __future__ import annotations
 
+import collections
 from typing import Optional
 
 import jax
@@ -43,8 +44,8 @@ from . import kv_quant as kvq
 from .models import mla
 from .models.common import (MASK_VALUE, ModelConfig, Params, _einsum,
                             _softcap, current_spmd_mesh, embed_tokens,
-                            gate_heads, gather_rows, mlp, project_qkv,
-                            rms_norm, transformer_block)
+                            gate_heads, gather_rows, layer_body, mlp,
+                            project_qkv, rms_norm, transformer_block)
 from .pallas import attention as pattn
 
 
@@ -54,6 +55,100 @@ def _cells(entries: jax.Array, pool: jax.Array) -> jax.Array:
     ModelConfig.lane_pack; a latent entry [..., W] as it is)."""
     lead = entries.ndim - (pool.ndim - 2)
     return entries.reshape(entries.shape[:lead] + pool.shape[2:])
+
+
+@layer_body(static=("cfg", "pool_replicas", "quant_spec", "kernel_quant"))
+def _paged_block(x, layer, pools, positions, pages, offs, table,
+                 kv_valid_len, *, cfg: ModelConfig, pool_replicas: int,
+                 quant_spec, kernel_quant: bool):
+    """forward_paged's layer as a body (models/common.layer_body): one
+    block over `pools` — that layer's (k_pool, v_pool, k_scale, v_scale),
+    the scales None where the pool is not quantized — and the dispatch's
+    arrays. -> (x, the four as the block leaves them)."""
+    k_pool, v_pool, k_sc, v_sc = pools
+    quant = k_sc is not None
+    kv_bits = quant_spec.bits if quant else 8
+    t = positions.shape[1]
+    page_size = k_pool.shape[1]
+
+    def attn_fn(h, layer):
+        q, k, v = project_qkv(h, layer, cfg, positions)
+        # Scatter this call's K/V into the rows' pages (write ranges
+        # are exclusive after COW, see module docstring) BEFORE the
+        # kernel reads the pool — quantize-on-write when the pool
+        # is quantized (per-cell scales: a token's write never
+        # touches its neighbours' quantization).
+        if quant:
+            k_q, k_s = kvq.quantize_cells(k, quant_spec)
+            v_q, v_s = kvq.quantize_cells(v, quant_spec)
+            k_pool2 = k_pool.at[pages, offs].set(k_q)
+            v_pool2 = v_pool.at[pages, offs].set(v_q)
+            k_sc2 = k_sc.at[pages, offs].set(k_s)
+            v_sc2 = v_sc.at[pages, offs].set(v_s)
+        else:
+            k_pool2 = k_pool.at[pages, offs].set(_cells(k, k_pool))
+            v_pool2 = v_pool.at[pages, offs].set(_cells(v, v_pool))
+            k_sc2 = v_sc2 = None
+        if quant and not kernel_quant:
+            # Declined shape: dequantize the pool for a bf16 kernel
+            # call (direct-caller fallback — the engine's serving
+            # path uses the gather view for these shapes).
+            kp, vp = (kvq.dequantize_cells(k_pool2, k_sc2,
+                                           quant_spec, q.dtype),
+                      kvq.dequantize_cells(v_pool2, v_sc2,
+                                           quant_spec, q.dtype))
+            ks = vs = None
+        else:
+            kp, vp = k_pool2, v_pool2
+            ks, vs = k_sc2, v_sc2
+        mesh = current_spmd_mesh()
+        multi = mesh is not None and mesh.size > 1
+        if t == 1:
+            if multi:
+                out = pattn.paged_decode_spmd(
+                    mesh, q, kp, vp, table, kv_valid_len,
+                    sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap,
+                    pool_replicas=pool_replicas,
+                    k_scale=ks, v_scale=vs, kv_bits=kv_bits)
+            else:
+                out = pattn.paged_decode_attention(
+                    q, kp, vp, table, kv_valid_len,
+                    sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap,
+                    k_scale=ks, v_scale=vs, kv_bits=kv_bits)
+        else:
+            if multi:
+                out = pattn.paged_prefill_spmd(
+                    mesh, q, kp, vp, table,
+                    positions[:, 0], kv_valid_len,
+                    sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap,
+                    pool_replicas=pool_replicas,
+                    k_scale=ks, v_scale=vs, kv_bits=kv_bits)
+            else:
+                out = pattn.paged_prefill_attention(
+                    q, kp, vp, table, positions[:, 0],
+                    kv_valid_len,
+                    sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap,
+                    k_scale=ks, v_scale=vs, kv_bits=kv_bits)
+        if out is None:
+            # engine.paged_direct gates on spmd_partitionable and
+            # serving buckets always satisfy the block check, so
+            # this cannot happen in serving — fail loudly for direct
+            # misuse rather than silently going dense.
+            raise ValueError(
+                "paged pool-direct serving under a multi-device "
+                "mesh needs a head layout that partitions over the "
+                f"model axis AND a block-legal chunk (T={t}, "
+                f"ps={page_size})")
+        out = _einsum("bthd,hde->bte", out, layer["o_proj"],
+                      tp="row", lora="o_proj").astype(h.dtype)
+        return out, (k_pool2, v_pool2, k_sc2, v_sc2)
+
+    return transformer_block(x, layer, cfg, positions, None, None, None,
+                             attn_fn=attn_fn)
 
 
 def forward_paged(
@@ -101,92 +196,16 @@ def forward_paged(
         x = x * jnp.sqrt(jnp.float32(cfg.embed_dim)).astype(x.dtype)
 
     quant = scales is not None
-    kv_bits = quant_spec.bits if quant else 8
     new_pools = []
     new_scales = []
     for li, (layer, (k_pool, v_pool)) in enumerate(
             zip(params["layers"], pools)):
         k_sc, v_sc = scales[li] if quant else (None, None)
-
-        def attn_fn(h, layer, k_pool=k_pool, v_pool=v_pool,
-                    k_sc=k_sc, v_sc=v_sc):
-            q, k, v = project_qkv(h, layer, cfg, positions)
-            # Scatter this call's K/V into the rows' pages (write ranges
-            # are exclusive after COW, see module docstring) BEFORE the
-            # kernel reads the pool — quantize-on-write when the pool
-            # is quantized (per-cell scales: a token's write never
-            # touches its neighbours' quantization).
-            if quant:
-                k_q, k_s = kvq.quantize_cells(k, quant_spec)
-                v_q, v_s = kvq.quantize_cells(v, quant_spec)
-                k_pool2 = k_pool.at[pages, offs].set(k_q)
-                v_pool2 = v_pool.at[pages, offs].set(v_q)
-                k_sc2 = k_sc.at[pages, offs].set(k_s)
-                v_sc2 = v_sc.at[pages, offs].set(v_s)
-            else:
-                k_pool2 = k_pool.at[pages, offs].set(_cells(k, k_pool))
-                v_pool2 = v_pool.at[pages, offs].set(_cells(v, v_pool))
-                k_sc2 = v_sc2 = None
-            if quant and not kernel_quant:
-                # Declined shape: dequantize the pool for a bf16 kernel
-                # call (direct-caller fallback — the engine's serving
-                # path uses the gather view for these shapes).
-                kp, vp = (kvq.dequantize_cells(k_pool2, k_sc2,
-                                               quant_spec, q.dtype),
-                          kvq.dequantize_cells(v_pool2, v_sc2,
-                                               quant_spec, q.dtype))
-                ks = vs = None
-            else:
-                kp, vp = k_pool2, v_pool2
-                ks, vs = k_sc2, v_sc2
-            mesh = current_spmd_mesh()
-            multi = mesh is not None and mesh.size > 1
-            if t == 1:
-                if multi:
-                    out = pattn.paged_decode_spmd(
-                        mesh, q, kp, vp, table, kv_valid_len,
-                        sliding_window=cfg.sliding_window,
-                        softcap=cfg.attn_logit_softcap,
-                        pool_replicas=pool_replicas,
-                        k_scale=ks, v_scale=vs, kv_bits=kv_bits)
-                else:
-                    out = pattn.paged_decode_attention(
-                        q, kp, vp, table, kv_valid_len,
-                        sliding_window=cfg.sliding_window,
-                        softcap=cfg.attn_logit_softcap,
-                        k_scale=ks, v_scale=vs, kv_bits=kv_bits)
-            else:
-                if multi:
-                    out = pattn.paged_prefill_spmd(
-                        mesh, q, kp, vp, table,
-                        positions[:, 0], kv_valid_len,
-                        sliding_window=cfg.sliding_window,
-                        softcap=cfg.attn_logit_softcap,
-                        pool_replicas=pool_replicas,
-                        k_scale=ks, v_scale=vs, kv_bits=kv_bits)
-                else:
-                    out = pattn.paged_prefill_attention(
-                        q, kp, vp, table, positions[:, 0],
-                        kv_valid_len,
-                        sliding_window=cfg.sliding_window,
-                        softcap=cfg.attn_logit_softcap,
-                        k_scale=ks, v_scale=vs, kv_bits=kv_bits)
-            if out is None:
-                # engine.paged_direct gates on spmd_partitionable and
-                # serving buckets always satisfy the block check, so
-                # this cannot happen in serving — fail loudly for direct
-                # misuse rather than silently going dense.
-                raise ValueError(
-                    "paged pool-direct serving under a multi-device "
-                    "mesh needs a head layout that partitions over the "
-                    f"model axis AND a block-legal chunk (T={t}, "
-                    f"ps={page_size})")
-            out = _einsum("bthd,hde->bte", out, layer["o_proj"],
-                          tp="row", lora="o_proj").astype(h.dtype)
-            return out, (k_pool2, v_pool2, k_sc2, v_sc2)
-
-        x, new_cache = transformer_block(
-            x, layer, cfg, positions, None, None, None, attn_fn=attn_fn)
+        x, new_cache = _paged_block(
+            x, layer, (k_pool, v_pool, k_sc, v_sc), positions, pages,
+            offs, table, kv_valid_len, cfg=cfg,
+            pool_replicas=pool_replicas, quant_spec=quant_spec,
+            kernel_quant=kernel_quant)
         new_pools.append(new_cache[:2])
         if quant:
             new_scales.append(new_cache[2:])
@@ -259,6 +278,86 @@ def _ragged_xla_attention(q, k_pool, v_pool, tables, token_seq,
     return jnp.einsum("thl,tlhd->thd", probs, vt).astype(q.dtype)
 
 
+@layer_body(static=("cfg", "attn_path", "quant_spec"))
+def _ragged_block(x, layer, pools, positions, walk, token_pages,
+                  token_offs, token_seq, copy_src, copy_dst, *,
+                  cfg: ModelConfig, attn_path: str, quant_spec):
+    """forward_ragged's layer as a body (models/common.layer_body): one
+    block over the flat buffer [1, T, E], `pools` that layer's (k_pool,
+    v_pool, k_scale, v_scale), `walk` the ragged kernel's (tables,
+    seq_of_block, block_qstart, query_offsets, kv_valid). -> (x, the
+    four as the block leaves them)."""
+    k_pool, v_pool, k_sc, v_sc = pools
+    tables, seq_of_block, block_qstart, query_offsets, kv_valid = walk
+    quant = k_sc is not None
+    kv_bits = quant_spec.bits if quant else 8
+    pos2 = positions[None]
+
+    def attn_fn(h, layer, k_pool=k_pool, v_pool=v_pool, k_sc=k_sc,
+                v_sc=v_sc):
+        q, k, v = project_qkv(h, layer, cfg, pos2)          # [1,T,H,D]
+        if copy_src is not None:
+            # Tree-path pre-COW (ISSUE 13): private frontier pages
+            # receive the committed cells before this layer's
+            # scatter can write draft cells into them.
+            k_pool = k_pool.at[copy_dst].set(k_pool[copy_src])
+            v_pool = v_pool.at[copy_dst].set(v_pool[copy_src])
+            if quant:
+                k_sc = k_sc.at[copy_dst].set(k_sc[copy_src])
+                v_sc = v_sc.at[copy_dst].set(v_sc[copy_src])
+        if quant:
+            # Quantize-on-write (ISSUE 11): each flat-buffer token
+            # writes its own payload + scale; pads land on the
+            # scratch page, never read.
+            k_q, k_s = kvq.quantize_cells(k[0], quant_spec)
+            v_q, v_s = kvq.quantize_cells(v[0], quant_spec)
+            k_pool2 = k_pool.at[token_pages, token_offs].set(k_q)
+            v_pool2 = v_pool.at[token_pages, token_offs].set(v_q)
+            k_sc2 = k_sc.at[token_pages, token_offs].set(k_s)
+            v_sc2 = v_sc.at[token_pages, token_offs].set(v_s)
+        else:
+            k_pool2 = k_pool.at[token_pages, token_offs].set(
+                _cells(k[0], k_pool))
+            v_pool2 = v_pool.at[token_pages, token_offs].set(
+                _cells(v[0], v_pool))
+            k_sc2 = v_sc2 = None
+        if attn_path == "kernel":
+            mesh = current_spmd_mesh()
+            if mesh is not None and mesh.size > 1:
+                out = pattn.ragged_paged_spmd(
+                    mesh, q[0], k_pool2, v_pool2, tables,
+                    seq_of_block, block_qstart, query_offsets,
+                    kv_valid, sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap,
+                    k_scale=k_sc2, v_scale=v_sc2, kv_bits=kv_bits)
+                if out is None:
+                    # The engine gates ragged_path on
+                    # partitionability at build time — reaching
+                    # here is direct misuse, fail loudly.
+                    raise ValueError(
+                        "ragged kernel cannot partition this head "
+                        "layout — engine should have resolved "
+                        "attn_path='xla'")
+            else:
+                out = pattn.ragged_paged_attention(
+                    q[0], k_pool2, v_pool2, tables, seq_of_block,
+                    block_qstart, query_offsets, kv_valid,
+                    sliding_window=cfg.sliding_window,
+                    softcap=cfg.attn_logit_softcap,
+                    k_scale=k_sc2, v_scale=v_sc2, kv_bits=kv_bits)
+        else:
+            out = _ragged_xla_attention(
+                q[0], k_pool2, v_pool2, tables, token_seq,
+                positions, kv_valid, cfg, k_sc=k_sc2, v_sc=v_sc2,
+                quant_spec=quant_spec)
+        out = _einsum("bthd,hde->bte", out[None], layer["o_proj"],
+                      tp="row", lora="o_proj").astype(h.dtype)
+        return out, (k_pool2, v_pool2, k_sc2, v_sc2)
+
+    return transformer_block(x, layer, cfg, pos2, None, None, None,
+                             attn_fn=attn_fn)
+
+
 def forward_ragged(
     params: Params, cfg: ModelConfig,
     tokens: jax.Array,            # [T] flat token buffer
@@ -315,76 +414,16 @@ def forward_ragged(
     pos2 = positions[None]
 
     quant = scales is not None
-    kv_bits = quant_spec.bits if quant else 8
     new_pools = []
     new_scales = []
+    walk = (tables, seq_of_block, block_qstart, query_offsets, kv_valid)
     for li, (layer, (k_pool, v_pool)) in enumerate(
             zip(params["layers"], pools)):
         k_sc, v_sc = scales[li] if quant else (None, None)
-
-        def attn_fn(h, layer, k_pool=k_pool, v_pool=v_pool,
-                    k_sc=k_sc, v_sc=v_sc):
-            q, k, v = project_qkv(h, layer, cfg, pos2)      # [1,T,H,D]
-            if copy_src is not None:
-                # Tree-path pre-COW (ISSUE 13): private frontier pages
-                # receive the committed cells before this layer's
-                # scatter can write draft cells into them.
-                k_pool = k_pool.at[copy_dst].set(k_pool[copy_src])
-                v_pool = v_pool.at[copy_dst].set(v_pool[copy_src])
-                if quant:
-                    k_sc = k_sc.at[copy_dst].set(k_sc[copy_src])
-                    v_sc = v_sc.at[copy_dst].set(v_sc[copy_src])
-            if quant:
-                # Quantize-on-write (ISSUE 11): each flat-buffer token
-                # writes its own payload + scale; pads land on the
-                # scratch page, never read.
-                k_q, k_s = kvq.quantize_cells(k[0], quant_spec)
-                v_q, v_s = kvq.quantize_cells(v[0], quant_spec)
-                k_pool2 = k_pool.at[token_pages, token_offs].set(k_q)
-                v_pool2 = v_pool.at[token_pages, token_offs].set(v_q)
-                k_sc2 = k_sc.at[token_pages, token_offs].set(k_s)
-                v_sc2 = v_sc.at[token_pages, token_offs].set(v_s)
-            else:
-                k_pool2 = k_pool.at[token_pages, token_offs].set(
-                    _cells(k[0], k_pool))
-                v_pool2 = v_pool.at[token_pages, token_offs].set(
-                    _cells(v[0], v_pool))
-                k_sc2 = v_sc2 = None
-            if attn_path == "kernel":
-                mesh = current_spmd_mesh()
-                if mesh is not None and mesh.size > 1:
-                    out = pattn.ragged_paged_spmd(
-                        mesh, q[0], k_pool2, v_pool2, tables,
-                        seq_of_block, block_qstart, query_offsets,
-                        kv_valid, sliding_window=cfg.sliding_window,
-                        softcap=cfg.attn_logit_softcap,
-                        k_scale=k_sc2, v_scale=v_sc2, kv_bits=kv_bits)
-                    if out is None:
-                        # The engine gates ragged_path on
-                        # partitionability at build time — reaching
-                        # here is direct misuse, fail loudly.
-                        raise ValueError(
-                            "ragged kernel cannot partition this head "
-                            "layout — engine should have resolved "
-                            "attn_path='xla'")
-                else:
-                    out = pattn.ragged_paged_attention(
-                        q[0], k_pool2, v_pool2, tables, seq_of_block,
-                        block_qstart, query_offsets, kv_valid,
-                        sliding_window=cfg.sliding_window,
-                        softcap=cfg.attn_logit_softcap,
-                        k_scale=k_sc2, v_scale=v_sc2, kv_bits=kv_bits)
-            else:
-                out = _ragged_xla_attention(
-                    q[0], k_pool2, v_pool2, tables, token_seq,
-                    positions, kv_valid, cfg, k_sc=k_sc2, v_sc=v_sc2,
-                    quant_spec=quant_spec)
-            out = _einsum("bthd,hde->bte", out[None], layer["o_proj"],
-                          tp="row", lora="o_proj").astype(h.dtype)
-            return out, (k_pool2, v_pool2, k_sc2, v_sc2)
-
-        x, new_cache = transformer_block(
-            x, layer, cfg, pos2, None, None, None, attn_fn=attn_fn)
+        x, new_cache = _ragged_block(
+            x, layer, (k_pool, v_pool, k_sc, v_sc), positions, walk,
+            token_pages, token_offs, token_seq, copy_src, copy_dst,
+            cfg=cfg, attn_path=attn_path, quant_spec=quant_spec)
         new_pools.append(new_cache[:2])
         if quant:
             new_scales.append(new_cache[2:])
@@ -410,7 +449,20 @@ def forward_ragged(
 # of Mamba-1 blocks is one entry of `params["layers"]` (leaves stacked
 # along a layer axis) and ONE `lax.scan` (`_scan_run`), so a program
 # holds one body a run whatever the depth; every other layer is a run of
-# one and is traced where it stands, as before.
+# one and goes through ONE jitted body a step-program family
+# (`_paged_hybrid_layer`, `_ragged_hybrid_layer`: models/common.
+# layer_body), its kind and the config it reads static — so the layers
+# of one signature are traced once and lowered once a program.
+#
+# The rule a new block kind follows (models/common.py has it in full):
+# a branch of the body reads only its arguments — x, that layer's
+# leaves, `own` (that layer's pools, or its parts of the state in
+# `_STATE_PARTS`' order), `held` (where its capture is written) and the
+# dispatch's arrays `d` — and what is static about it is `kind`, `cfg`
+# (for an attention layer `cfg.attention_layer(i)`, a frozen view equal
+# where two layers' geometry is) and `page_size`. It closes over nothing
+# a layer owns; a layer that differs in a static field is a second
+# trace, and nothing here tests a model's name.
 
 
 def _hybrid_head(params, cfg, x):
@@ -478,6 +530,136 @@ def _attention_out(out, h, layer, cfg: ModelConfig, dtype):
                    layer["o_proj"], tp="row").astype(dtype)
 
 
+# The parts of the state a layer of each kind advances, in the order its
+# body takes and returns them (a scanned Mamba-1 run: `_scan_run`).
+_STATE_PARTS = {"retention": ("ret", "retn"), "mamba2": ("ssm", "conv"),
+                "shortconv": ("sconv",)}
+
+
+@layer_body(static=("kind", "cfg", "page_size"))
+def _paged_hybrid_layer(x, layer, own, held, d, *, kind: str,
+                        cfg: ModelConfig, page_size: Optional[int]):
+    """forward_paged_hybrid's layer as a body (models/common.layer_body):
+    norm, ONE mixer of `kind`, residual. `own`: that layer's pools
+    (attention) or its parts of the state (`_STATE_PARTS`), `held`: the
+    store's arrays a retention layer's capture goes into (else None),
+    `d`: the dispatch's arrays (None where the program has none:
+    `active` says decode, `cap_len` that a capture is wanted). -> (x,
+    `own` as the layer leaves it, what it captured — a part each, () for
+    none — and an expert layer's `hybrid.MOE_COUNTS`, else None)."""
+    from .models import hybrid, retention, shortconv
+    positions, lengths, cap_len, active = (
+        d["positions"], d["lengths"], d["cap_len"], d["active"])
+    decode = active is not None
+    t = positions.shape[1]
+    h = hybrid.layer_norm_in(x, layer, cfg)
+    captured, counts = (), None
+    if kind == hybrid.RETENTION:
+        if decode:
+            out, *own = retention.retention_step(
+                h, layer, cfg, positions, *own, d["rows"], active)
+        else:
+            out, *own, snaps = retention.retention_prefill(
+                h, layer, cfg, positions, *own, d["rows"], lengths,
+                page_size, held, cap_len, d["snap_idx"])
+            captured = snaps or ()
+    elif kind == hybrid.MAMBA2:
+        if decode:
+            out, *own = hybrid.mamba2_step(h, layer, cfg, *own, active)
+        else:
+            # (with `cap_len`, also the state after that many tokens)
+            out, *own = hybrid.mamba2_prefill(h, layer, cfg, *own,
+                                              lengths, cap_len)
+            own, captured = own[:2], own[2:]
+    elif kind == hybrid.SHORTCONV:
+        if decode:
+            out, *own = shortconv.shortconv_step(h, layer, cfg, *own,
+                                                 active)
+        else:
+            # (with `cap_len`, also the tail after that many tokens)
+            out, *own = shortconv.shortconv_prefill(
+                h, layer, cfg, *own, lengths, cap_len)
+            own, captured = own[:1], own[1:]
+    elif kind == hybrid.EXPERTS:
+        out, c = hybrid.experts_mlp(h, layer, cfg, d["counted"])
+        counts = hybrid.step_counts(c, jnp.any(d["counted"]))
+    elif kind == hybrid.MLP:
+        out = mlp(h, layer, cfg)
+    else:
+        q, entries, kw = _attention_io(h, layer, cfg, positions,
+                                       own[0].dtype)
+        own = tuple(p.at[d["pages"], d["offs"]].set(_cells(e, p))
+                    for p, e in zip(own, entries))
+        k_pool, v_pool = (own + (None,))[:2]
+        if t == 1:
+            out = pattn.paged_decode_attention(
+                q, k_pool, v_pool, d["table"], d["kv_valid_len"],
+                sliding_window=cfg.sliding_window,
+                softcap=cfg.attn_logit_softcap, **kw)
+        else:
+            out = pattn.paged_prefill_attention(
+                q, k_pool, v_pool, d["table"], positions[:, 0],
+                d["kv_valid_len"], sliding_window=cfg.sliding_window,
+                softcap=cfg.attn_logit_softcap, **kw)
+        if out is None:
+            raise ValueError(
+                "paged pool-direct kernels declined this shape "
+                f"(T={t}, ps={page_size}); the engine gates hybrid "
+                "models on paged_direct at build time")
+        out = _attention_out(out, h, layer, cfg, h.dtype)
+    return x + out, tuple(own), tuple(captured), counts
+
+
+@layer_body(static=("kind", "cfg", "page_size", "attn_path"))
+def _ragged_hybrid_layer(x, layer, own, held, d, *, kind: str,
+                         cfg: ModelConfig, page_size: Optional[int],
+                         attn_path: str):
+    """forward_ragged_hybrid's layer as a body, `_paged_hybrid_layer`'s
+    twin over the flat buffer [1, T, E]: `d` the ragged dispatch's
+    arrays, `d["rg"]` `hybrid.ragged_meta`'s without its static block
+    size. A state layer always captures (`cap_n` 0: nothing taken)."""
+    from .models import hybrid, retention, shortconv
+    from .serving_loop import RAGGED_BLOCK_Q
+    rg = dict(d["rg"], block=RAGGED_BLOCK_Q)
+    positions = d["positions"]
+    pos2 = positions[None]
+    h = hybrid.layer_norm_in(x, layer, cfg)
+    captured, counts = (), None
+    if kind == hybrid.RETENTION:
+        out, *own, captured = retention.retention_ragged(
+            h, layer, cfg, pos2, *own, rg, page_size, held,
+            d["snap_idx"])
+    elif kind == hybrid.MAMBA2:
+        out, *own = hybrid.mamba2_ragged(h, layer, cfg, *own, rg)
+        own, captured = own[:2], own[2:]
+    elif kind == hybrid.SHORTCONV:
+        out, *own = shortconv.shortconv_ragged(h, layer, cfg, *own, rg)
+        own, captured = own[:1], own[1:]
+    elif kind == hybrid.EXPERTS:
+        out, c = hybrid.experts_mlp(h, layer, cfg, d["counted"])
+        counts = hybrid.step_counts(c, 1)
+    elif kind == hybrid.MLP:
+        out = mlp(h, layer, cfg)
+    else:
+        q, entries, kw = _attention_io(h, layer, cfg, pos2, own[0].dtype)
+        own = tuple(
+            p.at[d["token_pages"], d["token_offs"]].set(_cells(e[0], p))
+            for p, e in zip(own, entries))                  # e [1,T,...]
+        k_pool, v_pool = (own + (None,))[:2]
+        if attn_path == "kernel":
+            out = pattn.ragged_paged_attention(
+                q[0], k_pool, v_pool, d["tables"], rg["seq_of_block"],
+                rg["block_qstart"], d["query_offsets"], d["kv_valid"],
+                sliding_window=cfg.sliding_window,
+                softcap=cfg.attn_logit_softcap, **kw)
+        else:
+            out = _ragged_xla_attention(
+                q[0], k_pool, v_pool, d["tables"], rg["token_seq"],
+                positions, d["kv_valid"], cfg)
+        out = _attention_out(out[None], h, layer, cfg, h.dtype)
+    return x + out, tuple(own), tuple(captured), counts
+
+
 def forward_paged_hybrid(
     params: Params, cfg: ModelConfig,
     tokens: jax.Array,            # [B, T] (T==1 with `active`: decode)
@@ -516,26 +698,29 @@ def forward_paged_hybrid(
     assignments to held experts over the counted tokens, rows the
     grouped products multiplied and rows a loop over every held expert
     would have, expert-layer steps."""
-    from .models import hybrid, mamba1, retention, shortconv
+    from .models import hybrid, mamba1
     if pools:
         page_size = pools[0][0].shape[1]
     b, t = tokens.shape
-    pages = table[jnp.arange(b)[:, None], positions // page_size]
-    offs = positions % page_size
     decode = active is not None
     x = embed_tokens(params["embedding"], tokens)
-    if decode:
-        counted = active[:, None]
-    else:
-        counted = jnp.arange(t)[None, :] < lengths[:, None]
+    d = {"positions": positions, "table": table,
+         "kv_valid_len": kv_valid_len, "lengths": lengths,
+         "cap_len": cap_len, "active": active, "rows": rows,
+         "snap_idx": snap_idx,
+         "pages": table[jnp.arange(b)[:, None], positions // page_size],
+         "offs": positions % page_size,
+         "counted": (active[:, None] if decode else
+                     jnp.arange(t)[None, :] < lengths[:, None])}
     st = _state_lists(state)
-    ssm, conv, ret, retn = st["ssm"], st["conv"], st["ret"], st["retn"]
     cap = {p: [] for p in state} if cap_len is not None else None
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = ri = si = ci = 0
+    seen = collections.Counter()             # layers met, by kind
     for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
         kind = kinds[0]
+        i = seen[kind]
+        seen[kind] += 1
         if kind == hybrid.MAMBA1:
             if decode:
                 def mixer(h, layer, s, c, l, held):
@@ -546,92 +731,37 @@ def forward_paged_hybrid(
                     return mamba1.mamba1_prefill(
                         h, layer, cfg, s, c, l, rows, lengths, held,
                         cap_len, snap_idx)
-            held = None if cap is None else (snaps["ssm1"][si],
-                                             snaps["conv1"][si])
-            x, st["ssm1"][si], st["conv1"][si], held = _scan_run(
-                x, layer, kinds, cfg, st["ssm1"][si], st["conv1"][si],
+            held = None if cap is None else (snaps["ssm1"][i],
+                                             snaps["conv1"][i])
+            x, st["ssm1"][i], st["conv1"][i], held = _scan_run(
+                x, layer, kinds, cfg, st["ssm1"][i], st["conv1"][i],
                 held, mixer)
             if cap is not None:
                 cap["ssm1"].append(held[0])
                 cap["conv1"].append(held[1])
-            si += 1
             continue
-        h = hybrid.layer_norm_in(x, layer, cfg)
-        if kind == hybrid.RETENTION:
-            if decode:
-                out, ret[ri], retn[ri] = retention.retention_step(
-                    h, layer, cfg, positions, ret[ri], retn[ri], rows,
-                    active)
-            else:
-                out, ret[ri], retn[ri], held = retention.retention_prefill(
-                    h, layer, cfg, positions, ret[ri], retn[ri], rows,
-                    lengths, page_size,
-                    None if cap is None else (snaps["ret"][ri],
-                                              snaps["retn"][ri]),
-                    cap_len, snap_idx)
-                if cap is not None:
-                    cap["ret"].append(held[0])
-                    cap["retn"].append(held[1])
-            ri += 1
-        elif kind == hybrid.MAMBA2:
-            if decode:
-                out, ssm[mi], conv[mi] = hybrid.mamba2_step(
-                    h, layer, cfg, ssm[mi], conv[mi], active)
-            elif cap is None:
-                out, ssm[mi], conv[mi] = hybrid.mamba2_prefill(
-                    h, layer, cfg, ssm[mi], conv[mi], lengths)
-            else:
-                out, ssm[mi], conv[mi], s_cap, c_cap = \
-                    hybrid.mamba2_prefill(h, layer, cfg, ssm[mi],
-                                          conv[mi], lengths, cap_len)
-                cap["ssm"].append(s_cap)
-                cap["conv"].append(c_cap)
-            mi += 1
-        elif kind == hybrid.SHORTCONV:
-            tails = st["sconv"]
-            if decode:
-                out, tails[ci] = shortconv.shortconv_step(
-                    h, layer, cfg, tails[ci], active)
-            else:
-                # (with `cap_len`, also the tail after that many tokens)
-                out, tails[ci], *t_cap = shortconv.shortconv_prefill(
-                    h, layer, cfg, tails[ci], lengths, cap_len)
-                if cap is not None:
-                    cap["sconv"] += t_cap
-            ci += 1
-        elif kind == hybrid.EXPERTS:
-            out, c = hybrid.experts_mlp(h, layer, cfg, counted)
-            counts = counts + hybrid.step_counts(c, jnp.any(counted))
-        elif kind == hybrid.MLP:
-            out = mlp(h, layer, cfg)
-        else:
-            # The layer's own heads, window and rotary table, where the
-            # attention layers differ (ModelConfig.attn_layers).
-            lcfg = cfg.attention_layer(ai)
-            q, entries, kw = _attention_io(h, layer, lcfg, positions,
-                                           pools[ai][0].dtype)
-            layer_pools = tuple(p.at[pages, offs].set(_cells(e, p))
-                                for p, e in zip(pools[ai], entries))
-            k_pool, v_pool = (layer_pools + (None,))[:2]
-            if t == 1:
-                out = pattn.paged_decode_attention(
-                    q, k_pool, v_pool, table, kv_valid_len,
-                    sliding_window=lcfg.sliding_window,
-                    softcap=cfg.attn_logit_softcap, **kw)
-            else:
-                out = pattn.paged_prefill_attention(
-                    q, k_pool, v_pool, table, positions[:, 0],
-                    kv_valid_len, sliding_window=lcfg.sliding_window,
-                    softcap=cfg.attn_logit_softcap, **kw)
-            if out is None:
-                raise ValueError(
-                    "paged pool-direct kernels declined this shape "
-                    f"(T={t}, ps={page_size}); the engine gates hybrid "
-                    "models on paged_direct at build time")
-            out = _attention_out(out, h, layer, lcfg, h.dtype)
-            new_pools.append(layer_pools)
-            ai += 1
-        x = x + out
+        parts = _STATE_PARTS.get(kind, ())
+        attends = kind == hybrid.ATTENTION
+        held = None
+        if kind == hybrid.RETENTION and cap is not None:
+            held = (snaps["ret"][i], snaps["retn"][i])
+        # (an attention layer: its own heads, window and rotary table,
+        # where the attention layers differ — ModelConfig.attn_layers)
+        x, own, captured, c = _paged_hybrid_layer(
+            x, layer,
+            pools[i] if attends else tuple(st[p][i] for p in parts),
+            held, d, kind=kind,
+            cfg=cfg.attention_layer(i) if attends else cfg,
+            page_size=page_size)
+        if attends:
+            new_pools.append(own)
+        for p, a in zip(parts, own):
+            st[p][i] = a
+        if cap is not None:
+            for p, a in zip(parts, captured):
+                cap[p].append(a)
+        if c is not None:
+            counts = counts + c
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
     if last_pos is not None:
         x = gather_rows(x, last_pos)
@@ -661,82 +791,58 @@ def forward_ragged_hybrid(
     layers the ragged page-table kernel. ->
     (logits [S, V], new_pools, new_state, captured {"ssm": [[S,...]..],
     "conv": .., "ret" / "retn": the store's arrays}, counts)."""
-    from .models import hybrid, mamba1, retention, shortconv
+    from .models import hybrid, mamba1
     from .serving_loop import RAGGED_BLOCK_Q
     if pools:
         page_size = pools[0][0].shape[1]
     s_max = tables.shape[0]
     x = embed_tokens(params["embedding"], tokens[None])  # [1, T, E]
-    pos2 = positions[None]
     rg = hybrid.ragged_meta(positions, token_seq, query_offsets, kv_valid,
                             last_rows, seq_of_block, block_qstart,
                             seq_slot, cap_n, RAGGED_BLOCK_Q)
-    counted = (rg["token_valid"] & (token_seq != s_max - 1))[None]
+    # (the block's rows are static: a body takes the arrays, and puts
+    # the number back)
+    d = {"positions": positions, "tables": tables,
+         "query_offsets": query_offsets, "kv_valid": kv_valid,
+         "token_pages": token_pages, "token_offs": token_offs,
+         "snap_idx": snap_idx,
+         "rg": {k: v for k, v in rg.items() if k != "block"},
+         "counted": (rg["token_valid"] & (token_seq != s_max - 1))[None]}
     st = _state_lists(state)
-    ssm, conv, ret, retn = st["ssm"], st["conv"], st["ret"], st["retn"]
     cap = {p: [] for p in state}
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
-    ai = mi = ri = si = ci = 0
+    seen = collections.Counter()             # layers met, by kind
     for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
         kind = kinds[0]
+        i = seen[kind]
+        seen[kind] += 1
         if kind == hybrid.MAMBA1:
             def mixer(h, layer, s, c, l, held):
                 return mamba1.mamba1_ragged(h, layer, cfg, s, c, l, rg,
                                             held, snap_idx)
-            x, st["ssm1"][si], st["conv1"][si], held = _scan_run(
-                x, layer, kinds, cfg, st["ssm1"][si], st["conv1"][si],
-                (snaps["ssm1"][si], snaps["conv1"][si]), mixer)
+            x, st["ssm1"][i], st["conv1"][i], held = _scan_run(
+                x, layer, kinds, cfg, st["ssm1"][i], st["conv1"][i],
+                (snaps["ssm1"][i], snaps["conv1"][i]), mixer)
             cap["ssm1"].append(held[0])
             cap["conv1"].append(held[1])
-            si += 1
             continue
-        h = hybrid.layer_norm_in(x, layer, cfg)
-        if kind == hybrid.RETENTION:
-            out, ret[ri], retn[ri], held = retention.retention_ragged(
-                h, layer, cfg, pos2, ret[ri], retn[ri], rg, page_size,
-                (snaps["ret"][ri], snaps["retn"][ri]), snap_idx)
-            cap["ret"].append(held[0])
-            cap["retn"].append(held[1])
-            ri += 1
-        elif kind == hybrid.MAMBA2:
-            out, ssm[mi], conv[mi], s_cap, c_cap = hybrid.mamba2_ragged(
-                h, layer, cfg, ssm[mi], conv[mi], rg)
-            cap["ssm"].append(s_cap)
-            cap["conv"].append(c_cap)
-            mi += 1
-        elif kind == hybrid.SHORTCONV:
-            out, st["sconv"][ci], t_cap = shortconv.shortconv_ragged(
-                h, layer, cfg, st["sconv"][ci], rg)
-            cap["sconv"].append(t_cap)
-            ci += 1
-        elif kind == hybrid.EXPERTS:
-            out, c = hybrid.experts_mlp(h, layer, cfg, counted)
-            counts = counts + hybrid.step_counts(c, 1)
-        elif kind == hybrid.MLP:
-            out = mlp(h, layer, cfg)
-        else:
-            lcfg = cfg.attention_layer(ai)
-            q, entries, kw = _attention_io(h, layer, lcfg, pos2,
-                                           pools[ai][0].dtype)
-            layer_pools = tuple(
-                p.at[token_pages, token_offs].set(_cells(e[0], p))
-                for p, e in zip(pools[ai], entries))        # e [1,T,...]
-            k_pool, v_pool = (layer_pools + (None,))[:2]
-            if attn_path == "kernel":
-                out = pattn.ragged_paged_attention(
-                    q[0], k_pool, v_pool, tables, seq_of_block,
-                    block_qstart, query_offsets, kv_valid,
-                    sliding_window=lcfg.sliding_window,
-                    softcap=cfg.attn_logit_softcap, **kw)
-            else:
-                out = _ragged_xla_attention(
-                    q[0], k_pool, v_pool, tables, token_seq, positions,
-                    kv_valid, lcfg)
-            out = _attention_out(out[None], h, layer, lcfg, h.dtype)
-            new_pools.append(layer_pools)
-            ai += 1
-        x = x + out
+        parts = _STATE_PARTS.get(kind, ())
+        attends = kind == hybrid.ATTENTION
+        x, own, captured, c = _ragged_hybrid_layer(
+            x, layer,
+            pools[i] if attends else tuple(st[p][i] for p in parts),
+            ((snaps["ret"][i], snaps["retn"][i])
+             if kind == hybrid.RETENTION else None),
+            d, kind=kind, cfg=cfg.attention_layer(i) if attends else cfg,
+            page_size=page_size, attn_path=attn_path)
+        if attends:
+            new_pools.append(own)
+        for p, a, held in zip(parts, own, captured):
+            st[p][i] = a
+            cap[p].append(held)
+        if c is not None:
+            counts = counts + c
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
     logits = _hybrid_head(params, cfg, x[0, last_rows][None])
     new = {p: st[p] for p in state}

@@ -129,10 +129,13 @@ def _kernel(offsets, gids, tiles, visits, rows, w, out, acc, *,
         out[...] = jnp.where(mine, acc[...], out[...])
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def grouped_matmul(rows: jax.Array, weights: jax.Array, visits, *,
                    interpret: bool = False) -> jax.Array:
     """rows [m,K] (m whole row tiles: `padded_rows`), weights [G,K,N],
-    visits = group_visits(sizes, m) -> [m,N] float32."""
+    visits = group_visits(sizes, m) -> [m,N] float32. A jit of its own:
+    an expert layer's projections of one shape (gate and up) are one
+    trace and one lowering of the kernel."""
     (m, k), n = rows.shape, weights.shape[2]
     tm, tk, tn = tiling(m, k, n)
     assert m % tm == 0 and k % tk == 0, (m, k, tm, tk)

@@ -1346,6 +1346,11 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
                   "phase; describe-only)",
         "programs": "derived (lowerings heard; describe-only: "
                     "hits + misses + lowerings never compiled)",
+        "bodies_traced": "roundtable_setup_bodies_total{outcome=traced} "
+                         "(calls of a jitted layer body that traced it: "
+                         "models/common.layer_body)",
+        "bodies_reused": "roundtable_setup_bodies_total{outcome=reused} "
+                         "(calls that found it in JAX's trace cache)",
         "cache_hits": "roundtable_setup_programs_total{outcome=hit}",
         "cache_misses": "roundtable_setup_programs_total{outcome=miss}",
         "saved_s": "derived (jax's compile_time_saved_sec summed; "
