@@ -1477,6 +1477,154 @@ def test_hybrid_step_of_the_lfm2_cut_compiles(one_chip, monkeypatch,
     assert "bf16[640,128,4,128]" in hlo
 
 
+# --- a decoder whose upper half keeps no cache (ISSUE 56) --------------------
+#
+# Phi-4-mini-flash at published widths and depth 8 (3 Mamba-1, 2 window,
+# 1 full, 1 gated memory unit, 1 cross layer: every kind, both halves,
+# the seam): 40 query heads over 20 kv heads of 64 reach the paged kernels
+# as 40 over 10 of 128 — a kv pair a lane row `[640,128,10,128]`, group 4
+# (models/diffattn.py) — the cross layer reads the full layer's pools
+# through the decode walk at one token a row, and a join's upper half
+# multiplies `[rows, 2560]`.
+
+PHI4_POOL = (640, PAGE, 10, 128)
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged", "prefill"])
+def test_hybrid_step_of_the_phi4flash_cut_compiles(one_chip, monkeypatch,
+                                                   program):
+    """One decode step, one ragged join and one prologue chunk at the
+    cell's widths: the Mamba-1 kernels without inner norms and with the
+    memory emitted, differential attention through both walks and the
+    prefill kernel at group 4, the seam's gather, the cross layer over
+    another layer's pools, the LayerNorms."""
+    import re
+
+    from theroundtaible_tpu.engine.models import hybrid
+    from theroundtaible_tpu.engine.models.common import init_params
+    from theroundtaible_tpu.engine.models.registry import (
+        phi4flash_kinds, get_model_config)
+    from theroundtaible_tpu.engine.paged_forward import (
+        forward_paged_hybrid, forward_ragged_hybrid)
+    from theroundtaible_tpu.engine.pallas import mamba1 as m1
+    from theroundtaible_tpu.engine.serving_loop import (RaggedSeq,
+                                                        build_ragged_batch)
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(m1, "_interpret", lambda: False)
+    whole = get_model_config("phi-4-mini-flash-reasoning")
+    cfg = dataclasses.replace(
+        whole, num_layers=16, layer_kinds=phi4flash_kinds(8),
+        attn_layers=whole.attn_layers[:2] + whole.attn_layers[-1:],
+        last_token_from=12, attn_impl="flash")
+    assert cfg.attention_classes == ((40, 512, 2), (40, None, 1))
+    assert (cfg.page_heads, cfg.page_width) == PHI4_POOL[2:]
+    assert cfg.memory_layer == 8 and cfg.cross_layers == (14,)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def _compile(f, *shapes):
+        # (pools, state and the store donated, as the engine's programs
+        # take them: an update in place is what is asked about)
+        donated = tuple(i for i, a in enumerate(shapes)
+                        if a is pools or a is state or a is snaps)
+        return jax.jit(f, donate_argnums=donated).lower(
+            *shapes).compile().as_text()
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    i32 = jnp.int32
+    params = placed(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    assert params["layers"][0]["mamba1"]["in_proj"].shape == (1, 2560, 10240)
+    assert "dt_norm" not in params["layers"][0]["mamba1"]
+    assert params["layers"][1]["o_proj"].shape == (20, 128, 2560)
+    assert sorted(params["layers"][-2]) == sorted(
+        ["norm", "norm_b", "q_proj", "q_bias", "o_proj", "o_bias",
+         "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
+         "lambda_init", "sub_norm"])
+    pools = [(s(PHI4_POOL, jnp.bfloat16), s(PHI4_POOL, jnp.bfloat16))
+             for _ in cfg.attention_layers]
+    rows_n = DECODE_ROWS + 1
+    state = placed(jax.eval_shape(lambda: hybrid.zero_state(cfg, rows_n)))
+    snaps = {p: state[p] for p in hybrid.SLOT_PARTS if p in state}
+    if program == "decode":
+        def step(params, pools, state, tokens, positions, table, valid,
+                 active, rows):
+            return forward_paged_hybrid(
+                params, cfg, tokens, positions, pools, table, valid, state,
+                active=active, page_size=PAGE, rows=rows)
+
+        hlo = _compile(step, params, pools, state, s((DECODE_ROWS, 1), i32),
+                       s((DECODE_ROWS, 1), i32),
+                       s((DECODE_ROWS, PAGES_PER_SEQ), i32),
+                       s((DECODE_ROWS,), i32),
+                       s((DECODE_ROWS,), jnp.bool_), s((DECODE_ROWS,), i32))
+        assert "mamba1_step" in hlo
+    elif program == "prefill":
+        def step(params, pools, state, snaps, tokens, positions, table,
+                 valid, lengths, rows, cap_len, snap_idx):
+            return forward_paged_hybrid(
+                params, cfg, tokens, positions, pools, table, valid, state,
+                lengths=lengths, cap_len=cap_len, last_pos=lengths - 1,
+                page_size=PAGE, rows=rows, snaps=snaps, snap_idx=snap_idx)
+
+        one = s((1,), i32)
+        hlo = _compile(step, params, pools, state, snaps, s((1, 1024), i32),
+                       s((1, 1024), i32), s((1, PAGES_PER_SEQ), i32), one,
+                       one, one, one, one)
+        # The seam: the layers above it multiply ONE row of the chunk's
+        # 1024 — the unit's gate [1, 1, 5120], never [1, 1024, 5120]
+        # there (the Mamba-1 layers' own in-projection is twice as wide).
+        assert re.search(r"bf16\[1,1,2560\]", hlo)
+        assert not re.search(r"f32\[1,1024,200064\]", hlo)
+    else:
+        table = np.zeros((PAGES_PER_SEQ,), np.int32)
+        b = build_ragged_batch(
+            [RaggedSeq([5] * 150, 900, table), RaggedSeq([7], 1300, table)],
+            t_budget=RAGGED_T, s_max=ROWS + 1,
+            pages_per_seq=PAGES_PER_SEQ, scratch_page=0, pad_id=0,
+            page_size=PAGE)
+        names = ("tokens", "positions", "tables", "seq_of_block",
+                 "block_qstart", "query_offsets", "kv_valid",
+                 "token_pages", "token_offs", "token_seq", "last_rows")
+
+        def step(params, pools, state, snaps, seq_slot, cap_n, snap_idx,
+                 *arrays):
+            kw = dict(zip(names, arrays))
+            return forward_ragged_hybrid(
+                params, cfg, kw["tokens"], kw["positions"], pools,
+                kw["tables"], kw["seq_of_block"], kw["block_qstart"],
+                kw["query_offsets"], kw["kv_valid"], kw["token_pages"],
+                kw["token_offs"], kw["token_seq"], kw["last_rows"], state,
+                seq_slot, cap_n, page_size=PAGE, snaps=snaps,
+                snap_idx=snap_idx)
+
+        nine = s((ROWS + 1,), i32)
+        hlo = _compile(step, params, pools, state, snaps, nine, nine, nine,
+                       *[s(np.asarray(b[n]).shape, i32) for n in names])
+        assert "ragged_paged_attention" in hlo
+        # (the cross layer, above the seam: the decode walk over the
+        # sequences' last tokens)
+        assert "paged_decode_attention" in hlo
+    _assert_kernel(hlo)
+    assert "mamba1" in hlo
+    assert "bf16[640,128,10,128]" in hlo
+    # Ten rows a token do not fill whole tiles, so XLA stores the pool
+    # head-major: written through that view (paged_forward._write_cells)
+    # no pool is re-laid out — a whole-pool copy a pool a step was 47 %
+    # of the device's busy time (PERF.md, PR 56). (The prologue's kernel
+    # takes row-major page blocks and still makes XLA copy such a pool:
+    # this model's joins all take the ragged program, PERF.md section 7.)
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if re.search(r"= \w+\[640,[0-9,]+\]\S* copy(-start)?\(",
+                           line)]
+    assert program == "prefill" or not copies, copies
+
+
 # --- the int4 kernels the compiler refuses --------------------------------
 #
 # Shapes below come from a real Int4Leaf (quant.quantize_params on a

@@ -2036,8 +2036,11 @@ class InferenceEngine:
             hy.commit_state(state)
             hy.commit_snaps(snaps)
         hy.note_counts(counts, pipelined=False)
-        hy.note_scan(sum(int(takes[i]) for i in range(b)
-                         if rows_np[i] != hy.scratch_row))
+        live = [i for i in range(b) if rows_np[i] != hy.scratch_row]
+        fed = sum(int(takes[i]) for i in live)
+        hy.note_scan(fed)
+        hy.note_join(fed, len(live))
+        hy.note_shared_reads(fed + sum(int(offs[i]) for i in live))
         return last, pools
 
     def _hybrid_decode(self, fields, carry, names, max_new, greedy):
@@ -2103,7 +2106,10 @@ class InferenceEngine:
             hy.commit_state(state)
             hy.commit_snaps(snaps)
         hy.note_counts(counts, pipelined=False)
-        hy.note_scan(int((ends - starts)[:len(names)].sum()))
+        fed = int((ends - starts)[:len(names)].sum())
+        hy.note_scan(fed)
+        hy.note_join(fed, len(names))
+        hy.note_shared_reads(int(ends[:len(names)].sum()))
         return nxt, pools, key
 
     def _ragged_layout(self, batch: dict) -> dispatch_pack.Layout:
@@ -2261,6 +2267,16 @@ class InferenceEngine:
                   else "page_visits_window"] += layers * int(
                 np.where(valid > 0, hi - lo + 1, 0).sum())
         return self._note_window_reads(reads)
+
+    def note_plain_shared_reads(self, steps: int, read_to: tuple) -> None:
+        """What the cross layers of a plain decode segment read of the
+        pool they share, in positions: every step of every row reads the
+        row's whole cache (`read_to`: positions held at the last step)."""
+        if self.hybrid is None or not self.cfg.cross_layers:
+            return
+        valid = (np.asarray(read_to, np.int64)[:, None]
+                 - np.arange(steps)[None, :])               # [rows, steps]
+        self.hybrid.note_shared_reads(int(np.maximum(valid, 0).sum()))
 
     def window_page_holdings(self, read_to: tuple) -> dict:
         """What a segment's live rows HOLD, in pages x attention layers
@@ -3607,6 +3623,15 @@ class InferenceEngine:
                     self.cfg, self.dtype),
                 "state_dtype": jnp.dtype(self.dtype).name,
                 "conv_tokens": self.hybrid.conv_tokens,
+            }
+        if self.cfg.last_token_from is not None:
+            info["seam"] = {
+                "from_layer": self.cfg.last_token_from,
+                "layers_above": (self.cfg.num_layers
+                                 - self.cfg.last_token_from),
+                "cross_layers": len(self.cfg.cross_layers),
+                "memory_layer": self.cfg.memory_layer,
+                **self.hybrid.seam,
             }
         if self.cfg.attn_layers is not None:
             info["attention"] = self.attention_describe()

@@ -52,13 +52,24 @@ def estimate_param_count(cfg: ModelConfig) -> int:
             "retention": 2 * e * h * d + 2 * e * k * d + e * k + 2 * d,
         }
         if cfg.mamba1_layers:
-            # in, conv and its bias, x, the three small norms, dt and
-            # its bias, A_log, D, out (models/mamba1.py)
+            # in, conv and its bias, x, the three small norms (where the
+            # model has them), dt and its bias, A_log, D, out
+            # (models/mamba1.py); a gated memory unit: in and out
             d1, n, r = cfg.mamba1_dim, cfg.ssm_state, cfg.dt_rank
             per_kind["mamba1"] = (
                 2 * e * d1 + (cfg.conv_kernel + 1) * d1
-                + d1 * (r + 2 * n) + r + 2 * n + (r + 1) * d1
+                + d1 * (r + 2 * n)
+                + (r + 2 * n if cfg.mamba1_norms else 0) + (r + 1) * d1
                 + n * d1 + d1 + d1 * e)
+            per_kind["gmu"] = 2 * e * d1
+        if cfg.diff_attn:
+            # (models/diffattn.py) q and o, four lambda vectors, the
+            # pair norm and the stored l0; an attention layer also k and
+            # v; every projection's bias where the model has them
+            cross = 2 * e * h * d + 6 * d + 1
+            bias = (h * d + e, 2 * k * d) if cfg.attn_bias else (0, 0)
+            per_kind["cross"] = cross + bias[0]
+            per_kind["attention"] = cross + 2 * e * k * d + sum(bias)
         if cfg.latent:
             r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
             per_kind["attention"] = (
@@ -66,9 +77,12 @@ def estimate_param_count(cfg: ModelConfig) -> int:
                 + e * (r_kv + cfg.qk_rope_dim) + r_kv
                 + r_kv * h * (cfg.qk_nope_dim + cfg.v_head_dim)
                 + h * cfg.v_head_dim * e)
-        total = (sum(per_kind[kind] + e for kind in cfg.layer_kinds)
+        # (a norm a layer and the final one: weight, and bias where it
+        # is a LayerNorm)
+        norm = 2 * e if cfg.layer_norm else e
+        total = (sum(per_kind[kind] + norm for kind in cfg.layer_kinds)
                  + (1 if cfg.tie_embeddings else 2) * cfg.vocab_size * e
-                 + e)
+                 + norm)
         if cfg.attn_layers is not None or cfg.attn_gate:
             # Each attention layer's own heads (q and out-projection
             # above were counted at the model-level `h`), and its gate.

@@ -98,6 +98,11 @@ from .models.common import ModelConfig
 
 CONTINUE, SNAPSHOT, ZERO = "continue", "snapshot", "zero"
 MOE_COUNTS = hybrid.MOE_COUNTS
+# What a model with a seam counts (HybridStateStore.seam): a `segment`
+# span carries each for the programs it covers, describe()["seam"] the
+# lifetime totals, `roundtable_seam_<name>_total` the series.
+SEAM_COUNTS = ("lower_tokens", "upper_rows", "memory_rows",
+               "shared_pool_positions")
 
 
 def _chain(prev: bytes, block: list[int]) -> bytes:
@@ -154,6 +159,11 @@ class HybridStateStore:
         # and tokens x short-convolution layers they ran.
         self.scan_tokens = 0
         self.conv_tokens = 0
+        # A model whose upper layers keep nothing (`ModelConfig.
+        # last_token_from`): what its JOIN programs ran on either side of
+        # the seam, and what the layers that own no pages read of the
+        # pool they share (`note_join`, `note_shared_reads`).
+        self.seam = dict.fromkeys(SEAM_COUNTS, 0)
 
         @partial(jax.jit, donate_argnums=(0,))
         def restore(state, snaps, dst_rows, src_snaps, zero, n):
@@ -372,6 +382,30 @@ class HybridStateStore:
             self.conv_tokens += n
             telemetry.inc("roundtable_shortconv_tokens_total", n,
                           engine=self.engine)
+
+    def note_join(self, tokens: int, rows: int) -> None:
+        """A join dispatch (a prologue chunk, a ragged step) of a model
+        with a seam: `tokens` tokens went through the layers below it,
+        `rows` rows — each sequence's last token — through the layers
+        above, and as many rows of the memory were carried to them."""
+        if self.cfg.last_token_from is None:
+            return
+        self._note_seam(lower_tokens=tokens, upper_rows=rows, memory_rows=(
+            rows if self.cfg.memory_layer is not None else 0))
+
+    def note_shared_reads(self, positions: int) -> None:
+        """Positions x cross layers read from the pool of the attention
+        layer below them, by layers that own no pages."""
+        if self.cfg.cross_layers:
+            self._note_seam(shared_pool_positions=positions
+                            * len(self.cfg.cross_layers))
+
+    def _note_seam(self, **counts: int) -> None:
+        for name, n in counts.items():
+            if n:
+                self.seam[name] += n
+                telemetry.inc(f"roundtable_seam_{name}_total", n,
+                              engine=self.engine)
 
     def on_commit(self, name: str, tokens: list[int], exact: bool) -> None:
         """The slot committed `tokens`. `exact`: its state has consumed

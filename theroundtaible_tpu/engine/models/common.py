@@ -272,11 +272,31 @@ class ModelConfig:
     # Hybrid decoders (models/hybrid.py): one kind a layer, each layer ONE
     # mixer behind one norm and a residual — "mamba2" | "mamba1" |
     # "experts" | "attention" | "mlp" | "retention" |
-    # "shortconv". None = the attention-plus-MLP block above, untouched. A
-    # pre-norm block whose two halves have a norm each IS two such layers
-    # (attention, then mlp or experts): `axk1`.
+    # "shortconv" | "cross" | "gmu". None = the attention-plus-MLP block
+    # above, untouched. A pre-norm block whose two halves have a norm each
+    # IS two such layers (attention, then mlp or experts): `axk1`.
+    # "cross" and "gmu" keep NOTHING: a cross layer has a query and an
+    # out-projection and reads the pages of the nearest attention layer
+    # below it (under the model-level `sliding_window`, not that layer's:
+    # None is causal and unbounded); a gated memory unit reads `m`, the
+    # scan output of the last Mamba-1 layer below it, which rides beside
+    # the residual stream (engine/paged_forward.py: the carried tuple).
     layer_kinds: Optional[tuple[str, ...]] = None
     rope: bool = True                 # False: no position embedding at all
+    # Layers from this index on keep nothing (no pages, no state), so in
+    # a join — a prologue chunk, a ragged step — only each row's LAST
+    # token runs them: the step programs gather the carried tuple there
+    # (engine/paged_forward.py, "the seam"). None: every layer runs every
+    # token. Decode, one token a row, is the same either way.
+    last_token_from: Optional[int] = None
+    # The norm ahead of every mixer and the final one: a LayerNorm
+    # (mean and variance, weight `norm` AND bias `norm_b`) in place of
+    # the RMS norm.
+    layer_norm: bool = False
+    # Differential attention (models/diffattn.py) in every "attention"
+    # and "cross" layer: query heads (2j, 2j+1) over the kv head pair
+    # j // 2, two softmaxes, a subtraction and a norm a pair.
+    diff_attn: bool = False
     # Mamba-2 mixer
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -290,6 +310,9 @@ class ModelConfig:
     # `conv_kernel` above are shared with Mamba-2.
     mamba1_dim: int = 0
     dt_rank: int = 0
+    # dl, B and C each through an RMS norm of its own (`jamba`'s
+    # addition to Mamba-1); False: straight from W_x to W_dt and the scan.
+    mamba1_norms: bool = True
     # Routed + shared experts, as ONE chip's share of an expert-parallel
     # group: the router scores all `routed_experts`; this chip computes
     # ids [expert_offset, expert_offset + experts_held).
@@ -428,6 +451,21 @@ class ModelConfig:
     @property
     def expert_layers(self) -> tuple[int, ...]:
         return self._layers_of("experts")
+
+    @property
+    def cross_layers(self) -> tuple[int, ...]:
+        """The layers that read pages they do not own."""
+        return self._layers_of("cross")
+
+    @property
+    def memory_layer(self) -> Optional[int]:
+        """The Mamba-1 layer whose scan output `m` the gated memory
+        units read: the last one below the first of them (None: the
+        model has no such unit)."""
+        units = self._layers_of("gmu")
+        if not units:
+            return None
+        return max(i for i in self.mamba1_layers if i < units[0])
 
     @property
     def attention_layers(self) -> tuple[int, ...]:
@@ -1046,7 +1084,8 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
     x = embed_tokens(params["embedding"], tokens)
     zero = hybrid.zero_state(cfg, b, x.dtype)
     caches = []
-    for kind, layer in hybrid.layers_unrolled(cfg, params):
+    memory = None                # (what the gated memory units read)
+    for i, (kind, layer) in enumerate(hybrid.layers_unrolled(cfg, params)):
         if kind == hybrid.ATTENTION:
             lcfg = cfg.attention_layer(len(caches))
             mask = make_attention_mask(positions, t, kv_valid_len,
@@ -1062,9 +1101,12 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
                 jnp.arange(b), kv_valid_len, hybrid.RETENTION_CHUNK)[0]
         elif kind == hybrid.MAMBA1:
             # (a zero state of one layer: the run's first, at l = 0)
-            out = hybrid.mamba1.mamba1_prefill(
+            out, *rest = hybrid.mamba1.mamba1_prefill(
                 h, layer, cfg, zero["ssm1"][0][:, :1],
-                zero["conv1"][0][:, :1], 0, jnp.arange(b), kv_valid_len)[0]
+                zero["conv1"][0][:, :1], 0, jnp.arange(b), kv_valid_len,
+                emit=i == cfg.memory_layer)
+            if i == cfg.memory_layer:
+                memory = rest[-1]
         elif kind == hybrid.SHORTCONV:
             out = hybrid.shortconv.shortconv_prefill(
                 h, layer, cfg, zero["sconv"][0], kv_valid_len)[0]
@@ -1072,6 +1114,22 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
             out, _ = hybrid.experts_mlp(h, layer, cfg)
         elif kind == hybrid.MLP:
             out = mlp(h, layer, cfg)
+        elif kind == hybrid.GMU:
+            out = hybrid.gmu(h, memory, layer, x.dtype)
+        elif cfg.diff_attn:
+            # (a cross layer: the keys and values of the attention
+            # layer below it, causal and unbounded)
+            from . import diffattn
+            if kind == hybrid.CROSS:
+                q, kv = diffattn.queries(h, layer, cfg), caches[-1]
+                mask = make_attention_mask(positions, t, kv_valid_len,
+                                           cfg.sliding_window)
+            else:
+                q, *kv = project_qkv(h, layer, lcfg, positions)
+                q = diffattn.pack_queries(q)
+                caches.append(tuple(kv))
+            out = diffattn.output(diffattn.dense_attention(q, *kv, mask),
+                                  layer, cfg, x.dtype)
         elif cfg.latent:
             from . import mla
             out, kv = mla.expanded_attention(h, layer, cfg, positions,
@@ -1082,7 +1140,7 @@ def _forward_hybrid_whole(params, cfg, tokens, positions, kv_valid_len,
                                 mask, kv_valid_len)
             caches.append(kv)
         x = x + out
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
+    x = hybrid.final_norm(x, params, cfg)
     if last_pos is not None:
         x = gather_rows(x, last_pos)
     head = params["embedding"] if cfg.tie_embeddings else params["lm_head"]
@@ -1120,10 +1178,15 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             embedding = embedding.at[:, hybrid.GATE_CHANNEL].set(1.0)
 
         def one(i, kind, lk):
+            # (differential attention counts the PUBLISHED layers ahead,
+            # a mixer and the MLP behind it together)
+            depth = (sum(k not in (hybrid.MLP, hybrid.EXPERTS)
+                         for k in cfg.layer_kinds[:i]) if cfg.diff_attn
+                     else cfg.layer_kinds[:i].count(hybrid.RETENTION))
             return hybrid.init_layer(
                 cfg.attention_layer(cfg.attention_layers.index(i))
                 if kind == hybrid.ATTENTION else cfg, kind, lk, dtype,
-                depth=cfg.layer_kinds[:i].count(hybrid.RETENTION))
+                depth=depth)
 
         layers, i = [], 0
         for kinds, n in cfg.layer_runs:
@@ -1141,6 +1204,10 @@ def init_params(cfg: ModelConfig, key: jax.Array,
             i += n * width
         params = {"embedding": embedding, "layers": layers,
                   "final_norm": jnp.ones((cfg.embed_dim,), dtype)}
+        if cfg.layer_norm:
+            params["final_norm_b"] = (jax.random.normal(
+                jax.random.fold_in(k_embed, 2), (cfg.embed_dim,),
+                jnp.float32) * hybrid.NORM_BIAS_STD).astype(dtype)
         if cfg.tie_embeddings:
             # The head IS the embedding: at the initialiser's range, so
             # that a token's own row does not decide its own logit

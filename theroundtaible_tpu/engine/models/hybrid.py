@@ -13,6 +13,14 @@ A Mamba-1 layer (`mamba1`, models/mamba1.py: a decay for every (state
 index, channel) pair, so a scan kernel and no product form) is a fifth,
 a gated short convolution (`shortconv`, models/shortconv.py: three taps
 between two elementwise gates, whose whole state is two rows) a sixth.
+Two kinds keep NOTHING, so a join runs them on each row's last token
+alone (`ModelConfig.last_token_from`; engine/paged_forward.py, "the
+seam"): a differential cross layer (`cross`, models/diffattn.py: a
+query and an out-projection over the pages of the nearest attention
+layer below it) and a gated memory unit (`gmu`: `W_2 (m * silu(W_1 h))`,
+`m` the scan output of the last Mamba-1 layer below it, which rides
+beside the residual stream). Where `cfg.layer_norm`, the norm ahead of
+every mixer is a LayerNorm with weight and bias (`layer_norm_in`).
 `layer_kinds` keeps one entry a layer; the layers are stored and run as
 `ModelConfig.layer_runs` derives them: consecutive (mamba1, mlp) blocks
 are ONE entry of `params["layers"]` whose leaves carry a leading layer
@@ -69,11 +77,15 @@ import jax
 import jax.numpy as jnp
 
 from ..pallas import grouped
-from . import mamba1, retention, shortconv
+from . import diffattn, mamba1, retention, shortconv
 from .common import ModelConfig, Params, _einsum, rms_norm
 
 MAMBA2, EXPERTS, ATTENTION, MLP = "mamba2", "experts", "attention", "mlp"
 RETENTION, MAMBA1, SHORTCONV = "retention", mamba1.KIND, shortconv.KIND
+CROSS, GMU = diffattn.KIND, "gmu"
+# The kinds that keep neither pages nor state: what may lie above
+# `ModelConfig.last_token_from`.
+STATELESS = (CROSS, GMU, MLP)
 # State parts gathered to the batch's rows and scattered back by a step
 # program (small), and parts a layer updates in place on the whole slot
 # array (models/retention.py: 34 MB a row a layer; models/mamba1.py: one
@@ -550,8 +562,35 @@ def experts_mlp(h: jax.Array, layer: Params, cfg: ModelConfig,
 # --- the block -------------------------------------------------------------
 
 
+def _norm(x: jax.Array, weight, bias, cfg: ModelConfig) -> jax.Array:
+    """The model's norm over the last axis, in float32: RMS, or where
+    `cfg.layer_norm` a LayerNorm (mean and variance, weight and bias)."""
+    if not cfg.layer_norm:
+        return rms_norm(x, weight, cfg.norm_eps, False)
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(
+        jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + cfg.norm_eps)
+    return (xf * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
 def layer_norm_in(x: jax.Array, layer: Params, cfg: ModelConfig):
-    return rms_norm(x, layer["norm"], cfg.norm_eps, False)
+    return _norm(x, layer["norm"], layer.get("norm_b"), cfg)
+
+
+def final_norm(x: jax.Array, params: Params, cfg: ModelConfig):
+    return _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
+
+
+def gmu(h: jax.Array, m: jax.Array, layer: Params, dtype) -> jax.Array:
+    """A gated memory unit: W_2 (m * silu(W_1 h)); h [..., E] the
+    layer's normed input, m [..., d_inner] float32 the memory at the
+    same positions."""
+    gate = jax.nn.silu(_einsum("...e,ef->...f", h, layer["in_proj"]))
+    return _einsum("...f,fe->...e",
+                   (m.astype(jnp.float32) * gate).astype(dtype),
+                   layer["out_proj"]).astype(dtype)
 
 
 # What a mixer's out-projection adds to a residual stream of unit rms
@@ -592,6 +631,9 @@ TIED_EMBED_STD, MAMBA1_SHARE = 0.02, 0.134
 # while the experts' stay at RESIDUAL_SHARE: one expert changed by
 # rounding in the router then moves the stream by a twentieth of its rms.
 SHORTCONV_SHARE = 0.3
+# The seeded bias of a LayerNorm (`cfg.layer_norm`): small and not zero,
+# so that a comparison against a reference reads it.
+NORM_BIAS_STD = 0.02
 # The seeded gate of a retention layer (init_layer): the embedding
 # channel held at 1.0 (no out-projection writes to it), W_g's row there
 # over the kv heads, and the scale of its other rows (of unit scale).
@@ -603,7 +645,9 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
     """Random weights of one layer, by kind: in-projections at the
     scale that keeps activations of order one, out-projections at
     RESIDUAL_SHARE of it (RETENTION_SHARE in a model with retention
-    layers; `depth` counts the retention layers ahead of this one). An
+    layers; `depth` counts the retention layers ahead of this one — or,
+    in a model with differential attention, the PUBLISHED layers, mixer
+    and MLP together, ahead of this one: `diffattn.lambda_init`). An
     attention layer is given ITS view of the config
     (ModelConfig.attention_layer): its own head count."""
     e = cfg.embed_dim
@@ -624,6 +668,10 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
             ..., GATE_CHANNEL].set(0)
 
     layer: Params = {"norm": jnp.ones((e,), dtype)}
+    if cfg.layer_norm:
+        layer["norm_b"] = (jax.random.normal(
+            jax.random.fold_in(key, 8), (e,), jnp.float32)
+            * NORM_BIAS_STD).astype(dtype)
     if kind == MAMBA2:
         hh, d_in, conv = (cfg.mamba_heads, cfg.mamba_d_inner,
                           cfg.mamba_conv_dim)
@@ -711,6 +759,13 @@ def init_layer(cfg: ModelConfig, kind: str, key: jax.Array,
             "kv_b": dense(ks[3], (r_kv, h_, nope + dv), r_kv),
             "o_proj": dense(ks[4], (h_, dv, e), h_ * dv, RESIDUAL_SHARE),
         })
+    elif kind == GMU:
+        d1 = cfg.mamba1_dim
+        layer.update({"in_proj": dense(ks[0], (e, d1), e),
+                      "out_proj": out(ks[1], (d1, e), d1)})
+    elif kind in (ATTENTION, CROSS) and cfg.diff_attn:
+        layer.update(diffattn.init_mixer(cfg, ks, dense, out, dtype,
+                                         depth=depth, cross=kind == CROSS))
     elif kind == ATTENTION:
         h_, k_, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         layer.update({

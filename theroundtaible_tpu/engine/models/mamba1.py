@@ -7,12 +7,19 @@ d_inner = `cfg.mamba1_dim`, N = `cfg.ssm_state`, R = `cfg.dt_rank`:
     [u_t, z_t]        = W_in h_t                          (no bias)
     c_t               = silu(conv_b + sum_j conv_w[j] u_{t-K+1+j})
     [dl_t, B_t, C_t]  = W_x c_t                           (R, N, N)
-    dl, B, C          each through an RMS norm of its own (`jamba`)
+    dl, B, C          each through an RMS norm of its own where
+                      `cfg.mamba1_norms` (`jamba`: True; `phi4flash`:
+                      False — straight to W_dt and the scan, and the
+                      layer has no such leaves)
     dt_t              = softplus(W_dt dl_t + b_dt)        in R^d_inner
     S_t[n, d]         = exp(dt_t[d] A[n, d]) S_{t-1}[n, d]
                         + dt_t[d] c_t[d] B_t[n]           A = -exp(A_log)
     y_t               = S_t C_t + D c_t
     out_t             = W_out (y_t silu(z_t))             (no bias)
+
+With `emit` an entry point also returns m_t = y_t (float32, before the
+gate): what the gated memory units of the layers above read
+(`ModelConfig.memory_layer`); the layer's own output is unchanged.
 
 A sequence keeps S (float32) and the last K-1 rows of u (the conv's
 tail); nothing reads a past position again.
@@ -89,20 +96,21 @@ def init_mixer(cfg: ModelConfig, ks, dense, out, dtype) -> Params:
     """The reference implementation's initialisation: A = -(1..N) a
     channel, b_dt the inverse softplus of a log-uniform draw from
     DT_RANGE a channel, W_dt at R^-0.5, D and the three small norms
-    ones; `dense` / `out` are `hybrid.init_layer`'s (unit scale in, the
-    model's share out)."""
+    (where the model has them: `cfg.mamba1_norms`) ones; `dense` / `out`
+    are `hybrid.init_layer`'s (unit scale in, the model's share out)."""
     e, d, n, r = cfg.embed_dim, cfg.mamba1_dim, cfg.ssm_state, cfg.dt_rank
     lo, hi = DT_RANGE
     dt = jnp.exp(jax.random.uniform(ks[2], (d,), jnp.float32)
                  * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo))
+    norms = {"dt_norm": jnp.ones((r,), dtype),
+             "b_norm": jnp.ones((n,), dtype),
+             "c_norm": jnp.ones((n,), dtype)} if cfg.mamba1_norms else {}
     return {
         "in_proj": dense(ks[0], (e, 2 * d), e),
         "conv_w": dense(ks[1], (cfg.conv_kernel, d), cfg.conv_kernel),
         "conv_b": jnp.zeros((d,), dtype),
         "x_proj": dense(ks[3], (d, r + 2 * n), d),
-        "dt_norm": jnp.ones((r,), dtype),
-        "b_norm": jnp.ones((n,), dtype),
-        "c_norm": jnp.ones((n,), dtype),
+        **norms,
         "dt_proj": dense(ks[4], (r, d), r),
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
         "A_log": jnp.broadcast_to(
@@ -133,12 +141,15 @@ def _conv(rows: list, layer: Params) -> jax.Array:
 
 def _selective(c: jax.Array, layer: Params, cfg: ModelConfig, dtype):
     """c [..., d] float32 -> dt [..., d] float32, bc [..., 2N] float32:
-    the projections in `dtype`, the three norms, the softplus."""
+    the projections in `dtype`, the three norms (`cfg.mamba1_norms`),
+    the softplus."""
     r, n = cfg.dt_rank, cfg.ssm_state
     x = _einsum("...f,fr->...r", c.astype(dtype), layer["x_proj"])
-    dl, b, cm = (rms_norm(a, w, cfg.norm_eps, False) for a, w in (
-        (x[..., :r], layer["dt_norm"]), (x[..., r:r + n], layer["b_norm"]),
-        (x[..., r + n:], layer["c_norm"])))
+    dl, b, cm = x[..., :r], x[..., r:r + n], x[..., r + n:]
+    if cfg.mamba1_norms:
+        dl, b, cm = (rms_norm(a, layer[w], cfg.norm_eps, False)
+                     for a, w in ((dl, "dt_norm"), (b, "b_norm"),
+                                  (cm, "c_norm")))
     dt = jax.nn.softplus(
         _einsum("...r,rf->...f", dl.astype(dtype),
                 layer["dt_proj"]).astype(jnp.float32)
@@ -152,21 +163,24 @@ def _a_neg(layer: Params, cfg: ModelConfig) -> jax.Array:
 
 
 def _out(y: jax.Array, c: jax.Array, z: jax.Array, layer: Params,
-         dtype) -> jax.Array:
-    """(y + D c) silu(z) through the out-projection; y, c [..., d] f32."""
-    y = (y + layer["D"].astype(jnp.float32) * c) \
-        * jax.nn.silu(z.astype(jnp.float32))
-    return _einsum("...f,fe->...e", y.astype(dtype),
-                   layer["out_proj"]).astype(dtype)
+         dtype) -> tuple[jax.Array, jax.Array]:
+    """m = y + D c, and m silu(z) through the out-projection; y, c
+    [..., d] f32. -> (out, m)."""
+    m = y + layer["D"].astype(jnp.float32) * c
+    out = _einsum("...f,fe->...e",
+                  (m * jax.nn.silu(z.astype(jnp.float32))).astype(dtype),
+                  layer["out_proj"]).astype(dtype)
+    return out, m
 
 
 def mamba1_step(h: jax.Array, layer: Params, cfg: ModelConfig,
                 ssm: jax.Array, conv: jax.Array, l, rows: jax.Array,
-                active: jax.Array):
+                active: jax.Array, emit: bool = False):
     """One decode token a row. h [B,1,E]; ssm [R,L,N,G,W] / conv
     [R,L,K-1,G,W] EVERY slot's state of the run, `l` the layer in it;
     rows [B] each batch row's state row; rows with `active` False keep
-    their state. -> (out [B,1,E], ssm, conv)."""
+    their state. -> (out [B,1,E], ssm, conv), and with `emit` m
+    [B,1,d] float32."""
     n, g, w, k1 = dims(cfg)
     r_all = ssm.shape[0]
     # Slot order: the batch's rows at their state rows, every other slot
@@ -195,11 +209,13 @@ def mamba1_step(h: jax.Array, layer: Params, cfg: ModelConfig,
             + (dt * cg) * bc[:, :n, None, None]
         y = jnp.sum(new * bc[:, n:, None, None], axis=1)  # [R, G, W]
         ssm = jax.lax.dynamic_update_index_in_dim(ssm, new, l, 1)
-    out = _out(y.reshape(r_all, g * w), c, z, layer, h.dtype)
+    out, m = _out(y.reshape(r_all, g * w), c, z, layer, h.dtype)
     moved = jnp.concatenate([tail[:, 1:], cur.reshape(r_all, 1, g, w)],
                             axis=1)
     conv = jax.lax.dynamic_update_index_in_dim(
         conv, jnp.where(live[:, None, None, None], moved, tail), l, 1)
+    if emit:
+        return out[rows][:, None], ssm, conv, m[rows][:, None]
     return out[rows][:, None], ssm, conv
 
 
@@ -242,13 +258,15 @@ def scan_blocks(dt, c, bc, a, state, layer, block_slot, block_cap,
 def mamba1_ragged(h: jax.Array, layer: Params, cfg: ModelConfig,
                   ssm: jax.Array, conv: jax.Array, l, rg: dict,
                   snaps: Optional[tuple] = None,
-                  snap_idx: Optional[jax.Array] = None):
+                  snap_idx: Optional[jax.Array] = None,
+                  emit: bool = False):
     """A Mamba-1 mixer over the flat token buffer. h [1,T,E]; ssm / conv
     EVERY slot's state of the run (`mamba1_step`), `l` the layer in it;
     `rg` as `hybrid.ragged_meta` builds it. With `snaps` (the store's
     two arrays of the run) each sequence's state after `rg["cap_n"]` of
     its tokens is written at `snap_idx` (sequences with none: the
-    scratch snapshot). -> (out [1,T,E], ssm, conv, snaps)."""
+    scratch snapshot). -> (out [1,T,E], ssm, conv, snaps), and with
+    `emit` m [1,T,d] float32."""
     n, g, w, k1 = dims(cfg)
     t = h.shape[1]
     q = rg["block"]
@@ -283,7 +301,8 @@ def mamba1_ragged(h: jax.Array, layer: Params, cfg: ModelConfig,
         dt.reshape(t, g, w), c.reshape(t, g, w), bc, _a_neg(layer, cfg),
         ssm, jnp.asarray(l, jnp.int32), rg["block_slot"], block_cap, seq_b,
         block=q, n_seqs=cap_n.shape[0])
-    out = _out(y.reshape(t, g * w), c, z, layer, h.dtype)[None]
+    out, m = _out(y.reshape(t, g * w), c, z, layer, h.dtype)
+    out = out[None]
 
     def tails(count):
         # The last K-1 inputs of [old tail; the run's first `count`].
@@ -299,6 +318,8 @@ def mamba1_ragged(h: jax.Array, layer: Params, cfg: ModelConfig,
     if snaps is not None:
         snaps = (snaps[0].at[snap_idx, l].set(held),
                  snaps[1].at[snap_idx, l].set(tails(cap_n)))
+    if emit:
+        return out, ssm, conv, snaps, m[None]
     return out, ssm, conv, snaps
 
 
@@ -307,12 +328,13 @@ def mamba1_prefill(h: jax.Array, layer: Params, cfg: ModelConfig,
                    lengths: jax.Array,
                    snaps: Optional[tuple] = None,
                    cap_len: Optional[jax.Array] = None,
-                   snap_idx: Optional[jax.Array] = None):
+                   snap_idx: Optional[jax.Array] = None,
+                   emit: bool = False):
     """A Mamba-1 mixer over [B, T] rows, each from its own state row:
     the flat buffer of `mamba1_ragged` with a run a row. h [B,T,E];
     rows [B] the state rows; lengths [B] valid tokens a row; with
     `snaps`, `cap_len` [B] (0: none) and `snap_idx` [B] a snapshot a row.
-    -> (out [B,T,E], ssm, conv, snaps)."""
+    -> (out [B,T,E], ssm, conv, snaps), and with `emit` m [B,T,d]."""
     from ..serving_loop import RAGGED_BLOCK_Q as q
     b, t, e = h.shape
     tp = -(-t // q) * q
@@ -331,7 +353,8 @@ def mamba1_prefill(h: jax.Array, layer: Params, cfg: ModelConfig,
         "cap_n": (jnp.zeros((b,), jnp.int32) if cap_len is None
                   else cap_len),
     }
-    out, ssm, conv, snaps = mamba1_ragged(
+    out, ssm, conv, snaps, *m = mamba1_ragged(
         h.reshape(1, b * tp, e), layer, cfg, ssm, conv, l, rg, snaps,
-        snap_idx)
-    return out.reshape(b, tp, e)[:, :t], ssm, conv, snaps
+        snap_idx, emit)
+    return (out.reshape(b, tp, e)[:, :t], ssm, conv, snaps,
+            *(a.reshape(b, tp, -1)[:, :t] for a in m))

@@ -8,8 +8,11 @@ shared experts, attention — models/hybrid.py), A.X-K1 (latent attention
 — models/mla.py — beside a dense MLP or gated routed and shared
 experts), Brumby (power retention and no attention layer —
 models/retention.py), Jamba (Mamba-1 layers in scanned runs beside
-attention layers of one kv head — models/mamba1.py) and tiny test
-presets.
+attention layers of one kv head — models/mamba1.py), LFM2 (gated short
+convolutions — models/shortconv.py), Phi-4-mini-flash (a decoder whose
+upper half keeps no cache of its own: differential attention —
+models/diffattn.py — over one layer's pages, gated memory units over one
+Mamba-1 layer's scan output) and tiny test presets.
 Architecture behavior lives in ModelConfig fields (common.py).
 
 `resolve_model_config(adapter_config)` is the one way an engine gets its
@@ -26,8 +29,8 @@ import dataclasses
 from typing import Any
 
 from .common import AttnLayer, ModelConfig
-from .hybrid import (ATTENTION, EXPERTS, MAMBA1, MLP, RETENTION,
-                     SHORTCONV, kinds_of_pattern)
+from .hybrid import (ATTENTION, CROSS, EXPERTS, GMU, MAMBA1, MLP,
+                     RETENTION, SHORTCONV, kinds_of_pattern)
 
 _REGISTRY: dict[str, ModelConfig] = {}
 
@@ -407,6 +410,58 @@ TINY_LFM2 = register(_lfm2_config(
     vocab_size=512, embed_dim=64, num_heads=8, num_kv_heads=4,
     head_dim=64, mlp_dim=128, max_seq_len=512, rope_theta=1_000_000.0,
     norm_eps=1e-5, conv_kernel=3, moe_top_k=2, expert_dim=32))
+
+
+# --- Phi-4-mini-flash / phi4flash (SambaY, arXiv:2507.06607: with L =
+# `num_hidden_layers`, L % 4 == 0, and `mb_per_layer` 2, published layer i
+# is a Mamba-1 mixer WITHOUT Jamba's inner norms where i is even and
+# i <= L/2; differential attention — models/diffattn.py — over a window
+# where i is odd and i < L/2, causal and unbounded at i = L/2 + 1; above
+# that a gated memory unit over layer L/2's scan output where i is even,
+# and a differential CROSS layer over layer L/2 + 1's pages where i is
+# odd: the layers from L/2 + 2 up keep nothing, and a join runs them on
+# each row's last token (`last_token_from`). Each then a SwiGLU MLP;
+# LayerNorm with bias; no position embedding; tied head) ---
+
+
+def phi4flash_kinds(blocks: int) -> tuple[str, ...]:
+    """The mixer of every published layer by the model's own depth rule,
+    each followed by its MLP."""
+    if blocks % 4 or blocks < 4:
+        raise ValueError(f"num_hidden_layers {blocks}: the depth rule "
+                         "needs a multiple of 4")
+    half = blocks // 2
+    return tuple(k for i in range(blocks) for k in (
+        (MAMBA1 if i <= half else GMU) if i % 2 == 0
+        else (ATTENTION if i <= half + 1 else CROSS), MLP))
+
+
+def _phi4flash_config(name, *, blocks, heads, window, **kw):
+    kinds = phi4flash_kinds(blocks)
+    n_window = blocks // 4
+    return ModelConfig(
+        name=name, num_layers=2 * blocks, num_heads=heads,
+        tie_embeddings=True, rope=False, layer_kinds=kinds,
+        attn_layers=(AttnLayer(heads, window),) * n_window
+        + (AttnLayer(heads, None),),
+        attn_bias=True, layer_norm=True, diff_attn=True,
+        mamba1_norms=False, last_token_from=2 * (blocks // 2 + 2), **kw)
+
+
+PHI4_MINI_FLASH = register(_phi4flash_config(
+    "phi-4-mini-flash-reasoning", blocks=32, heads=40, window=512,
+    vocab_size=200_064, embed_dim=2560, num_kv_heads=20, head_dim=64,
+    mlp_dim=10_240, max_seq_len=8192, norm_eps=1e-5, mamba1_dim=5120,
+    ssm_state=16, conv_kernel=4, dt_rank=160))
+
+# Depth 8 (3 Mamba, 2 window, 1 full, 1 memory unit, 1 cross layer): 8
+# heads over 4 of 64, so that a kv pair fills a 128-lane row as the
+# published widths do.
+TINY_PHI4FLASH = register(_phi4flash_config(
+    "tiny-phi4flash", blocks=8, heads=8, window=16, vocab_size=512,
+    embed_dim=64, num_kv_heads=4, head_dim=64, mlp_dim=128,
+    max_seq_len=512, norm_eps=1e-5, mamba1_dim=128, ssm_state=8,
+    conv_kernel=4, dt_rank=4))
 
 
 # --- from a published config.json -------------------------------------------
@@ -875,6 +930,56 @@ def _lfm2_moe(name: str, arch: dict[str, Any],
     return cfg
 
 
+# Keys of a phi4flash config.json that say nothing this engine acts on,
+# and the values its layer equations assume. The Mamba sizes, the two
+# bias switches and `head_dim` are not in the published file: they are
+# the family's defaults, which a configuration file may state beside the
+# published keys (each flips by its one key).
+_PHI4FLASH_INERT = {"model_type", "max_position_embeddings", "embd_pdrop",
+                    "resid_pdrop"}
+_PHI4FLASH_FIXED = {
+    "hidden_act": "silu", "mb_per_layer": 2, "tie_word_embeddings": True,
+    "mlp_bias": False, "lm_head_bias": False, "attention_bias": True,
+    "mamba_conv_bias": True, "mamba_proj_bias": False}
+
+
+def _phi4flash(name: str, arch: dict[str, Any],
+               max_seq_len: int) -> ModelConfig:
+    arch = _acted_on(name, arch, "phi4flash", _PHI4FLASH_FIXED,
+                     _PHI4FLASH_INERT)
+    try:
+        e, heads = int(arch.pop("hidden_size")), \
+            int(arch.pop("num_attention_heads"))
+        expand = int(arch.pop("mamba_expand", 2))
+        cfg = _phi4flash_config(
+            name, blocks=int(arch.pop("num_hidden_layers")), heads=heads,
+            window=int(arch.pop("sliding_window")),
+            vocab_size=int(arch.pop("vocab_size")), embed_dim=e,
+            num_kv_heads=int(arch.pop("num_key_value_heads")),
+            head_dim=int(arch.pop("head_dim", e // heads)),
+            mlp_dim=int(arch.pop("intermediate_size")),
+            max_seq_len=max_seq_len,
+            norm_eps=float(arch.pop("layer_norm_eps")),
+            mamba1_dim=expand * e,
+            ssm_state=int(arch.pop("mamba_d_state", 16)),
+            conv_kernel=int(arch.pop("mamba_d_conv", 4)),
+            dt_rank=int(arch.pop("mamba_dt_rank", -(-e // 16))))
+    except KeyError as e:
+        raise ValueError(f"architecture of {name!r} lacks the key "
+                         f"{e.args[0]!r}") from None
+    _all_read(name, arch, "phi4flash")
+    if cfg.num_kv_heads % 2 or cfg.num_heads % cfg.num_kv_heads:
+        raise ValueError(
+            f"architecture of {name!r}: {cfg.num_heads} query heads over "
+            f"{cfg.num_kv_heads} kv heads are not whole groups of query "
+            "head pairs over kv head pairs")
+    if cfg.lane_pack != 2:
+        raise ValueError(
+            f"architecture of {name!r}: a kv pair of {cfg.head_dim}-wide "
+            "heads does not fill a 128-lane row (models/diffattn.py)")
+    return cfg
+
+
 def _dense_gqa(name: str, arch: dict[str, Any],
                max_seq_len: int) -> ModelConfig:
     heads = int(arch["num_attention_heads"])
@@ -922,6 +1027,8 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
         return _jamba(name, arch, max_seq_len)
     if kind == "lfm2_moe":
         return _lfm2_moe(name, arch, max_seq_len)
+    if kind == "phi4flash":
+        return _phi4flash(name, arch, max_seq_len)
     if kind in _DENSE_TYPES:
         try:
             return _dense_gqa(name, arch, max_seq_len)
@@ -931,7 +1038,7 @@ def resolve_model_config(config: dict[str, Any]) -> ModelConfig:
     raise ValueError(
         f"architecture of {name!r}: model_type {kind!r} is not one this "
         f"engine runs (nemotron_h, axk1, laguna, mellum, brumby, jamba, "
-        f"lfm2_moe, {', '.join(_DENSE_TYPES)})")
+        f"lfm2_moe, phi4flash, {', '.join(_DENSE_TYPES)})")
 
 
 def get_model_config(name: str, **overrides) -> ModelConfig:

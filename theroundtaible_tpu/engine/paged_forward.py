@@ -35,13 +35,14 @@ so this module never traces an unpartitionable kernel.
 from __future__ import annotations
 
 import collections
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from . import kv_quant as kvq
-from .models import mla
+from .models import diffattn, mla
 from .models.common import (MASK_VALUE, ModelConfig, Params, _einsum,
                             _softcap, current_spmd_mesh, embed_tokens,
                             gate_heads, gather_rows, layer_body, mlp,
@@ -55,6 +56,31 @@ def _cells(entries: jax.Array, pool: jax.Array) -> jax.Array:
     ModelConfig.lane_pack; a latent entry [..., W] as it is)."""
     lead = entries.ndim - (pool.ndim - 2)
     return entries.reshape(entries.shape[:lead] + pool.shape[2:])
+
+
+def _write_cells(pool: jax.Array, pages: jax.Array, offs: jax.Array,
+                 entries: jax.Array) -> jax.Array:
+    """`pool` with `entries` [..., K, D] written at (pages, offs) [...]:
+    `pool.at[pages, offs].set(...)`, in the form XLA updates in place. A
+    pool whose rows a token do not fill whole tiles (pallas/attention.py,
+    `_token_major`: not 2, 4 or a multiple of 8) is STORED head-major,
+    physically [P, K, ps, D]; a scatter over the row-major [P, ps, K, D]
+    makes XLA re-lay the whole pool out and back on every call (PERF.md,
+    PR 56: eight pools of 210 MB a decode step). Such a pool is written
+    through the view it is stored in, [P, K * ps, D] — a bitcast, as the
+    walks read it — K rows a token. (One device: a pool sharded over its
+    heads keeps the row-major scatter GSPMD partitions.)"""
+    cells = _cells(entries, pool)
+    mesh = current_spmd_mesh()
+    if (pool.ndim != 4 or pool.shape[2] == 1
+            or pattn._token_major(pool.shape[2], pool.dtype.itemsize)
+            or (mesh is not None and mesh.size > 1)):
+        return pool.at[pages, offs].set(cells)
+    p, ps, k, d = pool.shape
+    rows = jnp.arange(k) * ps + offs[..., None]              # [..., K]
+    view = pool.swapaxes(1, 2).reshape(p, k * ps, d)
+    view = view.at[pages[..., None], rows].set(cells)
+    return view.reshape(p, k, ps, d).swapaxes(1, 2)
 
 
 @layer_body(static=("cfg", "pool_replicas", "quant_spec", "kernel_quant"))
@@ -86,8 +112,8 @@ def _paged_block(x, layer, pools, positions, pages, offs, table,
             k_sc2 = k_sc.at[pages, offs].set(k_s)
             v_sc2 = v_sc.at[pages, offs].set(v_s)
         else:
-            k_pool2 = k_pool.at[pages, offs].set(_cells(k, k_pool))
-            v_pool2 = v_pool.at[pages, offs].set(_cells(v, v_pool))
+            k_pool2 = _write_cells(k_pool, pages, offs, k)
+            v_pool2 = _write_cells(v_pool, pages, offs, v)
             k_sc2 = v_sc2 = None
         if quant and not kernel_quant:
             # Declined shape: dequantize the pool for a bf16 kernel
@@ -316,10 +342,8 @@ def _ragged_block(x, layer, pools, positions, walk, token_pages,
             k_sc2 = k_sc.at[token_pages, token_offs].set(k_s)
             v_sc2 = v_sc.at[token_pages, token_offs].set(v_s)
         else:
-            k_pool2 = k_pool.at[token_pages, token_offs].set(
-                _cells(k[0], k_pool))
-            v_pool2 = v_pool.at[token_pages, token_offs].set(
-                _cells(v[0], v_pool))
+            k_pool2 = _write_cells(k_pool, token_pages, token_offs, k[0])
+            v_pool2 = _write_cells(v_pool, token_pages, token_offs, v[0])
             k_sc2 = v_sc2 = None
         if attn_path == "kernel":
             mesh = current_spmd_mesh()
@@ -454,9 +478,27 @@ def forward_ragged(
 # layer_body), its kind and the config it reads static — so the layers
 # of one signature are traced once and lowered once a program.
 #
+# What a program carries from layer to layer is a TUPLE, `carry`: the
+# residual stream x and whatever rides beside it. Width one is every
+# model but one kind's: a model with gated memory units (`gmu`) carries
+# `m`, the scan output of its `ModelConfig.memory_layer`, from that
+# layer up — recomputed every step, kept nowhere. A body reads
+# `carry[0]` and hands the rest on untouched; a `gmu` layer reads
+# `carry[1]`.
+#
+# THE SEAM (`ModelConfig.last_token_from`): the layers from that index on
+# keep nothing — no pages, no state (`hybrid.STATELESS`) — so of a
+# join's tokens only each row's last one needs them. In a prologue chunk
+# and a ragged step the carry is gathered to those rows there (`_seam_run`),
+# and the layers above run `[rows, 1, E]` through `_paged_hybrid_layer`
+# at one token a row — the decode program's upper half; a cross layer
+# then reads its pages through the decode walk. Decode itself, one token
+# a row from the start, has nothing to gather.
+#
 # The rule a new block kind follows (models/common.py has it in full):
-# a branch of the body reads only its arguments — x, that layer's
-# leaves, `own` (that layer's pools, or its parts of the state in
+# a branch of the body reads only its arguments — the carry, that
+# layer's leaves, `own` (that layer's pools — a cross layer: the pools
+# it reads — or its parts of the state in
 # `_STATE_PARTS`' order), `held` (where its capture is written) and the
 # dispatch's arrays `d` — and what is static about it is `kind`, `cfg`
 # (for an attention layer `cfg.attention_layer(i)`, a frozen view equal
@@ -479,31 +521,72 @@ def _state_lists(state: dict) -> dict:
                       "sconv")}
 
 
-def _scan_run(x, run, kinds, cfg: ModelConfig, ssm, conv, held, mixer):
+def _scan_run(x, run, kinds, cfg: ModelConfig, ssm, conv, held, mixer,
+              emit: bool = False):
     """One run of Mamba-1 blocks (`ModelConfig.layer_runs`) as ONE
     `lax.scan` over its stacked parameters: whatever the run's length
     the program holds one body — a mixer and, where the block has one,
     the MLP behind it. `ssm` / `conv` (every slot's state of the run,
     [rows, L, ...]) and `held` (the store's two arrays of the run, or
     None) ride in the carry and are updated in place at layer `l`;
-    `mixer(h, layer, ssm, conv, l, held) -> (out, ssm, conv, held)`."""
+    `mixer(h, layer, ssm, conv, l, held) -> (out, ssm, conv, held)`.
+    With `emit` the mixer returns its scan output `m` last, and the
+    run's LAST layer's is returned after `held`."""
     from .models import hybrid
 
     def block(carry, xs):
         x, ssm, conv, held = carry
         l, layers = xs
         layer = layers[hybrid.MAMBA1]
-        out, ssm, conv, held = mixer(
+        out, ssm, conv, held, *m = mixer(
             hybrid.layer_norm_in(x, layer, cfg), layer, ssm, conv, l, held)
         x = x + out
         for kind in kinds[1:]:                  # (the MLP behind it)
             x = x + mlp(hybrid.layer_norm_in(x, layers[kind], cfg),
                         layers[kind], cfg)
-        return (x, ssm, conv, held), None
+        return (x, ssm, conv, held), (m[0] if emit else None)
 
-    (x, ssm, conv, held), _ = jax.lax.scan(
+    (x, ssm, conv, held), ms = jax.lax.scan(
         block, (x, ssm, conv, held), (jnp.arange(ssm.shape[1]), run))
+    if emit:
+        return x, ssm, conv, held, ms[-1]
     return x, ssm, conv, held
+
+
+@functools.lru_cache(maxsize=None)
+def _seam_run(cfg: ModelConfig) -> Optional[int]:
+    """The index into `cfg.layer_runs` of the first run above the seam
+    (`ModelConfig.last_token_from`; None: the model has none). What lies
+    above keeps nothing, or the model cannot be served."""
+    from .models import hybrid
+    if cfg.last_token_from is None:
+        return None
+    kept = [k for k in cfg.layer_kinds[cfg.last_token_from:]
+            if k not in hybrid.STATELESS]
+    at, starts = 0, []
+    for kinds, n in cfg.layer_runs:
+        starts.append(at)
+        at += len(kinds) * n
+    if kept or cfg.last_token_from not in starts:
+        raise ValueError(
+            f"{cfg.name}: last_token_from {cfg.last_token_from} must "
+            f"begin a run of layers that keep nothing (found {kept})")
+    return starts.index(cfg.last_token_from)
+
+
+@functools.lru_cache(maxsize=None)
+def _memory_run(cfg: ModelConfig) -> Optional[int]:
+    """The index into `cfg.layer_runs` of the scanned run whose last
+    layer is `cfg.memory_layer` (None: no layer reads a memory)."""
+    if cfg.memory_layer is None:
+        return None
+    at = 0
+    for r, (kinds, n) in enumerate(cfg.layer_runs):
+        at += len(kinds) * n
+        if at - len(kinds) == cfg.memory_layer:
+            return r
+    raise ValueError(f"{cfg.name}: memory_layer {cfg.memory_layer} does "
+                     "not end a run of Mamba-1 blocks")
 
 
 def _attention_io(h, layer, cfg: ModelConfig, positions, dtype):
@@ -518,12 +601,17 @@ def _attention_io(h, layer, cfg: ModelConfig, positions, dtype):
                 {"v_dim": cfg.kv_lora_rank})
     q, k, v = (a.astype(dtype) for a in
                project_qkv(h, layer, cfg, positions))
+    if cfg.diff_attn:
+        # (a kv pair is one lane row of a page: models/diffattn.py)
+        q = diffattn.pack_queries(q)
     return q, (k, v), {}
 
 
 def _attention_out(out, h, layer, cfg: ModelConfig, dtype):
     """The kernels' result [B,T,H,*] -> the layer's output [B,T,E];
     `h` the layer's normed input, which a gated layer's gate reads."""
+    if cfg.diff_attn:
+        return diffattn.output(out, layer, cfg, dtype)
     if cfg.latent:
         out = mla.values_of(out, layer, cfg)
     return _einsum("bthd,hde->bte", gate_heads(out, h, layer, cfg),
@@ -537,21 +625,25 @@ _STATE_PARTS = {"retention": ("ret", "retn"), "mamba2": ("ssm", "conv"),
 
 
 @layer_body(static=("kind", "cfg", "page_size"))
-def _paged_hybrid_layer(x, layer, own, held, d, *, kind: str,
+def _paged_hybrid_layer(carry, layer, own, held, d, *, kind: str,
                         cfg: ModelConfig, page_size: Optional[int]):
     """forward_paged_hybrid's layer as a body (models/common.layer_body):
-    norm, ONE mixer of `kind`, residual. `own`: that layer's pools
-    (attention) or its parts of the state (`_STATE_PARTS`), `held`: the
+    norm, ONE mixer of `kind`, residual. `carry`: the residual stream
+    and what rides beside it (a `gmu` layer's memory), `own`: that
+    layer's pools (attention; a cross layer: the pools it reads) or its
+    parts of the state (`_STATE_PARTS`), `held`: the
     store's arrays a retention layer's capture goes into (else None),
     `d`: the dispatch's arrays (None where the program has none:
-    `active` says decode, `cap_len` that a capture is wanted). -> (x,
-    `own` as the layer leaves it, what it captured — a part each, () for
+    `active` says decode, `cap_len` that a capture is wanted). -> (the
+    carry, `own` as the layer leaves it — () where it wrote nothing —
+    what it captured — a part each, () for
     none — and an expert layer's `hybrid.MOE_COUNTS`, else None)."""
     from .models import hybrid, retention, shortconv
     positions, lengths, cap_len, active = (
         d["positions"], d["lengths"], d["cap_len"], d["active"])
     decode = active is not None
     t = positions.shape[1]
+    x, *beside = carry
     h = hybrid.layer_norm_in(x, layer, cfg)
     captured, counts = (), None
     if kind == hybrid.RETENTION:
@@ -585,11 +677,18 @@ def _paged_hybrid_layer(x, layer, own, held, d, *, kind: str,
         counts = hybrid.step_counts(c, jnp.any(d["counted"]))
     elif kind == hybrid.MLP:
         out = mlp(h, layer, cfg)
+    elif kind == hybrid.GMU:
+        out = hybrid.gmu(h, beside[0], layer, h.dtype)
     else:
-        q, entries, kw = _attention_io(h, layer, cfg, positions,
-                                       own[0].dtype)
-        own = tuple(p.at[d["pages"], d["offs"]].set(_cells(e, p))
-                    for p, e in zip(own, entries))
+        if kind == hybrid.CROSS:
+            # (reads what another layer wrote, under the MODEL's window,
+            # which is no layer's own: causal and unbounded)
+            q, kw = diffattn.queries(h, layer, cfg).astype(own[0].dtype), {}
+        else:
+            q, entries, kw = _attention_io(h, layer, cfg, positions,
+                                           own[0].dtype)
+            own = tuple(_write_cells(p, d["pages"], d["offs"], e)
+                        for p, e in zip(own, entries))
         k_pool, v_pool = (own + (None,))[:2]
         if t == 1:
             out = pattn.paged_decode_attention(
@@ -607,11 +706,13 @@ def _paged_hybrid_layer(x, layer, own, held, d, *, kind: str,
                 f"(T={t}, ps={page_size}); the engine gates hybrid "
                 "models on paged_direct at build time")
         out = _attention_out(out, h, layer, cfg, h.dtype)
-    return x + out, tuple(own), tuple(captured), counts
+        if kind == hybrid.CROSS:
+            own = ()
+    return (x + out, *beside), tuple(own), tuple(captured), counts
 
 
 @layer_body(static=("kind", "cfg", "page_size", "attn_path"))
-def _ragged_hybrid_layer(x, layer, own, held, d, *, kind: str,
+def _ragged_hybrid_layer(carry, layer, own, held, d, *, kind: str,
                          cfg: ModelConfig, page_size: Optional[int],
                          attn_path: str):
     """forward_ragged_hybrid's layer as a body, `_paged_hybrid_layer`'s
@@ -623,6 +724,7 @@ def _ragged_hybrid_layer(x, layer, own, held, d, *, kind: str,
     rg = dict(d["rg"], block=RAGGED_BLOCK_Q)
     positions = d["positions"]
     pos2 = positions[None]
+    x, *beside = carry
     h = hybrid.layer_norm_in(x, layer, cfg)
     captured, counts = (), None
     if kind == hybrid.RETENTION:
@@ -640,11 +742,17 @@ def _ragged_hybrid_layer(x, layer, own, held, d, *, kind: str,
         counts = hybrid.step_counts(c, 1)
     elif kind == hybrid.MLP:
         out = mlp(h, layer, cfg)
+    elif kind == hybrid.GMU:
+        out = hybrid.gmu(h, beside[0], layer, h.dtype)
     else:
-        q, entries, kw = _attention_io(h, layer, cfg, pos2, own[0].dtype)
-        own = tuple(
-            p.at[d["token_pages"], d["token_offs"]].set(_cells(e[0], p))
-            for p, e in zip(own, entries))                  # e [1,T,...]
+        if kind == hybrid.CROSS:
+            q, kw = diffattn.queries(h, layer, cfg).astype(own[0].dtype), {}
+        else:
+            q, entries, kw = _attention_io(h, layer, cfg, pos2,
+                                           own[0].dtype)
+            own = tuple(
+                _write_cells(p, d["token_pages"], d["token_offs"], e[0])
+                for p, e in zip(own, entries))              # e [1,T,...]
         k_pool, v_pool = (own + (None,))[:2]
         if attn_path == "kernel":
             out = pattn.ragged_paged_attention(
@@ -657,7 +765,9 @@ def _ragged_hybrid_layer(x, layer, own, held, d, *, kind: str,
                 q[0], k_pool, v_pool, d["tables"], rg["token_seq"],
                 positions, d["kv_valid"], cfg)
         out = _attention_out(out[None], h, layer, cfg, h.dtype)
-    return x + out, tuple(own), tuple(captured), counts
+        if kind == hybrid.CROSS:
+            own = ()
+    return (x + out, *beside), tuple(own), tuple(captured), counts
 
 
 def forward_paged_hybrid(
@@ -717,25 +827,38 @@ def forward_paged_hybrid(
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
     seen = collections.Counter()             # layers met, by kind
-    for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
+    carry = (x,)
+    # The seam: a join's rows, gathered where the layers keep nothing.
+    seam = _seam_run(cfg) if t > 1 and last_pos is not None else None
+    memory = _memory_run(cfg)
+    for r, ((kinds, _n), layer) in enumerate(zip(cfg.layer_runs,
+                                                 params["layers"])):
+        if r == seam:
+            carry = tuple(gather_rows(a, last_pos) for a in carry)
+            d = _above_seam(
+                jnp.take_along_axis(positions, last_pos[:, None], axis=1),
+                table, kv_valid_len)
         kind = kinds[0]
         i = seen[kind]
         seen[kind] += 1
         if kind == hybrid.MAMBA1:
+            emit = r == memory
             if decode:
                 def mixer(h, layer, s, c, l, held):
-                    return mamba1.mamba1_step(h, layer, cfg, s, c, l, rows,
-                                              active) + (held,)
+                    out = mamba1.mamba1_step(h, layer, cfg, s, c, l, rows,
+                                             active, emit)
+                    return out[:3] + (held,) + out[3:]
             else:
                 def mixer(h, layer, s, c, l, held):
                     return mamba1.mamba1_prefill(
                         h, layer, cfg, s, c, l, rows, lengths, held,
-                        cap_len, snap_idx)
+                        cap_len, snap_idx, emit)
             held = None if cap is None else (snaps["ssm1"][i],
                                              snaps["conv1"][i])
-            x, st["ssm1"][i], st["conv1"][i], held = _scan_run(
-                x, layer, kinds, cfg, st["ssm1"][i], st["conv1"][i],
-                held, mixer)
+            x, st["ssm1"][i], st["conv1"][i], held, *m = _scan_run(
+                carry[0], layer, kinds, cfg, st["ssm1"][i], st["conv1"][i],
+                held, mixer, emit)
+            carry = (x, *carry[1:], *m)
             if cap is not None:
                 cap["ssm1"].append(held[0])
                 cap["conv1"].append(held[1])
@@ -746,10 +869,12 @@ def forward_paged_hybrid(
         if kind == hybrid.RETENTION and cap is not None:
             held = (snaps["ret"][i], snaps["retn"][i])
         # (an attention layer: its own heads, window and rotary table,
-        # where the attention layers differ — ModelConfig.attn_layers)
-        x, own, captured, c = _paged_hybrid_layer(
-            x, layer,
-            pools[i] if attends else tuple(st[p][i] for p in parts),
+        # where the attention layers differ — ModelConfig.attn_layers;
+        # a cross layer: the pools of the attention layer below it)
+        carry, own, captured, c = _paged_hybrid_layer(
+            carry, layer,
+            pools[i] if attends else new_pools[-1]
+            if kind == hybrid.CROSS else tuple(st[p][i] for p in parts),
             held, d, kind=kind,
             cfg=cfg.attention_layer(i) if attends else cfg,
             page_size=page_size)
@@ -762,11 +887,21 @@ def forward_paged_hybrid(
                 cap[p].append(a)
         if c is not None:
             counts = counts + c
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
-    if last_pos is not None:
+    x = hybrid.final_norm(carry[0], params, cfg)
+    if last_pos is not None and seam is None:
         x = gather_rows(x, last_pos)
     new = {p: st[p] for p in state}
     return _hybrid_head(params, cfg, x), new_pools, new, cap, counts
+
+
+def _above_seam(positions, table, kv_valid_len) -> dict:
+    """The dispatch's arrays as the layers above the seam see them
+    (`_paged_hybrid_layer` at one token a row): positions [rows, 1] each
+    row's last, its page table and its valid length. Those layers keep
+    nothing, so nothing else is theirs to read."""
+    return {"positions": positions, "table": table,
+            "kv_valid_len": kv_valid_len, "lengths": None,
+            "cap_len": None, "active": None}
 
 
 def forward_ragged_hybrid(
@@ -813,29 +948,47 @@ def forward_ragged_hybrid(
     counts = jnp.zeros((len(hybrid.MOE_COUNTS),), jnp.int32)
     new_pools = []
     seen = collections.Counter()             # layers met, by kind
-    for (kinds, _n), layer in zip(cfg.layer_runs, params["layers"]):
+    carry = (x,)
+    seam, memory = _seam_run(cfg), _memory_run(cfg)
+    d_up = None                 # (set: the layers above the seam)
+    for r, ((kinds, _n), layer) in enumerate(zip(cfg.layer_runs,
+                                                 params["layers"])):
+        if r == seam:
+            # Each sequence's last token, a row: [1, T, .] -> [S, 1, .].
+            carry = tuple(a[0, last_rows][:, None] for a in carry)
+            d_up = _above_seam((kv_valid - 1)[:, None], tables, kv_valid)
         kind = kinds[0]
         i = seen[kind]
         seen[kind] += 1
         if kind == hybrid.MAMBA1:
+            emit = r == memory
+
             def mixer(h, layer, s, c, l, held):
                 return mamba1.mamba1_ragged(h, layer, cfg, s, c, l, rg,
-                                            held, snap_idx)
-            x, st["ssm1"][i], st["conv1"][i], held = _scan_run(
-                x, layer, kinds, cfg, st["ssm1"][i], st["conv1"][i],
-                (snaps["ssm1"][i], snaps["conv1"][i]), mixer)
+                                            held, snap_idx, emit)
+            x, st["ssm1"][i], st["conv1"][i], held, *m = _scan_run(
+                carry[0], layer, kinds, cfg, st["ssm1"][i], st["conv1"][i],
+                (snaps["ssm1"][i], snaps["conv1"][i]), mixer, emit)
+            carry = (x, *carry[1:], *m)
             cap["ssm1"].append(held[0])
             cap["conv1"].append(held[1])
             continue
         parts = _STATE_PARTS.get(kind, ())
         attends = kind == hybrid.ATTENTION
-        x, own, captured, c = _ragged_hybrid_layer(
-            x, layer,
-            pools[i] if attends else tuple(st[p][i] for p in parts),
-            ((snaps["ret"][i], snaps["retn"][i])
-             if kind == hybrid.RETENTION else None),
-            d, kind=kind, cfg=cfg.attention_layer(i) if attends else cfg,
-            page_size=page_size, attn_path=attn_path)
+        own = (pools[i] if attends else new_pools[-1]
+               if kind == hybrid.CROSS else tuple(st[p][i] for p in parts))
+        if d_up is not None:
+            carry, own, captured, c = _paged_hybrid_layer(
+                carry, layer, own, None, d_up, kind=kind, cfg=cfg,
+                page_size=page_size)
+        else:
+            carry, own, captured, c = _ragged_hybrid_layer(
+                carry, layer, own,
+                ((snaps["ret"][i], snaps["retn"][i])
+                 if kind == hybrid.RETENTION else None),
+                d, kind=kind,
+                cfg=cfg.attention_layer(i) if attends else cfg,
+                page_size=page_size, attn_path=attn_path)
         if attends:
             new_pools.append(own)
         for p, a, held in zip(parts, own, captured):
@@ -843,8 +996,10 @@ def forward_ragged_hybrid(
             cap[p].append(held)
         if c is not None:
             counts = counts + c
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, False)
-    logits = _hybrid_head(params, cfg, x[0, last_rows][None])
+    x = hybrid.final_norm(carry[0], params, cfg)
+    # ([S, 1, E] from the seam, else the buffer's rows gathered here)
+    sel = x[:, 0][None] if d_up is not None else x[0, last_rows][None]
+    logits = _hybrid_head(params, cfg, sel)
     new = {p: st[p] for p in state}
     return logits[0], new_pools, new, cap, counts
 

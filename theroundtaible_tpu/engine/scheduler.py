@@ -371,6 +371,7 @@ class SessionScheduler:
         self._snaps_seen = 0        # hybrid: snapshots at the last span's end
         self._scan_seen = 0         # ... and Mamba-1 tokens x layers scanned
         self._conv_seen = 0         # ... and short-conv tokens x layers run
+        self._seam_seen: dict = {}  # ... and a seam's counts (SEAM_COUNTS)
         self._hy_counting = False   # ... and whether that span was armed
         self.ragged_joins = 0
         # N-gram prompt indices by where they were built (ISSUE 30):
@@ -1727,6 +1728,9 @@ class SessionScheduler:
                 else self.engine.plain_window_reads(steps, read_to),
                 **self.engine.window_page_holdings(read_to))
         hy = getattr(self.engine, "hybrid", None)
+        if ragged is None and hy is not None:
+            # (a ragged dispatch counted its own at the seam)
+            self.engine.note_plain_shared_reads(steps, read_to)
         if hy is not None:
             # This segment has been read, so every program up to it has
             # ended: its expert counts fold into host ints without a
@@ -1756,6 +1760,7 @@ class SessionScheduler:
             # first span after arming only sets the base.
             delta, taken = hy.moe_delta(), hy.snapshots_taken
             scanned, conved = hy.scan_tokens, hy.conv_tokens
+            seam = dict(hy.seam)
             if self._hy_counting:
                 seg.attrs.update(
                     delta, snapshots_taken=taken - self._snaps_seen,
@@ -1765,8 +1770,12 @@ class SessionScheduler:
                     seg.attrs["scan_tokens"] = scanned - self._scan_seen
                 if self.engine.cfg.shortconv_layers:
                     seg.attrs["conv_tokens"] = conved - self._conv_seen
+                if self.engine.cfg.last_token_from is not None:
+                    seg.attrs.update({k: n - self._seam_seen.get(k, 0)
+                                      for k, n in seam.items()})
             self._hy_counting, self._snaps_seen = True, taken
             self._scan_seen, self._conv_seen = scanned, conved
+            self._seam_seen = seam
             seg.attrs["snapshot_bytes"] = hy.snapshot_bytes()
         seg.end()
 

@@ -1254,6 +1254,25 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
         "scan_runs": "static (lengths of the scanned runs)",
         "scan_tokens": "roundtable_mamba1_scan_tokens_total",
     },
+    # engine.describe()["seam"] (ISSUE 56): a model whose upper layers
+    # keep nothing (`ModelConfig.last_token_from`). What its join
+    # programs ran below the seam (tokens) and above it (rows: each
+    # sequence's last token), the rows of the memory carried across, and
+    # the positions the cross layers read of the pool they do not own —
+    # joins and decode steps both (HybridStateStore.note_join and
+    # note_shared_reads are the writers; a `segment` span carries each
+    # count for the programs it covers).
+    "engine_seam": {
+        "from_layer": "static (the first layer above the seam)",
+        "layers_above": "static (layers that keep nothing)",
+        "cross_layers": "static (layers that read another's pages)",
+        "memory_layer": "static (the layer whose scan output rides up)",
+        "lower_tokens": "roundtable_seam_lower_tokens_total",
+        "upper_rows": "roundtable_seam_upper_rows_total",
+        "memory_rows": "roundtable_seam_memory_rows_total",
+        "shared_pool_positions":
+            "roundtable_seam_shared_pool_positions_total",
+    },
     # engine.describe()["shortconv"] (ISSUE 52): the gated
     # short-convolution layers of a model that has them
     # (models/shortconv.py) and the tokens the join programs ran through
