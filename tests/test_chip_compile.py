@@ -500,6 +500,10 @@ WALK_WIDTHS = {"mistral-7b": (32, 8), "nemotron-3-nano": (32, 2),
 WALK_CASES = [(w, t, kv) for w in WALK_WIDTHS for t in (1024, 256)
               for kv in ("bf16", "int8")
               if not (w == "a.x-k1" and kv == "int8")]  # declines: S1b
+# ... and 1 536, a plain decoder's top shape at 16 slots (ISSUE 57:
+# serving_loop.ragged_token_budget) — Mistral's cell takes it; the
+# latent kernel's case is what ROADMAP S13 (d') starts from
+WALK_CASES += [("mistral-7b", 1536, "bf16"), ("a.x-k1", 1536, "bf16")]
 
 
 def _walk_case(widths: str, t: int, kv: str, one_chip):
@@ -534,7 +538,8 @@ def _walk_case(widths: str, t: int, kv: str, one_chip):
 @pytest.mark.parametrize("widths,t,kv", WALK_CASES)
 def test_ragged_kernel_compiles_at_the_cells_widths(one_chip, widths, t,
                                                     kv):
-    """Both flat-buffer shapes of all three cells, bf16 and int8 pages.
+    """Two flat-buffer shapes of all three cells, bf16 and int8 pages,
+    and 1 536 at the two attention-only widths.
     A bf16 pool is an operand of the call as the cell holds it — the
     benchmark's readers find the attention kernels by that operand —
     and is not copied on the way in."""
@@ -746,11 +751,11 @@ def test_laguna_kernel_compiles_for_v5e(one_chip, kernel, t, layers):
 
 
 def _window_step_hlo(cfg, pool, program: str, one_chip, check,
-                     join_at: int = 900) -> str:
+                     join_at: int = 900, ragged_t: int = RAGGED_T) -> str:
     """The compiled text of one decode step, one ragged join (a 150-token
-    run at `join_at` beside a decode row) or one 1024-token prologue
-    chunk of `cfg` over `pool` a layer; `check(params)` sees the
-    parameter shapes first."""
+    run at `join_at` beside a decode row, in a buffer of `ragged_t`) or
+    one 1024-token prologue chunk of `cfg` over `pool` a layer;
+    `check(params)` sees the parameter shapes first."""
     from theroundtaible_tpu.engine.models import hybrid
     from theroundtaible_tpu.engine.models.common import init_params
     from theroundtaible_tpu.engine.paged_forward import (
@@ -797,7 +802,7 @@ def _window_step_hlo(cfg, pool, program: str, one_chip, check,
         table = np.zeros((PAGES_PER_SEQ,), np.int32)
         b = build_ragged_batch(
             [RaggedSeq([5] * 150, join_at, table), RaggedSeq([7], 1300, table)],
-            t_budget=RAGGED_T, s_max=ROWS + 1,
+            t_budget=ragged_t, s_max=ROWS + 1,
             pages_per_seq=PAGES_PER_SEQ, scratch_page=0, pad_id=0,
             page_size=PAGE)
         names = ("tokens", "positions", "tables", "seq_of_block",
@@ -926,6 +931,44 @@ def test_hybrid_step_of_the_mellum_cut_compiles(one_chip, monkeypatch,
     # attention a block, and the grouped products of its experts
     assert hlo.count("tpu_custom_call") >= 2
     assert "grouped_matmul" in hlo
+
+
+@pytest.mark.parametrize("model,pool,compiles", [
+    ("laguna-xs.2", (CELL_POOL, 8), True),
+    ("mellum2-12b-a2.5b", (MELLUM_POOL, MELLUM_KV), False)])
+def test_hybrid_join_at_the_plain_decoders_top_shape(one_chip, monkeypatch,
+                                                     model, pool, compiles):
+    """Why `serving_loop.ragged_token_budget` leaves an engine of the
+    hybrid step programs its 1 024 (ISSUE 57): the ragged join of one
+    expert block in a 1 536-token buffer compiles at Laguna's widths
+    and is REFUSED at Mellum's — the gather of 1 536 x 8 assignments'
+    rows, `bf16[12288,2304]`, asks 16.41 MB of a 16 MB scoped limit
+    (the chip said the same: PERF.md, Findings PR 57). The day this
+    case compiles, ROADMAP S13 (d') is open."""
+    from theroundtaible_tpu.engine.models.registry import get_model_config
+
+    monkeypatch.setattr(pattn, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    whole = get_model_config(model)
+    at = next(i for i in range(0, len(whole.layer_kinds), 2)
+              if whole.layer_kinds[i + 1] == "experts")  # a mixer, experts
+    cfg = dataclasses.replace(
+        whole, num_layers=2, layer_kinds=whole.layer_kinds[at:at + 2],
+        attn_layers=whole.attn_layers[at // 2:at // 2 + 1],
+        attn_impl="flash")
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pages, kv = pool
+
+    def join():
+        return _window_step_hlo(
+            cfg, s((pages, PAGE, kv, D), jnp.bfloat16), "ragged", one_chip,
+            lambda params: None, ragged_t=1536)
+
+    if compiles:
+        assert "grouped_matmul" in join()
+    else:
+        with pytest.raises(Exception, match="vmem.*12288,2304"):
+            join()
 
 
 # --- power retention: the step kernel and the chunked runs (ISSUE 42) --------

@@ -164,6 +164,37 @@ class TestDrafter:
         for n in (1, 2, 3, 4):
             assert inc.draft(n) == fresh.draft(n)
 
+    @pytest.mark.parametrize("vocab,built,said", [
+        (5, 40, 30), (300, 700, 64), (32000, 2500, 128), (9, 31, 12),
+        (9, 32, 0), (2 ** 21 - 1, 64, 16), (2 ** 22, 64, 16)])
+    def test_a_corpus_built_whole_reads_as_the_loop_indexed_it(
+            self, monkeypatch, vocab, built, said):
+        """ISSUE 57: the tokens a drafter is built on are indexed as
+        sorted arrays from `_PACK_MIN` up; every gram of the corpus
+        then reads the two ends the dict's loop holds, and every
+        draft along the way is the loop's — also across the seam,
+        with a gram the dict saw once and the arrays before, and
+        where a token does not pack (the loop indexes it all)."""
+        import random
+        rng = random.Random(vocab * 1000 + built)
+        toks = [rng.randrange(vocab) for _ in range(built + said)]
+        if vocab > 2 ** 21:
+            toks[3] = vocab                      # one that does not pack
+        fast = NGramDrafter(toks[:built])
+        assert (fast._sorted is not None) == (
+            built >= sd._PACK_MIN and vocab <= 2 ** 21)
+        monkeypatch.setattr(sd, "_PACK_MIN", 10 ** 9)
+        loop = NGramDrafter(toks[:built])
+        assert loop._sorted is None and len(loop._index) > 0
+        for end in range(built, built + said + 1):
+            fast.sync(toks[:end])
+            loop.sync(toks[:end])
+            assert fast.draft(4) == loop.draft(4)
+            assert fast.draft_paths(4, 3) == loop.draft_paths(4, 3)
+        assert all(fast._ends(g) == ends
+                   for g, ends in loop._index.items())
+        assert fast._ends((vocab + 1,)) is None
+
     def test_empty_and_bounds(self):
         assert NGramDrafter([]).draft(4) == []
         assert NGramDrafter([1, 1]).draft(0) == []

@@ -943,7 +943,8 @@ class InferenceEngine:
                                        ragged_shape_grid)
             self.ragged_enabled = True
             self.ragged_tokens = ragged_token_budget(
-                num_slots, int(ragged_tokens or 0))
+                num_slots, int(ragged_tokens or 0),
+                hybrid=model_cfg.layer_kinds is not None)
             self.ragged_shapes = ragged_shape_grid(self.ragged_tokens)
             self.ragged_defer_min = ragged_defer_min()
             if attn == "dense":
@@ -1576,16 +1577,28 @@ class InferenceEngine:
         # built outside the get_engine cache (tests, benches) are
         # supervisable too.
         engine._engine_config = dict(config)
-        if engine.hybrid is not None and engine.ragged_enabled:
+        if engine.ragged_enabled:
             # A model with recurrent state compiles its ragged grid at
             # build: a join re-scans from where its state stands, so
             # which flat-buffer shape it takes depends on the batch's
             # composition, and traffic alone may first meet one of the
             # shapes long after start-up (a 21 s compile in the window:
-            # my chip run, PR 27).
+            # my chip run, PR 27). A plain decoder's joins compile as
+            # traffic meets them — until its grid has TWO shapes from
+            # the floor up: which of them a burst's want picks is then
+            # the traffic's chance (ragged_pick_shape), warm-up traffic
+            # may meet one and the window the other (an 18-21 s compile
+            # in the window, three runs of calls 2 and 3: my chip runs,
+            # PR 57), so its join program is warmed here too — alone and
+            # in the engine's own mode: the whole warm-up is eleven
+            # programs more than traffic compiles, 1.2 s each warm and
+            # 20 s cold on Mistral's cell (PERF.md, Findings PR 57).
             from . import compile_watch
-            with compile_watch.phase("warm_programs"):
-                engine._warm_ragged()
+            from .serving_loop import RAGGED_FLOOR_TOKENS
+            whole = engine.hybrid is not None
+            if whole or engine.ragged_shapes[-1] > RAGGED_FLOOR_TOKENS:
+                with compile_watch.phase("warm_programs"):
+                    engine._warm_ragged(whole=whole)
         if "dispatch_retries" in config:
             from .faults import RetryPolicy
             engine.retry = RetryPolicy(
@@ -1737,14 +1750,16 @@ class InferenceEngine:
         return time.monotonic() - t0
 
     @roomy_frame
-    def _warm_ragged(self) -> None:
+    def _warm_ragged(self, whole: bool = True) -> None:
         """Compile-and-stabilize the ragged mixed dispatch: a two-seq
         flat buffer (one prefill chunk + one decode-shaped row) through
         the REAL _ragged_dispatch seam, twice for the donated-pool
         layout fixpoint — in the engine-default sampling mode plus
         greedy (the scheduler's parity/STRICT mode) when they differ.
         The decode-shaped row attends warm garbage; outputs are
-        discarded, the compiled program is the point."""
+        discarded, the compiled program is the point. Not `whole`: the
+        join program alone, in the engine's own mode — what a plain
+        decoder's traffic compiles anyway (`from_config`)."""
         from .serving_loop import RaggedSeq, build_ragged_batch
         names = ("__warmup_0", "__warmup_1")
         if self.kv.num_slots < 2:
@@ -1758,15 +1773,14 @@ class InferenceEngine:
         t0 = self.kv.table_for([names[0]])[0]
         t1 = self.kv.table_for([names[1]])[0]
         bos = self.tokenizer.bos_id
-        modes = {True}
-        if self.sampling.temperature > 0.0:
-            modes.add(False)
+        own = self.sampling.temperature <= 0.0
+        modes = {True, own} if whole else {own}
         for greedy in sorted(modes, reverse=True):
             temp = 0.0 if greedy else max(self.sampling.temperature, 0.1)
             seqs = [RaggedSeq([bos] + [5] * 23, 0, t0, temperature=temp),
                     RaggedSeq([7], 8, t1, temperature=temp)]
             batches = [(seqs, 0, self.kv.num_slots + 1, 0, 0)]
-            if self.spec_decode:
+            if self.spec_decode and whole:
                 # Speculative verify programs (ISSUE 9 + 13): ONE extra
                 # compiled variant per (shape, mode) — score_width is
                 # the static spec_max_draft+1 and, on a tree-configured

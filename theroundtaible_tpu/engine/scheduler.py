@@ -82,6 +82,18 @@ from .serving_loop import (DECODE_SEGMENT, RAGGED_BLOCK_Q, RaggedSeq,
 # provenance surfaces keep (describe(), fleet_health).
 _OCCUPANCY_LOG_CAP = 256
 _EVENT_LOG_CAP = 64
+# What the loop sleeps after a flush that carried a row's FIRST tokens
+# when no row is left to fill (ISSUE 57): a burst's followers get theirs
+# in one flush, eight rows at once, and the streams' thread takes them
+# to their sockets over several turns of its own loop, each of which
+# waits for the interpreter while this thread runs on (CPython hands it
+# over 5 ms at a time). Measured on Mistral's cell (my chip runs, PR 57:
+# PERF.md, Findings, has the table): 0 ms — first tokens at their
+# clients 4-6 ms after the dispatch that sampled them, `ttft_p90_ms`
+# 108.9-112.5; 1 ms — 105.7-107.1; 2 ms — 2.5-3.7 ms after,
+# 104.3-112.4; 5 ms — 105.4-106.7. Where a last flush carries two rows
+# (the parent's packing) it buys nothing.
+_STREAM_YIELD_S = 0.002
 
 # The loop clock's phases (ISSUE 25; PERF.md section 3 has the table):
 # every instant of the scheduler's thread belongs to exactly one.
@@ -381,6 +393,11 @@ class SessionScheduler:
         self.indexed_at_draft = 0
         self.segment_prefill_tokens = 0
         self.segment_decode_tokens = 0
+        # What the join dispatches carried, by flat-buffer shape (ISSUE
+        # 57): {shape: [dispatches, buffer tokens, real tokens]} — a
+        # dispatch computes its whole buffer, so real over buffer is
+        # the share of a join program's work that served a token.
+        self.ragged_fill: dict[int, list[int]] = {}
         # Speculative verify dispatches issued (ISSUE 9) — bumped in
         # lockstep with its registry series like every counter here.
         self.spec_segments = 0
@@ -707,6 +724,10 @@ class SessionScheduler:
                 for r in list(self._active) if r.spec is not None)),
             "segment_prefill_tokens": self.segment_prefill_tokens,
             "segment_decode_tokens": self.segment_decode_tokens,
+            "ragged_fill": {
+                str(shape): dict(zip(
+                    ("dispatches", "buffer_tokens", "real_tokens"), row))
+                for shape, row in sorted(self.ragged_fill.items())},
             "queued": len(self._queue),
             "queued_peak": self.queued_peak,
             "active_rows": len(self._active),
@@ -993,7 +1014,9 @@ class SessionScheduler:
             if not self._run_spec_segment(live):
                 self._run_segment(live)
         clock.mark("flush")
-        self._flush_streams()
+        if self._flush_streams() and not any(
+                r.pending for r in self._active):
+            time.sleep(_STREAM_YIELD_S)
         clock.mark("retire")
         self._retire_finished()
         clock.mark("health")
@@ -1794,6 +1817,25 @@ class SessionScheduler:
             telemetry.inc("roundtable_segment_decode_tokens_total",
                           decode, engine=self._tname)
 
+    def _note_ragged_fill(self, seg, shape: int, want: int,
+                          real: int) -> None:
+        """One join dispatch's flat buffer (`shape`) and the real tokens
+        it carried, into `ragged_fill`, the two series and the
+        dispatch's `segment` span — which also carries `want`, what the
+        rows that were due asked of the buffer in 8-row blocks before
+        the budget capped it (how much a larger top shape would have
+        taken)."""
+        row = self.ragged_fill.setdefault(shape, [0, 0, 0])
+        row[0] += 1
+        row[1] += shape
+        row[2] += real
+        telemetry.inc("roundtable_ragged_buffer_tokens_total", shape,
+                      engine=self._tname, shape=shape)
+        telemetry.inc("roundtable_ragged_real_tokens_total", real,
+                      engine=self._tname, shape=shape)
+        if seg is not telemetry.NULL_SPAN:
+            seg.attrs.update(shape=shape, real_tokens=real, want=want)
+
     def _apply_share_plans(self) -> None:
         """Alias deferred leader spans whose leader chunks have written
         the common span (kvcache.share_prefixes defer_span contract):
@@ -1945,8 +1987,19 @@ class SessionScheduler:
         want = RAGGED_BLOCK_Q * len(live) + sum(
             -(-len(r.pending) // RAGGED_BLOCK_Q) * RAGGED_BLOCK_Q
             for r in filling)
-        shape = ragged_pick_shape(engine.ragged_shapes,
-                                  min(want, budget_slots))
+        # A want past the top shape is two dispatches, the second with
+        # a block of every active row beside the remainder: the first
+        # is the shape that makes the two cheapest. (An engine of the
+        # hybrid step programs keeps the parent's pick with the
+        # parent's budget — ragged_token_budget has the reasons; of
+        # the nine cells only Brumby's key gives such an engine a
+        # second shape to pick, and its followers re-scan a page's
+        # remainder, so what a second dispatch brings there is not
+        # the rows' blocks.)
+        shape = ragged_pick_shape(
+            engine.ragged_shapes,
+            want if engine.hybrid is None else min(want, budget_slots),
+            carry=RAGGED_BLOCK_Q * len(self._active))
         seqs: list[RaggedSeq] = []
         rows_in: list[tuple[str, _Row, int]] = []
         for r in live:
@@ -2049,6 +2102,7 @@ class SessionScheduler:
         telemetry.inc("roundtable_sched_ragged_segments_total",
                       engine=self._tname)
         self._note_segment_tokens(n_prefill, n_decode)
+        self._note_ragged_fill(seg, shape, want, n_prefill + n_decode)
         self._end_segment(seg, 1, n_decode, n_prefill,
                           read_to=tuple(r.pos if kind != "decode"
                                         else r.valid
@@ -3048,20 +3102,23 @@ class SessionScheduler:
             self._event("stream_error", session=req.session,
                         error=str(e)[:200])
 
-    def _stream_flush(self, req: _Request) -> None:
+    def _stream_flush(self, req: _Request) -> bool:
         """Push each row's NEW committed tokens (eos-trimmed, so the
         stream never carries post-eos filler and matches the journal's
-        `produced` exactly) to the request's on_commit callback."""
+        `produced` exactly) to the request's on_commit callback.
+        -> whether a row's first tokens were among them."""
         if req.on_commit is None:
-            return
+            return False
         engine = self.engine
         eos = engine.tokenizer.eos_id
         max_new, _padded = clamp_max_new(req.max_new,
                                          engine.max_seq_len)
+        first = False
         for i, r in enumerate(req.rows):
             ids = eos_trim(list(r.produced), eos, max_new)
             if len(ids) <= r.streamed:
                 continue
+            first = first or r.streamed == 0
             new = ids[r.streamed:]
             # queue_wait_s rides every tokens event (ISSUE 20): the
             # gateway's critical-path trace carves the scheduler queue
@@ -3073,18 +3130,22 @@ class SessionScheduler:
                 "queue_wait_s": round(
                     (req.admitted_at or req.enqueued) - req.enqueued, 3)})
             if req.on_commit is None:
-                return  # callback died mid-flush
+                return first  # callback died mid-flush
             r.streamed = len(ids)
+        return first
 
-    def _flush_streams(self) -> None:
+    def _flush_streams(self) -> bool:
         """The streaming seam's tick hook: after every segment fold
         (ragged, spec, while-loop — all land in rows' `produced`),
         flush each streaming request's newly committed span. Tokens
         flush at SEGMENT boundaries, the same grain retirement and the
-        journal observe — a streamed token is always a committed one."""
+        journal observe — a streamed token is always a committed one.
+        -> whether some row's first tokens went out."""
+        first = False
         for req in list(self._active_reqs):
             if req.on_commit is not None:
-                self._stream_flush(req)
+                first = self._stream_flush(req) or first
+        return first
 
     # --- retirement ---
 

@@ -32,6 +32,10 @@ DECODE_SEGMENT = 64  # tokens per decode program; timeout checks in between
 # composition, so the budget is the whole "shape grid" on this path —
 # a small fixed set of max-token shapes, not per-occupancy buckets.
 RAGGED_BLOCK_Q = 8
+# ... and what a slot brings to the join buffer's top shape on a plain
+# decoder (ragged_token_budget): a multiple of the block.
+RAGGED_SLOT_TOKENS = 96
+RAGGED_FLOOR_TOKENS = 1024
 RAGGED_TOKENS_ENV = "ROUNDTABLE_RAGGED_TOKENS"
 RAGGED_DEFER_MIN_ENV = "ROUNDTABLE_RAGGED_DEFER_MIN"
 
@@ -56,19 +60,43 @@ def roomy_frame(fn):
     return fn
 
 
-def ragged_token_budget(num_slots: int, asked: int = 0) -> int:
-    """Flat-buffer capacity per ragged dispatch: big enough that a
-    typical cold join's leader span streams in ONE dispatch — chunk
-    throughput must be bucket-class or deferral just slows the joiner
-    down — floored so every resident row's 8-row decode block still
-    leaves chunk room. ROUNDTABLE_RAGGED_TOKENS, or else the engine
-    config's `ragged_tokens` (`asked`), overrides (rounded up to a
-    block multiple)."""
+def ragged_token_budget(num_slots: int, asked: int = 0,
+                        hybrid: bool = False) -> int:
+    """Flat-buffer capacity per ragged dispatch — the TOP shape of the
+    join grid (ragged_shape_grid). The rule (ISSUE 57): on a PLAIN
+    decoder the top shape holds a round's leaders —
+    RAGGED_SLOT_TOKENS (96) a slot, 1 536 at 16 slots. A round of five
+    sessions is the first one's prologue and a burst of the other four:
+    four leaders' ~320-token deltas beside the first session's decode
+    blocks want 1 280-1 432 in fifteen bursts of twenty (my chip runs,
+    PR 57: PERF.md, Findings, with the `segment` spans; the ledger's
+    PR 57 line on `mistral-7b-int8.roundtable` bears it out or does
+    not), so the leaders ride ONE dispatch, their eight followers the
+    next, and the burst is two dispatches where 1 024 cut the fourth
+    leader and made it three. `hybrid` — the engine serves its model
+    through the hybrid step programs (`engine.hybrid`) — keeps ISSUE
+    8's budget, and the reasons are those programs' (PERF.md, same
+    entry): a shape is TWO of them, warmed at build (A.X-K1 at 1 536:
+    +9.7 % of its warm set-up against a bound of 10 %; Jamba: +13.5 %,
+    and +17 % of its median first token, since a follower there
+    re-scans a page's remainder and a round's work spreads over the
+    dispatches whatever the top shape), and the chip's compiler
+    refuses the 1 536 step at Mellum's widths (the experts' gather of
+    [12 288, 2 304]: tests/test_chip_compile.py has the case, and
+    Laguna's, which compiles — ROADMAP S13 (d') is the way back for
+    the expert cells). Both are floored at 1 024 — a typical cold
+    join's leader span streams in ONE dispatch — and leave every
+    resident row's 8-row decode block its chunk room.
+    ROUNDTABLE_RAGGED_TOKENS, or else the engine config's
+    `ragged_tokens` (`asked`), overrides (rounded up to a block
+    multiple)."""
     import os
     forced = int(os.environ.get(RAGGED_TOKENS_ENV, "0") or 0) or asked
     if forced > 0:
         return -(-forced // RAGGED_BLOCK_Q) * RAGGED_BLOCK_Q
-    return max(1024, RAGGED_BLOCK_Q * num_slots + 64)
+    if hybrid:
+        return max(RAGGED_FLOOR_TOKENS, RAGGED_BLOCK_Q * num_slots + 64)
+    return max(RAGGED_FLOOR_TOKENS, RAGGED_SLOT_TOKENS * num_slots)
 
 
 def ragged_defer_min() -> int:
@@ -97,12 +125,23 @@ def ragged_shape_grid(budget: int) -> tuple[int, ...]:
                          if s <= budget}))
 
 
-def ragged_pick_shape(grid: tuple[int, ...], want: int) -> int:
-    """Smallest grid shape >= want (the last shape when none is)."""
+def ragged_pick_shape(grid: tuple[int, ...], want: int,
+                      carry: int = 0) -> int:
+    """Smallest grid shape >= want. A want past the last shape takes a
+    second dispatch whatever is picked, and a dispatch computes its
+    whole buffer: the first is then the shape — of those from the
+    floor of ragged_token_budget up — with which the two compute the
+    fewest buffer tokens, the smaller on a tie (first tokens sooner).
+    `carry`: what the second dispatch brings of its own beside the
+    remainder (the join packer: a block of every active row). A caller
+    that caps its want at the budget gets the last shape, as ever; so
+    does a grid with one shape from the floor up."""
     for s in grid:
         if want <= s:
             return s
-    return grid[-1]
+    firsts = [s for s in grid if s >= RAGGED_FLOOR_TOKENS] or grid[-1:]
+    return min(firsts, key=lambda s: (
+        s + ragged_pick_shape(grid, min(want - s + carry, grid[-1])), s))
 
 
 # What the dispatch being issued has cost so far (ISSUE 53): the open

@@ -89,6 +89,7 @@ refcount surface), and rejected bytes live past it by definition.
 
 from __future__ import annotations
 
+import bisect
 import threading
 from collections import deque
 from typing import Any, Optional, Protocol, runtime_checkable
@@ -267,6 +268,43 @@ class Drafter(Protocol):
                     branch: int = 1) -> list[list[int]]: ...
 
 
+# A corpus indexed WHOLE (a prompt, at a row's first index) goes
+# through numpy, not the dict: a gram is packed into one int64 at
+# _PACK_BITS a token, and an order's grams are sorted once. Under
+# _PACK_MIN tokens the dict's loop is as quick.
+_PACK_BITS = 63 // NGRAM_MAX
+_PACK_MIN = 32
+
+
+def _sorted_grams(toks: list[int]) -> Optional[list[tuple]]:
+    """For each gram order n in 1..NGRAM_MAX: (the distinct grams of
+    `toks`, packed and sorted; each one's most recent END; the END
+    before that, or -1), a list to bisect and two arrays beside it —
+    what the dict's loop would hold for the same tokens
+    (`NGramDrafter.extend`), four times as fast at a transcript's
+    length. None where a token does not pack."""
+    import numpy as np
+    arr = np.asarray(toks, np.int64)
+    if arr.min() < 0 or arr.max() >> _PACK_BITS:
+        return None
+    tiers = []
+    for n in range(1, NGRAM_MAX + 1):
+        count = len(arr) - n + 1
+        if count < 1:
+            break
+        keys = arr[:count]
+        for i in range(1, n):
+            keys = (keys << _PACK_BITS) | arr[i:count + i]
+        order = np.argsort(keys, kind="stable")   # equal grams: by end
+        keys, ends = keys[order], order + n
+        last = np.flatnonzero(np.append(keys[1:] != keys[:-1], True))
+        before = np.maximum(last - 1, 0)
+        prev = np.where((last > 0) & (keys[before] == keys[last]),
+                        ends[before], -1)
+        tiers.append((keys[last].tolist(), ends[last], prev))
+    return tiers
+
+
 class NGramDrafter:
     """Hash-indexed n-gram / prompt-lookup proposer over ONE row's
     corpus: its (transcript-carrying, prefix-cache-attached) prompt plus
@@ -278,9 +316,15 @@ class NGramDrafter:
     draft looks up the context's tail gram and proposes the tokens that
     FOLLOWED it last time; the second-most-recent slot exists because
     the tail gram's own occurrence is always the most recent one and
-    carries no continuation."""
+    carries no continuation.
 
-    __slots__ = ("_toks", "_index")
+    The tokens a drafter is BUILT on (a prompt of thousands: the host
+    work of a round's joins, which must fit under the joins' own
+    dispatches — scheduler._index_in_flight; PERF.md, Findings PR 57)
+    are indexed as sorted arrays (`_sorted_grams`), and only what is
+    appended later goes into the dict; a lookup reads both."""
+
+    __slots__ = ("_toks", "_index", "_sorted")
 
     kind = "ngram"
 
@@ -288,6 +332,8 @@ class NGramDrafter:
         self._toks: list[int] = []
         # gram tuple -> (last_end, prev_end); end = index AFTER the gram.
         self._index: dict[tuple, tuple[int, int]] = {}
+        # ... of the ends past the tokens `_sorted` covers, if it does.
+        self._sorted: Optional[list[tuple]] = None
         if tokens:
             self.extend(tokens)
 
@@ -299,6 +345,10 @@ class NGramDrafter:
         toks = self._toks
         start = len(toks)
         toks.extend(tokens)
+        if not start and len(toks) >= _PACK_MIN:
+            self._sorted = _sorted_grams(toks)
+            if self._sorted is not None:
+                return
         idx = self._index
         for end in range(start + 1, len(toks) + 1):
             for n in range(1, NGRAM_MAX + 1):
@@ -310,6 +360,23 @@ class NGramDrafter:
                     idx[key] = (end, -1)
                 elif prev[0] != end:
                     idx[key] = (end, prev[0])
+
+    def _ends(self, gram: tuple) -> Optional[tuple[int, int]]:
+        """(most recent end, the one before or -1) of `gram`, or None."""
+        late = self._index.get(gram)
+        if self._sorted is None or (late is not None and late[1] != -1):
+            return late
+        packed = 0
+        for tok in gram:
+            if tok < 0 or tok >> _PACK_BITS:
+                return late
+            packed = (packed << _PACK_BITS) | tok
+        keys, last, prev = self._sorted[len(gram) - 1]
+        i = bisect.bisect_left(keys, packed)
+        if i == len(keys) or keys[i] != packed:
+            return late
+        return ((int(last[i]), int(prev[i])) if late is None
+                else (late[0], int(last[i])))
 
     def sync(self, context: list[int]) -> None:
         """Bring the index up to `context` (prompt + produced): extends
@@ -351,7 +418,7 @@ class NGramDrafter:
         paths: list[list[int]] = []
         seen_first: set[int] = set()
         for n in range(min(NGRAM_MAX, len(toks)), 0, -1):
-            entry = self._index.get(tuple(toks[len(toks) - n:]))
+            entry = self._ends(tuple(toks[len(toks) - n:]))
             if entry is None:
                 continue
             # The tail gram itself is always the most recent occurrence;

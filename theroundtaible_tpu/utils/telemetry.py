@@ -1104,6 +1104,11 @@ SURFACE_BINDINGS: dict[str, dict[str, str]] = {
             "roundtable_segment_prefill_tokens_total",
         "segment_decode_tokens":
             "roundtable_segment_decode_tokens_total",
+        # ISSUE 57: the join dispatches by flat-buffer shape
+        # (scheduler._note_ragged_fill is the one writer).
+        "ragged_fill": "roundtable_ragged_buffer_tokens_total{shape=...} "
+                       "/ roundtable_ragged_real_tokens_total{shape=...} "
+                       "(dispatches: describe-only)",
         "requeues": "roundtable_sched_requeues_total",
         "queued": "roundtable_sched_queue_depth gauge",
         "queued_peak": "max over roundtable_sched_queue_depth",
